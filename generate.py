@@ -16,7 +16,10 @@ init — which still exercises the full engine, scheduler and cache path.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import json
 import sys
+from functools import partial
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -179,7 +182,12 @@ def main(argv: list[str] | None = None) -> int:
             raise SystemExit(f"invalid constraint: {e}")
         overrides.append("inference.constrained=true")
     cfg = get_config(args.preset, overrides)
-    initialize(cfg.runtime)
+    if overrides:
+        print("overrides: " + " ".join(overrides), flush=True)
+    # What the run actually landed on, up front.
+    print("runtime: " + json.dumps(
+        dataclasses.asdict(initialize(cfg.runtime))
+    ), flush=True)
 
     prompts: list[list[int]] = []
     for spec in args.tokens:
@@ -193,7 +201,12 @@ def main(argv: list[str] | None = None) -> int:
     if not prompts:
         prompts = [[1, 2, 3, 4]]
 
-    params = init_params(cfg.model, jax.random.key(cfg.train.seed))
+    # Jitted, so each matrix is drawn, scaled and cast in one fused program
+    # instead of op-by-op with full-size temporaries — a 7B-wide stack in
+    # bf16 must initialize inside one chip's memory.
+    params = jax.jit(partial(init_params, cfg.model))(
+        jax.random.key(cfg.train.seed)
+    )
     if cfg.checkpoint.directory:
         # Trainer checkpoints hold the full train state; restore through the
         # SHARDED abstract state (NamedShardings attached), so a 70B-class
@@ -231,7 +244,8 @@ def main(argv: list[str] | None = None) -> int:
     # at the next step boundary the engine stops admission, sheds the wait
     # queue with typed outcomes, FINISHES every live request — donating
     # their pages to the prefix cache exactly as normal completion does —
-    # and this process exits 0 instead of dying mid-dispatch.
+    # and this process leaves through the normal exit path (non-zero if a
+    # queued request was shed) instead of dying mid-dispatch.
     with PreemptionHandler() as handler:
         reqs = [
             engine.submit_request(
@@ -283,6 +297,15 @@ def main(argv: list[str] | None = None) -> int:
         print(f"request {i}: prompt={prompt} -> generated={out}{tag}")
         if args.byte_tokenizer:
             print(f"  text: {bytes(t % 256 for t in out).decode('utf-8', 'replace')!r}")
+    # A request that ended in a typed error, a shed or an expiry is a
+    # failed run, not a tag on a green one.
+    failed = [
+        (i, r.outcome) for i, r in enumerate(reqs) if r.outcome != "completed"
+    ]
+    if failed:
+        print(f"FAILED: {len(failed)} of {len(reqs)} requests did not "
+              f"complete: {failed}", file=sys.stderr)
+        return 1
     return 0
 
 

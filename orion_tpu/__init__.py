@@ -10,70 +10,3 @@ batching engine for inference.
 """
 
 __version__ = "0.1.0"
-
-# -- jax API compatibility ---------------------------------------------------
-# The codebase targets the current jax surface (``jax.shard_map`` with
-# ``check_vma``); on older runtimes where shard_map still lives under
-# jax.experimental (and the flag is called check_rep), install an adapter at
-# the same spot so every call site — and tests importing ``jax.shard_map`` —
-# runs unchanged. No-op on new jax.
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _esm
-
-    def _shard_map_compat(
-        f, mesh=None, in_specs=None, out_specs=None, check_vma=None,
-        axis_names=None, **kw
-    ):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        if axis_names is not None:
-            # New-jax partial-manual selection; old spelling is the
-            # complementary ``auto`` axis set.
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return _esm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-
-    # Marker consumed by parallel/pipeline.py: the old runtime's SPMD
-    # partitioner cannot lower a partial-auto (axis_names-subset) region
-    # that uses axis_index / ppermute, or the transposed while loop
-    # jax.grad makes of a scanned one — the pipeline switches to its
-    # compat formulation (stage-id inputs, one-hot reduce-scatter ring
-    # hops, unrolled tick loops) when it sees this.
-    _shard_map_compat._orion_compat = True
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    def _axis_size(name):
-        # psum of a literal constant-folds to the static axis size.
-        return _jax.lax.psum(1, name)
-
-    _jax.lax.axis_size = _axis_size
-
-if not hasattr(_jax.lax, "pcast"):
-    def _pcast(x, *args, **kwargs):
-        # pcast only annotates replication for the new check_vma machinery;
-        # under the old shard_map (check_rep=False) identity is correct.
-        return x
-
-    _jax.lax.pcast = _pcast
-
-# The `name` primitive (jax.ad_checkpoint.checkpoint_name — the
-# remat="names" annotation in models/transformer.py) has no shard_map
-# replication rule on this jax version, so a rep-checked shard_map region
-# (the pipeline loop) raises "No replication rule for name" for ANY model
-# whose block body carries annotations. checkpoint_name is an identity:
-# the standard check (output replication = input replication) and the
-# no-rewrite rule are exact. No-op where jax already registers them.
-try:
-    from jax._src.ad_checkpoint import name_p as _name_p
-    from jax.experimental import shard_map as _sm_mod
-
-    if _name_p not in _sm_mod._check_rules:
-        _sm_mod.register_standard_check(_name_p)
-    if _name_p not in _sm_mod._rewrite_rules:
-        _sm_mod.register_norewrite(_name_p)
-except (ImportError, AttributeError):
-    pass

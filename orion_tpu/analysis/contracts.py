@@ -142,23 +142,29 @@ def _try_trace(jitted, args, kwargs=None):
 # ---------------------------------------------------------------------------
 
 
+def _open_jaxpr(x):
+    """The open jaxpr behind ``x`` (a jaxpr, or the closed form that wraps
+    one in ``.jaxpr``), or None for any other equation parameter. Duck-
+    typed on ``.eqns``: the classes' import path has moved between jax
+    releases, their shape has not."""
+    inner = getattr(x, "jaxpr", x)
+    return inner if hasattr(inner, "eqns") else None
+
+
 def _sub_jaxprs(eqn) -> Iterator:
     for v in eqn.params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for x in vs:
-            if isinstance(x, jax.core.ClosedJaxpr):
-                yield x.jaxpr
-            elif isinstance(x, jax.core.Jaxpr):
-                yield x
+            sub = _open_jaxpr(x)
+            if sub is not None:
+                yield sub
 
 
 def iter_eqns(jaxpr) -> Iterator:
     """Depth-first over every equation, descending into sub-jaxprs
     (scan/while/cond/pjit bodies) — a census over *staged sites*, not
     executions: a scanned layer body contributes each primitive once."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
-        jaxpr = jaxpr.jaxpr
-    for eqn in jaxpr.eqns:
+    for eqn in _open_jaxpr(jaxpr).eqns:
         yield eqn
         for sub in _sub_jaxprs(eqn):
             yield from iter_eqns(sub)
@@ -881,12 +887,10 @@ def _registry() -> dict[str, Contract]:
     )
 
     def _pp_hop_band(art: ProgramArtifact) -> tuple:
-        """Staged ring-hop band for a pipeline step: the ticks are
-        python-unrolled on the compat path (one staged hop per fwd tick
-        + one per bwd tick, minus the skipped boundary hops =
-        2*(M+pp-1)-2 for the differentiated schedules) and lax.scan'd on
-        modern jax / 1f1b (the body stages its hop once) — so the band
-        is [2, 2*(M+pp-1)]. Zero means the ring is GONE (stages stopped
+        """Staged ring-hop band for a pipeline step: the tick loops are
+        lax.scan'd, so each loop body stages its hop once (fwd + bwd =
+        2), and XLA may unroll up to one per tick — the band is
+        [2, 2*(M+pp-1)]. Zero means the ring is GONE (stages stopped
         talking); above means a schedule staged extra hops per tick."""
         p = art.meta["cfg"].parallel
         return (2, 2 * (p.pp_microbatches + p.pp - 1))
@@ -898,30 +902,20 @@ def _registry() -> dict[str, Contract]:
                    "data.batch_size=4"),
         predicates=(
             # Ring hops only: point-to-point traffic spelled as
-            # collective-permute (modern jax ppermute) or the one-hot
-            # psum_scatter emulation (compat seam -> reduce-scatter).
-            # An all-gather here is the failure mode where a stage
-            # gathers the whole activation stack instead of ring-hopping
-            # its slice; all-reduce belongs to the metric scalars only.
+            # collective-permute (lax.ppermute). An all-gather here is
+            # the failure mode where a stage gathers the whole activation
+            # stack instead of ring-hopping its slice; all-reduce belongs
+            # to the metric scalars only.
             collective_inventory(
-                all_gather=0, all_to_all=0,
-                collective_permute=lambda a: (0, _pp_hop_band(a)[1]),
-                reduce_scatter=lambda a: (0, _pp_hop_band(a)[1]),
-            ),
-            Predicate(
-                "ring_hops_present",
-                lambda a: [] if sum(
-                    collective_census(a.optimized_hlo)[op]
-                    for op in ("collective-permute", "reduce-scatter")
-                ) >= 2 else ["no ring hops staged: the pipeline ring "
-                             "is gone (stages not communicating)"],
+                all_gather=0, all_to_all=0, reduce_scatter=0,
+                collective_permute=_pp_hop_band,
             ),
             donation_complete,
         ),
         devices=2,
         doc="pp=2 pipeline step: ring-hop count per tick bounded "
-            "(2..2*(M+pp-1) staged hops as permute/psum_scatter), no "
-            "stage-gather all-gathers",
+            "(2..2*(M+pp-1) staged collective-permutes), no stage-gather "
+            "all-gathers",
     )
 
     # -- engine programs --------------------------------------------------

@@ -1,14 +1,18 @@
 """ctypes bindings for the native (C++) token-shard reader.
 
-Compiled on first use with g++ into this package directory (no network, no
-pybind11 — plain C ABI + ctypes, per the toolchain constraints). Callers
-treat ImportError/OSError as "native unavailable" and fall back to the numpy
-memmap reader (orion_tpu.data.loader._open_reader).
+Compiled on first use with g++ (no network, no pybind11 — plain C ABI +
+ctypes, per the toolchain constraints) into the checkout's git-ignored
+``.native_build/`` directory, under a name keyed on the SOURCE'S CONTENT: a
+copied or freshly unpacked checkout preserves no trustworthy mtimes, and a
+binary built from another revision of the source is simply a different file.
+Callers treat ImportError/OSError as "native unavailable" and fall back to
+the numpy memmap reader (orion_tpu.data.loader._open_reader).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,18 +21,21 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "native_loader.cpp")
-_SO = os.path.join(_DIR, "libnative_loader.so")
+_BUILD_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(_DIR))), ".native_build"
+)
 _BUILD_LOCK = threading.Lock()
 
 
 def _build() -> str:
     with _BUILD_LOCK:
-        if (
-            os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-        ):
-            return _SO
-        tmp = _SO + f".tmp.{os.getpid()}"
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        so = os.path.join(_BUILD_DIR, f"libnative_loader-{digest}.so")
+        if os.path.exists(so):
+            return so
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        tmp = so + f".tmp.{os.getpid()}"
         cmd = [
             "g++", "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
             _SRC, "-o", tmp,
@@ -38,8 +45,8 @@ def _build() -> str:
         except (subprocess.CalledProcessError, FileNotFoundError) as e:
             detail = getattr(e, "stderr", str(e))
             raise ImportError(f"native loader build failed: {detail}") from e
-        os.replace(tmp, _SO)  # atomic: concurrent processes race safely
-        return _SO
+        os.replace(tmp, so)  # atomic: concurrent processes race safely
+        return so
 
 
 def _load() -> ctypes.CDLL:

@@ -137,6 +137,11 @@ class InferenceEngine:
                 f"unknown model.weight_quant={self.mcfg.weight_quant!r}"
             )
         self.params = params
+        # The device the params live on (a router replica's, a mesh's
+        # first) — not whatever jax.devices()[0] happens to be.
+        self.device = min(
+            jax.tree.leaves(params)[0].devices(), key=lambda d: d.id
+        )
         self.eos_id = eos_id
         self.psz = self.icfg.page_size
         self.pages_per_seq = pages_per_seq(self.icfg)
@@ -603,7 +608,7 @@ class InferenceEngine:
                 "constrain", lambda: self.constraint_stats.as_timing()
             )
         reg.register("pool", self._pool_metrics)
-        reg.register("hbm", live_hbm_metrics)
+        reg.register("hbm", partial(live_hbm_metrics, self.device))
 
     def _register_trace_metrics(self) -> None:
         """Ring-occupancy gauges ("trace" section: events/capacity/

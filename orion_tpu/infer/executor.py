@@ -55,7 +55,8 @@ class DispatchExecutor:
     def __init__(self, engine):
         self.eng = engine
         # XLA reference programs, built lazily per dispatch name the first
-        # time a Pallas dispatch fails (inference.dispatch_fallback).
+        # time a Pallas dispatch fails (inference.dispatch_fallback, an
+        # opt-in: by default a failed dispatch fails the step).
         self._xla_fallbacks: dict[str, Any] = {}
         # Backoff jitter source. Fixed seed so a replayed fault episode
         # sleeps the same schedule; sleep durations never touch tokens,
@@ -128,10 +129,11 @@ class DispatchExecutor:
         """Run one device dispatch with the fault-tolerance envelope: the
         injection points (stall sleeps; dispatch exceptions raised BEFORE
         the primary call, so engine/cache state is untouched and retry is
-        sound), then on ANY failure up to ``inference.dispatch_retries``
-        retries on the XLA reference path, jittered backoff between
-        attempts. Raises DispatchFault(path) when every path is exhausted
-        — the engine fails the step, not the process.
+        sound), then — only with ``inference.dispatch_fallback`` on — on
+        ANY failure up to ``inference.dispatch_retries`` retries on the
+        XLA reference path, jittered backoff between attempts. Raises
+        DispatchFault(path) when every path is exhausted (at once, with
+        the fallback off) — the engine fails the step, not the process.
 
         The primary result is blocked on HERE so that execute-time device
         errors (async dispatch defers them to the first fetch) surface

@@ -19,28 +19,43 @@ from typing import Any, Optional
 
 import jax
 
-# Peak bf16 FLOP/s per chip by device kind; used for MFU. The dev chip is a
-# v5e (197 TF), the judged target a v5p (459 TF) — keep both so MFU is right
-# on either (SURVEY.md §8).
-PEAK_FLOPS_BF16: dict[str, float] = {
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v5": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
-    "cpu": 1e12,  # nominal, keeps MFU finite in CPU tests
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks: the denominators of MFU and MBU."""
+
+    bf16_flops: float        # dense bf16 FLOP/s
+    hbm_bytes_per_s: float   # HBM bandwidth
+    source: str
+
+
+_CLOUD_TPU_DOCS = "Google Cloud TPU documentation, system architecture"
+
+# The ONE peaks table (bench.py reads it too), keyed by the exact
+# ``device_kind`` string JAX reports. A TPU kind that is not here is an
+# error, never a default: add it with its source.
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "TPU v4": DevicePeaks(275e12, 1200e9, _CLOUD_TPU_DOCS + " (TPU v4)"),
+    "TPU v5 lite": DevicePeaks(197e12, 819e9, _CLOUD_TPU_DOCS + " (TPU v5e)"),
+    "TPU v5p": DevicePeaks(459e12, 2765e9, _CLOUD_TPU_DOCS + " (TPU v5p)"),
+    "TPU v6 lite": DevicePeaks(918e12, 1640e9, _CLOUD_TPU_DOCS + " (TPU v6e)"),
 }
 
 
-def peak_flops_per_device(device: Optional[jax.Device] = None) -> float:
-    d = device if device is not None else jax.devices()[0]
-    kind = getattr(d, "device_kind", "cpu")
-    for key, val in PEAK_FLOPS_BF16.items():
-        if key.lower() in kind.lower():
-            return val
-    return PEAK_FLOPS_BF16.get(kind, 1e12)
+def device_peaks(device: jax.Device) -> Optional[DevicePeaks]:
+    """Peaks of ``device``; None on a platform with no published peak (CPU
+    runs report utilization as "not measured", never against a nominal
+    number). An unknown TPU kind raises."""
+    if device.platform != "tpu":
+        return None
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peaks for device_kind={device.device_kind!r}; add it to "
+            f"orion_tpu.metrics.DEVICE_PEAKS with its source (known: "
+            f"{sorted(DEVICE_PEAKS)})"
+        ) from None
 
 
 @dataclass
@@ -54,7 +69,8 @@ class StepMetrics:
     tokens_per_sec: float = 0.0
     tokens_per_sec_per_device: float = 0.0
     model_flops: float = 0.0
-    mfu: float = 0.0
+    # None = not measured: the device has no published peak (a CPU run).
+    mfu: Optional[float] = None
     extras: dict[str, float] = field(default_factory=dict)
 
     def to_dict(self) -> dict[str, Any]:
@@ -477,13 +493,19 @@ class MetricsLogger:
         self,
         flops_per_token: float,
         num_devices: int,
+        device: jax.Device,
         peak_flops: Optional[float] = None,
         jsonl_path: Optional[str] = None,
         log_interval: int = 10,
     ):
+        """``device`` is one of the mesh's devices (they are homogeneous):
+        its kind picks the MFU peak unless ``peak_flops`` overrides it."""
         self.flops_per_token = flops_per_token
         self.num_devices = max(num_devices, 1)
-        self.peak_flops = peak_flops if peak_flops else peak_flops_per_device()
+        if not peak_flops:
+            peaks = device_peaks(device)
+            peak_flops = peaks.bf16_flops if peaks is not None else None
+        self.peak_flops = peak_flops
         self.jsonl_path = jsonl_path
         self.log_interval = max(log_interval, 1)
         self.history: list[StepMetrics] = []
@@ -504,7 +526,10 @@ class MetricsLogger:
         tps = tokens / step_time_s if step_time_s > 0 else 0.0
         model_flops = self.flops_per_token * tokens
         achieved = model_flops / step_time_s if step_time_s > 0 else 0.0
-        mfu = achieved / (self.num_devices * self.peak_flops)
+        mfu = (
+            achieved / (self.num_devices * self.peak_flops)
+            if self.peak_flops else None
+        )
         m = StepMetrics(
             step=step,
             loss=float(loss),
@@ -528,7 +553,10 @@ class MetricsLogger:
                 f"gnorm {m.grad_norm:7.3f}  lr {m.learning_rate:.2e}  "
                 f"{m.step_time_s * 1e3:7.1f} ms/step  "
                 f"{m.tokens_per_sec_per_device:9.0f} tok/s/dev  "
-                f"MFU {m.mfu * 100:5.2f}%"
+                + (
+                    f"MFU {m.mfu * 100:5.2f}%" if m.mfu is not None
+                    else "MFU not measured"
+                )
             )
             if "eval_loss" in extras:
                 line += f"  eval {extras['eval_loss']:8.4f}"
@@ -539,6 +567,36 @@ class MetricsLogger:
         if self._jsonl_file is not None:
             self._jsonl_file.close()
             self._jsonl_file = None
+
+
+class CompileCounter:
+    """Counts the XLA programs this process builds, from JAX's own
+    monitoring events (one ``backend_compile_duration`` per new program,
+    persistent-cache hits included — a hit is still a program the steady
+    state should not be asking for). The trainer stamps the per-step
+    delta into its metrics rows so "zero compiles after step 1" is a fact
+    a reader of the JSONL can check. ``close()`` unregisters."""
+
+    _EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.count = 0
+        self.seconds = 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, event: str, duration: float, **_: Any) -> None:
+        if event == self._EVENT:
+            self.count += 1
+            self.seconds += duration
+
+    def take(self) -> tuple[int, float]:
+        """(programs, seconds) since the last take; resets both."""
+        out = (self.count, self.seconds)
+        self.count, self.seconds = 0, 0.0
+        return out
+
+    def close(self) -> None:
+        jax.monitoring.unregister_event_duration_listener(self._on_event)
 
 
 class Stopwatch:

@@ -38,11 +38,6 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5: explicit mesh axis types (Manual detection under pp)
-    from jax.sharding import AxisType
-except ImportError:  # older jax: no Manual-mesh context to detect
-    AxisType = None
-
 from orion_tpu.config import ModelConfig
 
 
@@ -297,17 +292,14 @@ def moe_mlp_sorted_a2a(
     if ep == 1:
         return moe_mlp_sorted(x, params, cfg)
     # Inside the pipeline's shard_map (manual over pp) a nested shard_map
-    # must bind the CONTEXT abstract mesh — pp is already marked Manual
-    # there, and re-binding the concrete (all-Auto) mesh is rejected. The
-    # ep/tp/sp/batch axes this dispatch goes manual over are still Auto in
-    # that context, so sorted_a2a composes with pp (r4 restriction lifted,
-    # round 5); per-microbatch token slices only shrink C_loc, the same
-    # per-slice drop semantics as any batch sharding.
-    ctx = getattr(jax.sharding, "get_abstract_mesh", lambda: None)()
-    if AxisType is not None and ctx is not None and any(
-        t == AxisType.Manual for t in getattr(ctx, "axis_types", ())
-    ):
-        mesh = ctx
+    # must bind the CONTEXT abstract mesh (ops._dispatch.manual_context).
+    # The ep/tp/sp/batch axes this dispatch goes manual over are still Auto
+    # in that context, so sorted_a2a composes with pp; per-microbatch token
+    # slices only shrink C_loc, the same per-slice drop semantics as any
+    # batch sharding.
+    from orion_tpu.ops._dispatch import manual_context
+
+    mesh, _ = manual_context(mesh)
     E = cfg.n_experts
     if E % ep:
         raise ValueError(f"n_experts {E} not divisible by ep={ep}")

@@ -241,14 +241,20 @@ def _gate_act(cfg: ModelConfig):
     return functools.partial(jax.nn.gelu, approximate=True)
 
 
-def _norm(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
+def _norm(
+    x: jax.Array, p: Params, cfg: ModelConfig, mesh: Optional[Any] = None
+) -> jax.Array:
+    """``mesh``: the mesh the enclosing jit spans, so the Pallas norm
+    kernel runs per shard (ops.rmsnorm); the xla path ignores it."""
     scale = p["scale"]
     if cfg.norm_scale_plus_one:
         # Gemma-family RMSNorm parameterization: x_hat * (1 + w) (weights
         # initialized at zero); same kernels, shifted scale.
         scale = scale + 1.0
     if cfg.norm == "rmsnorm":
-        return ops.rmsnorm(x, scale, eps=cfg.norm_eps, impl=cfg.kernels)
+        return ops.rmsnorm(
+            x, scale, eps=cfg.norm_eps, impl=cfg.kernels, mesh=mesh
+        )
     return ops.layernorm(x, scale, p.get("bias"), eps=cfg.norm_eps)
 
 
@@ -267,9 +273,12 @@ def embed(
     return x
 
 
-def unembed(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+def unembed(
+    params: Params, x: jax.Array, cfg: ModelConfig,
+    mesh: Optional[Any] = None,
+) -> jax.Array:
     """Final norm + LM head -> float32 logits; shared like ``embed``."""
-    x = _norm(x, params["final_norm"], cfg)
+    x = _norm(x, params["final_norm"], cfg, mesh)
     if cfg.tie_embeddings:
         logits = jnp.einsum(
             "bsd,vd->bsv", x, params["embed"]["tokens"].astype(x.dtype)
@@ -284,12 +293,14 @@ def unembed(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 def qkv_proj(
-    x: jax.Array, p: Params, cfg: ModelConfig, positions: jax.Array
+    x: jax.Array, p: Params, cfg: ModelConfig, positions: jax.Array,
+    mesh: Optional[Any] = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """QKV projection + RoPE. x: [B, S, D] -> q [B,S,N,H], k/v [B,S,K,H].
 
     Shared between the training forward and the inference cache runner
     (orion_tpu.infer.runner), which attends against different KV sources.
+    ``mesh`` as in ``_norm`` (the Pallas RoPE kernel runs per shard).
     """
     B, S, _ = x.shape
     N, K, H = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
@@ -307,8 +318,10 @@ def qkv_proj(
     v = v.reshape(B, S, K, H)
 
     if cfg.pos_embedding == "rope":
-        q = ops.apply_rope(q, positions, theta=cfg.rope_theta, impl=cfg.kernels)
-        k = ops.apply_rope(k, positions, theta=cfg.rope_theta, impl=cfg.kernels)
+        rope = functools.partial(
+            ops.apply_rope, theta=cfg.rope_theta, impl=cfg.kernels, mesh=mesh
+        )
+        q, k = rope(q, positions), rope(k, positions)
     if cfg.query_scale is not None:
         # Net attention scale cfg.query_scale instead of head_dim**-0.5
         # (Gemma-2's query_pre_attn_scalar**-0.5): every attention kernel
@@ -353,7 +366,7 @@ def _attn_block(
 ) -> jax.Array:
     """``window`` is THIS layer's sliding window (already resolved through
     cfg.layer_window for interleaved local/global models)."""
-    q, k, v = qkv_proj(x, p, cfg, positions)
+    q, k, v = qkv_proj(x, p, cfg, positions, mesh)
 
     sp_active = (
         cfg.sequence_axis is not None
@@ -404,6 +417,7 @@ def _attn_block(
             block_q=cfg.attn_block_q,
             block_kv=cfg.attn_block_kv,
             impl=cfg.kernels,
+            mesh=mesh,
         )
     # remat="names" saves the kernel output: the single most expensive
     # per-layer tensor to rebuild (a full flash fwd pass) at [B,S,N,H]
@@ -448,18 +462,22 @@ def _block(
     per block without guessing from fused-op names).
     """
     with jax.named_scope("attention"):
-        xn = checkpoint_name(_norm(x, bp["attn_norm"], cfg), "attn_norm_out")
+        xn = checkpoint_name(
+            _norm(x, bp["attn_norm"], cfg, mesh), "attn_norm_out"
+        )
         a = _attn_block(xn, bp["attn"], cfg,
                         positions, segment_ids, mesh, window)
         if cfg.post_norms:
-            a = _norm(a, bp["post_attn_norm"], cfg)
+            a = _norm(a, bp["post_attn_norm"], cfg, mesh)
         x = x + a
     with jax.named_scope("mlp_moe"):
-        h = checkpoint_name(_norm(x, bp["mlp_norm"], cfg), "mlp_norm_out")
+        h = checkpoint_name(
+            _norm(x, bp["mlp_norm"], cfg, mesh), "mlp_norm_out"
+        )
         y, aux = mlp_or_moe(h, bp, cfg, mesh)
         y = checkpoint_name(y, "ffn_out")
         if cfg.post_norms:
-            y = _norm(y, bp["post_mlp_norm"], cfg)
+            y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
     return x + y, aux
 
 
@@ -482,7 +500,7 @@ def forward(
         mesh=mesh,
     )
     with jax.named_scope("unembed"):
-        logits = unembed(params, x, cfg)
+        logits = unembed(params, x, cfg, mesh)
     return logits, moe_aux
 
 
@@ -776,7 +794,7 @@ def loss_fn(
     def ce_chunk(carry, xs):
         xc, tc, mc = xs
         with jax.named_scope("unembed_chunk"):
-            logits = unembed(params, xc, cfg)  # [B, chunk, V] float32
+            logits = unembed(params, xc, cfg, mesh)  # [B, chunk, V] f32
         logz = jax.scipy.special.logsumexp(logits, axis=-1)
         tgt = _gather_target(logits, tc)
         nll_sum = ((logz - tgt) * mc).sum()

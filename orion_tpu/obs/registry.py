@@ -53,18 +53,13 @@ def _wall_now() -> float:
     return _T0_WALL + (time.monotonic() - _T0_MONO)
 
 
-def live_hbm_metrics(device: Optional[jax.Device] = None) -> dict[str, int]:
-    """Live device-memory gauges from the backend allocator, or {} when
-    the backend exposes none (CPU test runs). Keys follow the backend's
-    own naming (bytes_in_use / peak_bytes_in_use / bytes_limit)."""
-    d = device if device is not None else jax.devices()[0]
-    stats_fn = getattr(d, "memory_stats", None)
-    if not callable(stats_fn):
-        return {}
-    try:
-        stats = stats_fn()
-    except Exception:
-        return {}
+def live_hbm_metrics(device: jax.Device) -> dict[str, int]:
+    """Live device-memory gauges of ``device`` (one the caller's arrays
+    live on — the mesh's, not whatever ``jax.devices()[0]`` is) from the
+    backend allocator, or {} when the backend exposes none (CPU test
+    runs). Keys follow the backend's own naming (bytes_in_use /
+    peak_bytes_in_use / bytes_limit)."""
+    stats = device.memory_stats()
     if not stats:
         return {}
     out = {}

@@ -13,6 +13,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -153,15 +154,17 @@ def attention(
     are unchanged for callers honoring the pack_rows convention, and the
     xla path ignores it (no block structure to skip).
 
-    ``mesh`` (with a ``tp_axis`` of size > 1) runs the flash kernel under a
-    ``shard_map`` that splits the HEAD axes over tensor parallelism: a bare
-    ``pallas_call`` is opaque to XLA's SPMD partitioner, so jitting it over
-    tp-sharded q/k/v would otherwise gather full-size operands onto every
-    device (serving an 8B+ model sharded, SURVEY.md §4 stack B, needs the
-    kernel to stay sharded). The xla path ignores ``mesh`` — einsums
+    ``mesh`` (the mesh the enclosing jit spans) runs the flash kernel per
+    shard under a ``shard_map`` that splits the batch over dp/fsdp and the
+    HEAD axes over ``tp_axis``: a Mosaic kernel cannot be auto-partitioned
+    (ops/_dispatch.py), so on more than one device the wrapper is what
+    lets the program compile at all, and it keeps the kernel's operands
+    sharded instead of gathered. The xla path ignores ``mesh`` — einsums
     partition natively from the operands' shardings.
     """
-    from orion_tpu.ops._dispatch import resolve_impl
+    from orion_tpu.ops._dispatch import (
+        _BATCH_AXES, resolve_impl, shard_kernel, split_axes,
+    )
 
     use_pallas, interpret = resolve_impl(impl)
     if use_pallas:
@@ -183,58 +186,40 @@ def attention(
             seg_pad_zero=seg_pad_zero,
         )
         tp = mesh.shape.get(tp_axis, 1) if mesh is not None else 1
-        if tp > 1:
-            from jax.sharding import PartitionSpec as P
-
-            n_heads, n_kv = q.shape[2], k.shape[2]
-            if n_heads % tp or n_kv % tp:
-                raise ValueError(
-                    f"tp-sharded flash attention needs n_heads ({n_heads}) "
-                    f"and n_kv_heads ({n_kv}) divisible by {tp_axis}={tp}; "
-                    f"lower tp or use impl='xla'"
-                )
-            # Heads shard; batch/seq operands (segments, positions)
-            # replicate. Optional operands join the arg list only when
-            # present so the shard_map signature stays positional.
-            hspec = P(None, None, tp_axis, None)
-            sspec = P(None, None)  # segments are [B, S] (kernel contract)
-            opt = [
-                ("q_segment_ids", q_segment_ids, sspec),
-                ("kv_segment_ids", kv_segment_ids, sspec),
-                ("q_positions", q_positions,
-                 P(*([None] * (q_positions.ndim if q_positions is not None
-                               else 1)))),
-                ("kv_positions", kv_positions,
-                 P(*([None] * (kv_positions.ndim if kv_positions is not None
-                               else 1)))),
-            ]
-            names = [n for n, a, _ in opt if a is not None]
-            extras = [a for _, a, _ in opt if a is not None]
-            especs = [s for _, a, s in opt if a is not None]
-
-            def body(q_, k_, v_, *rest):
-                kw = dict(zip(names, rest))
-                return flash_attention(q_, k_, v_, **kernel_kw, **kw)
-
-            mapped = jax.shard_map(
-                body,
-                mesh=mesh,
-                in_specs=(hspec, hspec, hspec, *especs),
-                out_specs=hspec,
-                check_vma=False,
+        n_heads, n_kv = q.shape[2], k.shape[2]
+        if n_heads % tp or n_kv % tp:
+            raise ValueError(
+                f"tp-sharded flash attention needs n_heads ({n_heads}) "
+                f"and n_kv_heads ({n_kv}) divisible by {tp_axis}={tp}; "
+                f"lower tp or use impl='xla'"
             )
-            return mapped(q, k, v, *extras)
+        # Optional operands join the arg list only when present so the
+        # shard_map signature stays positional.
+        present = {
+            name: a for name, a in (
+                ("q_segment_ids", q_segment_ids),
+                ("kv_segment_ids", kv_segment_ids),
+                ("q_positions", q_positions),
+                ("kv_positions", kv_positions),
+            ) if a is not None
+        }
+        extras = list(present.values())
 
-        return flash_attention(
-            q,
-            k,
-            v,
-            q_segment_ids=q_segment_ids,
-            kv_segment_ids=kv_segment_ids,
-            q_positions=q_positions,
-            kv_positions=kv_positions,
-            **kernel_kw,
-        )
+        def specs(m, manual):
+            # Batch and heads split; sequence stays whole (sequence
+            # parallelism has its own shard_map, parallel/sequence.py).
+            b = split_axes(m, _BATCH_AXES, q.shape[0], manual)
+            hspec = P(b, None, split_axes(m, (tp_axis,), n_kv, manual), None)
+            # Segments are [B, S]; positions [B, S] or [S].
+            especs = [P(b, None) if a.ndim == 2 else P(None) for a in extras]
+            return (hspec, hspec, hspec, *especs), hspec
+
+        def body(q_, k_, v_, *rest):
+            return flash_attention(
+                q_, k_, v_, **kernel_kw, **dict(zip(present, rest))
+            )
+
+        return shard_kernel(body, mesh, specs)(q, k, v, *extras)
     return attention_xla(
         q,
         k,

@@ -11,6 +11,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 def _rmsnorm_xla(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -43,15 +44,35 @@ def rmsnorm(
     *,
     eps: float = 1e-5,
     impl: str = "xla",
+    mesh: Optional[jax.sharding.Mesh] = None,
 ) -> jax.Array:
-    """Root-mean-square normalization over the last axis."""
-    from orion_tpu.ops._dispatch import resolve_impl
+    """Root-mean-square normalization over the last axis.
+
+    ``mesh`` (the mesh the enclosing jit spans) runs the Pallas kernel per
+    shard — rows split over the batch (and, for [B, S, D], sequence) axes —
+    because a Mosaic kernel cannot be auto-partitioned; the xla path
+    ignores it."""
+    from orion_tpu.ops._dispatch import (
+        _BATCH_AXES, resolve_impl, shard_kernel, split_axes,
+    )
 
     use_pallas, interpret = resolve_impl(impl)
     if use_pallas:
         from orion_tpu.ops.pallas.norms import rmsnorm_pallas
 
-        return rmsnorm_pallas(x, scale, eps=eps, interpret=interpret)
+        def specs(m, manual):
+            lead = [split_axes(m, _BATCH_AXES, x.shape[0], manual)]
+            if x.ndim == 3:
+                lead.append(split_axes(m, ("sp",), x.shape[1], manual))
+            xs = P(*lead, *([None] * (x.ndim - len(lead))))
+            return (xs, P(None)), xs
+
+        return shard_kernel(
+            lambda x_, s_: rmsnorm_pallas(
+                x_, s_, eps=eps, interpret=interpret
+            ),
+            mesh, specs,
+        )(x, scale)
     return _rmsnorm_xla(x, scale, eps)
 
 

@@ -16,11 +16,31 @@ import jax
 
 NEG_INF = -1e30  # finite -inf stand-in: exp(NEG_INF - m) underflows to 0.
 
+# f32 bytes of one block of the row-wise kernels (RMSNorm, RoPE). Each keeps
+# a handful of f32 temporaries of the block live beside its double-buffered
+# bf16 in/out blocks, all inside Mosaic's 16 MiB scoped-VMEM limit: at 2 MiB
+# the whole grid step takes about 8 MiB whatever the width. The kernels size
+# their row blocks from this, because a FIXED row count scales with the
+# width — 256 rows were 2 MiB at llama-1b's widths and 4 MiB at Mistral-7B's,
+# which the v5e compiler refuses (16.11 and 18.75 MiB scoped).
+ROW_BLOCK_F32_BYTES = 2 * 2 ** 20
 
-def resolve_interpret(interpret) -> bool:
-    """None -> autodetect: compiled on TPU, interpreted elsewhere."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
+
+def resolve_interpret(interpret: bool) -> bool:
+    """The kernel's ``interpret`` flag, checked against the backend.
+
+    Compiled (``interpret=False``) means Mosaic-compiled for a TPU, always:
+    on any other backend this raises, naming the backend, so a run can
+    never pass with every kernel quietly interpreted. The interpreter is
+    reached only by asking for it (``kernels="pallas_interpret"``).
+    """
+    if not interpret and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"compiled Pallas kernels need a TPU, but the default JAX "
+            f"backend is {jax.default_backend()!r}; use "
+            f"kernels='pallas_interpret' (interpret=True) to run the "
+            f"kernels through the Pallas interpreter"
+        )
     return bool(interpret)
 
 

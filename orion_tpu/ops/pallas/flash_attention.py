@@ -521,7 +521,7 @@ def flash_attention_with_lse(
     q_offset: int = 0,
     block_q: Optional[int] = None,
     block_kv: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     q_positions: Optional[jax.Array] = None,
     kv_positions: Optional[jax.Array] = None,
     window: Optional[int] = None,
@@ -641,7 +641,7 @@ def flash_attention(
     q_offset: int = 0,
     block_q: Optional[int] = None,
     block_kv: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     q_positions: Optional[jax.Array] = None,
     kv_positions: Optional[jax.Array] = None,
     window: Optional[int] = None,

@@ -12,14 +12,18 @@ dx derivation for y = x * r * s with r = rsqrt(mean(x^2) + eps):
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from orion_tpu.ops.pallas.common import pad_axis, resolve_interpret, round_up
+from orion_tpu.ops.pallas.common import (
+    ROW_BLOCK_F32_BYTES,
+    pad_axis,
+    resolve_interpret,
+    round_up,
+)
 
 
 def _fwd_kernel(eps, x_ref, s_ref, o_ref):
@@ -43,7 +47,8 @@ def _dx_kernel(eps, x_ref, s_ref, g_ref, o_ref):
 
 def _rows_call(kernel, eps, block_rows, interpret, out_dtype, x2d, scale2d, *extra):
     R, D = x2d.shape
-    br = min(block_rows, round_up(R, 8))
+    fit = max(8, ROW_BLOCK_F32_BYTES // (4 * D) // 8 * 8)
+    br = min(block_rows, fit, round_up(R, 8))
     Rp = round_up(R, br)
     x2d = pad_axis(x2d, 0, Rp)
     extra = [pad_axis(e, 0, Rp) for e in extra]
@@ -91,7 +96,7 @@ def rmsnorm_pallas(
     *,
     eps: float = 1e-5,
     block_rows: int = 256,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """RMSNorm over the last axis; x [..., D], scale [D]."""
     D = x.shape[-1]

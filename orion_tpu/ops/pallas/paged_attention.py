@@ -332,7 +332,7 @@ def paged_attention(
     logit_softcap: Optional[float] = None,
     window: Optional[int] = None,           # sliding window: attend iff
     #                                         last_pos - kv_pos < window
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     k_scale: Optional[jax.Array] = None,    # [rows, K, SCALE_LANES] f32:
     v_scale: Optional[jax.Array] = None,    #   int8-pool per-token scales
     mesh: Optional[jax.sharding.Mesh] = None,

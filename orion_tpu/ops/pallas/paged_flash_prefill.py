@@ -426,7 +426,7 @@ def paged_flash_prefill(
     layer_base: Union[jax.Array, int] = 0,
     logit_softcap: Optional[float] = None,
     window: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     mesh: Optional[jax.sharding.Mesh] = None,

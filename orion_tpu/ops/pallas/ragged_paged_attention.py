@@ -333,7 +333,13 @@ def _kernel(
             bit = (
                 lax.shift_right_logical(word, jnp.clip(slot, 0, 31)) & 1
             ) == 1
-            mask = jnp.where(in_new, bit | (slot == qw), kv_pos < start)
+            # Boolean algebra, not a select between boolean vectors:
+            # Mosaic lowers an i1-valued select through i8 and refuses the
+            # truncation back ("Unsupported target bitwidth for
+            # truncation", libtpu 0.0.34).
+            mask = (in_new & (bit | (slot == qw))) | (
+                ~in_new & (kv_pos < start)
+            )
             if window is not None:
                 # Window distance among new slots is DEPTH distance
                 # (two siblings at one depth are window-equivalent even
@@ -341,10 +347,8 @@ def _kernel(
                 sdep = jnp.zeros_like(slot)
                 for w in range(W):
                     sdep = jnp.where(slot == w, dp_ref[b, w], sdep)
-                mask &= jnp.where(
-                    in_new,
-                    sdep >= qdep - window + 1,
-                    kv_pos >= start + qdep - window + 1,
+                mask &= (in_new & (sdep >= qdep - window + 1)) | (
+                    ~in_new & (kv_pos >= start + qdep - window + 1)
                 )
         z = jnp.where(mask, z, NEG_INF)
 
@@ -514,7 +518,7 @@ def ragged_paged_attention(
     logit_softcap: Optional[float] = None,
     window: Optional[int] = None,           # sliding window per query:
     #                                         attend iff q_pos - kv_pos < w
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     k_scale: Optional[jax.Array] = None,    # [rows, K, SCALE_LANES] f32:
     v_scale: Optional[jax.Array] = None,    #   int8-pool per-token scales
     tree_mask: Optional[jax.Array] = None,  # [B, W] i32 packed ancestor

@@ -11,13 +11,17 @@ with the angle sign flipped: dx = rope(g, -theta-angles).
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from orion_tpu.ops.pallas.common import pad_axis, resolve_interpret, round_up
+from orion_tpu.ops.pallas.common import (
+    ROW_BLOCK_F32_BYTES,
+    pad_axis,
+    resolve_interpret,
+    round_up,
+)
 
 
 def _rope_kernel(theta, flip, x_ref, pos_ref, o_ref):
@@ -45,7 +49,9 @@ def _rope_kernel(theta, flip, x_ref, pos_ref, o_ref):
 
 def _rope_call(theta, flip, block_seq, interpret, x, positions):
     B, S, N, H = x.shape
-    bs = min(block_seq, round_up(S, 8))
+    # Never below 128 rows: the (1, 1, bs) position block rides the lanes.
+    fit = max(128, ROW_BLOCK_F32_BYTES // (4 * N * H) // 128 * 128)
+    bs = min(block_seq, fit, round_up(S, 8))
     Sp = round_up(S, bs)
     xp = pad_axis(x, 1, Sp)
     pp = pad_axis(positions, 1, Sp)[:, None, :]  # (B, 1, Sp): TPU tiling
@@ -85,7 +91,7 @@ def rope_pallas(
     *,
     theta: float = 500_000.0,
     block_seq: int = 256,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Apply rotary embedding; x [B, S, N, H], positions [B, S] or [S]."""
     if positions.ndim == 1:

@@ -7,8 +7,11 @@ in float32; application casts back to the activation dtype.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 def rope_frequencies(
@@ -33,18 +36,39 @@ def apply_rope(
     *,
     theta: float = 500_000.0,
     impl: str = "xla",
+    mesh: Optional[jax.sharding.Mesh] = None,
 ) -> jax.Array:
     """Apply rotary embedding to q or k.
 
     x: [B, S, N, H]; positions: [B, S] (or [S], broadcast over batch).
+    ``mesh`` (the mesh the enclosing jit spans) runs the Pallas kernel per
+    shard — batch, sequence and heads split over their mesh axes — because
+    a Mosaic kernel cannot be auto-partitioned; the xla path ignores it.
     """
-    from orion_tpu.ops._dispatch import resolve_impl
+    from orion_tpu.ops._dispatch import (
+        _BATCH_AXES, resolve_impl, shard_kernel, split_axes,
+    )
 
     use_pallas, interpret = resolve_impl(impl)
     if use_pallas:
         from orion_tpu.ops.pallas.rope import rope_pallas
 
-        return rope_pallas(x, positions, theta=theta, interpret=interpret)
+        if positions.ndim == 1:
+            positions = jnp.broadcast_to(positions[None, :], x.shape[:2])
+
+        def specs(m, manual):
+            b = split_axes(m, _BATCH_AXES, x.shape[0], manual)
+            s = split_axes(m, ("sp",), x.shape[1], manual)
+            h = split_axes(m, ("tp",), x.shape[2], manual)
+            xs = P(b, s, h, None)
+            return (xs, P(b, s)), xs
+
+        return shard_kernel(
+            lambda x_, p_: rope_pallas(
+                x_, p_, theta=theta, interpret=interpret
+            ),
+            mesh, specs,
+        )(x, positions)
     return _rope_xla(x, positions, theta)
 
 

@@ -73,29 +73,10 @@ M=2/4/8 -> 2.75x/1.78x/1.33x (predicted 2.5/1.75/1.38 — the model
 tracks); pp=4 interleaved M=4,V=2 -> 1.12x, i.e. BETTER occupancy than
 GPipe at M=8 while using half the microbatches (2x the per-microbatch
 MXU shape) — exactly the regime the schedule exists for.
-
-Runtime compatibility: on the jax-0.4.x boxes where ``jax.shard_map`` is
-the adapter over ``jax.experimental.shard_map`` (orion_tpu.__init__), the
-SPMD partitioner cannot lower three things a partial-auto (manual over pp
-only) region wants to do: ``lax.axis_index`` (PartitionId HLO rejected),
-``lax.ppermute`` (manual-subgroup CollectivePermute check-fails), and the
-transposed while loop ``jax.grad`` makes of a scanned tick loop (the
-replicated output cotangent entering the loop check-fails the same way).
-Every schedule therefore routes through three seams that keep ONE code
-path semantically: the stage index arrives as a P(pp)-sharded iota input
-(``_stage_ids``) instead of axis_index; ring hops go through ``_make_hop``
-(ppermute on modern jax; a one-hot ``psum_scatter`` emulation with a
-custom-vjp reverse hop on compat runtimes, so jax never transposes the
-collective itself); and the differentiated schedules drive their ticks
-through ``_run_ticks`` (lax.scan on modern jax, python-unrolled on compat
-runtimes). The 1F1B schedule hand-writes its VJP, so its tick loops stay
-``lax.scan`` everywhere — only its replicated per-tick reads move out of
-the loop body (pre-gathered scan xs), which is the remaining compat rule.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable, Optional, Tuple
 
 import numpy as np
@@ -108,90 +89,21 @@ from jax.sharding import Mesh, PartitionSpec as P
 BlockFn = Callable[[jax.Array, Any], Tuple[jax.Array, jax.Array]]
 
 
-def _compat_runtime() -> bool:
-    """True on runtimes running the orion_tpu shard_map adapter (jax
-    0.4.x), whose SPMD partitioner needs the compat formulations above."""
-    return bool(getattr(jax.shard_map, "_orion_compat", False))
-
-
-def _stage_ids(pp: int) -> jax.Array:
-    """P(axis)-sharded iota input: each device's slice IS its stage index
-    (the axis_index replacement that lowers everywhere)."""
-    return jnp.arange(pp, dtype=jnp.int32)
-
-
-def _rs_hop(x, stage, npp: int, axis: str, reverse: bool, wrap: bool):
-    """One ring hop as a one-hot reduce-scatter: every device contributes
-    ``x`` at its destination's slot (zeros elsewhere) and psum_scatter
-    hands slot d to device d — unmatched receivers get zeros, exactly
-    ppermute's semantics. ~npp x the wire volume of a p2p permute, which
-    the fake-device mesh (and any compat box) doesn't care about."""
-    iota = jnp.arange(npp, dtype=jnp.int32).reshape((npp,) + (1,) * x.ndim)
-    dest = stage + (-1 if reverse else 1)
-    if wrap:
-        sel = iota == jnp.remainder(dest, npp)
-    else:
-        sel = (iota == dest) & (dest >= 0) & (dest < npp)
-    buf = jnp.where(sel, x[None], jnp.zeros_like(x)[None])
-    return lax.psum_scatter(
-        buf, axis, scatter_dimension=0, tiled=True
-    ).reshape(x.shape)
-
-
 def _make_hop(npp: int, axis: str, wrap: bool = False):
-    """``hop(x, stage, reverse=False)``: one ring hop along ``axis``.
+    """``hop(x, reverse=False)``: one ring hop along ``axis`` — a
+    ``lax.ppermute`` (whose transpose is the reverse permute). ``wrap``
+    closes the ring (the interleaved schedule's lap link)."""
+    if wrap:
+        fperm = [(i, (i + 1) % npp) for i in range(npp)]
+        rperm = [((i + 1) % npp, i) for i in range(npp)]
+    else:
+        fperm = [(i, i + 1) for i in range(npp - 1)]
+        rperm = [(i + 1, i) for i in range(npp - 1)]
 
-    Modern jax: ``lax.ppermute`` (whose transpose is the reverse permute,
-    natively). Compat runtimes: the ``_rs_hop`` emulation under a
-    custom-vjp whose backward is the reverse hop — the mathematically
-    exact transpose, expressed again as a psum_scatter so jax.grad of a
-    differentiated schedule never asks the old partitioner to transpose
-    a manual-subgroup collective."""
-    if not _compat_runtime():
-        if wrap:
-            fperm = [(i, (i + 1) % npp) for i in range(npp)]
-            rperm = [((i + 1) % npp, i) for i in range(npp)]
-        else:
-            fperm = [(i, i + 1) for i in range(npp - 1)]
-            rperm = [(i + 1, i) for i in range(npp - 1)]
-
-        def hop(x, stage, reverse: bool = False):
-            return lax.ppermute(x, axis, rperm if reverse else fperm)
-
-        return hop
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-    def send(x, stage, reverse):
-        return _rs_hop(x, stage, npp, axis, reverse, wrap)
-
-    def send_fwd(x, stage, reverse):
-        return send(x, stage, reverse), stage
-
-    def send_bwd(reverse, stage, g):
-        return (
-            _rs_hop(g, stage, npp, axis, not reverse, wrap),
-            np.zeros((), jax.dtypes.float0),
-        )
-
-    send.defvjp(send_fwd, send_bwd)
-
-    def hop(x, stage, reverse: bool = False):
-        return send(x, stage, reverse)
+    def hop(x, reverse: bool = False):
+        return lax.ppermute(x, axis, rperm if reverse else fperm)
 
     return hop
-
-
-def _run_ticks(tick, carry, xs, T: int):
-    """Drive a differentiated schedule's tick loop: ``lax.scan`` on modern
-    jax; python-unrolled on compat runtimes, where the transposed while
-    loop jax.grad would make of the scan breaks the old SPMD partitioner.
-    ``xs`` is a pytree of [T, ...] per-tick arrays."""
-    if not _compat_runtime():
-        carry, _ = lax.scan(tick, carry, xs)
-        return carry
-    for t in range(T):
-        carry, _ = tick(carry, jax.tree.map(lambda a: a[t], xs))
-    return carry
 
 
 def validate_row_state(row_state: Any, batch: int, num_microbatches: int):
@@ -289,9 +201,9 @@ def pipeline_forward(
     )
     x_mb = x.reshape(M, mb, S, D)
 
-    def local(stage_ids, x_mb, staged, rs_mb):
+    def local(x_mb, staged, rs_mb):
         stage_params = jax.tree.map(lambda a: a[0], staged)  # [L/pp, ...]
-        stage = stage_ids[0]
+        stage = lax.axis_index(axis)
         is_last = stage == pp - 1
         T = M + pp - 1
         hop = _make_hop(pp, axis)
@@ -321,7 +233,7 @@ def pipeline_forward(
             outputs = outputs.at[out_idx].set(
                 jnp.where(is_last & active, out, outputs[out_idx])
             )
-            state = hop(out, stage)
+            state = hop(out)
             return (state, outputs, aux_acc), None
 
         # The carries become device-varying over pp after the first tick, so
@@ -334,7 +246,7 @@ def pipeline_forward(
                 jnp.zeros((), jnp.float32),
             ),
         )
-        _, outputs, aux_acc = _run_ticks(tick, carry0, jnp.arange(T), T)
+        (_, outputs, aux_acc), _ = lax.scan(tick, carry0, jnp.arange(T))
         # Only the last stage holds real outputs; broadcast them (and the
         # per-stage aux partial sums) to every stage. Per-layer aux values
         # are batch means (e.g. the MoE balance loss), so average over the M
@@ -348,11 +260,11 @@ def pipeline_forward(
     outputs, aux = jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(P(axis), P(), P(axis), jax.tree.map(lambda _: P(), rs_mb)),
+        in_specs=(P(), P(axis), jax.tree.map(lambda _: P(), rs_mb)),
         out_specs=(P(), P()),
         axis_names={axis},
         check_vma=False,
-    )(_stage_ids(pp), x_mb, staged, rs_mb)
+    )(x_mb, staged, rs_mb)
     return outputs.reshape(B, S, D), aux
 
 
@@ -417,18 +329,17 @@ def _pipeline_1f1b(
         whether the stage-input stash is carried and returned (the VJP
         forward needs it; the no-grad primal skips its writes and
         footprint entirely — GPipe's forward cost exactly)."""
-        def fwd_local(stage_ids, x_mb, staged, rs_mb):
+        def fwd_local(x_mb, staged, rs_mb):
             stage_params = jax.tree.map(lambda a: a[0], staged)
-            stage = stage_ids[0]
+            stage = lax.axis_index(axis)
             is_last = stage == pp - 1
             T = M + pp - 1
             hop = _make_hop(pp, axis)
             ts = jnp.arange(T)
             # Per-tick reads of the replicated inputs happen HERE,
-            # outside the scan (compat rule, module docstring): the
-            # injected microbatch stream and this stage's row-state
-            # slices ride in as scan xs instead of being indexed inside
-            # the loop body.
+            # outside the scan: the injected microbatch stream and this
+            # stage's row-state slices ride in as scan xs instead of
+            # being indexed inside the loop body.
             injects = x_mb[jnp.clip(ts, 0, M - 1)]
             rs_seq = jax.tree.map(
                 lambda a: a[jnp.clip(ts - stage, 0, M - 1)], rs_mb
@@ -456,7 +367,7 @@ def _pipeline_1f1b(
                 outputs = outputs.at[out_idx].set(
                     jnp.where(is_last & active, out, outputs[out_idx])
                 )
-                state = hop(out, stage)
+                state = hop(out)
                 carry = (
                     (state, outputs, stash, aux_acc) if with_stash
                     else (state, outputs, aux_acc)
@@ -488,7 +399,7 @@ def _pipeline_1f1b(
     fwd_sm = jax.shard_map(
         make_fwd_local(True),
         mesh=mesh,
-        in_specs=(P(axis), P(), P(axis), rs_specs),
+        in_specs=(P(), P(axis), rs_specs),
         out_specs=(P(), P(), P(axis)),
         axis_names={axis},
         check_vma=False,
@@ -496,15 +407,15 @@ def _pipeline_1f1b(
     fwd_nostash_sm = jax.shard_map(
         make_fwd_local(False),
         mesh=mesh,
-        in_specs=(P(axis), P(), P(axis), rs_specs),
+        in_specs=(P(), P(axis), rs_specs),
         out_specs=(P(), P()),
         axis_names={axis},
         check_vma=False,
     )
 
-    def bwd_local(stage_ids, g_out, g_aux, stash, staged, rs_mb):
+    def bwd_local(g_out, g_aux, stash, staged, rs_mb):
         stage_params = jax.tree.map(lambda a: a[0], staged)
-        stage = stage_ids[0]
+        stage = lax.axis_index(axis)
         is_last = stage == pp - 1
         is_first = stage == 0
         T = M + pp - 1
@@ -548,7 +459,7 @@ def _pipeline_1f1b(
             dx = dx.at[midx].set(
                 jnp.where(is_first & active, da, dx[midx])
             )
-            gstate = hop(da, stage, reverse=True)
+            gstate = hop(da, reverse=True)
             return (gstate, dparams, dx), None
 
         zero_dp = jax.tree.map(jnp.zeros_like, stage_params)
@@ -570,28 +481,26 @@ def _pipeline_1f1b(
     bwd_sm = jax.shard_map(
         bwd_local,
         mesh=mesh,
-        in_specs=(P(axis), P(), P(), P(axis), P(axis), rs_specs),
+        in_specs=(P(), P(), P(axis), P(axis), rs_specs),
         out_specs=(P(), jax.tree.map(lambda _: P(axis), staged)),
         axis_names={axis},
         check_vma=False,
     )
 
-    sids = _stage_ids(pp)
-
     @jax.custom_vjp
     def run(x_mb, staged, rs_mb):
         # The no-grad primal (eval / forward-only callers): no stash
         # writes, no stash footprint — GPipe's forward, tick for tick.
-        return fwd_nostash_sm(sids, x_mb, staged, rs_mb)
+        return fwd_nostash_sm(x_mb, staged, rs_mb)
 
     def run_fwd(x_mb, staged, rs_mb):
-        outputs, aux, stash = fwd_sm(sids, x_mb, staged, rs_mb)
+        outputs, aux, stash = fwd_sm(x_mb, staged, rs_mb)
         return (outputs, aux), (stash, staged, rs_mb)
 
     def run_bwd(res, ct):
         stash, staged, rs_mb = res
         g_out, g_aux = ct
-        dx, dstaged = bwd_sm(sids, g_out, g_aux, stash, staged, rs_mb)
+        dx, dstaged = bwd_sm(g_out, g_aux, stash, staged, rs_mb)
         return dx, dstaged, jax.tree.map(_zero_cotangent, rs_mb)
 
     run.defvjp(run_fwd, run_bwd)
@@ -656,9 +565,9 @@ def _interleaved_pipeline(
     )
     x_mb = x.reshape(M, mb, S, D)
 
-    def local(stage_ids, x_mb, staged, rs_mb):
+    def local(x_mb, staged, rs_mb):
         chunks = jax.tree.map(lambda a: a[0], staged)   # [V, Lc, ...]
-        stage = stage_ids[0]
+        stage = lax.axis_index(axis)
         T = M + V * pp - 1
         hop = _make_hop(pp, axis, wrap=True)
         is_last = stage == pp - 1
@@ -699,7 +608,7 @@ def _interleaved_pipeline(
             outputs = outputs.at[out_idx].set(
                 jnp.where(emit, out, outputs[out_idx])
             )
-            state = hop(out, stage)
+            state = hop(out)
             return (state, outputs, aux_acc), None
 
         carry0 = jax.tree.map(
@@ -710,7 +619,7 @@ def _interleaved_pipeline(
                 jnp.zeros((), jnp.float32),
             ),
         )
-        _, outputs, aux_acc = _run_ticks(tick, carry0, jnp.arange(T), T)
+        (_, outputs, aux_acc), _ = lax.scan(tick, carry0, jnp.arange(T))
         outputs = lax.psum(
             jnp.where(is_last, outputs, jnp.zeros_like(outputs)), axis
         )
@@ -720,9 +629,9 @@ def _interleaved_pipeline(
     outputs, aux = jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(P(axis), P(), P(axis), jax.tree.map(lambda _: P(), rs_mb)),
+        in_specs=(P(), P(axis), jax.tree.map(lambda _: P(), rs_mb)),
         out_specs=(P(), P()),
         axis_names={axis},
         check_vma=False,
-    )(_stage_ids(pp), x_mb, staged, rs_mb)
+    )(x_mb, staged, rs_mb)
     return outputs.reshape(B, S, D), aux

@@ -13,13 +13,18 @@ from orion_tpu.runtime.mesh import (
     local_mesh,
     mesh_devices,
 )
-from orion_tpu.runtime.distributed import initialize, runtime_info
+from orion_tpu.runtime.distributed import (
+    enable_compile_cache,
+    initialize,
+    runtime_info,
+)
 
 __all__ = [
     "MESH_AXES",
     "build_mesh",
     "local_mesh",
     "mesh_devices",
+    "enable_compile_cache",
     "initialize",
     "runtime_info",
 ]

@@ -13,6 +13,7 @@ from __future__ import annotations
 import logging
 import os
 import time
+from functools import partial
 from typing import Any, Callable, Optional
 
 import jax
@@ -724,7 +725,7 @@ class Trainer:
                 f"{max(cfg.train.grad_accum, 1)}) must be divisible by "
                 f"dp*fsdp={dpf}"
             )
-        initialize(cfg.runtime)
+        self.runtime = initialize(cfg.runtime)
         self.mesh = build_mesh(cfg.parallel, platform=cfg.runtime.platform)
         # Plan first, shardings from it: both need the same abstract init
         # trace; building the plan once avoids paying it twice.
@@ -856,11 +857,13 @@ class Trainer:
         self.metrics = metrics_lib.MetricsLogger(
             flops_per_token=cfg.model.flops_per_token(cfg.data.seq_len),
             num_devices=self.mesh.size,
+            device=self.mesh.devices.flat[0],
             peak_flops=cfg.train.peak_flops_per_device,
             jsonl_path=cfg.train.metrics_jsonl,
             log_interval=cfg.train.log_interval,
         )
         self.tokens_per_step = tokens_per_step
+        self.device_memory: list[dict] = []   # filled at the end of fit()
         # -- Observability (orion_tpu/obs; README "Observability") ---------
         # Registry always exists (lazy provider reads — no hot-path cost);
         # tracer/flight only when train.trace / train.flight_dir ask, so
@@ -872,7 +875,9 @@ class Trainer:
             "robust", lambda: self.robustness.as_timing()
         )
         self.registry.register("train", self._last_step_metrics)
-        self.registry.register("hbm", live_hbm_metrics)
+        self.registry.register(
+            "hbm", partial(live_hbm_metrics, self.mesh.local_devices[0])
+        )
         self._tracer, self._flight = init_obs(
             trace=cfg.train.trace,
             trace_ring=cfg.train.trace_ring,
@@ -1237,6 +1242,7 @@ class Trainer:
         injector = self.fault_injector
         profile = cfg.train.profile_steps
         watch = metrics_lib.Stopwatch()
+        compiles = metrics_lib.CompileCounter()
         tracing = False
         # After an auto-rollback the replayed trajectory differs from the
         # one the existing checkpoints captured; overwrite them up to the
@@ -1294,9 +1300,14 @@ class Trainer:
                     m = jax.device_get(m)
                 dt = watch.lap(sync_on=m["loss"])
                 watchdog.heartbeat()
+                n_compiled, compile_s = compiles.take()
                 extras = {
                     "ce_loss": float(m["ce_loss"]),
                     "moe_aux": float(m["moe_aux"]),
+                    # XLA programs built since the previous row: the first
+                    # step's is the compile; a steady step's must be 0.
+                    "compiles": n_compiled,
+                    "compile_s": compile_s,
                 }
                 anomalous = bool(guard and m["anomaly"] > 0)
                 if guard:
@@ -1403,6 +1414,16 @@ class Trainer:
                     cfg.train.num_steps, state, force=True,
                     extra=self._ckpt_extra(),
                 )
+            # Allocator state of this process's mesh devices while the
+            # train state is still live (it is dropped when fit returns):
+            # on a multi-chip mesh each device should hold a comparable
+            # share.
+            from orion_tpu.obs import live_hbm_metrics
+
+            self.device_memory = [
+                {"id": d.id, **live_hbm_metrics(d)}
+                for d in self.mesh.local_devices
+            ]
             return self.metrics.history
         except (KeyboardInterrupt, FaultInjected, InjectedFault):
             # Preemption-safe path: persist the newest complete state, then
@@ -1430,6 +1451,7 @@ class Trainer:
                 self.ckpt.wait()
             raise
         finally:
+            compiles.close()
             if tracing:
                 jax.profiler.stop_trace()
             if self.ckpt is not None:

@@ -227,8 +227,8 @@ def test_dtype_whitelist_budget_fit():
 def test_contract_check_smoke():
     """tools/contract_check.py --smoke: every cpu-fast contract row holds
     on the real programs — typed JSON rows, verdict line, exit 0 (the
-    tier-1 CI hook; the full grid is the tunnel_window `contract_grid`
-    probe)."""
+    tier-1 CI hook; the full grid is `tools/contract_check.py` with no
+    flag)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run(
         [sys.executable, os.path.join(root, "tools", "contract_check.py"),

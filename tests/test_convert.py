@@ -23,10 +23,8 @@ from orion_tpu.models.convert import (
     from_hf_mixtral,
 )
 
-# Revived on jax-0.4.37 boxes by the round-6 compat shims (previously a
-# collection error), but too heavy for the tier-1 CPU budget — the serving
-# stack (test_infer / test_prefix_cache) owns that budget this round. Runs
-# in the full tier (no `-m "not slow"`).
+# Too heavy for the tier-1 CPU budget; runs in the full tier (no
+# `-m "not slow"`).
 pytestmark = pytest.mark.slow
 
 TOKENS = np.array([[5, 3, 9, 250, 17, 42, 7, 1]], np.int32)

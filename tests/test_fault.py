@@ -277,6 +277,12 @@ def tiny():
     return params, ref
 
 
+# The XLA-fallback rung is an opt-in (inference.dispatch_fallback defaults
+# off so a kernel that never compiled cannot pass as a slower, green run);
+# the tests that exercise the rung ask for it.
+FALLBACK = ["inference.dispatch_fallback=true"]
+
+
 def _engine(params, extra=(), inj=None):
     cfg = get_config("tiny-llama", INFER + list(extra))
     return InferenceEngine(cfg, params, fault_injector=inj)
@@ -322,7 +328,7 @@ def test_dispatch_fallback_xla_reference(tiny):
     retries once on the XLA reference path — same step, no failed step,
     byte-identical output."""
     params, _ = tiny
-    pall = ["model.kernels=pallas_interpret"]
+    pall = ["model.kernels=pallas_interpret"] + FALLBACK
     ref = _engine(params, pall).generate(MIX, 8)
     inj = FaultInjector([FaultSpec("dispatch", step=2, path="decode")])
     eng = _engine(params, pall, inj=inj)
@@ -632,7 +638,7 @@ def test_spec_fault_disable_counts_primary_faults_under_fallback(tiny):
     otherwise a persistently broken verify kernel pays a doomed primary
     attempt + fallback forever and spec_fault_limit is a dead knob."""
     params, ref = tiny
-    pall = SPEC + [
+    pall = SPEC + FALLBACK + [
         "model.kernels=pallas_interpret", "inference.spec_fault_limit=1",
     ]
     inj = FaultInjector(
@@ -743,7 +749,9 @@ def test_dispatch_retry_loop_absorbs_flaky_fallback(tiny):
     first two fallback attempts raise — with the attempts counted in
     RobustnessStats and the output byte-identical."""
     params, _ = tiny
-    pall = ["model.kernels=pallas_interpret", "inference.dispatch_retries=3"]
+    pall = FALLBACK + [
+        "model.kernels=pallas_interpret", "inference.dispatch_retries=3",
+    ]
     ref = _engine(params, ["model.kernels=pallas_interpret"]).generate(MIX, 8)
     inj = FaultInjector([FaultSpec("dispatch", step=2, path="decode")])
     eng = _engine(params, pall, inj=inj)
@@ -777,7 +785,7 @@ def test_dispatch_retries_zero_disables_fallback(tiny):
     even with dispatch_fallback=true — the 0-attempt loop is the
     fallback-off path."""
     params, _ = tiny
-    pall = ["model.kernels=pallas_interpret"]
+    pall = ["model.kernels=pallas_interpret"] + FALLBACK
     ref = _engine(params, pall).generate(MIX, 8)
     inj = FaultInjector([FaultSpec("dispatch", step=2, path="decode")])
     eng = _engine(
@@ -897,7 +905,9 @@ def test_fault_composition_int8_pallas_fallback(tiny):
     pool writes are bitwise the kernel's (the round-5 scale fix), so a
     mid-stream fallback step changes NOTHING downstream."""
     params, _ = tiny
-    extra = ["model.kernels=pallas_interpret", "inference.kv_quant=int8"]
+    extra = FALLBACK + [
+        "model.kernels=pallas_interpret", "inference.kv_quant=int8",
+    ]
     ref = _engine(params, extra).generate(MIX, 8)
     inj = FaultInjector([
         FaultSpec("dispatch", step=2, path="decode"),
@@ -929,7 +939,7 @@ def test_fault_composition_swa_expiry_and_fallback(tiny):
     assert done[r_live.rid].generated == ref[1]
     eng.assert_page_accounting()
     # fallback under SWA + pallas
-    pall = swa + ["model.kernels=pallas_interpret"]
+    pall = swa + FALLBACK + ["model.kernels=pallas_interpret"]
     pref = _engine(params, pall).generate(MIX, 8)
     assert pref == ref
     inj = FaultInjector([FaultSpec("dispatch", step=3, path="decode")])
@@ -944,7 +954,7 @@ def test_fault_composition_spec_verify_fallback(tiny):
     an injected fault — acceptance decisions, rollback footprint and
     greedy output all unchanged."""
     params, ref = tiny
-    pall = SPEC + ["model.kernels=pallas_interpret"]
+    pall = SPEC + FALLBACK + ["model.kernels=pallas_interpret"]
     assert _engine(params, pall).generate(MIX, 8) == ref
     inj = FaultInjector([FaultSpec("dispatch", step=2, path="verify")])
     eng = _engine(params, pall, inj=inj)

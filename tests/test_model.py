@@ -9,10 +9,8 @@ from orion_tpu import ops
 from orion_tpu.config import get_config
 from orion_tpu.models import forward, init_params, loss_fn, param_logical_axes
 
-# Revived on jax-0.4.37 boxes by the round-6 compat shims (previously a
-# collection error), but too heavy for the tier-1 CPU budget — the serving
-# stack (test_infer / test_prefix_cache) owns that budget this round. Runs
-# in the full tier (no `-m "not slow"`).
+# Too heavy for the tier-1 CPU budget; runs in the full tier (no
+# `-m "not slow"`).
 pytestmark = pytest.mark.slow
 
 

@@ -1,6 +1,6 @@
 """Pipeline row-state validation (ADVICE r5) — fast, execution-free
-checks that stay in tier-1 while the pipeline-execution tests (slow tier
-on jax-0.4.37 boxes) carry the schedule equivalence."""
+checks that stay in tier-1 while the pipeline-execution tests (slow tier)
+carry the schedule equivalence."""
 
 import jax
 import jax.numpy as jnp

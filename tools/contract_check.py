@@ -16,9 +16,7 @@ row fails or errors.
 
 The full grid layers layout variants (grad_accum, scan_group x remat,
 kv_quant, sliding windows, guard compositions) on top of each contract's
-base overrides; multi-chip-only compositions ride the tunnel_window
-queue (``contract_grid``) — on this box the fake 8-device CPU mesh
-covers every dp/tp row.
+base overrides; the fake 8-device CPU mesh covers every dp/tp row.
 """
 from __future__ import annotations
 

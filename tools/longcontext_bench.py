@@ -114,8 +114,9 @@ def serve_main(smoke: bool) -> int:
     if smoke:
         jax.config.update("jax_platforms", "cpu")
     elif jax.default_backend() != "tpu":
-        print("SKIP: no TPU backend (use --serve --smoke for the CPU check)")
-        return 0
+        print(f"FAIL: no TPU backend (default backend is "
+              f"{jax.default_backend()!r}); use --serve --smoke for the CPU check")
+        return 1
     from orion_tpu.config import get_config
     from orion_tpu.models import init_params
 
@@ -220,8 +221,9 @@ def main() -> int:
     if cpu:
         jax.config.update("jax_platforms", "cpu")
     elif jax.default_backend() != "tpu":
-        print("SKIP: no TPU backend (use --cpu for the logic check)")
-        return 0
+        print(f"FAIL: no TPU backend (default backend is "
+              f"{jax.default_backend()!r}); use --cpu for the logic check")
+        return 1
 
     from orion_tpu.ops.attention import attention_xla
     from orion_tpu.ops.pallas.flash_attention import flash_attention

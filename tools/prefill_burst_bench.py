@@ -27,8 +27,9 @@ def main() -> int:
     if cpu:
         jax.config.update("jax_platforms", "cpu")
     elif jax.default_backend() != "tpu":
-        print("SKIP: no TPU backend (use --cpu for the logic check)")
-        return 0
+        print(f"FAIL: no TPU backend (default backend is "
+              f"{jax.default_backend()!r}); use --cpu for the logic check")
+        return 1
 
     from orion_tpu.config import get_config
     from orion_tpu.infer import InferenceEngine

@@ -564,8 +564,9 @@ def main() -> int:
     if smoke:
         jax.config.update("jax_platforms", "cpu")
     elif jax.default_backend() != "tpu":
-        print("SKIP: no TPU backend (use --smoke for the CPU logic check)")
-        return 0
+        print(f"FAIL: no TPU backend (default backend is "
+              f"{jax.default_backend()!r}); use --smoke for the CPU logic check")
+        return 1
     if "--overload" in sys.argv[1:]:
         return overload_main(smoke)
     if "--structured" in sys.argv[1:]:

@@ -125,8 +125,9 @@ def main() -> int:
     if smoke:
         jax.config.update("jax_platforms", "cpu")
     elif jax.default_backend() != "tpu":
-        print("SKIP: no TPU backend (use --smoke for the CPU logic check)")
-        return 0
+        print(f"FAIL: no TPU backend (default backend is "
+              f"{jax.default_backend()!r}); use --smoke for the CPU logic check")
+        return 1
 
     from orion_tpu.config import get_config
     from orion_tpu.infer import InferenceEngine
@@ -167,16 +168,16 @@ def main() -> int:
         f"inference.speculate_tokens={speculate}",
     ]
     tree_ov = chain_ov + [f"inference.spec_tree_width={tree_width}"]
-    # Both kernel settings: "pallas" resolves to the compiled Mosaic
-    # kernels on a TPU backend and the Pallas interpreter elsewhere, so
-    # the same mode grid serves --smoke and on-chip runs. Greedy streams
-    # are comparable only WITHIN a kernel path (the xla and pallas
-    # attention algorithms round differently), so each spec mode gets
-    # its own baseline. The nonloop workload reuses the SAME engines
-    # (same programs — only the requests change).
+    # Both kernel paths: the "pallas" rows run the compiled Mosaic kernels
+    # on the chip and, under --smoke, ask for the Pallas interpreter by
+    # name. Greedy streams are comparable only WITHIN a kernel path (the
+    # xla and pallas attention algorithms round differently), so each
+    # spec mode gets its own baseline. The nonloop workload reuses the
+    # SAME engines (same programs — only the requests change).
     modes = []
     for path in ("xla", "pallas"):
-        kern = [f"model.kernels={path}"]
+        impl = "pallas_interpret" if smoke and path == "pallas" else path
+        kern = [f"model.kernels={impl}"]
         modes.append((f"baseline_{path}", path,
                       get_config(preset, base + kern)))
         modes.append((f"speculative_{path}", path,

@@ -13,6 +13,8 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import json
 import sys
 
 
@@ -58,6 +60,22 @@ def main(argv: list[str] | None = None) -> int:
 
     from orion_tpu.train import Trainer
 
+    if overrides:
+        print("overrides: " + " ".join(overrides), flush=True)
+    trainers = []
+
+    def make_trainer():
+        t = Trainer(cfg)
+        if not trainers:
+            # What the run actually landed on, up front: the backend
+            # initialize() settled on and the mesh built over it.
+            print("runtime: " + json.dumps(dataclasses.asdict(t.runtime)))
+            print("mesh: " + json.dumps(
+                {a: n for a, n in t.mesh.shape.items() if n > 1} or {"dp": 1}
+            ), flush=True)
+        trainers.append(t)
+        return t
+
     max_restarts = (
         args.max_restarts if args.max_restarts is not None
         else cfg.train.max_restarts
@@ -80,20 +98,26 @@ def main(argv: list[str] | None = None) -> int:
             last_fault["reason"] = f"{type(exc).__name__}: {exc}"
 
         history = run_with_restarts(
-            lambda attempt: Trainer(cfg).fit(
+            lambda attempt: make_trainer().fit(
                 restart_info=(attempt, last_fault["reason"])
             ),
             max_restarts=max_restarts,
             on_retry=_on_retry,
         )
     else:
-        history = Trainer(cfg).fit()
+        history = make_trainer().fit()
     if history:
         last = history[-1]
+        mfus = [h.mfu for h in history if h.mfu is not None]
+        mfu = (
+            f"{sum(mfus) / len(mfus) * 100:.2f}%" if mfus
+            else "not measured"
+        )
         print(
             f"done: {last.step} steps, final loss {last.loss:.4f}, "
-            f"mean MFU {sum(h.mfu for h in history) / len(history) * 100:.2f}%"
+            f"mean MFU {mfu}"
         )
+    print("memory: " + json.dumps(trainers[-1].device_memory))
     return 0
 
 
