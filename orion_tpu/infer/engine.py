@@ -19,7 +19,6 @@ registry gauges, ``prefix_match_tokens``) and nothing deeper.
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import logging
 import time
@@ -56,6 +55,7 @@ from orion_tpu.metrics import (
 )
 from orion_tpu.obs import (
     MetricsRegistry,
+    PhaseClock,
     export_chrome_safe,
     init_obs,
     live_hbm_metrics,
@@ -338,13 +338,6 @@ class InferenceEngine:
         # O(context) no matter how pages move (the typed
         # "shed:context_too_long" outcome covers that case instead).
         self._lazy = self._long and self.page_window is not None
-        self._dev_span = 0.0
-        self._mixed_span = 0.0
-        self._prefill_span = 0.0
-        self._spill_span = 0.0
-        self._restore_span = 0.0
-        self._pagein_span = 0.0
-        self._migrate_span = 0.0
         self.timing = self._zero_timing()
         # Cross-replica migration staging (ISSUE 20): requests whose KV
         # pages are arriving from a prefill replica but have not claimed
@@ -411,6 +404,14 @@ class InferenceEngine:
             injector=self._injector,
         )
         self._register_trace_metrics()
+        # The step's phases (obs.PhaseClock): host time into the
+        # reset_timing buckets and a profiler annotation ALWAYS, the ring
+        # only with inference.trace. buckets is a lambda because
+        # reset_timing swaps self.timing.
+        self._phase = PhaseClock(
+            self._tracer, self._PHASE_KEYS,
+            buckets=lambda: self.timing, tags=self._phase_tags,
+        )
         self._ttft_seen: set[int] = set()   # rids with a first_token event
         self._closed = False
 
@@ -679,31 +680,55 @@ class InferenceEngine:
                 out["residency_occupancy"] = held / hp.capacity
         return out
 
-    @contextlib.contextmanager
-    def _device_span(self, path: str, bucket: str = "_dev_span"):
-        """The ONE dispatch-timing primitive every device call site shares
-        (previously four copy-pasted ``t_dev = time.perf_counter()``
-        blocks): wraps dispatch + token fetch, accumulating the elapsed
-        wall time into the step's device/prefill bucket and emitting a
-        tracer span over the same window. On an exception the bucket is
-        NOT credited (the pre-refactor behavior: a failed step's partial
-        span never lands in the timing split) but the tracer span still
-        records — a postmortem wants to see the dispatch that died."""
-        tags = {"step": self.step_no}
-        if self._tracer.enabled:
-            # Dispatch spans carry the trace ids of every live slot they
-            # computed for (ISSUE 14): a request's correlated track in
-            # the merged timeline includes the device work that advanced
-            # it, not just its lifecycle instants. Built only when the
-            # tracer is on — the untraced host path is unchanged.
-            tags["tids"] = [
+    # The fixed set of ``orion/<phase>`` spans and the reset_timing() keys
+    # each one's SELF time feeds (obs.PhaseClock). The first key is the
+    # phase's own leaf; the rest are the sums the router's ITL proxy, the
+    # window autotune and the benchmark read (host_s, prefill_s, device_s,
+    # decode_device_s), which are therefore sums of leaves by
+    # construction, never separate measurements. A phase that raises
+    # books nothing: a failed dispatch's time stays with its parent and
+    # ends in host_s. The fallback phases mark an XLA retry inside
+    # ``<path>/run`` and book nothing of their own.
+    _PHASE_KEYS = {
+        "step": ("step_self_s", "host_s"),
+        "reap": ("reap_s", "host_s"),
+        "admit": ("admit_s", "host_s"),
+        "prefill/build": ("prefill_build_s", "host_s"),
+        "prefill/run": ("prefill_run_s", "prefill_s"),
+        "prefill/sample": ("prefill_sample_s", "prefill_s"),
+        "decode/build": ("decode_build_s", "host_s"),
+        "decode/run": ("decode_run_s", "decode_device_s", "device_s"),
+        "decode/fetch": ("decode_fetch_s", "decode_device_s", "device_s"),
+        "decode/emit": ("emit_s", "host_s"),
+        "verify/run": ("verify_run_s", "decode_device_s", "device_s"),
+        "compact": ("compact_s", "decode_device_s", "device_s"),
+        "mixed/run": ("mixed_device_s", "device_s"),
+        "mixed_verify/run": ("mixed_device_s", "device_s"),
+        "spill": ("spill_s",),
+        "restore": ("restore_s",),
+        "page_in": ("page_in_s",),
+        "migrate_out": ("migrate_out_s",),
+        "migrate_in": ("migrate_in_s",),
+        "prefill/fallback": (),
+        "decode/fallback": (),
+        "verify/fallback": (),
+        "mixed/fallback": (),
+        "mixed_verify/fallback": (),
+    }
+
+    def _phase_tags(self) -> dict:
+        """Ring tags of a phase span (built only with the tracer on): the
+        step that caused it and the trace ids of every live slot (ISSUE
+        14), so a request's correlated track in the merged timeline
+        includes the work that advanced it, not just its lifecycle
+        instants."""
+        return {
+            "step": self.step_no,
+            "tids": [
                 r.trace_id if r.trace_id is not None else r.rid
                 for r in self.slots if r is not None and not r.done
-            ]
-        t0 = time.perf_counter()
-        with self._tracer.span("dispatch/" + path, **tags):
-            yield
-        setattr(self, bucket, getattr(self, bucket) + time.perf_counter() - t0)
+            ],
+        }
 
     def _flight_dump(self, reason: str, **context) -> None:
         """Write a flight-recorder postmortem (no-op without
@@ -1130,159 +1155,141 @@ class InferenceEngine:
         (``self.decode_window`` fused token steps, one host round-trip)
         for all active slots; returns the requests that finished.
 
-        Each step's wall time is split into ``timing`` (see reset_timing):
-        the decode device span (dispatch through the [W, B] token fetch),
-        the prefill span (admission-burst dispatch through the first-token
-        fetch — its own bucket, so host_share stays meaningful on churny
-        workloads), and the host remainder — the observability needed to
-        tune the decode window from data rather than assertion.
+        The step is one ``orion/step`` phase whose children (reap, admit,
+        prefill/*, decode/*, ...) book their host time into ``timing``
+        (see reset_timing and _PHASE_KEYS): the split between waiting on
+        the device, prefill, and each part of the host's own work — the
+        observability needed to tune the decode window, and the serve
+        path, from data rather than assertion.
         """
-        t0 = time.perf_counter()
-        m0 = time.monotonic() if self._tracer.enabled else 0.0
-        if self._watchdog is not None and self._watchdog.armed:
-            # Refresh at step START so idle gaps between caller-driven
-            # steps never read as stalls — only time INSIDE a step does.
-            # Arming stays with the step-END heartbeat (Watchdog's
-            # first-completed-step contract): the first step's unbounded
-            # jit compile must not trip a false stall.
-            self._watchdog.heartbeat()
-        self._dev_span = 0.0
-        self._mixed_span = 0.0
-        self._prefill_span = 0.0
-        self._spill_span = 0.0
-        self._restore_span = 0.0
-        self._pagein_span = 0.0
-        self._spec_step = False
-        self._reap_expired()
-        # Reap expired/cancelled slots BEFORE admission so their pages are
-        # already donated/free when this step's admission pass budgets.
-        self._reap()
-        mixed = False
-        try:
-            self._admit()
-            self._maybe_inject_nan()
-            mixed = self.chunked and any(
-                r is not None and r.prefill_pending and not r.done
-                for r in self.slots
-            )
-            decoded = self._mixed_decode() if mixed else self._decode_all()
-            self._consec_failed = 0
-        except (DispatchFault, MemoryError) as e:
-            # Every dispatch path failed (or the page allocator did, at
-            # grow time): the step is abandoned with engine state
-            # consistent — injected dispatch faults fire before the device
-            # call, prefill faults unwind their admissions, grow faults
-            # leave pages owned — so fail the step, not the process. A
-            # persistent fault is not transient: re-raise after
-            # max_step_faults consecutive losses.
-            if isinstance(e, MemoryError):
-                self.robust.pool_faults += 1
-            self.robust.failed_steps += 1
-            self._consec_failed += 1
-            log.error(
-                "engine step %d failed (%s); continuing (%d/%d consecutive)",
-                self.step_no, e, self._consec_failed,
-                self.icfg.max_step_faults,
-            )
-            self._flight_note(
-                "failed_step", consecutive=self._consec_failed,
-                error=f"{type(e).__name__}: {e}",
-            )
-            if self._consec_failed >= self.icfg.max_step_faults:
-                self._flight_dump(
-                    "max_step_faults",
-                    consecutive=self._consec_failed, error=str(e),
+        with self._phase("step") as span:
+            if self._watchdog is not None and self._watchdog.armed:
+                # Refresh at step START so idle gaps between caller-driven
+                # steps never read as stalls — only time INSIDE a step
+                # does. Arming stays with the step-END heartbeat
+                # (Watchdog's first-completed-step contract): the first
+                # step's unbounded jit compile must not trip a false stall.
+                self._watchdog.heartbeat()
+            tune = self.icfg.decode_window_autotune
+            waited0 = self._autotune_waited() if tune else 0.0
+            self._spec_step = False
+            with self._phase("reap"):
+                self._reap_expired()
+                # Reap expired/cancelled slots BEFORE admission so their
+                # pages are already donated/free when this step's
+                # admission pass budgets.
+                self._reap()
+            mixed = False
+            try:
+                if self.waiting:
+                    with self._phase("admit"):
+                        self._admit()
+                self._maybe_inject_nan()
+                mixed = self.chunked and any(
+                    r is not None and r.prefill_pending and not r.done
+                    for r in self.slots
                 )
-                raise
-            decoded = False
-        total = time.perf_counter() - t0
-        # device_s keeps its historical meaning (every decode-facing
-        # dispatch, mixed chunk+decode included); the per-phase split
-        # rides alongside so the router's ITL-proxy tiebreak can read
-        # PURE decode time — a replica grinding a long prompt through
-        # mixed steps no longer looks "slow to decode" (ISSUE 20
-        # load-gauge satellite).
-        self.timing["device_s"] += self._dev_span + self._mixed_span
-        self.timing["decode_device_s"] += self._dev_span
-        self.timing["mixed_device_s"] += self._mixed_span
-        self.timing["prefill_s"] += self._prefill_span
-        # Host-tier copy spans get their own buckets (the bench derives
-        # real d2h/h2d bandwidth from them); they are neither decode
-        # device time nor scheduler host time.
-        self.timing["spill_s"] += self._spill_span
-        self.timing["restore_s"] += self._restore_span
-        self.timing["page_in_s"] += self._pagein_span
-        self.timing["host_s"] += (
-            total - self._dev_span - self._mixed_span - self._prefill_span
-            - self._spill_span - self._restore_span - self._pagein_span
-        )
-        self.timing["steps"] += 1
-        if decoded:
-            self.timing["windows"] += 1
-            # While chunked prefill is in flight the decode window is
-            # clamped to 1 (the mixed step); autotune only reads clean
-            # decode-window timings, so mixed steps never resize it.
-            # Speculative verify steps are held out the same way: their
-            # dispatch is the static verify shape, not the [W, B] decode
-            # window, so their split says nothing about the window.
-            if (
-                self.icfg.decode_window_autotune
-                and not mixed and not self._spec_step
-            ):
-                if self._autotune_skip:
-                    # First decode-window step at a freshly-resized [W, B]
-                    # shape: its spans carry the retrace/recompile cost,
-                    # not steady-state timing — excluded from the tuner
-                    # (see _autotune_window).
-                    self._autotune_skip = False
-                else:
-                    self._autotune_window(total)
-        if self.mcfg.debug_asserts:
-            from orion_tpu.runtime.asserts import raise_if_failed
+                decoded = (
+                    self._mixed_decode() if mixed else self._decode_all()
+                )
+                self._consec_failed = 0
+            except (DispatchFault, MemoryError) as e:
+                # Every dispatch path failed (or the page allocator did,
+                # at grow time): the step is abandoned with engine state
+                # consistent — injected dispatch faults fire before the
+                # device call, prefill faults unwind their admissions,
+                # grow faults leave pages owned — so fail the step, not
+                # the process. A persistent fault is not transient:
+                # re-raise after max_step_faults consecutive losses.
+                if isinstance(e, MemoryError):
+                    self.robust.pool_faults += 1
+                self.robust.failed_steps += 1
+                self._consec_failed += 1
+                log.error(
+                    "engine step %d failed (%s); continuing (%d/%d "
+                    "consecutive)",
+                    self.step_no, e, self._consec_failed,
+                    self.icfg.max_step_faults,
+                )
+                self._flight_note(
+                    "failed_step", consecutive=self._consec_failed,
+                    error=f"{type(e).__name__}: {e}",
+                )
+                if self._consec_failed >= self.icfg.max_step_faults:
+                    self._flight_dump(
+                        "max_step_faults",
+                        consecutive=self._consec_failed, error=str(e),
+                    )
+                    raise
+                decoded = False
+            total = time.monotonic() - span.t0
+            self.timing["steps"] += 1
+            if decoded:
+                self.timing["windows"] += 1
+                # While chunked prefill is in flight the decode window is
+                # clamped to 1 (the mixed step); autotune only reads clean
+                # decode-window timings, so mixed steps never resize it.
+                # Speculative verify steps are held out the same way:
+                # their dispatch is the static verify shape, not the
+                # [W, B] decode window, so their split says nothing about
+                # the window.
+                if tune and not mixed and not self._spec_step:
+                    if self._autotune_skip:
+                        # First decode-window step at a freshly-resized
+                        # [W, B] shape: its spans carry the retrace/
+                        # recompile cost, not steady-state timing —
+                        # excluded from the tuner (see _autotune_window).
+                        self._autotune_skip = False
+                    else:
+                        self._autotune_window(
+                            total,
+                            total - (self._autotune_waited() - waited0),
+                        )
+            if self.mcfg.debug_asserts:
+                from orion_tpu.runtime.asserts import raise_if_failed
 
-            # The token fetch synced the device work, but not the async
-            # callback thread — the barrier orders it before the check.
-            jax.effects_barrier()
-            raise_if_failed()
-        if self._watchdog is not None:
-            if self._watchdog.stalled:
-                # The watchdog fired DURING this step (a wedged/slow
-                # dispatch): the step is marked stalled and counted; the
-                # process carries on, deadline expiry handles the SLO
-                # consequences at the next boundary.
-                self.robust.stalled_steps += 1
-                self._flight_dump("watchdog_stall", step_wall_s=total)
-            self._watchdog.heartbeat()
-        if self._tracer.enabled:
-            # Request-lifecycle instants, swept at the step boundary where
-            # every token-emitting path has already run: first_token fires
-            # once per request (TTFT), outcome exactly once at the end.
-            # The wait queue is in the sweep too: a request preempted in
-            # the very step that produced its first token sits there, and
-            # skipping it would stamp its TTFT steps late.
-            for r in itertools.chain(
-                self.slots, self.waiting, self._just_finished
-            ):
-                if (
-                    r is not None and r.generated
-                    and r.rid not in self._ttft_seen
+                # The token fetch synced the device work, but not the
+                # async callback thread — the barrier orders it before
+                # the check.
+                jax.effects_barrier()
+                raise_if_failed()
+            if self._watchdog is not None:
+                if self._watchdog.stalled:
+                    # The watchdog fired DURING this step (a wedged/slow
+                    # dispatch): the step is marked stalled and counted;
+                    # the process carries on, deadline expiry handles the
+                    # SLO consequences at the next boundary.
+                    self.robust.stalled_steps += 1
+                    self._flight_dump("watchdog_stall", step_wall_s=total)
+                self._watchdog.heartbeat()
+            if span.tags is not None:
+                # Request-lifecycle instants, swept at the step boundary
+                # where every token-emitting path has already run:
+                # first_token fires once per request (TTFT), outcome
+                # exactly once at the end. The wait queue is in the sweep
+                # too: a request preempted in the very step that produced
+                # its first token sits there, and skipping it would stamp
+                # its TTFT steps late.
+                for r in itertools.chain(
+                    self.slots, self.waiting, self._just_finished
                 ):
-                    self._ttft_seen.add(r.rid)
+                    if (
+                        r is not None and r.generated
+                        and r.rid not in self._ttft_seen
+                    ):
+                        self._ttft_seen.add(r.rid)
+                        self._tracer.instant(
+                            "first_token", rid=r.rid, step=self.step_no,
+                            **self._trace_ctx(r),
+                        )
+                for r in self._just_finished:
+                    self._ttft_seen.discard(r.rid)
                     self._tracer.instant(
-                        "first_token", rid=r.rid, step=self.step_no,
+                        "outcome", rid=r.rid, outcome=r.outcome,
+                        tokens=len(r.generated), step=self.step_no,
                         **self._trace_ctx(r),
                     )
-            for r in self._just_finished:
-                self._ttft_seen.discard(r.rid)
-                self._tracer.instant(
-                    "outcome", rid=r.rid, outcome=r.outcome,
-                    tokens=len(r.generated), step=self.step_no,
-                    **self._trace_ctx(r),
-                )
-            self._tracer.record_span(
-                "step", m0, time.monotonic(), step=self.step_no,
-                decoded=bool(decoded),
-            )
+                span.tags["decoded"] = bool(decoded)
         self.step_no += 1
         done, self._just_finished = self._just_finished, []
         return done
@@ -1291,6 +1298,30 @@ class InferenceEngine:
     def _zero_timing() -> dict:
         return {
             "device_s": 0.0, "host_s": 0.0, "prefill_s": 0.0,
+            # The leaves of a step (one per bucketed phase of
+            # _PHASE_KEYS; every other *_s key here is a sum of leaves):
+            #   host_s    == reap_s + admit_s + prefill_build_s
+            #                + decode_build_s + emit_s + step_self_s
+            #   prefill_s == prefill_run_s + prefill_sample_s
+            #   decode_device_s == decode_run_s + decode_fetch_s
+            #                      + verify_run_s + compact_s
+            # admit_s is _admit's SELF time (its prefill phases nest in
+            # it), step_self_s the part of a step no child covers.
+            "reap_s": 0.0, "admit_s": 0.0, "prefill_build_s": 0.0,
+            "prefill_run_s": 0.0, "prefill_sample_s": 0.0,
+            "decode_build_s": 0.0, "decode_run_s": 0.0,
+            "decode_fetch_s": 0.0, "emit_s": 0.0, "step_self_s": 0.0,
+            "verify_run_s": 0.0, "compact_s": 0.0,
+            # Prefill sizing: prefill_tokens counts the real prompt
+            # positions the prefill dispatches computed (prefix-cached
+            # positions excluded), prefill_pad_tokens the rest of each
+            # dispatched [rows -> power of two] x [largest bucket] block.
+            "prefill_dispatches": 0, "prefill_tokens": 0,
+            "prefill_pad_tokens": 0,
+            # What the paged decode kernel had to read: over every token
+            # step of every decode window, the live slots' context
+            # lengths (bounded by the sliding window where there is one).
+            "decode_kv_tokens": 0,
             # Per-phase device split (ISSUE 20 load-gauge satellite):
             # decode_device_s covers pure decode-phase dispatches
             # (decode windows, verify, draft compaction) and pairs with
@@ -1331,8 +1362,12 @@ class InferenceEngine:
     def reset_timing(self) -> dict:
         """Return and zero the accumulated step timing split: device_s
         (decode dispatch -> token fetch, including mixed chunk+decode
-        dispatches), prefill_s (admission bursts), host_s (scheduler
-        remainder), windows/steps counters, the slot_steps/wasted_steps
+        dispatches), prefill_s (admission bursts: uploads, dispatch,
+        first-token sample), host_s (scheduler remainder), the leaf
+        ``<phase>_s`` keys those three are sums of (_zero_timing), the
+        prefill_dispatches/prefill_tokens/prefill_pad_tokens and
+        decode_kv_tokens sizing counters, windows/steps counters, the
+        slot_steps/wasted_steps
         decode-waste tally, the mixed_steps/prefill_chunks/chunk_tokens/
         chunk_pad_tokens chunked-prefill tally, the CURRENT decode_window
         (after any autotune growth/shrink — a snapshot, not zeroed), with
@@ -1389,7 +1424,16 @@ class InferenceEngine:
                 log.error("metrics export failed: %s", e)
         return out
 
-    def _autotune_window(self, step_total: float) -> None:
+    def _autotune_waited(self) -> float:
+        """Running total of the time steps spent waiting on dispatches
+        and tier copies — what _autotune_window's host share excludes."""
+        t = self.timing
+        return (
+            t["decode_device_s"] + t["prefill_s"] + t["spill_s"]
+            + t["restore_s"] + t["page_in_s"]
+        )
+
+    def _autotune_window(self, step_total: float, host: float) -> None:
         """Resize the decode window from the step's measured device/host
         split (see InferenceConfig.decode_window_autotune): double while
         the per-step host share exceeds the target; halve when it falls
@@ -1408,10 +1452,6 @@ class InferenceEngine:
         (_autotune_skip) — the recompile cost is paid once per resize
         either way, but it can no longer cascade into a second, spurious
         resize."""
-        host = (
-            step_total - self._dev_span - self._prefill_span
-            - self._spill_span - self._restore_span - self._pagein_span
-        )
         denom = step_total if step_total > 0 else 1.0
         target = self.icfg.decode_host_share_target
         if (
@@ -1769,8 +1809,7 @@ class InferenceEngine:
         padded = np.zeros(npad, np.int32)
         padded[:n] = pages
         try:
-            with self._device_span("spill", "_spill_span"), \
-                    self._tracer.annotation("orion/spill"):
+            with self._phase("spill"):
                 blocks = self._gather_pages(self.cache, jnp.asarray(padded))
                 # orion: allow[host-sync] the ONE batched d2h per eviction sweep — the host copy IS the operation
                 blocks = jax.device_get(blocks)
@@ -1837,8 +1876,7 @@ class InferenceEngine:
                     )
                     for k, v in blocks.items()
                 }
-            with self._device_span("restore", "_restore_span"), \
-                    self._tracer.annotation("orion/restore"):
+            with self._phase("restore"):
                 self.cache = self._scatter_pages(
                     self.cache, jnp.asarray(padded),
                     {k: jnp.asarray(v) for k, v in blocks.items()},
@@ -1914,13 +1952,8 @@ class InferenceEngine:
         pages demoted; 0 with the tier (or the cache) off."""
         if self._pcache is None or self._host_pool is None:
             return 0
-        # Runs OUTSIDE step() (step's span flush won't see this), so the
-        # spill span flushes straight into the timing bucket here.
-        self._spill_span = 0.0
         n = self._pcache.demote(self._pcache.evictable_pages())
         self.prefix_stats.evicted_pages += n
-        self.timing["spill_s"] += self._spill_span
-        self._spill_span = 0.0
         return n
 
     def _match_prefix(self, context: list[int]):
@@ -2157,8 +2190,7 @@ class InferenceEngine:
                     )
                     for k, v in blocks.items()
                 }
-            with self._device_span("page_in", "_pagein_span"), \
-                    self._tracer.annotation("orion/page_in"):
+            with self._phase("page_in"):
                 self.cache = self._scatter_pages(
                     self.cache, jnp.asarray(padded),
                     {k: jnp.asarray(v) for k, v in blocks.items()},
@@ -2443,10 +2475,8 @@ class InferenceEngine:
         npad = 1 << (n - 1).bit_length()
         padded = np.zeros(npad, np.int32)
         padded[:n] = [req.pages[j] for j in live]
-        self._migrate_span = 0.0
         try:
-            with self._device_span("migrate_out", "_migrate_span"), \
-                    self._tracer.annotation("orion/migrate_out"):
+            with self._phase("migrate_out"):
                 blocks = self._gather_pages(self.cache, jnp.asarray(padded))
                 jax.block_until_ready(blocks)  # orion: allow[host-sync] a torn gather must surface HERE, not inside the destination scatter
         # orion: allow[fault-except] migrate-out envelope: pure read — nothing to unwind; typed DispatchFault, source request intact
@@ -2459,10 +2489,6 @@ class InferenceEngine:
             raise DispatchFault(
                 "migrate_out", f"{type(e).__name__}: {e}"
             ) from e
-        # Runs OUTSIDE step() (same contract as offload_prefix_cache):
-        # flush the copy span straight into the timing bucket.
-        self.timing["migrate_out_s"] += self._migrate_span
-        self._migrate_span = 0.0
         return live, blocks
 
     def finish_migration(self, rid: int) -> None:
@@ -2526,9 +2552,7 @@ class InferenceEngine:
             npad = 1 << (n - 1).bit_length()
             padded = np.zeros(npad, np.int32)
             padded[:n] = fresh
-            self._migrate_span = 0.0
-            with self._device_span("migrate_in", "_migrate_span"), \
-                    self._tracer.annotation("orion/migrate_in"):
+            with self._phase("migrate_in"):
                 self.cache = self._scatter_pages(
                     self.cache, jnp.asarray(padded),
                     {k: jnp.asarray(v) for k, v in blocks.items()},
@@ -2545,8 +2569,6 @@ class InferenceEngine:
             raise DispatchFault(
                 "migrate_in", f"{type(e).__name__}: {e}"
             ) from e
-        self.timing["migrate_in_s"] += self._migrate_span
-        self._migrate_span = 0.0
         if live and max(live) >= len(req.pages):
             req.pages.extend([None] * (max(live) + 1 - len(req.pages)))
         for j, p in zip(live, fresh):
@@ -2924,37 +2946,41 @@ class InferenceEngine:
         prefix page ids ride along for the mid-sequence attention gather
         (runner.prefill_step), padded to the burst's max match (power of
         two, so jit specializations stay bounded)."""
-        n_pages = s_pad // self.psz
-        nb = 1 << (len(reqs) - 1).bit_length()   # next power of two
-        tokens = np.zeros((nb, s_pad), np.int32)
-        lengths = np.ones(nb, np.int32)          # pad rows: length 1
-        pages = np.zeros((nb, n_pages), np.int32)  # pad rows: scratch page 0
-        max_pre = max(r.n_prefix for r in reqs)
-        p_pre = 1 << (max_pre - 1).bit_length() if max_pre > 0 else 0
-        pre_lens = np.zeros(nb, np.int32)
-        pre_pages = np.zeros((nb, p_pre), np.int32)
-        for i, req in enumerate(reqs):
-            npre = req.n_prefix
-            tail = req.context[npre * self.psz:]
-            tokens[i, : len(tail)] = tail
-            lengths[i] = len(tail)
-            pre_lens[i] = npre * self.psz
-            if npre:
-                # Dead (behind-window) matched pages point at scratch 0 —
-                # behind every tail query's window, never attended.
-                pre_pages[i, :npre] = [
-                    0 if p is None else p for p in req.pages[:npre]
+        with self._phase("prefill/build"):
+            n_pages = s_pad // self.psz
+            nb = 1 << (len(reqs) - 1).bit_length()   # next power of two
+            tokens = np.zeros((nb, s_pad), np.int32)
+            lengths = np.ones(nb, np.int32)          # pad rows: length 1
+            # pad rows: scratch page 0
+            pages = np.zeros((nb, n_pages), np.int32)
+            max_pre = max(r.n_prefix for r in reqs)
+            p_pre = 1 << (max_pre - 1).bit_length() if max_pre > 0 else 0
+            pre_lens = np.zeros(nb, np.int32)
+            pre_pages = np.zeros((nb, p_pre), np.int32)
+            for i, req in enumerate(reqs):
+                npre = req.n_prefix
+                tail = req.context[npre * self.psz:]
+                tokens[i, : len(tail)] = tail
+                lengths[i] = len(tail)
+                pre_lens[i] = npre * self.psz
+                if npre:
+                    # Dead (behind-window) matched pages point at scratch
+                    # 0 — behind every tail query's window, never
+                    # attended.
+                    pre_pages[i, :npre] = [
+                        0 if p is None else p for p in req.pages[:npre]
+                    ]
+                # Dead (behind-window) logical pages write to scratch
+                # page 0; those positions are never read back (sliding-
+                # window mask). Positions past this row's own bucket
+                # (shorter than the burst's) go to scratch too.
+                tail_pg = req.pages[npre:]
+                pages[i, : len(tail_pg)] = [
+                    0 if p is None else p for p in tail_pg
                 ]
-            # Dead (behind-window) logical pages write to scratch page 0;
-            # those positions are never read back (sliding-window mask).
-            # Positions past this row's own bucket (shorter than the
-            # burst's) go to scratch too.
-            tail_pg = req.pages[npre:]
-            pages[i, : len(tail_pg)] = [
-                0 if p is None else p for p in tail_pg
-            ]
-        with self._device_span("prefill", "_prefill_span"):
-            try:
+        try:
+            # The uploads are part of prefill_s, as they always were.
+            with self._phase("prefill/run"):
                 logits, self.cache = self._run_dispatch(
                     "prefill", "prefill",
                     self.params,
@@ -2965,17 +2991,22 @@ class InferenceEngine:
                     jnp.asarray(pre_lens),
                     jnp.asarray(pre_pages),
                 )
-            except DispatchFault:
-                # Unwind this burst's admissions: their slots are claimed
-                # but NO KV was written, so tear down with nothing donated
-                # (n_cached=0 — donating would insert garbage pages into
-                # the prefix cache) and re-queue at the head for the next
-                # step's re-prefill.
-                for r in reversed(reqs):
-                    self._teardown_slot(r, 0)
-                    r.freed_until = 0
-                    self.waiting.appendleft(r)
-                raise
+        except DispatchFault:
+            # Unwind this burst's admissions: their slots are claimed but
+            # NO KV was written, so tear down with nothing donated
+            # (n_cached=0 — donating would insert garbage pages into the
+            # prefix cache) and re-queue at the head for the next step's
+            # re-prefill.
+            for r in reversed(reqs):
+                self._teardown_slot(r, 0)
+                r.freed_until = 0
+                self.waiting.appendleft(r)
+            raise
+        real = int(lengths[: len(reqs)].sum())
+        self.timing["prefill_dispatches"] += 1
+        self.timing["prefill_tokens"] += real
+        self.timing["prefill_pad_tokens"] += nb * s_pad - real
+        with self._phase("prefill/sample"):
             firsts = self._sample(logits, reqs)  # blocks on the fetch
         for i, req in enumerate(reqs):
             if req.done:
@@ -3455,7 +3486,7 @@ class InferenceEngine:
             jnp.asarray(mask),
             sub,
         )
-        with self._device_span("verify"):
+        with self._phase("verify/run"):
             if all(
                 r.temperature is None and r.top_k is None and r.top_p is None
                 for r in active
@@ -3635,8 +3666,7 @@ class InferenceEngine:
                 moves += len(off)
         if moves:
             try:
-                with self._device_span("compact"), \
-                        self._tracer.annotation("orion/compact"):
+                with self._phase("compact"):
                     self.cache = self._compact(
                         self.cache,
                         jnp.asarray(self.page_table),
@@ -3699,40 +3729,49 @@ class InferenceEngine:
                 self._rollback_slot(r)
 
     def _decode_all(self) -> bool:
-        self._roll_window()
-        live = [r for r in self.slots if r is not None and not r.done]
-        if self._long:
-            # Host-resident residue on a decode slot (a page-in fault
-            # retrying, per the keep-host-refs envelope): restore before
-            # any dispatch reads the pages.
-            for r in live:
-                if r.host_pages:
-                    self._page_in_request(r)
-        if self.constrained and any(
-            r.constraint is not None for r in live
-        ):
-            # Constrained slots decode through the masked verify path
-            # unconditionally (the fused window cannot carry FSM masks);
-            # forced runs make the step multi-token whenever the grammar
-            # allows, and unconstrained co-tenants draft normally.
+        with self._phase("decode/build"):
+            self._roll_window()
+            live = [r for r in self.slots if r is not None and not r.done]
+            if self._long:
+                # Host-resident residue on a decode slot (a page-in fault
+                # retrying, per the keep-host-refs envelope): restore
+                # before any dispatch reads the pages.
+                for r in live:
+                    if r.host_pages:
+                        self._page_in_request(r)
+            drafts = None
+            if self.constrained and any(
+                r.constraint is not None for r in live
+            ):
+                # Constrained slots decode through the masked verify path
+                # unconditionally (the fused window cannot carry FSM
+                # masks); forced runs make the step multi-token whenever
+                # the grammar allows, and unconstrained co-tenants draft
+                # normally.
+                drafts = self._propose_constrained_drafts(live)
+            elif self._spec is not None and not self._spec_disabled:
+                drafts = self._propose_drafts(live)
+            window = self._decode_build_window() if drafts is None else None
+        if drafts is not None:
             self._spec_step = True
-            return self._verify_all(self._propose_constrained_drafts(live))
-        if self._spec is not None and not self._spec_disabled:
-            drafts = self._propose_drafts(live)
-            if drafts is not None:
-                self._spec_step = True
-                return self._verify_all(drafts)
-        return self._decode_window_all()
+            return self._verify_all(drafts)
+        return self._decode_run_window(window)
 
     def _decode_window_all(self) -> bool:
-        """The plain fused decode window over all live slots (the
-        non-speculative step body; also the verify path's fallback when
-        preemption strips every drafted slot)."""
+        """The plain fused decode window over all live slots, for the
+        verify path's fallback when preemption strips every drafted slot
+        (_decode_all builds the window inside its own build phase)."""
+        with self._phase("decode/build"):
+            window = self._decode_build_window()
+        return self._decode_run_window(window)
+
+    def _decode_build_window(self):
+        """Provision pages and upload the inputs of one fused decode
+        window; None when no slot is live. Runs inside ``decode/build``."""
         self._grow_pages()
         active = [r for r in self.slots if r is not None and not r.done]
         if not active:
-            self._reap()
-            return False
+            return None
         W = self.decode_window
         mask = np.array(
             [r is not None and not r.done for r in self.slots], bool
@@ -3747,7 +3786,27 @@ class InferenceEngine:
             jnp.asarray(mask),
             jax.random.split(sub, W),
         )
-        with self._device_span("decode"):
+        # Token step j of the window reads seq_len + j cached positions
+        # per live slot (the sliding window's last at most).
+        if self.mcfg.sliding_window is None:
+            kv = (W * sum(self.seq_lens[mask].tolist())
+                  + len(active) * (W * (W - 1) // 2))
+        else:
+            kv = int(np.minimum(
+                self.seq_lens[mask][:, None] + np.arange(W),
+                self.mcfg.sliding_window,
+            ).sum())
+        self.timing["decode_kv_tokens"] += kv
+        return active, W, common
+
+    def _decode_run_window(self, window) -> bool:
+        """Dispatch a built decode window, fetch its ``[W, B]`` tokens and
+        emit them (the non-speculative step body)."""
+        if window is None:
+            self._reap()
+            return False
+        active, W, common = window
+        with self._phase("decode/run"):
             if all(
                 r.temperature is None and r.top_k is None and r.top_p is None
                 for r in active
@@ -3760,6 +3819,7 @@ class InferenceEngine:
                     jnp.asarray(self.slot_top_k),
                     jnp.asarray(self.slot_top_p),
                 )
+        with self._phase("decode/fetch"):
             if self._guard:
                 toks, ok, self.cache = out
                 tokens, okh = jax.device_get((toks, ok))   # orion: allow[host-sync] the decode window's ONE documented fetch
@@ -3768,30 +3828,32 @@ class InferenceEngine:
                 toks, self.cache = out
                 tokens = np.asarray(jax.device_get(toks))  # orion: allow[host-sync] [W, B] — the decode window's ONE documented fetch
                 okh = None
-        self.timing["slot_steps"] += W * len(active)
-        self.timing["decode_slot_steps"] += W * len(active)
-        if okh is not None:
-            for req in active:
-                if not okh[req.slot]:
-                    # Non-finite logits in this slot's window: the whole
-                    # window's tokens for it are suspect — drop them all
-                    # and quarantine (neighbors' tokens are unaffected;
-                    # no slot ever reads another's pages).
-                    self._quarantine(req, "nan")
-            active = [r for r in active if r.slot is not None]
-        for j in range(W):
-            for req in active:
-                if req.done:
-                    # Finished mid-window: the device still decoded this
-                    # slot; the discarded overshoot is the tunable waste.
-                    self.timing["wasted_steps"] += 1
-                    continue
-                tok = int(tokens[j, req.slot])
-                self.seq_lens[req.slot] += 1
-                self.last_token[req.slot] = tok
-                req.generated.append(tok)
-                self._maybe_finish(req, tok)
-        self._reap()
+        with self._phase("decode/emit"):
+            self.timing["slot_steps"] += W * len(active)
+            self.timing["decode_slot_steps"] += W * len(active)
+            if okh is not None:
+                for req in active:
+                    if not okh[req.slot]:
+                        # Non-finite logits in this slot's window: the
+                        # whole window's tokens for it are suspect — drop
+                        # them all and quarantine (neighbors' tokens are
+                        # unaffected; no slot ever reads another's pages).
+                        self._quarantine(req, "nan")
+                active = [r for r in active if r.slot is not None]
+            for j in range(W):
+                for req in active:
+                    if req.done:
+                        # Finished mid-window: the device still decoded
+                        # this slot; the discarded overshoot is the
+                        # tunable waste.
+                        self.timing["wasted_steps"] += 1
+                        continue
+                    tok = int(tokens[j, req.slot])
+                    self.seq_lens[req.slot] += 1
+                    self.last_token[req.slot] = tok
+                    req.generated.append(tok)
+                    self._maybe_finish(req, tok)
+            self._reap()
         return True
 
     def _mixed_decode(self) -> bool:
@@ -3998,7 +4060,7 @@ class InferenceEngine:
                 jnp.asarray(mask),
                 sub,
             ) + chunk_args
-            with self._device_span("mixed_verify", "_mixed_span"):
+            with self._phase("mixed_verify/run"):
                 if defaults:
                     out = self._run_dispatch(
                         "mixed_verify", "mixed_verify_defaults", *common,
@@ -4026,7 +4088,7 @@ class InferenceEngine:
                 jnp.asarray(mask),
                 sub,
             ) + chunk_args
-            with self._device_span("mixed", "_mixed_span"):
+            with self._phase("mixed/run"):
                 if defaults:
                     out = self._run_dispatch(
                         "mixed", "mixed_defaults", *common

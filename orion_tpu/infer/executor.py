@@ -161,13 +161,11 @@ class DispatchExecutor:
                 raise InjectedFault(
                     f"injected {path} dispatch fault (step {eng.step_no})"
                 )
-            # TraceAnnotation (not a host-ring span — _device_span owns
-            # that window): names this dispatch in a concurrently-captured
-            # device profile so xprof rows align with the Chrome export.
-            with eng._tracer.annotation("orion/" + path):
-                out = getattr(eng, "_" + name)(*args, **kwargs)
-                # orion: allow[host-sync] THE envelope sync point: execute-time faults must surface here, not at the caller's fetch
-                jax.block_until_ready(out)
+            # The caller's ``orion/<path>/run`` phase (engine._phase) names
+            # this dispatch in the ring and in a device profile.
+            out = getattr(eng, "_" + name)(*args, **kwargs)
+            # orion: allow[host-sync] THE envelope sync point: execute-time faults must surface here, not at the caller's fetch
+            jax.block_until_ready(out)
             return out
         # orion: allow[fault-except] the fault envelope exists to contain ANY dispatch failure (DispatchFault re-raise below)
         except Exception as e:
@@ -199,9 +197,7 @@ class DispatchExecutor:
                     attempt + 1, eng.icfg.dispatch_retries,
                 )
                 try:
-                    with eng._tracer.annotation(
-                        "orion/" + path + "/fallback"
-                    ):
+                    with eng._phase(path + "/fallback"):
                         out = fb(*args, **kwargs)
                         # orion: allow[host-sync] fallback attempts must surface their own execute-time faults inside the retry loop
                         jax.block_until_ready(out)

@@ -3,8 +3,9 @@ burn-rate monitoring.
 
 The serving engine, the trainer, and the multi-replica router all thread
 through this package (ISSUEs 9 + 14): ``Tracer`` is the host-side
-span/event ring (Chrome trace-event export with overflow accounting,
-``jax.profiler`` annotation passthrough for device-profile alignment;
+span/event ring (Chrome trace-event export with overflow accounting;
+``PhaseClock`` is the one span primitive of the engine's step phases:
+host-time buckets, a ``jax.profiler`` annotation always, the ring when on;
 ``merge_chrome`` merges the router's ring plus N replica rings into one
 Perfetto timeline on a shared clock), ``FlightRecorder`` the bounded
 postmortem ring that auto-dumps on degradation triggers,
@@ -26,6 +27,7 @@ from orion_tpu.obs.slo import SLOMonitor, SLOObjective, build_objectives
 from orion_tpu.obs.trace import (
     NULL_TRACER,
     NullTracer,
+    PhaseClock,
     Tracer,
     export_chrome_safe,
     merge_chrome,
@@ -38,6 +40,7 @@ __all__ = [
     "MetricsRegistry",
     "NULL_TRACER",
     "NullTracer",
+    "PhaseClock",
     "SLOMonitor",
     "SLOObjective",
     "Tracer",

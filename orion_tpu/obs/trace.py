@@ -12,11 +12,11 @@ constraints, in order:
      engine that runs for a week holds the most recent ``capacity``
      events, which is exactly what the flight recorder wants to dump when
      something degrades.
-  3. **Profiler-aligned.** ``annotation()`` / ``step_annotation()`` wrap
-     ``jax.profiler.TraceAnnotation`` / ``StepTraceAnnotation`` so host
-     spans emitted around device dispatches land in the SAME xprof
-     timeline as the device trace captured by ``train.profile_steps`` —
-     line the Chrome export up with the device profile by name.
+  3. **Profiler-aligned.** ``PhaseClock`` (the engine's step phases)
+     always enters ``jax.profiler.TraceAnnotation("orion/<phase>")`` and
+     ``step_annotation()`` wraps ``StepTraceAnnotation``, so host spans
+     land in the SAME xprof timeline as a concurrently captured device
+     trace, under the name the ring and the docs use.
 
 Export is Chrome trace-event JSON (``export_chrome``), loadable in
 Perfetto / ``chrome://tracing``; timestamps are microseconds relative to
@@ -69,7 +69,7 @@ class NullTracer:
     dropped = 0
     capacity = 0
 
-    def span(self, name: str, annotate: bool = False, **tags) -> _NullCtx:
+    def span(self, name: str, **tags) -> _NullCtx:
         return _NULL_CTX
 
     def instant(self, name: str, **tags) -> None:
@@ -78,9 +78,6 @@ class NullTracer:
     def record_span(self, name: str, t_start: float, t_end: float,
                     **tags) -> None:
         return None
-
-    def annotation(self, name: str) -> _NullCtx:
-        return _NULL_CTX
 
     def step_annotation(self, name: str, step: int) -> _NullCtx:
         return _NULL_CTX
@@ -104,29 +101,21 @@ class _Span:
     a span interrupted by an exception is exactly the span a postmortem
     wants to see)."""
 
-    __slots__ = ("_tracer", "name", "tags", "t0", "t1", "_ann")
+    __slots__ = ("_tracer", "name", "tags", "t0", "t1")
 
-    def __init__(self, tracer: "Tracer", name: str, annotate: bool,
-                 tags: dict):
+    def __init__(self, tracer: "Tracer", name: str, tags: dict):
         self._tracer = tracer
         self.name = name
         self.tags = tags
         self.t0 = 0.0
         self.t1 = 0.0
-        self._ann = (
-            jax.profiler.TraceAnnotation(name) if annotate else None
-        )
 
     def __enter__(self) -> "_Span":
-        if self._ann is not None:
-            self._ann.__enter__()
         self.t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.t1 = time.monotonic()
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
         self._tracer._append(
             ("span", self.name, self.t0, self.t1, self.tags)
         )
@@ -171,12 +160,9 @@ class Tracer:
             self.dropped += 1
         self._ring.append(event)
 
-    def span(self, name: str, annotate: bool = False, **tags) -> _Span:
-        """Context manager recording a [enter, exit) span. With
-        ``annotate``, also enters a ``jax.profiler.TraceAnnotation`` of
-        the same name so the span shows up in a concurrently-captured
-        device profile (train.profile_steps window)."""
-        return _Span(self, name, annotate, tags)
+    def span(self, name: str, **tags) -> _Span:
+        """Context manager recording a [enter, exit) span."""
+        return _Span(self, name, tags)
 
     def instant(self, name: str, **tags) -> None:
         t = time.monotonic()
@@ -185,14 +171,9 @@ class Tracer:
     def record_span(self, name: str, t_start: float, t_end: float,
                     **tags) -> None:
         """Append an already-measured span (times on the time.monotonic
-        clock) — for call sites that cannot wrap their body in a ``with``
-        without restructuring (e.g. the engine's whole-step span)."""
+        clock) — for call sites that measured it themselves (PhaseClock,
+        the trainer's whole-step span)."""
         self._append(("span", name, t_start, t_end, tags))
-
-    def annotation(self, name: str):
-        """Bare ``jax.profiler.TraceAnnotation`` context (device-profile
-        alignment only; records nothing in the host ring)."""
-        return jax.profiler.TraceAnnotation(name)
 
     def step_annotation(self, name: str, step: int):
         """``jax.profiler.StepTraceAnnotation`` context: marks a train
@@ -242,6 +223,83 @@ class Tracer:
         }
         _write_chrome(path, evs, meta)
         return len(evs) - 1  # metadata event excluded
+
+
+class _Phase:
+    """One live phase of a ``PhaseClock`` (see there)."""
+
+    __slots__ = ("_clock", "name", "_keys", "_ann", "tags", "t0", "t1",
+                 "_children")
+
+    def __init__(self, clock: "PhaseClock", name: str):
+        self._clock = clock
+        self.name = clock.names[name]
+        self._keys = clock.keys[name]
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self.tags: Optional[dict] = None
+        self.t0 = self.t1 = self._children = 0.0
+
+    def __enter__(self) -> "_Phase":
+        clock = self._clock
+        if clock.tracer.enabled:
+            self.tags = clock.tags()
+        clock.stack.append(self)
+        self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = t1 = time.monotonic()
+        self._ann.__exit__(exc_type, exc, tb)
+        clock = self._clock
+        clock.stack.pop()
+        covered = self._children
+        if self._keys and exc_type is None:
+            covered = t1 - self.t0
+            own = covered - self._children
+            buckets = clock.buckets()
+            for key in self._keys:
+                buckets[key] += own
+        if clock.stack:
+            clock.stack[-1]._children += covered
+        if self.tags is not None:
+            clock.tracer.record_span(self.name, self.t0, t1, **self.tags)
+        return False
+
+
+class PhaseClock:
+    """The ONE span primitive for the phases of an owner's step (the
+    serving engine's ``orion/<phase>`` spans). ``clock(phase)`` is a
+    context manager that
+
+      - always adds the host time it covered (``time.monotonic``, the
+        ring's clock) to the phase's buckets: ``keys[phase]`` names the
+        entries of ``buckets()`` it feeds. A phase books its SELF time —
+        what no nested phase booked — so the buckets of one step
+        partition it exactly, however the phases nest. A phase that
+        raises books nothing and one with no keys never does (a retry
+        marker): their time stays with the enclosing phase;
+      - always enters ``jax.profiler.TraceAnnotation("orion/<phase>")``:
+        under a profiler session the span lands on the profiler's clock
+        beside the device planes, without one it costs well under a
+        microsecond;
+      - with the tracer enabled, appends the span to the ring with
+        ``tags()`` (built at entry, and only then).
+
+    The set of phases is the fixed ``keys`` table (an unknown phase is a
+    KeyError): nothing per token, per request or per slot opens a span.
+    """
+
+    def __init__(self, tracer, keys: dict, buckets, tags):
+        self.tracer = tracer
+        self.keys = keys
+        self.names = {phase: "orion/" + phase for phase in keys}
+        self.buckets = buckets
+        self.tags = tags
+        self.stack: list = []
+
+    def __call__(self, phase: str) -> _Phase:
+        return _Phase(self, phase)
 
 
 def _chrome_events(

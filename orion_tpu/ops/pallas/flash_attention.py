@@ -372,6 +372,7 @@ def _fwd_call(st: _Statics, q, k, v, qseg, kseg, qpos=None, kpos=None):
             pltpu.VMEM((st.block_q, H), jnp.float32),
         ],
         interpret=st.interpret,
+        name="flash_fwd",
     )(*args)
     return out[0], out[1]
 
@@ -417,6 +418,7 @@ def _bwd_call(st: _Statics, q, k, v, qseg, kseg, o, lse, do, g_lse=None,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((st.block_q, H), jnp.float32)],
         interpret=st.interpret,
+        name="flash_bwd_dq",
     )(*args)
 
     # grid = (batch, kv_head, kv_block, group, q_block): the dk/dv output
@@ -458,6 +460,7 @@ def _bwd_call(st: _Statics, q, k, v, qseg, kseg, o, lse, do, g_lse=None,
             pltpu.VMEM((st.block_kv, H), jnp.float32),
         ],
         interpret=st.interpret,
+        name="flash_bwd_dkv",
     )(*args5)
     return dq, dk, dv
 

@@ -61,6 +61,7 @@ def _rows_call(kernel, eps, block_rows, interpret, out_dtype, x2d, scale2d, *ext
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((Rp, D), out_dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2d, scale2d, *extra)
     return out[:R]
 

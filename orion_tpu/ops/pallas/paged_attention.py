@@ -310,6 +310,7 @@ def _call(q, k_pool, v_pool, page_table, last_pos, base, k_new, v_new,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=resolve_interpret(interpret),
+        name="paged_decode",
     )(page_table.astype(jnp.int32), base, last_pos.astype(jnp.int32), *args)
     attn = out[0].reshape(B, K, G8, H)[:, :, :G, :].reshape(B, N, H)
     if fused_write:

@@ -401,6 +401,7 @@ def _call(q, k_pool, v_pool, walk, start, lens, base, k_new, v_new,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=resolve_interpret(interpret),
+        name="paged_flash_prefill",
     )(
         walk.astype(jnp.int32), base, start.astype(jnp.int32),
         lens.astype(jnp.int32), *args,

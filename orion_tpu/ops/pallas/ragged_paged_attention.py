@@ -493,6 +493,7 @@ def _call(q, k_pool, v_pool, page_table, start, lens, base, k_new, v_new,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=resolve_interpret(interpret),
+        name="ragged_paged",
     )(*prefetch, *args)
     attn = out[0].reshape(B, K, WG8, H)[:, :, :WG, :]
     attn = attn.reshape(B, K, W, G, H).transpose(0, 2, 1, 3, 4)

@@ -65,6 +65,7 @@ def _rope_call(theta, flip, block_seq, interpret, x, positions):
         out_specs=pl.BlockSpec((1, bs, N, H), lambda b, i: (b, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
         interpret=interpret,
+        name="rope",
     )(xp, pp)
     return out[:, :S]
 

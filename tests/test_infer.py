@@ -397,21 +397,17 @@ def test_decode_window_autotune_shrinks_on_low_host_share():
     eng = InferenceEngine(acfg, params)
     eng.decode_window = 16
     # Host share 0.01 < target 0.25 / 4: halve.
-    eng._dev_span, eng._prefill_span = 0.99, 0.0
-    eng._autotune_window(1.0)
+    eng._autotune_window(1.0, 0.01)
     assert eng.decode_window == 8
     # In the hysteresis band [target/4, target]: hold.
-    eng._dev_span = 0.9
-    eng._autotune_window(1.0)
+    eng._autotune_window(1.0, 0.1)
     assert eng.decode_window == 8
     # Above target: grow (the original path, bounded by the max).
-    eng._dev_span = 0.5
-    eng._autotune_window(1.0)
+    eng._autotune_window(1.0, 0.5)
     assert eng.decode_window == 16
     # Shrink floors at the CONFIGURED window, never below.
     eng.decode_window = 2
-    eng._dev_span = 0.99
-    eng._autotune_window(1.0)
+    eng._autotune_window(1.0, 0.01)
     assert eng.decode_window == 2
     # The current window is surfaced with the timing drain.
     assert eng.reset_timing()["decode_window"] == 2
@@ -445,9 +441,8 @@ def test_autotune_excludes_first_post_resize_step():
     )
     # Unit check: the resize itself is what arms the exclusion.
     eng2 = InferenceEngine(acfg, params)
-    eng2._dev_span, eng2._prefill_span = 0.5, 0.0
     assert not eng2._autotune_skip
-    eng2._autotune_window(1.0)
+    eng2._autotune_window(1.0, 0.5)
     assert eng2.decode_window == 4
     assert eng2._autotune_skip
 

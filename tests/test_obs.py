@@ -237,12 +237,14 @@ def test_trace_off_identical_and_trace_covers_lifecycle(tmp_path):
     spans = [e for e in evs if e["ph"] == "X"]
     inst = [e for e in evs if e["ph"] == "i"]
     # Every dispatch has a span: the prefill burst + one decode span per
-    # decode step; every step has a "step" span.
-    dispatch = [e for e in spans if e["name"].startswith("dispatch/")]
-    assert sum(1 for e in dispatch if e["name"] == "dispatch/prefill") >= 1
-    n_decode = sum(1 for e in dispatch if e["name"] == "dispatch/decode")
+    # decode step; every step has an "orion/step" span.
+    dispatch = [e for e in spans if e["name"].endswith("/run")]
+    assert sum(
+        1 for e in dispatch if e["name"] == "orion/prefill/run"
+    ) == t["prefill_dispatches"] >= 1
+    n_decode = sum(1 for e in dispatch if e["name"] == "orion/decode/run")
     assert n_decode == t["windows"]
-    assert sum(1 for e in spans if e["name"] == "step") == t["steps"]
+    assert sum(1 for e in spans if e["name"] == "orion/step") == t["steps"]
     assert all(e["dur"] >= 0 for e in spans)
     # Full request lifecycle: submit -> admit -> first_token -> outcome,
     # once per request, tagged with rid and the typed outcome.
@@ -316,7 +318,9 @@ def test_flight_dump_on_injected_nan_fault(tmp_path):
     # Fault-adjacent span window: the dispatches leading up to the
     # quarantine are in the dump.
     span_names = {s["name"] for s in doc["spans"] if s["kind"] == "span"}
-    assert any(n.startswith("dispatch/") for n in span_names)
+    assert any(
+        n.startswith("orion/") and n.endswith("/run") for n in span_names
+    )
     # The injected fault itself was stamped into the event ring (the
     # FaultInjector on_fire observer).
     assert any(e["kind"] == "injected_fault" for e in doc["events"])
@@ -393,8 +397,9 @@ def test_obs_report_renders_trace_and_dump(tmp_path, capsys):
     eng.close()
     assert obs_report.main([str(path)]) == 0
     out = capsys.readouterr().out
-    assert "span groups by total time" in out
-    assert "dispatch/decode" in out
+    assert "span groups by self time" in out
+    assert "engine step split" in out and "orion/decode/emit" in out
+    assert "orion/decode/run" in out
     assert "per-request TTFT breakdown" in out
     assert "completed" in out
 
@@ -403,7 +408,7 @@ def test_obs_report_renders_trace_and_dump(tmp_path, capsys):
     fr = FlightRecorder(tr, str(tmp_path), snapshot=lambda: {
         "robust.failed_steps": 3, "engine.steps": 9,
     })
-    with tr.span("dispatch/decode", step=1):
+    with tr.span("orion/decode/run", step=1):
         pass
     fr.note("dispatch_fault", path="decode", step=1)
     p = fr.dump("watchdog_stall")
@@ -517,7 +522,7 @@ def test_obs_report_flags_truncation_and_fleet(tmp_path, capsys):
                worst_ms=410.0, target_ms=50.0, goal=0.9)
     rt.instant("outcome", rid=5, tid=5, outcome="completed", retried=1)
     rep = Tracer()
-    with rep.span("dispatch/decode", step=0):
+    with rep.span("orion/decode/run", step=0):
         pass
     rep.instant("admit", rid=0, tid=5, retried=1, slot=0)
     path = tmp_path / "merged.json"

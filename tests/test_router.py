@@ -474,7 +474,8 @@ def test_merged_trace_three_replicas_failover(tiny, tmp_path):
     # Dispatch spans carry the tids they computed for.
     dspans = [
         e for e in evs
-        if e["ph"] == "X" and e["name"].startswith("dispatch/")
+        if e["ph"] == "X" and e["name"].startswith("orion/")
+        and e["name"].endswith("/run")
     ]
     assert any(e["args"].get("tids") for e in dspans)
     # Namespaced per-replica traces: live replicas wrote theirs at

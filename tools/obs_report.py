@@ -12,8 +12,10 @@ Accepts any artifact the obs layer writes:
   - a flight-recorder dump (``inference.flight_dir`` /
     ``train.flight_dir`` auto-dumps on degradation triggers).
 
-Reports: span groups by total time (the slowest-spans table), the top
-individual spans, a per-request TTFT breakdown (submit -> admit queue
+Reports: span groups by total and SELF time (a span's time that no span
+nested in it covers, so the shares partition the timeline), the split of
+an engine step into its ``orion/<phase>`` spans, the top individual
+spans, a per-request TTFT breakdown (submit -> admit queue
 wait vs admit -> first-token compute, from the lifecycle instants), and —
 for flight dumps — the fault-adjacent event window that explains why the
 dump exists. Merged traces additionally get the FLEET view: per-replica
@@ -96,30 +98,62 @@ def print_truncation(meta, procs) -> None:
 
 
 def group_spans(spans):
-    """name -> dict(count, total_s, max_s)."""
+    """name -> dict(count, total_s, self_s, max_s). ``self_s`` is the time
+    of a group's spans that no span nested inside them (same process)
+    covers: the engine's phases nest (orion/step > orion/admit >
+    orion/prefill/run), so only self times add up to the timeline."""
     groups: dict = collections.defaultdict(
-        lambda: {"count": 0, "total_s": 0.0, "max_s": 0.0}
+        lambda: {"count": 0, "total_s": 0.0, "self_s": 0.0, "max_s": 0.0}
     )
-    for name, _t, dur, _tags, _pid in spans:
+    by_pid: dict = collections.defaultdict(list)
+    for name, t, dur, _tags, pid in spans:
         g = groups[name]
         g["count"] += 1
         g["total_s"] += dur
+        g["self_s"] += dur
         g["max_s"] = max(g["max_s"], dur)
+        by_pid[pid].append((t, -dur, name))
+    for rows in by_pid.values():
+        open_spans: list = []            # (end, name), innermost last
+        for t, neg, name in sorted(rows):
+            while open_spans and open_spans[-1][0] <= t:
+                open_spans.pop()
+            if open_spans and t - neg <= open_spans[-1][0] + 1e-9:
+                groups[open_spans[-1][1]]["self_s"] += neg
+            open_spans.append((t - neg, name))
     return dict(groups)
 
 
 def print_groups(groups, top: int) -> None:
-    total = sum(g["total_s"] for g in groups.values()) or 1e-12
+    total = sum(g["self_s"] for g in groups.values()) or 1e-12
     print(f"{'span group':<28s} {'count':>7s} {'total':>9s} {'mean':>9s} "
-          f"{'max':>9s} {'share':>7s}")
+          f"{'max':>9s} {'self':>9s} {'share':>7s}")
     ranked = sorted(
-        groups.items(), key=lambda kv: kv[1]["total_s"], reverse=True
+        groups.items(), key=lambda kv: kv[1]["self_s"], reverse=True
     )
     for name, g in ranked[:top]:
         mean = g["total_s"] / g["count"]
         print(f"{name:<28s} {g['count']:>7d} {g['total_s'] * 1e3:>8.1f}ms "
               f"{mean * 1e3:>8.2f}ms {g['max_s'] * 1e3:>8.2f}ms "
-              f"{g['total_s'] / total * 100:>6.1f}%")
+              f"{g['self_s'] * 1e3:>8.1f}ms "
+              f"{g['self_s'] / total * 100:>6.1f}%")
+
+
+def print_step_split(groups) -> None:
+    """The engine's step, phase by phase: each ``orion/<phase>``'s self
+    time per ``orion/step`` (README "Observability" has the list)."""
+    steps = groups.get("orion/step", {}).get("count", 0)
+    if not steps:
+        return
+    phases = {n: g for n, g in groups.items() if n.startswith("orion/")}
+    total = sum(g["self_s"] for g in phases.values()) or 1e-12
+    print(f"\nengine step split ({steps} steps; self time per step):")
+    for name, g in sorted(
+        phases.items(), key=lambda kv: kv[1]["self_s"], reverse=True
+    ):
+        what = "(uncovered)" if name == "orion/step" else ""
+        print(f"  {name:<26s} {g['self_s'] / steps * 1e3:>9.3f}ms "
+              f"{g['self_s'] / total * 100:>6.1f}%  {what}")
 
 
 def print_slowest(spans, top: int) -> None:
@@ -345,13 +379,13 @@ def print_fault_window(meta, tail: int = 12) -> None:
 def compare(path_a: str, path_b: str, top: int) -> int:
     ga = group_spans(load(path_a)[0])
     gb = group_spans(load(path_b)[0])
-    ta = sum(g["total_s"] for g in ga.values()) or 1e-12
-    tb = sum(g["total_s"] for g in gb.values()) or 1e-12
+    ta = sum(g["self_s"] for g in ga.values()) or 1e-12
+    tb = sum(g["self_s"] for g in gb.values()) or 1e-12
     names = set(ga) | set(gb)
     rows = []
     for n in names:
-        sa = ga.get(n, {"total_s": 0.0})["total_s"] / ta
-        sb = gb.get(n, {"total_s": 0.0})["total_s"] / tb
+        sa = ga.get(n, {"self_s": 0.0})["self_s"] / ta
+        sb = gb.get(n, {"self_s": 0.0})["self_s"] / tb
         rows.append((abs(sb - sa), n, sa, sb))
     print(f"span-share diff: A={path_a}  B={path_b}")
     print(f"{'span group':<28s} {'A share':>8s} {'B share':>8s} "
@@ -394,8 +428,10 @@ def main(argv=None) -> int:
     if meta.get("reason"):
         print_fault_window(meta)
     if spans:
-        print("\nspan groups by total time:")
-        print_groups(group_spans(spans), args.top)
+        print("\nspan groups by self time:")
+        groups = group_spans(spans)
+        print_groups(groups, args.top)
+        print_step_split(groups)
         print_slowest(spans, min(args.top, 10))
     if fleet:
         print_fleet_shares(spans, procs, args.top)
