@@ -53,6 +53,7 @@ from orion_tpu.metrics import (
     RobustnessStats,
     SpecDecodeStats,
 )
+from orion_tpu.models.moe import expert_rows
 from orion_tpu.obs import (
     MetricsRegistry,
     PhaseClock,
@@ -1318,6 +1319,11 @@ class InferenceEngine:
             # dispatched [rows -> power of two] x [largest bucket] block.
             "prefill_dispatches": 0, "prefill_tokens": 0,
             "prefill_pad_tokens": 0,
+            # Expert-matmul rows per MoE layer those dispatches computed
+            # (models/moe.expert_rows: k per routed position on the
+            # dropless grouped path, E per dispatched position on the
+            # capacity buckets); 0 for a dense model.
+            "prefill_expert_rows": 0,
             # What the paged decode kernel had to read: over every token
             # step of every decode window, the live slots' context
             # lengths (bounded by the sliding window where there is one).
@@ -1365,9 +1371,9 @@ class InferenceEngine:
         dispatches), prefill_s (admission bursts: uploads, dispatch,
         first-token sample), host_s (scheduler remainder), the leaf
         ``<phase>_s`` keys those three are sums of (_zero_timing), the
-        prefill_dispatches/prefill_tokens/prefill_pad_tokens and
-        decode_kv_tokens sizing counters, windows/steps counters, the
-        slot_steps/wasted_steps
+        prefill_dispatches/prefill_tokens/prefill_pad_tokens/
+        prefill_expert_rows and decode_kv_tokens sizing counters,
+        windows/steps counters, the slot_steps/wasted_steps
         decode-waste tally, the mixed_steps/prefill_chunks/chunk_tokens/
         chunk_pad_tokens chunked-prefill tally, the CURRENT decode_window
         (after any autotune growth/shrink — a snapshot, not zeroed), with
@@ -3006,6 +3012,10 @@ class InferenceEngine:
         self.timing["prefill_dispatches"] += 1
         self.timing["prefill_tokens"] += real
         self.timing["prefill_pad_tokens"] += nb * s_pad - real
+        if self.mcfg.is_moe:
+            # Pad rows have length 1, so one position of each routes too.
+            self.timing["prefill_expert_rows"] += expert_rows(
+                self.mcfg, nb, s_pad, int(lengths.sum()), self.mesh)
         with self._phase("prefill/sample"):
             firsts = self._sample(logits, reqs)  # blocks on the fetch
         for i, req in enumerate(reqs):

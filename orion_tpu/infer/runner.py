@@ -104,6 +104,7 @@ def _scan_layers(params: Params, cfg: ModelConfig, body, init_carry):
 
 
 def _prefill_ctx(
+    params: Params,
     cache: Cache,
     tokens: jax.Array,
     lengths: jax.Array,
@@ -167,7 +168,14 @@ def _prefill_ctx(
         # pages, then the chunk's own pages (walk step P_pre + cb OWNS
         # chunk page cb — the kernel's fused write targets it).
         walk = jnp.concatenate([prefix_pages, pages], axis=1)
+    # The layer-stacked expert weights, where the layer scan slices one
+    # stack [L, ...] (no window pattern): the dropless MoE dispatch reads a
+    # layer's matrices out of it in place (ops.grouped_matmul).
+    moe_stack = None
+    if cfg.is_moe and cfg.scan_layers and cfg.window_pattern is None:
+        moe_stack = params["blocks"]["moe"]
     return dict(
+        moe_stack=moe_stack,
         Nb=Nb, S_pad=S_pad, psz=psz, NP=NP, n_pages=S_pad // psz,
         quant=quant, P_pre=P_pre, positions=positions, seg=seg,
         kv_pos=kv_pos, kv_seg=kv_seg, pages=pages,
@@ -192,6 +200,7 @@ def _prefill_layer(
     Nb, psz, NP = ctx["Nb"], ctx["psz"], ctx["NP"]
     n_pages, quant, P_pre = ctx["n_pages"], ctx["quant"], ctx["P_pre"]
     positions, seg = ctx["positions"], ctx["seg"]
+    layer_stack = None if ctx["moe_stack"] is None else (ctx["moe_stack"], l)
     h = _norm(x, bp["attn_norm"], cfg, mesh)
     q, k, v = qkv_proj(h, bp["attn"], cfg, positions, mesh)
     if P_pre and ctx["paged"]:
@@ -223,7 +232,8 @@ def _prefill_layer(
             a = _norm(a, bp["post_attn_norm"], cfg, mesh)
         x = x + a
         h2 = _norm(x, bp["mlp_norm"], cfg, mesh)
-        y, _ = mlp_or_moe(h2, bp, cfg)
+        y, _ = mlp_or_moe(
+            h2, bp, cfg, mesh, valid=seg > 0, layer_stack=layer_stack)
         if cfg.post_norms:
             y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
         return x + y, cc
@@ -271,7 +281,11 @@ def _prefill_layer(
         a = _norm(a, bp["post_attn_norm"], cfg, mesh)
     x = x + a
     h2 = _norm(x, bp["mlp_norm"], cfg, mesh)
-    y, _ = mlp_or_moe(h2, bp, cfg)
+    # Padded positions are not routed: nothing reads their activations
+    # (segment ids mask them in attention, their KV goes to the scratch
+    # page, logits come off each row's last real position).
+    y, _ = mlp_or_moe(
+        h2, bp, cfg, mesh, valid=seg > 0, layer_stack=layer_stack)
     if cfg.post_norms:
         y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
     x = x + y
@@ -357,8 +371,8 @@ def prefill_step(
     is never read.
     """
     ctx = _prefill_ctx(
-        cache, tokens, lengths, pages, prefix_lens, prefix_pages, cfg,
-        paged_prefill=paged_prefill,
+        params, cache, tokens, lengths, pages, prefix_lens, prefix_pages,
+        cfg, paged_prefill=paged_prefill,
     )
 
     def body(carry, bp, l, j):
@@ -1016,8 +1030,8 @@ def mixed_step(
         del active  # host-side bookkeeping filters; kept for decode parity
     wp = jnp.minimum(seq_lens, max_seq_len - 1)
     pctx = _prefill_ctx(
-        cache, p_tokens, p_lengths, p_pages, p_prefix_lens, p_prefix_pages,
-        cfg, paged_prefill=paged_prefill,
+        params, cache, p_tokens, p_lengths, p_pages, p_prefix_lens,
+        p_prefix_pages, cfg, paged_prefill=paged_prefill,
     )
     dctx = _decode_ctx(cache, wp, page_table, cfg)
 
@@ -1094,8 +1108,8 @@ def mixed_verify_step(
 
     W = tokens.shape[1]
     pctx = _prefill_ctx(
-        cache, p_tokens, p_lengths, p_pages, p_prefix_lens, p_prefix_pages,
-        cfg, paged_prefill=paged_prefill,
+        params, cache, p_tokens, p_lengths, p_pages, p_prefix_lens,
+        p_prefix_pages, cfg, paged_prefill=paged_prefill,
     )
     vctx = _verify_ctx(
         cache, seq_lens, lens, page_table, active, W, max_seq_len, cfg,

@@ -1,10 +1,12 @@
-"""Mixture-of-experts layer with capacity-based top-k dispatch.
+"""Mixture-of-experts layer: capacity-based top-k dispatch, and a dropless
+grouped dispatch where the capacity one provably drops nothing.
 
 Covers the reference's Mixtral 8x7B workload (BASELINE.json:10, "expert-
-parallel all-to-all"). Everything is static-shaped for XLA; overflowing
-tokens beyond capacity are dropped (Switch-style). Three dispatch modes
-(``model.moe_dispatch``), identical semantics where their drop rules
-coincide (see each docstring):
+parallel all-to-all"). Everything is static-shaped for XLA. The three
+capacity dispatch modes (``model.moe_dispatch``) give every expert a bucket of
+``moe_capacity`` rows per batch row and drop what overflows it
+(Switch-style); they have identical semantics where their drop rules coincide
+(see each docstring):
 
   - **einsum** — dispatch/combine are einsums against a static-capacity
     one-hot tensor; expert parallelism is purely a sharding choice (expert
@@ -24,6 +26,20 @@ coincide (see each docstring):
     expert owners (the literal NCCL-a2a structure of the reference,
     BASELINE.json:10). Tokens are routed per ep-local sequence slice, so
     overflow drops are per-slice rather than global-priority.
+
+A bucket costs its full capacity whether filled or not: at the only dropless
+capacity (``capacity_factor = E / k``, C = S) that is all E experts over every
+position, E / k times the routed work. So within the sorted modes
+``moe_dispatch`` takes the **grouped** path (``moe_mlp_grouped``) wherever
+``takes_grouped_path`` holds — the capacity is dropless, no ``ep`` axis is
+live, and the routed rows plus tile rounding are fewer than the bucket rows —
+all three known at trace time from shapes, config and mesh: the k*T routed
+assignments are sorted by expert and multiplied by grouped matmuls
+(``ops.grouped_matmul``), padded positions of a prefill block not routed at
+all. Prefill blocks of a dropless model go grouped; decode-sized blocks, a
+capacity that drops (training at 1.25) and expert-parallel layouts keep their
+buckets. ``einsum`` mode is never rerouted (it is the plain form the others
+are tested against).
 
 Aux load-balancing loss follows Switch/Mixtral: E * sum_e f_e * p_e.
 """
@@ -364,20 +380,145 @@ def moe_mlp_sorted_a2a(
     return y, aux.astype(jnp.float32)
 
 
+def _bucket_rows(cfg: ModelConfig, B: int, S: int) -> int:
+    """Expert-matmul rows the capacity dispatches compute for a [B, S, D]
+    block: every expert's bucket at full capacity, filled or not."""
+    return cfg.n_experts * B * moe_capacity(cfg, S)
+
+
+def takes_grouped_path(cfg: ModelConfig, B: int, S: int, mesh=None) -> bool:
+    """The rule by which ``moe_dispatch`` leaves the capacity buckets of its
+    sorted modes for ``moe_mlp_grouped`` (``einsum`` mode never does: it is
+    the plain form the others are tested against); every term is known at
+    trace time.
+
+    (a) ``moe_capacity(cfg, S) >= S``: an expert's bucket holds a whole row,
+        so the capacity dispatch provably drops nothing and both paths
+        define the same function;
+    (b) no live ``ep`` axis: the expert-parallel layouts keep their buckets
+        (static shapes for the all-to-all);
+    (c) the grouped matmul visits fewer MXU rows than the buckets hold, its
+        tile rounding counted at the worst case (every expert's group ends
+        inside a tile): ``k*T + E*tm < E*B*C``. Decode (``[32, 1, D]``: 64
+        + 8*tm against 256) stays on the buckets, where all E experts'
+        weights are read whichever path runs.
+    """
+    from orion_tpu.ops.grouped_matmul import TILE_M
+
+    if cfg.moe_dispatch == "einsum" or moe_capacity(cfg, S) < S:
+        return False
+    if mesh is not None and mesh.shape.get("ep", 1) > 1:
+        return False
+    E, k = cfg.n_experts, cfg.n_experts_per_token
+    return k * B * S + E * TILE_M < _bucket_rows(cfg, B, S)
+
+
+def expert_rows(cfg: ModelConfig, B: int, S: int,
+                n_valid: Optional[int] = None, mesh=None) -> int:
+    """Expert-matmul rows per MoE layer that ``moe_dispatch`` computes for a
+    [B, S, D] block of which ``n_valid`` positions (all, when None) are real:
+    the routed assignments ``k * n_valid`` on the grouped path (its tile
+    rounding, at most ``E * tm`` rows a matmul, depends on the routing and
+    is NOT counted), all ``E * B * C`` bucket rows on the capacity paths."""
+    if takes_grouped_path(cfg, B, S, mesh):
+        n = B * S if n_valid is None else n_valid
+        return cfg.n_experts_per_token * n
+    return _bucket_rows(cfg, B, S)
+
+
+def moe_mlp_grouped(
+    x: jax.Array,
+    params: dict[str, Any],
+    cfg: ModelConfig,
+    valid: Optional[jax.Array] = None,
+    mesh=None,
+    layer_stack: Optional[tuple[dict[str, Any], jax.Array]] = None,
+) -> tuple[jax.Array, jax.Array]:
+    """The dropless dispatch: only the routed (token, expert) assignments
+    are multiplied. The block is flattened to T = B*S tokens, its k*T
+    assignments stable-sorted by expert, and the three expert matmuls run
+    as grouped matmuls over the sorted rows (``ops.grouped_matmul``), so an
+    expert costs the rows the router gave it and not a capacity bucket.
+    Same mathematics as ``moe_mlp_sorted`` where that drops nothing: a
+    row's result does not depend on which rows share its matmul.
+
+    ``valid`` [B, S] bool marks the real positions of a padded block
+    (prefill): assignments of the others sort behind the last group, are in
+    no ``group_sizes`` entry, and their output is zero.
+
+    ``layer_stack`` = (the layer-stacked MoE weights [L, E, ...], this
+    layer's index) lets the matmuls read the expert matrices out of the
+    stack in place (``ops.grouped_matmul``'s ``layer``); ``params`` then
+    serves the router, and any matrix the stack holds in another dtype.
+    """
+    from orion_tpu.ops.grouped_matmul import grouped_matmul
+
+    B, S, D = x.shape
+    E, k = cfg.n_experts, cfg.n_experts_per_token
+    T = B * S
+    probs, gate, idx = _router_topk(x, params["router"], cfg)
+
+    # Assignment a = t*k + j (token t, slot j); invalid ones get key E.
+    key = idx.reshape(T * k)
+    gate = gate.reshape(T, k)
+    if valid is not None:
+        v = valid.reshape(T)
+        key = jnp.where(jnp.repeat(v, k), key, E)
+        gate = gate * v[:, None].astype(gate.dtype)
+    order = jnp.argsort(key, stable=True)                    # [kT]
+    group_sizes = jnp.sum(
+        key[:, None] == jnp.arange(E, dtype=key.dtype), axis=0,
+        dtype=jnp.int32)                                     # [E]
+    xs = x.reshape(T, D)[order // k]                         # [kT, D]
+    if valid is not None:
+        # Rows behind the last group are never computed (the kernel leaves
+        # them uninitialised); the selects keep what is there out of the
+        # result and out of the cotangents.
+        live = (jnp.arange(T * k) < group_sizes.sum())[:, None]
+        xs = jnp.where(live, xs, 0)
+
+    def gmm(a, name, contract_tp=False):
+        w, layer = params[name], None
+        if layer_stack is not None and layer_stack[0][name].dtype == a.dtype:
+            w, layer = layer_stack[0][name], layer_stack[1]
+        return grouped_matmul(a, w, group_sizes, impl=cfg.kernels, mesh=mesh,
+                              contract_tp=contract_tp, layer=layer)
+
+    h_in = gmm(xs, "w_in")
+    if cfg.is_gated_mlp:
+        from orion_tpu.models.transformer import _gate_act
+
+        h = _gate_act(cfg)(gmm(xs, "w_gate")) * h_in
+    else:
+        h = jax.nn.gelu(h_in)
+    out = gmm(h, "w_out", contract_tp=True)                  # [kT, D]
+    if valid is not None:
+        out = jnp.where(live, out, 0)
+    out = out[jnp.argsort(order)].reshape(T, k, D)           # un-sort
+    y = jnp.einsum("tkd,tk->td", out, gate.astype(x.dtype))
+    return y.reshape(B, S, D), _aux_loss(probs, idx, cfg).astype(jnp.float32)
+
+
 def moe_dispatch(
     x: jax.Array,
     params: dict[str, Any],
     cfg: ModelConfig,
     mesh=None,
+    valid: Optional[jax.Array] = None,
+    layer_stack: Optional[tuple[dict[str, Any], jax.Array]] = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Entry point: select the dispatch per ``cfg.moe_dispatch``."""
+    """Entry point: select the dispatch per ``cfg.moe_dispatch``, and within
+    the sorted modes the dropless grouped path where ``takes_grouped_path``
+    admits it. ``valid`` [B, S] (real positions of a padded block) and
+    ``layer_stack`` (see ``moe_mlp_grouped``) are read by the grouped path
+    alone; the capacity paths route every position."""
     mode = cfg.moe_dispatch
+    if mode not in ("einsum", "sorted", "sorted_a2a"):
+        raise ValueError(f"unknown model.moe_dispatch={mode!r}")
+    if takes_grouped_path(cfg, x.shape[0], x.shape[1], mesh):
+        return moe_mlp_grouped(x, params, cfg, valid, mesh, layer_stack)
     if mode == "einsum":
         return moe_mlp(x, params, cfg)
-    if mode == "sorted":
-        return moe_mlp_sorted(x, params, cfg)
-    if mode == "sorted_a2a":
-        if mesh is None or mesh.shape.get("ep", 1) == 1:
-            return moe_mlp_sorted(x, params, cfg)
-        return moe_mlp_sorted_a2a(x, params, cfg, mesh)
-    raise ValueError(f"unknown model.moe_dispatch={mode!r}")
+    if mode == "sorted_a2a" and mesh is not None:
+        return moe_mlp_sorted_a2a(x, params, cfg, mesh)   # ep == 1: sorted
+    return moe_mlp_sorted(x, params, cfg)

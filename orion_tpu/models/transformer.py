@@ -343,15 +343,23 @@ def out_proj(out: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
 
 
 def mlp_or_moe(
-    h: jax.Array, bp: Params, cfg: ModelConfig, mesh: Optional[Any] = None
+    h: jax.Array, bp: Params, cfg: ModelConfig, mesh: Optional[Any] = None,
+    valid: Optional[jax.Array] = None,
+    layer_stack: Optional[tuple[Params, jax.Array]] = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """The post-attention half of a block: dense MLP or MoE. Returns (y, aux)."""
+    """The post-attention half of a block: dense MLP or MoE. Returns (y, aux).
+
+    ``valid`` [B, S] marks the real positions of a padded block (prefill):
+    the dropless MoE dispatch routes only those. ``layer_stack`` = (the
+    layer-stacked ``blocks["moe"]``, this layer's index) lets it read the
+    expert matrices in place (moe.moe_mlp_grouped)."""
     if cfg.is_moe:
         moe_params = {
             k: v.astype(h.dtype) if k != "router" else v
             for k, v in bp["moe"].items()
         }
-        return moe_lib.moe_dispatch(h, moe_params, cfg, mesh)
+        return moe_lib.moe_dispatch(
+            h, moe_params, cfg, mesh, valid, layer_stack)
     return _mlp_block(h, bp["mlp"], cfg), jnp.zeros((), jnp.float32)
 
 

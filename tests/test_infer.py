@@ -856,3 +856,135 @@ def test_windowed_submit_accounts_for_bucket_bottom_peak():
         "inference.max_batch_size=2",
     ])
     InferenceEngine(cfg2, params).submit(prompt, 500)
+
+
+# -- the dropless grouped MoE dispatch (models/moe.takes_grouped_path) -------
+
+# The Mixtral serving cell's MoE geometry: E = 8, top-2, capacity_factor
+# 4.0 = E / k (dropless), 32 slots, prefill bursts within 4096 padded tokens.
+_CELL_PREFILL = [(1, 512), (1, 1024), (1, 1536), (1, 2048), (2, 512),
+                 (2, 1024), (2, 1536), (2, 2048), (4, 512), (4, 1024),
+                 (8, 512)]
+_RULE_CASES = (
+    [(f"prefill{b}x{s}", b, s, 4.0, {}, True) for b, s in _CELL_PREFILL]
+    + [
+        ("decode32x1", 32, 1, 4.0, {}, False),
+        ("verify32x5", 32, 5, 4.0, {}, False),
+        ("window_step32x1_cf8", 32, 1, 8.0, {}, False),
+        ("train_factor_1.25", 8, 512, 1.25, {}, False),   # (a): it drops
+        ("live_ep", 8, 512, 4.0, {"dp": 2, "ep": 4}, False),   # (b)
+        ("live_tp_only", 8, 512, 4.0, {"dp": 4, "tp": 2}, True),
+    ]
+)
+
+
+@pytest.mark.parametrize(
+    "B,S,factor,axes,grouped", [c[1:] for c in _RULE_CASES],
+    ids=[c[0] for c in _RULE_CASES])
+def test_moe_grouped_rule(B, S, factor, axes, grouped):
+    """Which blocks leave the capacity buckets: every prefill shape of the
+    Mixtral cell, and nothing at the decode / verify shapes, under a
+    capacity that drops, or with a live ep axis. ``expert_rows`` (the
+    engine's counter) follows the same rule."""
+    import dataclasses
+
+    from orion_tpu.models import moe as moe_lib
+    from tests.conftest import make_mesh
+
+    cfg = dataclasses.replace(
+        get_config("tiny-mixtral").model, n_experts=8,
+        n_experts_per_token=2, capacity_factor=factor)
+    mesh = make_mesh(jax.devices("cpu")[:8], **axes) if axes else None
+    assert moe_lib.takes_grouped_path(cfg, B, S, mesh) is grouped
+    buckets = 8 * B * moe_lib.moe_capacity(cfg, S)
+    n_valid = B * S // 2 + 1
+    assert moe_lib.expert_rows(cfg, B, S, None, mesh) == (
+        2 * B * S if grouped else buckets)
+    assert moe_lib.expert_rows(cfg, B, S, n_valid, mesh) == (
+        2 * n_valid if grouped else buckets)
+    einsum = dataclasses.replace(cfg, moe_dispatch="einsum")
+    assert moe_lib.expert_rows(einsum, B, S, n_valid, mesh) == buckets
+
+
+def test_decode_program_holds_no_grouped_matmul():
+    """At the cell's MoE geometry the prefill program multiplies routed rows
+    (a ragged dot in its jaxpr) and the decode-window program keeps the
+    capacity buckets: the benchmark finds decode's expert fusions by shape,
+    and the output check ties the window program to the one-step body."""
+    from functools import partial
+
+    from orion_tpu.infer import runner
+    from orion_tpu.infer.kv_cache import init_cache, pages_per_seq
+
+    cfg, _ = _setup("tiny-mixtral", [
+        "model.n_experts=8", "model.capacity_factor=4.0",
+        "inference.max_seq_len=1024", "inference.page_size=64",
+        "inference.num_pages=64", "inference.max_batch_size=32",
+        "inference.prefill_chunk=512"])
+    mcfg, icfg = cfg.model, cfg.inference
+    params = jax.eval_shape(lambda: init_params(mcfg, jax.random.key(0)))
+    cache = jax.eval_shape(lambda: init_cache(mcfg, icfg))
+    i32 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.int32)
+    B, W = icfg.max_batch_size, 8
+    decode = jax.make_jaxpr(partial(
+        runner.decode_window, cfg=mcfg, max_seq_len=icfg.max_seq_len,
+        temperature=icfg.temperature, top_k=icfg.top_k, top_p=icfg.top_p))(
+        params, cache, i32(B), i32(B), i32(B, pages_per_seq(icfg)),
+        jax.ShapeDtypeStruct((B,), jnp.bool_),
+        jax.eval_shape(lambda: jax.random.split(jax.random.key(0), W)))
+    assert "ragged_dot" not in str(decode)
+    nb, s_pad = 8, 512
+    prefill = jax.make_jaxpr(partial(runner.prefill_step, cfg=mcfg))(
+        params, cache, i32(nb, s_pad), i32(nb),
+        i32(nb, s_pad // icfg.page_size), i32(nb), i32(nb, 0))
+    assert str(prefill).count("ragged_dot") >= 3      # w_in, w_gate, w_out
+
+
+def _moe_burst(monkeypatch, grouped):
+    """A tiny-mixtral engine at the dropless factor, one burst of three rows
+    of unlike lengths (padded to 4 x 16), with the rule patched so that
+    prefill takes the grouped path (or never does): tiny blocks fail the
+    rule's row count, which charges E x 256 rows of tile rounding."""
+    from orion_tpu.models import moe as moe_lib
+
+    monkeypatch.setattr(
+        moe_lib, "takes_grouped_path",
+        lambda cfg, B, S, mesh=None: grouped and S > 1)
+    traced, path = [], moe_lib.moe_mlp_grouped
+    monkeypatch.setattr(
+        moe_lib, "moe_mlp_grouped",
+        lambda x, *a: traced.append(x.shape[:2]) or path(x, *a))
+    cfg, params = _setup("tiny-mixtral", ["model.capacity_factor=2.0"])
+    eng = InferenceEngine(cfg, params)
+    prompts = [[5, 3, 9, 250, 17, 8, 1, 2, 3, 4, 5, 6, 7], [9, 8, 7], [42]]
+    for p in prompts:
+        eng.submit(p, 6)
+    done = list(eng.step())
+    timing = dict(eng.timing)
+    while eng.has_work():
+        done += eng.step()
+    assert set(traced) == ({(4, 16)} if grouped else set())
+    return {r.rid: list(r.generated) for r in done}, timing, cfg.model
+
+
+def test_moe_grouped_prefill_matches_bucket_prefill(monkeypatch):
+    """Greedy tokens of a ragged burst are the same whether prefill
+    multiplies only the routed rows of its real positions or every
+    expert's capacity bucket over every position, padding included."""
+    bucket, _, _ = _moe_burst(monkeypatch, grouped=False)
+    grouped, _, _ = _moe_burst(monkeypatch, grouped=True)
+    assert len(grouped) == 3 and all(len(g) == 6 for g in grouped.values())
+    assert grouped == bucket
+
+
+@pytest.mark.parametrize("grouped", [False, True])
+def test_prefill_expert_rows_exact(monkeypatch, grouped):
+    """``prefill_expert_rows`` on a known burst: 13 + 3 + 1 real positions
+    in a 4 x 16 block whose pad row has length 1. The grouped path computes
+    k rows for each position that routes (the pad row's one included), the
+    capacity buckets E x B x C = 4 x 4 x 16."""
+    _, t, mcfg = _moe_burst(monkeypatch, grouped)
+    assert (t["prefill_dispatches"], t["prefill_tokens"],
+            t["prefill_pad_tokens"]) == (1, 17, 47)
+    k, E = mcfg.n_experts_per_token, mcfg.n_experts
+    assert t["prefill_expert_rows"] == (k * 18 if grouped else E * 4 * 16)

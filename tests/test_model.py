@@ -326,6 +326,78 @@ def test_moe_sorted_grads_match_einsum(overflow):
         )
 
 
+def _grouped_case(routing, B, S):
+    """Dropless tiny-mixtral MoE inputs whose router is steered through a
+    constant feature of x: ``empty`` keeps expert 1 out of every top-2,
+    ``full`` puts expert 2 into every top-2."""
+    import dataclasses
+
+    moe_lib, cfg, x, params = _moe_setup(seed=7, B=B, S=S)
+    cfg = dataclasses.replace(
+        cfg, capacity_factor=cfg.n_experts / cfg.n_experts_per_token)
+    x = x.at[..., 0].set(1.0)
+    bias = {"any": [0, 0, 0, 0], "empty": [0, -50, 0, 0],
+            "full": [0, 0, 50, 0]}[routing]
+    params["router"] = params["router"].at[0].set(jnp.asarray(bias, float))
+    return moe_lib, cfg, x, params
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize(
+    "routing,B,S", [("any", 3, 16), ("any", 1, 40), ("empty", 2, 16),
+                    ("full", 2, 16)])
+def test_moe_grouped_matches_sorted(routing, B, S, masked, impl):
+    """The dropless grouped dispatch against the capacity dispatch at a
+    capacity that drops nothing: outputs, aux loss and every gradient agree
+    over several rows, an expert with no token and one with every token.
+    With ``valid`` the real positions agree and the masked ones are zero,
+    in the output and in x's gradient."""
+    import dataclasses
+
+    moe_lib, cfg, x, params = _grouped_case(routing, B, S)
+    gcfg = dataclasses.replace(cfg, kernels=impl)
+    _, _, idx = moe_lib._router_topk(x, params["router"], cfg)
+    counts = np.bincount(np.asarray(idx).ravel(), minlength=cfg.n_experts)
+    if routing == "empty":
+        assert counts[1] == 0
+    if routing == "full":
+        assert counts[2] == B * S
+    valid = None
+    keep = jnp.ones((B, S, 1), bool)
+    if masked:
+        lengths = jnp.asarray([S, 5, 1][:B])
+        valid = jnp.arange(S)[None] < lengths[:, None]
+        keep = valid[..., None]
+    w = jax.random.normal(jax.random.key(11), x.shape)
+
+    def loss(fn, x, p):
+        y, aux = fn(x, p)
+        return jnp.sum(jnp.where(keep, y * w, 0)) + aux, y
+
+    sorted_fn = lambda x, p: moe_lib.moe_mlp_sorted(x, p, cfg)
+    grouped_fn = lambda x, p: moe_lib.moe_mlp_grouped(x, p, gcfg, valid)
+    (_, y_s), g_s = jax.value_and_grad(
+        lambda x, p: loss(sorted_fn, x, p), argnums=(0, 1), has_aux=True
+    )(x, params)
+    (_, y_g), g_g = jax.value_and_grad(
+        lambda x, p: loss(grouped_fn, x, p), argnums=(0, 1), has_aux=True
+    )(x, params)
+    np.testing.assert_allclose(
+        np.asarray(jnp.where(keep, y_g, 0)),
+        np.asarray(jnp.where(keep, y_s, 0)), atol=2e-6)
+    assert not np.asarray(jnp.where(keep, 0, y_g)).any()
+    # The aux loss reads every position on both paths, so x's gradient at a
+    # masked position is the router's alone there: compare the real ones.
+    np.testing.assert_allclose(
+        np.asarray(jnp.where(keep, g_g[0], 0)),
+        np.asarray(jnp.where(keep, g_s[0], 0)), atol=2e-6)
+    for name in params:
+        np.testing.assert_allclose(
+            np.asarray(g_g[1][name]), np.asarray(g_s[1][name]), atol=5e-6,
+            err_msg=name)
+
+
 def test_moe_dispatch_unknown_mode_raises():
     import dataclasses
 

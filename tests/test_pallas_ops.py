@@ -5,6 +5,8 @@ code paths are exercised without a TPU; fwd and grads must match the xla ops
 to fp32 tolerance.
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1090,3 +1092,47 @@ def test_ragged_tree_width_limit():
             tree_mask=jnp.zeros((1, 32), jnp.int32),
             depths=jnp.zeros((1, 32), jnp.int32), interpret=True,
         )
+
+
+@pytest.mark.parametrize(
+    "layout", ["one_device", "tp_n", "tp_contract", "layer_stack"])
+def test_grouped_matmul_pallas_matches_ragged_dot(layout):
+    """The megablox kernel behind ``ops.grouped_matmul`` (interpret mode)
+    against ``lax.ragged_dot``, values and gradients: an empty group, rows
+    past the last group (unspecified, so not compared), a row count that is
+    no whole number of m-tiles, under a mesh the kernel per ``tp`` shard
+    with the weights' n axis or the contraction axis split, and the weights
+    read out of a layer stack at a traced layer index."""
+    from orion_tpu.ops.grouped_matmul import grouped_matmul
+    from tests.conftest import make_mesh
+
+    m, k, n = 300, 64, 96
+    sizes = jnp.asarray([120, 0, 37, 93], jnp.int32)       # 250 of 300 rows
+    lhs, rhs = _rand(0, m, k), _rand(1, 4, k, n) * 0.2
+    live = (jnp.arange(m) < int(sizes.sum()))[:, None]
+    mesh = None
+    if layout in ("tp_n", "tp_contract"):
+        mesh = make_mesh(jax.devices("cpu")[:8], dp=4, tp=2)
+    # Layer 1 of a stack of 3, its index an argument of the jit (traced, as
+    # a layer scan's is); None without a stack.
+    layer = jnp.int32(1) if layout == "layer_stack" else None
+
+    def run(impl, a, w, layer):
+        if layer is not None:
+            w = jnp.stack([w + 1, w, w - 1])
+        out = grouped_matmul(a, w, sizes, impl=impl, mesh=mesh, layer=layer,
+                             contract_tp=layout == "tp_contract")
+        return jnp.where(live, out, 0)
+
+    def loss(impl, a, w, layer):
+        return jnp.sum(run(impl, a, w, layer) * jnp.cos(jnp.arange(n)))
+
+    ref = run("xla", lhs, rhs, layer)
+    out = jax.jit(partial(run, "pallas_interpret"))(lhs, rhs, layer)
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    g_ref = jax.grad(partial(loss, "xla"), argnums=(0, 1))(lhs, rhs, layer)
+    g_out = jax.jit(jax.grad(partial(loss, "pallas_interpret"),
+                             argnums=(0, 1)))(lhs, rhs, layer)
+    np.testing.assert_allclose(
+        jnp.where(live, g_out[0], 0), g_ref[0], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(g_out[1], g_ref[1], rtol=1e-5, atol=1e-5)
