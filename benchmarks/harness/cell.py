@@ -13,6 +13,7 @@ from typing import Optional
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 BENCH = ROOT / "benchmarks"
+PUBLISHED = pathlib.Path("tests", "benchmark", "data", "published")
 
 
 def load_benchmark(root: pathlib.Path = ROOT) -> dict:
@@ -35,6 +36,7 @@ class Cell:
     end_to_end: list        # BENCHMARK.json entries this cell reports
     per_layer: list
     bench_dir: pathlib.Path = BENCH
+    published: Optional[dict] = None   # the source's config.json, as published
 
     @classmethod
     def find(cls, name: str, benchmark: Optional[dict] = None,
@@ -50,6 +52,12 @@ class Cell:
         with open(root / files[w["config"]], encoding="utf-8") as f:
             config = json.load(f)
         bench_dir = (root / files[w["config"]]).parent.parent
+        pub = root / PUBLISHED / f"{w['config']}.json"
+        if not pub.exists():
+            raise SystemExit(f"{pub}: not there (every configuration brings "
+                             f"its source's published keys)")
+        with open(pub, encoding="utf-8") as f:
+            published = json.load(f)
         from benchmarks.traffic.generator import load_mix
 
         mix = load_mix(w["traffic"], bench_dir / "traffic")
@@ -58,7 +66,10 @@ class Cell:
         per = [m for m in bm["per_layer"]
                if _applies(m, name) and m["moves"] in e2e_names]
         return cls(name, w["chips"], w["config"], w["traffic"], config, mix,
-                   e2e, per, bench_dir)
+                   e2e, per, bench_dir, published)
+
+    def program_config(self):
+        return program_config(self.config, published=self.published)
 
     def kind_module(self):
         """``kinds/<kind>.py`` of the traffic file's ``kind``."""
@@ -67,6 +78,16 @@ class Cell:
     def reader(self, metric_name: str):
         """``metrics/<name>.py``: a module with ``read(obs)``."""
         return self._load("metrics", f"{metric_name}.py")
+
+    def reference(self):
+        """``reference/<name>.py`` of the configuration's ``reference``
+        (default ``model``): the plain model of this architecture, with
+        ``param_spec(hf) -> {path: (shape, kind)}``, ``logits_at(params,
+        tokens, at, hf, quant) -> (logits, router_margin)`` and, for a
+        configuration that is trained, ``loss(params, inputs, targets, hf,
+        quant, ...)``. It reads the configuration file's own keys."""
+        return self._load("reference",
+                          f"{self.config.get('reference', 'model')}.py")
 
     def _load(self, sub: str, filename: str):
         """Beside the cell's own configuration first, then in this
@@ -92,8 +113,10 @@ def _load(path: pathlib.Path):
     return mod
 
 
-# Published key -> the program's ModelConfig field. The configuration file
-# is the only place the sizes live; the program's preset must agree with it.
+# Published key -> the program's ModelConfig field: the default map. A
+# configuration whose source names a size otherwise brings ``orion.widths``
+# (the same form) in its own file. The configuration file is the only place
+# the sizes live; the program's preset must agree with it.
 _WIDTHS = {
     "hidden_size": "d_model", "intermediate_size": "d_ff",
     "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
@@ -102,30 +125,79 @@ _WIDTHS = {
     "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
     "num_local_experts": "n_experts",
     "num_experts_per_tok": "n_experts_per_token",
+    "head_dim": "resolved_head_dim",
 }
 
 
-def program_config(config: dict, extra: tuple = ()):
+def widths(config: dict) -> dict:
+    """Published key -> ModelConfig field for this configuration: the
+    default map, and over it the file's own ``orion.widths``."""
+    return {**_WIDTHS, **config["orion"].get("widths", {})}
+
+
+def key_of(config: dict, field_name: str) -> str:
+    """The key under which this configuration's source states the size the
+    program calls ``field_name`` (``n_layers``, ``vocab_size`` ...)."""
+    keys = [k for k, f in widths(config).items()
+            if f == field_name and k in config]
+    if len(keys) != 1:
+        raise SystemExit(f"the configuration states model.{field_name} "
+                         f"under {keys}: one key has to")
+    return keys[0]
+
+
+def program_config(config: dict, extra: tuple = (), published=None):
     """The program's Config for a configuration file, checked key by key
-    against the published sizes the file states."""
+    against the sizes the file states. ``published`` (the source's own
+    ``config.json``, ``Cell.published``): each of its keys must be checked
+    here or be listed, with the reason it has no field, under
+    ``orion.unchecked``, so that a size cannot go unchecked by silence."""
     from orion_tpu.config import get_config
 
     o = config["orion"]
     cfg = get_config(o["preset"], list(o["overrides"]) + list(extra))
-    for key, fld in _WIDTHS.items():
+    mapped, unchecked = widths(config), o.get("unchecked", {})
+    reduced = config.get("reduced", ())
+    for key, value in (published or {}).items():
+        if key not in config:
+            raise SystemExit(f"the source publishes {key!r} and the "
+                             f"configuration file does not state it")
+        stated = (config.get("published", {}).get(key) if key in reduced
+                  else config[key])
+        if stated != value:
+            raise SystemExit(
+                f"the source publishes {key}={value!r} and the configuration "
+                f"file states {stated!r}"
+                + ("" if key in reduced else f", with {key!r} not in 'reduced'"))
+        if key not in mapped and key not in unchecked:
+            raise SystemExit(
+                f"the source publishes {key!r} and nothing checks it: map it "
+                f"to a field of the program in orion.widths, or give the "
+                f"reason it has none in orion.unchecked")
+    for key, fld in mapped.items():
         if key not in config:
             continue
+        if not hasattr(cfg.model, fld):
+            raise SystemExit(f"orion.widths maps {key!r} to model.{fld}, "
+                             f"which the program does not have")
         got = getattr(cfg.model, fld)
         if got != config[key]:
             raise SystemExit(
                 f"configuration says {key}={config[key]!r} but the program "
                 f"would run model.{fld}={got!r}"
             )
-    if cfg.model.resolved_head_dim != (
-        config.get("head_dim")
-        or config["hidden_size"] // config["num_attention_heads"]
-    ):
-        raise SystemExit("head_dim of the program differs from the file's")
+    # The head size: a source that names it (``head_dim``, or keys of its own
+    # that orion.widths maps to the program's head-size fields) has had it
+    # checked by the loop above; one that does not means hidden / heads.
+    if not any("head_dim" in f for k, f in mapped.items() if k in config):
+        want, rest = divmod(cfg.model.d_model, cfg.model.n_heads)
+        if rest:
+            raise SystemExit(
+                "the source names no head size and hidden_size / "
+                "num_attention_heads is not whole: map its head-size keys "
+                "in orion.widths")
+        if cfg.model.resolved_head_dim != want:
+            raise SystemExit("head_dim of the program differs from the file's")
     return cfg
 
 

@@ -16,7 +16,7 @@ from typing import Optional
 
 import numpy as np
 
-from benchmarks.harness.cell import Outcome, Phases, program_config
+from benchmarks.harness.cell import Outcome, Phases
 from benchmarks.harness.stats import emission_gaps
 from benchmarks.kinds import shapes
 from benchmarks.traffic import generator
@@ -115,18 +115,28 @@ class Driver:
 # -- the output check ---------------------------------------------------------
 
 
-def _kv_at(cache, page_table, slot, positions, n_layers: int):
-    """K and V of one slot at ``positions`` in every layer, out of the paged
-    pool ([layers x pages, kv heads, page, head]) -> [2, layers, W, kv, head]."""
+def _kv_at(cache, page_table, slot, positions, n_layers: int,
+           n_pages: int, page_size: int):
+    """What the cache holds of one slot at ``positions`` in every layer: of
+    every leaf of the engine's cache dict in the paged layout ([layers x
+    pages, heads, page, width]: K and V, a latent, ...), the entries
+    [layers, W, heads, width] in float32, raveled and joined in the order of
+    the leaves' names. Leaves of another layout (the scale pools of a
+    quantised cache) are left out."""
     import jax.numpy as jnp
 
-    n_pages = cache["k"].shape[0] // n_layers
-    page_size = cache["k"].shape[2]
     rows = (jnp.arange(n_layers)[:, None] * n_pages
             + page_table[slot, positions // page_size][None, :])
     offset = (positions % page_size)[None, :]
-    return jnp.stack([cache[name][rows, :, offset, :].astype(jnp.float32)
-                      for name in ("k", "v")])
+    paged = [name for name in sorted(cache) if cache[name].ndim == 4
+             and cache[name].shape[0] == n_layers * n_pages
+             and cache[name].shape[2] == page_size]
+    if not paged:
+        raise ValueError(f"no leaf of the cache {sorted(cache)} has the "
+                         f"paged layout")
+    return jnp.concatenate([
+        cache[name][rows, :, offset, :].astype(jnp.float32).ravel()
+        for name in paged])
 
 
 class LogitTap:
@@ -151,13 +161,14 @@ class LogitTap:
         self.prefill: list = []
         self.decode: list = []      # per window: (logits [W, V], kv, gap)
         mcfg, mesh = engine.mcfg, engine.mesh
-        self._layers = mcfg.n_layers
+        self._pool = (mcfg.n_layers, engine.icfg.num_pages,
+                      engine.icfg.page_size)
         self._core = jax.jit(
             lambda p, c, tok, pos, pt: runner._decode_core(
                 p, c, tok, pos, pt, mcfg, mesh),
             donate_argnums=(1,),
         )
-        self._kv = jax.jit(_kv_at, static_argnums=(4,))
+        self._kv = jax.jit(_kv_at, static_argnums=(4, 5, 6))
         self._orig = engine._executor.run
 
     def __enter__(self):
@@ -180,7 +191,7 @@ class LogitTap:
             slot = int(np.argmax(np.asarray(mask)))     # the probe is alone
             at = seq_lens[slot] + jnp.arange(W)
             wrote = np.asarray(self._kv(cache, page_table, slot, at,
-                                        self._layers))
+                                        *self._pool))
             steps = []
             for j in range(W):                  # step j read token j - 1
                 tok = last_token if j == 0 else toks[j - 1]
@@ -190,7 +201,7 @@ class LogitTap:
                     params, cache, tok, seq_lens + j, page_table)
                 steps.append(np.asarray(logits[slot], np.float32))
             again = np.asarray(self._kv(cache, page_table, slot, at,
-                                        self._layers))
+                                        *self._pool))
             logits = np.stack(steps)
             picked = logits[np.arange(W), np.asarray(toks)[:, slot]]
             gap = (logits.max(axis=-1) - picked) / logits.std(axis=-1)
@@ -204,28 +215,26 @@ def _rel_err(got: np.ndarray, want: np.ndarray) -> float:
     return float(np.linalg.norm(got - want) / np.linalg.norm(want))
 
 
-def probe_numbers(engine, hf: dict, mix: dict, seed: int,
+def probe_numbers(engine, ref, hf: dict, mix: dict, seed: int,
                   control: Optional[str] = None,
                   break_link: bool = False) -> dict:
     """Each probe prompt goes ALONE through the engine's prefill and
     ``probe_windows`` decode windows. Returns, for every compared position
     (the last prompt position and every decode step), the relative L2 error
-    of the logits against the float32 reference on the same tokens
-    (``err``) with the reference's own router margin there (``margin``), and
+    of the logits against the float32 reference ``ref`` (the cell's
+    ``reference()``) on the same tokens (``err``) with the reference's own router margin there (``margin``), and
     for every window the two numbers of ``LogitTap``. With ``control``,
     ``control_err`` holds the errors of the CONTROL in the program's place
     (the reference at that lower precision): the run that has to fail."""
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.reference import model as ref
-
     n_new = mix["probe_windows"] * engine.decode_window
     rng = random.Random(seed * 7919 + 13)
     out = {"probe": [], "err": [], "margin": [], "control_err": [],
            "window_kv_rel_err": [], "window_token_gap": []}
     for i, n in enumerate(mix["probe_prompts"]):
-        prompt = [rng.randrange(1, hf["vocab_size"]) for _ in range(n)]
+        prompt = [rng.randrange(1, engine.mcfg.vocab_size) for _ in range(n)]
         with LogitTap(engine, break_link) as tap:
             req = engine.submit_request(prompt, n_new + 1)
             while engine.has_work():
@@ -300,9 +309,9 @@ def build_engine(cell, seed: int):
     from orion_tpu.infer import InferenceEngine
     from orion_tpu.runtime import initialize
 
-    cfg = program_config(cell.config)
+    cfg = cell.program_config()
     initialize(cfg.runtime)
-    params = weights.make_params(cell.config, cfg.model.param_dtype, seed)
+    params = weights.for_cell(cell, cfg, seed)
     return cfg, InferenceEngine(cfg, params, seed=seed % (2 ** 31))
 
 
@@ -347,14 +356,14 @@ def run(cell, dev, *, seed: int, seconds: float, trace: bool,
     cfg, engine = build_engine(cell, seed)
     jax.block_until_ready(engine.params)
     phases.mark("weights+engine")
-    numbers = probe_numbers(engine, hf, mix, seed)
+    numbers = probe_numbers(engine, cell.reference(), hf, mix, seed)
     phases.mark("output check")
     correct, checks = decide(numbers, hf["correct"])
     warmed = warm_shapes(engine, cell, cfg)
     phases.mark("warm shapes")
     print(f"warmed prefill shapes (rows, tokens): {warmed}", flush=True)
 
-    stream = generator.request_stream(mix, seed, hf["vocab_size"])
+    stream = generator.request_stream(mix, seed, cfg.model.vocab_size)
     drv = Driver(engine, mix, cfg.inference,
                  hf["frontend"]["prefill_token_budget"], stream)
     # Warm phase: the same load, uncounted, so that the window opens on a

@@ -10,7 +10,7 @@ import time
 
 import numpy as np
 
-from benchmarks.harness.cell import Outcome, Phases, program_config
+from benchmarks.harness.cell import Outcome, Phases, key_of
 
 clock = time.monotonic
 
@@ -68,14 +68,14 @@ def _split(params, chosen):
     return [flat[i][1] for i in idx], rest, merge
 
 
-def check_numbers(trainer, cfg, hf, state, batch, control=None,
+def check_numbers(trainer, cfg, ref, hf, state, batch, control=None,
                   phases=None) -> dict:
     """Loss and a sample of gradient leaves of the FIRST step: the
     program's own loss function (its kernels, scan, remat and mesh) against
-    the float32 reference on the same weights and batch."""
+    the float32 reference ``ref`` (the cell's ``reference()``) on the same
+    weights and batch."""
     import jax
 
-    from benchmarks.reference import model as ref
     from orion_tpu.models.transformer import loss_fn
 
     import dataclasses
@@ -91,7 +91,7 @@ def check_numbers(trainer, cfg, hf, state, batch, control=None,
                       out_shardings=trainer.shardings["params"]["blocks"])
         params = dict(params, blocks=cut(params["blocks"]))
         mcfg = dataclasses.replace(mcfg, n_layers=depth)
-        hf = dict(hf, num_hidden_layers=depth)
+        hf = {**hf, key_of(hf, "n_layers"): depth}
     chosen = _sample_paths(params, hf["correct"]["grad_leaves"])
     sub, rest, merge = _split(params, chosen)
 
@@ -151,14 +151,13 @@ def build(cell, dev, seed: int):
     from orion_tpu.train import Trainer
     from orion_tpu.train.optimizer import init_opt_state
 
-    cfg = program_config(cell.config)
+    cfg = cell.program_config()
     trainer = Trainer(cfg)
     if trainer.mesh.size != cell.chips:
         raise SystemExit(f"the mesh has {trainer.mesh.size} devices, the "
                          f"cell {cell.chips}")
     sh = trainer.shardings
-    params = weights.make_params(cell.config, cfg.model.param_dtype, seed,
-                                 out_shardings=sh["params"])
+    params = weights.for_cell(cell, cfg, seed, out_shardings=sh["params"])
 
     def rest(p):
         import jax.numpy as jnp
@@ -188,8 +187,8 @@ def run(cell, dev, *, seed: int, seconds: float, trace: bool,
     jax.block_until_ready(state)
     phases.mark("trainer+weights")
     batches = _batches(trainer, cfg, mix, seed)
-    numbers = check_numbers(trainer, cfg, hf, state, batches[0],
-                            phases=phases)
+    numbers = check_numbers(trainer, cfg, cell.reference(), hf, state,
+                            batches[0], phases=phases)
     phases.mark("check: compared")
     correct, checks = decide(numbers["sound"], hf["correct"]["limits"])
     print(f"gradient leaves compared: {numbers['leaves']}; seen and not "

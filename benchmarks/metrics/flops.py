@@ -59,3 +59,11 @@ def expert_weight_bytes(hf: dict, dtype_bytes: int = 2) -> int:
     """All experts' three matrices, all layers."""
     D, N, K, H, F, L, V = _dims(hf)
     return L * hf["num_local_experts"] * 3 * D * F * dtype_bytes
+
+
+def expert_matmul_flops(hf: dict, rows_per_layer: int) -> float:
+    """The three matmuls of a gated expert feed-forward (in and gate [D, F],
+    out [F, D]: 2 D F each) over ``rows_per_layer`` (token, expert) rows in
+    each of the layers."""
+    D, N, K, H, F, L, V = _dims(hf)
+    return L * rows_per_layer * 3 * 2 * D * F

@@ -44,14 +44,19 @@ def op_seconds(obs, pattern: str) -> Optional[float]:
 def decode_program(obs):
     """(seconds, runs) of the decode-window program in the trace.
 
-    The engine jits ``partial`` objects, so its programs all carry the name
-    ``jit__unknown`` and a fingerprint that changes with every edit. The
-    decode program is the one that ran most often (one shape, every step;
-    each prefill shape is a program of its own). PERF.md section 7 asks the
-    program for names."""
+    A program whose name carries ``decode_window`` is it (several
+    fingerprints of that name, one a shape, are added up). The engine jits
+    ``partial`` objects, so until the program names its jits they all carry
+    the name ``jit__unknown`` and a fingerprint that changes with every
+    edit; the decode program is then the ``unknown`` one that ran most often
+    (one shape, every step; each prefill shape is a program of its own)."""
     tr = obs.get("trace")
     if not tr:
         return None
+    named = [k for k in tr["module_n"] if "decode_window" in k]
+    if named:
+        return (sum(tr["module_s"][k] for k in named),
+                sum(tr["module_n"][k] for k in named))
     runs = {k: n for k, n in tr["module_n"].items() if "unknown" in k}
     if not runs:
         return None

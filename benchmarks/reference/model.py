@@ -152,6 +152,40 @@ def _moe(x, p, hf, quant):
     return y, margin
 
 
+def param_spec(hf: dict) -> dict:
+    """The tree this model reads, in the layout the program's model reads
+    (``embed.tokens``, ``blocks.attn.wq`` stacked over layers, ...):
+    {path: (shape, kind)}; kind is 'normal', 'resid' or 'norm'
+    (``weights.py`` draws them)."""
+    D, V, L = hf["hidden_size"], hf["vocab_size"], hf["num_hidden_layers"]
+    N, K = hf["num_attention_heads"], hf["num_key_value_heads"]
+    H = hf.get("head_dim") or D // N
+    F = hf["intermediate_size"]
+    E = hf.get("num_local_experts", 0)
+    spec = {
+        ("embed", "tokens"): ((V, D), "normal"),
+        ("final_norm", "scale"): ((D,), "norm"),
+        ("blocks", "attn_norm", "scale"): ((L, D), "norm"),
+        ("blocks", "mlp_norm", "scale"): ((L, D), "norm"),
+        ("blocks", "attn", "wq"): ((L, D, N * H), "normal"),
+        ("blocks", "attn", "wk"): ((L, D, K * H), "normal"),
+        ("blocks", "attn", "wv"): ((L, D, K * H), "normal"),
+        ("blocks", "attn", "wo"): ((L, N * H, D), "resid"),
+    }
+    if not hf.get("tie_word_embeddings", False):
+        spec[("lm_head",)] = ((D, V), "normal")
+    if E:
+        spec[("blocks", "moe", "router")] = ((L, D, E), "normal")
+        spec[("blocks", "moe", "w_in")] = ((L, E, D, F), "normal")
+        spec[("blocks", "moe", "w_gate")] = ((L, E, D, F), "normal")
+        spec[("blocks", "moe", "w_out")] = ((L, E, F, D), "resid")
+    else:
+        spec[("blocks", "mlp", "w_in")] = ((L, D, F), "normal")
+        spec[("blocks", "mlp", "w_gate")] = ((L, D, F), "normal")
+        spec[("blocks", "mlp", "w_out")] = ((L, F, D), "resid")
+    return spec
+
+
 def hidden_states(params, tokens, hf: dict, quant: Optional[str] = None,
                   whole=lambda tree, where: tree):
     """tokens [S] -> (final-norm input [S, D] in float32, router margins

@@ -56,8 +56,9 @@ def main(argv=None, root: pathlib.Path = ROOT, allow_cpu: bool = False) -> int:
                    trace=bool(args.trace), t_process=T_PROCESS,
                    compiles=compiles)
 
-    for name, value, limit in out.checks:
-        print(f"check: {name} = {value!r} (limit {limit!r})", flush=True)
+    said = [f"check: {name} = {value!r} (limit {limit!r})"
+            for name, value, limit in out.checks]
+    print("\n".join(said), flush=True)
     print(f"compiles_in_window: {out.obs.get('compiles_in_window')}",
           flush=True)
     print(f"compiles in set-up: {out.obs.get('compiles_setup')} programs, "
@@ -95,6 +96,9 @@ def main(argv=None, root: pathlib.Path = ROOT, allow_cpu: bool = False) -> int:
     if args.trace and out.breakdown is not None and on_device:
         line["breakdown"] = out.breakdown
     print(json.dumps(line), flush=True)
+    # Each number compared beside its limit, as the last lines of standard
+    # error too: of a run that is not correct the driver keeps the end of that.
+    print("\n".join(said), file=sys.stderr, flush=True)
     return 0
 
 

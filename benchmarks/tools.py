@@ -92,7 +92,8 @@ def calibrate(args) -> int:
             s = args.first_seed + i
             cfg, trainer, state = kind.build(cell, dev, s)
             batch = kind._batches(trainer, cfg, cell.mix, s)[0]
-            out = kind.check_numbers(trainer, cfg, cell.config, state, batch,
+            out = kind.check_numbers(trainer, cfg, cell.reference(),
+                                     cell.config, state, batch,
                                      control="int8")
             print("calibrate: " + json.dumps({"seed": s, **out}), flush=True)
             del state, trainer, batch
@@ -105,13 +106,13 @@ def calibrate(args) -> int:
         s = args.first_seed + i
         if i:
             engine.params = None
-            engine.params = weights.make_params(
-                cell.config, cfg.model.param_dtype, s)
-        n = kind.probe_numbers(engine, cell.config, cell.mix, s,
-                               control="int8")
+            engine.params = weights.for_cell(cell, cfg, s)
+        n = kind.probe_numbers(engine, cell.reference(), cell.config,
+                               cell.mix, s, control="int8")
         broken = kind.probe_numbers(
-            engine, dict(cell.config), dict(cell.mix, probe_prompts=[
-                cell.mix["probe_prompts"][0]]), s, break_link=True)
+            engine, cell.reference(), cell.config,
+            dict(cell.mix, probe_prompts=[cell.mix["probe_prompts"][0]]), s,
+            break_link=True)
         print("calibrate: " + json.dumps({
             "seed": s, **n,
             "sound": kind.judged(n, margin_min),

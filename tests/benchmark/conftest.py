@@ -6,6 +6,7 @@ import collections
 import copy
 import json
 import pathlib
+import shutil
 import sys
 
 import pytest
@@ -13,6 +14,8 @@ import pytest
 REPO = pathlib.Path(__file__).resolve().parents[2]
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
+
+from benchmarks.harness.cell import PUBLISHED  # noqa: E402  (needs the path)
 
 BASE = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
             num_key_value_heads=2, vocab_size=256, num_hidden_layers=2,
@@ -58,10 +61,33 @@ MIXES = {
     "tiny-train": dict(kind="train", seq_len=64, distinct_batches=2,
                        warm_steps=2, max_in_flight=2, trace_steps=2),
 }
+# A configuration that is not Mistral, as a later PR brings one: its files
+# lie under data/tiny_other/ (configuration, the source's published keys, the
+# reference its configuration names) and are copied into the root unedited.
+OTHER = "tiny-other-serve"
+OTHER_FILES = REPO / "tests" / "benchmark" / "data" / "tiny_other"
 CELLS = {"tiny.train": ("tiny-train", "tiny-train", "mistral-7b.train-8k"),
          "tiny.dense-batch": ("tiny-serve", "tiny-dense-batch",
                               "mixtral-8x7b.serve-batch"),
-         "tiny.batch": ("tiny-moe-serve", "tiny-batch", "mixtral-8x7b.serve-batch")}
+         "tiny.batch": ("tiny-moe-serve", "tiny-batch", "mixtral-8x7b.serve-batch"),
+         "tiny.other-batch": (OTHER, "tiny-batch", "mixtral-8x7b.serve-batch")}
+NOT_SIZES = ("role", "frontend", "orion", "correct")
+
+
+def add_configuration(root: pathlib.Path, name: str, cfg: dict,
+                      published: dict) -> dict:
+    """Write a configuration's two files; its BENCHMARK.json entry."""
+    (root / "benchmarks" / "configs" / f"{name}.json").write_text(
+        json.dumps(cfg))
+    (root / PUBLISHED / f"{name}.json").write_text(json.dumps(published))
+    return {"name": name, "source": cfg.get("source", "test"),
+            "reduced": cfg.get("reduced", []), "why": "test",
+            "file": f"benchmarks/configs/{name}.json"}
+
+
+def other_configuration() -> tuple[dict, dict]:
+    return (json.loads((OTHER_FILES / "config.json").read_text()),
+            json.loads((OTHER_FILES / "published.json").read_text()))
 
 
 def write_root(root: pathlib.Path, extra_metrics=()) -> pathlib.Path:
@@ -69,9 +95,15 @@ def write_root(root: pathlib.Path, extra_metrics=()) -> pathlib.Path:
     real = json.loads((REPO / "BENCHMARK.json").read_text())
     (root / "benchmarks" / "configs").mkdir(parents=True)
     (root / "benchmarks" / "traffic").mkdir()
-    for name, cfg in CONFIGS.items():
-        (root / "benchmarks" / "configs" / f"{name}.json").write_text(
-            json.dumps(cfg))
+    (root / "benchmarks" / "reference").mkdir()
+    (root / PUBLISHED).mkdir(parents=True)
+    entries = [add_configuration(
+        root, name, cfg,
+        {k: v for k, v in cfg.items() if k not in NOT_SIZES})
+        for name, cfg in CONFIGS.items()]
+    entries.append(add_configuration(root, OTHER, *other_configuration()))
+    shutil.copy(OTHER_FILES / "reference.py",
+                root / "benchmarks" / "reference" / "biased.py")
     for name, mix in MIXES.items():
         (root / "benchmarks" / "traffic" / f"{name}.json").write_text(
             json.dumps(mix))
@@ -79,9 +111,7 @@ def write_root(root: pathlib.Path, extra_metrics=()) -> pathlib.Path:
     for tiny, (_, _, real_name) in CELLS.items():
         stands_for[real_name].append(tiny)
     bm = copy.deepcopy(real)
-    bm["configs"] = [
-        {"name": n, "source": "test", "reduced": [], "why": "test",
-         "file": f"benchmarks/configs/{n}.json"} for n in CONFIGS]
+    bm["configs"] = entries
     bm["workloads"] = [
         {"name": cell, "config": c, "traffic": t, "chips": 1, "why": "test"}
         for cell, (c, t, _) in CELLS.items()]
@@ -109,4 +139,9 @@ def run_cell(root, workload, capsys, monkeypatch, trace=0, seconds=0.5,
     rc = bench_run.main([
         "--workload", workload, "--seed", str(seed), "--seconds",
         str(seconds), "--trace", str(trace)], root=root, allow_cpu=True)
-    return rc, capsys.readouterr().out.strip().splitlines()
+    said = capsys.readouterr()
+    lines = said.out.strip().splitlines()
+    checks = [l for l in lines if l.startswith("check: ")]
+    if rc == 0:     # every compared number ends standard error too
+        assert said.err.strip().splitlines()[-len(checks):] == checks
+    return rc, lines

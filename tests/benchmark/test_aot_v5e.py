@@ -17,6 +17,16 @@ from jax.sharding import SingleDeviceSharding
 USABLE = 15.75 * 2 ** 30       # what a v5e chip reports of its 16 GiB
 
 
+def _serve_cells() -> list:
+    """Every cell of BENCHMARK.json whose traffic mix is of kind ``serve``
+    (small files read at collection; nothing here touches a device)."""
+    from benchmarks.harness.cell import BENCH, load_benchmark
+    from benchmarks.traffic.generator import load_mix
+
+    return [w["name"] for w in load_benchmark()["workloads"]
+            if load_mix(w["traffic"], BENCH / "traffic")["kind"] == "serve"]
+
+
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
@@ -51,20 +61,22 @@ def _abstract(tree, sharding):
         tree)
 
 
-@pytest.mark.parametrize("cell_name", ["mixtral-8x7b.serve-batch"])
+@pytest.mark.parametrize("cell_name", _serve_cells())
 def test_decode_and_widest_prefill_fit_the_chip(
         one_chip, compiled_kernels, cell_name):
-    from benchmarks.harness.cell import Cell, program_config
+    from benchmarks.harness.cell import Cell
     from benchmarks.kinds import serve
     from benchmarks.reference import weights
     from orion_tpu.infer import runner
     from orion_tpu.infer.kv_cache import init_cache, pages_per_seq
 
     cell = Cell.find(cell_name)
-    cfg = program_config(cell.config)
+    cfg = cell.program_config()
     mcfg, icfg = cfg.model, cfg.inference
+    spec = cell.reference().param_spec(cell.config)
     params = _abstract(jax.eval_shape(lambda: weights._draw(
-        cell.config, jnp.dtype(mcfg.param_dtype), jax.random.key(0))), one_chip)
+        spec, mcfg.n_layers, jnp.dtype(mcfg.param_dtype),
+        jax.random.key(0))), one_chip)
     cache = _abstract(jax.eval_shape(lambda: init_cache(mcfg, icfg)), one_chip)
     i32 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.int32, sharding=one_chip)
 

@@ -61,6 +61,8 @@ def test_attention_and_train_flops_by_hand():
 def test_bytes_by_hand():
     moe = dict(HF, num_local_experts=4)
     assert flops.expert_weight_bytes(moe) == 3 * 4 * 3 * 8 * 16 * 2
+    # 5 (token, expert) rows in each of 3 layers, three matmuls of 2*8*16
+    assert flops.expert_matmul_flops(moe, 5) == 3 * 5 * 3 * 2 * 8 * 16
 
 
 def test_mistral_masked_count_against_the_unmasked_one():

@@ -25,21 +25,63 @@ def test_top_level_keys_and_limits():
     assert runs * (BM["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
 
 
+# What ``reduced`` may cut, by the field of the program that the key sizes
+# (the key itself is named as the source names it): depth, the routed experts
+# held here, the rows of the vocabulary held here. Never a width.
+MAY_BE_REDUCED = {"n_layers", "n_experts", "vocab_size"}
+
+
+def assert_configuration_keeps_to_its_source(entry, cfg, published):
+    """One entry of ``configs``, its file and its source's keys as published.
+    No model's sizes are written here: each configuration is held to the
+    file of ITS source."""
+    from benchmarks.harness.cell import widths
+
+    assert set(entry) == {"name", "source", "file", "reduced", "why"}
+    assert NAME.match(entry["name"])
+    assert cfg["source"] == entry["source"] and "assumed" in cfg
+    reduced = cfg["reduced"]
+    assert reduced and reduced == entry["reduced"]
+    assert set(cfg.get("published", {})) == set(reduced)
+    for key, value in published.items():
+        if key in reduced:
+            # cut, and the file says from what
+            assert cfg[key] != value, key
+            assert cfg["published"][key] == value, key
+        else:
+            assert cfg[key] == value, key        # no width is ever cut
+    field_of = widths(cfg)
+    for key in reduced:
+        assert key in published, key
+        field = field_of.get(key)
+        assert field in MAY_BE_REDUCED, (key, field)
+        if field == "n_layers":
+            assert 1 <= cfg[key] < published[key]
+            continue
+        # a chip's share of a layer: the file says of how many chips
+        chips = cfg["deployment"]["chips_sharing_a_layer"]
+        assert isinstance(chips, int) and chips >= 2
+        assert cfg[key] < published[key] <= cfg[key] * chips, key
+        if field == "n_experts":
+            assert cfg[key] >= 8, key
+        if field == "vocab_size":
+            assert 8 * cfg[key] >= published[key], key
+
+
 def test_configs_and_cells():
+    from benchmarks.harness.cell import PUBLISHED
+
     names = [c["name"] for c in BM["configs"]]
     assert len(set(names)) == len(names)
     used = {w["config"] for w in BM["workloads"]}
     assert used == set(names)
+    files = [c["file"] for c in BM["configs"]]
+    assert len(set(files)) == len(files)
     for c in BM["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert NAME.match(c["name"]) and c["source"].startswith("https://")
-        cfg = json.loads((REPO / c["file"]).read_text())
-        assert c["reduced"] == cfg["reduced"] == ["num_hidden_layers"]
-        assert cfg["source"] == c["source"] and "assumed" in cfg
-        # widths as published (Mistral-7B / Mixtral-8x7B config.json)
-        assert (cfg["hidden_size"], cfg["intermediate_size"],
-                cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                cfg["vocab_size"]) == (4096, 14336, 32, 8, 32000)
+        assert c["source"].startswith("https://")
+        assert_configuration_keeps_to_its_source(
+            c, json.loads((REPO / c["file"]).read_text()),
+            json.loads((REPO / PUBLISHED / f"{c['name']}.json").read_text()))
     pairs = [(w["config"], w["traffic"]) for w in BM["workloads"]]
     assert len(set(pairs)) == len(pairs)
     four = [w for w in BM["workloads"] if w["chips"] == 4]
@@ -69,7 +111,9 @@ def test_metrics():
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
         assert m["moves"] in e2e
-        assert set(m.get("workloads", [])) <= cells
+        # every per-layer metric names its cells: a cell that a later PR
+        # adds gets the metrics it lists itself under, and no others
+        assert m["workloads"] and set(m["workloads"]) <= cells
         assert (REPO / "benchmarks" / "metrics" / f"{m['name']}.py").exists()
         layers.add(m["layer"])
     perf = (REPO / "PERF.md").read_text()
@@ -86,3 +130,56 @@ def test_every_cell_reports_setup_one_more_and_a_layer_metric(cell):
     assert c.per_layer
     for m in c.per_layer:
         assert hasattr(c.reader(m["name"]), "read")
+
+
+def test_the_configuration_that_is_not_mistral_keeps_to_its_source_too(
+        tiny_root):
+    from tests.benchmark.conftest import OTHER, other_configuration
+
+    bm = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    entry = next(c for c in bm["configs"] if c["name"] == OTHER)
+    cfg, published = other_configuration()
+    assert len(cfg["reduced"]) == 2 and cfg["reference"] != "model"
+    assert_configuration_keeps_to_its_source(entry, cfg, published)
+    # and the same assertions refuse a width that is cut, a cut the file
+    # does not own up to, and a share with no deployment behind it
+    for change in (lambda c: c.update(ffn_hidden_size=80),
+                   lambda c: c["published"].pop("padded_vocab_size"),
+                   lambda c: c.update(deployment="an eighth, say"),
+                   lambda c: c.update(padded_vocab_size=128),
+                   lambda c: c.update(reduced=c["reduced"] + ["kv_channels"],
+                                      kv_channels=16)):
+        bad = json.loads(json.dumps(cfg))
+        change(bad)
+        with pytest.raises((AssertionError, TypeError, KeyError)):
+            assert_configuration_keeps_to_its_source(
+                dict(entry, reduced=bad["reduced"]), bad, published)
+
+
+# What each cell reported before every per-layer metric named its cells
+# (``Cell.find`` at dc729ef), by name; the serve cell has since gained
+# ``prefill_expert_roofline.batch``.
+TRAIN_METRICS = {"compile_s", "train_step_ms.train", "train_mfu_pct.train",
+                 "flash_attn_roofline.train", "device_idle_pct.train",
+                 "compiles_in_window.train"}
+PER_LAYER = {
+    "mistral-7b.train-8k": TRAIN_METRICS,
+    "mistral-7b.train-8k-fsdp4": TRAIN_METRICS,
+    "mixtral-8x7b.serve-batch": {
+        "compile_s", "decode_step_ms.batch", "moe_ffn_roofline.batch",
+        "engine_host_ms_per_step.batch", "device_idle_pct.batch",
+        "prefill_share_pct.batch", "slot_occupancy_pct.batch",
+        "ttft_p50_ms.batch", "itl_p50_ms.batch", "compiles_in_window.batch",
+        "admit_ms_per_step.batch", "engine_gap_ms_per_step.batch",
+        "prefill_pad_pct.batch", "prefill_device_ms_per_ktoken.batch",
+        "paged_decode_roofline.batch", "idle_unattributed_pct.batch",
+        "prefill_expert_rows_per_token.batch",
+        "prefill_expert_roofline.batch"},
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PER_LAYER))
+def test_a_cell_reports_the_metrics_it_reported(cell):
+    from benchmarks.harness.cell import Cell
+
+    assert {m["name"] for m in Cell.find(cell).per_layer} == PER_LAYER[cell]

@@ -5,12 +5,15 @@ import json
 
 import pytest
 
-from tests.benchmark.conftest import run_cell, write_root
+from tests.benchmark.conftest import (OTHER, PUBLISHED, add_configuration,
+                                      other_configuration, run_cell,
+                                      write_root)
 
 KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
-@pytest.mark.parametrize("workload", ["tiny.train", "tiny.dense-batch", "tiny.batch"])
+@pytest.mark.parametrize("workload", ["tiny.train", "tiny.dense-batch",
+                                      "tiny.batch", "tiny.other-batch"])
 @pytest.mark.parametrize("trace", [0, 1])
 def test_each_kind_runs_and_prints_the_contract_line(
         tiny_root, capsys, monkeypatch, workload, trace):
@@ -58,9 +61,10 @@ def test_an_unknown_workload_is_refused(tiny_root):
 
 def test_configuration_mix_and_metric_are_added_as_new_files_only(
         tmp_path, capsys, monkeypatch):
-    """A later PR's cell: one configuration file, one traffic file, one
-    reader module and entries in BENCHMARK.json; no file that is there is
-    edited, and the harness finds all three by name."""
+    """A later PR's cell: one configuration file with its source's published
+    keys, one traffic file, one reader module and entries in BENCHMARK.json;
+    no file that is there is edited, and the harness finds all of them by
+    name."""
     dummy = {"name": "requests_in_window.dummy", "unit": "count",
              "better": "higher", "source": "program_counter",
              "layer": "benchmark", "moves": "serve_tokens_per_s",
@@ -68,9 +72,11 @@ def test_configuration_mix_and_metric_are_added_as_new_files_only(
     root = write_root(tmp_path / "root")
     bench = root / "benchmarks"
     cfg = json.loads((bench / "configs" / "tiny-serve.json").read_text())
-    cfg["num_hidden_layers"] = 1
+    published = json.loads((root / PUBLISHED / "tiny-serve.json").read_text())
+    cfg.update(num_hidden_layers=1, reduced=["num_hidden_layers"],
+               published={"num_hidden_layers": 2})
     cfg["orion"]["overrides"].append("model.n_layers=1")
-    (bench / "configs" / "dummy-config.json").write_text(json.dumps(cfg))
+    entry = add_configuration(root, "dummy-config", cfg, published)
     mix = json.loads((bench / "traffic" / "tiny-batch.json").read_text())
     mix["clients"] = 2
     (bench / "traffic" / "dummy-mix.json").write_text(json.dumps(mix))
@@ -78,9 +84,7 @@ def test_configuration_mix_and_metric_are_added_as_new_files_only(
     (bench / "metrics" / "requests_in_window.dummy.py").write_text(
         "def read(obs):\n    return obs['requests']\n")
     bm = json.loads((root / "BENCHMARK.json").read_text())
-    bm["configs"].append({"name": "dummy-config", "source": "test",
-                          "file": "benchmarks/configs/dummy-config.json",
-                          "reduced": ["num_hidden_layers"], "why": "test"})
+    bm["configs"].append(entry)
     bm["workloads"].append({"name": "dummy.cell", "config": "dummy-config",
                             "traffic": "dummy-mix", "chips": 1, "why": "t"})
     for m in bm["end_to_end"]:
@@ -101,3 +105,48 @@ def test_a_configuration_that_disagrees_with_the_program_is_refused(tiny_root):
     bad = dict(CONFIGS["tiny-serve"], hidden_size=128)
     with pytest.raises(SystemExit, match="hidden_size"):
         program_config(bad)
+
+
+def _other_root(tmp_path, change):
+    """The tiny root with the configuration that is not Mistral changed by
+    ``change(cfg, published)`` before its files are written."""
+    root = write_root(tmp_path / "root")
+    cfg, published = other_configuration()
+    change(cfg, published)
+    add_configuration(root, OTHER, cfg, published)
+    return root
+
+
+def _wider(cfg, published):
+    """A width cut: the file and the program agree on 192, the source says
+    160, and ``reduced`` does not (and may not) list it."""
+    cfg["ffn_hidden_size"] = 192
+    cfg["orion"]["overrides"].append("model.d_ff=192")
+
+
+def _one_more_size(cfg, published):
+    """A size the source publishes and nothing checks."""
+    published["conv_kernel"] = cfg["conv_kernel"] = 4
+
+
+def _depth_as_published_unstated(cfg, published):
+    """A reduced key whose published value the file does not state."""
+    del cfg["published"]["num_layers"]
+
+
+@pytest.mark.parametrize("change, key", [
+    (_wider, "ffn_hidden_size"), (_one_more_size, "conv_kernel"),
+    (_depth_as_published_unstated, "num_layers")])
+def test_a_configuration_that_leaves_its_source_is_refused_by_the_key(
+        tmp_path, capsys, monkeypatch, change, key):
+    root = _other_root(tmp_path, change)
+    with pytest.raises(SystemExit, match=key):
+        run_cell(root, "tiny.other-batch", capsys, monkeypatch)
+
+
+def test_without_its_published_keys_a_configuration_does_not_run(
+        tmp_path, capsys, monkeypatch):
+    root = write_root(tmp_path / "root")
+    (root / PUBLISHED / f"{OTHER}.json").unlink()
+    with pytest.raises(SystemExit, match=f"{OTHER}.json"):
+        run_cell(root, "tiny.other-batch", capsys, monkeypatch)

@@ -1,6 +1,8 @@
 """The plain reference against the program at a tiny size in float32, the
 control that has to fail, and the layout of the benchmark-made weights."""
 
+import dataclasses
+import hashlib
 import json
 
 import jax
@@ -9,7 +11,9 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import model, weights
-from tests.benchmark.conftest import CONFIGS, REPO
+from tests.benchmark.conftest import CELLS, CONFIGS, OTHER, REPO
+
+BM = json.loads((REPO / "BENCHMARK.json").read_text())
 
 
 def _program(cfg_name):
@@ -18,21 +22,36 @@ def _program(cfg_name):
     return program_config(CONFIGS[cfg_name]).model
 
 
-@pytest.mark.parametrize("cfg_name", ["tiny-serve", "tiny-moe-serve"])
-def test_reference_matches_the_program_and_the_control_does_not(cfg_name):
+def _params(hf, dtype, seed):
+    """Weights of a tiny Mistral-shaped configuration (``reference/model.py``)."""
+    return weights.make_params(model.param_spec(hf), hf["num_hidden_layers"],
+                               dtype, seed)
+
+
+@pytest.mark.parametrize("workload", ["tiny.dense-batch", "tiny.batch",
+                                      "tiny.other-batch"])
+def test_reference_matches_the_program_and_the_control_does_not(
+        tiny_root, workload):
+    """Each configuration through the reference ITS file names, found as the
+    harness finds it."""
+    from benchmarks.harness.cell import Cell
     from orion_tpu.models.transformer import forward
 
-    hf, m = CONFIGS[cfg_name], _program(cfg_name)
-    p = weights.make_params(hf, "float32", 2 ** 31 + 5)
-    toks = jax.random.randint(jax.random.key(1), (1, 48), 1, hf["vocab_size"])
-    got, _ = forward(p, toks, m)
+    cell = Cell.find(workload, root=tiny_root)
+    hf, ref, cfg = cell.config, cell.reference(), cell.program_config()
+    assert (ref.__name__ == "bench_reference_model") == (
+        cell.config_name != OTHER)
+    p = weights.for_cell(cell, cfg, 2 ** 31 + 5)
+    toks = jax.random.randint(jax.random.key(1), (1, 48), 1,
+                              cfg.model.vocab_size)
+    got, _ = forward(p, toks, cfg.model)
     at = jnp.arange(48)
-    want, margin = model.logits_at(p, toks[0], at, hf)
+    want, margin = ref.logits_at(p, toks[0], at, hf)
     assert margin.shape == (48,) and bool(jnp.all(margin >= 0))
     assert bool(jnp.all(jnp.isinf(margin))) == ("num_local_experts" not in hf)
     err = float(jnp.linalg.norm(got[0] - want) / jnp.linalg.norm(want))
     assert err < 1e-5
-    low = model.logits_at(p, toks[0], at, hf, quant="int8")[0]
+    low = ref.logits_at(p, toks[0], at, hf, quant="int8")[0]
     control = float(jnp.linalg.norm(low - want) / jnp.linalg.norm(want))
     limit = hf["correct"]["limits"]["logit_rel_err_worst_probe_median_clear"]
     assert control > 3 * limit > 3 * err
@@ -40,7 +59,7 @@ def test_reference_matches_the_program_and_the_control_does_not(cfg_name):
 
 def test_a_sliding_window_is_honoured():
     hf = dict(CONFIGS["tiny-serve"], sliding_window=8)
-    p = weights.make_params(hf, "float32", 3)
+    p = _params(hf, "float32", 3)
     toks = jax.random.randint(jax.random.key(2), (40,), 1, 256)
     at = jnp.asarray([39])
     windowed = model.logits_at(p, toks, at, hf)[0]
@@ -57,7 +76,7 @@ def test_training_loss_and_gradients_match_the_program():
     from orion_tpu.models.transformer import loss_fn
 
     hf, m = CONFIGS["tiny-train"], _program("tiny-train")
-    p = weights.make_params(hf, "float32", 11)
+    p = _params(hf, "float32", 11)
     seq = jax.random.randint(jax.random.key(3), (2, 33), 1, 256)
     batch = {"inputs": seq[:, :-1], "targets": seq[:, 1:]}
     l_ref, g_ref = jax.value_and_grad(
@@ -75,19 +94,30 @@ def test_training_loss_and_gradients_match_the_program():
     assert worst > 3 * hf["correct"]["limits"]["grad_rel_err_max"]
 
 
-@pytest.mark.parametrize("name", [
-    "mistral-7b-train-1chip", "mistral-7b-train-4chip",
-    "mixtral-8x7b-serve-1chip"])
+def _cell_of(config_name):
+    return next(w["name"] for w in BM["workloads"]
+                if w["config"] == config_name)
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BM["configs"]])
 def test_real_configurations_resolve_and_the_weights_fit_the_program(name):
-    """Shapes only: the tree the benchmark draws is the tree the program's
-    own init would make, at the published widths."""
-    from benchmarks.harness.cell import program_config
+    """Shapes only: the tree the benchmark draws, from the spec of the
+    configuration's own reference, is the tree the program's own init would
+    make, at the published widths; and the checks of ``program_config`` leave
+    the program's Config as the file's preset and overrides give it."""
+    from benchmarks.harness.cell import Cell
+    from orion_tpu.config import get_config
     from orion_tpu.models import init_params
 
-    hf = json.loads((REPO / "benchmarks" / "configs" / f"{name}.json").read_text())
-    cfg = program_config(hf)          # checks every published size
+    cell = Cell.find(_cell_of(name))
+    cfg = cell.program_config()       # checks every published size
+    o = cell.config["orion"]
+    assert dataclasses.asdict(cfg) == dataclasses.asdict(
+        get_config(o["preset"], list(o["overrides"])))
+    spec = cell.reference().param_spec(cell.config)
     mine = jax.eval_shape(lambda: weights._draw(
-        hf, jnp.dtype(cfg.model.param_dtype), jax.random.key(0)))
+        spec, cfg.model.n_layers, jnp.dtype(cfg.model.param_dtype),
+        jax.random.key(0)))
     theirs = jax.eval_shape(lambda: init_params(cfg.model, jax.random.key(0)))
     assert jax.tree.structure(mine) == jax.tree.structure(theirs)
     for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(theirs)):
@@ -96,11 +126,57 @@ def test_real_configurations_resolve_and_the_weights_fit_the_program(name):
         assert cfg.model.capacity_factor == 4.0      # dropless
 
 
+def test_the_tree_of_the_configuration_that_is_not_mistral_fits_the_program(
+        tiny_root):
+    from benchmarks.harness.cell import Cell
+    from orion_tpu.models import init_params
+
+    cell = Cell.find("tiny.other-batch", root=tiny_root)
+    cfg = cell.program_config()
+    mine = weights.for_cell(cell, cfg, 1)
+    theirs = init_params(cfg.model, jax.random.key(0))
+    assert jax.tree.structure(mine) == jax.tree.structure(theirs)
+    assert "bq" in mine["blocks"]["attn"] and "bo" not in mine["blocks"]["attn"]
+    for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(theirs)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+
+
+# sha256 over every leaf's path and bytes, as ``weights.make_params`` drew
+# them at dc729ef (the parent of the PR that moved ``param_spec`` into the
+# reference): the refactor may not move one bit of any cell's weights.
+DRAWN_AT_THE_PARENT = {
+    ("tiny-serve", "float32", 2 ** 31 + 9):
+        "4ec4165377e17e6cd59157abc28e10b32e694e39f3a7dce66d39370164d0f431",
+    ("tiny-serve", "bfloat16", 7):
+        "1f3724343c3a99b85c0b3400f8bbd8a072b00f3a824579065221c00de9dfee42",
+    ("tiny-moe-serve", "float32", 2 ** 31 + 9):
+        "7b6111a59afa5f7a4d38343298308d3c1f94828a77cb4dc0759d16749cbcec13",
+    ("tiny-moe-serve", "bfloat16", 7):
+        "59a5deef5a7d400b784d48eff858a576a03001daaa4d7fd87ee8d103013d0fcd",
+}
+
+
+@pytest.mark.parametrize("cfg_name, dtype, seed", sorted(DRAWN_AT_THE_PARENT))
+def test_the_weights_are_bitwise_those_of_the_parent(tiny_root, cfg_name,
+                                                     dtype, seed):
+    from benchmarks.harness.cell import Cell
+
+    cell = Cell.find(next(c for c, v in CELLS.items() if v[0] == cfg_name),
+                     root=tiny_root)
+    p = weights.make_params(cell.reference().param_spec(cell.config),
+                            cell.config["num_hidden_layers"], dtype, seed)
+    digest = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(p)[0]:
+        digest.update(jax.tree_util.keystr(path).encode())
+        digest.update(np.asarray(leaf).tobytes())
+    assert digest.hexdigest() == DRAWN_AT_THE_PARENT[cfg_name, dtype, seed]
+
+
 def test_the_same_seed_gives_the_same_weights():
     hf = CONFIGS["tiny-serve"]
-    a = weights.make_params(hf, "float32", 2 ** 31 + 9)
-    b = weights.make_params(hf, "float32", 2 ** 31 + 9)
-    c = weights.make_params(hf, "float32", 2 ** 31 + 10)
+    a = _params(hf, "float32", 2 ** 31 + 9)
+    b = _params(hf, "float32", 2 ** 31 + 9)
+    c = _params(hf, "float32", 2 ** 31 + 10)
     assert all(bool(jnp.array_equal(x, y)) for x, y in
                zip(jax.tree.leaves(a), jax.tree.leaves(b)))
     assert not bool(jnp.array_equal(a["lm_head"], c["lm_head"]))

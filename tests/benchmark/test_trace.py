@@ -87,3 +87,56 @@ def test_recorded_v5e_trace():
     for name, seconds in out["breakdown"]["idle_gaps"]:
         assert seconds > 0
         assert name in reduce.BENCH_SPANS or name.startswith("host:")
+
+
+@pytest.mark.parametrize("modules, want", [
+    # today: the engine jits partials, every program is "unknown"; the decode
+    # window is the one that ran most often
+    ({"jit__unknown(1)": (0.9, 3), "jit__unknown(2)": (4.0, 20),
+      "jit__argmax(3)": (0.1, 40)}, (4.0, 20)),
+    # once the program names its jits: the name wins over the count, and
+    # two fingerprints of that name are one program
+    ({"jit__unknown(1)": (0.9, 30), "jit_decode_window(2)": (4.0, 20),
+      "jit_decode_window(7)": (1.0, 5), "jit_prefill_step(3)": (2.0, 9)},
+     (5.0, 25)),
+    ({"jit__argmax(3)": (0.1, 40)}, None),
+])
+def test_the_decode_program_is_found_by_its_name_or_by_its_count(modules, want):
+    from benchmarks.metrics import lib
+
+    obs = {"decode_window": 8, "trace": {
+        "module_s": {k: s for k, (s, _) in modules.items()},
+        "module_n": {k: n for k, (_, n) in modules.items()}}}
+    assert lib.decode_program(obs) == want
+    if want:
+        assert lib.decode_step_ms(obs) == pytest.approx(
+            1e3 * want[0] / (want[1] * 8))
+    assert lib.decode_program({"trace": None}) is None
+
+
+def test_prefill_expert_roofline_on_the_recorded_gmm_operations():
+    """The arithmetic, by hand, on what a v5e recorded: 51,050 routed rows a
+    layer x 4 layers x 6 D F over 197 TFLOP/s is 0.3652 s of work at peak;
+    the 288 grouped matmuls of the segment took 0.7679 s."""
+    from benchmarks.harness.cell import Cell
+    from benchmarks.harness.device import PEAKS
+
+    cell = Cell.find("mixtral-8x7b.serve-batch")
+    rec = json.loads(
+        (REPO / "tests/benchmark/data/trace_prefill_gmm_v5e.json").read_text())
+    tr = reduce.reduce(rec, rec["window_s"])
+    tr["timing"] = rec["timing"]
+    obs = {"trace": tr, "config": cell.config, "peaks": PEAKS["TPU v5 lite"]}
+    read = cell.reader("prefill_expert_roofline.batch").read
+    got = read(obs)
+    seconds = sum(d for _, _, d in rec["devices"]["0"]["XLA Ops"]) / 1e9
+    assert len(rec["devices"]["0"]["XLA Ops"]) == 288
+    assert seconds == pytest.approx(0.767931238)
+    flop = 4 * 51050 * 6 * 4096 * 14336
+    assert got == pytest.approx(100 * flop / 197e12 / seconds)
+    assert got == pytest.approx(rec["read"]) and 40 < got < 100
+    # nothing to read: no trace, no counter (a program before PR 26), no
+    # kernel of that name in the segment
+    assert read(dict(obs, trace=None)) is None
+    assert read(dict(obs, trace=dict(tr, timing={}))) is None
+    assert read(dict(obs, trace=dict(tr, op_s={"fusion.1": 1.0}))) is None
