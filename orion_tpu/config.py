@@ -22,6 +22,69 @@ from typing import Any, Callable, Mapping, Optional, Sequence, Tuple
 
 
 @dataclass(frozen=True)
+class RopeConfig:
+    """One rotary table; a layer kind selects it (``ops.rope.rope_table``
+    computes the frequencies). The defaults are the plain table."""
+
+    theta: float = 500_000.0
+    # Leading share of each head that rotates (partial_rotary_factor); the
+    # other dims pass through.
+    rotary_fraction: float = 1.0
+    # YaRN (rope_type "yarn"): context extension factor, None = off. The
+    # frequencies blend 1/f and 1/(factor f) by the linear ramp between the
+    # correction dims of beta_fast / beta_slow at yarn_original_max_pos.
+    yarn_factor: Optional[float] = None
+    yarn_original_max_pos: int = 8192
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    # cos and sin are multiplied by it (YaRN's attention_factor).
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if not 0.0 < self.rotary_fraction <= 1.0:
+            raise ValueError(
+                f"rotary_fraction={self.rotary_fraction} must lie in (0, 1]")
+        if self.theta <= 1.0 or (
+                self.yarn_factor is not None and self.yarn_factor < 1.0):
+            raise ValueError(
+                f"rope theta={self.theta} must exceed 1 and "
+                f"yarn_factor={self.yarn_factor} be None or at least 1")
+
+    @property
+    def is_plain(self) -> bool:
+        return (self.rotary_fraction == 1.0 and self.yarn_factor is None
+                and self.attention_factor == 1.0)
+
+
+class LayerKind(typing.NamedTuple):
+    """What is static about one layer: every kernel and every weight shape
+    of the layer follows from it."""
+
+    window: Optional[int]       # sliding window (None = full attention)
+    n_heads: int                # query heads
+    rope: RopeConfig
+    moe: bool                   # sparse feed-forward (else dense, d_ff wide)
+
+
+class LayerPlan(typing.NamedTuple):
+    """The layer program of a model whose layers differ in shape: ``lead``
+    layers of their own, then ``repeats`` periods of ``period`` layers and a
+    tail of the period's first ``tail`` positions. Position j of the period
+    has its own stacked leaves, ``counts[j]`` = repeats (+ 1 under the tail)
+    layers deep; layer ``lead + g * period + j`` is entry g of stack j."""
+
+    lead: int
+    period: int
+    repeats: int
+    tail: int
+
+    @property
+    def counts(self) -> Tuple[int, ...]:
+        return tuple(self.repeats + (j < self.tail)
+                     for j in range(self.period))
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Architecture of a decoder-only transformer.
 
@@ -66,6 +129,22 @@ class ModelConfig:
     # With a pattern, serving keeps FULL-context pages (global layers read
     # the whole history), so only the attention masks are windowed.
     sliding_window_pattern: Optional[int] = None
+    # The attention type of every layer as the source publishes it
+    # ("full_attention" | "sliding_attention"); the first n_layers entries
+    # are read, so a depth cut keeps the published list. Not with
+    # sliding_window_pattern, which is another way of writing such a list
+    # (``layer_kinds`` is the one list either way).
+    layer_types: Optional[Tuple[str, ...]] = None
+    # Query heads of every layer, where layers differ (first n_layers
+    # entries read); None => n_heads everywhere.
+    n_heads_per_layer: Optional[Tuple[int, ...]] = None
+    # Rotary tables by attention type; None => the plain table at
+    # rope_theta (rope_sliding: None => rope_full's).
+    rope_full: Optional[RopeConfig] = None
+    rope_sliding: Optional[RopeConfig] = None
+    # "per-head": each head's attention output is multiplied by a sigmoid
+    # gate computed from the layer's normed input (attn.wg [D, heads]).
+    attn_gate: Optional[str] = None
     # Gemma-family block/embedding details:
     post_norms: bool = False          # extra norms AFTER attention and MLP
     norm_scale_plus_one: bool = False  # rmsnorm multiplies by (1 + w)
@@ -88,6 +167,25 @@ class ModelConfig:
     # einsum), "sorted_a2a" (sorted + explicit shard_map all_to_all on ep;
     # per-slice overflow drops; not composable with pp).
     moe_dispatch: str = "sorted"
+    # Leading layers whose feed-forward is dense (d_ff wide) before the
+    # sparse stack (mlp_only_layers).
+    n_dense_layers: int = 0
+    # Width of a routed expert (None => d_ff) and of the shared expert
+    # added, ungated, beside the routed ones (0 => none).
+    moe_d_ff: Optional[int] = None
+    shared_expert_d_ff: int = 0
+    # The renormalised top-k gates are multiplied by it
+    # (moe_routed_scaling_factor).
+    router_scale: float = 1.0
+    # An expert layer that holds a SHARE of the experts and routes over all
+    # of them: router_width is the router's outputs (None => n_experts, the
+    # whole layer is here), n_experts stays the number of expert matrices
+    # on this device, and they are experts [expert_offset, expert_offset +
+    # n_experts). The layer computes its own experts' part of the result
+    # (gates renormalised over all chosen experts, held or not) plus the
+    # shared expert; nothing stands in for the absent ones.
+    router_width: Optional[int] = None
+    expert_offset: int = 0
 
     # Numerics.
     dtype: str = "bfloat16"         # activation / weight compute dtype
@@ -218,6 +316,19 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def resolved_router_width(self) -> int:
+        return (self.n_experts if self.router_width is None
+                else self.router_width)
+
+    @property
+    def holds_expert_share(self) -> bool:
+        return self.is_moe and self.resolved_router_width != self.n_experts
+
+    @property
+    def resolved_moe_d_ff(self) -> int:
+        return self.d_ff if self.moe_d_ff is None else self.moe_d_ff
+
+    @property
     def resolved_attn_out_bias(self) -> bool:
         return (
             self.attn_bias
@@ -248,20 +359,83 @@ class ModelConfig:
         pattern. Must divide n_layers (checked where the scan is built)."""
         return self.scan_group * (self.window_pattern or 1)
 
-    def layer_window(self, layer: int) -> Optional[int]:
-        """The sliding window for a given layer index (None = global).
+    @property
+    def layer_kinds(self) -> Tuple[LayerKind, ...]:
+        """The kind of every layer: THE per-layer list. ``layer_types``
+        writes its attention types out; ``sliding_window_pattern`` is the
+        arithmetic form (layers l % pattern != pattern - 1 windowed, the
+        Gemma-family interleave); with neither, the window is every
+        layer's."""
+        L, p = self.n_layers, self.sliding_window_pattern
+        if self.layer_types is not None and p is not None:
+            raise ValueError(
+                "model.layer_types and model.sliding_window_pattern both "
+                "write the per-layer list: set one")
+        for name in ("layer_types", "n_heads_per_layer"):
+            got = getattr(self, name)
+            if got is not None and len(got) < L:
+                raise ValueError(
+                    f"model.{name} has {len(got)} entries for "
+                    f"n_layers={L}")
+        full = self.rope_full or RopeConfig(theta=self.rope_theta)
+        sliding = self.rope_sliding or full
+        kinds = []
+        for l in range(L):
+            if self.layer_types is not None:
+                if self.layer_types[l] not in (
+                        "full_attention", "sliding_attention"):
+                    raise ValueError(
+                        f"model.layer_types[{l}]={self.layer_types[l]!r}; "
+                        f"full_attention|sliding_attention")
+                windowed = self.layer_types[l] == "sliding_attention"
+            else:
+                windowed = p is None or l % p != p - 1
+            windowed = windowed and self.sliding_window is not None
+            kinds.append(LayerKind(
+                window=self.sliding_window if windowed else None,
+                n_heads=(self.n_heads if self.n_heads_per_layer is None
+                         else self.n_heads_per_layer[l]),
+                rope=sliding if windowed else full,
+                moe=self.is_moe and l >= self.n_dense_layers,
+            ))
+        return tuple(kinds)
 
-        With sliding_window_pattern, only layers l % pattern != pattern-1
-        are windowed (Gemma-family local/global interleave); the argument
-        must be a PYTHON int (the window is static in every kernel), so
-        layer scans group layers by pattern position.
-        """
-        if self.sliding_window is None:
+    def layer_kind(self, layer: int) -> LayerKind:
+        """``layer`` must be a PYTHON int (a kind is static in every
+        kernel and weight shape); layer scans call their body once per
+        static position and pass a layer of that kind."""
+        return self.layer_kinds[layer]
+
+    def layer_window(self, layer: int) -> Optional[int]:
+        """The sliding window for a given layer index (None = global)."""
+        return self.layer_kind(layer).window
+
+    @property
+    def page_window(self) -> Optional[int]:
+        """The window the page allocator may free behind: the sliding
+        window where EVERY layer is windowed, else None (one full layer
+        reads the whole history, and pages are shared by all layers)."""
+        windows = {k.window for k in self.layer_kinds}
+        return self.sliding_window if windows == {self.sliding_window} else None
+
+    @property
+    def layer_plan(self) -> Optional[LayerPlan]:
+        """None for a model whose layers share one stacked leaf per weight
+        (every model of one head count, one rotary table and one
+        feed-forward kind: the layer scan and ``window_pattern`` serve it,
+        parameter tree and programs as they always were). Else the leading
+        dense layers, the smallest period of what follows, and its tail."""
+        if (self.layer_types is None and self.n_heads_per_layer is None
+                and self.n_dense_layers == 0):
             return None
-        p = self.sliding_window_pattern
-        if p is None or layer % p != p - 1:
-            return self.sliding_window
-        return None
+        kinds = self.layer_kinds
+        lead = min(self.n_dense_layers, self.n_layers)
+        rest = kinds[lead:]
+        period = next(
+            (p for p in range(1, len(rest) + 1)
+             if all(rest[i] == rest[i % p] for i in range(len(rest)))), 1)
+        return LayerPlan(lead, period, len(rest) // period,
+                         len(rest) % period)
 
     def num_params(self) -> int:
         """Approximate parameter count (embeddings + blocks + norms)."""
@@ -1576,6 +1750,39 @@ def _mixtral_model(**kw) -> ModelConfig:
     return ModelConfig(**base)
 
 
+def _laguna_model(**kw) -> ModelConfig:
+    """Laguna-S-2.1 (poolside, config.json): 48 layers, the first dense and
+    full, then (window, window, window, full) periods; 48 query heads in
+    full layers and 72 in window layers over 8 KV heads; YaRN on half of
+    each head in full layers, plain theta 1e4 in window layers; a per-head
+    sigmoid gate on the attention output; 256 experts 1024 wide, top-10,
+    gates renormalised and scaled by 2.5, and a shared expert."""
+    types = ("full_attention",) + ("sliding_attention",) * 3
+    base = dict(
+        name="laguna-s-2.1", vocab_size=100352, max_seq_len=4096,
+        d_model=3072, n_layers=48, n_heads=48, n_kv_heads=8, head_dim=128,
+        d_ff=12288, pos_embedding="rope", norm="rmsnorm", norm_eps=1e-6,
+        activation="swiglu", tie_embeddings=False, sliding_window=512,
+        layer_types=types * 12,
+        n_heads_per_layer=(48, 72, 72, 72) * 12,
+        rope_full=RopeConfig(
+            theta=500_000.0, rotary_fraction=0.5, yarn_factor=128.0,
+            yarn_original_max_pos=8192, yarn_beta_fast=32.0,
+            yarn_beta_slow=1.0, attention_factor=1.4852030263919618),
+        rope_sliding=RopeConfig(theta=10_000.0),
+        attn_gate="per-head",
+        n_experts=256, router_width=256, n_experts_per_token=10,
+        n_dense_layers=1, moe_d_ff=1024, shared_expert_d_ff=1024,
+        router_scale=2.5,
+        # Dropless: an expert's capacity is at least the row length from
+        # router_width / top-k = 25.6 up (the published model drops none).
+        capacity_factor=26.0,
+        dtype="bfloat16", kernels="xla", remat="full",
+    )
+    base.update(kw)
+    return ModelConfig(**base)
+
+
 @register_preset("gpt2-125m")
 def _p_gpt2() -> Config:
     """Baseline config 1: GPT-2 125M single-device CPU-runnable smoke test."""
@@ -1777,6 +1984,45 @@ def _p_tiny_gemma2() -> Config:
         data=DataConfig(batch_size=4, seq_len=64),
         optimizer=OptimizerConfig(learning_rate=1e-3, warmup_steps=5),
         train=TrainConfig(num_steps=20, log_interval=5),
+    )
+
+
+@register_preset("laguna-s-2.1")
+def _p_laguna() -> Config:
+    """Laguna-S-2.1 at its published sizes, for serving (a deployment
+    holds a share: model.n_experts / model.expert_offset, the vocabulary
+    and the depth its chips hold)."""
+    return Config(
+        model=_laguna_model(),
+        inference=InferenceConfig(max_seq_len=4096, page_size=64),
+    )
+
+
+@register_preset("tiny-laguna")
+def _p_tiny_laguna() -> Config:
+    """Tiny Laguna-family model for CPU tests: a dense lead layer, one
+    period of (window x3, full) and one layer more, so the period's end and
+    the tail are crossed; 6 and 4 query heads over 2 KV heads (groups 3 and
+    2); 16 experts top-4 with a shared one; YaRN on half of each head in
+    full layers; a window shorter than the test prompts."""
+    types = ("full_attention",) + ("sliding_attention",) * 3
+    return Config(
+        model=_laguna_model(
+            name="tiny-laguna", vocab_size=256, max_seq_len=128, d_model=64,
+            n_layers=6, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
+            sliding_window=8, layer_types=types * 2,
+            n_heads_per_layer=(4, 6, 6, 6) * 2,
+            rope_full=RopeConfig(
+                theta=10_000.0, rotary_fraction=0.5, yarn_factor=8.0,
+                yarn_original_max_pos=16, yarn_beta_fast=4.0,
+                yarn_beta_slow=1.0, attention_factor=1.2),
+            n_experts=16, router_width=16, n_experts_per_token=4,
+            moe_d_ff=32, shared_expert_d_ff=32, capacity_factor=4.0,
+            dtype="float32", remat="none"),
+        data=DataConfig(batch_size=4, seq_len=64),
+        inference=InferenceConfig(max_seq_len=128, page_size=8,
+                                  num_pages=128, max_batch_size=4,
+                                  prefill_chunk=16),
     )
 
 

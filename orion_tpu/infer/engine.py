@@ -19,6 +19,7 @@ registry gauges, ``prefix_match_tokens``) and nothing deeper.
 
 from __future__ import annotations
 
+import collections
 import itertools
 import logging
 import time
@@ -129,6 +130,23 @@ class InferenceEngine:
         self.cfg = cfg
         self.mcfg = cfg.model
         self.icfg = cfg.inference
+        if self.mcfg.layer_plan is not None:
+            # Layers of different shapes (head counts, a dense lead, a
+            # share of the experts) are computed by prefill and the decode
+            # window; the verify and mixed bodies share their functions but
+            # nothing compares them on such a model: refuse, by name.
+            off = [name for name, on in (
+                ("inference.speculative", self.icfg.speculative),
+                ("inference.constrained", self.icfg.constrained),
+                ("inference.chunked_prefill", self.icfg.chunked_prefill),
+                ("model.weight_quant", self.mcfg.weight_quant),
+            ) if on]
+            if off:
+                raise ValueError(
+                    f"model {self.mcfg.name!r} has layers of different "
+                    f"shapes (model.layer_types / n_heads_per_layer / "
+                    f"n_dense_layers) and is served by whole-prompt prefill "
+                    f"and the decode window only: unset {', '.join(off)}")
         if self.mcfg.weight_quant == "int8":
             from orion_tpu.models.quantize import quantize_params
 
@@ -313,14 +331,19 @@ class InferenceEngine:
         self._key = jax.random.key(seed)
         self.preemptions = 0
         # Page-management window: with interleaved local/global layers
-        # (sliding_window_pattern) the GLOBAL layers read the whole
+        # (model.layer_kinds) the GLOBAL layers read the whole
         # history, so pages never die and rolling/dead-on-arrival page
         # logic must treat the model as unwindowed; only the attention
         # masks are per-layer windowed (runner/cfg.layer_window).
-        self.page_window = (
-            self.mcfg.sliding_window
-            if self.mcfg.sliding_window_pattern is None else None
-        )
+        self.page_window = self.mcfg.page_window
+        # What a window-aware allocator would know (the counters
+        # kv_dead_window_page_layers / kv_live_page_layers): how many
+        # layers read only their window of a context whose pages all stay.
+        self._layers_by_window = collections.Counter(
+            k.window for k in self.mcfg.layer_kinds)
+        self._window_layers = (
+            0 if self.page_window is not None
+            else self.mcfg.n_layers - self._layers_by_window[None])
         # Decode window: mutable engine state (inference.decode_window is
         # only the starting point when auto-tune is on). Page provisioning
         # and admission always budget for _provision_window, so growth can
@@ -1324,10 +1347,24 @@ class InferenceEngine:
             # dropless grouped path, E per dispatched position on the
             # capacity buckets); 0 for a dense model.
             "prefill_expert_rows": 0,
+            # Of those rows, summed over the sparse layers, the ones on
+            # experts held here, counted by the prefill program itself
+            # (runner.HELD_ROWS); 0 unless model.router_width says the
+            # device holds a share.
+            "prefill_held_expert_rows": 0,
             # What the paged decode kernel had to read: over every token
             # step of every decode window, the live slots' context
             # lengths (bounded by the sliding window where there is one).
             "decode_kv_tokens": 0,
+            # The same summed over the layers, each by its own kind: a
+            # full layer reads a slot's length, a window layer its window
+            # at most.
+            "decode_kv_token_layers": 0,
+            # At each decode window, over the live slots: pages x layers
+            # the pool holds for them, and of those the ones lying wholly
+            # behind a window layer's window (a model that mixes window
+            # and full layers keeps every page for every layer).
+            "kv_live_page_layers": 0, "kv_dead_window_page_layers": 0,
             # Per-phase device split (ISSUE 20 load-gauge satellite):
             # decode_device_s covers pure decode-phase dispatches
             # (decode windows, verify, draft compaction) and pairs with
@@ -1372,7 +1409,9 @@ class InferenceEngine:
         first-token sample), host_s (scheduler remainder), the leaf
         ``<phase>_s`` keys those three are sums of (_zero_timing), the
         prefill_dispatches/prefill_tokens/prefill_pad_tokens/
-        prefill_expert_rows and decode_kv_tokens sizing counters,
+        prefill_expert_rows/prefill_held_expert_rows, decode_kv_tokens/
+        decode_kv_token_layers and kv_live_page_layers/
+        kv_dead_window_page_layers sizing counters,
         windows/steps counters, the slot_steps/wasted_steps
         decode-waste tally, the mixed_steps/prefill_chunks/chunk_tokens/
         chunk_pad_tokens chunked-prefill tally, the CURRENT decode_window
@@ -3018,6 +3057,11 @@ class InferenceEngine:
                 self.mcfg, nb, s_pad, int(lengths.sum()), self.mesh)
         with self._phase("prefill/sample"):
             firsts = self._sample(logits, reqs)  # blocks on the fetch
+            if self.mcfg.holds_expert_share:
+                # The program has ended (its tokens are here): a 4-byte
+                # copy, no wait.
+                self.timing["prefill_held_expert_rows"] += int(
+                    self._executor.held_rows)
         for i, req in enumerate(reqs):
             if req.done:
                 continue   # quarantined during mask build (_sample_masks)
@@ -3807,7 +3851,26 @@ class InferenceEngine:
                 self.mcfg.sliding_window,
             ).sum())
         self.timing["decode_kv_tokens"] += kv
+        self._count_kv_by_layer_kind(self.seq_lens[mask].astype(np.int64), W)
         return active, W, common
+
+    def _count_kv_by_layer_kind(self, lens: np.ndarray, W: int) -> None:
+        """decode_kv_token_layers, kv_live_page_layers and
+        kv_dead_window_page_layers of one decode window over live slots of
+        lengths ``lens`` (host arithmetic, no device value read)."""
+        steps = lens[:, None] + np.arange(W)
+        for window, n in self._layers_by_window.items():
+            read = steps if window is None else np.minimum(steps, window)
+            self.timing["decode_kv_token_layers"] += n * int(read.sum())
+        if self._window_layers:
+            # A page is dead for a window layer when the query at position
+            # len reads none of it: its last position is under len - window.
+            dead = np.maximum(
+                lens - self.mcfg.sliding_window + 1, 0) // self.psz
+            self.timing["kv_live_page_layers"] += self.mcfg.n_layers * int(
+                (-(-lens // self.psz)).sum())
+            self.timing["kv_dead_window_page_layers"] += (
+                self._window_layers * int(dead.sum()))
 
     def _decode_run_window(self, window) -> bool:
         """Dispatch a built decode window, fetch its ``[W, B]`` tokens and

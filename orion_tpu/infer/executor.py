@@ -62,6 +62,9 @@ class DispatchExecutor:
         # sleeps the same schedule; sleep durations never touch tokens,
         # so this is log-determinism, not output-determinism.
         self._rng = random.Random(0)
+        # The last prefill's count of expert rows on held experts (a device
+        # scalar; only a model that holds a share of its experts has one).
+        self.held_rows = None
 
     def jit_program(self, name: str, mcfg, mesh):
         """Build one jitted dispatch program. ``name`` is a coarse path
@@ -93,7 +96,18 @@ class DispatchExecutor:
                 top_k=icfg.top_k,
                 top_p=icfg.top_p,
             )
-        return jax.jit(partial(fn, **kw), donate_argnums=(1,))
+        program = jax.jit(partial(fn, **kw), donate_argnums=(1,))
+        if stem == "prefill" and mcfg.holds_expert_share:
+            # Such a model's prefill has a third result, the rows it
+            # computed on experts held here (runner.HELD_ROWS): kept on the
+            # device for the engine to fetch after the sampled tokens, so
+            # that every caller still gets (logits, cache).
+            def run(*args):
+                logits, cache, self.held_rows = program(*args)
+                return logits, cache
+
+            return run
+        return program
 
     def fallback_program(self, name: str):
         """The XLA reference program for ``name`` (degradation ladder rung

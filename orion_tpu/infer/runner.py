@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.config import ModelConfig
+from orion_tpu.models import moe as moe_lib
 from orion_tpu.models.transformer import (
     Params,
     _norm,
@@ -45,6 +46,7 @@ from orion_tpu.models.transformer import (
     mlp_or_moe,
     out_proj,
     qkv_proj,
+    scan_layer_plan,
     unembed,
 )
 from orion_tpu.ops import attention
@@ -54,16 +56,25 @@ Cache = dict[str, jax.Array]
 
 
 def _scan_layers(params: Params, cfg: ModelConfig, body, init_carry):
-    """Run ``body(carry, bp, l, j) -> carry`` over all layers.
+    """Run ``body(carry, bp, l, j, stack=None) -> carry`` over all layers.
 
     ``l`` is the layer index (traced under scan, static ints otherwise);
-    ``j`` is the STATIC pattern position (l % sliding_window_pattern, or 0
-    without a pattern) — the sliding window is static in every kernel, so
-    interleaved local/global models (Gemma-family) scan over GROUPS of
-    ``pattern`` layers with one body call per static position.
+    ``j`` is the STATIC index of a layer of this layer's kind
+    (``cfg.layer_kind(j)``: window, query heads, rotary table, feed-forward)
+    — a kind is static in every kernel, so interleaved local/global models
+    (Gemma-family; j = l % sliding_window_pattern) scan over GROUPS of
+    ``pattern`` layers with one body call per static position, and a model
+    whose layers differ in shape runs its layer plan
+    (``transformer.scan_layer_plan``, which also hands the body ``stack``).
     """
     L = cfg.n_layers
     pattern = cfg.window_pattern
+    if cfg.layer_plan is not None:
+        if not cfg.scan_layers:
+            raise ValueError(
+                "a model whose layers differ in shape needs scan_layers=true")
+        return scan_layer_plan(
+            params["blocks"], cfg.layer_plan, body, init_carry)
     if cfg.scan_layers:
         if pattern is None:
             def scan_body(carry, xs):
@@ -172,7 +183,8 @@ def _prefill_ctx(
     # stack [L, ...] (no window pattern): the dropless MoE dispatch reads a
     # layer's matrices out of it in place (ops.grouped_matmul).
     moe_stack = None
-    if cfg.is_moe and cfg.scan_layers and cfg.window_pattern is None:
+    if (cfg.is_moe and cfg.scan_layers and cfg.window_pattern is None
+            and cfg.layer_plan is None):
         moe_stack = params["blocks"]["moe"]
     return dict(
         moe_stack=moe_stack,
@@ -193,16 +205,20 @@ def _prefill_layer(
     ctx: dict,
     cfg: ModelConfig,
     mesh: Optional[jax.sharding.Mesh],
+    stack=None,
 ) -> tuple[jax.Array, Cache]:
     """One transformer layer of (possibly mid-sequence) prefill: flash/xla
     attention over [gathered prefix pages + own K/V], then scatter the new
-    K/V pages into the carried pool."""
+    K/V pages into the carried pool. ``stack``: ``_scan_layers``' (a model
+    with a layer plan; else the one stack of ``ctx``)."""
     Nb, psz, NP = ctx["Nb"], ctx["psz"], ctx["NP"]
     n_pages, quant, P_pre = ctx["n_pages"], ctx["quant"], ctx["P_pre"]
     positions, seg = ctx["positions"], ctx["seg"]
-    layer_stack = None if ctx["moe_stack"] is None else (ctx["moe_stack"], l)
+    layer_stack = stack
+    if ctx["moe_stack"] is not None:
+        layer_stack = (ctx["moe_stack"], l)
     h = _norm(x, bp["attn_norm"], cfg, mesh)
-    q, k, v = qkv_proj(h, bp["attn"], cfg, positions, mesh)
+    q, k, v = qkv_proj(h, bp["attn"], cfg, positions, mesh, _kind(cfg, j))
     if P_pre and ctx["paged"]:
         # Paged-flash prefill: the chunk's queries walk the paged history
         # in-kernel (no dense prefix gather) and the chunk's own pages
@@ -227,13 +243,14 @@ def _prefill_layer(
             out, cc["k"], cc["v"], cc["k_scale"], cc["v_scale"] = res
         else:
             out, cc["k"], cc["v"] = res
-        a = out_proj(out, bp["attn"], cfg)
+        a = out_proj(out, bp["attn"], cfg, h)
         if cfg.post_norms:
             a = _norm(a, bp["post_attn_norm"], cfg, mesh)
         x = x + a
         h2 = _norm(x, bp["mlp_norm"], cfg, mesh)
         y, _ = mlp_or_moe(
             h2, bp, cfg, mesh, valid=seg > 0, layer_stack=layer_stack)
+        _count_held_rows(cc, h2, bp, cfg, seg > 0)
         if cfg.post_norms:
             y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
         return x + y, cc
@@ -276,7 +293,7 @@ def _prefill_layer(
             block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
             impl=cfg.kernels, mesh=mesh,
         )
-    a = out_proj(out, bp["attn"], cfg)
+    a = out_proj(out, bp["attn"], cfg, h)
     if cfg.post_norms:
         a = _norm(a, bp["post_attn_norm"], cfg, mesh)
     x = x + a
@@ -286,6 +303,7 @@ def _prefill_layer(
     # page, logits come off each row's last real position).
     y, _ = mlp_or_moe(
         h2, bp, cfg, mesh, valid=seg > 0, layer_stack=layer_stack)
+    _count_held_rows(cc, h2, bp, cfg, seg > 0)
     if cfg.post_norms:
         y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
     x = x + y
@@ -313,6 +331,23 @@ def _prefill_layer(
     cc["k"] = cc["k"].at[rows].set(kpages)
     cc["v"] = cc["v"].at[rows].set(vpages)
     return x, cc
+
+
+def _kind(cfg: ModelConfig, j: int):
+    """The layer's kind for ``qkv_proj`` where a model's layers differ
+    (``j`` static, ``_scan_layers``); None is the model's one kind."""
+    return None if cfg.layer_plan is None else cfg.layer_kind(j)
+
+
+HELD_ROWS = "held_expert_rows"
+
+
+def _count_held_rows(cc: Cache, h2, bp, cfg: ModelConfig, valid) -> None:
+    """Where ``prefill_step`` carries the counter (a model that holds a
+    share of its experts), add this layer's routed rows on held experts."""
+    if HELD_ROWS in cc and "moe" in bp:
+        cc[HELD_ROWS] = cc[HELD_ROWS] + moe_lib.held_rows(
+            h2, bp["moe"]["router"], cfg, valid)
 
 
 def _prefill_logits(
@@ -375,13 +410,21 @@ def prefill_step(
         cfg, paged_prefill=paged_prefill,
     )
 
-    def body(carry, bp, l, j):
+    def body(carry, bp, l, j, stack=None):
         x, cc = carry
-        return _prefill_layer(x, cc, bp, l, j, ctx, cfg, mesh)
+        return _prefill_layer(x, cc, bp, l, j, ctx, cfg, mesh, stack)
 
     x = embed(params, tokens, ctx["positions"], cfg)
-    x, cache = _scan_layers(params, cfg, body, (x, dict(cache)))
-    return _prefill_logits(params, x, lengths, cfg, mesh), cache
+    cache = dict(cache)
+    if cfg.holds_expert_share:
+        # Rides the layer scan beside the pool and leaves as a third
+        # result: the engine's prefill_held_expert_rows counter.
+        cache[HELD_ROWS] = jnp.zeros((), jnp.int32)
+    x, cache = _scan_layers(params, cfg, body, (x, cache))
+    logits = _prefill_logits(params, x, lengths, cfg, mesh)
+    if cfg.holds_expert_share:
+        return logits, cache, cache.pop(HELD_ROWS)
+    return logits, cache
 
 
 def _decode_ctx(
@@ -444,7 +487,8 @@ def _decode_layer(
     cc = dict(cc)
     win = cfg.layer_window(j)
     h = _norm(x, bp["attn_norm"], cfg, mesh)
-    q, k, v = qkv_proj(h, bp["attn"], cfg, ctx["positions"], mesh)
+    q, k, v = qkv_proj(
+        h, bp["attn"], cfg, ctx["positions"], mesh, _kind(cfg, j))
     K, H = k.shape[2], k.shape[3]
     if ctx["use_pallas"]:
         # Ragged paged-attention kernel: walks the page table directly
@@ -510,7 +554,7 @@ def _decode_layer(
             q, k_ctx, v_ctx, causal=False, mask=kv_mask,
             logit_softcap=cfg.attn_logit_softcap,
         )
-    a = out_proj(out, bp["attn"], cfg)
+    a = out_proj(out, bp["attn"], cfg, h)
     if cfg.post_norms:
         a = _norm(a, bp["post_attn_norm"], cfg, mesh)
     x = x + a
@@ -533,7 +577,7 @@ def _decode_core(
     """One decode forward for every slot -> (logits [B, V], cache')."""
     ctx = _decode_ctx(cache, write_pos, page_table, cfg)
 
-    def body(carry, bp, l, j):
+    def body(carry, bp, l, j, stack=None):
         x, cc = carry
         return _decode_layer(x, cc, bp, l, j, ctx, cfg, mesh)
 
@@ -765,7 +809,8 @@ def _verify_layer(
     cc = dict(cc)
     win = cfg.layer_window(j)
     h = _norm(x, bp["attn_norm"], cfg, mesh)
-    q, k, v = qkv_proj(h, bp["attn"], cfg, ctx["positions"], mesh)
+    q, k, v = qkv_proj(
+        h, bp["attn"], cfg, ctx["positions"], mesh, _kind(cfg, j))
     K, H = k.shape[2], k.shape[3]
     if ctx["use_pallas"]:
         # Multi-query ragged paged attention: one kernel walks each
@@ -846,7 +891,7 @@ def _verify_layer(
             q, k_ctx, v_ctx, causal=False, mask=kv_mask,
             logit_softcap=cfg.attn_logit_softcap,
         )
-    a = out_proj(out, bp["attn"], cfg)
+    a = out_proj(out, bp["attn"], cfg, h)
     if cfg.post_norms:
         a = _norm(a, bp["post_attn_norm"], cfg, mesh)
     x = x + a
@@ -945,7 +990,7 @@ def verify_step(
         depths=depths, tree_mask=tree_mask,
     )
 
-    def body(carry, bp, l, j):
+    def body(carry, bp, l, j, stack=None):
         x, cc = carry
         return _verify_layer(x, cc, bp, l, j, ctx, cfg, mesh)
 
@@ -1035,9 +1080,9 @@ def mixed_step(
     )
     dctx = _decode_ctx(cache, wp, page_table, cfg)
 
-    def body(carry, bp, l, j):
+    def body(carry, bp, l, j, stack=None):
         xp, xd, cc = carry
-        xp, cc = _prefill_layer(xp, cc, bp, l, j, pctx, cfg, mesh)
+        xp, cc = _prefill_layer(xp, cc, bp, l, j, pctx, cfg, mesh, stack)
         xd, cc = _decode_layer(xd, cc, bp, l, j, dctx, cfg, mesh)
         return xp, xd, cc
 
@@ -1116,9 +1161,9 @@ def mixed_verify_step(
         depths=depths, tree_mask=tree_mask,
     )
 
-    def body(carry, bp, l, j):
+    def body(carry, bp, l, j, stack=None):
         xp, xv, cc = carry
-        xp, cc = _prefill_layer(xp, cc, bp, l, j, pctx, cfg, mesh)
+        xp, cc = _prefill_layer(xp, cc, bp, l, j, pctx, cfg, mesh, stack)
         xv, cc = _verify_layer(xv, cc, bp, l, j, vctx, cfg, mesh)
         return xp, xv, cc
 

@@ -58,9 +58,21 @@ from orion_tpu.config import ModelConfig
 
 
 def moe_capacity(cfg: ModelConfig, tokens_per_group: int) -> int:
+    """An expert's bucket: its even share of the assignments (over ALL the
+    experts the router chooses among, held here or not) times the factor."""
     cap = int(cfg.capacity_factor * tokens_per_group * cfg.n_experts_per_token
-              / cfg.n_experts)
+              / cfg.resolved_router_width)
     return max(cap, 1)
+
+
+def _held(idx: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
+    """(index among the experts held here, whether the chosen expert is one
+    of them) of router choices ``idx``. A layer that holds every expert
+    holds them all at their own index."""
+    if not cfg.holds_expert_share:
+        return idx, jnp.ones(idx.shape, bool)
+    local = idx - cfg.expert_offset
+    return local, (local >= 0) & (local < cfg.n_experts)
 
 
 def _router_topk(
@@ -75,7 +87,7 @@ def _router_topk(
     it survives checkify's index-check rewrite in this jax version, so
     ``runtime.checkify`` keeps its FULL check set on MoE models too.
     """
-    E = cfg.n_experts
+    E = router_w.shape[-1]      # the router's width is the router's own
     logits = jnp.einsum(
         "bsd,de->bse", x, router_w, preferred_element_type=jnp.float32
     )
@@ -86,6 +98,8 @@ def _router_topk(
     onehot = jax.nn.one_hot(idx, E, dtype=probs.dtype)   # [B,S,k,E]
     gate = (probs[..., None, :] * onehot).sum(-1)        # scatter-free gather
     gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)  # renormalize
+    if cfg.router_scale != 1.0:
+        gate = gate * cfg.router_scale
     # remat="names" (models/transformer.REMAT_SAVE_NAMES) saves the gates:
     # [B,S,k] f32 is near-free to store and pins the softmax/argsort chain
     # every dispatch mode's backward needs. No-op under other policies.
@@ -101,7 +115,7 @@ def _aux_stats(
     compose across equal-sized shards by plain averaging, so sharded
     callers pmean these BEFORE taking the product (the loss is bilinear in
     the stats, not linear in per-shard losses)."""
-    E, k = cfg.n_experts, cfg.n_experts_per_token
+    E, k = probs.shape[-1], cfg.n_experts_per_token
     onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)  # [B,S,k,E]
     frac = onehot.sum(axis=2).mean(axis=(0, 1)) / k
     mean_prob = probs.mean(axis=(0, 1))
@@ -111,7 +125,7 @@ def _aux_stats(
 def _aux_loss(probs: jax.Array, idx: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Switch eq. 4 load-balance loss: E * sum_e fraction_e * mean-prob_e."""
     frac, mean_prob = _aux_stats(probs, idx, cfg)
-    return cfg.n_experts * jnp.sum(frac * mean_prob)
+    return probs.shape[-1] * jnp.sum(frac * mean_prob)
 
 
 def route(
@@ -177,7 +191,7 @@ def route_indices(
     route()'s float one-hot cumsum count the same stream.
     """
     B, S, _ = x.shape
-    E, k = cfg.n_experts, cfg.n_experts_per_token
+    E, k = cfg.resolved_router_width, cfg.n_experts_per_token
     C = moe_capacity(cfg, S)
 
     probs, gate, idx = _router_topk(x, router_w, cfg)
@@ -272,10 +286,17 @@ def moe_mlp_sorted(
     E, C = cfg.n_experts, moe_capacity(cfg, x.shape[1])
     idx, gate, pos, keep, (frac, mp) = route_indices(
         x, params["router"], cfg)
+    if cfg.holds_expert_share:
+        # Assignments to experts held elsewhere are dropped here (their
+        # bucket position was counted per expert, so the held ones keep
+        # theirs); the gates stay normalised over all k chosen.
+        idx, held = _held(idx, cfg)
+        keep = keep & held
+        idx = jnp.clip(idx, 0, E - 1)
     xin = _scatter_dispatch(x, idx, pos, keep, E, C)
     out = _expert_ffn(xin, params, cfg)
     y = _gather_combine(out, idx, pos, keep, gate, dtype)
-    aux = E * jnp.sum(frac * mp)
+    aux = cfg.resolved_router_width * jnp.sum(frac * mp)
     return y, aux.astype(jnp.float32)
 
 
@@ -458,19 +479,22 @@ def moe_mlp_grouped(
     T = B * S
     probs, gate, idx = _router_topk(x, params["router"], cfg)
 
-    # Assignment a = t*k + j (token t, slot j); invalid ones get key E.
-    key = idx.reshape(T * k)
+    # Assignment a = t*k + j (token t, slot j); invalid ones, and those to
+    # experts held elsewhere, get key E.
+    key, held = (a.reshape(T * k) for a in _held(idx, cfg))
     gate = gate.reshape(T, k)
     if valid is not None:
-        v = valid.reshape(T)
-        key = jnp.where(jnp.repeat(v, k), key, E)
-        gate = gate * v[:, None].astype(gate.dtype)
+        held = held & jnp.repeat(valid.reshape(T), k)
+    partial = valid is not None or cfg.holds_expert_share
+    if partial:
+        key = jnp.where(held, key, E)
+        gate = gate * held.reshape(T, k).astype(gate.dtype)
     order = jnp.argsort(key, stable=True)                    # [kT]
     group_sizes = jnp.sum(
         key[:, None] == jnp.arange(E, dtype=key.dtype), axis=0,
         dtype=jnp.int32)                                     # [E]
     xs = x.reshape(T, D)[order // k]                         # [kT, D]
-    if valid is not None:
+    if partial:
         # Rows behind the last group are never computed (the kernel leaves
         # them uninitialised); the selects keep what is there out of the
         # result and out of the cotangents.
@@ -492,7 +516,7 @@ def moe_mlp_grouped(
     else:
         h = jax.nn.gelu(h_in)
     out = gmm(h, "w_out", contract_tp=True)                  # [kT, D]
-    if valid is not None:
+    if partial:
         out = jnp.where(live, out, 0)
     out = out[jnp.argsort(order)].reshape(T, k, D)           # un-sort
     y = jnp.einsum("tkd,tk->td", out, gate.astype(x.dtype))
@@ -515,10 +539,45 @@ def moe_dispatch(
     mode = cfg.moe_dispatch
     if mode not in ("einsum", "sorted", "sorted_a2a"):
         raise ValueError(f"unknown model.moe_dispatch={mode!r}")
+    if cfg.holds_expert_share and (mode != "sorted" or (
+            mesh is not None and mesh.shape.get("ep", 1) > 1)):
+        raise ValueError(
+            "a layer that holds a share of its experts (model.router_width "
+            "> model.n_experts) is computed by moe_dispatch=sorted with no "
+            "ep axis: the share IS this device's part of the experts")
     if takes_grouped_path(cfg, x.shape[0], x.shape[1], mesh):
-        return moe_mlp_grouped(x, params, cfg, valid, mesh, layer_stack)
-    if mode == "einsum":
-        return moe_mlp(x, params, cfg)
-    if mode == "sorted_a2a" and mesh is not None:
-        return moe_mlp_sorted_a2a(x, params, cfg, mesh)   # ep == 1: sorted
-    return moe_mlp_sorted(x, params, cfg)
+        y, aux = moe_mlp_grouped(x, params, cfg, valid, mesh, layer_stack)
+    elif mode == "einsum":
+        y, aux = moe_mlp(x, params, cfg)
+    elif mode == "sorted_a2a" and mesh is not None:
+        y, aux = moe_mlp_sorted_a2a(x, params, cfg, mesh)  # ep == 1: sorted
+    else:
+        y, aux = moe_mlp_sorted(x, params, cfg)
+    if "shared" in params:
+        y = y + _shared_expert(x, params["shared"], cfg)
+    return y, aux
+
+
+def _shared_expert(x: jax.Array, p: dict[str, Any], cfg: ModelConfig
+                   ) -> jax.Array:
+    """The shared expert, a gated feed-forward every token takes, added
+    UNGATED beside the routed ones (assumed: the config names no gate on
+    it). ONE function, so that the published modelling code corrects it in
+    one place."""
+    from orion_tpu.models.transformer import _gate_act
+
+    h = _gate_act(cfg)(jnp.einsum("bsd,df->bsf", x, p["w_gate"])) * (
+        jnp.einsum("bsd,df->bsf", x, p["w_in"]))
+    return jnp.einsum("bsf,fd->bsd", h, p["w_out"])
+
+
+def held_rows(x: jax.Array, router_w: jax.Array, cfg: ModelConfig,
+              valid: Optional[jax.Array] = None) -> jax.Array:
+    """How many of the block's routed (token, expert) assignments fall on
+    experts held here (int32 scalar): the rows this layer's expert matmuls
+    have to compute. ``valid`` [B, S] as in ``moe_dispatch``. The same
+    router head as the dispatch, so XLA computes it once."""
+    _, held = _held(_router_topk(x, router_w, cfg)[2], cfg)
+    if valid is not None:
+        held = held & valid[..., None]
+    return held.sum(dtype=jnp.int32)

@@ -111,7 +111,11 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     if cfg.norm == "layernorm":
         params["final_norm"]["bias"] = jnp.zeros((D,), pdt)
 
-    def init_block(bkey: jax.Array) -> Params:
+    def init_block(bkey: jax.Array, kind=None) -> Params:
+        """``kind`` (a ``config.LayerKind``) sizes a layer of a model
+        whose layers differ; None is the model's one kind."""
+        N = cfg.n_heads if kind is None else kind.n_heads
+        moe = cfg.is_moe if kind is None else kind.moe
         bkeys = iter(jax.random.split(bkey, 16))
         block: Params = {
             "attn_norm": {"scale": norm_scale()},
@@ -135,15 +139,24 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             block["attn"]["bv"] = jnp.zeros((K * H,), pdt)
         if cfg.resolved_attn_out_bias:
             block["attn"]["bo"] = jnp.zeros((D,), pdt)
-        if cfg.is_moe:
-            E = cfg.n_experts
+        if moe:
+            E, Fe = cfg.n_experts, cfg.resolved_moe_d_ff
             block["moe"] = {
-                "router": _normal(next(bkeys), (D, E), pdt, std),
-                "w_in": _normal(next(bkeys), (E, D, F), pdt, std),
-                "w_out": _normal(next(bkeys), (E, F, D), pdt, resid_std),
+                "router": _normal(
+                    next(bkeys), (D, cfg.resolved_router_width), pdt, std),
+                "w_in": _normal(next(bkeys), (E, D, Fe), pdt, std),
+                "w_out": _normal(next(bkeys), (E, Fe, D), pdt, resid_std),
             }
             if cfg.is_gated_mlp:
-                block["moe"]["w_gate"] = _normal(next(bkeys), (E, D, F), pdt, std)
+                block["moe"]["w_gate"] = _normal(
+                    next(bkeys), (E, D, Fe), pdt, std)
+            if cfg.shared_expert_d_ff:
+                Fs = cfg.shared_expert_d_ff
+                block["moe"]["shared"] = {
+                    "w_in": _normal(next(bkeys), (D, Fs), pdt, std),
+                    "w_gate": _normal(next(bkeys), (D, Fs), pdt, std),
+                    "w_out": _normal(next(bkeys), (Fs, D), pdt, resid_std),
+                }
         else:
             block["mlp"] = {
                 "w_in": _normal(next(bkeys), (D, F), pdt, std),
@@ -154,11 +167,27 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             if cfg.mlp_bias:
                 block["mlp"]["b_in"] = jnp.zeros((F,), pdt)
                 block["mlp"]["b_out"] = jnp.zeros((D,), pdt)
+        if cfg.attn_gate is not None:
+            block["attn"]["wg"] = _normal(next(bkeys), (D, N), pdt, std)
 
         return block
 
     layer_keys = jax.random.split(next(keys), L)
-    if cfg.scan_layers:
+    plan = cfg.layer_plan
+    if plan is not None:
+        # Layers that differ in shape: the leading layers each on their
+        # own, and one stack per position of the period (config.LayerPlan).
+        kinds = cfg.layer_kinds
+        params["blocks"] = {"period": {
+            str(j): jax.vmap(functools.partial(
+                init_block, kind=kinds[plan.lead + j]))(
+                    layer_keys[plan.lead + j::plan.period])
+            for j in range(plan.period) if plan.counts[j]}}
+        if plan.lead:
+            params["blocks"]["lead"] = {
+                str(i): init_block(layer_keys[i], kinds[i])
+                for i in range(plan.lead)}
+    elif cfg.scan_layers:
         params["blocks"] = jax.vmap(init_block)(layer_keys)
     else:
         params["blocks"] = [init_block(k) for k in layer_keys]
@@ -171,8 +200,36 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     Logical names are mapped to mesh axes by parallel.sharding rules:
     vocab/heads/mlp -> tp, embed -> fsdp, expert -> ep, layers -> unsharded.
     """
-    lead = ("layers",) if cfg.scan_layers else ()
+    plan = cfg.layer_plan
+    if plan is not None:
+        kinds = cfg.layer_kinds
+        blocks: Params = {"period": {
+            str(j): _block_axes(cfg, ("layers",), kinds[plan.lead + j])
+            for j in range(plan.period) if plan.counts[j]}}
+        if plan.lead:
+            blocks["lead"] = {str(i): _block_axes(cfg, (), kinds[i])
+                              for i in range(plan.lead)}
+    else:
+        block = _block_axes(
+            cfg, ("layers",) if cfg.scan_layers else (), None)
+        blocks = block if cfg.scan_layers else [block] * cfg.n_layers
+    axes: Params = {
+        "embed": {"tokens": ("vocab", "embed")},
+        "final_norm": {"scale": ("embed",)},
+        "blocks": blocks,
+    }
+    if cfg.pos_embedding == "learned":
+        axes["embed"]["positions"] = ("pos", "embed")
+    if not cfg.tie_embeddings:
+        axes["lm_head"] = ("embed", "vocab")
+    if cfg.norm == "layernorm":
+        axes["final_norm"]["bias"] = ("embed",)
+    return axes
 
+
+def _block_axes(cfg: ModelConfig, lead: tuple, kind) -> Params:
+    """One block's logical axes (``kind`` as in ``init_params``)."""
+    moe = cfg.is_moe if kind is None else kind.moe
     block = {
         "attn_norm": {"scale": lead + ("embed",)},
         "mlp_norm": {"scale": lead + ("embed",)},
@@ -195,7 +252,9 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         block["attn"]["bv"] = lead + ("kv_heads",)
     if cfg.resolved_attn_out_bias:
         block["attn"]["bo"] = lead + ("embed",)
-    if cfg.is_moe:
+    if cfg.attn_gate is not None:
+        block["attn"]["wg"] = lead + ("embed", "heads")
+    if moe:
         block["moe"] = {
             "router": lead + ("embed", "expert"),
             "w_in": lead + ("expert", "embed", "mlp"),
@@ -203,6 +262,12 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         }
         if cfg.is_gated_mlp:
             block["moe"]["w_gate"] = lead + ("expert", "embed", "mlp")
+        if cfg.shared_expert_d_ff:
+            block["moe"]["shared"] = {
+                "w_in": lead + ("embed", "mlp"),
+                "w_gate": lead + ("embed", "mlp"),
+                "w_out": lead + ("mlp", "embed"),
+            }
     else:
         block["mlp"] = {
             "w_in": lead + ("embed", "mlp"),
@@ -213,19 +278,7 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         if cfg.mlp_bias:
             block["mlp"]["b_in"] = lead + ("mlp",)
             block["mlp"]["b_out"] = lead + ("embed",)
-
-    axes: Params = {
-        "embed": {"tokens": ("vocab", "embed")},
-        "final_norm": {"scale": ("embed",)},
-        "blocks": block if cfg.scan_layers else [block] * cfg.n_layers,
-    }
-    if cfg.pos_embedding == "learned":
-        axes["embed"]["positions"] = ("pos", "embed")
-    if not cfg.tie_embeddings:
-        axes["lm_head"] = ("embed", "vocab")
-    if cfg.norm == "layernorm":
-        axes["final_norm"]["bias"] = ("embed",)
-    return axes
+    return block
 
 
 # ---------------------------------------------------------------------------
@@ -294,16 +347,23 @@ def unembed(
 
 def qkv_proj(
     x: jax.Array, p: Params, cfg: ModelConfig, positions: jax.Array,
-    mesh: Optional[Any] = None,
+    mesh: Optional[Any] = None, kind=None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """QKV projection + RoPE. x: [B, S, D] -> q [B,S,N,H], k/v [B,S,K,H].
 
     Shared between the training forward and the inference cache runner
     (orion_tpu.infer.runner), which attends against different KV sources.
     ``mesh`` as in ``_norm`` (the Pallas RoPE kernel runs per shard).
+    ``kind`` (``cfg.layer_kind(l)``, static) gives this layer's query heads
+    and rotary table where a model's layers differ; None is the model's one
+    kind.
     """
     B, S, _ = x.shape
     N, K, H = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    theta, table = cfg.rope_theta, None
+    if kind is not None:
+        N, theta = kind.n_heads, kind.rope.theta
+        table = None if kind.rope.is_plain else kind.rope
     dtype = x.dtype
 
     q = jnp.einsum("bsd,dh->bsh", x, _load_w(p["wq"], dtype))
@@ -319,7 +379,8 @@ def qkv_proj(
 
     if cfg.pos_embedding == "rope":
         rope = functools.partial(
-            ops.apply_rope, theta=cfg.rope_theta, impl=cfg.kernels, mesh=mesh
+            ops.apply_rope, theta=theta, rope=table, impl=cfg.kernels,
+            mesh=mesh,
         )
         q, k = rope(q, positions), rope(k, positions)
     if cfg.query_scale is not None:
@@ -330,16 +391,33 @@ def qkv_proj(
     return q, k, v
 
 
-def out_proj(out: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
-    """Attention output projection. out: [B, S, N, H] -> [B, S, D]."""
+def out_proj(out: jax.Array, p: Params, cfg: ModelConfig,
+             h: Optional[jax.Array] = None) -> jax.Array:
+    """Attention output projection. out: [B, S, N, H] -> [B, S, D].
+
+    With ``model.attn_gate`` each head's output is first multiplied by
+    ``sigmoid(h wg)``, ``h`` [B, S, D] the layer's normed input (the one
+    ``qkv_proj`` read): the head-wise gate of arXiv:2505.06708."""
     B, S = out.shape[0], out.shape[1]
     dtype = out.dtype
+    if cfg.attn_gate is not None:
+        out = out * _attn_gate(h, p, cfg)[..., None].astype(dtype)
     y = jnp.einsum(
         "bsh,hd->bsd", out.reshape(B, S, -1), _load_w(p["wo"], dtype)
     )
     if cfg.resolved_attn_out_bias:
         y = y + p["bo"].astype(dtype)
     return y
+
+
+def _attn_gate(h: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
+    """[B, S, N] gate on the attention output: ONE function, so that the
+    published modelling code, when at hand, corrects the reading of
+    ``gating: per-head`` in one place (the reference has its own)."""
+    if cfg.attn_gate != "per-head":
+        raise ValueError(f"model.attn_gate={cfg.attn_gate!r}; per-head|None")
+    return jax.nn.sigmoid(
+        jnp.einsum("bsd,dn->bsn", h, _load_w(p["wg"], h.dtype)))
 
 
 def mlp_or_moe(
@@ -352,10 +430,12 @@ def mlp_or_moe(
     ``valid`` [B, S] marks the real positions of a padded block (prefill):
     the dropless MoE dispatch routes only those. ``layer_stack`` = (the
     layer-stacked ``blocks["moe"]``, this layer's index) lets it read the
-    expert matrices in place (moe.moe_mlp_grouped)."""
-    if cfg.is_moe:
+    expert matrices in place (moe.moe_mlp_grouped). Which of the two a
+    layer is, its parameters say (a model may lead with dense layers)."""
+    if "moe" in bp:
         moe_params = {
-            k: v.astype(h.dtype) if k != "router" else v
+            k: v if k == "router" else jax.tree.map(
+                lambda a: a.astype(h.dtype), v)
             for k, v in bp["moe"].items()
         }
         return moe_lib.moe_dispatch(
@@ -371,10 +451,12 @@ def _attn_block(
     segment_ids: Optional[jax.Array],
     mesh: Optional[Any] = None,
     window: Optional[int] = None,
+    kind=None,
 ) -> jax.Array:
     """``window`` is THIS layer's sliding window (already resolved through
-    cfg.layer_window for interleaved local/global models)."""
-    q, k, v = qkv_proj(x, p, cfg, positions, mesh)
+    cfg.layer_window for interleaved local/global models); ``kind`` as in
+    ``qkv_proj``."""
+    q, k, v = qkv_proj(x, p, cfg, positions, mesh, kind)
 
     sp_active = (
         cfg.sequence_axis is not None
@@ -431,7 +513,7 @@ def _attn_block(
     # per-layer tensor to rebuild (a full flash fwd pass) at [B,S,N,H]
     # storage. (No-op identity under every other policy.)
     out = checkpoint_name(out, "attn_out")
-    return out_proj(out, p, cfg)
+    return out_proj(out, p, cfg, x)
 
 
 def _mlp_block(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
@@ -458,10 +540,12 @@ def _block(
     segment_ids: Optional[jax.Array],
     mesh: Optional[Any] = None,
     window: Optional[int] = None,
+    kind=None,
 ) -> tuple[jax.Array, jax.Array]:
     """One transformer block. Returns (x, moe_aux_loss).
 
-    ``window``: this layer's resolved sliding window. With cfg.post_norms
+    ``window``: this layer's resolved sliding window (``kind``, where a
+    model's layers differ, carries it too). With cfg.post_norms
     (Gemma-family) each sublayer output is normalized again before the
     residual add.
 
@@ -474,7 +558,7 @@ def _block(
             _norm(x, bp["attn_norm"], cfg, mesh), "attn_norm_out"
         )
         a = _attn_block(xn, bp["attn"], cfg,
-                        positions, segment_ids, mesh, window)
+                        positions, segment_ids, mesh, window, kind)
         if cfg.post_norms:
             a = _norm(a, bp["post_attn_norm"], cfg, mesh)
         x = x + a
@@ -487,6 +571,39 @@ def _block(
         if cfg.post_norms:
             y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
     return x + y, aux
+
+
+def scan_layer_plan(blocks: Params, plan, body, carry):
+    """Run ``body(carry, bp, l, j, stack) -> carry`` over the layers of a
+    model whose layers differ in shape (``config.LayerPlan``): the leading
+    layers, a ``lax.scan`` over the periods that calls the body once per
+    static position, and the tail. ``l`` is the layer's index (traced under
+    the scan), ``j`` the STATIC index of a layer of the same kind
+    (``cfg.layer_kind(j)``), ``stack`` = (the position's layer-stacked MoE
+    weights or None, the layer's index in that stack) for the dispatch that
+    reads expert matrices in place. Shared by the training forward and the
+    cache runner."""
+    for i in range(plan.lead):
+        carry = body(carry, blocks["lead"][str(i)], i, i, None)
+
+    def one_period(carry, g, positions):
+        for j in range(positions):
+            stack = blocks["period"][str(j)]
+            carry = body(
+                carry, jax.tree.map(lambda a: a[g], stack),
+                plan.lead + g * plan.period + j, plan.lead + j,
+                (stack["moe"], g) if "moe" in stack else None)
+        return carry
+
+    if plan.repeats == 1:
+        carry = one_period(carry, 0, plan.period)
+    elif plan.repeats > 1:
+        carry, _ = jax.lax.scan(
+            lambda c, g: (one_period(c, g, plan.period), None),
+            carry, jnp.arange(plan.repeats))
+    if plan.tail:
+        carry = one_period(carry, plan.repeats, plan.tail)
+    return carry
 
 
 def forward(
@@ -552,7 +669,8 @@ def _hidden_states(
         # default; the policy dispatch lives in remat_policy.
         return jax.checkpoint(fn, policy=policy)
 
-    def make_block_fn(window: Optional[int], with_rs: bool = False):
+    def make_block_fn(window: Optional[int], with_rs: bool = False,
+                      kind=None):
         """Per-layer body (NOT remat-wrapped: the caller wraps its scan/
         pipeline unit via ``_remat``). ``with_rs`` (the packed-pipeline
         path) takes the per-row state (positions/segment_ids, already
@@ -562,7 +680,7 @@ def _hidden_states(
             def block_fn(carry, bp, rs):
                 return _block(
                     carry, bp, cfg, rs["positions"],
-                    rs.get("segment_ids"), mesh, window,
+                    rs.get("segment_ids"), mesh, window, kind,
                 )
         else:
             def block_fn(carry, bp):
@@ -571,7 +689,8 @@ def _hidden_states(
                     pos = jnp.broadcast_to(
                         pos[:1], (carry.shape[0], pos.shape[1])
                     )
-                return _block(carry, bp, cfg, pos, segment_ids, mesh, window)
+                return _block(carry, bp, cfg, pos, segment_ids, mesh, window,
+                              kind)
 
         return block_fn
 
@@ -632,7 +751,27 @@ def _hidden_states(
         and mesh is not None
         and mesh.shape.get(cfg.pipeline_axis, 1) > 1
     )
-    if pp_active:
+    plan = cfg.layer_plan
+    if plan is not None:
+        if pp_active or cfg.scan_group > 1 or not cfg.scan_layers:
+            raise ValueError(
+                f"model {cfg.name!r} has layers of different shapes "
+                f"(model.layer_types / n_heads_per_layer / n_dense_layers): "
+                f"its layer program runs under scan_layers=true, "
+                f"scan_group=1 and no pipeline axis")
+        kinds = cfg.layer_kinds
+        fns = {j: _remat(make_block_fn(kinds[j].window, kind=kinds[j]))
+               for j in set(range(plan.lead)) | {
+                   plan.lead + j for j in range(plan.period)}}
+
+        def body(carry, bp, l, j, stack):
+            x, aux_t = carry
+            x, aux = fns[j](x, bp)
+            return x, aux_t + aux
+
+        x, moe_aux = scan_layer_plan(
+            params["blocks"], plan, body, (x, jnp.zeros((), jnp.float32)))
+    elif pp_active:
         if not cfg.scan_layers:
             raise ValueError("pipeline parallelism requires scan_layers=True")
         from orion_tpu.parallel.pipeline import pipeline_forward
