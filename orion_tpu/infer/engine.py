@@ -1360,6 +1360,11 @@ class InferenceEngine:
             # full layer reads a slot's length, a window layer its window
             # at most.
             "decode_kv_token_layers": 0,
+            # What the kernel's block walk copies for them: whole pages,
+            # from the page of a window's first position to the page that
+            # takes the new token. x page_size over decode_kv_token_layers
+            # is the page rounding no walk of whole pages avoids.
+            "decode_kv_pages_read": 0,
             # At each decode window, over the live slots: pages x layers
             # the pool holds for them, and of those the ones lying wholly
             # behind a window layer's window (a model that mixes window
@@ -1410,7 +1415,7 @@ class InferenceEngine:
         ``<phase>_s`` keys those three are sums of (_zero_timing), the
         prefill_dispatches/prefill_tokens/prefill_pad_tokens/
         prefill_expert_rows/prefill_held_expert_rows, decode_kv_tokens/
-        decode_kv_token_layers and kv_live_page_layers/
+        decode_kv_token_layers/decode_kv_pages_read and kv_live_page_layers/
         kv_dead_window_page_layers sizing counters,
         windows/steps counters, the slot_steps/wasted_steps
         decode-waste tally, the mixed_steps/prefill_chunks/chunk_tokens/
@@ -3855,13 +3860,17 @@ class InferenceEngine:
         return active, W, common
 
     def _count_kv_by_layer_kind(self, lens: np.ndarray, W: int) -> None:
-        """decode_kv_token_layers, kv_live_page_layers and
-        kv_dead_window_page_layers of one decode window over live slots of
-        lengths ``lens`` (host arithmetic, no device value read)."""
-        steps = lens[:, None] + np.arange(W)
+        """decode_kv_token_layers, decode_kv_pages_read,
+        kv_live_page_layers and kv_dead_window_page_layers of one decode
+        window over live slots of lengths ``lens`` (host arithmetic, no
+        device value read)."""
+        steps = lens[:, None] + np.arange(W)    # the new token's position
         for window, n in self._layers_by_window.items():
             read = steps if window is None else np.minimum(steps, window)
             self.timing["decode_kv_token_layers"] += n * int(read.sum())
+            first = 0 if window is None else np.maximum(steps - window + 1, 0)
+            self.timing["decode_kv_pages_read"] += n * int(
+                (steps // self.psz - first // self.psz + 1).sum())
         if self._window_layers:
             # A page is dead for a window layer when the query at position
             # len reads none of it: its last position is under len - window.

@@ -3,45 +3,55 @@
 The inference engine's decode step attends one new token per sequence
 against that sequence's KV pages (PAPERS.md:9 "ragged paged attention for
 TPU LLM inference"; SURVEY.md §3 `ops`: fused attention, "ragged/paged
-variant for inference"). The jnp reference path scatters the new token's
-K/V into the pool and materializes every sequence's full padded context via
-a pool gather; this kernel walks the page table directly and performs the
-KV write itself:
+variant for inference"); speculative verification attends W of them
+(``ragged_paged_attention.py``, a thin wrapper over this module). The jnp
+reference path scatters the new tokens' K/V into the pool and materializes
+every sequence's full padded context via a pool gather; this kernel walks
+the page table itself, in BLOCKS of several pages, and performs the KV
+write itself. One kernel body serves both: decode is verify at W = 1, so
+the two agree bitwise by construction.
 
-  - ``page_table``/``last_pos``/``layer base`` ride the scalar-prefetch
-    channel, so each grid step's k/v BlockSpec index map points the DMA at
-    the NEXT physical page while the current one computes — the gather
-    never materializes. The base offset makes the kernel work on the flat
-    [L*num_pages, ...] pool at a *traced* layer index, so the layer scan
-    can carry one pool array and update it in place.
-  - The new token's K/V is written INSIDE the kernel (on the grid step
-    whose page contains ``last_pos``), with the pool passed through via
-    ``input_output_aliases``. An external scatter followed by a pallas read
-    defeats XLA's in-place buffer analysis — the custom call made XLA
-    materialize a fresh multi-GB pool copy per layer (measured 140 ms/step
-    vs ~7 ms with the fused write).
-  - Pool layout is [rows, K, psz, H]: all K kv-heads of a page form one
-    (1, K, psz, H) block whose minor dims (psz, H) are (8, 128)-tiling
-    legal, and the head dim is a dot_general *batch* dim — one batched MXU
-    op per page instead of a K-step head loop (11x on a v5e) or a
-    (batch, head, page) grid of tiny blocks (worse still).
-  - Grid is (batch, page). Pages wholly past a sequence's length skip
-    their compute (`pl.when`) AND their fetch: the index map clamps them to
-    the sequence's first page, so the invalid tail re-requests the block
-    already resident and Mosaic elides the copies. Compute and traffic are
-    both proportional to the ragged ACTUAL context lengths — the "ragged"
-    in ragged paged attention.
-  - The grouped query heads of one kv head form a G8-row band of the
-    [K*G8, H] q block.
+  - Grid is (batch, ceil(P / nb)): a grid step owns ``nb`` consecutive
+    entries of a sequence's page table (``BLOCK_PAGES``). Pages are not
+    contiguous in the pool, so no BlockSpec can fetch them: the pools stay
+    in HBM (``pl.ANY``) and the step issues one async copy per LIVE page
+    into a double-buffered ``[2, K, nb*psz, H]`` VMEM scratch, starting
+    the next live block's copies (of this sequence, or of the next
+    sequence's first live block) before it computes on the current one.
+    Pages past a row's last position, and under a sliding window pages
+    wholly behind it, start no copy; a block with no live page does
+    nothing at all. ``page_table``/cursor/``layer base`` ride the
+    scalar-prefetch channel; the base offset makes the kernel work on the
+    flat [L*num_pages, ...] pool at a *traced* layer index, so the layer
+    scan carries one pool array and updates it in place.
+  - One QK and one PV product per kv head over the whole block, operands
+    in the pool's dtype (bf16 in serving; int8 pools cast to the query's
+    dtype, exact), f32 accumulation. Scale, softcap, the softmax
+    statistics and the accumulator are f32. The kv-head dim is a
+    dot_general *batch* dim; the grouped query heads of one kv head (times
+    W) form a row band of the [K*WG8, H] q block.
+  - Only the pages that take new tokens are written. The pools are
+    aliased in/out; the step that owns such a page merges the new rows
+    into its VMEM copy by a masked select against a position iota (Mosaic
+    rejects vector stores at runtime sublane offsets — the round-5
+    lesson), attends over the merged block and copies that one page back.
+    Every other page is read only. No external scatter feeds the kernel:
+    that defeats XLA's in-place buffer analysis and cost a fresh multi-GB
+    pool copy per layer (measured 140 ms/step vs ~7 ms fused).
+  - Dead columns of a live block (dead pages, positions past the query)
+    hold stale but finite data — the scratch is zeroed once per call —
+    so their masked probabilities contribute exact zeros.
 
-Decode is inference-only; no VJP is defined.
+Rows whose page-table entries are 0 (inactive / mid-prefill slots) read
+and write only the reserved scratch page; its content is unobservable.
+Padding queries of a W-row (``j >= lens``) write nothing and return
+garbage rows the caller discards — the XLA reference's discard semantics
+are the contract. Inference-only; no VJP is defined.
 
 Under chunked prefill (``runner.mixed_step``) this kernel serves the
-decode rows of the unified mixed dispatch — same contract, one query
-token per sequence with the fused in-place write — while prompt-chunk
-rows ride the flash kernel's segment-id path in the same program; the
-two in-place pool updates touch disjoint pages (the engine masks
-mid-prefill slots' decode rows onto the scratch page).
+decode rows of the unified mixed dispatch while prompt-chunk rows ride
+the flash kernel's segment-id path in the same program; the two in-place
+pool updates touch disjoint pages.
 """
 
 from __future__ import annotations
@@ -63,132 +73,278 @@ from orion_tpu.ops.pallas.common import (
 )
 
 LANES = 128
+# Pages per grid step (fewer where the page table is narrower), chosen on a
+# v5e by tools/paged_decode_sweep.py over {2, 4, 8, 16} at both serving
+# cells' shapes: 2 is a fifth to a half slower everywhere, 4 up to a tenth
+# slower on window layers, 16 no faster than 8 on full layers, 5 % faster on
+# a 512-token window's nine pages and 20 % slower at one page a sequence.
+BLOCK_PAGES = 8
+
+
+# Mosaic's default scoped-VMEM limit is 16 MiB; over this estimate the call
+# raises its own limit, and the engine refuses a verify width outright.
+VMEM_BUDGET_BYTES = 12 * 2 ** 20
+
+
+def vmem_bytes(W, *, n_heads, n_kv_heads, head_dim, page_size,
+               kv_itemsize, quant, block_pages=BLOCK_PAGES) -> int:
+    """Estimated VMEM footprint of one grid step: the q/out blocks, the
+    double-buffered K and V block scratch, the new-token blocks, the f32
+    scratch (m/l/acc), the logits and probabilities of one block, and the
+    scale blocks under quant. An estimate (Mosaic's allocator has its own
+    padding)."""
+    K = n_kv_heads
+    T = block_pages * page_size
+    rows = K * max(round_up(W * n_heads // K, 8), 8)
+    q_io = 2 * 2 * rows * head_dim * 4
+    kv = 2 * 2 * K * T * head_dim * kv_itemsize
+    new = 2 * 2 * W * max(K, 8) * head_dim * 4
+    scratch = rows * (2 * LANES + head_dim) * 4
+    logits = 3 * rows * T * 4
+    scales = (2 * 2 * block_pages * max(K, 8) * LANES * 4) if quant else 0
+    return q_io + kv + new + scratch + logits + scales
 
 
 def _kernel(
     softcap: Optional[float],
     psz: int,
-    K: int,
-    G8: int,
+    P: int,
+    G: int,
+    W: int,
+    nb: int,
     fused_write: bool,
     window: Optional[int],
     quant: bool,
+    tree: bool,
     pt_ref,        # [B, P] scalar-prefetched page table (per-layer-relative)
     base_ref,      # [1] scalar-prefetched flat-pool row base (layer * NP)
-    sl_ref,        # [B] scalar-prefetched last valid position per sequence
+    st_ref,        # [B] scalar-prefetched cursor (first new position)
+    ln_ref,        # [B] scalar-prefetched real query count per row (1..W)
     *refs,
 ):
     refs = list(refs)
-    q_ref, k_ref, v_ref = refs[:3]
-    i = 3
-    ks_ref = vs_ref = kn_ref = vn_ref = None
-    if quant:
-        ks_ref, vs_ref = refs[i], refs[i + 1]
-        i += 2
+    tm_ref = dp_ref = None
+    if tree:
+        # Token-tree verification: packed per-column ancestor words and
+        # tree depths ride the scalar prefetch like the page table.
+        tm_ref, dp_ref = refs[:2]           # [B, W] i32 each
+        refs = refs[2:]
+    n_pool = 4 if quant else 2              # k, v (+ k_scale, v_scale)
+    q_ref, pools = refs[0], refs[1:1 + n_pool]
+    refs = refs[1 + n_pool:]
+    new_refs = ()
     if fused_write:
-        kn_ref, vn_ref = refs[i], refs[i + 1]
-        i += 2
-    o_ref = refs[i]
-    i += 1
-    ko_ref = vo_ref = kso_ref = vso_ref = None
+        new_refs, refs = refs[:2], refs[2:]
+    o_ref, refs = refs[0], refs[1:]
     if fused_write:
-        ko_ref, vo_ref = refs[i], refs[i + 1]
-        i += 2
-        if quant:
-            kso_ref, vso_ref = refs[i], refs[i + 1]
-            i += 2
-    m_s, l_s, acc_s = refs[i:]
+        # Aliased in/out: read through the OUTPUT refs, so a page written
+        # earlier in the call reads back the same here as under the
+        # interpreter (whose outputs are copies of the inputs).
+        pools, refs = refs[:n_pool], refs[n_pool:]
+    m_s, l_s, acc_s = refs[:3]
+    bufs = refs[3:3 + n_pool]               # [2, K, nb*psz, H] / [2, nb, K, SW]
+    sems, wsems, slot_ref = refs[3 + n_pool:]
 
-    b, ip = pl.program_id(0), pl.program_id(1)
-    npages = pl.num_programs(1)
-    last_pos = sl_ref[b]
-    H = q_ref.shape[-1]
-    scale = H ** -0.5
+    b, ib = pl.program_id(0), pl.program_id(1)
+    B = pl.num_programs(0)
+    K, T, H = bufs[0].shape[1:]
+    WG8 = q_ref.shape[1] // K
+    cdt = q_ref.dtype if quant else bufs[0].dtype
 
-    @pl.when(ip == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
+    def span(bb):
+        """Row bb's cursor, last position and first/last live page."""
+        start = st_ref[bb]
+        # The clamp keeps a degenerate caller (cursor at the context edge)
+        # in-bounds the way the XLA body's scratch redirect does.
+        last = jnp.minimum(start + ln_ref[bb] - 1, P * psz - 1)
+        hi = last // psz
+        lo = 0
+        if window is not None:
+            # Pages wholly behind the EARLIEST query's window; later
+            # queries' tighter windows ride the mask.
+            lo = jnp.minimum(jnp.maximum(start - window + 1, 0) // psz, hi)
+        return start, last, lo, hi
 
-    if fused_write:
-        # Pass the page through (aliased in/out), inserting the new token's
-        # K/V on the page that owns position last_pos. The invalid tail is
-        # clamped onto that same (last valid) page, and the insert re-runs
-        # on every revisit: the revisits re-copy the STALE input block
-        # (fetched before any write-back), so a single insert at the owning
-        # grid step would be clobbered by the tail's final write-back.
-        # The insert is a MASKED full-block merge, not a dynamic-index row
-        # store: Mosaic rejects vector stores at runtime-computed sublane /
-        # lane offsets ("cannot statically prove the index is a multiple of
-        # the tile"), which the round-5 compiled run hit; a select against a
-        # sublane iota stores the whole (tiling-legal) block instead.
-        off = last_pos % psz
-        insert = ip >= last_pos // psz
-        row = lax.broadcasted_iota(jnp.int32, (K, psz, 1), 1)
-        sel = insert & (row == off)                       # [K, psz, 1]
-        if not quant:
-            ko_ref[0] = jnp.where(
-                sel, kn_ref[0][:, None, :].astype(ko_ref.dtype), k_ref[0]
-            )
-            vo_ref[0] = jnp.where(
-                sel, vn_ref[0][:, None, :].astype(vo_ref.dtype), v_ref[0]
-            )
-        else:
-            # Quantize the new token's K/V in-kernel via the SAME function
-            # the jnp prefill path uses (common.quantize_kv) — decode and
-            # prefill quantization agree bit-for-bit by construction. The
-            # scale pools merge the same way against a lane iota.
-            col = lax.broadcasted_iota(jnp.int32, ks_ref[0].shape, 1)
-            scol = insert & (col == off)                  # [K, SCALE_LANES]
-            for new_ref, in_ref, out_ref, sin_ref, sout_ref in (
-                (kn_ref, k_ref, ko_ref, ks_ref, kso_ref),
-                (vn_ref, v_ref, vo_ref, vs_ref, vso_ref),
-            ):
-                qv, s = quantize_kv(new_ref[0])             # [K, H], [K]
-                out_ref[0] = jnp.where(
-                    sel, qv.astype(out_ref.dtype)[:, None, :], in_ref[0]
-                )
-                sout_ref[0] = jnp.where(scol, s[:, None], sin_ref[0])
+    def page_buf(s, slot, j):
+        if s < 2:
+            at = pl.ds(pl.multiple_of(j * psz, psz), psz)
+            return bufs[s].at[slot, :, at, :]
+        return bufs[s].at[slot, j]
 
-        k_src, v_src = ko_ref, vo_ref
-        ks_src, vs_src = kso_ref, vso_ref
-    else:
-        k_src, v_src = k_ref, v_ref
-        ks_src, vs_src = ks_ref, vs_ref
+    def fetch(bb, blk, slot, lo_b, hi_b, wait):
+        """Start (or wait for) the copies of block blk's live pages: a loop
+        over them, so the body is traced once whatever ``nb``."""
+        def page(j, carry):
+            row = base_ref[0] + pt_ref[bb, blk * nb + j]
+            for s in range(n_pool):
+                cp = pltpu.make_async_copy(
+                    pools[s].at[row], page_buf(s, slot, j), sems.at[slot, s])
+                if wait:
+                    cp.wait()
+                else:
+                    cp.start()
+            return carry
 
-    # Ragged skip: pages wholly beyond this sequence's context do nothing
-    # (their fetches were elided by the clamped index map). With a sliding
-    # window, pages wholly BEHIND the window skip too (same elision via the
-    # index map's lower clamp), so compute and traffic are O(window).
-    run = ip * psz <= last_pos
-    if window is not None:
-        run &= ip * psz + psz - 1 >= last_pos - window + 1
+        lax.fori_loop(jnp.maximum(lo_b - blk * nb, 0),
+                      jnp.minimum(hi_b - blk * nb + 1, nb), page, 0)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0].reshape(K, G8, H).astype(jnp.float32)
-        k = k_src[0].astype(jnp.float32)                 # [K, psz, H]
-        v = v_src[0].astype(jnp.float32)
+    start, last, lo, hi = span(b)
+    first_blk, last_blk = lo // nb, hi // nb
+
+    @pl.when((ib >= first_blk) & (ib <= last_blk))
+    def _step():
+        @pl.when((b == 0) & (ib == first_blk))
+        def _prime():
+            slot_ref[0] = 0
+            for buf in bufs:
+                buf[...] = jnp.zeros(buf.shape, buf.dtype)
+            fetch(b, ib, 0, lo, hi, wait=False)
+
+        slot = slot_ref[0]
+        at_end = ib == last_blk
+        nxt = jnp.where(at_end, b + 1, b)
+        nxt_c = jnp.minimum(nxt, B - 1)
+        _, _, lo_n, hi_n = span(nxt_c)
+
+        @pl.when(nxt < B)
+        def _prefetch():
+            fetch(nxt_c, jnp.where(at_end, lo_n // nb, ib + 1), 1 - slot,
+                  lo_n, hi_n, wait=False)
+
+        fetch(b, ib, slot, lo, hi, wait=True)
+        slot_ref[0] = 1 - slot
+
+        @pl.when(ib == first_blk)
+        def _init():
+            m_s[:] = jnp.full_like(m_s, NEG_INF)
+            l_s[:] = jnp.zeros_like(l_s)
+            acc_s[:] = jnp.zeros_like(acc_s)
+
+        def write_back(wait):
+            """Merge the row's new tokens into the pages of this block
+            that own them and copy those pages back (wait=False), or wait
+            for the copies. W consecutive positions touch at most
+            ceil((W - 1) / psz) + 1 pages."""
+            for t in range((W - 1 + psz - 1) // psz + 1):
+                pg = start // psz + t
+                j = pg - ib * nb
+
+                @pl.when((pg <= hi) & (j >= 0) & (j < nb))
+                def _():
+                    row = base_ref[0] + pt_ref[b, pg]
+                    copies = [
+                        pltpu.make_async_copy(
+                            page_buf(s, slot, j), pools[s].at[row],
+                            wsems.at[s],
+                        )
+                        for s in range(n_pool)
+                    ]
+                    if wait:
+                        for cp in copies:
+                            cp.wait()
+                        return
+                    at = pl.ds(pl.multiple_of(j * psz, psz), psz)
+                    pos = pg * psz + lax.broadcasted_iota(
+                        jnp.int32, (1, psz, 1), 1)
+                    if quant:
+                        # One lanes-padded scale row per kv head: the
+                        # page's tokens are its first psz lanes.
+                        lane = lax.broadcasted_iota(
+                            jnp.int32, (1, bufs[2].shape[-1]), 1)
+                        spos = jnp.where(lane < psz, pg * psz + lane, -1)
+                    for s, new_ref in enumerate(new_refs):
+                        page = bufs[s][slot, :, at, :]       # [K, psz, H]
+                        if quant:
+                            sc = bufs[s + 2][slot, j]        # [K, SW]
+                        for w in range(W):
+                            here = (w < ln_ref[b]) & (start + w <= last)
+                            new = new_ref[0, w]              # [K, H]
+                            if quant:
+                                # The SAME function the jnp cache paths
+                                # use, so decode, prefill and verify
+                                # quantize bit-for-bit alike.
+                                new, s_new = quantize_kv(new)
+                                sc = jnp.where(
+                                    here & (spos == start + w),
+                                    s_new[:, None], sc)
+                            page = jnp.where(
+                                here & (pos == start + w),
+                                new[:, None, :].astype(page.dtype), page)
+                        bufs[s][slot, :, at, :] = page
+                        if quant:
+                            bufs[s + 2][slot, j] = sc
+                    for cp in copies:
+                        cp.start()
+
+        if fused_write:
+            write_back(wait=False)
+
+        q = q_ref[0].reshape(K, WG8, H).astype(cdt)
+        k = bufs[0][slot].astype(cdt)                        # [K, T, H]
+        v = bufs[1][slot].astype(cdt)
         z = lax.dot_general(
-            q * scale, k, (((2,), (2,)), ((0,), (0,))),
+            q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )                                                # [K, G8, psz]
+        ) * (H ** -0.5)                                      # [K, WG8, T]
         if quant:
-            # int8 pool: the per-(head, token) K scale applies to the logit
-            # COLUMNS after the matmul (cheaper than dequantizing the
-            # [K, psz, H] block before it).
-            z = z * ks_src[0][:, :psz][:, None, :]
-        z = z.reshape(K * G8, psz)
+            # int8 pool: the per-(head, token) K scale applies to the
+            # logit COLUMNS after the matmul (cheaper than dequantizing
+            # the [K, T, H] block before it).
+            ks, vs = (
+                jnp.concatenate(
+                    [bufs[s][slot, j][:, :psz] for j in range(nb)], axis=-1)
+                for s in (2, 3)
+            )                                                # [K, T] each
+            z = z * ks[:, None, :]
+        z = z.reshape(K * WG8, T)
         if softcap is not None:
             z = softcap * jnp.tanh(z / softcap)
-        kv_pos = ip * psz + lax.broadcasted_iota(
-            jnp.int32, (K * G8, psz), 1
-        )
-        mask = kv_pos <= last_pos
-        if window is not None:
-            # q sits at last_pos: attend iff last_pos - kv_pos < window.
-            mask &= kv_pos >= last_pos - window + 1
+        kv_pos = ib * T + lax.broadcasted_iota(jnp.int32, (K * WG8, T), 1)
+        # Row r of a K-band holds query w = r // G (padding rows past W*G
+        # clamp to the last query; their outputs are sliced away).
+        qw = 0
+        if W > 1:
+            rowq = lax.broadcasted_iota(jnp.int32, (K * WG8, T), 0) % WG8
+            qw = jnp.minimum(rowq // G, W - 1)
+        if not tree:
+            q_pos = start + qw
+            mask = kv_pos <= q_pos
+            if window is not None:
+                mask &= kv_pos >= q_pos - window + 1
+        else:
+            # Token tree: committed context (kv_pos < start) is visible to
+            # every query; among the W new slots, query w sees slot i iff
+            # bit i of its ancestor word is set (or i == w). Depths
+            # replace slot order for logical positions: W static and
+            # small, so the per-row word/depth vectors build as W unrolled
+            # scalar-SMEM selects (Mosaic has no vector gather from SMEM).
+            word = jnp.zeros_like(kv_pos)
+            qdep = jnp.zeros_like(kv_pos)
+            for w in range(W):
+                word = jnp.where(qw == w, tm_ref[b, w], word)
+                qdep = jnp.where(qw == w, dp_ref[b, w], qdep)
+            slot_i = kv_pos - start
+            in_new = (slot_i >= 0) & (slot_i < W)
+            bit = (
+                lax.shift_right_logical(word, jnp.clip(slot_i, 0, 31)) & 1
+            ) == 1
+            # Boolean algebra, not a select between boolean vectors:
+            # Mosaic lowers an i1-valued select through i8 and refuses the
+            # truncation back ("Unsupported target bitwidth for
+            # truncation", libtpu 0.0.34).
+            mask = (in_new & (bit | (slot_i == qw))) | (
+                ~in_new & (kv_pos < start)
+            )
+            if window is not None:
+                # Window distance among new slots is DEPTH distance (two
+                # siblings at one depth are window-equivalent even though
+                # their pool slots differ).
+                sdep = jnp.zeros_like(slot_i)
+                for w in range(W):
+                    sdep = jnp.where(slot_i == w, dp_ref[b, w], sdep)
+                mask &= (in_new & (sdep >= qdep - window + 1)) | (
+                    ~in_new & (kv_pos >= start + qdep - window + 1)
+                )
         z = jnp.where(mask, z, NEG_INF)
 
         m_prev = m_s[:, :1]
@@ -198,126 +354,191 @@ def _kernel(
         l_s[:] = jnp.broadcast_to(
             l_s[:, :1] * alpha + p.sum(axis=-1, keepdims=True), l_s.shape
         )
-        pw = p.reshape(K, G8, psz)
+        pw = p.reshape(K, WG8, T)
         if quant:
             # Fold the V scale into the probabilities (per kv column), so
             # the PV matmul consumes the int8 block directly.
-            pw = pw * vs_src[0][:, :psz][:, None, :]
+            pw = pw * vs[:, None, :]
         pv = lax.dot_general(
-            pw, v, (((2,), (1,)), ((0,), (0,))),
+            pw.astype(cdt), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )                                                # [K, G8, H]
-        acc_s[:] = acc_s[:] * alpha + pv.reshape(K * G8, H)
+        )                                                    # [K, WG8, H]
+        acc_s[:] = acc_s[:] * alpha + pv.reshape(K * WG8, H)
         m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
 
-    @pl.when(ip == npages - 1)
-    def _finish():
-        l = l_s[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_s[:] / l_safe).astype(o_ref.dtype)
+        if fused_write:
+            write_back(wait=True)
+
+        @pl.when(at_end)
+        def _finish():
+            l = l_s[:, :1]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0] = (acc_s[:] / l_safe).astype(o_ref.dtype)
 
 
-def _call(q, k_pool, v_pool, page_table, last_pos, base, k_new, v_new,
-          softcap, window, interpret, k_scale=None, v_scale=None):
-    B, N, H = q.shape
-    rows_total, K, psz, _ = k_pool.shape
+# Jitted so that a program with many instances of the kernel (a decode
+# window holds steps x layers of them) traces and lowers each distinct one
+# once, not once a call site: pallas_call itself re-traces its kernel on
+# every call, and the block walk's body is long.
+@functools.partial(jax.jit, static_argnames=(
+    "softcap", "window", "interpret", "name", "nb"))
+def _call(q, k_pool, v_pool, page_table, start, lens, base, k_new, v_new,
+          k_scale, v_scale, tree_mask, depths, *, softcap, window, interpret,
+          name, nb):
+    B, W, N, H = q.shape
+    _, K, psz, _ = k_pool.shape
     P = page_table.shape[1]
     G = N // K
-    G8 = max(round_up(G, 8), 8)
+    WG = W * G
+    WG8 = max(round_up(WG, 8), 8)
     fused_write = k_new is not None
     quant = k_scale is not None
+    tree = tree_mask is not None
 
-    qg = q.reshape(B, K, G, H)
-    if G8 != G:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, G8 - G), (0, 0)))
-    qg = qg.reshape(B, K * G8, H)
+    # Pack the W queries' GQA bands per kv head: [K, W*G] rows, padded to
+    # a sublane multiple — the kernel recovers (w, g) from the row index.
+    qg = q.reshape(B, W, K, G, H).transpose(0, 2, 1, 3, 4)
+    qg = qg.reshape(B, K, WG, H)
+    if WG8 != WG:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, WG8 - WG), (0, 0)))
+    qg = qg.reshape(B, K * WG8, H)
 
-    def kv_index(b, ip, pt, bs, sl):
-        # Clamp the invalid tail (pages past the context) to the LAST valid
-        # page: consecutive identical block requests elide the DMA, and in
-        # fused-write mode the tail's write-backs then re-target the page
-        # that received the new token (which re-applies its insert — see
-        # _kernel) instead of clobbering some other page. With a sliding
-        # window, pages wholly behind the window clamp UP to the window's
-        # first page the same way (their write-backs rewrite that page with
-        # its own just-fetched data — harmless), eliding their DMAs too.
-        valid_ip = jnp.minimum(ip, sl[b] // psz)
-        if window is not None:
-            first = jnp.maximum(sl[b] - window + 1, 0) // psz
-            valid_ip = jnp.maximum(valid_ip, jnp.minimum(first, sl[b] // psz))
-        return (bs[0] + pt[b, valid_ip], 0, 0, 0)
+    prefetch = [
+        page_table.astype(jnp.int32), base, start.astype(jnp.int32),
+        lens.astype(jnp.int32),
+    ]
+    if tree:
+        prefetch += [tree_mask.astype(jnp.int32), depths.astype(jnp.int32)]
 
-    def row_index(b, ip, pt, bs, sl):
-        return (b, 0, 0)
-
-    q_spec = pl.BlockSpec((1, K * G8, H), row_index)
-    kv_spec = pl.BlockSpec((1, K, psz, H), kv_index)
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [qg, k_pool, v_pool]
-    if quant:
-        # One page's scales: (1, K, SCALE_LANES) f32 — a full (8, 128)
-        # lane tile, same clamped page walk as the data blocks.
-        sw = k_scale.shape[-1]
-        sc_spec = pl.BlockSpec(
-            (1, K, sw), lambda b, ip, pt, bs, sl: kv_index(
-                b, ip, pt, bs, sl)[:3]
-        )
-        in_specs += [sc_spec, sc_spec]
-        args += [k_scale, v_scale]
+    q_spec = pl.BlockSpec((1, K * WG8, H), lambda b, ib, *_: (b, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    pools = [k_pool, v_pool] + ([k_scale, v_scale] if quant else [])
+    in_specs = [q_spec] + [hbm] * len(pools)
+    args = [qg, *pools]
     out_specs = [q_spec]
-    out_shape = [jax.ShapeDtypeStruct((B, K * G8, H), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((B, K * WG8, H), q.dtype)]
     aliases = {}
     if fused_write:
-        new_spec = pl.BlockSpec((1, K, H), row_index)
+        # The runner's [B, W, K, H] as it is: token w is a leading index.
+        new_spec = pl.BlockSpec(
+            (1, W, K, H), lambda b, ib, *_: (b, 0, 0, 0))
         in_specs += [new_spec, new_spec]
         args += [k_new, v_new]
-        out_specs += [kv_spec, kv_spec]
-        out_shape += [
-            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
-        ]
-        # Operand indices count the scalar-prefetch args (pt, base, sl) and
-        # q before the pools; without quant the pools are operands 4 and 5
-        # -> outputs 1 and 2. With quant the scale pools sit between the
-        # data pools and k_new/v_new, and are themselves aliased outputs.
-        if quant:
-            sw = k_scale.shape[-1]
-            out_specs += [sc_spec, sc_spec]
-            out_shape += [
-                jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
-                jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype),
-            ]
-            aliases = {4: 1, 5: 2, 6: 3, 7: 4}
-        else:
-            aliases = {4: 1, 5: 2}
+        out_specs += [hbm] * len(pools)
+        out_shape += [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools]
+        # Operand indices count the scalar-prefetch args and q before the
+        # pools; pool i aliases output 1 + i.
+        aliases = {len(prefetch) + 1 + i: 1 + i for i in range(len(pools))}
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, P),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((K * G8, LANES), jnp.float32),
-            pltpu.VMEM((K * G8, LANES), jnp.float32),
-            pltpu.VMEM((K * G8, H), jnp.float32),
-        ],
-    )
+    T = nb * psz
+    scratch = [
+        pltpu.VMEM((K * WG8, LANES), jnp.float32),
+        pltpu.VMEM((K * WG8, LANES), jnp.float32),
+        pltpu.VMEM((K * WG8, H), jnp.float32),
+        pltpu.VMEM((2, K, T, H), k_pool.dtype),
+        pltpu.VMEM((2, K, T, H), v_pool.dtype),
+    ]
+    if quant:
+        scratch += [pltpu.VMEM((2, nb, K, k_scale.shape[-1]), jnp.float32)] * 2
+    scratch += [
+        pltpu.SemaphoreType.DMA((2, len(pools))),
+        pltpu.SemaphoreType.DMA((len(pools),)),
+        pltpu.SMEM((1,), jnp.int32),
+    ]
+    need = vmem_bytes(
+        W, n_heads=N, n_kv_heads=K, head_dim=H, page_size=psz,
+        kv_itemsize=k_pool.dtype.itemsize, quant=quant, block_pages=nb)
     out = pl.pallas_call(
         functools.partial(
-            _kernel, softcap, psz, K, G8, fused_write, window, quant
+            _kernel, softcap, psz, P, G, W, nb, fused_write, window, quant,
+            tree,
         ),
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(B, pl.cdiv(P, nb)),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        ),
         out_shape=out_shape,
         input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            # The block pipeline carries state from one grid step to the
+            # next: both axes run in order on one core.
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=(
+                None if need <= VMEM_BUDGET_BYTES else need + 8 * 2 ** 20),
+        ),
         interpret=resolve_interpret(interpret),
-        name="paged_decode",
-    )(page_table.astype(jnp.int32), base, last_pos.astype(jnp.int32), *args)
-    attn = out[0].reshape(B, K, G8, H)[:, :, :G, :].reshape(B, N, H)
-    if fused_write:
-        if quant:
-            return attn, out[1], out[2], out[3], out[4]
-        return attn, out[1], out[2]
-    return attn, k_pool, v_pool
+        name=name,
+    )(*prefetch, *args)
+    attn = out[0].reshape(B, K, WG8, H)[:, :, :WG, :]
+    attn = attn.reshape(B, K, W, G, H).transpose(0, 2, 1, 3, 4)
+    return (attn.reshape(B, W, N, H), *(out[1:] if fused_write else ()))
+
+
+def attend(q, k_pool, v_pool, page_table, start, lens, *, layer_base,
+           k_new, v_new, logit_softcap, window, interpret, k_scale, v_scale,
+           tree_mask=None, depths=None, mesh=None, tp_axis="tp",
+           name="paged_decode"):
+    """W-query attention over the paged pool: ``paged_attention`` and
+    ``ragged_paged_attention`` are this at W = 1 and at W. Returns
+    ``(out [B, W, N, H], *written pools)``."""
+    assert (k_new is None) == (v_new is None)
+    assert (k_scale is None) == (v_scale is None)
+    if window is not None and window < 1:
+        raise ValueError(f"window={window} must be >= 1")
+    N, K = q.shape[2], k_pool.shape[1]
+    assert N % K == 0, (q.shape, K)
+    base = jnp.asarray(layer_base, jnp.int32).reshape(1)
+    # Optional operands, in _call's order; None where absent.
+    opt = [k_new, v_new, k_scale, v_scale, tree_mask, depths]
+
+    def run(q_, kp_, vp_, pt_, st_, ln_, base_, *given):
+        it = iter(given)
+        full = [next(it) if o is not None else None for o in opt]
+        return _call(
+            q_, kp_, vp_, pt_, st_, ln_, base_, *full,
+            softcap=logit_softcap, window=window, interpret=interpret,
+            name=name, nb=min(BLOCK_PAGES, pt_.shape[1]),
+        )
+
+    args = [q, k_pool, v_pool, page_table, start, lens, base]
+    given = [o for o in opt if o is not None]
+    tp = mesh.shape.get(tp_axis, 1) if mesh is not None else 1
+    if tp == 1:
+        return run(*args, *given)
+    # Tensor-parallel serving: split the HEAD axes (q heads, pool kv heads,
+    # new-token kv heads, scale-pool kv heads) across ``tp_axis`` and run
+    # the kernel per shard — a bare pallas_call is opaque to XLA's
+    # partitioner, so jitting it over a tp-sharded pool would gather the
+    # whole multi-GB pool onto every device. The page walk is
+    # head-independent (page table, cursors, base, tree words replicate),
+    # and the fused in-place write stays consistent per shard: each device
+    # owns its K/tp slice of every page. G = N/K is preserved per shard.
+    if N % tp or K % tp:
+        raise ValueError(
+            f"tp-sharded paged attention needs n_heads ({N}) and "
+            f"n_kv_heads ({K}) divisible by {tp_axis}={tp}; lower tp "
+            f"or serve with kernels='xla'"
+        )
+    from jax.sharding import PartitionSpec as PS
+
+    heads4 = PS(None, None, tp_axis, None)      # q, k_new/v_new [B, W, *, H]
+    poolspec = PS(None, tp_axis, None, None)    # [rows, K, psz, H]
+    scspec = PS(None, tp_axis, None)            # [rows, K, SCALE_LANES]
+    rep = PS()
+    opt_specs = [heads4, heads4, scspec, scspec, rep, rep]
+    out_specs = [heads4]
+    if k_new is not None:
+        out_specs += [poolspec, poolspec]
+        out_specs += [scspec, scspec] if k_scale is not None else []
+    return jax.shard_map(
+        run, mesh=mesh,
+        in_specs=(heads4, poolspec, poolspec, rep, rep, rep, rep, *(
+            s for s, o in zip(opt_specs, opt) if o is not None)),
+        out_specs=tuple(out_specs), check_vma=False,
+    )(*args, *given)
 
 
 def paged_attention(
@@ -345,7 +566,8 @@ def paged_attention(
     ``(out, k_pool', v_pool')`` with the new token's K/V written into row
     ``layer_base + page_table[b, last_pos // psz]`` at column
     ``last_pos % psz`` — in place via input/output aliasing (an external
-    scatter feeding this call costs a full pool copy per layer instead).
+    scatter feeding this call costs a full pool copy per layer instead);
+    every other page of the pool is bitwise untouched.
 
     Semantics match gathering each sequence's pages (rows ``layer_base +
     page_table``) into a [B, P*psz, K, H] context, applying the scatter,
@@ -360,84 +582,15 @@ def paged_attention(
     (kv_cache.quantize_kv semantics), returning
     ``(out, k_pool', v_pool', k_scale', v_scale')``.
     """
-    assert (k_new is None) == (v_new is None)
-    assert (k_scale is None) == (v_scale is None)
-    if window is not None and window < 1:
-        raise ValueError(f"window={window} must be >= 1")
-    K = k_pool.shape[1]
-    assert q.shape[1] % K == 0, (q.shape, K)
-    base = jnp.asarray(layer_base, jnp.int32).reshape(1)
-
-    tp = mesh.shape.get(tp_axis, 1) if mesh is not None else 1
-    if tp > 1:
-        # Tensor-parallel serving: split the HEAD axes (q heads, pool kv
-        # heads, new-token kv heads, scale-pool kv heads) across ``tp_axis``
-        # and run the kernel per shard — a bare pallas_call is opaque to
-        # XLA's partitioner, so jitting it over a tp-sharded pool would
-        # gather the whole multi-GB pool onto every device. The page walk
-        # is head-independent (page_table/last_pos/base replicate), and the
-        # fused in-place write stays consistent per shard: each device
-        # owns its K/tp slice of every page. G = N/K is preserved per
-        # shard, so the in-kernel GQA mapping is unchanged.
-        N = q.shape[1]
-        if N % tp or K % tp:
-            raise ValueError(
-                f"tp-sharded paged attention needs n_heads ({N}) and "
-                f"n_kv_heads ({K}) divisible by {tp_axis}={tp}; lower tp "
-                f"or serve with kernels='xla'"
-            )
-        from jax.sharding import PartitionSpec as P
-
-        qspec = P(None, tp_axis, None)          # [B, N, H]
-        poolspec = P(None, tp_axis, None, None)  # [rows, K, psz, H]
-        rep2, rep1 = P(None, None), P(None)
-        args = [q, k_pool, v_pool, page_table, last_pos, base]
-        in_specs = [qspec, poolspec, poolspec, rep2, rep1, rep1]
-        out_specs = [qspec]
-        have_new, have_scale = k_new is not None, k_scale is not None
-        if have_new:
-            args += [k_new, v_new]
-            in_specs += [qspec, qspec]           # [B, K, H]
-            out_specs += [poolspec, poolspec]
-        if have_scale:
-            scspec = P(None, tp_axis, None)      # [rows, K, SCALE_LANES]
-            args += [k_scale, v_scale]
-            in_specs += [scspec, scspec]
-            if have_new:
-                out_specs += [scspec, scspec]
-
-        def body(q_, kp_, vp_, pt_, lp_, base_, *rest):
-            kn = vn = ks = vs = None
-            rest = list(rest)
-            if have_new:
-                kn, vn = rest[0], rest[1]
-                rest = rest[2:]
-            if have_scale:
-                ks, vs = rest[0], rest[1]
-            res = _call(
-                q_, kp_, vp_, pt_, lp_, base_, kn, vn,
-                logit_softcap, window, interpret, ks, vs,
-            )
-            if not have_new:
-                return res[0]
-            return res[:3] if not have_scale else res
-
-        mapped = jax.shard_map(
-            body, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=tuple(out_specs) if have_new else out_specs[0],
-            check_vma=False,
-        )
-        out = mapped(*args)
-        if not have_new:
-            return out
-        return tuple(out)
-
-    out = _call(
-        q, k_pool, v_pool, page_table, last_pos, base, k_new, v_new,
-        logit_softcap, window, interpret, k_scale, v_scale,
+    out = attend(
+        q[:, None], k_pool, v_pool, page_table, last_pos,
+        jnp.ones_like(last_pos),
+        layer_base=layer_base,
+        k_new=None if k_new is None else k_new[:, None],
+        v_new=None if v_new is None else v_new[:, None],
+        logit_softcap=logit_softcap, window=window, interpret=interpret,
+        k_scale=k_scale, v_scale=v_scale, mesh=mesh, tp_axis=tp_axis,
     )
     if k_new is None:
-        return out[0]
-    if k_scale is None:
-        return out[:3]
-    return out
+        return out[0][:, 0]
+    return (out[0][:, 0], *out[1:])

@@ -303,6 +303,23 @@ def test_kv_counters_know_window_layers_from_full_ones(tiny):
     engine.close()
 
 
+def test_kv_pages_read_counts_whole_pages_by_layer_kind(tiny):
+    """decode_kv_pages_read of one decode window: a full layer copies the
+    pages up to the one that takes the new token (position 30: 4 pages of
+    8), a window layer from the page of its window's first position
+    (positions 23..30: 2 pages; 24..31: 1)."""
+    from orion_tpu.infer import InferenceEngine
+
+    engine = InferenceEngine(tiny[0], tiny[1], seed=0)
+    engine.reset_timing()
+    engine._count_kv_by_layer_kind(np.asarray([30, 5], np.int64), 2)
+    t = engine.reset_timing()
+    assert t["decode_kv_pages_read"] == 2 * (4 + 4 + 1 + 1) + 4 * (
+        2 + 1 + 1 + 1)
+    assert engine.reset_timing()["decode_kv_pages_read"] == 0
+    engine.close()
+
+
 # What models of one kind were at the parent commit (afec8d0): parameter
 # tree (paths and bytes), logits and router loss of a fixed batch, sha256.
 GOLDEN = {

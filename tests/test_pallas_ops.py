@@ -270,7 +270,7 @@ class TestModelWithPallasKernels:
 # -- paged decode attention -------------------------------------------------
 
 
-def _paged_reference(q, k_pool, v_pool, page_table, last_pos):
+def _paged_reference(q, k_pool, v_pool, page_table, last_pos, window=None):
     from orion_tpu.ops.attention import attention_xla
 
     B, N, H = q.shape
@@ -281,10 +281,10 @@ def _paged_reference(q, k_pool, v_pool, page_table, last_pos):
         B, P * psz, K, H)
     v_ctx = v_pool[page_table].transpose(0, 1, 3, 2, 4).reshape(
         B, P * psz, K, H)
-    mask = (
-        jnp.arange(P * psz, dtype=jnp.int32)[None, None, :]
-        <= last_pos[:, None, None]
-    )
+    pos = jnp.arange(P * psz, dtype=jnp.int32)[None, None, :]
+    mask = pos <= last_pos[:, None, None]
+    if window is not None:
+        mask &= pos >= (last_pos - window + 1)[:, None, None]
     return attention_xla(
         q[:, None], k_ctx, v_ctx, causal=False, mask=mask
     )[:, 0]
@@ -641,6 +641,158 @@ def test_paged_attention_rejects_degenerate_window():
             q, pool, pool, jnp.zeros((1, 2), jnp.int32),
             jnp.zeros(1, jnp.int32), window=0, interpret=True,
         )
+
+
+# The block walk (PR 29): 20 pages of 16 in blocks of 8, so a row's pages
+# fill three grid steps, the last of them partial. Contexts: inside the
+# first page (blocks 1 and 2 all dead), ending on a page edge that is also a
+# block edge, one token past it (the write lands on a page's FIRST row, in a
+# block of its own), mid-way, and the whole table (a page's LAST row).
+_WALK_LAST_POS = (5, 127, 128, 200, 319)
+
+
+def _walk_case(G, K=2, key=29, dtype=jnp.float32):
+    B, H, psz, P, num_pages = len(_WALK_LAST_POS), 64, 16, 20, 128
+    keys = jax.random.split(jax.random.key(key), 5)
+    q = jax.random.normal(keys[0], (B, K * G, H), dtype)
+    k_pool = jax.random.normal(keys[1], (num_pages, K, psz, H), dtype)
+    v_pool = jax.random.normal(keys[2], (num_pages, K, psz, H), dtype)
+    k_new = jax.random.normal(keys[3], (B, K, H), dtype)
+    v_new = jax.random.normal(keys[4], (B, K, H), dtype)
+    # Distinct pages for every (row, logical page), none of them page 0.
+    perm = np.random.default_rng(key).permutation(num_pages - 1) + 1
+    page_table = jnp.asarray(perm[: B * P].reshape(B, P), jnp.int32)
+    last_pos = jnp.asarray(_WALK_LAST_POS, jnp.int32)
+    return q, k_pool, v_pool, k_new, v_new, page_table, last_pos
+
+
+@pytest.mark.parametrize("window", [None, 40, 1000])
+@pytest.mark.parametrize("G", [4, 6, 9])
+def test_paged_block_walk_matches_gather(G, window):
+    """The block walk against the gather reference over a flat 2-layer
+    pool at layer_base > 0: query groups of 4, 6 and 9 heads (row bands of
+    8 and 16), no window, a window that starts mid-block (40) and one that
+    covers every page; and the fused write leaves every page of the pool
+    other than each row's ``last_pos`` page bitwise as it was."""
+    from orion_tpu.ops.pallas.paged_attention import paged_attention
+
+    q, kp, vp, kn, vn, pt, last_pos = _walk_case(G)
+    num_pages, psz = kp.shape[0], kp.shape[2]
+    B = q.shape[0]
+    kp2 = jnp.concatenate([kp * 0.5, kp], axis=0)
+    vp2 = jnp.concatenate([vp * 0.5, vp], axis=0)
+    rows = pt[jnp.arange(B), last_pos // psz]
+    kp_ref = kp.at[rows, :, last_pos % psz].set(kn)
+    vp_ref = vp.at[rows, :, last_pos % psz].set(vn)
+    ref = _paged_reference(q, kp_ref, vp_ref, pt, last_pos, window)
+
+    out, kp3, vp3 = jax.jit(
+        lambda q, kp, vp, kn, vn: paged_attention(
+            q, kp, vp, pt, last_pos, layer_base=jnp.int32(num_pages),
+            k_new=kn, v_new=vn, window=window, interpret=True)
+    )(q, kp2, vp2, kn, vn)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    # Layer 0 untouched; layer 1 is the scatter, so every page but the
+    # rows' last_pos pages is bitwise the input and those differ in one row.
+    assert (np.asarray(kp3[:num_pages]) == np.asarray(kp2[:num_pages])).all()
+    assert (np.asarray(vp3[:num_pages]) == np.asarray(vp2[:num_pages])).all()
+    assert (np.asarray(kp3[num_pages:]) == np.asarray(kp_ref)).all()
+    assert (np.asarray(vp3[num_pages:]) == np.asarray(vp_ref)).all()
+    touched = np.zeros(num_pages, bool)
+    touched[np.asarray(rows)] = True
+    assert (np.asarray(kp3[num_pages:])[~touched]
+            == np.asarray(kp)[~touched]).all()
+
+    # Read-only call (no k_new): same walk, nothing written.
+    out_ro = paged_attention(
+        q, kp_ref, vp_ref, pt, last_pos, window=window, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out_ro), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("nb", [2, 4, 16])
+def test_paged_block_walk_any_block_size(nb, monkeypatch):
+    """The walk is right at every block size the sweep tries, a block
+    wider than what is left of the table (16 of 20) included."""
+    from orion_tpu.ops.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "BLOCK_PAGES", nb)
+    q, kp, vp, kn, vn, pt, last_pos = _walk_case(4)
+    psz = kp.shape[2]
+    rows = pt[jnp.arange(q.shape[0]), last_pos // psz]
+    kp_ref = kp.at[rows, :, last_pos % psz].set(kn)
+    vp_ref = vp.at[rows, :, last_pos % psz].set(vn)
+    for window in (None, 40):
+        out, kp2, vp2 = pa.paged_attention(
+            q, kp, vp, pt, last_pos, k_new=kn, v_new=vn, window=window,
+            interpret=True)
+        ref = _paged_reference(q, kp_ref, vp_ref, pt, last_pos, window)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=2e-5)
+        assert (np.asarray(kp2) == np.asarray(kp_ref)).all()
+        assert (np.asarray(vp2) == np.asarray(vp_ref)).all()
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_paged_block_walk_int8(window):
+    """int8 pools under the block walk: the per-page scale rows are
+    gathered beside the pages, the quantized write lands bitwise, and
+    every unwritten page and scale row is untouched."""
+    from orion_tpu.infer.kv_cache import SCALE_LANES, quantize_kv
+    from orion_tpu.ops.pallas.paged_attention import paged_attention
+
+    q, kf, vf, kn, vn, pt, last_pos = _walk_case(4, key=31)
+    num_pages, K, psz, H = kf.shape
+    B = q.shape[0]
+    pools = []
+    for pool in (kf, vf):
+        qv, s = quantize_kv(pool.transpose(0, 2, 1, 3))
+        sc = jnp.zeros((num_pages, K, SCALE_LANES), jnp.float32
+                       ).at[:, :, :psz].set(s.transpose(0, 2, 1))
+        pools.append((qv.transpose(0, 2, 1, 3), sc))
+    (kq, k_sc), (vq, v_sc) = pools
+    knq, kns = quantize_kv(kn)
+    vnq, vns = quantize_kv(vn)
+    rows, off = pt[jnp.arange(B), last_pos // psz], last_pos % psz
+    kq_ref = kq.at[rows, :, off].set(knq)
+    vq_ref = vq.at[rows, :, off].set(vnq)
+    ks_ref = k_sc.at[rows, :, off].set(kns)
+    vs_ref = v_sc.at[rows, :, off].set(vns)
+    ref = _paged_reference(
+        q, kq_ref.astype(jnp.float32) * ks_ref[:, :, :psz][..., None],
+        vq_ref.astype(jnp.float32) * vs_ref[:, :, :psz][..., None],
+        pt, last_pos, window)
+
+    out, kq2, vq2, ks2, vs2 = paged_attention(
+        q, kq, vq, pt, last_pos, k_new=kn, v_new=vn,
+        k_scale=k_sc, v_scale=v_sc, window=window, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    for got, want in ((kq2, kq_ref), (vq2, vq_ref), (ks2, ks_ref),
+                      (vs2, vs_ref)):
+        assert (np.asarray(got) == np.asarray(want)).all()
+
+
+def test_paged_block_walk_bf16_operands():
+    """A bf16 pool is multiplied as bf16 (the serving cells' precision):
+    the result is within bf16's rounding of the f32 reference over the
+    same bf16 values, and the written rows are the new token's bits."""
+    from orion_tpu.ops.pallas.paged_attention import paged_attention
+
+    q, kp, vp, kn, vn, pt, last_pos = _walk_case(4, dtype=jnp.bfloat16)
+    psz = kp.shape[2]
+    rows = pt[jnp.arange(q.shape[0]), last_pos // psz]
+    kp_ref = kp.at[rows, :, last_pos % psz].set(kn)
+    vp_ref = vp.at[rows, :, last_pos % psz].set(vn)
+    out, kp2, vp2 = paged_attention(
+        q, kp, vp, pt, last_pos, k_new=kn, v_new=vn, interpret=True)
+    assert out.dtype == jnp.bfloat16
+    ref = _paged_reference(
+        q.astype(jnp.float32), kp_ref.astype(jnp.float32),
+        vp_ref.astype(jnp.float32), pt, last_pos, None)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref), atol=3e-2)
+    assert (np.asarray(kp2) == np.asarray(kp_ref)).all()
+    assert (np.asarray(vp2) == np.asarray(vp_ref)).all()
 
 
 # -- multi-query ragged paged attention (speculative verification) ----------

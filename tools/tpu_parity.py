@@ -24,7 +24,9 @@ The paged / ragged / tree / prefill groups run at two geometries: a small
 one (8 query / 4 kv heads, 4 pages a row, window 100) and the serving leg's
 of ``chip_smoke.py`` — Mistral-7B's 32 query / 8 kv heads x 128, 64-token
 pages, 80 pages a row, window 4096 — so the kernels are compiled at the
-block shapes the engine actually dispatches.
+block shapes the engine actually dispatches. The paged and ragged groups
+also run at the Laguna cell's decode shapes (query groups of 6 and 9, 76
+pages a row, window 512).
 
 The pytest suite runs these kernels only through the Pallas interpreter on
 the fake-CPU mesh (tests/conftest.py); this script is the complementary
@@ -130,6 +132,13 @@ SMALL = Geom("", N=8, K=4, P=4, num_pages=64, window=100, straddle=127,
 # chip_smoke.py's serving leg: Mistral-7B heads, rows past the 4096 window.
 SERVE = Geom(" @serve", N=32, K=8, P=80, num_pages=384, window=4096,
              straddle=4223, P_pre=70, NC=4)
+# The laguna-s-2.1 cell's decode shapes: query groups of 6 (full layers) and
+# 9 (window layers), a 76-page table walked in blocks, window 512.
+LAGUNA = [
+    Geom(f" @laguna-G{n // 8}", N=n, K=8, P=76, num_pages=384, window=512,
+         straddle=1023, P_pre=70, NC=4)
+    for n in (48, 72)
+]
 
 
 def quantized_pools(k_pool, v_pool, psz):
@@ -779,6 +788,9 @@ def main() -> int:
         guarded(f"ragged{g.tag}", ragged_paged_checks, g)
         guarded(f"tree{g.tag}", ragged_tree_checks, g)
         guarded(f"prefill{g.tag}", paged_prefill_checks, g)
+    for g in LAGUNA:
+        guarded(f"paged{g.tag}", paged_checks, g)
+        guarded(f"ragged{g.tag}", ragged_paged_checks, g)
     guarded("norm/rope", norm_rope_checks)
 
     green = sum(ok for _, ok in RESULTS)
