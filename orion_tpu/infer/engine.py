@@ -132,9 +132,12 @@ class InferenceEngine:
         self.icfg = cfg.inference
         if self.mcfg.layer_plan is not None:
             # Layers of different shapes (head counts, a dense lead, a
-            # share of the experts) are computed by prefill and the decode
-            # window; the verify and mixed bodies share their functions but
-            # nothing compares them on such a model: refuse, by name.
+            # share of the experts) run through the same one layer body on
+            # every path (transformer.block under the runner's dense and
+            # paged backends), and the runner's verify step agrees with its
+            # decode window on such a model (tests/test_laguna.py). What is
+            # missing is a comparison of THESE paths at the engine and a
+            # benchmark cell that runs them (ROADMAP R6): refuse, by name.
             off = [name for name, on in (
                 ("inference.speculative", self.icfg.speculative),
                 ("inference.constrained", self.icfg.constrained),

@@ -87,7 +87,7 @@ class DispatchExecutor:
         if stem in ("prefill", "mixed", "mixed_verify"):
             # Blockwise paged-flash prefill (inference.paged_prefill):
             # resolved against THIS build's kernels — the XLA fallback
-            # build (kernels="xla") ignores it inside _prefill_ctx, so
+            # build (kernels="xla") ignores it inside runner._prefill_ctx, so
             # the reference body stays the degradation-ladder rung.
             kw["paged_prefill"] = icfg.paged_prefill
         if is_default:

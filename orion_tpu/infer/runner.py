@@ -24,8 +24,14 @@ copy per layer. Carrying per-layer pool slices as scan xs/ys instead makes
 XLA rewrite the whole pool every step (measured 5.4 GB/step on the 1B
 bench model — 20x the useful traffic).
 
-Model math is shared with training via models.transformer.qkv_proj /
-out_proj / mlp_or_moe — the cache runner only changes what attention reads.
+The layer itself is ``models.transformer.block``, the one body training
+runs too: this module only supplies what attention reads and where K/V go,
+as two cache backends. ``_dense_layer`` (prefill, and the chunk rows of a
+mixed step) attends a block of new tokens over [gathered prefix + own K/V]
+and writes whole pages; ``_paged_layer`` writes W new tokens a slot into the
+pool and attends over it — ``verify_step`` at W = speculate_tokens + 1, the
+decode window's step at W = 1 (``_decode_core``), so a drafted position and
+a decoded one are computed by the same code and agree bit for bit.
 Inactive batch slots point at the reserved scratch page 0 and are masked by
 seq_lens alone — no dynamic batch shapes anywhere.
 """
@@ -41,11 +47,8 @@ from orion_tpu.config import ModelConfig
 from orion_tpu.models import moe as moe_lib
 from orion_tpu.models.transformer import (
     Params,
-    _norm,
+    block,
     embed,
-    mlp_or_moe,
-    out_proj,
-    qkv_proj,
     scan_layer_plan,
     unembed,
 )
@@ -53,6 +56,9 @@ from orion_tpu.ops import attention
 from orion_tpu.ops.attention import attention_xla
 
 Cache = dict[str, jax.Array]
+# A kernel with a fused write hands the pools back in this order (the scale
+# pools only where the cache is int8).
+_POOLS = ("k", "v", "k_scale", "v_scale")
 
 
 def _scan_layers(params: Params, cfg: ModelConfig, body, init_carry):
@@ -142,7 +148,6 @@ def _prefill_ctx(
     Nb, S_pad = tokens.shape
     psz = cache["k"].shape[2]
     NP = cache["k"].shape[0] // cfg.n_layers
-    quant = "k_scale" in cache
     P_pre = 0 if prefix_pages is None else prefix_pages.shape[1]
     use_pallas, interpret = resolve_impl(cfg.kernels)
     paged = bool(paged_prefill and P_pre and use_pallas and S_pad % psz == 0)
@@ -187,16 +192,15 @@ def _prefill_ctx(
             and cfg.layer_plan is None):
         moe_stack = params["blocks"]["moe"]
     return dict(
-        moe_stack=moe_stack,
-        Nb=Nb, S_pad=S_pad, psz=psz, NP=NP, n_pages=S_pad // psz,
-        quant=quant, P_pre=P_pre, positions=positions, seg=seg,
+        moe_stack=moe_stack, psz=psz, NP=NP,
+        P_pre=P_pre, positions=positions, seg=seg,
         kv_pos=kv_pos, kv_seg=kv_seg, pages=pages,
         prefix_pages=prefix_pages, prefix_lens=prefix_lens,
         lengths=lengths, paged=paged, interpret=interpret, walk=walk,
     )
 
 
-def _prefill_layer(
+def _dense_layer(
     x: jax.Array,
     cc: Cache,
     bp: Any,
@@ -207,114 +211,95 @@ def _prefill_layer(
     mesh: Optional[jax.sharding.Mesh],
     stack=None,
 ) -> tuple[jax.Array, Cache]:
-    """One transformer layer of (possibly mid-sequence) prefill: flash/xla
-    attention over [gathered prefix pages + own K/V], then scatter the new
-    K/V pages into the carried pool. ``stack``: ``_scan_layers``' (a model
-    with a layer plan; else the one stack of ``ctx``)."""
-    Nb, psz, NP = ctx["Nb"], ctx["psz"], ctx["NP"]
-    n_pages, quant, P_pre = ctx["n_pages"], ctx["quant"], ctx["P_pre"]
+    """The dense backend: one layer of (possibly mid-sequence) prefill.
+    Attention is flash/xla over [gathered prefix pages + own K/V] with the
+    new K/V pages scattered into the carried pool, or the paged-flash kernel
+    that does both; the layer itself is ``transformer.block``. ``stack``:
+    ``_scan_layers``' (a model with a layer plan; else the one stack of
+    ``ctx``)."""
+    psz, NP, P_pre = ctx["psz"], ctx["NP"], ctx["P_pre"]
     positions, seg = ctx["positions"], ctx["seg"]
-    layer_stack = stack
-    if ctx["moe_stack"] is not None:
-        layer_stack = (ctx["moe_stack"], l)
-    h = _norm(x, bp["attn_norm"], cfg, mesh)
-    q, k, v = qkv_proj(h, bp["attn"], cfg, positions, mesh, _kind(cfg, j))
-    if P_pre and ctx["paged"]:
-        # Paged-flash prefill: the chunk's queries walk the paged history
-        # in-kernel (no dense prefix gather) and the chunk's own pages
-        # are written fused (no external scatter) — one kernel replaces
-        # the whole gather/attend/scatter body below, O(real context)
-        # HBM traffic per chunk.
-        from orion_tpu.ops.pallas.paged_flash_prefill import (
-            paged_flash_prefill,
-        )
+    win = cfg.layer_window(j)
 
-        res = paged_flash_prefill(
-            q, cc["k"], cc["v"], ctx["walk"], ctx["prefix_lens"],
-            ctx["lengths"], k, v,
-            n_prefix_pages=P_pre, layer_base=l * NP,
-            logit_softcap=cfg.attn_logit_softcap,
-            window=cfg.layer_window(j), interpret=ctx["interpret"],
-            k_scale=cc.get("k_scale"), v_scale=cc.get("v_scale"),
-            mesh=mesh,
-        )
-        cc = dict(cc)
-        if quant:
-            out, cc["k"], cc["v"], cc["k_scale"], cc["v_scale"] = res
-        else:
-            out, cc["k"], cc["v"] = res
-        a = out_proj(out, bp["attn"], cfg, h)
-        if cfg.post_norms:
-            a = _norm(a, bp["post_attn_norm"], cfg, mesh)
-        x = x + a
-        h2 = _norm(x, bp["mlp_norm"], cfg, mesh)
-        y, _ = mlp_or_moe(
-            h2, bp, cfg, mesh, valid=seg > 0, layer_stack=layer_stack)
-        _count_held_rows(cc, h2, bp, cfg, seg > 0)
-        if cfg.post_norms:
-            y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
-        return x + y, cc
-    if P_pre:
-        # Gather this layer's cached prefix K/V pages from the pool
-        # and attend tail queries over prefix + tail. [Nb, P_pre] page
-        # rows -> [Nb, P_pre*psz, K, H] (heads-major pages).
-        Kh, Hd = k.shape[2], k.shape[3]
-        rows_pre = l * NP + ctx["prefix_pages"]
-        k_pre = cc["k"][rows_pre].transpose(0, 1, 3, 2, 4)
-        v_pre = cc["v"][rows_pre].transpose(0, 1, 3, 2, 4)
-        if quant:
-            ksc = cc["k_scale"][rows_pre][..., :psz]   # [Nb,P,K,psz]
-            vsc = cc["v_scale"][rows_pre][..., :psz]
-            k_pre = k_pre.astype(jnp.float32) * ksc.transpose(
-                0, 1, 3, 2)[..., None]
-            v_pre = v_pre.astype(jnp.float32) * vsc.transpose(
-                0, 1, 3, 2)[..., None]
-        k_pre = k_pre.reshape(Nb, P_pre * psz, Kh, Hd).astype(k.dtype)
-        v_pre = v_pre.reshape(Nb, P_pre * psz, Kh, Hd).astype(v.dtype)
+    def attend(q, k, v):
+        """-> (out, the pools this layer writes, not yet traced: the page
+        scatter stays behind the feed-forward, where the compiled prefill
+        has always had it)."""
+        if P_pre and ctx["paged"]:
+            # Paged-flash prefill: the chunk's queries walk the paged history
+            # in-kernel (no dense prefix gather) and the chunk's own pages
+            # are written fused (no external scatter) — one kernel replaces
+            # the whole gather/attend/scatter below, O(real context) HBM
+            # traffic per chunk.
+            from orion_tpu.ops.pallas.paged_flash_prefill import (
+                paged_flash_prefill,
+            )
+
+            out, *pools = paged_flash_prefill(
+                q, cc["k"], cc["v"], ctx["walk"], ctx["prefix_lens"],
+                ctx["lengths"], k, v,
+                n_prefix_pages=P_pre, layer_base=l * NP,
+                logit_softcap=cfg.attn_logit_softcap,
+                window=win, interpret=ctx["interpret"],
+                k_scale=cc.get("k_scale"), v_scale=cc.get("v_scale"),
+                mesh=mesh,
+            )
+            return out, lambda: dict(zip(_POOLS, pools))
+        kv, kv_seg, kv_at = (k, v), seg, {}
+        if P_pre:
+            # Gather this layer's cached prefix K/V pages from the pool
+            # and attend tail queries over prefix + tail. [Nb, P_pre] page
+            # rows -> [Nb, P_pre*psz, K, H] (heads-major pages).
+            k_pre, v_pre = _gather_context(
+                cc, l * NP + ctx["prefix_pages"], psz, k.dtype)
+            kv = (jnp.concatenate([k_pre, k], axis=1),
+                  jnp.concatenate([v_pre, v], axis=1))
+            kv_seg = ctx["kv_seg"]
+            kv_at = dict(q_positions=positions, kv_positions=ctx["kv_pos"])
         out = attention(
-            q,
-            jnp.concatenate([k_pre, k], axis=1),
-            jnp.concatenate([v_pre, v], axis=1),
-            causal=True,
-            q_segment_ids=seg, kv_segment_ids=ctx["kv_seg"],
-            seg_pad_zero=True,
-            q_positions=positions, kv_positions=ctx["kv_pos"],
-            logit_softcap=cfg.attn_logit_softcap,
-            window=cfg.layer_window(j),
+            q, *kv, causal=True,
+            q_segment_ids=seg, kv_segment_ids=kv_seg, seg_pad_zero=True,
+            **kv_at,
+            logit_softcap=cfg.attn_logit_softcap, window=win,
             block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
             impl=cfg.kernels, mesh=mesh,
         )
-    else:
-        out = attention(
-            q, k, v, causal=True,
-            q_segment_ids=seg, kv_segment_ids=seg, seg_pad_zero=True,
-            logit_softcap=cfg.attn_logit_softcap,
-            window=cfg.layer_window(j),
-            block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
-            impl=cfg.kernels, mesh=mesh,
-        )
-    a = out_proj(out, bp["attn"], cfg, h)
-    if cfg.post_norms:
-        a = _norm(a, bp["post_attn_norm"], cfg, mesh)
-    x = x + a
-    h2 = _norm(x, bp["mlp_norm"], cfg, mesh)
+        return out, lambda: _scatter_pages(cc, k, v, l * NP + ctx["pages"])
+
     # Padded positions are not routed: nothing reads their activations
     # (segment ids mask them in attention, their KV goes to the scratch
     # page, logits come off each row's last real position).
-    y, _ = mlp_or_moe(
-        h2, bp, cfg, mesh, valid=seg > 0, layer_stack=layer_stack)
-    _count_held_rows(cc, h2, bp, cfg, seg > 0)
-    if cfg.post_norms:
-        y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
-    x = x + y
-    # Scatter this layer's K/V pages into the pool (in-place on the
-    # carried flat pool). Positions beyond each row's `length` hold
-    # garbage from the padding — decode masks them out via seq_lens,
-    # and the next real token overwrites its slot.
+    valid = seg > 0
+    if ctx["moe_stack"] is not None:
+        stack = (ctx["moe_stack"], l)
+    # Where ``prefill_step`` carries the counter (a model that holds a share
+    # of its experts), add this layer's routed rows on held experts.
+    held, tap = cc.get(HELD_ROWS), None
+    if held is not None and "moe" in bp:
+        def tap(h2):
+            nonlocal held
+            held = held + moe_lib.held_rows(
+                h2, bp["moe"]["router"], cfg, valid)
+
+    x, _, written = block(
+        x, bp, cfg, positions, attend, kind=_kind(cfg, j), mesh=mesh,
+        ffn_mesh=mesh, valid=valid, layer_stack=stack, ffn_tap=tap)
+    cc = {**cc, **written()}
+    if held is not None:
+        cc[HELD_ROWS] = held
+    return x, cc
+
+
+def _scatter_pages(cc: Cache, k: jax.Array, v: jax.Array, rows: jax.Array):
+    """Whole pages of new K/V [Nb, S, K, H] into pool rows ``rows``
+    [Nb, S // psz] (in place on the carried flat pool): the pools written,
+    by name. Positions beyond a row's length hold garbage from the padding
+    — decode masks them out via seq_lens, and the next real token
+    overwrites its slot."""
+    (Nb, n_pages), psz = rows.shape, cc["k"].shape[2]
     K, H = k.shape[2], k.shape[3]
-    rows = l * NP + ctx["pages"]                 # [Nb, n_pages]
-    cc = dict(cc)
-    if quant:
+    new = {}
+    if "k_scale" in cc:
         from orion_tpu.infer.kv_cache import quantize_kv
 
         # Per (token, head) int8 + f32 scale; scale pages land in the
@@ -323,14 +308,29 @@ def _prefill_layer(
         v, vs = quantize_kv(v)
         kspg = ks.reshape(Nb, n_pages, psz, K).transpose(0, 1, 3, 2)
         vspg = vs.reshape(Nb, n_pages, psz, K).transpose(0, 1, 3, 2)
-        cc["k_scale"] = cc["k_scale"].at[rows, :, :psz].set(kspg)
-        cc["v_scale"] = cc["v_scale"].at[rows, :, :psz].set(vspg)
+        new["k_scale"] = cc["k_scale"].at[rows, :, :psz].set(kspg)
+        new["v_scale"] = cc["v_scale"].at[rows, :, :psz].set(vspg)
     # Pool pages are [K, psz, H] (heads major, see kv_cache.py).
     kpages = k.reshape(Nb, n_pages, psz, K, H).transpose(0, 1, 3, 2, 4)
     vpages = v.reshape(Nb, n_pages, psz, K, H).transpose(0, 1, 3, 2, 4)
-    cc["k"] = cc["k"].at[rows].set(kpages)
-    cc["v"] = cc["v"].at[rows].set(vpages)
-    return x, cc
+    new["k"] = cc["k"].at[rows].set(kpages)
+    new["v"] = cc["v"].at[rows].set(vpages)
+    return new
+
+
+def _gather_context(cc: Cache, rows: jax.Array, psz: int, dtype):
+    """The pool rows ``rows`` [B, P] as a padded context: K and V
+    [B, P*psz, K, H] in ``dtype``, dequantized where the pool is int8."""
+    B, P = rows.shape
+    out = []
+    for name in ("k", "v"):
+        # [B, P, K, psz, H] -> [B, P, psz, K, H] (heads-major pages).
+        c = cc[name][rows].transpose(0, 1, 3, 2, 4)
+        if name + "_scale" in cc:
+            sc = cc[name + "_scale"][rows][..., :psz]      # [B, P, K, psz]
+            c = c.astype(jnp.float32) * sc.transpose(0, 1, 3, 2)[..., None]
+        out.append(c.reshape(B, P * psz, *c.shape[3:]).astype(dtype))
+    return out
 
 
 def _kind(cfg: ModelConfig, j: int):
@@ -340,14 +340,6 @@ def _kind(cfg: ModelConfig, j: int):
 
 
 HELD_ROWS = "held_expert_rows"
-
-
-def _count_held_rows(cc: Cache, h2, bp, cfg: ModelConfig, valid) -> None:
-    """Where ``prefill_step`` carries the counter (a model that holds a
-    share of its experts), add this layer's routed rows on held experts."""
-    if HELD_ROWS in cc and "moe" in bp:
-        cc[HELD_ROWS] = cc[HELD_ROWS] + moe_lib.held_rows(
-            h2, bp["moe"]["router"], cfg, valid)
 
 
 def _prefill_logits(
@@ -412,7 +404,7 @@ def prefill_step(
 
     def body(carry, bp, l, j, stack=None):
         x, cc = carry
-        return _prefill_layer(x, cc, bp, l, j, ctx, cfg, mesh, stack)
+        return _dense_layer(x, cc, bp, l, j, ctx, cfg, mesh, stack)
 
     x = embed(params, tokens, ctx["positions"], cfg)
     cache = dict(cache)
@@ -427,144 +419,6 @@ def prefill_step(
     return logits, cache
 
 
-def _decode_ctx(
-    cache: Cache,
-    write_pos: jax.Array,
-    page_table: jax.Array,
-    cfg: ModelConfig,
-) -> dict:
-    """Batch-level tensors the per-layer decode body consumes."""
-    B = write_pos.shape[0]
-    kp = cache["k"]
-    psz = kp.shape[2]
-    NP = kp.shape[0] // cfg.n_layers
-    P = page_table.shape[1]
-    batch_idx = jnp.arange(B)
-    page_idx = page_table[batch_idx, write_pos // psz]   # [B]
-    offset = write_pos % psz                             # [B]
-    # KV positions valid after the write: arange <= write_pos; the
-    # (per-layer) sliding window narrows it inside the body.
-    kv_arange = jnp.arange(P * psz, dtype=jnp.int32)[None, None, :]
-    kv_base_mask = kv_arange <= write_pos[:, None, None]  # [B, 1, P*psz]
-
-    from orion_tpu.ops._dispatch import resolve_impl
-
-    use_pallas, interpret = resolve_impl(cfg.kernels)
-    return dict(
-        B=B, psz=psz, NP=NP, P=P, quant="k_scale" in cache,
-        write_pos=write_pos, page_table=page_table,
-        positions=write_pos[:, None], page_idx=page_idx, offset=offset,
-        kv_arange=kv_arange, kv_base_mask=kv_base_mask,
-        use_pallas=use_pallas, interpret=interpret,
-    )
-
-
-def _decode_layer(
-    x: jax.Array,
-    cc: Cache,
-    bp: Any,
-    l,
-    j: int,
-    ctx: dict,
-    cfg: ModelConfig,
-    mesh: Optional[jax.sharding.Mesh],
-) -> tuple[jax.Array, Cache]:
-    """One transformer layer of single-token decode: fused-write ragged
-    paged attention (pallas) or scatter + masked pool gather (xla).
-
-    LOCKSTEP: _verify_layer is this body generalized from 1 to W queries
-    per slot, branch for branch (its pallas branch is the multi-query
-    ragged paged-attention kernel, its xla branch this scatter+gather
-    with a W dim), and speculative byte-identity (greedy spec-on ==
-    spec-off, enforced by tests/test_spec_decode.py across kv_quant /
-    SWA / prefix-cache compositions) holds only while the two agree
-    op-for-op on the write/gather/dequant/mask math — fix both together.
-    """
-    B, psz, NP, P = ctx["B"], ctx["psz"], ctx["NP"], ctx["P"]
-    quant = ctx["quant"]
-    write_pos, page_table = ctx["write_pos"], ctx["page_table"]
-    page_idx, offset = ctx["page_idx"], ctx["offset"]
-    cc = dict(cc)
-    win = cfg.layer_window(j)
-    h = _norm(x, bp["attn_norm"], cfg, mesh)
-    q, k, v = qkv_proj(
-        h, bp["attn"], cfg, ctx["positions"], mesh, _kind(cfg, j))
-    K, H = k.shape[2], k.shape[3]
-    if ctx["use_pallas"]:
-        # Ragged paged-attention kernel: walks the page table directly
-        # (compute proportional to actual context lengths) and writes
-        # the new token's K/V itself — the pool stays in place through
-        # the kernel's input/output aliasing, where an external scatter
-        # feeding the kernel would cost a pool copy per layer. Under
-        # kv_quant the kernel also dequantizes in place and quantizes
-        # the written token (scales aliased alongside).
-        from orion_tpu.ops.pallas.paged_attention import paged_attention
-
-        res = paged_attention(
-            q[:, 0], cc["k"], cc["v"], page_table, write_pos,
-            layer_base=l * NP,
-            k_new=k[:, 0], v_new=v[:, 0],
-            logit_softcap=cfg.attn_logit_softcap,
-            window=win,
-            interpret=ctx["interpret"],
-            k_scale=cc.get("k_scale"),
-            v_scale=cc.get("v_scale"),
-            mesh=mesh,
-        )
-        if quant:
-            out, cc["k"], cc["v"], cc["k_scale"], cc["v_scale"] = res
-        else:
-            out, cc["k"], cc["v"] = res
-        out = out[:, None]
-    else:
-        rows = l * NP + page_idx
-        if quant:
-            from orion_tpu.infer.kv_cache import quantize_kv
-
-            kq, ks = quantize_kv(k[:, 0])    # [B,K,H] i8, [B,K]
-            vq, vs = quantize_kv(v[:, 0])
-            cc["k"] = cc["k"].at[rows, :, offset].set(kq)
-            cc["v"] = cc["v"].at[rows, :, offset].set(vq)
-            cc["k_scale"] = cc["k_scale"].at[rows, :, offset].set(ks)
-            cc["v_scale"] = cc["v_scale"].at[rows, :, offset].set(vs)
-        else:
-            cc["k"] = cc["k"].at[rows, :, offset].set(k[:, 0])
-            cc["v"] = cc["v"].at[rows, :, offset].set(v[:, 0])
-        # [B, P, K, psz, H] -> [B, P*psz, K, H] padded-context gather.
-        k_ctx = cc["k"][l * NP + page_table].transpose(0, 1, 3, 2, 4)
-        v_ctx = cc["v"][l * NP + page_table].transpose(0, 1, 3, 2, 4)
-        if quant:
-            # Dequantize the gathered context: [B, P, psz, K] scales.
-            ksc = cc["k_scale"][l * NP + page_table][..., :psz]
-            vsc = cc["v_scale"][l * NP + page_table][..., :psz]
-            k_ctx = k_ctx.astype(jnp.float32) * ksc.transpose(
-                0, 1, 3, 2)[..., None]
-            v_ctx = v_ctx.astype(jnp.float32) * vsc.transpose(
-                0, 1, 3, 2)[..., None]
-            k_ctx = k_ctx.astype(q.dtype)
-            v_ctx = v_ctx.astype(q.dtype)
-        k_ctx = k_ctx.reshape(B, P * psz, K, H)
-        v_ctx = v_ctx.reshape(B, P * psz, K, H)
-        kv_mask = ctx["kv_base_mask"]
-        if win is not None:
-            kv_mask = kv_mask & (
-                ctx["kv_arange"] >= (write_pos - win + 1)[:, None, None]
-            )
-        out = attention_xla(
-            q, k_ctx, v_ctx, causal=False, mask=kv_mask,
-            logit_softcap=cfg.attn_logit_softcap,
-        )
-    a = out_proj(out, bp["attn"], cfg, h)
-    if cfg.post_norms:
-        a = _norm(a, bp["post_attn_norm"], cfg, mesh)
-    x = x + a
-    h2 = _norm(x, bp["mlp_norm"], cfg, mesh)
-    y, _ = mlp_or_moe(h2, bp, cfg)
-    if cfg.post_norms:
-        y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
-    return x + y, cc
-
-
 def _decode_core(
     params: Params,
     cache: Cache,
@@ -574,12 +428,13 @@ def _decode_core(
     cfg: ModelConfig,
     mesh: Optional[jax.sharding.Mesh] = None,
 ) -> tuple[jax.Array, Cache]:
-    """One decode forward for every slot -> (logits [B, V], cache')."""
-    ctx = _decode_ctx(cache, write_pos, page_table, cfg)
+    """One decode forward for every slot -> (logits [B, V], cache'): the
+    paged backend at W = 1."""
+    ctx = _one_token_ctx(cache, write_pos, page_table, cfg)
 
     def body(carry, bp, l, j, stack=None):
         x, cc = carry
-        return _decode_layer(x, cc, bp, l, j, ctx, cfg, mesh)
+        return _paged_layer(x, cc, bp, l, j, ctx, cfg, mesh)
 
     x = embed(params, tokens[:, None], ctx["positions"], cfg)
     x, cache = _scan_layers(params, cfg, body, (x, dict(cache)))
@@ -650,25 +505,28 @@ def decode_window(
     return toks, cache
 
 
-def _verify_ctx(
+def _paged_ctx(
     cache: Cache,
     seq_lens: jax.Array,      # [B] accepted-token cursor per slot
-    lens: jax.Array,          # [B] real verify tokens this row (1..W)
+    lens: jax.Array,          # [B] real tokens this row (1..W)
     page_table: jax.Array,    # [B, pages_per_seq]
     active: jax.Array,        # [B] bool
     W: int,
     max_seq_len: int,
     cfg: ModelConfig,
+    name: str = "ragged_paged",             # the kernel's name in a trace
     depths: Optional[jax.Array] = None,     # [B, W] tree depth per column
     tree_mask: Optional[jax.Array] = None,  # [B, W] packed ancestor words
 ) -> dict:
-    """Batch-level tensors for the verify body (speculative decoding).
+    """Batch-level tensors of the paged backend (``_paged_layer``): W new
+    tokens per slot written into the pool and attended over it. Draft
+    verification is this at W = speculate_tokens + 1, the decode window's
+    step at W = 1 (``_one_token_ctx``).
 
     Row b holds ``lens[b]`` real tokens — the pending last token plus its
     drafts — writing KV at positions ``seq_lens[b] + j``. Unlike prefill
     chunks these start MID-PAGE (the cursor is arbitrary), so per-token
-    (page, offset) pairs come from the page table exactly as decode's do;
-    unlike decode there are W of them per row. Padding positions (j >=
+    (page, offset) pairs come from the page table. Padding positions (j >=
     lens, inactive rows, past max_seq_len) scatter to scratch page 0 on
     the xla branch — never clamped onto a real page, so a row near the
     context limit cannot clobber its own final KV slot the way a clamp
@@ -759,8 +617,10 @@ def _verify_ctx(
     # uses.
     start = jnp.minimum(seq_lens, max_seq_len - 1).astype(jnp.int32)
     k_lens = jnp.clip(jnp.minimum(lens, max_seq_len - start), 1, W)
+    if W == 1:      # what the clip says, which XLA does not fold
+        k_lens = jnp.ones_like(start)
     return dict(
-        B=B, W=W, psz=psz, NP=NP, P=P, quant="k_scale" in cache,
+        psz=psz, NP=NP, name=name,
         page_table=page_table, positions=rope_pos, q_pos=q_pos,
         page_idx=page_idx, offset=offset,
         kv_arange=kv_arange, kv_base_mask=kv_base_mask,
@@ -771,7 +631,23 @@ def _verify_ctx(
     )
 
 
-def _verify_layer(
+def _one_token_ctx(
+    cache: Cache, write_pos: jax.Array, page_table: jax.Array,
+    cfg: ModelConfig,
+) -> dict:
+    """The paged backend's tensors for ONE token per slot at ``write_pos``
+    [B], every row live: the decode window's step and the decode half of a
+    mixed step. The caller keeps ``write_pos`` inside the context
+    (``decode_window`` clamps a frozen slot onto its own last column), so
+    the context limit here is the page table's reach and no write is sent
+    to scratch; the kernel keeps the name the decode metrics read."""
+    return _paged_ctx(
+        cache, write_pos, jnp.ones_like(write_pos), page_table,
+        jnp.ones(write_pos.shape, bool), 1,
+        page_table.shape[1] * cache["k"].shape[2], cfg, name="paged_decode")
+
+
+def _paged_layer(
     x: jax.Array,
     cc: Cache,
     bp: Any,
@@ -781,98 +657,66 @@ def _verify_layer(
     cfg: ModelConfig,
     mesh: Optional[jax.sharding.Mesh],
 ) -> tuple[jax.Array, Cache]:
-    """One transformer layer of batched draft verification: the decode
-    body generalized from one query to W per slot — every draft
+    """The paged backend: one layer of W new tokens per slot — every
     position's K/V lands in the pool first (quantized under kv_quant,
-    exactly as a sequential decode would have written it), then each
-    query attends the context up to its own position. One pass over this
-    layer's weights serves all W positions of all slots; position i's
-    logits therefore match the i-th sequential decode step's bit-for-bit,
-    which is what makes greedy acceptance exact.
+    exactly as a sequential decode would have written it), then each query
+    attends the context up to its own position; the layer itself is
+    ``transformer.block``. One pass over this layer's weights serves all W
+    positions of all slots, and because the decode step IS this at W = 1,
+    position i's logits match the i-th sequential decode step's
+    bit-for-bit, which is what makes greedy acceptance exact
+    (tests/test_spec_decode.py).
 
-    Pallas branch: the multi-query ragged paged-attention kernel
-    (ops/pallas/ragged_paged_attention.py) — the fused-write W=1 decode
-    kernel generalized to W ragged queries, writing all lens[b] drafts'
-    K/V in-kernel (aliased pools, quantized in-kernel under kv_quant with
-    the shared common.quantize_kv, so its written bytes match this body's
-    xla scatter bit-for-bit). XLA branch: scatter + masked padded-context
-    gather, the reference.
-
-    LOCKSTEP: this is _decode_layer with a W dimension, branch for
-    branch — any change to either body's write/gather/dequant/mask math
-    must land in both, or the greedy spec-on == spec-off equivalence
-    suite (tests/test_spec_decode.py) fails."""
-    B, W, psz, NP, P = ctx["B"], ctx["W"], ctx["psz"], ctx["NP"], ctx["P"]
-    quant = ctx["quant"]
-    page_table = ctx["page_table"]
-    page_idx, offset = ctx["page_idx"], ctx["offset"]
-    cc = dict(cc)
+    Pallas branch: the ragged paged-attention kernel
+    (ops/pallas/paged_attention.attend) walks each slot's page table once
+    for all W queries (compute proportional to actual context lengths),
+    masks queries causally among the W new positions, and writes every real
+    token's K/V itself — the pool stays in place through the kernel's
+    input/output aliasing, where an external scatter feeding the kernel
+    would cost a pool copy per layer. Under kv_quant it dequantizes in
+    place and quantizes the written tokens with the shared
+    common.quantize_kv, so its written bytes match the xla scatter
+    bit-for-bit. Rows with all-zero page-table entries (inactive /
+    mid-prefill slots) read and write only the reserved scratch page, like
+    the xla branch's `valid` redirect. XLA branch: scatter + masked
+    padded-context gather, the reference."""
+    psz, NP, page_table = ctx["psz"], ctx["NP"], ctx["page_table"]
     win = cfg.layer_window(j)
-    h = _norm(x, bp["attn_norm"], cfg, mesh)
-    q, k, v = qkv_proj(
-        h, bp["attn"], cfg, ctx["positions"], mesh, _kind(cfg, j))
-    K, H = k.shape[2], k.shape[3]
-    if ctx["use_pallas"]:
-        # Multi-query ragged paged attention: one kernel walks each
-        # slot's pages once for all W queries (page DMAs amortized W×),
-        # writes every real draft's K/V in place through the aliased
-        # pools, and masks queries causally among the W new positions —
-        # the verify step stops being the one step type that abandons
-        # the fused kernels. Rows with all-zero page-table entries
-        # (inactive / mid-prefill slots) read and write only the
-        # reserved scratch page, like the xla branch's `valid` redirect.
-        from orion_tpu.ops.pallas.ragged_paged_attention import (
-            ragged_paged_attention,
-        )
 
-        res = ragged_paged_attention(
-            q, cc["k"], cc["v"], page_table, ctx["start"], ctx["k_lens"],
-            layer_base=l * NP,
-            k_new=k, v_new=v,
-            logit_softcap=cfg.attn_logit_softcap,
-            window=win,
-            interpret=ctx["interpret"],
-            k_scale=cc.get("k_scale"),
-            v_scale=cc.get("v_scale"),
-            tree_mask=ctx["tree_mask"],
-            depths=ctx["depths"],
-            mesh=mesh,
-        )
-        if quant:
-            out, cc["k"], cc["v"], cc["k_scale"], cc["v_scale"] = res
-        else:
-            out, cc["k"], cc["v"] = res
-    else:
-        rows = l * NP + page_idx                   # [B, W]
-        if quant:
+    def attend(q, k, v):
+        new = dict(cc)
+        if ctx["use_pallas"]:
+            from orion_tpu.ops.pallas.paged_attention import attend as kernel
+
+            out, *pools = kernel(
+                q, cc["k"], cc["v"], page_table, ctx["start"], ctx["k_lens"],
+                layer_base=l * NP,
+                k_new=k, v_new=v,
+                logit_softcap=cfg.attn_logit_softcap,
+                window=win,
+                interpret=ctx["interpret"],
+                k_scale=cc.get("k_scale"),
+                v_scale=cc.get("v_scale"),
+                tree_mask=ctx["tree_mask"],
+                depths=ctx["depths"],
+                mesh=mesh,
+                name=ctx["name"],
+            )
+            new.update(zip(_POOLS, pools))
+            return out, new
+        rows, offset = l * NP + ctx["page_idx"], ctx["offset"]    # [B, W]
+        written = {"k": k, "v": v}
+        if "k_scale" in cc:
             from orion_tpu.infer.kv_cache import quantize_kv
 
-            kq, ks = quantize_kv(k)                # [B,W,K,H] i8, [B,W,K]
-            vq, vs = quantize_kv(v)
-            cc["k"] = cc["k"].at[rows, :, offset].set(kq)
-            cc["v"] = cc["v"].at[rows, :, offset].set(vq)
-            cc["k_scale"] = cc["k_scale"].at[rows, :, offset].set(ks)
-            cc["v_scale"] = cc["v_scale"].at[rows, :, offset].set(vs)
-        else:
-            cc["k"] = cc["k"].at[rows, :, offset].set(k)
-            cc["v"] = cc["v"].at[rows, :, offset].set(v)
-        # [B, P, K, psz, H] -> [B, P*psz, K, H] padded-context gather (the
-        # just-written draft K/V reads back out of the pool, so under
-        # kv_quant each query attends its drafts DEQUANTIZED — the decode
-        # path's exact numerics).
-        k_ctx = cc["k"][l * NP + page_table].transpose(0, 1, 3, 2, 4)
-        v_ctx = cc["v"][l * NP + page_table].transpose(0, 1, 3, 2, 4)
-        if quant:
-            ksc = cc["k_scale"][l * NP + page_table][..., :psz]
-            vsc = cc["v_scale"][l * NP + page_table][..., :psz]
-            k_ctx = k_ctx.astype(jnp.float32) * ksc.transpose(
-                0, 1, 3, 2)[..., None]
-            v_ctx = v_ctx.astype(jnp.float32) * vsc.transpose(
-                0, 1, 3, 2)[..., None]
-            k_ctx = k_ctx.astype(q.dtype)
-            v_ctx = v_ctx.astype(q.dtype)
-        k_ctx = k_ctx.reshape(B, P * psz, K, H)
-        v_ctx = v_ctx.reshape(B, P * psz, K, H)
+            written["k"], written["k_scale"] = quantize_kv(k)
+            written["v"], written["v_scale"] = quantize_kv(v)
+        for name, val in written.items():   # [B,W,K,H] (scales [B,W,K])
+            new[name] = cc[name].at[rows, :, offset].set(val)
+        # Padded-context gather (the just-written K/V reads back out of the
+        # pool, so under kv_quant each query attends its own dispatch's
+        # tokens DEQUANTIZED — what a later step reads of them).
+        k_ctx, v_ctx = _gather_context(new, l * NP + page_table, psz, q.dtype)
         kv_mask = ctx["kv_base_mask"]
         if win is not None:
             wmask = (
@@ -891,15 +735,11 @@ def _verify_layer(
             q, k_ctx, v_ctx, causal=False, mask=kv_mask,
             logit_softcap=cfg.attn_logit_softcap,
         )
-    a = out_proj(out, bp["attn"], cfg, h)
-    if cfg.post_norms:
-        a = _norm(a, bp["post_attn_norm"], cfg, mesh)
-    x = x + a
-    h2 = _norm(x, bp["mlp_norm"], cfg, mesh)
-    y, _ = mlp_or_moe(h2, bp, cfg)
-    if cfg.post_norms:
-        y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
-    return x + y, cc
+        return out, new
+
+    x, _, cc = block(
+        x, bp, cfg, ctx["positions"], attend, kind=_kind(cfg, j), mesh=mesh)
+    return x, cc
 
 
 def _draft_next(tokens: jax.Array, lens: jax.Array) -> jax.Array:
@@ -912,6 +752,37 @@ def _draft_next(tokens: jax.Array, lens: jax.Array) -> jax.Array:
     )
     steps = jnp.arange(W, dtype=jnp.int32)[None, :]
     return jnp.where(steps + 1 < lens[:, None], shifted, -1)
+
+
+def _verdicts(
+    logits: jax.Array,        # [B, W, V]
+    tokens: jax.Array, lens: jax.Array, active: jax.Array, key: jax.Array,
+    *, temperature, top_k, top_p, parents, legal_mask, nan_guard: bool,
+) -> tuple[jax.Array, ...]:
+    """``(accept [B, W], alt [B, W])`` off a verify dispatch's logits (the
+    chain walk, or the CHILD-indexed tree walk where ``parents`` is given),
+    with ``ok`` [B] third under ``nan_guard``."""
+    from orion_tpu.infer.sampling import (
+        spec_verify_sample,
+        spec_verify_sample_tree,
+    )
+
+    sampling = dict(temperature=temperature, top_k=top_k, top_p=top_p,
+                    legal_mask=legal_mask)
+    if parents is None:
+        verdicts = spec_verify_sample(
+            logits, _draft_next(tokens, lens), key, **sampling)
+    else:
+        verdicts = spec_verify_sample_tree(
+            logits, tokens, parents, lens, key, **sampling)
+    if nan_guard:
+        # Per-slot finite check over the row's REAL positions only (padding
+        # positions compute on scratch-page garbage by design).
+        steps = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
+        valid = active[:, None] & (steps < lens[:, None])
+        ok = jnp.where(valid, jnp.isfinite(logits).all(-1), True).all(-1)
+        return (*verdicts, ok)
+    return tuple(verdicts)
 
 
 def verify_step(
@@ -955,14 +826,13 @@ def verify_step(
     sampling.spec_verify_sample; the engine walks each row to its first
     rejection and emits ``accepted drafts + one bonus/correction token``.
 
-    The body follows the decode step's resolve_impl switch: under
-    kernels='pallas' each layer runs the multi-query ragged
-    paged-attention kernel (page walk + in-kernel fused write for all W
-    positions — the pool gather never materializes, and the page DMAs
-    amortize over the W queries); under 'xla' it is the decode body's
-    scatter + masked gather with a W dimension, kept as the reference.
-    Either way the per-position logits match sequential decode on the
-    same kernel setting bit-for-bit.
+    Each layer is ``_paged_layer``, the code the decode window's step runs
+    at W = 1: under kernels='pallas' the ragged paged-attention kernel (page
+    walk + in-kernel fused write for all W positions — the pool gather never
+    materializes, and the page DMAs amortize over the W queries); under
+    'xla' scatter + masked gather, kept as the reference. Either way the
+    per-position logits match sequential decode on the same kernel setting
+    bit-for-bit.
 
     Token trees (``depths``/``parents``/``tree_mask`` given): columns
     1..lens-1 hold a flattened DraftTree instead of a chain — writes
@@ -979,44 +849,22 @@ def verify_step(
     already uses. ``None`` keeps this the unconstrained trace (its own
     jit specialization), which is what the byte-identity pin tests.
     """
-    from orion_tpu.infer.sampling import (
-        spec_verify_sample,
-        spec_verify_sample_tree,
-    )
-
-    W = tokens.shape[1]
-    ctx = _verify_ctx(
-        cache, seq_lens, lens, page_table, active, W, max_seq_len, cfg,
-        depths=depths, tree_mask=tree_mask,
+    ctx = _paged_ctx(
+        cache, seq_lens, lens, page_table, active, tokens.shape[1],
+        max_seq_len, cfg, depths=depths, tree_mask=tree_mask,
     )
 
     def body(carry, bp, l, j, stack=None):
         x, cc = carry
-        return _verify_layer(x, cc, bp, l, j, ctx, cfg, mesh)
+        return _paged_layer(x, cc, bp, l, j, ctx, cfg, mesh)
 
     x = embed(params, tokens, ctx["positions"], cfg)
     x, cache = _scan_layers(params, cfg, body, (x, dict(cache)))
     logits = unembed(params, x, cfg, mesh)                 # [B, W, V]
-    if parents is None:
-        accept, alt = spec_verify_sample(
-            logits, _draft_next(tokens, lens), key,
-            temperature=temperature, top_k=top_k, top_p=top_p,
-            legal_mask=legal_mask,
-        )
-    else:
-        accept, alt = spec_verify_sample_tree(
-            logits, tokens, parents, lens, key,
-            temperature=temperature, top_k=top_k, top_p=top_p,
-            legal_mask=legal_mask,
-        )
-    if nan_guard:
-        # Per-slot finite check over the row's REAL positions only (padding
-        # positions compute on scratch-page garbage by design).
-        steps = jnp.arange(W, dtype=jnp.int32)[None, :]
-        valid = active[:, None] & (steps < lens[:, None])
-        ok = jnp.where(valid, jnp.isfinite(logits).all(-1), True).all(-1)
-        return accept, alt, ok, cache
-    return accept, alt, cache
+    return (*_verdicts(
+        logits, tokens, lens, active, key, temperature=temperature,
+        top_k=top_k, top_p=top_p, parents=parents, legal_mask=legal_mask,
+        nan_guard=nan_guard), cache)
 
 
 def mixed_step(
@@ -1049,11 +897,11 @@ def mixed_step(
 
     Returns ``(decode_tokens [B], chunk_logits [Nc, V], cache)``.
 
-    Each layer runs the decode body (fused-write ragged paged attention —
-    the same math as ``decode_window`` with W=1, so the greedy decode
+    Each layer runs the paged backend at W = 1 (fused-write ragged paged
+    attention — ``decode_window``'s own step, so the greedy decode
     stream is bit-identical to unchunked serving; sampled decode matches
     a decode_window=1 engine at equal PRNG state, while W>1 windows group
-    key splits differently) and the prefill body (a
+    key splits differently) and the dense backend (a
     prefill chunk is exactly the prefix-cache mid-sequence tail prefill:
     resume at a page-aligned ``p_prefix_lens`` over the pages earlier
     chunks already wrote, flash attention with per-row segment ids
@@ -1078,12 +926,12 @@ def mixed_step(
         params, cache, p_tokens, p_lengths, p_pages, p_prefix_lens,
         p_prefix_pages, cfg, paged_prefill=paged_prefill,
     )
-    dctx = _decode_ctx(cache, wp, page_table, cfg)
+    dctx = _one_token_ctx(cache, wp, page_table, cfg)
 
     def body(carry, bp, l, j, stack=None):
         xp, xd, cc = carry
-        xp, cc = _prefill_layer(xp, cc, bp, l, j, pctx, cfg, mesh, stack)
-        xd, cc = _decode_layer(xd, cc, bp, l, j, dctx, cfg, mesh)
+        xp, cc = _dense_layer(xp, cc, bp, l, j, pctx, cfg, mesh, stack)
+        xd, cc = _paged_layer(xd, cc, bp, l, j, dctx, cfg, mesh)
         return xp, xd, cc
 
     xp = embed(params, p_tokens, pctx["positions"], cfg)
@@ -1132,7 +980,7 @@ def mixed_verify_step(
     tree_mask: Optional[jax.Array] = None,  # [B, W] packed ancestor words
     legal_mask: Optional[jax.Array] = None,  # [B, W, V] constraint masks
 ) -> tuple[jax.Array, ...]:
-    """``mixed_step`` with the decode half replaced by the verify body:
+    """``mixed_step`` with the decode half at W verify positions a slot:
     speculative decoding composed with chunked prefill. One dispatch runs
     up to the chunk budget of prompt tail (prompt-phase slots — they skip
     drafting by construction, their prompts ARE the chunk rows) AND a
@@ -1146,47 +994,28 @@ def mixed_verify_step(
     decoding (its pages are not in any chunk row), so the in-place pool
     updates commute.
     """
-    from orion_tpu.infer.sampling import (
-        spec_verify_sample,
-        spec_verify_sample_tree,
-    )
-
-    W = tokens.shape[1]
     pctx = _prefill_ctx(
         params, cache, p_tokens, p_lengths, p_pages, p_prefix_lens,
         p_prefix_pages, cfg, paged_prefill=paged_prefill,
     )
-    vctx = _verify_ctx(
-        cache, seq_lens, lens, page_table, active, W, max_seq_len, cfg,
-        depths=depths, tree_mask=tree_mask,
+    vctx = _paged_ctx(
+        cache, seq_lens, lens, page_table, active, tokens.shape[1],
+        max_seq_len, cfg, depths=depths, tree_mask=tree_mask,
     )
 
     def body(carry, bp, l, j, stack=None):
         xp, xv, cc = carry
-        xp, cc = _prefill_layer(xp, cc, bp, l, j, pctx, cfg, mesh, stack)
-        xv, cc = _verify_layer(xv, cc, bp, l, j, vctx, cfg, mesh)
+        xp, cc = _dense_layer(xp, cc, bp, l, j, pctx, cfg, mesh, stack)
+        xv, cc = _paged_layer(xv, cc, bp, l, j, vctx, cfg, mesh)
         return xp, xv, cc
 
     xp = embed(params, p_tokens, pctx["positions"], cfg)
     xv = embed(params, tokens, vctx["positions"], cfg)
     xp, xv, cache = _scan_layers(params, cfg, body, (xp, xv, dict(cache)))
     logits = unembed(params, xv, cfg, mesh)                # [B, W, V]
-    if parents is None:
-        accept, alt = spec_verify_sample(
-            logits, _draft_next(tokens, lens), key,
-            temperature=temperature, top_k=top_k, top_p=top_p,
-            legal_mask=legal_mask,
-        )
-    else:
-        accept, alt = spec_verify_sample_tree(
-            logits, tokens, parents, lens, key,
-            temperature=temperature, top_k=top_k, top_p=top_p,
-            legal_mask=legal_mask,
-        )
+    verdicts = _verdicts(
+        logits, tokens, lens, active, key, temperature=temperature,
+        top_k=top_k, top_p=top_p, parents=parents, legal_mask=legal_mask,
+        nan_guard=nan_guard)
     p_logits = _prefill_logits(params, xp, p_lengths, cfg, mesh)
-    if nan_guard:
-        steps = jnp.arange(W, dtype=jnp.int32)[None, :]
-        valid = active[:, None] & (steps < lens[:, None])
-        ok = jnp.where(valid, jnp.isfinite(logits).all(-1), True).all(-1)
-        return accept, alt, ok, p_logits, cache
-    return accept, alt, p_logits, cache
+    return (*verdicts, p_logits, cache)

@@ -13,7 +13,7 @@ mesh axes (dp/fsdp/tp/sp/ep) — parallelism never appears in model code.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -443,55 +443,53 @@ def mlp_or_moe(
     return _mlp_block(h, bp["mlp"], cfg), jnp.zeros((), jnp.float32)
 
 
-def _attn_block(
-    x: jax.Array,
-    p: Params,
+def _train_attend(
     cfg: ModelConfig,
-    positions: jax.Array,
     segment_ids: Optional[jax.Array],
     mesh: Optional[Any] = None,
     window: Optional[int] = None,
-    kind=None,
-) -> jax.Array:
-    """``window`` is THIS layer's sliding window (already resolved through
-    cfg.layer_window for interleaved local/global models); ``kind`` as in
-    ``qkv_proj``."""
-    q, k, v = qkv_proj(x, p, cfg, positions, mesh, kind)
-
+) -> Callable[..., tuple[jax.Array, None]]:
+    """The training layer's ``attend`` for ``block``: causal attention of a
+    layer's q over its own k/v (flash, or ``sequence_attention`` when the
+    sequence axis is live), with no state to hand on. ``window`` is THIS
+    layer's sliding window (already resolved through cfg.layer_window for
+    interleaved local/global models)."""
     sp_active = (
         cfg.sequence_axis is not None
         and mesh is not None
         and mesh.shape.get(cfg.sequence_axis, 1) > 1
     )
-    if sp_active:
-        from orion_tpu.parallel.sequence import sequence_attention
 
-        # sliding_window threads through every SP method; under "ring" it
-        # also truncates the ring scan to O(window) comm — the combination
-        # SWA exists for (long-context Mistral-family training).
-        out = sequence_attention(
-            q,
-            k,
-            v,
-            mesh,
-            method=cfg.sequence_method,
-            axis=cfg.sequence_axis,
-            causal=True,
-            q_segment_ids=segment_ids,
-            kv_segment_ids=segment_ids,
-            logit_softcap=cfg.attn_logit_softcap,
-            window=window,
-            block_q=cfg.attn_block_q,
-            block_kv=cfg.attn_block_kv,
-            impl=cfg.kernels,
-            debug_asserts=cfg.debug_asserts,
-        )
-    else:
+    def attend(q, k, v):
+        if sp_active:
+            from orion_tpu.parallel.sequence import sequence_attention
+
+            # sliding_window threads through every SP method; under "ring"
+            # it also truncates the ring scan to O(window) comm — the
+            # combination SWA exists for (long-context Mistral-family
+            # training).
+            return sequence_attention(
+                q,
+                k,
+                v,
+                mesh,
+                method=cfg.sequence_method,
+                axis=cfg.sequence_axis,
+                causal=True,
+                q_segment_ids=segment_ids,
+                kv_segment_ids=segment_ids,
+                logit_softcap=cfg.attn_logit_softcap,
+                window=window,
+                block_q=cfg.attn_block_q,
+                block_kv=cfg.attn_block_kv,
+                impl=cfg.kernels,
+                debug_asserts=cfg.debug_asserts,
+            ), None
         # Window distance is measured on token INDEX, which equals position
         # distance within a document for contiguous packed rows (positions
         # restart per doc but stay contiguous); cross-document pairs are
         # segment-masked regardless.
-        out = ops.attention(
+        return ops.attention(
             q,
             k,
             v,
@@ -508,12 +506,9 @@ def _attn_block(
             block_kv=cfg.attn_block_kv,
             impl=cfg.kernels,
             mesh=mesh,
-        )
-    # remat="names" saves the kernel output: the single most expensive
-    # per-layer tensor to rebuild (a full flash fwd pass) at [B,S,N,H]
-    # storage. (No-op identity under every other policy.)
-    out = checkpoint_name(out, "attn_out")
-    return out_proj(out, p, cfg, x)
+        ), None
+
+    return attend
 
 
 def _mlp_block(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
@@ -532,45 +527,76 @@ def _mlp_block(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
     return y
 
 
-def _block(
+def block(
     x: jax.Array,
     bp: Params,
     cfg: ModelConfig,
     positions: jax.Array,
-    segment_ids: Optional[jax.Array],
-    mesh: Optional[Any] = None,
-    window: Optional[int] = None,
+    attend: Callable[..., tuple[jax.Array, Any]],
+    *,
     kind=None,
-) -> tuple[jax.Array, jax.Array]:
-    """One transformer block. Returns (x, moe_aux_loss).
+    mesh: Optional[Any] = None,
+    ffn_mesh: Optional[Any] = None,
+    valid: Optional[jax.Array] = None,
+    layer_stack: Optional[tuple[Params, jax.Array]] = None,
+    ffn_tap: Optional[Callable[[jax.Array], None]] = None,
+) -> tuple[jax.Array, jax.Array, Any]:
+    """One transformer layer, the only place one is written: training,
+    prefill, the decode window and draft verification all call it. Returns
+    ``(x, moe_aux_loss, state)``.
 
-    ``window``: this layer's resolved sliding window (``kind``, where a
-    model's layers differ, carries it too). With cfg.post_norms
-    (Gemma-family) each sublayer output is normalized again before the
-    residual add.
+    ``attend(q, k, v) -> (out [B, S, N, H], state)`` is all that differs
+    between them: what attention reads and where K/V go (``_train_attend``
+    here; the dense and paged backends of ``infer/runner.py``, whose state
+    is the KV pool). The body never sees a cache, a page table or a segment
+    mask. ``kind`` (``cfg.layer_kind(j)``, static; None is the model's one
+    kind) reaches ``qkv_proj``; with cfg.post_norms (Gemma-family) each
+    sublayer output is normalized again before the residual add.
+
+    The feed-forward's arguments are each caller's own (``mlp_or_moe``),
+    and each caller passes what it passed when it had a body of its own.
+    ``ffn_mesh`` is apart from ``mesh`` (norms, rotary kernel) because the
+    paged backend (decode, verify) passes none: no serving path ever did
+    until PR 26 gave prefill the mesh for the grouped kernel's shard_map,
+    and with none ``sorted_a2a`` computes as ``sorted``, which a [B, 1 or
+    W, D] block needs in any case (the all-to-all layout splits S over
+    ``ep``). It passes no ``layer_stack`` and no ``valid`` because only
+    the dropless grouped path reads either, and ``moe.takes_grouped_path``
+    keeps a block that short on its buckets (PR 26). Inherited more than
+    decided: ROADMAP D13.
+    ``ffn_tap`` is handed the feed-forward's normed input (the runner counts
+    a prefill's held-expert rows off it).
 
     jax.named_scope annotations label the phases in profiler traces
     (SURVEY.md §6 "Tracing / profiling": xprof shows attention vs mlp time
-    per block without guessing from fused-op names).
+    per block without guessing from fused-op names); the checkpoint_name
+    marks are what remat="names" saves, identities in every other program.
     """
     with jax.named_scope("attention"):
-        xn = checkpoint_name(
+        h = checkpoint_name(
             _norm(x, bp["attn_norm"], cfg, mesh), "attn_norm_out"
         )
-        a = _attn_block(xn, bp["attn"], cfg,
-                        positions, segment_ids, mesh, window, kind)
+        q, k, v = qkv_proj(h, bp["attn"], cfg, positions, mesh, kind)
+        out, state = attend(q, k, v)
+        # remat="names" saves the kernel output: the single most expensive
+        # per-layer tensor to rebuild (a full flash fwd pass) at [B,S,N,H]
+        # storage. (No-op identity under every other policy.)
+        out = checkpoint_name(out, "attn_out")
+        a = out_proj(out, bp["attn"], cfg, h)
         if cfg.post_norms:
             a = _norm(a, bp["post_attn_norm"], cfg, mesh)
         x = x + a
     with jax.named_scope("mlp_moe"):
-        h = checkpoint_name(
+        h2 = checkpoint_name(
             _norm(x, bp["mlp_norm"], cfg, mesh), "mlp_norm_out"
         )
-        y, aux = mlp_or_moe(h, bp, cfg, mesh)
+        y, aux = mlp_or_moe(h2, bp, cfg, ffn_mesh, valid, layer_stack)
+        if ffn_tap is not None:
+            ffn_tap(h2)
         y = checkpoint_name(y, "ffn_out")
         if cfg.post_norms:
             y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
-    return x + y, aux
+    return x + y, aux, state
 
 
 def scan_layer_plan(blocks: Params, plan, body, carry):
@@ -678,10 +704,11 @@ def _hidden_states(
         closing over the full-batch arrays."""
         if with_rs:
             def block_fn(carry, bp, rs):
-                return _block(
+                return block(
                     carry, bp, cfg, rs["positions"],
-                    rs.get("segment_ids"), mesh, window, kind,
-                )
+                    _train_attend(cfg, rs.get("segment_ids"), mesh, window),
+                    kind=kind, mesh=mesh, ffn_mesh=mesh,
+                )[:2]
         else:
             def block_fn(carry, bp):
                 pos = positions
@@ -689,8 +716,11 @@ def _hidden_states(
                     pos = jnp.broadcast_to(
                         pos[:1], (carry.shape[0], pos.shape[1])
                     )
-                return _block(carry, bp, cfg, pos, segment_ids, mesh, window,
-                              kind)
+                return block(
+                    carry, bp, cfg, pos,
+                    _train_attend(cfg, segment_ids, mesh, window),
+                    kind=kind, mesh=mesh, ffn_mesh=mesh,
+                )[:2]
 
         return block_fn
 

@@ -486,6 +486,14 @@ def attend(q, k_pool, v_pool, page_table, start, lens, *, layer_base,
     ``(out [B, W, N, H], *written pools)``."""
     assert (k_new is None) == (v_new is None)
     assert (k_scale is None) == (v_scale is None)
+    if (tree_mask is None) != (depths is None):
+        raise ValueError("tree_mask and depths must be given together")
+    if tree_mask is not None and q.shape[1] > 31:
+        raise ValueError(
+            f"tree verification packs the ancestor mask into int32 words: "
+            f"W={q.shape[1]} columns exceed the 31-bit budget; lower "
+            f"inference.speculate_tokens"
+        )
     if window is not None and window < 1:
         raise ValueError(f"window={window} must be >= 1")
     N, K = q.shape[2], k_pool.shape[1]

@@ -1,6 +1,6 @@
 """Blockwise paged-flash prefill: chunk queries attend paged KV history.
 
-Chunked prefill (``runner._prefill_layer`` with P_pre > 0) is a
+Chunked prefill (``runner._dense_layer`` with P_pre > 0) is a
 mid-sequence tail prefill: S_pad new tokens per slot attend the slot's
 ENTIRE paged KV history. The XLA reference body gathers the P_pre prefix
 pages into a dense [Nb, P_pre*psz, K, H] copy per chunk per layer —
@@ -441,7 +441,7 @@ def paged_flash_prefill(
     (walk steps < n_prefix_pages) plus the chunk's earlier positions,
     under the optional sliding window and logit softcap. Returns
     ``(out [B, S_pad, N, H], k_pool', v_pool'[, k_scale', v_scale'])``.
-    Semantics match ``runner._prefill_layer``'s XLA reference: the dense
+    Semantics match the XLA route of ``runner._dense_layer``: the dense
     prefix gather + flash attention + page scatter collapse into one
     kernel whose HBM traffic is O(real context), not O(padded gather
     copy), and whose VMEM is bounded by the page block, not S.

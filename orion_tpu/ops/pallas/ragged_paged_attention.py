@@ -83,7 +83,6 @@ def check_verify_fit(
         )
 
 
-
 def ragged_paged_attention(
     q: jax.Array,            # [B, W, N, H] the W new positions' queries
     k_pool: jax.Array,       # [L*num_pages, K, psz, H] flat pool
@@ -121,7 +120,7 @@ def ragged_paged_attention(
     caller discards. Rows whose page-table entries are 0 (inactive /
     mid-prefill slots) read and write only the reserved scratch page.
 
-    Semantics match ``runner._verify_layer``'s XLA reference: scatter all
+    Semantics match the XLA branch of ``runner._paged_layer``: scatter all
     W tokens, gather the padded context, mask per query. With
     ``k_scale``/``v_scale`` the pools are int8 (inference.kv_quant) and
     the fused write quantizes in-kernel (kv_cache.quantize_kv semantics),
@@ -137,14 +136,6 @@ def ragged_paged_attention(
     bit-for-bit (the degenerate case IS the plain W-query verify). Mask
     words are int32, so tree verification caps W at 31 columns.
     """
-    if (tree_mask is None) != (depths is None):
-        raise ValueError("tree_mask and depths must be given together")
-    if tree_mask is not None and q.shape[1] > 31:
-        raise ValueError(
-            f"tree verification packs the ancestor mask into int32 words: "
-            f"W={q.shape[1]} columns exceed the 31-bit budget; lower "
-            f"inference.speculate_tokens"
-        )
     out = attend(
         q, k_pool, v_pool, page_table, start, lens, layer_base=layer_base,
         k_new=k_new, v_new=v_new, logit_softcap=logit_softcap,

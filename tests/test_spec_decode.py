@@ -789,6 +789,65 @@ def test_tree_chain_degenerate_verify_step_bitwise():
     assert (np.asarray(a_plain)[:, :-1] == np.asarray(a_tree)[:, 1:]).all()
 
 
+@pytest.mark.parametrize("kv_quant", [None, "int8"])
+@pytest.mark.parametrize("kernels", ["xla", "pallas_interpret"])
+def test_verify_at_one_token_is_the_decode_step(kernels, kv_quant):
+    """The decode window's step is the paged backend at W = 1
+    (``runner._decode_core``), so ``verify_step`` at W = 1 and
+    ``decode_window`` at one step must write the same pool bytes for live
+    slots and pick the same greedy token, on both kernel paths and both pool
+    formats. The two differ only where nothing reads: a frozen slot past the
+    context clamps onto its own last column in the window and goes to the
+    scratch page in verify, so every row here is inside the context (one on
+    its last position) and the dead row carries the engine's all-zero page
+    row."""
+    import numpy as np
+
+    from orion_tpu.infer.kv_cache import init_cache
+    from orion_tpu.infer.runner import decode_window, verify_step
+
+    cfg, params = _setup(overrides=[
+        f"model.kernels={kernels}", "inference.num_pages=40"] + (
+            [f"inference.kv_quant={kv_quant}"] if kv_quant else []))
+    mcfg, icfg = cfg.model, cfg.inference
+    B, P = icfg.max_batch_size, icfg.max_seq_len // icfg.page_size
+    jnp = jax.numpy
+    cache = {}
+    for i, (name, a) in enumerate(sorted(init_cache(mcfg, icfg).items())):
+        key = jax.random.key(10 + i)        # a context that is not zeros
+        if a.dtype == jnp.int8:
+            cache[name] = jax.random.randint(key, a.shape, -127, 128, a.dtype)
+        elif name.endswith("_scale"):
+            cache[name] = jax.random.uniform(key, a.shape, a.dtype, .01, .1)
+        else:
+            cache[name] = jax.random.normal(key, a.shape, a.dtype)
+    tokens = jnp.asarray([11, 42, 7, 99], jnp.int32)
+    seq_lens = jnp.asarray([5, 17, 3, icfg.max_seq_len - 1], jnp.int32)
+    active = jnp.asarray([True, True, False, True])
+    pt = np.arange(1, 1 + B * P).reshape(B, P)
+    pt[2] = 0
+    live = np.concatenate([pt[0], pt[1], pt[3]])
+    pt = jnp.asarray(pt, jnp.int32)
+    toks, c_dec = decode_window(
+        params, dict(cache), tokens, seq_lens, pt, active,
+        jax.random.split(jax.random.key(0), 1), 0.0, 0, 1.0, mcfg,
+        icfg.max_seq_len)
+    _, alt, c_ver = verify_step(
+        params, dict(cache), tokens[:, None], seq_lens,
+        jnp.ones((B,), jnp.int32), pt, active, jax.random.key(0),
+        0.0, 0, 1.0, cfg=mcfg, max_seq_len=icfg.max_seq_len)
+    assert sorted(c_dec) == sorted(c_ver) == sorted(cache)
+    assert ("k_scale" in cache) == (kv_quant == "int8")
+    rows = (np.arange(mcfg.n_layers)[:, None] * icfg.num_pages
+            + live[None]).ravel()
+    for name in cache:
+        got, want = np.asarray(c_ver[name])[rows], np.asarray(c_dec[name])[rows]
+        assert (got == want).all(), name
+        assert (want != np.asarray(cache[name])[rows]).any(), name
+    keep = np.asarray(active)
+    assert (np.asarray(toks)[0][keep] == np.asarray(alt)[:, 0][keep]).all()
+
+
 def test_tree_sample_statistics():
     """Multi-branch rejection sampling preserves the target law: with
     two sibling drafts off the root, the emitted token (first accepted
