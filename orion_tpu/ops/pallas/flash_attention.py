@@ -9,16 +9,39 @@ Design (SURVEY.md §8 hard-part #1):
 
 - Layout inside the kernel is [batch, heads, seq, head_dim]; the public
   wrapper transposes from the model's [B, S, N, H].
-- Grid is (batch, q_head, q_block, kv_block) with the kv block innermost, so
+- Grid is (batch, q_head, q_block, kv_step) with the kv step innermost, so
   the online-softmax state (m, l, acc) lives in VMEM scratch carried across
-  the kv iterations of one q block.
+  the kv steps of one q block.
 - GQA is expressed through the k/v BlockSpec index maps (q head n reads kv
   head n * K // N); the backward dk/dv kernel accumulates over the group.
-- Causal skipping: blocks strictly above the diagonal skip their compute via
-  ``pl.when`` (DMAs still happen — acceptable; revisit with a kv-bound grid).
+- Only LIVE blocks are visited (PR 32). Where the causal mask and the window
+  are on token index (no explicit positions) a q row's live kv blocks are a
+  range that ``_kv_range`` computes from the statics, the kv axis of the grid
+  is as long as the longest such range, and the index maps take step j of row
+  iq to block ``first + j``: a dead block costs neither a grid step nor its
+  DMA (a row shorter than the longest keeps pointing at its last block, which
+  is not fetched again, and skips). ``_q_range`` is the same for the dk/dv
+  kernel's q axis. With explicit positions (ring / striped layouts) or
+  ``seg_pad_zero`` liveness is data: the range is the static superset (the
+  whole row under positions) and a ``pl.when`` test on the block's positions
+  / segment ids skips inside it. ``block_counts`` reports the visited set.
+- Every visited block is masked, also the 18 of 30 at the train shape that no
+  edge crosses: the mask on token index is three compares on one iota
+  difference, and skipping it costs more than it saves (under a ``lax.cond``
+  the forward took 6.4 ms a call where masking everywhere took 4.6; a second,
+  unmasked copy of the body would double what every program lowers for at
+  most the 0.05 to 0.25 ms that no mask at all saves; PERF.md §6 PR 32).
+- The matmuls take their operands in the inputs' own dtype (bf16 in every
+  cell) with f32 accumulation; scale, softcap, running max / sum, ``lse``,
+  ``delta`` and the accumulators are f32. (Not for speed: f32 operands time
+  the same, the MXU is not what bounds these kernels.)
 - The backward pass recomputes attention probabilities from saved (lse) as in
   the flash-attention-2 formulation: two kernels, one accumulating dq over kv
   blocks, one accumulating dk/dv over (group, q-block).
+- ``_fwd_call`` / ``_bwd_call`` are jitted on their statics, so a process
+  traces each distinct kernel once and a program lowers it once however many
+  call sites hold it (``pallas_call`` alone re-traces at every call site; a
+  model whose layers are unrolled paid for that in set-up, PERF.md §6 PR 31).
 """
 
 from __future__ import annotations
@@ -29,6 +52,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -44,9 +68,11 @@ class _Statics:
     causal: bool
     logit_softcap: Optional[float]
     q_offset: int
-    # Unpadded kv length: padded kv columns are masked in-kernel. Padded q
+    # Unpadded lengths. Padded kv columns are masked in-kernel. Padded q
     # ROWS are deliberately not masked — they produce garbage that the
-    # wrapper slices off, and their cotangents are zero in backward.
+    # wrapper slices off, and their cotangents are zero in backward; seq_q
+    # only keeps a block that nothing but padded rows reach off the grid.
+    seq_q: int
     seq_kv: int
     block_q: int
     block_kv: int
@@ -57,8 +83,8 @@ class _Statics:
     has_pos: bool = False
     # Sliding-window attention (Mistral-family): attend only to the last
     # `window` positions, i.e. 0 <= q_pos - kv_pos < window (requires
-    # causal). Blocks entirely behind the window skip like causal blocks
-    # entirely ahead of the diagonal.
+    # causal). Blocks entirely behind the window are not visited, like
+    # causal blocks entirely ahead of the diagonal.
     window: Optional[int] = None
     # Opt-in declaration that segment id 0 means PADDING (the pack_rows /
     # ragged-prefill convention): all-padding blocks then SKIP their
@@ -68,11 +94,118 @@ class _Statics:
     seg_pad_zero: bool = False
 
 
+def _static_range(st: _Statics) -> bool:
+    """Liveness is a function of block indices alone."""
+    return st.causal and not st.has_pos
+
+
+def _fdiv(x, b: int):
+    """floor(x / b) for x >= 0; a shift where ``b`` is a power of two (the
+    index maps run this on the scalar core at every grid step)."""
+    return x >> (b.bit_length() - 1) if b & (b - 1) == 0 else x // b
+
+
+def _kv_range(st: _Statics, iq, nk: int, xp=jnp):
+    """(first live kv block, number of live kv blocks) of q block ``iq``.
+
+    Exact on token index: a block is in the range if and only if one of its
+    (real q row, real kv column) pairs passes the causal and window tests.
+    The index maps call it on grid indices and ``block_counts`` on numpy
+    ranges (``xp``): one function, so what is counted is what is visited.
+    """
+    if not _static_range(st):
+        return 0 * iq, 0 * iq + nk
+    bq, bk = st.block_q, st.block_kv
+    q_min = iq * bq + st.q_offset
+    q_max = xp.minimum(iq * bq + bq - 1, st.seq_q - 1) + st.q_offset
+    hi = xp.where(q_max < 0, -1,
+                  xp.minimum(_fdiv(xp.maximum(q_max, 0), bk), nk - 1))
+    lo = 0 * iq
+    if st.window is not None:
+        # The oldest position the block's FIRST row still sees; past the
+        # last real column, nothing in the row's window exists.
+        oldest = xp.maximum(q_min - st.window + 1, 0)
+        lo = xp.where(oldest > st.seq_kv - 1, nk, _fdiv(oldest, bk))
+    return lo, xp.maximum(hi - lo + 1, 0)
+
+
+def _q_range(st: _Statics, ik, nq: int, xp=jnp):
+    """(first live q block, number of live q blocks) of kv block ``ik``:
+    ``_kv_range`` seen from the dk/dv kernel's side."""
+    if not _static_range(st):
+        return 0 * ik, 0 * ik + nq
+    bq, bk = st.block_q, st.block_kv
+    first = ik * bk - st.q_offset        # the first q row that sees the block
+    lo = xp.where(first > st.seq_q - 1, nq, _fdiv(xp.maximum(first, 0), bq))
+    hi = 0 * ik + nq - 1
+    if st.window is not None:
+        kv_max = xp.minimum(ik * bk + bk - 1, st.seq_kv - 1)
+        last = kv_max + st.window - 1 - st.q_offset   # the last q row
+        hi = xp.where(last < 0, -1,
+                      xp.minimum(_fdiv(xp.maximum(last, 0), bq), nq - 1))
+    return lo, xp.maximum(hi - lo + 1, 0)
+
+
+def _steps(st: _Statics, rng, n_outer: int, n_inner: int) -> int:
+    """Length of the grid's inner (visited) axis: the longest live range."""
+    if not _static_range(st):
+        return n_inner
+    _, cnt = rng(st, np.arange(n_outer), n_inner, np)
+    return max(int(cnt.max()), 1)
+
+
+def _step(st: _Statics, rng, outer, j, n_inner: int):
+    """(block that step ``j`` of row ``outer`` visits, whether it is inside
+    the row's live range). Past the range the step stays on the range's last
+    block, so nothing is copied for it."""
+    if not _static_range(st):
+        return j, True
+    lo, cnt = rng(st, outer, n_inner)
+    return jnp.clip(lo + jnp.minimum(j, cnt - 1), 0, n_inner - 1), j < cnt
+
+
+def _unmasked(st: _Statics, iq, ik):
+    """Whether every pair of block (iq, ik) attends on token index: no edge
+    (diagonal, window, kv padding) crosses it. Counted, not used: the
+    kernels mask these blocks too (module docstring). Padded q rows count
+    as rows."""
+    bq, bk = st.block_q, st.block_kv
+    full = ik * bk + bk <= st.seq_kv + 0 * iq
+    if st.causal:
+        q_min = iq * bq + st.q_offset
+        full &= ik * bk + bk - 1 <= q_min
+        if st.window is not None:
+            full &= q_min + bq - 1 - ik * bk < st.window
+    return full
+
+
+def visited_blocks(st: _Statics, nq: int, nk: int) -> np.ndarray:
+    """[nq, nk] bool: the (q block, kv block) cells whose body runs in
+    index mode (under positions or ``seg_pad_zero``: may run)."""
+    lo, cnt = _kv_range(st, np.arange(nq), nk, np)
+    ik = np.arange(nk)[None, :]
+    return (ik >= lo[:, None]) & (ik < (lo + cnt)[:, None])
+
+
+def block_counts(st: _Statics, nq: int, nk: int, has_seg: bool = False) -> dict:
+    """The grid of one head of one sequence: blocks in the full grid, grid
+    steps taken, blocks visited, and visited blocks that no edge crosses."""
+    seen = visited_blocks(st, nq, nk)
+    iq, ik = np.arange(nq)[:, None], np.arange(nk)[None, :]
+    return {
+        "full": nq * nk,
+        "steps": nq * _steps(st, _kv_range, nq, nk),
+        "visited": int(seen.sum()),
+        "unmasked": 0 if has_seg or st.has_pos else int(
+            (seen & _unmasked(st, iq, ik)).sum()),
+    }
+
+
 def _unpack_refs(has_seg: bool, has_pos: bool, refs):
     """(q, k, v, qseg, kseg, qpos, kpos, rest) from a kernel's ref list.
 
-    Input order matches _io_args: q, k, v, [qseg, kseg], [qpos, kpos], then
-    the kernel-specific inputs/outputs/scratch in ``rest``.
+    Input order: q, k, v, [qseg, kseg], [qpos, kpos], then the
+    kernel-specific inputs/outputs/scratch in ``rest``.
     """
     i = 3
     qseg = kseg = qpos = kpos = None
@@ -85,44 +218,68 @@ def _unpack_refs(has_seg: bool, has_pos: bool, refs):
     return refs[0], refs[1], refs[2], qseg, kseg, qpos, kpos, refs[i:]
 
 
-def _block_mask(st: _Statics, iq, ik, qseg_ref, kseg_ref, qpos_ref, kpos_ref):
-    """[bq, bk] bool mask for grid cell (iq, ik); True = attend.
+def _block_mask(st: _Statics, iq, ik, qseg_ref, kseg_ref, qpos_ref, kpos_ref,
+                kv_rows: bool = False):
+    """[bq, bk] bool mask for grid cell (iq, ik) ([bk, bq] with ``kv_rows``),
+    or None where every pair attends by construction; True = attend.
 
     qseg/kseg (and qpos/kpos) hold the FULL padded sequence of per-token
     ids (blocked (1, 1, S) — TPU tiling forbids (1, bq) blocks); sliced
     here by grid cell.
     """
     bq, bk = st.block_q, st.block_kv
-    kv_idx = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = kv_idx < st.seq_kv  # kv padding
+    shape, q_ax, kv_ax = ((bk, bq), 1, 0) if kv_rows else ((bq, bk), 0, 1)
+    mask = None
+
+    def both(a, b):
+        return b if a is None else a & b
+
+    def outer(ref_q, ref_kv):
+        q_ids = ref_q[0, 0, pl.ds(iq * bq, bq)]
+        kv_ids = ref_kv[0, 0, pl.ds(ik * bk, bk)]
+        if kv_rows:
+            return q_ids[None, :], kv_ids[:, None]
+        return q_ids[:, None], kv_ids[None, :]
+
+    kv_i = jax.lax.broadcasted_iota(jnp.int32, shape, kv_ax)
+    if st.seq_kv % bk:
+        mask = kv_i < st.seq_kv - ik * bk  # kv padding
     if st.causal:
         if st.has_pos:
-            q_ids = qpos_ref[0, 0, pl.ds(iq * bq, bq)]
-            kv_ids = kpos_ref[0, 0, pl.ds(ik * bk, bk)]
-            dist = q_ids[:, None] - kv_ids[None, :]
+            q_ids, kv_ids = outer(qpos_ref, kpos_ref)
+            dist = q_ids - kv_ids
+            first = 0
         else:
-            q_pos = iq * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0
-            )
-            dist = (q_pos + st.q_offset) - kv_idx
-        mask &= dist >= 0
+            # Token index: q position - kv position is (row - col) less a
+            # scalar of the block, so the compares are on ONE iota difference.
+            dist = jax.lax.broadcasted_iota(jnp.int32, shape, q_ax) - kv_i
+            first = ik * bk - iq * bq - st.q_offset
+        mask = both(mask, dist >= first)
         if st.window is not None:
-            mask &= dist < st.window
+            mask = both(mask, dist < first + st.window)
     if qseg_ref is not None:
-        q_ids = qseg_ref[0, 0, pl.ds(iq * bq, bq)]
-        kv_ids = kseg_ref[0, 0, pl.ds(ik * bk, bk)]
-        mask &= q_ids[:, None] == kv_ids[None, :]
+        q_ids, kv_ids = outer(qseg_ref, kseg_ref)
+        mask = both(mask, q_ids == kv_ids)
     return mask
 
 
-def _block_run(st: _Statics, iq, ik, qpos_ref, kpos_ref,
-               qseg_ref=None, kseg_ref=None):
-    """Block-skip condition for grid cell (iq, ik).
+def _mask_logits(st: _Statics, z, iq, ik, qseg, kseg, qpos, kpos,
+                 kv_rows: bool = False):
+    """``z`` with the pairs that do not attend at NEG_INF."""
+    mask = _block_mask(st, iq, ik, qseg, kseg, qpos, kpos, kv_rows)
+    if mask is None:
+        return z
+    return jnp.where(mask, z, NEG_INF)
 
-    Causal — index mode: static-shape comparison on block indices;
-    position mode: dynamic — a block is skippable only if its largest q
-    position precedes its smallest kv position (stripe layouts make this
-    the common case for half the blocks, preserving the 2x causal saving).
+
+def _data_run(st: _Statics, iq, ik, qpos_ref, kpos_ref, qseg_ref, kseg_ref):
+    """The part of a block's liveness that is data, tested inside the static
+    range; True where there is none.
+
+    Positions: a block is skippable only if its largest q position precedes
+    its smallest kv position (stripe layouts make this the common case for
+    half the blocks, preserving the 2x causal saving), or it lies wholly
+    behind the window.
 
     Segments — under ``st.seg_pad_zero`` (the caller declares id 0 =
     padding, the data/loader.pack_rows / infer ragged-prefill convention):
@@ -134,23 +291,16 @@ def _block_run(st: _Statics, iq, ik, qpos_ref, kpos_ref,
     """
     run = True
     bq, bk = st.block_q, st.block_kv
-    if st.causal:
-        if st.has_pos:
-            q_ids = qpos_ref[0, 0, pl.ds(iq * bq, bq)]
-            kv_ids = kpos_ref[0, 0, pl.ds(ik * bk, bk)]
-            run = jnp.max(q_ids) >= jnp.min(kv_ids)
-            if st.window is not None:
-                # Skip blocks entirely behind the window: largest kv
-                # position within reach of the smallest q position. (kv
-                # padding is PAD_POS_KV, so padded blocks stay
-                # runnable-but-masked.)
-                run &= jnp.max(kv_ids) > jnp.min(q_ids) - st.window
-        else:
-            q_max = iq * bq + bq - 1 + st.q_offset
-            run = ik * bk <= q_max
-            if st.window is not None:
-                q_min = iq * bq + st.q_offset
-                run = run & (ik * bk + bk - 1 > q_min - st.window)
+    if st.causal and st.has_pos:
+        q_ids = qpos_ref[0, 0, pl.ds(iq * bq, bq)]
+        kv_ids = kpos_ref[0, 0, pl.ds(ik * bk, bk)]
+        run = jnp.max(q_ids) >= jnp.min(kv_ids)
+        if st.window is not None:
+            # Skip blocks entirely behind the window: largest kv
+            # position within reach of the smallest q position. (kv
+            # padding is PAD_POS_KV, so padded blocks stay
+            # runnable-but-masked.)
+            run &= jnp.max(kv_ids) > jnp.min(q_ids) - st.window
     if st.seg_pad_zero and qseg_ref is not None:
         q_seg = qseg_ref[0, 0, pl.ds(iq * bq, bq)]
         kv_seg = kseg_ref[0, 0, pl.ds(ik * bk, bk)]
@@ -158,67 +308,77 @@ def _block_run(st: _Statics, iq, ik, qpos_ref, kpos_ref,
     return run
 
 
-def _scaled_logits(st: _Statics, q, k, scale):
-    """Returns (z, dz_dscale_factor) where z is the softcapped logit block.
+def _dot(a, b, contract):
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())), preferred_element_type=jnp.float32)
+
+
+def _scaled_logits(st: _Statics, a, b, scale):
+    """Returns (z, dz_dscale_factor) where z is the softcapped logit block
+    ``a @ b.T`` ([bq, bk] for (q, k), [bk, bq] for (k, q)).
 
     The second value is tanh(s/cap) (needed by backward) or None.
     """
-    s = jax.lax.dot_general(
-        q.astype(jnp.float32) * scale,
-        k.astype(jnp.float32),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    s = _dot(a, b, ((1,), (1,))) * scale
     if st.logit_softcap is not None:
         t = jnp.tanh(s / st.logit_softcap)
         return st.logit_softcap * t, t
     return s, None
 
 
-def _fwd_kernel(st: _Statics, has_seg, *refs):
+def _lane_sums(p):
+    """[bq, LANES] whose lanes add up to ``p``'s row sums: the column chunks
+    added up lane for lane. The running sum stays spread over the lanes and
+    is reduced across them once a q block, not once a grid step (at the
+    train shape the forward takes 4.33 ms a call this way and 4.55 with a
+    row sum a step; PERF.md §6 PR 32)."""
+    bq, bk = p.shape
+    if bk % LANES:
+        return jnp.broadcast_to(
+            p.sum(axis=-1, keepdims=True) * (1.0 / LANES), (bq, LANES))
+    part = p[:, :LANES]
+    for c in range(1, bk // LANES):
+        part = part + p[:, c * LANES:(c + 1) * LANES]
+    return part
+
+
+def _fwd_kernel(st: _Statics, has_seg, nk, *refs):
     (q_ref, k_ref, v_ref, qseg, kseg, qpos, kpos,
      (o_ref, lse_ref, m_s, l_s, acc_s)) = _unpack_refs(
         has_seg, st.has_pos, refs)
 
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+    iq, j = pl.program_id(2), pl.program_id(3)
+    ik, live = _step(st, _kv_range, iq, j, nk)
     scale = q_ref.shape[-1] ** -0.5
 
-    @pl.when(ik == 0)
+    @pl.when(j == 0)
     def _init():
         m_s[:] = jnp.full_like(m_s, NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    # Skip blocks with nothing visible under the causal mask.
-    run = _block_run(st, iq, ik, qpos, kpos, qseg, kseg)
-
-    @pl.when(run)
+    @pl.when(live & _data_run(st, iq, ik, qpos, kpos, qseg, kseg))
     def _body():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
         v = v_ref[0, 0]
-        z, _ = _scaled_logits(st, q, k, scale)
-        mask = _block_mask(st, iq, ik, qseg, kseg, qpos, kpos)
-        z = jnp.where(mask, z, NEG_INF)
+        z, _ = _scaled_logits(st, q_ref[0, 0], k_ref[0, 0], scale)
+        z = _mask_logits(st, z, iq, ik, qseg, kseg, qpos, kpos)
 
         m_prev = m_s[:, :1]                       # [bq, 1]
         m_new = jnp.maximum(m_prev, z.max(axis=-1, keepdims=True))
-        # Masked rows keep m == NEG_INF; exp(z - m) would be exp(0) = 1
-        # there, so re-apply the mask multiplicatively.
-        p = jnp.exp(z - m_new) * mask.astype(jnp.float32)
-        alpha = jnp.exp(m_prev - m_new)           # [bq, 1]
-        l_new = l_s[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        # A row with nothing attended yet keeps m == NEG_INF, and
+        # exp(z - m) would be exp(0) = 1 on its masked pairs: subtract 0
+        # there, so that they read exp(NEG_INF) = 0.
+        m_sub = jnp.where(m_new > 0.5 * NEG_INF, m_new, 0.0)
+        p = jnp.exp(z - m_sub)
+        alpha = jnp.exp(m_prev - m_sub)           # [bq, 1]
+        l_s[:] = l_s[:] * alpha + _lane_sums(p)
+        acc_s[:] = acc_s[:] * alpha + _dot(
+            p.astype(v.dtype), v, ((1,), (0,)))
         m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-        l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
-        l = l_s[:, :1]
+        l = l_s[:].sum(axis=-1, keepdims=True)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_s[:] / l_safe).astype(o_ref.dtype)
         lse = m_s[:, :1] + jnp.log(l_safe)
@@ -226,140 +386,143 @@ def _fwd_kernel(st: _Statics, has_seg, *refs):
         lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
 
-def _dq_kernel(st: _Statics, has_seg, *refs):
+def _bwd_block(st: _Statics, iq, ik, scale, q_ref, k_ref, v_ref, qseg, kseg,
+               qpos, kpos, do_ref, lse_ref, delta_ref, kv_rows: bool = False):
+    """(p, ds, do) of one block, f32 p / ds: what both backward kernels
+    recompute before their own products. [bq, bk], or with ``kv_rows``
+    [bk, bq]: the orientation in which dk/dv's products contract p and ds
+    over their columns, so that neither is transposed."""
+    v = v_ref[0, 0]
+    do = do_ref[0, 0]
+    if kv_rows:
+        z, t = _scaled_logits(st, k_ref[0, 0], q_ref[0, 0], scale)
+        lse = lse_ref[0, 0].T[:1]                 # [1, bq] (lanes-broadcast)
+        delta = delta_ref[0, 0].T[:1]
+        dp = _dot(v, do, ((1,), (1,)))
+    else:
+        z, t = _scaled_logits(st, q_ref[0, 0], k_ref[0, 0], scale)
+        lse = lse_ref[0, 0][:, :1]                # [bq, 1]
+        delta = delta_ref[0, 0][:, :1]
+        dp = _dot(do, v, ((1,), (1,)))
+    # Mask INSIDE the exp (as the forward does): a fully-masked q row
+    # carries the finite NEG_INF lse stand-in, so exp(z - lse) on its
+    # raw logits overflows to inf and inf * 0-mask is NaN (hit by the
+    # round-5 compiled ring-merge parity check).
+    p = jnp.exp(_mask_logits(
+        st, z - lse, iq, ik, qseg, kseg, qpos, kpos, kv_rows))
+    dz = p * (dp - delta)
+    ds = dz if t is None else dz * (1.0 - t * t)
+    return p, ds, do
+
+
+def _dq_kernel(st: _Statics, has_seg, nk, *refs):
     (q_ref, k_ref, v_ref, qseg, kseg, qpos, kpos,
      (do_ref, lse_ref, delta_ref, dq_ref, dq_s)) = _unpack_refs(
         has_seg, st.has_pos, refs)
 
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+    iq, j = pl.program_id(2), pl.program_id(3)
+    ik, live = _step(st, _kv_range, iq, j, nk)
     scale = q_ref.shape[-1] ** -0.5
 
-    @pl.when(ik == 0)
+    @pl.when(j == 0)
     def _init():
         dq_s[:] = jnp.zeros_like(dq_s)
 
-    run = _block_run(st, iq, ik, qpos, kpos, qseg, kseg)
-
-    @pl.when(run)
+    @pl.when(live & _data_run(st, iq, ik, qpos, kpos, qseg, kseg))
     def _body():
-        q = q_ref[0, 0]
+        _, ds, _ = _bwd_block(st, iq, ik, scale, q_ref, k_ref, v_ref, qseg,
+                              kseg, qpos, kpos, do_ref, lse_ref, delta_ref)
         k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0].astype(jnp.float32)
-        z, t = _scaled_logits(st, q, k, scale)
-        mask = _block_mask(st, iq, ik, qseg, kseg, qpos, kpos)
-        lse = lse_ref[0, 0][:, :1]                # [bq, 1] (lanes-broadcast)
-        # Mask INSIDE the exp (as the forward does): a fully-masked q row
-        # carries the finite NEG_INF lse stand-in, so exp(z - lse) on its
-        # raw logits overflows to inf and inf * 0-mask is NaN (hit by the
-        # round-5 compiled ring-merge parity check).
-        p = jnp.exp(jnp.where(mask, z - lse, NEG_INF))
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dz = p * (dp - delta_ref[0, 0][:, :1])
-        ds = dz if t is None else dz * (1.0 - t * t)
-        dq_s[:] += jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        dq_s[:] += _dot(ds.astype(k.dtype), k, ((1,), (0,))) * scale
 
-    @pl.when(ik == nk - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
         dq_ref[0, 0] = dq_s[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(st: _Statics, has_seg, *refs):
+def _dkv_kernel(st: _Statics, has_seg, nq, *refs):
     (q_ref, k_ref, v_ref, qseg, kseg, qpos, kpos,
      (do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s)) = _unpack_refs(
         has_seg, st.has_pos, refs)
 
-    # grid = (batch, kv_head, kv_block, group, q_block)
-    ik, g, iq = pl.program_id(2), pl.program_id(3), pl.program_id(4)
-    ng, nq = pl.num_programs(3), pl.num_programs(4)
+    # grid = (batch, kv_head, kv_block, group, q_step)
+    ik, g, j = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    iq, live = _step(st, _q_range, ik, j, nq)
     scale = q_ref.shape[-1] ** -0.5
 
-    @pl.when((g == 0) & (iq == 0))
+    @pl.when((g == 0) & (j == 0))
     def _init():
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
-    run = _block_run(st, iq, ik, qpos, kpos, qseg, kseg)
-
-    @pl.when(run)
+    @pl.when(live & _data_run(st, iq, ik, qpos, kpos, qseg, kseg))
     def _body():
+        # p and ds as [bk, bq]: both products contract their columns, so
+        # neither block is transposed (6.5 ms a call at the train shape
+        # against 6.8 with [bq, bk] and a transposing contraction).
+        p, ds, do = _bwd_block(st, iq, ik, scale, q_ref, k_ref, v_ref, qseg,
+                               kseg, qpos, kpos, do_ref, lse_ref, delta_ref,
+                               kv_rows=True)
         q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0].astype(jnp.float32)
-        z, t = _scaled_logits(st, q, k, scale)
-        mask = _block_mask(st, iq, ik, qseg, kseg, qpos, kpos)
-        lse = lse_ref[0, 0][:, :1]
-        # Masked inside the exp — see _dq_kernel for the NaN rationale.
-        p = jnp.exp(jnp.where(mask, z - lse, NEG_INF))
-        dv_s[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dz = p * (dp - delta_ref[0, 0][:, :1])
-        ds = dz if t is None else dz * (1.0 - t * t)
-        dk_s[:] += jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        dv_s[:] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
+        dk_s[:] += _dot(ds.astype(q.dtype), q, ((1,), (0,))) * scale
 
-    @pl.when((g == ng - 1) & (iq == nq - 1))
+    @pl.when((g == pl.num_programs(3) - 1) & (j == pl.num_programs(4) - 1))
     def _finish():
         dk_ref[0, 0] = dk_s[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_s[:].astype(dv_ref.dtype)
 
 
-def _seg_specs(Sq_p: int, Skv_p: int, batch_index):
-    """Full-sequence (1, 1, S) segment-id blocks (TPU tiling-legal); the
-    kernels slice the current block's ids with pl.ds."""
-    return [
-        pl.BlockSpec((1, 1, Sq_p), batch_index),
-        pl.BlockSpec((1, 1, Skv_p), batch_index),
-    ]
+def _specs(st: _Statics, H: int, Sq: int, Skv: int, n_ids: int, q_at, kv_at):
+    """BlockSpecs of one grid: q-side [.., bq, H] and [.., bq, LANES] blocks,
+    the kv block, and ``n_ids`` pairs of full-sequence (1, 1, S) id blocks
+    (TPU tiling-legal; the kernels slice the current block's ids with
+    pl.ds). ``q_at`` / ``kv_at`` take the grid indices to the (batch, head,
+    block) of the q-side and the kv-side block."""
+    def q_map(*grid):
+        return (*q_at(*grid), 0)
+
+    def kv_map(*grid):
+        return (*kv_at(*grid), 0)
+
+    def ids_map(b, *_):
+        return (b, 0, 0)
+
+    ids = [pl.BlockSpec((1, 1, Sq), ids_map),
+           pl.BlockSpec((1, 1, Skv), ids_map)] * n_ids
+    return (pl.BlockSpec((1, 1, st.block_q, H), q_map),
+            pl.BlockSpec((1, 1, st.block_q, LANES), q_map),
+            pl.BlockSpec((1, 1, st.block_kv, H), kv_map), ids)
 
 
+def _row_specs(st: _Statics, G: int, H: int, nk: int, Sq: int, Skv: int,
+               n_ids: int):
+    """``_specs`` of the (batch, q head, q block, kv step) grid."""
+    return _specs(
+        st, H, Sq, Skv, n_ids,
+        lambda b, n, iq, j: (b, n, iq),
+        lambda b, n, iq, j: (b, n // G, _step(st, _kv_range, iq, j, nk)[0]))
+
+
+def _ids(qseg, kseg, qpos, kpos):
+    return [a for a in (qseg, kseg, qpos, kpos) if a is not None]
+
+
+@functools.partial(jax.jit, static_argnums=0)
 def _fwd_call(st: _Statics, q, k, v, qseg, kseg, qpos=None, kpos=None):
     """q: [B,N,Sq,H]; k,v: [B,K,Skv,H] (padded) -> (o, lse[f32 B,N,Sq])."""
     B, N, Sq, H = q.shape
     K, Skv = k.shape[1], k.shape[2]
-    G = N // K
     nq, nk = Sq // st.block_q, Skv // st.block_kv
-    grid = (B, N, nq, nk)
-
-    q_spec = pl.BlockSpec((1, 1, st.block_q, H), lambda b, n, iq, ik: (b, n, iq, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, st.block_kv, H), lambda b, n, iq, ik: (b, n // G, ik, 0)
-    )
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [q, k, v]
-    if qseg is not None:
-        in_specs += _seg_specs(Sq, Skv, lambda b, n, iq, ik: (b, 0, 0))
-        args += [qseg, kseg]
-    if qpos is not None:
-        in_specs += _seg_specs(Sq, Skv, lambda b, n, iq, ik: (b, 0, 0))
-        args += [qpos, kpos]
+    ids = _ids(qseg, kseg, qpos, kpos)
+    q_spec, row_spec, kv_spec, id_specs = _row_specs(
+        st, N // K, H, nk, Sq, Skv, len(ids) // 2)
 
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, st, qseg is not None),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, st.block_q, H), lambda b, n, iq, ik: (b, n, iq, 0)),
-            pl.BlockSpec(
-                (1, 1, st.block_q, LANES), lambda b, n, iq, ik: (b, n, iq, 0)
-            ),
-        ],
+        functools.partial(_fwd_kernel, st, qseg is not None, nk),
+        grid=(B, N, nq, _steps(st, _kv_range, nq, nk)),
+        in_specs=[q_spec, kv_spec, kv_spec, *id_specs],
+        out_specs=[q_spec, row_spec],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             # lse is lanes-broadcast [B, N, Sq, 128]: TPU tiling forbids a
@@ -373,16 +536,19 @@ def _fwd_call(st: _Statics, q, k, v, qseg, kseg, qpos=None, kpos=None):
         ],
         interpret=st.interpret,
         name="flash_fwd",
-    )(*args)
+    )(q, k, v, *ids)
     return out[0], out[1]
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def _bwd_call(st: _Statics, q, k, v, qseg, kseg, o, lse, do, g_lse=None,
               qpos=None, kpos=None):
     B, N, Sq, H = q.shape
     K, Skv = k.shape[1], k.shape[2]
     G = N // K
     nq, nk = Sq // st.block_q, Skv // st.block_kv
+    has_seg = qseg is not None
+    ids = _ids(qseg, kseg, qpos, kpos)
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if g_lse is not None:
@@ -392,65 +558,34 @@ def _bwd_call(st: _Statics, q, k, v, qseg, kseg, o, lse, do, g_lse=None,
         delta = delta - g_lse
     delta = jnp.broadcast_to(delta[..., None], (B, N, Sq, LANES))
 
-    q_spec4 = pl.BlockSpec((1, 1, st.block_q, H), lambda b, n, iq, ik: (b, n, iq, 0))
-    kv_spec4 = pl.BlockSpec(
-        (1, 1, st.block_kv, H), lambda b, n, iq, ik: (b, n // G, ik, 0)
-    )
-    row_spec4 = pl.BlockSpec(
-        (1, 1, st.block_q, LANES), lambda b, n, iq, ik: (b, n, iq, 0)
-    )
-    in_specs = [q_spec4, kv_spec4, kv_spec4]
-    args = [q, k, v]
-    if qseg is not None:
-        in_specs += _seg_specs(Sq, Skv, lambda b, n, iq, ik: (b, 0, 0))
-        args += [qseg, kseg]
-    if qpos is not None:
-        in_specs += _seg_specs(Sq, Skv, lambda b, n, iq, ik: (b, 0, 0))
-        args += [qpos, kpos]
-    in_specs += [q_spec4, row_spec4, row_spec4]
-    args += [do, lse, delta]
-
+    q_spec, row_spec, kv_spec, id_specs = _row_specs(
+        st, G, H, nk, Sq, Skv, len(ids) // 2)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, st, qseg is not None),
-        grid=(B, N, nq, nk),
-        in_specs=in_specs,
-        out_specs=q_spec4,
+        functools.partial(_dq_kernel, st, has_seg, nk),
+        grid=(B, N, nq, _steps(st, _kv_range, nq, nk)),
+        in_specs=[q_spec, kv_spec, kv_spec, *id_specs,
+                  q_spec, row_spec, row_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((st.block_q, H), jnp.float32)],
         interpret=st.interpret,
         name="flash_bwd_dq",
-    )(*args)
+    )(q, k, v, *ids, do, lse, delta)
 
-    # grid = (batch, kv_head, kv_block, group, q_block): the dk/dv output
+    # grid = (batch, kv_head, kv_block, group, q_step): the dk/dv output
     # block for (b, kh, ik) is revisited across the two inner dims, so the
     # accumulator scratch carries over the whole group x q sweep.
-    def _q_map5(b, kh, ik, g, iq):
-        return (b, kh * G + g, iq, 0)
-
-    def _row_map5(b, kh, ik, g, iq):
-        return (b, kh * G + g, iq, 0)
-
-    q_spec5 = pl.BlockSpec((1, 1, st.block_q, H), _q_map5)
-    kv_spec5 = pl.BlockSpec(
-        (1, 1, st.block_kv, H), lambda b, kh, ik, g, iq: (b, kh, ik, 0)
-    )
-    row_spec5 = pl.BlockSpec((1, 1, st.block_q, LANES), _row_map5)
-    in_specs5 = [q_spec5, kv_spec5, kv_spec5]
-    args5 = [q, k, v]
-    if qseg is not None:
-        in_specs5 += _seg_specs(Sq, Skv, lambda b, kh, ik, g, iq: (b, 0, 0))
-        args5 += [qseg, kseg]
-    if qpos is not None:
-        in_specs5 += _seg_specs(Sq, Skv, lambda b, kh, ik, g, iq: (b, 0, 0))
-        args5 += [qpos, kpos]
-    in_specs5 += [q_spec5, row_spec5, row_spec5]
-    args5 += [do, lse, delta]
-
+    q_spec, row_spec, kv_spec, id_specs = _specs(
+        st, H, Sq, Skv, len(ids) // 2,
+        lambda b, kh, ik, g, j: (
+            b, kh * G + g, _step(st, _q_range, ik, j, nq)[0]),
+        lambda b, kh, ik, g, j: (b, kh, ik))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, st, qseg is not None),
-        grid=(B, K, nk, G, nq),
-        in_specs=in_specs5,
-        out_specs=[kv_spec5, kv_spec5],
+        functools.partial(_dkv_kernel, st, has_seg, nq),
+        grid=(B, K, nk, G, _steps(st, _q_range, nk, nq)),
+        in_specs=[q_spec, kv_spec, kv_spec, *id_specs,
+                  q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -461,7 +596,7 @@ def _bwd_call(st: _Statics, q, k, v, qseg, kseg, o, lse, do, g_lse=None,
         ],
         interpret=st.interpret,
         name="flash_bwd_dkv",
-    )(*args5)
+    )(q, k, v, *ids, do, lse, delta)
     return dq, dk, dv
 
 
@@ -564,10 +699,19 @@ def _prep(
 ):
     """Shared wrapper prep: statics + [B,N,S,H] transpose + block padding.
 
-    block_q/block_kv default to large (1024) tiles: on v5e the online-softmax
-    bookkeeping (max/sum/rescale on the VPU) is amortized over tile area, and
-    1024x1024 measured ~2.3x xla attention fwd+bwd at the bench shapes while
-    the conservative 128x128 was ~2x *slower* than xla.
+    block_q/block_kv default to large (1024) tiles, whatever the window and
+    the head count: a grid step costs the forward about a microsecond of
+    per-row bookkeeping (running max, rescale of the accumulator) whatever
+    its width, so a narrow kv block loses more than its tighter fit to the
+    mask saves. The sweep (tools/flash_sweep.py on a v5e, PERF.md §6 PR 32):
+    at the train shape (8192 under window 4096) forward / dq / dkv take 4.3 /
+    5.4 / 6.5 ms a call at 1024 x 1024, 5.4 / 5.5 / 6.7 at 512 x 512 and
+    11.8 / 8.9 / 10.5 at 256 x 256, though those visit 30, 27 and 25.5 M
+    pairs; with no window at 4096 tokens 1024 x 1024 takes 2.4 ms and
+    512 x 512 3.8. The one shape where 512 wins is Laguna's window 512 at
+    4096 tokens (15 blocks of half the pairs: 2.41 ms against 2.65), not
+    enough of a prefill program to earn a rule. 2048-wide blocks overrun
+    VMEM.
     """
     assert (q_segment_ids is None) == (kv_segment_ids is None)
     assert (q_positions is None) == (kv_positions is None)
@@ -597,6 +741,7 @@ def _prep(
         causal=causal,
         logit_softcap=logit_softcap,
         q_offset=q_offset,
+        seq_q=Sq,
         seq_kv=Skv,
         block_q=bq,
         block_kv=bk,

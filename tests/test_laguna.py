@@ -384,3 +384,40 @@ def test_models_of_one_kind_are_bit_equal_to_the_parent(preset):
         hashlib.sha256(np.asarray(a).tobytes()).hexdigest()[:16]
         for a in (logits, aux)]
     assert got == GOLDEN[preset]
+
+
+def test_each_distinct_flash_kernel_is_traced_once_a_process(tiny, monkeypatch):
+    """Set-up's cost, held here and not only by the driver's clock: the six
+    unrolled layers of a prefill program hold six flash call sites and two
+    distinct kernels (4 heads under no window, 6 under window 8), and the
+    output check's fresh one-step jits of the same shape trace neither again.
+    ``pallas_call`` alone re-traces its kernel at every call site (PERF.md
+    section 6, PRs 29 and 31)."""
+    import functools
+    import importlib
+
+    from orion_tpu.infer import runner
+    from orion_tpu.infer.kv_cache import init_cache
+
+    fa = importlib.import_module("orion_tpu.ops.pallas.flash_attention")
+    cfg = get_config("tiny-laguna", [
+        "model.kernels=pallas_interpret", "inference.max_seq_len=64",
+        "inference.page_size=8", "inference.num_pages=24",
+        "inference.max_batch_size=2"])
+    m = cfg.model
+    args = (tiny[1], init_cache(m, cfg.inference),
+            jnp.ones((2, 24), jnp.int32), jnp.asarray([20, 13], jnp.int32),
+            jnp.arange(1, 7, dtype=jnp.int32).reshape(2, 3))
+    traced = []
+    kernel = fa._fwd_kernel
+
+    def counting(st, *rest):
+        traced.append(st)
+        return kernel(st, *rest)
+
+    monkeypatch.setattr(fa, "_fwd_kernel", counting)
+    jax.clear_caches()
+    for _ in range(3):          # the warm program, then two fresh probes
+        jax.jit(functools.partial(runner.prefill_step, cfg=m)).lower(*args)
+        assert len(traced) == 2, traced
+    assert {st.window for st in traced} == {None, 8}

@@ -1288,3 +1288,268 @@ def test_grouped_matmul_pallas_matches_ragged_dot(layout):
     np.testing.assert_allclose(
         jnp.where(live, g_out[0], 0), g_ref[0], rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(g_out[1], g_ref[1], rtol=1e-5, atol=1e-5)
+
+
+# -- flash attention: the live-block grid (PR 32) -----------------------------
+# Every case runs forward and all three gradients through the interpreter
+# against attention_xla. Blocks are small so that windows, offsets and kv
+# padding cross several of them.
+
+def _fa():
+    import importlib
+
+    return importlib.import_module("orion_tpu.ops.pallas.flash_attention")
+
+
+def _halves(B, S):
+    return jnp.concatenate(
+        [jnp.zeros((B, S // 2), jnp.int32), jnp.ones((B, S - S // 2), jnp.int32)],
+        axis=1)
+
+
+_LIVE_CASES = {
+    # window against 64-wide blocks: smaller, equal to k blocks, no multiple
+    "window<block": dict(S=(256, 256), kw=dict(window=24)),
+    "window=2blocks": dict(S=(256, 256), kw=dict(window=128)),
+    "window=block": dict(S=(256, 256), kw=dict(window=64)),
+    "window!%block": dict(S=(256, 256), kw=dict(window=100)),
+    "window>seq": dict(S=(192, 192), kw=dict(window=1000)),
+    "unequal-blocks": dict(S=(256, 256), kw=dict(window=90), blocks=(128, 32)),
+    "unequal-blocks-T": dict(S=(256, 256), kw=dict(window=90), blocks=(32, 128)),
+    # a tail of queries over prefix + tail
+    "q_offset": dict(S=(64, 192), kw=dict(q_offset=128)),
+    "q_offset+window": dict(S=(64, 192), kw=dict(q_offset=128, window=70)),
+    "kv-padding": dict(S=(40, 100), kw=dict(q_offset=60), blocks=(32, 32)),
+    "kv-padding+window": dict(S=(40, 100), kw=dict(q_offset=60, window=33),
+                              blocks=(32, 32)),
+    "q-padding": dict(S=(100, 100), kw=dict(window=50)),
+    "non-causal": dict(S=(128, 100), kw=dict(causal=False)),
+    "softcap+window": dict(S=(192, 192), kw=dict(window=70, logit_softcap=5.0)),
+    "gqa4": dict(S=(192, 192), kw=dict(window=70), heads=(8, 2)),
+    "gqa6": dict(S=(192, 192), kw=dict(window=70), heads=(12, 2)),
+    "gqa9": dict(S=(192, 192), kw=dict(window=70), heads=(18, 2)),
+    # segment ids: 0 is a real id unless the caller says otherwise
+    "segments-0-real": dict(S=(256, 256), kw=dict(window=100), seg="halves",
+                            blocks=(128, 128)),
+    "segments-pad0": dict(S=(256, 256), kw=dict(window=100), seg="burst",
+                          blocks=(128, 128)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LIVE_CASES))
+def test_flash_live_blocks_fwd_and_grads(case):
+    c = _LIVE_CASES[case]
+    (Sq, Skv), kw = c["S"], dict(c["kw"])
+    N, K = c.get("heads", (4, 2))
+    bq, bk = c.get("blocks", (64, 64))
+    B = 2
+    q, k, v = _qkv(B=B, Sq=Sq, Skv=Skv, N=N, K=K, H=32)
+    fkw, rows = {}, np.ones((B, Sq), bool)
+    if c.get("seg") == "halves":
+        kw.update(q_segment_ids=_halves(B, Sq), kv_segment_ids=_halves(B, Skv))
+    elif c.get("seg") == "burst":
+        # pack_rows / prefill convention: id 0 is padding (rows are garbage)
+        seg = (jnp.arange(Sq)[None, :] < jnp.asarray([[Sq], [70]])).astype(
+            jnp.int32)
+        kw.update(q_segment_ids=seg, kv_segment_ids=seg)
+        fkw["seg_pad_zero"] = True
+        rows = np.asarray(seg, bool)
+    w = jnp.asarray(rows, jnp.float32)[:, :, None, None]
+
+    def loss(fn, extra):
+        def f(q, k, v):
+            o = fn(q, k, v, **kw, **extra)
+            return jnp.sum((o * w) ** 2), o
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, o_p), g_p = loss(flash_attention, dict(
+        block_q=bq, block_kv=bk, interpret=True, **fkw))(q, k, v)
+    (_, o_x), g_x = loss(attention_xla, {})(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(o_p)[rows], np.asarray(o_x)[rows], rtol=1e-5, atol=1e-5)
+    for a, b, name in zip(g_p, g_x, "qkv"):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
+                                   err_msg=f"d{name}")
+
+
+def test_flash_segment_zero_is_a_real_id_without_the_flag():
+    """Rows of segment 0 attend each other (0 == 0) unless the caller
+    declares 0 padding: the skip is opt-in."""
+    q, k, v = _qkv(Sq=128, Skv=128)
+    seg = _halves(2, 128)
+    out = flash_attention(q, k, v, q_segment_ids=seg, kv_segment_ids=seg,
+                          block_q=64, block_kv=64, interpret=True)
+    alone = attention_xla(q[:, :64], k[:, :64], v[:, :64])
+    np.testing.assert_allclose(out[:, :64], alone, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_flash_with_lse_positions_window_and_lse_cotangent(window):
+    """The ring's case: explicit positions (a kv block that starts before
+    the q block, and one wholly ahead of it), a window on true distance, and
+    a loss that reads the lse."""
+    from orion_tpu.ops.pallas.flash_attention import flash_attention_with_lse
+
+    B, S, N, K, H = 2, 128, 4, 2, 32
+    q, k, v = _qkv(B=B, Sq=S, Skv=S, N=N, K=K, H=H)
+    qpos = jnp.arange(S, dtype=jnp.int32) + 64
+    kpos = jnp.arange(S, dtype=jnp.int32) + 32    # kv 32..159, q 64..191
+
+    def ref(q, k, v):
+        logits = jnp.einsum("bqnh,bknh->bnqk", q, jnp.repeat(k, N // K, 2),
+                            ) * H ** -0.5
+        d = qpos[:, None] - kpos[None, :]
+        m = d >= 0
+        if window is not None:
+            m &= d < window
+        logits = jnp.where(m[None, None], logits, -jnp.inf)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        p = jnp.exp(logits - lse[..., None])
+        return jnp.einsum("bnqk,bknh->bqnh", p, jnp.repeat(v, N // K, 2)), lse
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse)), (o, lse)
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, (o_p, l_p)), g_p = loss(lambda q, k, v: flash_attention_with_lse(
+        q, k, v, q_positions=qpos, kv_positions=kpos, window=window,
+        block_q=128, block_kv=128, interpret=True))(q, k, v)
+    (_, (o_x, l_x)), g_x = loss(ref)(q, k, v)
+    np.testing.assert_allclose(o_p, o_x, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(l_p, l_x, rtol=1e-5, atol=1e-5)
+    for a, b in zip(g_p, g_x):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("mode", ["index", "positions"])
+def test_flash_fully_masked_rows_keep_lse_and_zero_grads(mode):
+    """Rows that attend nothing (queries past every key's window; a ring
+    step whose kv block lies wholly ahead): out 0, lse -inf, gradients
+    finite and zero on those rows, including whole q blocks that no grid
+    step visits."""
+    from orion_tpu.ops.pallas.flash_attention import flash_attention_with_lse
+
+    q, k, v = _qkv(Sq=128, Skv=32)
+    if mode == "index":
+        kw = dict(window=16)                    # rows >= 47 see nothing
+        dead = np.arange(128) >= 47
+    else:
+        kw = dict(q_positions=jnp.arange(128, dtype=jnp.int32),
+                  kv_positions=jnp.arange(32, dtype=jnp.int32) + 64)
+        dead = np.arange(128) < 64
+
+    def f(q, k, v):
+        o, lse = flash_attention_with_lse(
+            q, k, v, block_q=32, block_kv=32, interpret=True, **kw)
+        fin = jnp.where(jnp.isfinite(lse), lse, 0.0)
+        return jnp.sum(o ** 2) + jnp.sum(fin), (o, lse)
+
+    (_, (o, lse)), (dq, dk, dv) = jax.value_and_grad(
+        f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert bool(jnp.all(jnp.isneginf(lse[:, :, dead])))
+    assert bool(jnp.all(jnp.isfinite(lse[:, :, ~dead])))
+    assert not np.asarray(o)[:, dead].any()
+    assert not np.asarray(dq)[:, dead].any()
+    for g in (dq, dk, dv):
+        assert bool(jnp.all(jnp.isfinite(g)))
+    assert np.asarray(dk).any() and np.asarray(dv).any()
+
+
+def _kernel_dots(fn, *args):
+    """(operand dtypes, result dtype) of every matmul inside the Pallas
+    kernels of ``fn``'s program."""
+    found = []
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            if inside and eqn.primitive.name == "dot_general":
+                found.append((eqn.invars[0].aval.dtype, eqn.invars[1].aval.dtype,
+                              eqn.outvars[0].aval.dtype))
+            kernel = eqn.primitive.name == "pallas_call"
+            for val in eqn.params.values():
+                for sub in (val if isinstance(val, (tuple, list)) else [val]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, inside or kernel)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+    return found
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_flash_multiplies_on_the_inputs_own_dtype(dtype):
+    """q, k, v, dO and the probabilities reach the MXU in the inputs' dtype
+    with float32 accumulation: bf16 inputs are not upcast, f32 inputs give
+    f32 products."""
+    dt = jnp.dtype(dtype)
+    q, k, v = _qkv(Sq=128, Skv=128, dtype=dt)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash_attention(
+            *a, window=40, block_q=64, block_kv=64, interpret=True,
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    dots = _kernel_dots(grads, q, k, v)
+    assert len(dots) == 2 + 3 + 4           # forward, dq, dk/dv
+    for a, b, out in dots:
+        assert (a, b, out) == (dt, dt, jnp.float32), dots
+
+
+_GRID_TABLE = [
+    # Sq, Skv, bq, bk, q_offset, window
+    (8192, 8192, 1024, 1024, 0, 4096),
+    (8192, 8192, 512, 512, 0, 4096),
+    (4096, 4096, 512, 512, 0, 512),
+    (4096, 4096, 1024, 1024, 0, 512),
+    (4096, 4096, 1024, 1024, 0, None),
+    (256, 256, 64, 64, 0, 24),
+    (256, 256, 64, 64, 0, 64),
+    (256, 256, 64, 64, 0, 65),
+    (256, 256, 128, 32, 0, 90),
+    (256, 256, 32, 128, 0, 90),
+    (64, 192, 64, 64, 128, 70),
+    (40, 100, 32, 32, 60, 33),
+    (100, 100, 64, 64, 0, 50),
+    (70, 128, 64, 32, 0, None),         # keys past the last real query
+    (128, 32, 32, 32, 0, 16),           # queries past every key's window
+    (8, 72, 8, 64, 64, None),
+]
+
+
+@pytest.mark.parametrize("shape", _GRID_TABLE, ids=lambda s: "-".join(map(str, s)))
+def test_flash_visits_exactly_the_live_blocks(shape):
+    """In index mode the grid visits a block if and only if the dense mask
+    has a True in it: no live block missed, no dead block visited, from the
+    forward's side (kv range of a q row) and from dk/dv's (q range of a kv
+    block); the grid's inner axis is the longest range."""
+    fa = _fa()
+    Sq, Skv, bq, bk, off, window = shape
+    nq, nk = -(-Sq // bq), -(-Skv // bk)
+    st = fa._Statics(causal=True, logit_softcap=None, q_offset=off, seq_q=Sq,
+                     seq_kv=Skv, block_q=bq, block_kv=bk, interpret=True,
+                     window=window)
+    d = (np.arange(Sq)[:, None] + off) - np.arange(Skv)[None, :]
+    dense = (d >= 0) if window is None else (d >= 0) & (d < window)
+    pad = np.zeros((nq * bq, nk * bk), bool)
+    pad[:Sq, :Skv] = dense
+    live = pad.reshape(nq, bq, nk, bk).any(axis=(1, 3))
+    pad[Sq:, :] = True      # padded q rows are sliced off: masked or not
+    whole = pad.reshape(nq, bq, nk, bk).all(axis=(1, 3))
+
+    seen = fa.visited_blocks(st, nq, nk)
+    np.testing.assert_array_equal(seen, live)
+    lo, cnt = fa._q_range(st, np.arange(nk), nq, np)
+    iq = np.arange(nq)[:, None]
+    np.testing.assert_array_equal((iq >= lo[None]) & (iq < (lo + cnt)[None]),
+                                  live)
+    counts = fa.block_counts(st, nq, nk)
+    assert counts["visited"] == live.sum() and counts["full"] == nq * nk
+    assert counts["steps"] == nq * max(live.sum(axis=1).max(), 1)
+    # a block that skips its mask step must be wholly True in the dense mask
+    skip = np.asarray(fa._unmasked(st, iq, np.arange(nk)[None, :])) & seen
+    assert not (skip & ~whole).any()
+    assert counts["unmasked"] == skip.sum()
+    if Sq % bq == 0:
+        assert skip.sum() == (whole & live).sum()

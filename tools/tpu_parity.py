@@ -5,7 +5,10 @@ Runs the fused kernels *compiled* on the real chip (interpret=False) and
 compares fwd + grads against the xla reference ops:
 
   - flash attention: plain GQA causal; sliding window (full dq/dk/dv);
-    segment-packed; explicit-position (striped-ring layout)
+    segment-packed; explicit-position (striped-ring layout); the benchmark
+    cells' own shapes (train 8192 under window 4096 with all three
+    gradients; Laguna's and Mixtral's prefill layers over a padded burst's
+    segments)
   - flash_attention_with_lse: out + lse parity and grads THROUGH the lse
     (a two-block ring-style merge, exactly how parallel/sequence.py uses it)
   - paged decode attention: gather parity, fused in-kernel KV write,
@@ -725,6 +728,51 @@ def flash_checks() -> None:
              q, k, v, causal=True).astype(jnp.float32))
 
 
+def flash_cell_checks() -> None:
+    """Flash attention at the benchmark cells' own shapes (PR 32): the train
+    cells' 8192 under window 4096 with all three gradients (4 query heads
+    over one kv head: the XLA reference holds [heads, 8192, 8192] in f32),
+    and the serving cells' prefill layers over a padded burst's segment ids
+    (id 0 = padding; real rows compared): Laguna's 72 heads under window 512
+    and 48 under none, Mixtral's 32 heads at 8 rows x 512."""
+    def sq(o):
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    def qkv(B, S, N, K=8):
+        ks = jax.random.split(jax.random.key(S + N), 3)
+        return (jax.random.normal(ks[0], (B, S, N, 128), jnp.bfloat16),
+                jax.random.normal(ks[1], (B, S, K, 128), jnp.bfloat16),
+                jax.random.normal(ks[2], (B, S, K, 128), jnp.bfloat16))
+
+    q, k, v = qkv(1, 8192, 4, K=1)
+    fns = (lambda *a: flash_attention(*a, window=4096, interpret=INTERP),
+           lambda *a: attention_xla(*a, causal=True, window=4096))
+    check("flash @train 8192 w4096 fwd", jax.jit(fns[0])(q, k, v),
+          jax.jit(fns[1])(q, k, v), 2e-2)
+    g_p, g_x = (jax.jit(jax.grad(lambda *a, f=f: sq(f(*a)),
+                                 argnums=(0, 1, 2)))(q, k, v) for f in fns)
+    for n, gp, gx in zip("qkv", g_p, g_x):
+        check(f"flash @train 8192 w4096 d{n}", gp, gx, 4e-2)
+
+    rng = np.random.default_rng(32)
+    for tag, B, S, N, window in (("laguna 72 w512", 2, 2048, 72, 512),
+                                 ("laguna 48 full", 2, 2048, 48, None),
+                                 ("mixtral 32 full", 8, 512, 32, None)):
+        real = rng.integers(S - 511, S + 1, size=B)
+        real[0] = S
+        seg = jnp.asarray(np.arange(S)[None, :] < real[:, None], jnp.int32)
+        q, k, v = qkv(B, S, N)
+        kw = dict(causal=True, window=window, q_segment_ids=seg,
+                  kv_segment_ids=seg)
+        rows = seg[:, :, None, None].astype(jnp.bfloat16)
+        check(f"flash @{tag} {B}x{S} burst fwd",
+              jax.jit(lambda q, k, v: flash_attention(
+                  q, k, v, seg_pad_zero=True, interpret=INTERP, **kw))(
+                      q, k, v) * rows,
+              jax.jit(lambda q, k, v: attention_xla(q, k, v, **kw))(
+                  q, k, v) * rows, 2e-2)
+
+
 def norm_rope_checks() -> None:
     """Fused RMSNorm and RoPE, fwd + grads, at the bench width and at
     Mistral-7B's (the row/sequence blocks scale with the width)."""
@@ -783,6 +831,8 @@ def main() -> int:
           f"devices={len(jax.devices())} interpret={INTERP}", flush=True)
 
     guarded("flash", flash_checks)
+    if not INTERP:      # the cells' sizes: minutes under the interpreter
+        guarded("flash @cells", flash_cell_checks)
     for g in (SMALL, SERVE):
         guarded(f"paged{g.tag}", paged_checks, g)
         guarded(f"ragged{g.tag}", ragged_paged_checks, g)
