@@ -145,6 +145,17 @@ class ModelConfig:
     # "per-head": each head's attention output is multiplied by a sigmoid
     # gate computed from the layer's normed input (attn.wg [D, heads]).
     attn_gate: Optional[str] = None
+    # What a layer's attention computes: "softmax", or "power_retention"
+    # (ops/retention.py): weights are the SQUARE of the scaled score under
+    # a learned decay, log sigmoid(h attn.wr) a K/V head and position from
+    # the layer's normed input, normalised by their sum. Such a layer is a
+    # recurrence too: serving keeps a fixed-size state row a slot and only
+    # the positions since the last complete chunk in pages (the chunk is the
+    # program's choice, not the model's: ops/retention.fold_chunk).
+    attention: str = "softmax"
+    # RMSNorm over each query and key head before the rotary embedding
+    # (attn.q_norm / attn.k_norm [head_dim]; Qwen3-family).
+    qk_norm: bool = False
     # Gemma-family block/embedding details:
     post_norms: bool = False          # extra norms AFTER attention and MLP
     norm_scale_plus_one: bool = False  # rmsnorm multiplies by (1 + w)
@@ -300,6 +311,10 @@ class ModelConfig:
         # `is None` first: the override parser maps the literal "none" to
         # None for every field, and None < 1 is a TypeError, not the
         # domain-check message.
+        if self.attention not in ("softmax", "power_retention"):
+            raise ValueError(
+                f"model.attention={self.attention!r}; "
+                f"softmax|power_retention")
         if self.scan_group is None or self.scan_group < 1:
             raise ValueError(f"model.scan_group={self.scan_group} must be >= 1")
         if self.scan_unroll is None or self.scan_unroll < 1:
@@ -314,6 +329,10 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_retention(self) -> bool:
+        return self.attention == "power_retention"
 
     @property
     def resolved_router_width(self) -> int:
@@ -2023,6 +2042,51 @@ def _p_tiny_laguna() -> Config:
         inference=InferenceConfig(max_seq_len=128, page_size=8,
                                   num_pages=128, max_batch_size=4,
                                   prefill_chunk=16),
+    )
+
+
+def _brumby_model(**kw) -> ModelConfig:
+    """Brumby-14B-Base (manifestai/Brumby-14B-Base config.json): the
+    Qwen3-14B key set with every attention layer a power-retention layer."""
+    base = dict(
+        name="brumby-14b", vocab_size=151_936, max_seq_len=32_768,
+        d_model=5120, n_layers=40, n_heads=40, n_kv_heads=8, head_dim=128,
+        d_ff=17_408, pos_embedding="rope", rope_theta=1_000_000.0,
+        norm="rmsnorm", norm_eps=1e-6, activation="swiglu",
+        tie_embeddings=False, attention="power_retention", qk_norm=True,
+        dtype="bfloat16", param_dtype="bfloat16", kernels="pallas",
+        remat="full",
+    )
+    base.update(kw)
+    return ModelConfig(**base)
+
+
+@register_preset("brumby-14b")
+def _p_brumby() -> Config:
+    """Brumby-14B-Base at its published sizes, for serving (a deployment
+    holds the depth its chip holds)."""
+    return Config(
+        model=_brumby_model(),
+        inference=InferenceConfig(max_seq_len=12_288, page_size=64),
+    )
+
+
+@register_preset("tiny-brumby")
+def _p_tiny_brumby() -> Config:
+    """Tiny Brumby-family model for CPU tests: head 16 (a state of 9 slabs
+    of 16), query groups of 2 over 2 K/V heads; its longest sequence of 128
+    makes the fold chunk 16 tokens (ops/retention.fold_chunk) over pages of
+    4, so that a short prompt crosses several folds."""
+    return Config(
+        model=_brumby_model(
+            name="tiny-brumby", vocab_size=256, max_seq_len=128, d_model=64,
+            n_layers=3, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
+            dtype="float32", param_dtype="float32",
+            kernels="xla", remat="none"),
+        data=DataConfig(batch_size=4, seq_len=64),
+        inference=InferenceConfig(max_seq_len=128, page_size=4,
+                                  num_pages=160, max_batch_size=4,
+                                  prefill_chunk=16, decode_window=4),
     )
 
 

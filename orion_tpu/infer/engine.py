@@ -130,26 +130,47 @@ class InferenceEngine:
         self.cfg = cfg
         self.mcfg = cfg.model
         self.icfg = cfg.inference
+        # What a model's architecture cannot be served with yet: ONE list,
+        # refused by name. Layers of different shapes (a layer plan) run
+        # through the same one layer body on every path and the runner's
+        # verify step agrees with its decode window on such a model
+        # (tests/test_laguna.py); what is missing is a comparison of THESE
+        # paths at the engine and a benchmark cell that runs them (ROADMAP
+        # R6). A power-retention model keeps a state row a slot, which
+        # nothing can snapshot, share or roll back yet (ROADMAP R9): no
+        # cached prefix or host tier, no resuming a prompt mid-way, no
+        # drafts to reject, and its tail pages are not quantised.
+        refused, why = [], []
         if self.mcfg.layer_plan is not None:
-            # Layers of different shapes (head counts, a dense lead, a
-            # share of the experts) run through the same one layer body on
-            # every path (transformer.block under the runner's dense and
-            # paged backends), and the runner's verify step agrees with its
-            # decode window on such a model (tests/test_laguna.py). What is
-            # missing is a comparison of THESE paths at the engine and a
-            # benchmark cell that runs them (ROADMAP R6): refuse, by name.
-            off = [name for name, on in (
+            why.append(
+                "has layers of different shapes (model.layer_types / "
+                "n_heads_per_layer / n_dense_layers)")
+            refused += [
                 ("inference.speculative", self.icfg.speculative),
                 ("inference.constrained", self.icfg.constrained),
                 ("inference.chunked_prefill", self.icfg.chunked_prefill),
                 ("model.weight_quant", self.mcfg.weight_quant),
-            ) if on]
-            if off:
-                raise ValueError(
-                    f"model {self.mcfg.name!r} has layers of different "
-                    f"shapes (model.layer_types / n_heads_per_layer / "
-                    f"n_dense_layers) and is served by whole-prompt prefill "
-                    f"and the decode window only: unset {', '.join(off)}")
+            ]
+        if self.mcfg.is_retention:
+            why.append(
+                "keeps a fixed-size state a request "
+                "(model.attention=power_retention)")
+            refused += [
+                ("inference.prefix_cache", self.icfg.prefix_cache),
+                ("inference.host_tier_bytes", self.icfg.host_tier_bytes),
+                ("inference.long_context", self.icfg.long_context),
+                ("inference.speculative", self.icfg.speculative),
+                ("inference.constrained", self.icfg.constrained),
+                ("inference.chunked_prefill", self.icfg.chunked_prefill),
+                ("inference.kv_quant", self.icfg.kv_quant),
+                ("model.weight_quant", self.mcfg.weight_quant),
+            ]
+        off = list(dict.fromkeys(name for name, on in refused if on))
+        if off:
+            raise ValueError(
+                f"model {self.mcfg.name!r} {' and '.join(why)} and is "
+                f"served by whole-prompt prefill and the decode window "
+                f"only: unset {', '.join(off)}")
         if self.mcfg.weight_quant == "int8":
             from orion_tpu.models.quantize import quantize_params
 
@@ -339,6 +360,28 @@ class InferenceEngine:
         # logic must treat the model as unwindowed; only the attention
         # masks are per-layer windowed (runner/cfg.layer_window).
         self.page_window = self.mcfg.page_window
+        # A power-retention model's pages hold only a sequence's tail: the
+        # positions since its last fold, a chunk ago at most
+        # (runner.fold_step). fold_lens mirrors the cache's state_len.
+        self._chunk = None
+        if self.mcfg.is_retention:
+            from orion_tpu.ops.retention import fold_chunk
+
+            self._chunk = fold_chunk(self.mcfg.max_seq_len)
+        self.fold_lens = np.zeros(self.max_batch, np.int64)
+        if self._chunk is not None:
+            widest = max(self.icfg.decode_window, self.icfg.decode_window_max
+                         if self.icfg.decode_window_autotune else 0)
+            if self._chunk % self.psz or widest > self.psz:
+                raise ValueError(
+                    f"the fold chunk of {self._chunk} positions "
+                    f"(ops/retention.fold_chunk of model.max_seq_len) must "
+                    f"be a multiple of inference.page_size={self.psz}, and "
+                    f"a decode window no longer than a page")
+            if self.mesh is not None:
+                raise ValueError(
+                    "a power-retention model is served on one device: its "
+                    "kernels are not run per shard yet")
         # What a window-aware allocator would know (the counters
         # kv_dead_window_page_layers / kv_live_page_layers): how many
         # layers read only their window of a context whose pages all stay.
@@ -467,6 +510,8 @@ class InferenceEngine:
             "decode_defaults", self.mcfg, self.mesh
         )
         self._prefill = self._jit_program("prefill", self.mcfg, self.mesh)
+        if self._chunk is not None:
+            self._fold = self._jit_program("fold", self.mcfg, self.mesh)
         self._mixed = self._jit_program("mixed", self.mcfg, self.mesh)
         self._mixed_defaults = self._jit_program(
             "mixed_defaults", self.mcfg, self.mesh
@@ -729,6 +774,7 @@ class InferenceEngine:
         "decode/emit": ("emit_s", "host_s"),
         "verify/run": ("verify_run_s", "decode_device_s", "device_s"),
         "compact": ("compact_s", "decode_device_s", "device_s"),
+        "fold/run": ("fold_s", "decode_device_s", "device_s"),
         "mixed/run": ("mixed_device_s", "device_s"),
         "mixed_verify/run": ("mixed_device_s", "device_s"),
         "spill": ("spill_s",),
@@ -737,6 +783,7 @@ class InferenceEngine:
         "migrate_out": ("migrate_out_s",),
         "migrate_in": ("migrate_in_s",),
         "prefill/fallback": (),
+        "fold/fallback": (),
         "decode/fallback": (),
         "verify/fallback": (),
         "mixed/fallback": (),
@@ -1339,6 +1386,23 @@ class InferenceEngine:
             "decode_build_s": 0.0, "decode_run_s": 0.0,
             "decode_fetch_s": 0.0, "emit_s": 0.0, "step_self_s": 0.0,
             "verify_run_s": 0.0, "compact_s": 0.0,
+            # A power-retention model (all 0 for a K/V model): fold_s the
+            # fold dispatches at the start of a decode window (``folds`` of
+            # them: one a slot whose tail holds a complete chunk); per token
+            # step and layer, the live slots' state rows the decode kernel
+            # read (decode_state_slot_layers), those of them that hold
+            # nothing yet (no fold so far: decode_state_empty_slot_layers)
+            # and the tail positions it read, the new token among them
+            # (decode_tail_token_layers); prefill_retention_units counts a
+            # real prompt position of index t as min(2 (t + 1), D) a layer,
+            # D = H (H + 1) / 2: the cheaper of the quadratic and the
+            # recurrent form for its query, in units of 2 x H x query heads
+            # operations. Host arithmetic on lengths, no device value read.
+            "fold_s": 0.0, "folds": 0,
+            "decode_state_slot_layers": 0,
+            "decode_state_empty_slot_layers": 0,
+            "decode_tail_token_layers": 0,
+            "prefill_retention_units": 0,
             # Prefill sizing: prefill_tokens counts the real prompt
             # positions the prefill dispatches computed (prefix-cached
             # positions excluded), prefill_pad_tokens the rest of each
@@ -1807,6 +1871,8 @@ class InferenceEngine:
             if W is not None and not self.chunked
             else np.zeros_like(ctxs)
         )
+        if self._chunk is not None:
+            first_live = ctxs // self._chunk * self._chunk // psz
         n_real = bucket // psz - first_live
         last = np.minimum(ctxs + Wd - 1, icfg.max_seq_len - 1)
         first_window = np.minimum(last // psz + 1, self.pages_per_seq)
@@ -2102,6 +2168,9 @@ class InferenceEngine:
         wholly before that are dead — never allocated at admission, and
         freed as the window rolls past them (_roll_window). 0 without SWA.
         """
+        if self._chunk is not None:
+            # Everything below the last complete chunk is in the state.
+            return context_len // self._chunk * self._chunk // self.psz
         W = self.page_window
         if W is None:
             return 0
@@ -2114,13 +2183,16 @@ class InferenceEngine:
         them, so a windowed sequence's steady-state footprint is
         O(window), not O(context). Freed logical slots keep a None
         placeholder so page indices stay position-aligned; their table
-        entries point at scratch page 0 (never read)."""
-        if self.page_window is None:
+        entries point at scratch page 0 (never read). A power-retention
+        model's dead pages are those behind its slot's last fold."""
+        if self.page_window is None and self._chunk is None:
             return
         for req in self.slots:
             if req is None or req.slot is None:
                 continue
             first = min(
+                int(self.fold_lens[req.slot]) // self.psz
+                if self._chunk is not None else
                 self._first_live_page(int(self.seq_lens[req.slot])),
                 len(req.pages),
             )
@@ -2475,6 +2547,11 @@ class InferenceEngine:
         req = self._active_request(rid)
         if req is None:
             raise ValueError(f"no active request {rid} to export")
+        if self._chunk is not None:
+            raise ValueError(
+                f"model {self.mcfg.name!r} keeps a state row a request "
+                f"(model.attention=power_retention), which migration does "
+                f"not ship yet")
         slot = req.slot
         return {
             "prompt": list(req.prompt),
@@ -3010,6 +3087,11 @@ class InferenceEngine:
             p_pre = 1 << (max_pre - 1).bit_length() if max_pre > 0 else 0
             pre_lens = np.zeros(nb, np.int32)
             pre_pages = np.zeros((nb, p_pre), np.int32)
+            # A power-retention model: the state row each row of the burst
+            # owns (slot + 1; padding rows take scratch row 0).
+            state_rows = () if self._chunk is None else (jnp.asarray(
+                [r.slot + 1 for r in reqs] + [0] * (nb - len(reqs)),
+                jnp.int32),)
             for i, req in enumerate(reqs):
                 npre = req.n_prefix
                 tail = req.context[npre * self.psz:]
@@ -3043,6 +3125,7 @@ class InferenceEngine:
                     jnp.asarray(pages),
                     jnp.asarray(pre_lens),
                     jnp.asarray(pre_pages),
+                    *state_rows,
                 )
         except DispatchFault:
             # Unwind this burst's admissions: their slots are claimed but
@@ -3059,6 +3142,15 @@ class InferenceEngine:
         self.timing["prefill_dispatches"] += 1
         self.timing["prefill_tokens"] += real
         self.timing["prefill_pad_tokens"] += nb * s_pad - real
+        if self._chunk is not None:
+            from orion_tpu.ops.retention import query_units
+
+            for i, req in enumerate(reqs):
+                n = int(lengths[i])
+                self.fold_lens[req.slot] = n // self._chunk * self._chunk
+                self.timing["prefill_retention_units"] += (
+                    self.mcfg.n_layers
+                    * query_units(n, self.mcfg.resolved_head_dim))
         if self.mcfg.is_moe:
             # Pad rows have length 1, so one position of each routes too.
             self.timing["prefill_expert_rows"] += expert_rows(
@@ -3127,6 +3219,8 @@ class InferenceEngine:
         self.page_table[slot] = 0
         self.seq_lens[slot] = 0
         self.last_token[slot] = 0
+        # The state row is the slot's: the next prefill into it writes it.
+        self.fold_lens[slot] = 0
 
     def _preempt(self, req: Request) -> None:
         """Evict an active request, returning its pages; it re-enters at the
@@ -3830,6 +3924,8 @@ class InferenceEngine:
     def _decode_build_window(self):
         """Provision pages and upload the inputs of one fused decode
         window; None when no slot is live. Runs inside ``decode/build``."""
+        if self._chunk is not None:
+            self._fold_tails()
         self._grow_pages()
         active = [r for r in self.slots if r is not None and not r.done]
         if not active:
@@ -3858,9 +3954,40 @@ class InferenceEngine:
                 self.seq_lens[mask][:, None] + np.arange(W),
                 self.mcfg.sliding_window,
             ).sum())
+        if self._chunk is not None:
+            # The state and the tail are what this model's kernel reads.
+            lens = self.seq_lens[mask].astype(np.int64)
+            folded = self.fold_lens[mask]
+            L = self.mcfg.n_layers
+            self.timing["decode_state_slot_layers"] += L * W * len(active)
+            self.timing["decode_state_empty_slot_layers"] += (
+                L * W * int((folded == 0).sum()))
+            self.timing["decode_tail_token_layers"] += L * int(
+                (lens - folded).sum() * W + len(active) * (W * (W + 1) // 2))
+            return active, W, common
         self.timing["decode_kv_tokens"] += kv
         self._count_kv_by_layer_kind(self.seq_lens[mask].astype(np.int64), W)
         return active, W, common
+
+    def _fold_tails(self) -> None:
+        """A power-retention model, at the start of a decode window: every
+        live slot whose tail holds a complete chunk has it folded into its
+        state row (``runner.fold_step``, one dispatch a slot: one shape),
+        and the chunk's pages go back to the pool. The one place besides
+        prefill where a state changes."""
+        C = self._chunk
+        for req in self.slots:
+            if req is None or req.done or req.slot is None:
+                continue
+            slot = req.slot
+            while int(self.seq_lens[slot]) - int(self.fold_lens[slot]) >= C:
+                with self._phase("fold/run"):
+                    self.cache = self._run_dispatch(
+                        "fold", "fold", self.cache,
+                        jnp.int32(slot), jnp.asarray(self.page_table[slot]))
+                self.fold_lens[slot] += C
+                self.timing["folds"] += 1
+        self._roll_window()
 
     def _count_kv_by_layer_kind(self, lens: np.ndarray, W: int) -> None:
         """decode_kv_token_layers, decode_kv_pages_read,

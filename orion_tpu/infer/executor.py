@@ -28,6 +28,7 @@ import jax
 
 from orion_tpu.infer.runner import (
     decode_window,
+    fold_step,
     mixed_step,
     mixed_verify_step,
     prefill_step,
@@ -50,6 +51,7 @@ class DispatchExecutor:
         "mixed": mixed_step,
         "verify": verify_step,
         "mixed_verify": mixed_verify_step,
+        "fold": fold_step,
     }
 
     def __init__(self, engine):
@@ -77,6 +79,10 @@ class DispatchExecutor:
         is_default = name.endswith("_defaults")
         stem = name[: -len("_defaults")] if is_default else name
         fn = self.PROGRAM_FNS[stem]
+        if stem == "fold":
+            # No weights, no sampling: (cache, slot, page-table row).
+            return jax.jit(partial(fn, cfg=mcfg, mesh=mesh),
+                           donate_argnums=(0,))
         if stem == "prefill":
             kw: dict[str, Any] = dict(cfg=mcfg, mesh=mesh)
         else:
@@ -97,6 +103,21 @@ class DispatchExecutor:
                 top_p=icfg.top_p,
             )
         program = jax.jit(partial(fn, **kw), donate_argnums=(1,))
+        if stem == "prefill" and mcfg.is_retention:
+            # Which state row each row of a burst owns is the engine's to
+            # say; a caller that says nothing (a warm-up) gets the scratch
+            # row in the same shape and dtype, so that it compiles the
+            # program the engine runs.
+            import jax.numpy as jnp
+
+            def run_rows(params, cache, tokens, lengths, pages, pre_lens,
+                         pre_pages, state_rows=None):
+                if state_rows is None:
+                    state_rows = jnp.zeros((tokens.shape[0],), jnp.int32)
+                return program(params, cache, tokens, lengths, pages,
+                               pre_lens, pre_pages, state_rows)
+
+            return run_rows
         if stem == "prefill" and mcfg.holds_expert_share:
             # Such a model's prefill has a third result, the rows it
             # computed on experts held here (runner.HELD_ROWS): kept on the

@@ -99,12 +99,45 @@ def init_cache(
         if icfg.kv_quant is not None:
             raise ValueError(f"unknown inference.kv_quant={icfg.kv_quant!r}")
         dtype = jnp.dtype(mcfg.dtype)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        if mcfg.is_retention:
+            cache.update(retention_leaves(mcfg, icfg, dtype))
+        return cache
 
     if device is not None:
         with jax.default_device(device):
             return alloc()
     return alloc()
+
+
+# The leaves of ``retention_leaves`` that are a slot's and not a page's.
+SLOT_LEAVES = ("state", "state_z", "state_len")
+
+
+def retention_leaves(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> Cache:
+    """What a power-retention model keeps beside its pages, which hold only
+    a sequence's positions since its last fold (``k`` / ``v``, and ``g``:
+    the cumulative log-gates of each position within its own chunk,
+    float32, [layers, pages, K, page]: a layer's slice
+    is small enough to be taken out, written and put back in the layer scan,
+    where a scatter into the flat pool makes XLA re-lay the whole of it).
+
+    ``state`` [layers x (slots + 1), K, R, H, H] and ``state_z`` [layers x
+    (slots + 1), R, K, H] are a slot's fixed-size state (``ops/retention``:
+    R slabs of H; row 0 of each layer is a scratch row as page 0 is, slot b
+    owns row b + 1), and ``state_len`` [slots + 1] the positions it holds:
+    a multiple of the chunk, written by prefill and by the fold alone."""
+    from orion_tpu.ops.retention import n_slabs
+
+    K, H = mcfg.n_kv_heads, mcfg.resolved_head_dim
+    R, rows = n_slabs(H), mcfg.n_layers * (icfg.max_batch_size + 1)
+    return {
+        "g": jnp.zeros((mcfg.n_layers, icfg.num_pages, K, icfg.page_size),
+                       jnp.float32),
+        "state": jnp.zeros((rows, K, R, H, H), dtype),
+        "state_z": jnp.zeros((rows, R, K, H), jnp.float32),
+        "state_len": jnp.zeros((icfg.max_batch_size + 1,), jnp.int32),
+    }
 
 
 class PageAllocator:
@@ -286,10 +319,15 @@ def scrub_pages(
         jnp.arange(n_layers, dtype=jnp.int32)[:, None] * num_pages
         + pages[None, :].astype(jnp.int32)
     ).reshape(-1)
-    return {
-        name: arr.at[layer_rows].set(jnp.zeros((), arr.dtype))
-        for name, arr in cache.items()
-    }
+    out = {}
+    for name, arr in cache.items():
+        if name in SLOT_LEAVES:     # no page: the next prefill writes the row
+            out[name] = arr
+        elif name == "g":           # [layers, pages, K, page]
+            out[name] = arr.at[:, pages].set(jnp.zeros((), arr.dtype))
+        else:                       # [layers x pages, ...]
+            out[name] = arr.at[layer_rows].set(jnp.zeros((), arr.dtype))
+    return out
 
 
 class HostPagePool:

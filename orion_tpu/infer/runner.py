@@ -365,6 +365,8 @@ def prefill_step(
     pages: jax.Array,         # [Nb, S_pad // page_size] int32 page ids
     prefix_lens: Optional[jax.Array] = None,   # [Nb] int32 cached tokens
     prefix_pages: Optional[jax.Array] = None,  # [Nb, P_pre] int32 page ids
+    state_rows: Optional[jax.Array] = None,    # [Nb] int32: slot + 1, a
+    #              power-retention model's (0 = the scratch row); else None
     *,
     cfg: ModelConfig,
     mesh: Optional[jax.sharding.Mesh] = None,
@@ -397,6 +399,13 @@ def prefill_step(
     all-zero page lists: their K/V lands on the reserved scratch page 0 and
     is never read.
     """
+    if cfg.is_retention:
+        if prefix_pages is not None and prefix_pages.shape[1]:
+            raise ValueError(
+                "a power-retention model prefills whole prompts: a cached "
+                "prefix would need its state, which nothing snapshots")
+        return _retained_prefill(
+            params, cache, tokens, lengths, pages, state_rows, cfg, mesh)
     ctx = _prefill_ctx(
         params, cache, tokens, lengths, pages, prefix_lens, prefix_pages,
         cfg, paged_prefill=paged_prefill,
@@ -429,12 +438,19 @@ def _decode_core(
     mesh: Optional[jax.sharding.Mesh] = None,
 ) -> tuple[jax.Array, Cache]:
     """One decode forward for every slot -> (logits [B, V], cache'): the
-    paged backend at W = 1."""
-    ctx = _one_token_ctx(cache, write_pos, page_table, cfg)
+    paged backend at W = 1 (the retained backend for a power-retention
+    model: it reads a slot's state row and never writes it, so the body can
+    be run again on the cache it handed back)."""
+    if cfg.is_retention:
+        ctx, layer = _retained_ctx(
+            cache, write_pos, page_table, cfg), _retained_layer
+    else:
+        ctx, layer = _one_token_ctx(
+            cache, write_pos, page_table, cfg), _paged_layer
 
     def body(carry, bp, l, j, stack=None):
         x, cc = carry
-        return _paged_layer(x, cc, bp, l, j, ctx, cfg, mesh)
+        return layer(x, cc, bp, l, j, ctx, cfg, mesh)
 
     x = embed(params, tokens[:, None], ctx["positions"], cfg)
     x, cache = _scan_layers(params, cfg, body, (x, dict(cache)))
@@ -1019,3 +1035,209 @@ def mixed_verify_step(
         nan_guard=nan_guard)
     p_logits = _prefill_logits(params, xp, p_lengths, cfg, mesh)
     return (*verdicts, p_logits, cache)
+
+
+# -- the retained backend: a fixed-size state row a slot beside a paged tail --
+#
+# A power-retention model (model.attention, ops/retention.py) keeps of a
+# sequence a state row (``state`` / ``state_z``: everything below
+# ``state_len``, a multiple of the model's chunk, ``_chunk``) and, in pages, only
+# the positions from there on: K, V and ``g``, each position's cumulative
+# log-gate within its own chunk. The state is written in two places only:
+# at the end of prefill and by ``fold_step``. The page table stays indexed
+# by absolute position; entries behind the state may point anywhere.
+
+
+def _chunk(cfg: ModelConfig) -> int:
+    from orion_tpu.ops.retention import fold_chunk
+
+    return fold_chunk(cfg.max_seq_len)
+
+
+def _retained_prefill(params, cache, tokens, lengths, pages, state_rows,
+                      cfg: ModelConfig, mesh):
+    """Whole prompts: every complete chunk of a row into its state row
+    (``state_rows``: slot + 1; padding rows and a warm-up take scratch row
+    0), every position's K, V and gate into ``pages`` (the engine points
+    the pages behind a row's state at scratch page 0)."""
+    from orion_tpu.ops.retention import chunk_cumsum, power_retention
+
+    Nb, S_pad = tokens.shape
+    psz = cache["k"].shape[2]
+    NP = cache["k"].shape[0] // cfg.n_layers
+    n_rows = cache["state_len"].shape[0]
+    C = _chunk(cfg)
+    if state_rows is None:
+        # A caller of the bare function with a K/V model's seven arguments
+        # (tests/benchmark/test_aot_v5e.py); the engine's program always
+        # gets an array (executor.jit_program), so that one program serves
+        # the warm-up and the window.
+        state_rows = jnp.zeros((Nb,), jnp.int32)
+    positions = jnp.broadcast_to(
+        jnp.arange(S_pad, dtype=jnp.int32), (Nb, S_pad))
+    valid = positions < lengths[:, None]
+
+    def body(carry, bp, l, j, stack=None):
+        x, cc = carry
+
+        def attend(q, k, v, log_g):
+            y, (S, z) = power_retention(
+                q, k, v, log_g, lengths=lengths, chunk=C, impl=cfg.kernels)
+
+            def written():
+                rows = l * NP + pages
+                new = _scatter_pages(cc, k, v, rows)
+                b = chunk_cumsum(log_g, C).reshape(Nb, -1, psz, k.shape[2])
+                new["g"] = _layer_slice(
+                    cc["g"], l, lambda g: g.at[pages].set(
+                        b.transpose(0, 1, 3, 2)))
+                srows = l * n_rows + state_rows
+                new["state"] = cc["state"].at[srows].set(
+                    S.astype(cc["state"].dtype))
+                new["state_z"] = cc["state_z"].at[srows].set(
+                    jnp.swapaxes(z, 1, 2))
+                return new
+
+            return y, written
+
+        x, _, written = block(
+            x, bp, cfg, positions, attend, kind=_kind(cfg, j), mesh=mesh,
+            ffn_mesh=mesh, valid=valid)
+        return x, {**cc, **written()}
+
+    x = embed(params, tokens, positions, cfg)
+    x, cache = _scan_layers(params, cfg, body, (x, dict(cache)))
+    cache["state_len"] = cache["state_len"].at[state_rows].set(
+        lengths // C * C)
+    return _prefill_logits(params, x, lengths, cfg, mesh), cache
+
+
+def _layer_slice(pool: jax.Array, l, update):
+    """``update`` applied to layer l's slice of a [layers, ...] leaf."""
+    one = jax.lax.dynamic_index_in_dim(pool, l, keepdims=False)
+    return jax.lax.dynamic_update_index_in_dim(pool, update(one), l, 0)
+
+
+def _retained_ctx(cache: Cache, pos: jax.Array, page_table: jax.Array,
+                  cfg: ModelConfig) -> dict:
+    """Batch-level tensors of ``_retained_layer``: one new token a slot at
+    position ``pos`` (slot b of the page table owns state row b + 1)."""
+    from orion_tpu.ops._dispatch import resolve_impl
+    from orion_tpu.ops.retention import tail_pages
+
+    psz = cache["k"].shape[2]
+    NP = cache["k"].shape[0] // cfg.n_layers
+    P = page_table.shape[1]
+    C = _chunk(cfg)
+    F = cache["state_len"][1:]                                 # [B]
+    slots = jnp.arange(pos.shape[0])
+    at = jnp.minimum(pos, P * psz - 1)
+    prev = jnp.maximum(pos - 1, 0)
+    nT = tail_pages(C, psz)
+    tp = jnp.minimum(F[:, None] // psz + jnp.arange(nT), P - 1)
+    jpos = F[:, None] + jnp.arange(nT * psz)                   # [B, T]
+    use_pallas, interpret = resolve_impl(cfg.kernels)
+    return dict(
+        NP=NP, C=C, F=F, pos=pos, at=at, positions=at[:, None],
+        page_table=page_table,
+        new_page=page_table[slots, at // psz], new_off=at % psz,
+        prev_page=page_table[slots, prev // psz], prev_off=prev % psz,
+        tail=jnp.take_along_axis(page_table, tp, axis=1),
+        second=jpos >= (F + C)[:, None],
+        live=jpos <= at[:, None],
+        n_rows=cache["state_len"].shape[0],
+        use_pallas=use_pallas, interpret=interpret,
+    )
+
+
+def _retained_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
+                    cfg: ModelConfig, mesh) -> tuple[jax.Array, Cache]:
+    """The retained backend: one layer of one new token a slot. Its K and V
+    land in the tail page (inside the kernel on the pallas path, as the
+    paged backend's do) and its cumulative log-gate in ``g``; the query
+    attends its slot's tail and reads its state row. The gates are this
+    function's: what the kernel is handed is, for the new token and for
+    every tail position, the log-decay since ``state_len``."""
+    from orion_tpu.ops.retention import BIG, retention_decode_xla
+
+    NP, C, F, pos, at = ctx["NP"], ctx["C"], ctx["F"], ctx["pos"], ctx["at"]
+    B = at.shape[0]
+
+    def attend(q, k, v, log_g):
+        gp = jax.lax.dynamic_index_in_dim(cc["g"], l, keepdims=False)
+        K = gp.shape[1]
+        # A chunk's sum restarts at its first position.
+        b_new = log_g[:, 0] + jnp.where(
+            (pos % C == 0)[:, None], 0.0,
+            gp[ctx["prev_page"], :, ctx["prev_off"]])          # [B, K]
+        gp = gp.at[ctx["new_page"], :, ctx["new_off"]].set(b_new)
+        bt = gp[ctx["tail"]].transpose(0, 2, 1, 3).reshape(B, K, -1)
+        # A tail spans two chunks at most: positions of the second add the
+        # whole first chunk's sum (its last position's entry).
+        first = bt[:, :, C - 1]                                # [B, K]
+        c_tail = bt + jnp.where(ctx["second"][:, None, :],
+                                first[:, :, None], 0.0)
+        c_tail = jnp.where(ctx["live"][:, None, :], c_tail, BIG)
+        c_q = b_new + jnp.where((at >= F + C)[:, None], first, 0.0)
+        kw = dict(layer_base=l * NP, state_base=l * ctx["n_rows"])
+        args = (q[:, 0], k[:, 0], v[:, 0], c_q, c_tail, cc["k"], cc["v"],
+                cc["state"], cc["state_z"], ctx["page_table"], F, pos)
+        if ctx["use_pallas"]:
+            if mesh is not None:
+                raise ValueError("the retention kernels run on one device")
+            from orion_tpu.ops.pallas.retention import retention_decode
+
+            y, kp, vp = retention_decode(
+                *args, interpret=ctx["interpret"], **kw)
+        else:
+            y, kp, vp = retention_decode_xla(*args, **kw)
+        return y[:, None], {**cc, "k": kp, "v": vp,
+                            "g": jax.lax.dynamic_update_index_in_dim(
+                                cc["g"], gp, l, 0)}
+
+    x, _, cc = block(x, bp, cfg, ctx["positions"], attend,
+                     kind=_kind(cfg, j), mesh=mesh)
+    return x, cc
+
+
+def fold_step(cache: Cache, slot: jax.Array, page_row: jax.Array, *,
+              cfg: ModelConfig, mesh=None) -> Cache:
+    """Fold the complete chunk at the head of ONE slot's tail (positions
+    ``state_len .. state_len + chunk`` of slot ``slot``, through
+    its page-table row ``page_row`` [P]) into its state row, in every
+    layer, and advance ``state_len``. The engine runs it at the start of a
+    decode window for each slot whose tail holds a complete chunk, then
+    frees the chunk's pages. No weights are read."""
+    from orion_tpu.ops._dispatch import resolve_impl
+    from orion_tpu.ops.retention import retention_fold_xla
+
+    del mesh
+    psz = cache["k"].shape[2]
+    NP = cache["k"].shape[0] // cfg.n_layers
+    n_rows = cache["state_len"].shape[0]
+    C, P = _chunk(cfg), page_row.shape[0]
+    F = cache["state_len"][slot + 1]
+    pages = page_row[jnp.minimum(F // psz + jnp.arange(C // psz), P - 1)]
+    use_pallas, interpret = resolve_impl(cfg.kernels)
+
+    def chunk(pool, rows):      # [n, K, psz, ...] -> [K, C, ...]
+        x = jnp.moveaxis(pool[rows], 1, 0)
+        return x.reshape(x.shape[0], C, *x.shape[3:])
+
+    def body(carry, l):
+        state, z = carry
+        rows = l * NP + pages
+        args = (state, z, chunk(cache["k"], rows), chunk(cache["v"], rows),
+                chunk(jax.lax.dynamic_index_in_dim(
+                    cache["g"], l, keepdims=False), pages),
+                l * n_rows + slot + 1)
+        if use_pallas:
+            from orion_tpu.ops.pallas.retention import retention_fold
+
+            return tuple(retention_fold(*args, interpret=interpret)), None
+        return retention_fold_xla(*args), None
+
+    (state, z), _ = jax.lax.scan(
+        body, (cache["state"], cache["state_z"]), jnp.arange(cfg.n_layers))
+    return {**cache, "state": state, "state_z": z,
+            "state_len": cache["state_len"].at[slot + 1].add(C)}

@@ -169,6 +169,11 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                 block["mlp"]["b_out"] = jnp.zeros((D,), pdt)
         if cfg.attn_gate is not None:
             block["attn"]["wg"] = _normal(next(bkeys), (D, N), pdt, std)
+        if cfg.qk_norm:
+            block["attn"]["q_norm"] = jnp.ones((H,), pdt)
+            block["attn"]["k_norm"] = jnp.ones((H,), pdt)
+        if cfg.is_retention:
+            block["attn"]["wr"] = _normal(next(bkeys), (D, K), pdt, std)
 
         return block
 
@@ -254,6 +259,11 @@ def _block_axes(cfg: ModelConfig, lead: tuple, kind) -> Params:
         block["attn"]["bo"] = lead + ("embed",)
     if cfg.attn_gate is not None:
         block["attn"]["wg"] = lead + ("embed", "heads")
+    if cfg.qk_norm:
+        block["attn"]["q_norm"] = lead + (None,)
+        block["attn"]["k_norm"] = lead + (None,)
+    if cfg.is_retention:
+        block["attn"]["wr"] = lead + ("embed", "kv_heads")
     if moe:
         block["moe"] = {
             "router": lead + ("embed", "expert"),
@@ -376,6 +386,10 @@ def qkv_proj(
     q = q.reshape(B, S, N, H)
     k = k.reshape(B, S, K, H)
     v = v.reshape(B, S, K, H)
+    if cfg.qk_norm:
+        # Per head, over its H numbers, before the rotary embedding.
+        q = ops.rmsnorm(q, p["q_norm"], eps=cfg.norm_eps)
+        k = ops.rmsnorm(k, p["k_norm"], eps=cfg.norm_eps)
 
     if cfg.pos_embedding == "rope":
         rope = functools.partial(
@@ -420,6 +434,14 @@ def _attn_gate(h: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
         jnp.einsum("bsd,dn->bsn", h, _load_w(p["wg"], h.dtype)))
 
 
+def retention_log_gate(h: jax.Array, p: Params) -> jax.Array:
+    """[B, S, K] float32: the log of a power-retention layer's gate, one a
+    K/V head and position, from the layer's normed input ``h`` (the one
+    ``qkv_proj`` read). ONE function, as ``_attn_gate`` is."""
+    return jax.nn.log_sigmoid(jnp.einsum(
+        "bsd,dk->bsk", h, _load_w(p["wr"], h.dtype)).astype(jnp.float32))
+
+
 def mlp_or_moe(
     h: jax.Array, bp: Params, cfg: ModelConfig, mesh: Optional[Any] = None,
     valid: Optional[jax.Array] = None,
@@ -459,6 +481,24 @@ def _train_attend(
         and mesh is not None
         and mesh.shape.get(cfg.sequence_axis, 1) > 1
     )
+
+    if cfg.is_retention:
+        if sp_active or segment_ids is not None:
+            raise ValueError(
+                "model.attention=power_retention trains whole unpacked "
+                "sequences on one sequence shard: no sequence axis, no "
+                "segment ids")
+        from orion_tpu.ops.retention import fold_chunk, power_retention
+
+        def retain(q, k, v, log_g):
+            # The XLA chunked form, which JAX differentiates (the Pallas
+            # kernels have no backward).
+            return power_retention(
+                q, k, v, log_g,
+                chunk=min(fold_chunk(cfg.max_seq_len), q.shape[1]),
+                impl="xla")[0], None
+
+        return retain
 
     def attend(q, k, v):
         if sp_active:
@@ -545,7 +585,9 @@ def block(
     prefill, the decode window and draft verification all call it. Returns
     ``(x, moe_aux_loss, state)``.
 
-    ``attend(q, k, v) -> (out [B, S, N, H], state)`` is all that differs
+    ``attend(q, k, v) -> (out [B, S, N, H], state)`` (with the log-gates
+    [B, S, K] as a fourth argument under model.attention=power_retention)
+    is all that differs
     between them: what attention reads and where K/V go (``_train_attend``
     here; the dense and paged backends of ``infer/runner.py``, whose state
     is the KV pool). The body never sees a cache, a page table or a segment
@@ -577,7 +619,12 @@ def block(
             _norm(x, bp["attn_norm"], cfg, mesh), "attn_norm_out"
         )
         q, k, v = qkv_proj(h, bp["attn"], cfg, positions, mesh, kind)
-        out, state = attend(q, k, v)
+        if cfg.is_retention:
+            # Such a layer's attention also reads the log-gates, handed to
+            # ``attend`` the way ``out_proj`` is handed ``h``.
+            out, state = attend(q, k, v, retention_log_gate(h, bp["attn"]))
+        else:
+            out, state = attend(q, k, v)
         # remat="names" saves the kernel output: the single most expensive
         # per-layer tensor to rebuild (a full flash fwd pass) at [B,S,N,H]
         # storage. (No-op identity under every other policy.)

@@ -22,6 +22,16 @@ compares fwd + grads against the xla reference ops:
   - blockwise paged-flash prefill (chunk queries over the paged history,
     chunk pages written in-kernel): {float, int8} x {full, window}
   - fused RMSNorm, fused RoPE
+  - power retention (``--only retention`` runs these alone): the chunked
+    prefill kernel (outputs and the state it hands out, ragged lengths),
+    the decode kernel over a state row and a paged tail (tail only, state
+    and tail across a chunk's end, an empty tail; the page it wrote
+    bitwise) and the fold, each against the XLA form; and, with gates near
+    1, the chain prefill -> fold -> decode against the benchmark's
+    QUADRATIC float32 reference (which shares nothing with the program),
+    with a control that reads the state as zeros and has to fail; at the
+    Brumby cell's shapes (40 query / 8 kv heads x 128, the program's chunk,
+    64-token pages) on the chip and at a head of 16 under the interpreter
 
 The paged / ragged / tree / prefill groups run at two geometries: a small
 one (8 query / 4 kv heads, 4 pages a row, window 100) and the serving leg's
@@ -813,6 +823,157 @@ def norm_rope_checks() -> None:
         check(f"rope N={n_heads} dx", gp, gx, 4e-2)
 
 
+def retention_checks() -> None:
+    from benchmarks.reference.brumby import _retention as quadratic
+    from orion_tpu.ops import retention as ret
+    from orion_tpu.ops.pallas import retention as pret
+
+    if INTERP:
+        N, K, H, C, psz, dt, tol = 4, 2, 16, 16, 4, jnp.float32, 2e-5
+        lens, S, fade = (40, 16, 9), 48, 0.06
+    else:
+        N, K, H, C, psz, dt, tol = 40, 8, 128, ret.CHUNK, 64, jnp.bfloat16, 3e-2
+        lens, S, fade = (2304, 1024, 700), 3072, 0.001
+    R = ret.n_slabs(H)
+    ks = iter(jax.random.split(jax.random.key(7), 48))
+    nrm = lambda *sh: jax.random.normal(next(ks), sh, jnp.float32)  # noqa: E731
+    gate = lambda *sh: jax.nn.log_sigmoid(nrm(*sh) + 2.0)           # noqa: E731
+    # Gates NEAR 1 (a chunk keeps 22-37 % of what came before it, the whole
+    # row 1-5 %), where a state read wrongly is a wrong output.
+    slow = lambda *sh: -fade * (1.0 + jax.nn.sigmoid(nrm(*sh)))     # noqa: E731
+
+    # prefill: ragged rows against the XLA form
+    B = len(lens)
+    q, k, v = (nrm(B, S, n, H).astype(dt) for n in (N, K, K))
+    g, ln = gate(B, S, K), jnp.asarray(lens, jnp.int32)
+    b = ret.chunk_cumsum(g, C)
+    want, (Sw, zw) = ret.power_retention(q, k, v, g, lengths=ln, chunk=C)
+    got, (Sg, zg) = pret.retention_prefill(
+        q, k, v, b, lengths=ln, chunk=C, interpret=INTERP)
+    check("retention prefill out", got, want, tol)
+    check("retention prefill state", Sg, Sw, tol)
+    check("retention prefill state_z", zg, zw, tol)
+
+    # The chain prefill -> fold -> decode with gates near 1 against the
+    # QUADRATIC float32 form of benchmarks/reference/brumby.py (every
+    # pair's weight written out: no state, no chunk, nothing of the
+    # program's): a row of 3 chunks less a quarter, then one more token.
+    n = 3 * C - C // 4
+    q1, k1, v1 = (nrm(1, n + 1, h, H).astype(dt) for h in (N, K, K))
+    g1 = slow(1, n + 1, K)
+    f32 = lambda x: x[0].astype(jnp.float32)                        # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        quad = jax.jit(quadratic)(f32(q1), f32(k1), f32(v1), g1[0])
+    b1 = ret.chunk_cumsum(g1, C)                           # [1, n + 1, K]
+    y1, (S1, z1) = pret.retention_prefill(
+        q1[:, :n], k1[:, :n], v1[:, :n], b1[:, :n], chunk=C,
+        interpret=INTERP)
+    check("retention prefill out, gates near 1, vs the quadratic form",
+          y1[0], quad[:n], tol)
+    # The state of ONE chunk from a prefill, the second folded onto it,
+    # against the state of two chunks that the prefill handed out above.
+    _, (Sa, za) = pret.retention_prefill(
+        q1[:, :C], k1[:, :C], v1[:, :C], b1[:, :C], chunk=C,
+        interpret=INTERP)
+    Bd, L, NP, P = 4, 2, 64, 3 * C // psz
+    slot, lay = 1, 1
+    row = lay * (Bd + 1) + slot + 1
+    state = jnp.zeros((L * (Bd + 1), K, R, H, H), dt).at[row].set(
+        Sa[0].astype(dt))
+    state_z = jnp.zeros((L * (Bd + 1), R, K, H), jnp.float32).at[row].set(
+        jnp.swapaxes(za[0], 0, 1))
+    heads_first = lambda x: jnp.swapaxes(x[0, C:2 * C], 0, 1)       # noqa: E731
+    state, state_z = pret.retention_fold(
+        state, state_z, heads_first(k1), heads_first(v1),
+        b1[0, C:2 * C].T, jnp.int32(row), interpret=INTERP)
+    check("retention fold onto a prefill's state vs the prefill of both",
+          state[row], S1[0], tol)
+    check("retention fold onto a prefill's state_z vs the prefill of both",
+          jnp.swapaxes(state_z[row], 0, 1), z1[0], tol)
+    # One more token: the folded row, the tail (positions 2C .. n) in pages.
+    nT = ret.tail_pages(C, psz)
+    T = nT * psz
+    table = np.zeros((Bd, P), np.int32)
+    pages = np.arange(2 * C // psz, n // psz + 1)
+    table[slot, pages] = 1 + np.arange(len(pages))
+    assert len(pages) < NP
+    kp, vp = (jnp.zeros((L * NP, K, psz, H), dt) for _ in range(2))
+    tail = lambda x: jnp.pad(                                       # noqa: E731
+        x[0, 2 * C:n], ((0, len(pages) * psz - (n - 2 * C)), (0, 0), (0, 0))
+    ).reshape(-1, psz, K, H).transpose(0, 2, 1, 3)
+    rows = lay * NP + 1 + np.arange(len(pages))
+    kp, vp = kp.at[rows].set(tail(k1)), vp.at[rows].set(tail(v1))
+    F = jnp.zeros((Bd,), jnp.int32).at[slot].set(2 * C)
+    pos = jnp.zeros((Bd,), jnp.int32).at[slot].set(n)
+    held = jnp.arange(T) <= n - 2 * C
+    ct = jnp.where(held[None, :], jnp.pad(
+        b1[0, 2 * C:n + 1].T, ((0, 0), (0, T - (n + 1 - 2 * C)))), ret.BIG)
+    c_tail = jnp.full((Bd, K, T), ret.BIG).at[slot].set(ct)
+    c_q = jnp.zeros((Bd, K)).at[slot].set(b1[0, n])
+    one = lambda x: jnp.zeros((Bd,) + x.shape[2:], dt).at[slot].set(  # noqa: E731
+        x[0, n])
+    yd, _, _ = pret.retention_decode(
+        one(q1), one(k1), one(v1), c_q, c_tail, kp, vp, state, state_z,
+        jnp.asarray(table), F, pos, layer_base=lay * NP,
+        state_base=lay * (Bd + 1), interpret=INTERP)
+    check("retention decode after prefill and fold, gates near 1, vs the "
+          "quadratic form", yd[slot], quad[n], tol)
+    blind, _, _ = pret.retention_decode(
+        one(q1), one(k1), one(v1), c_q, c_tail, kp, vp,
+        jnp.zeros_like(state), jnp.zeros_like(state_z), jnp.asarray(table),
+        F, pos, layer_base=lay * NP, state_base=lay * (Bd + 1),
+        interpret=INTERP)
+    seen = float(jnp.max(jnp.abs(blind[slot].astype(jnp.float32) - quad[n]))
+                 / jnp.max(jnp.abs(quad[n])))
+    record("the same check with the state read as zeros FAILS (it holds "
+           "the state)", not seen < 3 * tol, f": rel={seen:.3e}")
+
+    # decode against the XLA form: slot 0 tail only, 1 state + a tail past a
+    # chunk's end, 2 state + empty tail, 3 inactive (page table of zeros)
+    F = jnp.asarray([0, C, 2 * C, 0], jnp.int32)
+    pos = jnp.asarray([C // 2 + 3, 2 * C + 2, 2 * C, 0], jnp.int32)
+    table = np.zeros((Bd, P), np.int32)
+    nxt = 1
+    for s in range(3):
+        for pg in range(int(F[s]) // psz, int(pos[s]) // psz + 1):
+            table[s, pg] = nxt
+            nxt += 1
+    assert nxt <= NP
+    table = jnp.asarray(table)
+    kp, vp = (0.5 * nrm(L * NP, K, psz, H).astype(dt) for _ in range(2))
+    state = (0.1 * nrm(L * (Bd + 1), K, R, H, H)).astype(dt)
+    state_z = jnp.abs(nrm(L * (Bd + 1), R, K, H))
+    jpos = F[:, None] + jnp.arange(T)
+    c_tail = -0.05 * (jnp.arange(T) + 1.0)[None, None] * jnp.ones((Bd, K, 1))
+    c_tail = jnp.where((jpos <= pos[:, None])[:, None], c_tail, ret.BIG)
+    c_q = -0.05 * (pos - F + 1.0)[:, None] * jnp.ones((1, K))
+    qd, kn, vn = (nrm(Bd, h, H).astype(dt) for h in (N, K, K))
+    args = (qd, kn, vn, c_q, c_tail, kp, vp, state, state_z, table, F, pos)
+    kw = dict(layer_base=NP, state_base=Bd + 1)
+    yw, kw_, vw_ = ret.retention_decode_xla(*args, **kw)
+    yg, kg_, vg_ = pret.retention_decode(*args, interpret=INTERP, **kw)
+    check("retention decode out", yg[:3], yw[:3], tol)
+    # every page but the scratch page (the inactive slot's sink)
+    bitwise("retention decode pools",
+            [(kg_[:NP], kw_[:NP]), (kg_[NP + 1:], kw_[NP + 1:]),
+             (vg_[NP + 1:], vw_[NP + 1:])])
+
+    # fold: one slot's chunk into its row, the other rows untouched
+    kc, vc = (nrm(K, C, H).astype(dt) for _ in range(2))
+    bc = jnp.cumsum(gate(K, C), axis=1)
+    row = jnp.int32(Bd + 2)
+    # Jitted: run op by op, the XLA form's row update aborts the v5e
+    # compiler (fusion_emitter: IsFusibleUnalignedDUS; PR 33, call 1).
+    Sw, zw = jax.jit(ret.retention_fold_xla)(state, state_z, kc, vc, bc, row)
+    Sg, zg = pret.retention_fold(state, state_z, kc, vc, bc, row,
+                                 interpret=INTERP)
+    check("retention fold state", Sg, Sw, tol)
+    check("retention fold state_z", zg, zw, tol)
+    others = np.arange(state.shape[0]) != int(row)
+    bitwise("retention fold leaves other rows",
+            [(Sg[others], state[others]), (zg[others], state_z[others])])
+
+
 def main() -> int:
     global INTERP
     INTERP = "--interpret" in sys.argv[1:]
@@ -830,6 +991,13 @@ def main() -> int:
     print(f"platform={dev.platform} device_kind={dev.device_kind!r} "
           f"devices={len(jax.devices())} interpret={INTERP}", flush=True)
 
+    if "retention" in sys.argv[1:]:     # --only retention
+        guarded("retention", retention_checks)
+        green = sum(ok for _, ok in RESULTS)
+        print(f"{'ALL-OK' if green == len(RESULTS) else 'SOME-FAIL'} "
+              f"{green} of {len(RESULTS)} checks [retention only]")
+        return 0 if green == len(RESULTS) else 1
+    guarded("retention", retention_checks)
     guarded("flash", flash_checks)
     if not INTERP:      # the cells' sizes: minutes under the interpreter
         guarded("flash @cells", flash_cell_checks)
