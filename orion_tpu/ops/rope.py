@@ -15,6 +15,35 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+# Sequence lengths from here up reach the Pallas kernel under
+# ``impl="pallas"``; shorter ones (decode's one new token a slot, a verify
+# window, a short chunk) are the XLA forms below, which the compiler fuses
+# with the q/k norm before them and the layout change after them. From
+# tools/rope_sweep.py on a v5e (PERF.md section 6, PR 34), bf16
+# ``[B, S, N, 128]`` at B = 32, us a call in a chain of calls, kernel / XLA:
+#
+#   S        N = 8        N = 32       N = 40       N = 72      N = 72 (t)
+#   1     11.3 / 0.9   14.1 / 2.2   60.4 / 2.6   44.2 / 4.3   28.2 / 5.1
+#   8     13.7 / 4.8   24.2 / 16.0  71.7 / 21.1  62.3 / 33.8  47.8 / 40.3
+#   16    17.3 / 9.3   38.7 / 31.3  94.9 / 38.1  95.6 / 68.0  77.0 / 75.8
+#   32    27.9 / 18.5  71.4 / 63.9   142 / 71.9   138 / 102   99.6 / 114
+#   64    48.6 / 37.7   100 / 89     196 / 104    213 / 283    147 / 401
+#   128     91 / 74     153 / 256    328 / 424    424 / 1205   324 / 1369
+#   512    237 / 302    906 / 2542  1517 / 3193  2049 / 5563  1933 / 6918
+#
+# ((t): the table kernel on a YaRN, half-rotated table.) The kernel's grid
+# is one batch row a step on a block of at least 8 rows, 0.35-1.9 us a step
+# whatever the rows hold: at one row it costs 5 to 23 times the XLA form.
+# Through 32 rows the XLA form is ahead at every head count on the plain
+# table and within 15 % on a table; it falls off from 48 rows on a table
+# (2x at 48, 2.7x at 64) and from 64-128 on the plain one (1.3 to 3.6 times
+# the kernel's time at 512). At B = 1 the two are within a tenth at 8 and
+# 32 heads and the XLA form is ahead by up to 3x at 40 and 72. The kernel
+# handed all ``B x S`` rows as ONE sequence reads 0.9-6.2 us at S = 1, level
+# with the XLA form alone (0.9-5.1) and so behind it inside a program, where
+# only the XLA form fuses with its neighbours: no second kernel was built.
+KERNEL_MIN_SEQ = 64
+
 
 def rope_frequencies(
     head_dim: int, positions: jax.Array, theta: float
@@ -77,13 +106,16 @@ def apply_rope(
     ``mesh`` (the mesh the enclosing jit spans) runs the Pallas kernel per
     shard — batch, sequence and heads split over their mesh axes — because
     a Mosaic kernel cannot be auto-partitioned; the xla path ignores it.
+    ``impl="pallas"`` means the kernel from ``KERNEL_MIN_SEQ`` rows up and
+    the XLA form under it: the length the caller hands over decides, at
+    trace time.
     """
     from orion_tpu.ops._dispatch import (
         _BATCH_AXES, resolve_impl, shard_kernel, split_axes,
     )
 
     use_pallas, interpret = resolve_impl(impl)
-    if use_pallas:
+    if use_pallas and x.shape[1] >= KERNEL_MIN_SEQ:
         from orion_tpu.ops.pallas.rope import rope_pallas
 
         if positions.ndim == 1:

@@ -46,8 +46,10 @@ def test_pallas_on_cpu_raises_and_only_interpret_interprets():
         ops.attention(q, q, q, impl="pallas")
     with pytest.raises(RuntimeError, match="backend is 'cpu'"):
         ops.rmsnorm(jnp.ones((4, 8)), jnp.ones((8,)), impl="pallas")
+    S = ops.rope.KERNEL_MIN_SEQ      # from here up the kernel rotates
     with pytest.raises(RuntimeError, match="backend is 'cpu'"):
-        ops.apply_rope(q, jnp.arange(16), impl="pallas")
+        ops.apply_rope(
+            jnp.ones((1, S, 2, 8), jnp.float32), jnp.arange(S), impl="pallas")
     out = ops.attention(q, q, q, impl="pallas_interpret")
     assert out.shape == q.shape
 

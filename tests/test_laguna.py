@@ -218,7 +218,7 @@ def test_rope_tables():
     """The program's table against the reference's own computation of it, at
     the published parameters; partial rotation leaves the other dims; the
     Pallas kernel (interpreted) equals the jnp path and its gradient."""
-    from orion_tpu.ops.rope import apply_rope, rope_table
+    from orion_tpu.ops.rope import KERNEL_MIN_SEQ, apply_rope, rope_table
 
     m, ref = get_config("laguna-s-2.1").model, _reference()
     for kind, rope in (("full_attention", m.rope_full),
@@ -233,8 +233,9 @@ def test_rope_tables():
     # fast dims keep 1/f, slow dims read 1/(128 f), a ramp between
     np.testing.assert_allclose(inv[:4], 1 / f[:4], rtol=1e-6)
     np.testing.assert_allclose(inv[-4:], 1 / (128 * f[-4:]), rtol=1e-6)
-    x = jax.random.normal(jax.random.key(0), (2, 24, 6, 128), jnp.float32)
-    pos = jnp.broadcast_to(jnp.arange(24)[None] * 300, (2, 24))
+    S = KERNEL_MIN_SEQ + 8      # a length the kernel takes
+    x = jax.random.normal(jax.random.key(0), (2, S, 6, 128), jnp.float32)
+    pos = jnp.broadcast_to(jnp.arange(S)[None] * 100, (2, S))
     a = apply_rope(x, pos, rope=m.rope_full, impl="xla")
     np.testing.assert_array_equal(a[..., 64:], x[..., 64:])
     b = apply_rope(x, pos, rope=m.rope_full, impl="pallas_interpret")

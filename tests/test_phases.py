@@ -274,17 +274,21 @@ def pallas_names(jaxpr, out=None) -> set:
 
 
 KERNELS = ["model.kernels=pallas_interpret"]
+# ``rope`` is in a program whose rows are ``ops.rope.KERNEL_MIN_SEQ`` (64)
+# tokens or longer (the 100-token prompt's prefill); decode's one token a
+# slot, the verify window of 4 and the mixed step's chunk of 16 rotate in
+# XLA's own code (ISSUE 34).
 PROGRAMS = {
     "plain": (KERNELS, {
         "prefill": {"flash_fwd", "rmsnorm", "rope"},
-        "decode": {"paged_decode", "rmsnorm", "rope"}}),
+        "decode": {"paged_decode", "rmsnorm"}}),
     "chunked-paged": (
         KERNELS + MODES["chunked"] + ["inference.paged_prefill=true"], {
             "mixed": {"flash_fwd", "paged_decode", "paged_flash_prefill",
-                      "rmsnorm", "rope"}}),
+                      "rmsnorm"}}),
     "speculative": (KERNELS + MODES["speculative"], {
         "prefill": {"flash_fwd", "rmsnorm", "rope"},
-        "verify": {"ragged_paged", "rmsnorm", "rope"}}),
+        "verify": {"ragged_paged", "rmsnorm"}}),
 }
 
 

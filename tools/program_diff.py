@@ -22,8 +22,10 @@ order, under other numbers from the compiler's passes) or ``DIFFERENT``
 with the count of differing lines, and exits non-zero on the last.
 
 A refactor that claims equal programs shows it here before the chip does
-(PERF.md §6, PR 30). One process at a time: libtpu holds a lock. Nothing
-here is a measurement."""
+(PERF.md §6, PR 30). The instruction names in the compiled text are the
+ledger's (``breakdown.device_ops``: ``rope.20``, ``fusion.372`` ...), so a
+dump also says what an operation the ledger names IS (PR 34). One process
+at a time: libtpu holds a lock. Nothing here is a measurement."""
 
 from __future__ import annotations
 

@@ -21,7 +21,11 @@ compares fwd + grads against the xla reference ops:
     chain-degenerate} x {float, int8} x {full, window}
   - blockwise paged-flash prefill (chunk queries over the paged history,
     chunk pages written in-kernel): {float, int8} x {full, window}
-  - fused RMSNorm, fused RoPE
+  - fused RMSNorm, fused RoPE; and the rotation a decode program runs
+    (``--only rope`` runs these alone): one new token a slot is under
+    ``ops.rope.KERNEL_MIN_SEQ`` and so the XLA form, held here to the kernel
+    on the same rows at ``[32, 1, 40 | 72 | 8, 128]``, positions to 12287,
+    the plain, the half-rotated and the YaRN table
   - power retention (``--only retention`` runs these alone): the chunked
     prefill kernel (outputs and the state it hands out, ragged lengths),
     the decode kernel over a state row and a paged tail (tail only, state
@@ -823,6 +827,42 @@ def norm_rope_checks() -> None:
         check(f"rope N={n_heads} dx", gp, gx, 4e-2)
 
 
+def rope_decode_checks() -> None:
+    """The rotation a decode program runs (one new token a slot, under
+    ``ops.rope.KERNEL_MIN_SEQ``: the XLA form, compiled) against the kernel
+    on the same rows, at the serving cells' head counts and tables, with
+    positions up to the longest sequence a cell holds."""
+    from orion_tpu.config import RopeConfig, get_config
+    from orion_tpu.ops.rope import KERNEL_MIN_SEQ, apply_rope, rope_table
+
+    impl = "pallas_interpret" if INTERP else "pallas"
+    B, H, last = 32, 128, 12287
+    pos = jnp.concatenate([
+        jnp.asarray([last, last - 1, 0, 1], jnp.int32),
+        jax.random.randint(jax.random.key(5), (B - 4,), 0, last + 1)])
+    tables = {
+        "plain 1e6": (1e6, None),
+        "half-rotated": (1e4, RopeConfig(theta=1e4, rotary_fraction=0.5)),
+        "yarn": (5e5, get_config("laguna-s-2.1").model.rope_full),
+    }
+    for S in sorted({1, KERNEL_MIN_SEQ - 1}):
+        p2 = jnp.minimum(pos[:, None] + jnp.arange(S)[None, :], last)
+        for N in (40, 72, 8):
+            x = jax.random.normal(
+                jax.random.key(N), (B, S, N, H), jnp.bfloat16)
+            for tag, (theta, rope) in tables.items():
+                table = None if rope is None else rope_table(H, rope)
+                check(
+                    f"rope decode [{B},{S},{N},{H}] {tag}: xla form vs kernel",
+                    jax.jit(lambda x: apply_rope(
+                        x, p2, theta=theta, rope=rope, impl=impl))(x),
+                    jax.jit(lambda x: rope_pallas(
+                        x, p2, theta=theta, table=table,
+                        interpret=INTERP))(x),
+                    1e-2,
+                )
+
+
 def retention_checks() -> None:
     from benchmarks.reference.brumby import _retention as quadratic
     from orion_tpu.ops import retention as ret
@@ -991,12 +1031,14 @@ def main() -> int:
     print(f"platform={dev.platform} device_kind={dev.device_kind!r} "
           f"devices={len(jax.devices())} interpret={INTERP}", flush=True)
 
-    if "retention" in sys.argv[1:]:     # --only retention
-        guarded("retention", retention_checks)
-        green = sum(ok for _, ok in RESULTS)
-        print(f"{'ALL-OK' if green == len(RESULTS) else 'SOME-FAIL'} "
-              f"{green} of {len(RESULTS)} checks [retention only]")
-        return 0 if green == len(RESULTS) else 1
+    for name, group in (("retention", retention_checks),
+                        ("rope", rope_decode_checks)):
+        if name in sys.argv[1:]:        # --only <name>
+            guarded(name, group)
+            green = sum(ok for _, ok in RESULTS)
+            print(f"{'ALL-OK' if green == len(RESULTS) else 'SOME-FAIL'} "
+                  f"{green} of {len(RESULTS)} checks [{name} only]")
+            return 0 if green == len(RESULTS) else 1
     guarded("retention", retention_checks)
     guarded("flash", flash_checks)
     if not INTERP:      # the cells' sizes: minutes under the interpreter
@@ -1010,6 +1052,7 @@ def main() -> int:
         guarded(f"paged{g.tag}", paged_checks, g)
         guarded(f"ragged{g.tag}", ragged_paged_checks, g)
     guarded("norm/rope", norm_rope_checks)
+    guarded("rope decode", rope_decode_checks)
 
     green = sum(ok for _, ok in RESULTS)
     verdict = "ALL-OK" if green == len(RESULTS) else "SOME-FAIL"
