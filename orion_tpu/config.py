@@ -156,6 +156,22 @@ class ModelConfig:
     # RMSNorm over each query and key head before the rotary embedding
     # (attn.q_norm / attn.k_norm [head_dim]; Qwen3-family).
     qk_norm: bool = False
+    # Latent attention (kv_lora_rank set; the five sizes go together): a
+    # layer projects its input down to ONE row a position, ``kv_lora_rank``
+    # numbers (normed) and a rotary key of ``qk_rope_head_dim`` shared by
+    # all heads, and queries through a ``q_lora_rank`` bottleneck (normed)
+    # up to n_heads x (qk_nope_head_dim | qk_rope_head_dim). The EXPANDED
+    # form rebuilds per-head keys (nope | the shared rotary key) and values
+    # (``v_head_dim``) from the row (training, prefill); the ABSORBED form
+    # carries the query into the latent space and attends over the rows
+    # themselves (decode). Serving pages hold the row and nothing else
+    # (infer/kv_cache.latent_leaf). head_dim is qk_nope + qk_rope, and
+    # n_kv_heads = n_heads (the expanded form's).
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
     # Gemma-family block/embedding details:
     post_norms: bool = False          # extra norms AFTER attention and MLP
     norm_scale_plus_one: bool = False  # rmsnorm multiplies by (1 + w)
@@ -188,6 +204,13 @@ class ModelConfig:
     # The renormalised top-k gates are multiplied by it
     # (moe_routed_scaling_factor).
     router_scale: float = 1.0
+    # How the router scores experts: "softmax" over all of them, or
+    # "sigmoid" of each logit on its own (float32). With router_bias the
+    # top-k is chosen on score + moe.router_bias [E] (a selection bias, a
+    # parameter of the layer) while the gates stay the scores WITHOUT it,
+    # renormalised over the chosen (+ 1e-20) and scaled.
+    router_score: str = "softmax"
+    router_bias: bool = False
     # An expert layer that holds a SHARE of the experts and routes over all
     # of them: router_width is the router's outputs (None => n_experts, the
     # whole layer is here), n_experts stays the number of expert matrices
@@ -315,6 +338,9 @@ class ModelConfig:
             raise ValueError(
                 f"model.attention={self.attention!r}; "
                 f"softmax|power_retention")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"model.router_score={self.router_score!r}; softmax|sigmoid")
         if self.scan_group is None or self.scan_group < 1:
             raise ValueError(f"model.scan_group={self.scan_group} must be >= 1")
         if self.scan_unroll is None or self.scan_unroll < 1:
@@ -333,6 +359,22 @@ class ModelConfig:
     @property
     def is_retention(self) -> bool:
         return self.attention == "power_retention"
+
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank is not None
+
+    @property
+    def latent_row_width(self) -> int:
+        """Numbers a cached position holds in a latent layer: the
+        compressed row and the shared rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def n_shared_experts(self) -> int:
+        """The shared expert's width in routed experts' widths (how the
+        DeepSeek-family key set states it)."""
+        return self.shared_expert_d_ff // self.resolved_moe_d_ff
 
     @property
     def resolved_router_width(self) -> int:
@@ -2086,6 +2128,68 @@ def _p_tiny_brumby() -> Config:
         data=DataConfig(batch_size=4, seq_len=64),
         inference=InferenceConfig(max_seq_len=128, page_size=4,
                                   num_pages=160, max_batch_size=4,
+                                  prefill_chunk=16, decode_window=4),
+    )
+
+
+def _glm_flash_model(**kw) -> ModelConfig:
+    """GLM-4.7-Flash (zai-org, config.json, model_type glm4_moe_lite):
+    latent attention (q through 768, one row of 512 + 64 a position, 20
+    heads of 192 | 64 and values of 256), one leading dense layer, then 64
+    experts 1536 wide, top-4 of sigmoid scores under a selection bias,
+    gates renormalised and scaled by 1.8, and a shared expert. The
+    multi-token-prediction module is no part of the served logits and is
+    left out."""
+    base = dict(
+        name="glm-4.7-flash", vocab_size=154_880, max_seq_len=202_752,
+        d_model=2048, n_layers=47, n_heads=20, n_kv_heads=20, head_dim=256,
+        d_ff=10_240, pos_embedding="rope", rope_theta=1_000_000.0,
+        norm="rmsnorm", norm_eps=1e-5, activation="swiglu",
+        tie_embeddings=False,
+        q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256,
+        n_experts=64, n_experts_per_token=4, n_dense_layers=1,
+        moe_d_ff=1536, shared_expert_d_ff=1536, router_scale=1.8,
+        router_score="sigmoid", router_bias=True,
+        # Dropless: an expert's bucket holds every row of a decode block
+        # from n_experts / top-k = 16 up (the published model drops none).
+        capacity_factor=16.0,
+        dtype="bfloat16", param_dtype="bfloat16", kernels="pallas",
+        remat="full",
+    )
+    base.update(kw)
+    return ModelConfig(**base)
+
+
+@register_preset("glm-4.7-flash")
+def _p_glm_flash() -> Config:
+    """GLM-4.7-Flash at its published sizes, for serving (a deployment
+    holds the depth its chip holds)."""
+    return Config(
+        model=_glm_flash_model(),
+        inference=InferenceConfig(max_seq_len=21_504, page_size=64),
+    )
+
+
+@register_preset("tiny-glm")
+def _p_tiny_glm() -> Config:
+    """Tiny GLM-Flash-family model for CPU tests, every ratio that matters
+    kept unlike: nope 24 != rope 8, values 16 != the row's 48, queries
+    through 40; a leading dense layer, 8 experts top-2 with a shared one,
+    sigmoid scores under a bias."""
+    return Config(
+        model=_glm_flash_model(
+            name="tiny-glm", vocab_size=256, max_seq_len=128, d_model=64,
+            n_layers=3, n_heads=4, n_kv_heads=4, head_dim=32, d_ff=128,
+            q_lora_rank=40, kv_lora_rank=48, qk_nope_head_dim=24,
+            qk_rope_head_dim=8, v_head_dim=16,
+            n_experts=8, n_experts_per_token=2, moe_d_ff=32,
+            shared_expert_d_ff=32, capacity_factor=4.0,
+            dtype="float32", param_dtype="float32", kernels="xla",
+            remat="none"),
+        data=DataConfig(batch_size=4, seq_len=64),
+        inference=InferenceConfig(max_seq_len=128, page_size=8,
+                                  num_pages=128, max_batch_size=4,
                                   prefill_chunk=16, decode_window=4),
     )
 

@@ -151,20 +151,31 @@ class InferenceEngine:
                 ("inference.chunked_prefill", self.icfg.chunked_prefill),
                 ("model.weight_quant", self.mcfg.weight_quant),
             ]
+        # What reads K and V of heads out of pages, which a cache of
+        # another kind is not served with.
+        kv_only = [
+            ("inference.prefix_cache", self.icfg.prefix_cache),
+            ("inference.host_tier_bytes", self.icfg.host_tier_bytes),
+            ("inference.long_context", self.icfg.long_context),
+            ("inference.speculative", self.icfg.speculative),
+            ("inference.constrained", self.icfg.constrained),
+            ("inference.chunked_prefill", self.icfg.chunked_prefill),
+            ("inference.kv_quant", self.icfg.kv_quant),
+            ("model.weight_quant", self.mcfg.weight_quant),
+        ]
         if self.mcfg.is_retention:
             why.append(
                 "keeps a fixed-size state a request "
                 "(model.attention=power_retention)")
-            refused += [
-                ("inference.prefix_cache", self.icfg.prefix_cache),
-                ("inference.host_tier_bytes", self.icfg.host_tier_bytes),
-                ("inference.long_context", self.icfg.long_context),
-                ("inference.speculative", self.icfg.speculative),
-                ("inference.constrained", self.icfg.constrained),
-                ("inference.chunked_prefill", self.icfg.chunked_prefill),
-                ("inference.kv_quant", self.icfg.kv_quant),
-                ("model.weight_quant", self.mcfg.weight_quant),
-            ]
+            refused += kv_only
+        if self.mcfg.is_latent:
+            # A latent cache holds one compressed row a position, which the
+            # prefix gather, the chunk rows, the host tier's paging, the
+            # int8 pools and the verify kernel do not read yet.
+            why.append(
+                "caches one compressed row a position "
+                "(model.kv_lora_rank)")
+            refused += kv_only
         off = list(dict.fromkeys(name for name, on in refused if on))
         if off:
             raise ValueError(
@@ -235,6 +246,10 @@ class InferenceEngine:
             _detect_tp_mesh(self.params)
             if resolve_impl(self.mcfg.kernels)[0] else None
         )
+        if self.mesh is not None and self.mcfg.is_latent:
+            raise ValueError(
+                "a latent-attention model is served on one device: its "
+                "decode kernel is not run per shard yet")
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -572,11 +587,14 @@ class InferenceEngine:
                 kv_quant=self.icfg.kv_quant,
                 dtype_itemsize=jnp.dtype(self.mcfg.dtype).itemsize,
             )
-        if self.icfg.paged_prefill and resolve_impl(self.mcfg.kernels)[0]:
+        if (self.icfg.paged_prefill and not self.mcfg.is_latent
+                and resolve_impl(self.mcfg.kernels)[0]):
             # Same init-time VMEM gate for the paged-flash prefill
             # kernel: its blocks are page-sized (one page of queries x
             # the GQA group), so the failure mode is a too-large
             # page_size, named here instead of a Mosaic OOM mid-chunk.
+            # (A latent model prefills whole prompts: no row of it ever
+            # reaches that kernel.)
             from orion_tpu.ops.pallas.paged_flash_prefill import (
                 check_prefill_fit,
             )
@@ -1403,6 +1421,19 @@ class InferenceEngine:
             "decode_state_empty_slot_layers": 0,
             "decode_tail_token_layers": 0,
             "prefill_retention_units": 0,
+            # A latent-attention model (all 0 for a K/V model, whose
+            # decode_kv_* are 0 for this one): per token step, the live
+            # slots' cached positions summed over the layers, each one
+            # latent row the decode kernel reads
+            # (decode_latent_token_layers); S (S + 1) / 2 a real prompt and
+            # layer, the query-key pairs the expanded causal attention of a
+            # prefill computes (prefill_attn_pairs); and, summed at each
+            # decode window, the pool bytes the live slots' pages hold over
+            # all layers and their cached tokens (latent_live_page_bytes /
+            # latent_live_tokens: bytes a token and layer is their ratio
+            # over the layers). Host arithmetic on lengths.
+            "decode_latent_token_layers": 0, "prefill_attn_pairs": 0,
+            "latent_live_page_bytes": 0, "latent_live_tokens": 0,
             # Prefill sizing: prefill_tokens counts the real prompt
             # positions the prefill dispatches computed (prefix-cached
             # positions excluded), prefill_pad_tokens the rest of each
@@ -2552,6 +2583,11 @@ class InferenceEngine:
                 f"model {self.mcfg.name!r} keeps a state row a request "
                 f"(model.attention=power_retention), which migration does "
                 f"not ship yet")
+        if self.mcfg.is_latent:
+            raise ValueError(
+                f"model {self.mcfg.name!r} caches one compressed row a "
+                f"position (model.kv_lora_rank), which migration has not "
+                f"been run on yet")
         slot = req.slot
         return {
             "prompt": list(req.prompt),
@@ -3151,6 +3187,9 @@ class InferenceEngine:
                 self.timing["prefill_retention_units"] += (
                     self.mcfg.n_layers
                     * query_units(n, self.mcfg.resolved_head_dim))
+        if self.mcfg.is_latent:
+            self.timing["prefill_attn_pairs"] += self.mcfg.n_layers * sum(
+                int(n) * (int(n) + 1) // 2 for n in lengths[: len(reqs)])
         if self.mcfg.is_moe:
             # Pad rows have length 1, so one position of each routes too.
             self.timing["prefill_expert_rows"] += expert_rows(
@@ -3964,6 +4003,19 @@ class InferenceEngine:
                 L * W * int((folded == 0).sum()))
             self.timing["decode_tail_token_layers"] += L * int(
                 (lens - folded).sum() * W + len(active) * (W * (W + 1) // 2))
+            return active, W, common
+        if self.mcfg.is_latent:
+            # The rows the latent decode kernel reads, and what the pool
+            # holds for the live slots at this window: whole pages (the
+            # ones provisioned for the window ahead among them) of the
+            # leaf's own row width, padding included.
+            L = self.mcfg.n_layers
+            self.timing["decode_latent_token_layers"] += L * kv
+            held = sum(p is not None for r in active for p in r.pages)
+            self.timing["latent_live_page_bytes"] += (
+                held * host_page_bytes(self.cache, L))
+            self.timing["latent_live_tokens"] += int(
+                self.seq_lens[mask].sum())
             return active, W, common
         self.timing["decode_kv_tokens"] += kv
         self._count_kv_by_layer_kind(self.seq_lens[mask].astype(np.int64), W)

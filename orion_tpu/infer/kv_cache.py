@@ -70,6 +70,43 @@ def scale_width(psz: int) -> int:
 from orion_tpu.ops.pallas.common import quantize_kv  # noqa: F401,E402
 
 
+# The one paged leaf of a latent-attention model (``latent_leaf``).
+LATENT = "latent"
+
+
+def paged_leaf(cache: "Cache") -> jax.Array:
+    """The leaf that says how a cache's pages are laid out ([layers x
+    pages, heads, page, width]): ``k`` of a K/V cache, the one row leaf of
+    a latent cache. Every place that needs a pool's page size or page count
+    reads it here, so a backend adds a leaf and not a branch at each."""
+    return cache["k"] if "k" in cache else cache[LATENT]
+
+
+def page_geometry(cache: "Cache", n_layers: int) -> tuple[int, int]:
+    """(page size, pages a layer) of a cache's pool."""
+    leaf = paged_leaf(cache)
+    return leaf.shape[2], leaf.shape[0] // n_layers
+
+
+def latent_width(mcfg: ModelConfig) -> int:
+    """Columns a latent pool's row holds: ``kv_lora_rank +
+    qk_rope_head_dim`` padded with zeros to whole lane tiles (512 + 64 ->
+    640), so that a page is [page, 5 x 128] to the decode kernel's copies
+    and products. A device lays a 576-wide (or a 64-wide) minor dimension
+    out in whole 128-lane tiles in any case: the padding costs no memory a
+    split into a 512 leaf and a 64 leaf would save."""
+    return -(-mcfg.latent_row_width // 128) * 128
+
+
+def latent_leaf(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> "Cache":
+    """A latent-attention model's whole cache: ONE row a token and layer
+    (the normed compressed row | the shared rotary key | zeros), in the
+    paged layout with one "head", [layers x pages, 1, page, width]."""
+    return {LATENT: jnp.zeros(
+        (mcfg.n_layers * icfg.num_pages, 1, icfg.page_size,
+         latent_width(mcfg)), dtype)}
+
+
 def init_cache(
     mcfg: ModelConfig,
     icfg: InferenceConfig,
@@ -99,6 +136,8 @@ def init_cache(
         if icfg.kv_quant is not None:
             raise ValueError(f"unknown inference.kv_quant={icfg.kv_quant!r}")
         dtype = jnp.dtype(mcfg.dtype)
+        if mcfg.is_latent:
+            return latent_leaf(mcfg, icfg, dtype)
         cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         if mcfg.is_retention:
             cache.update(retention_leaves(mcfg, icfg, dtype))
@@ -268,7 +307,7 @@ def compact_draft_kv(
     byte-identity argument runs through this function.
     """
     B, W = src.shape
-    psz = cache["k"].shape[2]
+    psz = paged_leaf(cache).shape[2]
     P = page_table.shape[1]
     max_pos = P * psz - 1
     bidx = jnp.arange(B, dtype=jnp.int32)[:, None]
@@ -291,7 +330,7 @@ def compact_draft_kv(
 
 
 def poison_page(cache: Cache, page, *, n_layers: int, num_pages: int) -> Cache:
-    """Overwrite one pool page's K rows (all layers) with NaN — the fault
+    """Overwrite one pool page's K rows (a latent pool's rows; all layers) with NaN — the fault
     INJECTION primitive behind the NaN-quarantine tests (runtime/fault.py
     FaultSpec kind="nan"): real NaNs flow through the real attention into
     exactly one slot's logits, because no other slot ever reads this
@@ -299,7 +338,8 @@ def poison_page(cache: Cache, page, *, n_layers: int, num_pages: int) -> Cache:
     f32 ``k_scale`` rows are poisoned instead (dequantized K goes NaN, same
     blast radius). ``page`` may be a traced scalar."""
     layer_rows = jnp.arange(n_layers, dtype=jnp.int32) * num_pages + page
-    target = "k_scale" if "k_scale" in cache else "k"
+    target = ("k_scale" if "k_scale" in cache
+              else "k" if "k" in cache else LATENT)
     out = dict(cache)
     arr = out[target]
     out[target] = arr.at[layer_rows].set(jnp.asarray(jnp.nan, arr.dtype))

@@ -44,11 +44,15 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.config import ModelConfig
+from orion_tpu.infer.kv_cache import LATENT, page_geometry
 from orion_tpu.models import moe as moe_lib
 from orion_tpu.models.transformer import (
     Params,
     block,
     embed,
+    latent_absorb,
+    latent_attention,
+    latent_unabsorb,
     scan_layer_plan,
     unembed,
 )
@@ -146,8 +150,7 @@ def _prefill_ctx(
     from orion_tpu.ops._dispatch import resolve_impl
 
     Nb, S_pad = tokens.shape
-    psz = cache["k"].shape[2]
-    NP = cache["k"].shape[0] // cfg.n_layers
+    psz, NP = page_geometry(cache, cfg.n_layers)
     P_pre = 0 if prefix_pages is None else prefix_pages.shape[1]
     use_pallas, interpret = resolve_impl(cfg.kernels)
     paged = bool(paged_prefill and P_pre and use_pallas and S_pad % psz == 0)
@@ -406,14 +409,19 @@ def prefill_step(
                 "prefix would need its state, which nothing snapshots")
         return _retained_prefill(
             params, cache, tokens, lengths, pages, state_rows, cfg, mesh)
+    if cfg.is_latent and prefix_pages is not None and prefix_pages.shape[1]:
+        raise ValueError(
+            "a latent-attention model prefills whole prompts: a cached "
+            "prefix would have to be expanded again, which no path does")
     ctx = _prefill_ctx(
         params, cache, tokens, lengths, pages, prefix_lens, prefix_pages,
         cfg, paged_prefill=paged_prefill,
     )
+    layer = _latent_prefill_layer if cfg.is_latent else _dense_layer
 
     def body(carry, bp, l, j, stack=None):
         x, cc = carry
-        return _dense_layer(x, cc, bp, l, j, ctx, cfg, mesh, stack)
+        return layer(x, cc, bp, l, j, ctx, cfg, mesh, stack)
 
     x = embed(params, tokens, ctx["positions"], cfg)
     cache = dict(cache)
@@ -440,10 +448,14 @@ def _decode_core(
     """One decode forward for every slot -> (logits [B, V], cache'): the
     paged backend at W = 1 (the retained backend for a power-retention
     model: it reads a slot's state row and never writes it, so the body can
-    be run again on the cache it handed back)."""
+    be run again on the cache it handed back; the latent backend for a
+    latent-attention model)."""
     if cfg.is_retention:
         ctx, layer = _retained_ctx(
             cache, write_pos, page_table, cfg), _retained_layer
+    elif cfg.is_latent:
+        ctx, layer = _latent_ctx(
+            cache, write_pos, page_table, cfg), _latent_layer
     else:
         ctx, layer = _one_token_ctx(
             cache, write_pos, page_table, cfg), _paged_layer
@@ -562,9 +574,7 @@ def _paged_ctx(
     verify; with both None this function is untouched (same trace).
     """
     B = seq_lens.shape[0]
-    kp = cache["k"]
-    psz = kp.shape[2]
-    NP = kp.shape[0] // cfg.n_layers
+    psz, NP = page_geometry(cache, cfg.n_layers)
     P = page_table.shape[1]
     batch_idx = jnp.arange(B)[:, None]
     steps = jnp.arange(W, dtype=jnp.int32)[None, :]
@@ -660,7 +670,8 @@ def _one_token_ctx(
     return _paged_ctx(
         cache, write_pos, jnp.ones_like(write_pos), page_table,
         jnp.ones(write_pos.shape, bool), 1,
-        page_table.shape[1] * cache["k"].shape[2], cfg, name="paged_decode")
+        page_table.shape[1] * page_geometry(cache, cfg.n_layers)[0], cfg,
+        name="paged_decode")
 
 
 def _paged_layer(
@@ -1037,6 +1048,112 @@ def mixed_verify_step(
     return (*verdicts, p_logits, cache)
 
 
+# -- the latent backend: one compressed row a token and layer ------------------
+#
+# A latent-attention model (model.kv_lora_rank; transformer.latent_proj)
+# caches of a position ONE row a layer, ``[c_kv | k_pe | zeros]``
+# (kv_cache.latent_leaf). Prefill attends in the EXPANDED form (K and V of
+# every head rebuilt from the rows, the flash kernel) and writes whole pages
+# of rows; the decode window attends in the ABSORBED form over the pages
+# themselves, one shared "head" for all query heads, which is what makes the
+# small cache pay: a step reads a cached row once.
+
+
+def _latent_prefill_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
+                          cfg: ModelConfig, mesh, stack=None
+                          ) -> tuple[jax.Array, Cache]:
+    """One layer of whole-prompt prefill: expanded attention over the
+    block's own rows, the rows into ``ctx['pages']`` (behind the
+    feed-forward, as the dense backend's scatter is)."""
+    psz, NP, seg = ctx["psz"], ctx["NP"], ctx["seg"]
+    pool = cc[LATENT]
+
+    def attend(q, row, wkv_b):
+        out = latent_attention(
+            q, row, wkv_b, cfg, q_segment_ids=seg, kv_segment_ids=seg,
+            seg_pad_zero=True, block_q=cfg.attn_block_q,
+            block_kv=cfg.attn_block_kv, impl=cfg.kernels, mesh=mesh)
+
+        def written():
+            Nb, S, w = row.shape
+            pages = jnp.pad(row, ((0, 0), (0, 0), (0, pool.shape[-1] - w)))
+            return {LATENT: pool.at[l * NP + ctx["pages"]].set(
+                pages.reshape(Nb, S // psz, 1, psz, -1).astype(pool.dtype))}
+
+        return out, written
+
+    valid = seg > 0
+    x, _, written = block(
+        x, bp, cfg, ctx["positions"], attend, kind=_kind(cfg, j), mesh=mesh,
+        ffn_mesh=mesh, valid=valid, layer_stack=stack)
+    return x, {**cc, **written()}
+
+
+def _latent_ctx(cache: Cache, pos: jax.Array, page_table: jax.Array,
+                cfg: ModelConfig) -> dict:
+    """Batch-level tensors of ``_latent_layer``: one new token a slot at
+    position ``pos`` [B] (the caller keeps it inside the page table's
+    reach, as for ``_one_token_ctx``)."""
+    from orion_tpu.ops._dispatch import resolve_impl
+
+    psz, NP = page_geometry(cache, cfg.n_layers)
+    P = page_table.shape[1]
+    at = jnp.minimum(pos, P * psz - 1)
+    use_pallas, interpret = resolve_impl(cfg.kernels)
+    return dict(
+        psz=psz, NP=NP, at=at, positions=at[:, None], page_table=page_table,
+        page=page_table[jnp.arange(pos.shape[0]), at // psz], offset=at % psz,
+        live=jnp.arange(P * psz, dtype=jnp.int32)[None, :] <= at[:, None],
+        use_pallas=use_pallas, interpret=interpret,
+    )
+
+
+def _latent_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
+                  cfg: ModelConfig, mesh) -> tuple[jax.Array, Cache]:
+    """The latent backend: one layer of one new token a slot. Its row lands
+    in its page (inside the kernel on the pallas path) and its queries,
+    absorbed into the rows' space, attend the slot's pages; the output
+    comes back through W_uv. XLA branch: scatter + masked padded-context
+    gather, the reference."""
+    NP, page_table = ctx["NP"], ctx["page_table"]
+    R, scale = cfg.kv_lora_rank, cfg.resolved_head_dim ** -0.5
+    pool = cc[LATENT]
+
+    def attend(q, row, wkv_b):
+        pad = pool.shape[-1] - row.shape[-1]
+        q_lat = jnp.pad(latent_absorb(q, wkv_b, cfg)[:, 0],
+                        ((0, 0), (0, 0), (0, pad)))           # [B, N, Wd]
+        new = jnp.pad(row[:, 0], ((0, 0), (0, pad))).astype(pool.dtype)
+        if ctx["use_pallas"]:
+            if mesh is not None:
+                raise ValueError(
+                    "the latent decode kernel runs on one device")
+            from orion_tpu.ops.pallas.latent_paged_attention import (
+                latent_paged_attention,
+            )
+
+            o_lat, written = latent_paged_attention(
+                q_lat, pool, page_table, ctx["at"], new,
+                layer_base=l * NP, value_width=R, scale=scale,
+                interpret=ctx["interpret"])
+        else:
+            written = pool.at[l * NP + ctx["page"], 0, ctx["offset"]].set(new)
+            rows = written[l * NP + page_table][:, :, 0]      # [B, P, psz, Wd]
+            rows = rows.reshape(rows.shape[0], -1, rows.shape[-1])
+            z = jnp.einsum("bnw,btw->bnt", q_lat, rows,
+                           preferred_element_type=jnp.float32) * scale
+            z = jnp.where(ctx["live"][:, None, :], z, -jnp.inf)
+            o_lat = jnp.einsum(
+                "bnt,btr->bnr", jax.nn.softmax(z, axis=-1).astype(q.dtype),
+                rows[..., :R])
+        out = latent_unabsorb(o_lat[:, None].astype(q.dtype), wkv_b, cfg)
+        return out, {**cc, LATENT: written}
+
+    x, _, cc = block(x, bp, cfg, ctx["positions"], attend,
+                     kind=_kind(cfg, j), mesh=mesh)
+    return x, cc
+
+
 # -- the retained backend: a fixed-size state row a slot beside a paged tail --
 #
 # A power-retention model (model.attention, ops/retention.py) keeps of a
@@ -1063,8 +1180,7 @@ def _retained_prefill(params, cache, tokens, lengths, pages, state_rows,
     from orion_tpu.ops.retention import chunk_cumsum, power_retention
 
     Nb, S_pad = tokens.shape
-    psz = cache["k"].shape[2]
-    NP = cache["k"].shape[0] // cfg.n_layers
+    psz, NP = page_geometry(cache, cfg.n_layers)
     n_rows = cache["state_len"].shape[0]
     C = _chunk(cfg)
     if state_rows is None:
@@ -1125,8 +1241,7 @@ def _retained_ctx(cache: Cache, pos: jax.Array, page_table: jax.Array,
     from orion_tpu.ops._dispatch import resolve_impl
     from orion_tpu.ops.retention import tail_pages
 
-    psz = cache["k"].shape[2]
-    NP = cache["k"].shape[0] // cfg.n_layers
+    psz, NP = page_geometry(cache, cfg.n_layers)
     P = page_table.shape[1]
     C = _chunk(cfg)
     F = cache["state_len"][1:]                                 # [B]
@@ -1212,8 +1327,7 @@ def fold_step(cache: Cache, slot: jax.Array, page_row: jax.Array, *,
     from orion_tpu.ops.retention import retention_fold_xla
 
     del mesh
-    psz = cache["k"].shape[2]
-    NP = cache["k"].shape[0] // cfg.n_layers
+    psz, NP = page_geometry(cache, cfg.n_layers)
     n_rows = cache["state_len"].shape[0]
     C, P = _chunk(cfg), page_row.shape[0]
     F = cache["state_len"][slot + 1]

@@ -33,6 +33,20 @@ from orion_tpu.config import ModelConfig
 Params = dict[str, Any]
 
 
+def _has_importer(cfg: ModelConfig) -> None:
+    """A latent-attention model's projections (``wq_a``, ``q_a_norm``,
+    ``wq_b``, ``wkv_a``, ``kv_a_norm``, ``wkv_b``; a router's selection
+    bias) have no slot in any schema here: say so, where a converter would
+    otherwise fail on the first key it misses (ROADMAP R11)."""
+    if cfg.is_latent:
+        raise ValueError(
+            f"model {cfg.name!r} has latent-attention projections "
+            f"(model.kv_lora_rank): no converter here reads or writes that "
+            f"key set (self_attn.q_a_proj / kv_a_proj_with_mqa / kv_b_proj, "
+            f"mlp.gate.e_score_correction_bias); it is served from seeded "
+            f"weights only (ROADMAP R11)")
+
+
 def _stack(cfg: ModelConfig, blocks: list[Params]) -> Any:
     if not cfg.scan_layers:
         return blocks
@@ -106,6 +120,7 @@ def to_hf_llama(
     export arrives as ml_dtypes.bfloat16 numpy arrays; view-cast for
     torch: ``torch.from_numpy(v.view(np.uint16)).view(torch.bfloat16)``).
     """
+    _has_importer(cfg)
     unexportable = []
     if cfg.attn_bias or cfg.mlp_bias:
         unexportable.append("attention/mlp biases")
@@ -168,6 +183,7 @@ def to_hf_llama(
 
 def from_hf_llama(sd: Mapping[str, np.ndarray], cfg: ModelConfig) -> Params:
     """Llama/Llama-2/Llama-3-family ``LlamaForCausalLM`` state dict."""
+    _has_importer(cfg)
     L = cfg.n_layers
 
     def t(name):  # torch Linear [out, in] -> [in, out]
@@ -208,6 +224,7 @@ def from_hf_qwen2(sd: Mapping[str, np.ndarray], cfg: ModelConfig) -> Params:
     The Llama schema plus q/k/v projection biases (and no o bias) —
     cfg should set ``attn_bias=True, attn_out_bias=False``.
     """
+    _has_importer(cfg)
     if not cfg.attn_bias or cfg.resolved_attn_out_bias:
         raise ValueError(
             "Qwen2-family configs need attn_bias=True, attn_out_bias=False "
@@ -245,6 +262,7 @@ def from_hf_gemma2(sd: Mapping[str, np.ndarray], cfg: ModelConfig) -> Params:
     embed_scale=True, activation='geglu', tie_embeddings=True,
     sliding_window_pattern=2 (+ the softcaps and query_scale).
     """
+    _has_importer(cfg)
     need = dict(post_norms=True, norm_scale_plus_one=True,
                 embed_scale=True, tie_embeddings=True)
     bad = {k: getattr(cfg, k) for k, v in need.items()
@@ -313,6 +331,7 @@ def from_hf_gemma2(sd: Mapping[str, np.ndarray], cfg: ModelConfig) -> Params:
 
 def from_hf_gpt2(sd: Mapping[str, np.ndarray], cfg: ModelConfig) -> Params:
     """GPT-2 ``GPT2LMHeadModel`` state dict (Conv1D stores [in, out])."""
+    _has_importer(cfg)
     D = cfg.d_model
     sd = {k.removeprefix("transformer."): v for k, v in sd.items()}
 
@@ -370,6 +389,7 @@ def from_hf_mixtral(sd: Mapping[str, np.ndarray], cfg: ModelConfig) -> Params:
     HF's is dropless; they agree when ``capacity_factor`` admits every
     routed token (tests pin that regime).
     """
+    _has_importer(cfg)
     E = cfg.n_experts
 
     def t(name):

@@ -76,10 +76,14 @@ def _held(idx: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
 
 
 def _router_topk(
-    x: jax.Array, router_w: jax.Array, cfg: ModelConfig
+    x: jax.Array, router_w: jax.Array, cfg: ModelConfig,
+    bias: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Shared router head: (probs [B,S,E] f32, gate [B,S,k] f32 renormalized,
-    idx [B,S,k] int32).
+    idx [B,S,k] int32). ONE function with two scorings
+    (``model.router_score``): a softmax over the experts, or each logit's
+    sigmoid with the top-k chosen on score + ``bias`` [E] (``moe.router_bias``,
+    under ``model.router_bias``) and the gates the scores WITHOUT it.
 
     Top-k is argsort + a one-hot product rather than ``lax.top_k`` +
     gather: identical values/indices (verified in tests), negligible cost
@@ -91,19 +95,29 @@ def _router_topk(
     logits = jnp.einsum(
         "bsd,de->bse", x, router_w, preferred_element_type=jnp.float32
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    idx = jnp.argsort(-probs, axis=-1)[
+    if cfg.router_score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        chosen_on = probs if bias is None else probs + bias.astype(probs.dtype)
+    else:
+        chosen_on = probs = jax.nn.softmax(logits, axis=-1)
+    idx = jnp.argsort(-chosen_on, axis=-1)[
         ..., : cfg.n_experts_per_token
     ].astype(jnp.int32)
     onehot = jax.nn.one_hot(idx, E, dtype=probs.dtype)   # [B,S,k,E]
     gate = (probs[..., None, :] * onehot).sum(-1)        # scatter-free gather
-    gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)  # renormalize
+    if cfg.router_score == "sigmoid":
+        gate = gate / (gate.sum(-1, keepdims=True) + 1e-20)
+    else:
+        gate = gate / jnp.clip(gate.sum(-1, keepdims=True), 1e-9)  # renormalize
     if cfg.router_scale != 1.0:
         gate = gate * cfg.router_scale
     # remat="names" (models/transformer.REMAT_SAVE_NAMES) saves the gates:
     # [B,S,k] f32 is near-free to store and pins the softmax/argsort chain
     # every dispatch mode's backward needs. No-op under other policies.
     gate = checkpoint_name(gate, "moe_router_gate")
+    if cfg.router_score == "sigmoid":
+        # What the load-balance statistics read: the scores as shares.
+        probs = probs / probs.sum(-1, keepdims=True)
     return probs, gate, idx
 
 
@@ -129,14 +143,15 @@ def _aux_loss(probs: jax.Array, idx: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 def route(
-    x: jax.Array, router_w: jax.Array, cfg: ModelConfig
+    x: jax.Array, router_w: jax.Array, cfg: ModelConfig,
+    bias: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Router: returns (dispatch [B,S,E,C], combine [B,S,E,C], aux_loss)."""
     B, S, _ = x.shape
     E, k = cfg.n_experts, cfg.n_experts_per_token
     C = moe_capacity(cfg, S)
 
-    probs, gate, idx = _router_topk(x, router_w, cfg)
+    probs, gate, idx = _router_topk(x, router_w, cfg, bias)
 
     # Slot-major priority: all slot-0 (top-1) choices claim capacity before
     # any slot-1 choice, matching Switch-Transformer semantics.
@@ -166,7 +181,8 @@ def moe_mlp(
     einsum operands) on the ``ep`` mesh axis.
     """
     dtype = x.dtype
-    disp, comb, aux = route(x, params["router"], cfg)
+    disp, comb, aux = route(
+        x, params["router"], cfg, params.get("router_bias"))
     disp = disp.astype(dtype)
     comb = comb.astype(dtype)
 
@@ -178,7 +194,8 @@ def moe_mlp(
 
 
 def route_indices(
-    x: jax.Array, router_w: jax.Array, cfg: ModelConfig
+    x: jax.Array, router_w: jax.Array, cfg: ModelConfig,
+    bias: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Integer routing for the sorted dispatch.
 
@@ -194,7 +211,7 @@ def route_indices(
     E, k = cfg.resolved_router_width, cfg.n_experts_per_token
     C = moe_capacity(cfg, S)
 
-    probs, gate, idx = _router_topk(x, router_w, cfg)
+    probs, gate, idx = _router_topk(x, router_w, cfg, bias)
 
     # Slot-major assignment stream [B, k*S]: all slot-0 choices precede any
     # slot-1 choice (matches route()'s prio layout).
@@ -285,7 +302,7 @@ def moe_mlp_sorted(
     dtype = x.dtype
     E, C = cfg.n_experts, moe_capacity(cfg, x.shape[1])
     idx, gate, pos, keep, (frac, mp) = route_indices(
-        x, params["router"], cfg)
+        x, params["router"], cfg, params.get("router_bias"))
     if cfg.holds_expert_share:
         # Assignments to experts held elsewhere are dropped here (their
         # bucket position was counted per expert, so the held ones keep
@@ -347,6 +364,10 @@ def moe_mlp_sorted_a2a(
         )
 
     has_gate = "w_gate" in params
+    if "router_bias" in params:
+        raise ValueError(
+            "model.router_bias is not carried into moe_dispatch=sorted_a2a's "
+            "shard_map: use moe_dispatch=sorted")
 
     def body(x_loc, router_w, w_in, w_out, *gate_w):
         p_loc = {"w_in": w_in, "w_out": w_out}
@@ -477,7 +498,8 @@ def moe_mlp_grouped(
     B, S, D = x.shape
     E, k = cfg.n_experts, cfg.n_experts_per_token
     T = B * S
-    probs, gate, idx = _router_topk(x, params["router"], cfg)
+    probs, gate, idx = _router_topk(
+        x, params["router"], cfg, params.get("router_bias"))
 
     # Assignment a = t*k + j (token t, slot j); invalid ones, and those to
     # experts held elsewhere, get key E.

@@ -117,15 +117,33 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         N = cfg.n_heads if kind is None else kind.n_heads
         moe = cfg.is_moe if kind is None else kind.moe
         bkeys = iter(jax.random.split(bkey, 16))
-        block: Params = {
-            "attn_norm": {"scale": norm_scale()},
-            "mlp_norm": {"scale": norm_scale()},
-            "attn": {
+        if cfg.is_latent:
+            # Latent attention's projections in qkv_proj's place
+            # (``latent_proj`` / ``latent_expand``).
+            qr, R = cfg.q_lora_rank, cfg.kv_lora_rank
+            nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                              cfg.v_head_dim)
+            attn = {
+                "wq_a": _normal(next(bkeys), (D, qr), pdt, std),
+                "q_a_norm": jnp.ones((qr,), pdt),
+                "wq_b": _normal(next(bkeys), (qr, N * (nope + rope)), pdt,
+                                std),
+                "wkv_a": _normal(next(bkeys), (D, R + rope), pdt, std),
+                "kv_a_norm": jnp.ones((R,), pdt),
+                "wkv_b": _normal(next(bkeys), (R, N * (nope + vd)), pdt, std),
+                "wo": _normal(next(bkeys), (N * vd, D), pdt, resid_std),
+            }
+        else:
+            attn = {
                 "wq": _normal(next(bkeys), (D, N * H), pdt, std),
                 "wk": _normal(next(bkeys), (D, K * H), pdt, std),
                 "wv": _normal(next(bkeys), (D, K * H), pdt, std),
                 "wo": _normal(next(bkeys), (N * H, D), pdt, resid_std),
-            },
+            }
+        block: Params = {
+            "attn_norm": {"scale": norm_scale()},
+            "mlp_norm": {"scale": norm_scale()},
+            "attn": attn,
         }
         if cfg.norm == "layernorm":
             block["attn_norm"]["bias"] = jnp.zeros((D,), pdt)
@@ -150,6 +168,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             if cfg.is_gated_mlp:
                 block["moe"]["w_gate"] = _normal(
                     next(bkeys), (E, D, Fe), pdt, std)
+            if cfg.router_bias:
+                block["moe"]["router_bias"] = jnp.zeros(
+                    (cfg.resolved_router_width,), pdt)
             if cfg.shared_expert_d_ff:
                 Fs = cfg.shared_expert_d_ff
                 block["moe"]["shared"] = {
@@ -245,6 +266,16 @@ def _block_axes(cfg: ModelConfig, lead: tuple, kind) -> Params:
             "wo": lead + ("heads", "embed"),
         },
     }
+    if cfg.is_latent:
+        block["attn"] = {
+            "wq_a": lead + ("embed", None),
+            "q_a_norm": lead + (None,),
+            "wq_b": lead + (None, "heads"),
+            "wkv_a": lead + ("embed", None),
+            "kv_a_norm": lead + (None,),
+            "wkv_b": lead + (None, "heads"),
+            "wo": lead + ("heads", "embed"),
+        }
     if cfg.norm == "layernorm":
         block["attn_norm"]["bias"] = lead + ("embed",)
         block["mlp_norm"]["bias"] = lead + ("embed",)
@@ -272,6 +303,8 @@ def _block_axes(cfg: ModelConfig, lead: tuple, kind) -> Params:
         }
         if cfg.is_gated_mlp:
             block["moe"]["w_gate"] = lead + ("expert", "embed", "mlp")
+        if cfg.router_bias:
+            block["moe"]["router_bias"] = lead + ("expert",)
         if cfg.shared_expert_d_ff:
             block["moe"]["shared"] = {
                 "w_in": lead + ("embed", "mlp"),
@@ -442,6 +475,102 @@ def retention_log_gate(h: jax.Array, p: Params) -> jax.Array:
         "bsd,dk->bsk", h, _load_w(p["wr"], h.dtype)).astype(jnp.float32))
 
 
+def latent_proj(
+    x: jax.Array, p: Params, cfg: ModelConfig, positions: jax.Array,
+) -> tuple[jax.Array, jax.Array]:
+    """A latent layer's projections, in ``qkv_proj``'s place: x [B, S, D] ->
+    (q [B, S, N, nope + rope], the rotary part rotated; row [B, S, R + rope]:
+    the normed compressed row beside the ONE rotated rotary key all heads
+    share, which is what a cache holds of the position). The rotation is
+    the XLA form at every length (a head of 64 is half a lane tile)."""
+    B, S, _ = x.shape
+    N, R = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope_d = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    if cfg.resolved_head_dim != nope + rope_d or cfg.n_kv_heads != N:
+        raise ValueError(
+            f"a latent model's head_dim is qk_nope_head_dim + "
+            f"qk_rope_head_dim ({nope} + {rope_d}) and its n_kv_heads its "
+            f"n_heads; got head_dim={cfg.resolved_head_dim}, "
+            f"n_kv_heads={cfg.n_kv_heads}")
+    dtype = x.dtype
+    rope = functools.partial(ops.apply_rope, theta=cfg.rope_theta, impl="xla")
+    with jax.named_scope("latent/down"):
+        c_q = ops.rmsnorm(
+            jnp.einsum("bsd,dr->bsr", x, _load_w(p["wq_a"], dtype)),
+            p["q_a_norm"], eps=cfg.norm_eps)
+        q = jnp.einsum("bsr,rh->bsh", c_q, _load_w(p["wq_b"], dtype))
+        q = q.reshape(B, S, N, nope + rope_d)
+        q = jnp.concatenate(
+            [q[..., :nope], rope(q[..., nope:], positions)], axis=-1)
+        row = jnp.einsum("bsd,dr->bsr", x, _load_w(p["wkv_a"], dtype))
+        c_kv = ops.rmsnorm(row[..., :R], p["kv_a_norm"], eps=cfg.norm_eps)
+        k_pe = rope(row[..., None, R:], positions)[:, :, 0]
+        row = jnp.concatenate([c_kv, k_pe], axis=-1)
+    return q, row
+
+
+def _wkv_b_heads(wkv_b: jax.Array, cfg: ModelConfig, dtype
+                 ) -> tuple[jax.Array, jax.Array]:
+    """``wkv_b`` [R, N x (nope + v)] as (W_uk [R, N, nope], W_uv [R, N, v])."""
+    w = _load_w(wkv_b, dtype).reshape(
+        cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def latent_expand(row: jax.Array, wkv_b: jax.Array, cfg: ModelConfig
+                  ) -> tuple[jax.Array, jax.Array]:
+    """The EXPANDED form's keys and values from cached rows [B, T, R + rope]:
+    k [B, T, N, nope + rope] (every head's own nope part beside the shared
+    rotary key) and v [B, T, N, v_head_dim]."""
+    R = cfg.kv_lora_rank
+    with jax.named_scope("latent/expand"):
+        w_uk, w_uv = _wkv_b_heads(wkv_b, cfg, row.dtype)
+        k_nope = jnp.einsum("btr,rnh->btnh", row[..., :R], w_uk)
+        v = jnp.einsum("btr,rnh->btnh", row[..., :R], w_uv)
+        k_pe = jnp.broadcast_to(
+            row[..., None, R:], (*k_nope.shape[:3], row.shape[-1] - R))
+        return jnp.concatenate([k_nope, k_pe], axis=-1), v
+
+
+def latent_attention(q: jax.Array, row: jax.Array, wkv_b: jax.Array,
+                     cfg: ModelConfig, **kw) -> jax.Array:
+    """Causal attention in the EXPANDED form: q [B, S, N, nope + rope] over
+    the keys and values of ``latent_expand(row)`` -> [B, S, N, v_head_dim].
+    ``kw`` goes to ``ops.attention`` (segment ids, blocks, impl, mesh).
+    Values narrower than a key are padded with zeros to the key's size,
+    which every attention kernel here takes, and cut again."""
+    k, v = latent_expand(row, wkv_b, cfg)
+    pad = k.shape[-1] - v.shape[-1]
+    if pad < 0:
+        raise ValueError(
+            f"model.v_head_dim={cfg.v_head_dim} exceeds the key's "
+            f"{k.shape[-1]}")
+    if pad:
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, pad),))
+    return ops.attention(q, k, v, causal=True, **kw)[..., :cfg.v_head_dim]
+
+
+def latent_absorb(q: jax.Array, wkv_b: jax.Array, cfg: ModelConfig
+                  ) -> jax.Array:
+    """The ABSORBED form's query: q [B, S, N, nope + rope] carried into the
+    rows' space, [B, S, N, R + rope] (``q_nope W_uk^T`` beside ``q_rope``),
+    so that its product with a cached row is the expanded form's score."""
+    nope = cfg.qk_nope_head_dim
+    with jax.named_scope("latent/absorb"):
+        w_uk, _ = _wkv_b_heads(wkv_b, cfg, q.dtype)
+        q_lat = jnp.einsum("bsnh,rnh->bsnr", q[..., :nope], w_uk)
+        return jnp.concatenate([q_lat, q[..., nope:]], axis=-1)
+
+
+def latent_unabsorb(o_lat: jax.Array, wkv_b: jax.Array, cfg: ModelConfig
+                    ) -> jax.Array:
+    """The absorbed form's output [B, S, N, R] (the probabilities' mean of
+    the compressed rows) through W_uv: [B, S, N, v_head_dim]."""
+    with jax.named_scope("latent/absorb"):
+        _, w_uv = _wkv_b_heads(wkv_b, cfg, o_lat.dtype)
+        return jnp.einsum("bsnr,rnh->bsnh", o_lat, w_uv)
+
+
 def mlp_or_moe(
     h: jax.Array, bp: Params, cfg: ModelConfig, mesh: Optional[Any] = None,
     valid: Optional[jax.Array] = None,
@@ -456,7 +585,7 @@ def mlp_or_moe(
     layer is, its parameters say (a model may lead with dense layers)."""
     if "moe" in bp:
         moe_params = {
-            k: v if k == "router" else jax.tree.map(
+            k: v if k in ("router", "router_bias") else jax.tree.map(
                 lambda a: a.astype(h.dtype), v)
             for k, v in bp["moe"].items()
         }
@@ -481,6 +610,23 @@ def _train_attend(
         and mesh is not None
         and mesh.shape.get(cfg.sequence_axis, 1) > 1
     )
+
+    if cfg.is_latent:
+        if sp_active:
+            raise ValueError(
+                "a latent-attention model trains on one sequence shard: "
+                "no sequence axis")
+
+        def expanded(q, row, wkv_b):
+            # The expanded form, which JAX differentiates (the absorbed
+            # kernel is decode's and has no backward).
+            return latent_attention(
+                q, row, wkv_b, cfg, q_segment_ids=segment_ids,
+                kv_segment_ids=segment_ids, seg_pad_zero=True,
+                block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
+                impl=cfg.kernels, mesh=mesh), None
+
+        return expanded
 
     if cfg.is_retention:
         if sp_active or segment_ids is not None:
@@ -586,8 +732,9 @@ def block(
     ``(x, moe_aux_loss, state)``.
 
     ``attend(q, k, v) -> (out [B, S, N, H], state)`` (with the log-gates
-    [B, S, K] as a fourth argument under model.attention=power_retention)
-    is all that differs
+    [B, S, K] as a fourth argument under model.attention=power_retention;
+    ``attend(q, row, wkv_b) -> (out [B, S, N, v_head_dim], state)`` for a
+    latent-attention model) is all that differs
     between them: what attention reads and where K/V go (``_train_attend``
     here; the dense and paged backends of ``infer/runner.py``, whose state
     is the KV pool). The body never sees a cache, a page table or a segment
@@ -618,12 +765,19 @@ def block(
         h = checkpoint_name(
             _norm(x, bp["attn_norm"], cfg, mesh), "attn_norm_out"
         )
-        q, k, v = qkv_proj(h, bp["attn"], cfg, positions, mesh, kind)
-        if cfg.is_retention:
+        if cfg.is_latent:
+            # Such a layer hands ``attend`` its queries, the ONE row a
+            # position a cache keeps, and the matrix that expands the row
+            # (or absorbs the query): which form attends is the backend's.
+            q, row = latent_proj(h, bp["attn"], cfg, positions)
+            out, state = attend(q, row, bp["attn"]["wkv_b"])
+        elif cfg.is_retention:
+            q, k, v = qkv_proj(h, bp["attn"], cfg, positions, mesh, kind)
             # Such a layer's attention also reads the log-gates, handed to
             # ``attend`` the way ``out_proj`` is handed ``h``.
             out, state = attend(q, k, v, retention_log_gate(h, bp["attn"]))
         else:
+            q, k, v = qkv_proj(h, bp["attn"], cfg, positions, mesh, kind)
             out, state = attend(q, k, v)
         # remat="names" saves the kernel output: the single most expensive
         # per-layer tensor to rebuild (a full flash fwd pass) at [B,S,N,H]

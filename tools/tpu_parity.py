@@ -26,6 +26,9 @@ compares fwd + grads against the xla reference ops:
     ``ops.rope.KERNEL_MIN_SEQ`` and so the XLA form, held here to the kernel
     on the same rows at ``[32, 1, 40 | 72 | 8, 128]``, positions to 12287,
     the plain, the half-rotated and the YaRN table
+  - latent attention (``--only latent`` runs these alone): the latent
+    paged decode kernel at the GLM cell's shapes against the XLA absorbed
+    form, against the expanded form, and a control without the rotary term.
   - power retention (``--only retention`` runs these alone): the chunked
     prefill kernel (outputs and the state it hands out, ragged lengths),
     the decode kernel over a state row and a paged tail (tail only, state
@@ -1014,6 +1017,91 @@ def retention_checks() -> None:
             [(Sg[others], state[others]), (zg[others], state_z[others])])
 
 
+def latent_checks() -> None:
+    """The latent paged decode kernel (``latent_paged_decode``) at the GLM
+    cell's shapes: 20 query heads over rows of 512 + 64 padded to 640,
+    pages of 64, contexts from one row to the whole 21504, at a traced
+    layer base. Against the XLA absorbed form on the same pool (and the
+    pool it hands back bitwise), against the EXPANDED form on the same rows
+    (equation 4 against equation 3, peaked scores), and a control that
+    drops ``q_rope . k_pe`` and has to fail."""
+    from orion_tpu.config import get_config
+    from orion_tpu.models.transformer import (
+        latent_absorb, latent_expand, latent_unabsorb)
+    from orion_tpu.ops.pallas.latent_paged_attention import (
+        latent_paged_attention,
+    )
+
+    cfg = get_config("glm-4.7-flash").model
+    N, R, rope = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    psz, Wd = 64, 640
+    P, NP = (16, 80) if INTERP else (336, 700)
+    lens = [1, 700, 1000, 1024] if INTERP else [1, 3000, 11501, 21504]
+    B, scale, dt = len(lens), cfg.resolved_head_dim ** -0.5, jnp.bfloat16
+    ks = jax.random.split(jax.random.key(7), 5)
+    rows = jax.random.normal(ks[0], (2 * NP, 1, psz, R + rope), jnp.float32)
+    pool = jnp.pad(rows, ((0, 0),) * 3 + ((0, Wd - R - rope),)).astype(dt)
+    table = jnp.asarray(np.stack([
+        np.random.default_rng(b).permutation(np.arange(1, NP))[:P]
+        for b in range(B)]), jnp.int32)
+    pos = jnp.asarray(lens, jnp.int32) - 1
+    # Peaked: a query of a few units against rows of unit numbers.
+    wkv_b = (jax.random.normal(ks[1], (R, N * (cfg.qk_nope_head_dim
+                                               + cfg.v_head_dim)))
+             * R ** -0.5).astype(dt)
+    q = (3.0 * jax.random.normal(ks[2], (B, 1, N, cfg.resolved_head_dim))
+         ).astype(dt)
+    new = jnp.pad(jax.random.normal(ks[3], (B, R + rope)),
+                  ((0, 0), (0, Wd - R - rope))).astype(dt)
+    q_lat = jnp.pad(latent_absorb(q, wkv_b, cfg)[:, 0],
+                    ((0, 0), (0, 0), (0, Wd - R - rope)))
+
+    def context(pool_, base):
+        written = pool_.at[base + table[jnp.arange(B), pos // psz], 0,
+                           pos % psz].set(new)
+        return written, written[base + table][:, :, 0].reshape(B, -1, Wd)
+
+    def absorbed(ql, pool_, base):
+        written, ctx = context(pool_, base)
+        z = jnp.einsum("bnw,btw->bnt", ql.astype(jnp.float32),
+                       ctx.astype(jnp.float32)) * scale
+        live = jnp.arange(ctx.shape[1])[None, :] <= pos[:, None]
+        p = jax.nn.softmax(jnp.where(live[:, None], z, -jnp.inf), axis=-1)
+        return jnp.einsum("bnt,btr->bnr", p,
+                          ctx[..., :R].astype(jnp.float32)), written
+
+    def expanded(pool_, base):
+        _, ctx = context(pool_, base)
+        k, v = latent_expand(ctx[..., :R + rope].astype(jnp.float32),
+                             wkv_b.astype(jnp.float32), cfg)
+        live = jnp.arange(ctx.shape[1])[None, None, :] <= pos[:, None, None]
+        return attention_xla(q.astype(jnp.float32), k, v, causal=False,
+                             mask=live)[:, 0]
+
+    for layer in (0, 1):
+        base = jnp.asarray(layer * NP, jnp.int32)
+        got, pool_got = jax.jit(
+            lambda ql, pl_, b: latent_paged_attention(
+                ql, pl_, table, pos, new, layer_base=b, value_width=R,
+                scale=scale, interpret=INTERP))(q_lat, pool, base)
+        want, pool_want = jax.jit(absorbed)(q_lat, pool, base)
+        check(f"latent decode out, layer {layer}", got, want, 2e-2)
+        bitwise(f"latent decode pool, layer {layer}", [(pool_got, pool_want)])
+    base = jnp.asarray(NP, jnp.int32)
+    out = latent_unabsorb(got[:, None], wkv_b, cfg)[:, 0]
+    full = jax.jit(expanded)(pool, base)
+    check("latent decode (absorbed) vs the expanded form", out, full, 3e-2)
+    blind, _ = latent_paged_attention(
+        q_lat.at[..., R:].set(0), pool, table, pos, new, layer_base=base,
+        value_width=R, scale=scale, interpret=INTERP)
+    blind = latent_unabsorb(blind[:, None], wkv_b, cfg)[:, 0].astype(
+        jnp.float32)
+    rel = float(jnp.max(jnp.abs(blind - full))) / float(
+        jnp.max(jnp.abs(full)))
+    record("latent decode CONTROL without q_rope . k_pe differs from the "
+           "expanded form", rel > 0.1, f": rel={rel:.3e}")
+
+
 def main() -> int:
     global INTERP
     INTERP = "--interpret" in sys.argv[1:]
@@ -1032,7 +1120,8 @@ def main() -> int:
           f"devices={len(jax.devices())} interpret={INTERP}", flush=True)
 
     for name, group in (("retention", retention_checks),
-                        ("rope", rope_decode_checks)):
+                        ("rope", rope_decode_checks),
+                        ("latent", latent_checks)):
         if name in sys.argv[1:]:        # --only <name>
             guarded(name, group)
             green = sum(ok for _, ok in RESULTS)
@@ -1040,6 +1129,7 @@ def main() -> int:
                   f"{green} of {len(RESULTS)} checks [{name} only]")
             return 0 if green == len(RESULTS) else 1
     guarded("retention", retention_checks)
+    guarded("latent", latent_checks)
     guarded("flash", flash_checks)
     if not INTERP:      # the cells' sizes: minutes under the interpreter
         guarded("flash @cells", flash_cell_checks)
