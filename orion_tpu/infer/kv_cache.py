@@ -150,32 +150,39 @@ def init_cache(
 
 
 # The leaves of ``retention_leaves`` that are a slot's and not a page's.
-SLOT_LEAVES = ("state", "state_z", "state_len")
+SLOT_LEAVES = ("state", "state_z", "state_len", "g")
 
 
 def retention_leaves(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> Cache:
     """What a power-retention model keeps beside its pages, which hold only
-    a sequence's positions since its last fold (``k`` / ``v``, and ``g``:
-    the cumulative log-gates of each position within its own chunk,
-    float32, [layers, pages, K, page]: a layer's slice
-    is small enough to be taken out, written and put back in the layer scan,
-    where a scatter into the flat pool makes XLA re-lay the whole of it).
+    the K and V of a sequence's positions since its last fold (its tail).
+    Everything else is a SLOT's, [layers x (slots + 1), ...] with row 0 of
+    each layer a scratch row as page 0 is and slot b owning row b + 1:
 
     ``state`` [layers x (slots + 1), K, R, H, H] and ``state_z`` [layers x
-    (slots + 1), R, K, H] are a slot's fixed-size state (``ops/retention``:
-    R slabs of H; row 0 of each layer is a scratch row as page 0 is, slot b
-    owns row b + 1), and ``state_len`` [slots + 1] the positions it holds:
-    a multiple of the chunk, written by prefill and by the fold alone."""
-    from orion_tpu.ops.retention import n_slabs
+    (slots + 1), R, K, H] are the fixed-size state (``ops/retention``: R
+    slabs of H), and ``state_len`` [slots + 1] the positions it holds: a
+    multiple of the chunk, written by prefill and by the fold alone.
+
+    ``g`` [layers, slots + 1, K, T] float32 holds the tail's cumulative
+    log-gates, each position's sum within its own chunk: column c is
+    position ``state_len + c``, T = ``tail_pages`` x page (a chunk, a
+    decode window, rounded up to whole 128-lane rows). It is the very
+    array a decode step hands its kernel, kept and not rebuilt from pages:
+    a step writes one column of a slot's row, a fold moves the row down a
+    chunk, a prefill writes it whole (zeros behind the newest position),
+    and no read of a column behind the newest position is used."""
+    from orion_tpu.ops.retention import fold_chunk, n_slabs, tail_pages
 
     K, H = mcfg.n_kv_heads, mcfg.resolved_head_dim
-    R, rows = n_slabs(H), mcfg.n_layers * (icfg.max_batch_size + 1)
+    R, slots = n_slabs(H), icfg.max_batch_size + 1
+    T = tail_pages(fold_chunk(mcfg.max_seq_len),
+                   icfg.page_size) * icfg.page_size
     return {
-        "g": jnp.zeros((mcfg.n_layers, icfg.num_pages, K, icfg.page_size),
-                       jnp.float32),
-        "state": jnp.zeros((rows, K, R, H, H), dtype),
-        "state_z": jnp.zeros((rows, R, K, H), jnp.float32),
-        "state_len": jnp.zeros((icfg.max_batch_size + 1,), jnp.int32),
+        "g": jnp.zeros((mcfg.n_layers, slots, K, T), jnp.float32),
+        "state": jnp.zeros((mcfg.n_layers * slots, K, R, H, H), dtype),
+        "state_z": jnp.zeros((mcfg.n_layers * slots, R, K, H), jnp.float32),
+        "state_len": jnp.zeros((slots,), jnp.int32),
     }
 
 
@@ -363,8 +370,6 @@ def scrub_pages(
     for name, arr in cache.items():
         if name in SLOT_LEAVES:     # no page: the next prefill writes the row
             out[name] = arr
-        elif name == "g":           # [layers, pages, K, page]
-            out[name] = arr.at[:, pages].set(jnp.zeros((), arr.dtype))
         else:                       # [layers x pages, ...]
             out[name] = arr.at[layer_rows].set(jnp.zeros((), arr.dtype))
     return out

@@ -1159,10 +1159,14 @@ def _latent_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
 # A power-retention model (model.attention, ops/retention.py) keeps of a
 # sequence a state row (``state`` / ``state_z``: everything below
 # ``state_len``, a multiple of the model's chunk, ``_chunk``) and, in pages, only
-# the positions from there on: K, V and ``g``, each position's cumulative
-# log-gate within its own chunk. The state is written in two places only:
-# at the end of prefill and by ``fold_step``. The page table stays indexed
-# by absolute position; entries behind the state may point anywhere.
+# the K and V of the positions from there on. Those positions' cumulative
+# log-gates, each within its own chunk, are the slot's too (``g``
+# [layers, slots + 1, K, T]: column c is position ``state_len + c``,
+# ``kv_cache.retention_leaves``): the array a decode step hands its kernel,
+# kept as it is. The state is written in two places only: at the end of
+# prefill and by ``fold_step``, which are also the two that re-base a row of
+# ``g``. The page table stays indexed by absolute position; entries behind
+# the state may point anywhere.
 
 
 def _chunk(cfg: ModelConfig) -> int:
@@ -1175,14 +1179,15 @@ def _retained_prefill(params, cache, tokens, lengths, pages, state_rows,
                       cfg: ModelConfig, mesh):
     """Whole prompts: every complete chunk of a row into its state row
     (``state_rows``: slot + 1; padding rows and a warm-up take scratch row
-    0), every position's K, V and gate into ``pages`` (the engine points
-    the pages behind a row's state at scratch page 0)."""
+    0) and the gates of the positions behind them into its row of ``g``,
+    written whole; every position's K and V into ``pages`` (the engine
+    points the pages behind a row's state at scratch page 0)."""
     from orion_tpu.ops.retention import chunk_cumsum, power_retention
 
     Nb, S_pad = tokens.shape
-    psz, NP = page_geometry(cache, cfg.n_layers)
+    _, NP = page_geometry(cache, cfg.n_layers)
     n_rows = cache["state_len"].shape[0]
-    C = _chunk(cfg)
+    C, T = _chunk(cfg), cache["g"].shape[-1]
     if state_rows is None:
         # A caller of the bare function with a K/V model's seven arguments
         # (tests/benchmark/test_aot_v5e.py); the engine's program always
@@ -1192,6 +1197,12 @@ def _retained_prefill(params, cache, tokens, lengths, pages, state_rows,
     positions = jnp.broadcast_to(
         jnp.arange(S_pad, dtype=jnp.int32), (Nb, S_pad))
     valid = positions < lengths[:, None]
+    folded = lengths // C * C
+    # Column c of a row of ``g`` is position folded + c: the row's tail,
+    # then zeros (nothing of the slot's last tenant stays).
+    tail = folded[:, None] + jnp.arange(T, dtype=jnp.int32)     # [Nb, T]
+    tail_at = jnp.minimum(tail, S_pad - 1)[:, None, :]
+    tail_live = (tail < lengths[:, None])[:, None, :]
 
     def body(carry, bp, l, j, stack=None):
         x, cc = carry
@@ -1201,12 +1212,10 @@ def _retained_prefill(params, cache, tokens, lengths, pages, state_rows,
                 q, k, v, log_g, lengths=lengths, chunk=C, impl=cfg.kernels)
 
             def written():
-                rows = l * NP + pages
-                new = _scatter_pages(cc, k, v, rows)
-                b = chunk_cumsum(log_g, C).reshape(Nb, -1, psz, k.shape[2])
-                new["g"] = _layer_slice(
-                    cc["g"], l, lambda g: g.at[pages].set(
-                        b.transpose(0, 1, 3, 2)))
+                new = _scatter_pages(cc, k, v, l * NP + pages)
+                b = jnp.swapaxes(chunk_cumsum(log_g, C), 1, 2)  # [Nb, K, S]
+                new["g"] = cc["g"].at[l, state_rows].set(jnp.where(
+                    tail_live, jnp.take_along_axis(b, tail_at, axis=2), 0.0))
                 srows = l * n_rows + state_rows
                 new["state"] = cc["state"].at[srows].set(
                     S.astype(cc["state"].dtype))
@@ -1223,15 +1232,8 @@ def _retained_prefill(params, cache, tokens, lengths, pages, state_rows,
 
     x = embed(params, tokens, positions, cfg)
     x, cache = _scan_layers(params, cfg, body, (x, dict(cache)))
-    cache["state_len"] = cache["state_len"].at[state_rows].set(
-        lengths // C * C)
+    cache["state_len"] = cache["state_len"].at[state_rows].set(folded)
     return _prefill_logits(params, x, lengths, cfg, mesh), cache
-
-
-def _layer_slice(pool: jax.Array, l, update):
-    """``update`` applied to layer l's slice of a [layers, ...] leaf."""
-    one = jax.lax.dynamic_index_in_dim(pool, l, keepdims=False)
-    return jax.lax.dynamic_update_index_in_dim(pool, update(one), l, 0)
 
 
 def _retained_ctx(cache: Cache, pos: jax.Array, page_table: jax.Array,
@@ -1239,25 +1241,22 @@ def _retained_ctx(cache: Cache, pos: jax.Array, page_table: jax.Array,
     """Batch-level tensors of ``_retained_layer``: one new token a slot at
     position ``pos`` (slot b of the page table owns state row b + 1)."""
     from orion_tpu.ops._dispatch import resolve_impl
-    from orion_tpu.ops.retention import tail_pages
 
     psz, NP = page_geometry(cache, cfg.n_layers)
-    P = page_table.shape[1]
     C = _chunk(cfg)
     F = cache["state_len"][1:]                                 # [B]
-    slots = jnp.arange(pos.shape[0])
-    at = jnp.minimum(pos, P * psz - 1)
-    prev = jnp.maximum(pos - 1, 0)
-    nT = tail_pages(C, psz)
-    tp = jnp.minimum(F[:, None] // psz + jnp.arange(nT), P - 1)
-    jpos = F[:, None] + jnp.arange(nT * psz)                   # [B, T]
+    at = jnp.minimum(pos, page_table.shape[1] * psz - 1)
+    col = jnp.arange(cache["g"].shape[-1], dtype=jnp.int32)    # [T]
+    jpos = F[:, None] + col                                    # [B, T]
     use_pallas, interpret = resolve_impl(cfg.kernels)
     return dict(
         NP=NP, C=C, F=F, pos=pos, at=at, positions=at[:, None],
         page_table=page_table,
-        new_page=page_table[slots, at // psz], new_off=at % psz,
-        prev_page=page_table[slots, prev // psz], prev_off=prev % psz,
-        tail=jnp.take_along_axis(page_table, tp, axis=1),
+        # The columns of a slot's row of ``g`` that hold the new position
+        # and the one before it; a chunk's sum restarts at its first
+        # position, which therefore has no column before it.
+        new_col=jpos == at[:, None],
+        prev_col=(jpos == pos[:, None] - 1) & (pos % C != 0)[:, None],
         second=jpos >= (F + C)[:, None],
         live=jpos <= at[:, None],
         n_rows=cache["state_len"].shape[0],
@@ -1269,24 +1268,24 @@ def _retained_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
                     cfg: ModelConfig, mesh) -> tuple[jax.Array, Cache]:
     """The retained backend: one layer of one new token a slot. Its K and V
     land in the tail page (inside the kernel on the pallas path, as the
-    paged backend's do) and its cumulative log-gate in ``g``; the query
-    attends its slot's tail and reads its state row. The gates are this
-    function's: what the kernel is handed is, for the new token and for
-    every tail position, the log-decay since ``state_len``."""
+    paged backend's do) and its cumulative log-gate in one column of the
+    slot's row of ``g``; the query attends its slot's tail and reads its
+    state row. The gates are this function's: what the kernel is handed is,
+    for the new token and for every tail position, the log-decay since
+    ``state_len``. A column behind the newest position may hold anything (a
+    quarantined tenant's NaN): each is read behind a ``where``."""
     from orion_tpu.ops.retention import BIG, retention_decode_xla
 
     NP, C, F, pos, at = ctx["NP"], ctx["C"], ctx["F"], ctx["pos"], ctx["at"]
     B = at.shape[0]
 
     def attend(q, k, v, log_g):
-        gp = jax.lax.dynamic_index_in_dim(cc["g"], l, keepdims=False)
-        K = gp.shape[1]
-        # A chunk's sum restarts at its first position.
+        g = cc["g"]
+        at_l = (l, 1, 0, 0)                     # the slots' rows of layer l
+        bt = jax.lax.dynamic_slice(g, at_l, (1, B, *g.shape[2:]))[0]
         b_new = log_g[:, 0] + jnp.where(
-            (pos % C == 0)[:, None], 0.0,
-            gp[ctx["prev_page"], :, ctx["prev_off"]])          # [B, K]
-        gp = gp.at[ctx["new_page"], :, ctx["new_off"]].set(b_new)
-        bt = gp[ctx["tail"]].transpose(0, 2, 1, 3).reshape(B, K, -1)
+            ctx["prev_col"][:, None, :], bt, 0.0).sum(-1)      # [B, K]
+        bt = jnp.where(ctx["new_col"][:, None, :], b_new[:, :, None], bt)
         # A tail spans two chunks at most: positions of the second add the
         # whole first chunk's sum (its last position's entry).
         first = bt[:, :, C - 1]                                # [B, K]
@@ -1307,8 +1306,8 @@ def _retained_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
         else:
             y, kp, vp = retention_decode_xla(*args, **kw)
         return y[:, None], {**cc, "k": kp, "v": vp,
-                            "g": jax.lax.dynamic_update_index_in_dim(
-                                cc["g"], gp, l, 0)}
+                            "g": jax.lax.dynamic_update_slice(
+                                g, bt[None], at_l)}
 
     x, _, cc = block(x, bp, cfg, ctx["positions"], attend,
                      kind=_kind(cfg, j), mesh=mesh)
@@ -1318,11 +1317,13 @@ def _retained_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
 def fold_step(cache: Cache, slot: jax.Array, page_row: jax.Array, *,
               cfg: ModelConfig, mesh=None) -> Cache:
     """Fold the complete chunk at the head of ONE slot's tail (positions
-    ``state_len .. state_len + chunk`` of slot ``slot``, through
-    its page-table row ``page_row`` [P]) into its state row, in every
-    layer, and advance ``state_len``. The engine runs it at the start of a
-    decode window for each slot whose tail holds a complete chunk, then
-    frees the chunk's pages. No weights are read."""
+    ``state_len .. state_len + chunk`` of slot ``slot``: K and V through
+    its page-table row ``page_row`` [P], gates from the first ``chunk``
+    columns of its row of ``g``) into its state row, in every layer; then
+    advance ``state_len`` and move the row of ``g`` down a chunk with it.
+    The engine runs it at the start of a decode window for each slot whose
+    tail holds a complete chunk, then frees the chunk's pages. No weights
+    are read."""
     from orion_tpu.ops._dispatch import resolve_impl
     from orion_tpu.ops.retention import retention_fold_xla
 
@@ -1332,19 +1333,20 @@ def fold_step(cache: Cache, slot: jax.Array, page_row: jax.Array, *,
     C, P = _chunk(cfg), page_row.shape[0]
     F = cache["state_len"][slot + 1]
     pages = page_row[jnp.minimum(F // psz + jnp.arange(C // psz), P - 1)]
+    gates = jax.lax.dynamic_index_in_dim(
+        cache["g"], slot + 1, axis=1, keepdims=False)          # [L, K, T]
     use_pallas, interpret = resolve_impl(cfg.kernels)
 
     def chunk(pool, rows):      # [n, K, psz, ...] -> [K, C, ...]
         x = jnp.moveaxis(pool[rows], 1, 0)
         return x.reshape(x.shape[0], C, *x.shape[3:])
 
-    def body(carry, l):
+    def body(carry, xs):
         state, z = carry
+        l, b = xs
         rows = l * NP + pages
         args = (state, z, chunk(cache["k"], rows), chunk(cache["v"], rows),
-                chunk(jax.lax.dynamic_index_in_dim(
-                    cache["g"], l, keepdims=False), pages),
-                l * n_rows + slot + 1)
+                b, l * n_rows + slot + 1)
         if use_pallas:
             from orion_tpu.ops.pallas.retention import retention_fold
 
@@ -1352,6 +1354,10 @@ def fold_step(cache: Cache, slot: jax.Array, page_row: jax.Array, *,
         return retention_fold_xla(*args), None
 
     (state, z), _ = jax.lax.scan(
-        body, (cache["state"], cache["state_z"]), jnp.arange(cfg.n_layers))
+        body, (cache["state"], cache["state_z"]),
+        (jnp.arange(cfg.n_layers), gates[:, :, :C]))
+    moved = jnp.pad(gates[:, :, C:], ((0, 0), (0, 0), (0, C)))
     return {**cache, "state": state, "state_z": z,
+            "g": jax.lax.dynamic_update_index_in_dim(
+                cache["g"], moved, slot + 1, 1),
             "state_len": cache["state_len"].at[slot + 1].add(C)}

@@ -361,25 +361,44 @@ def test_a_fold_that_writes_nothing_is_seen_with_the_benchmarks_weights(tiny):
 
 def test_a_quarantined_request_leaves_no_nan_behind(tiny):
     """NaN quarantine on a model with per-slot leaves: the victim errors,
-    its pages of ``k``, ``v`` AND ``g`` are scrubbed, the state leaves (of
-    another layout) are left alone, and the neighbour's tokens are those of
-    a fault-free run."""
+    its pages of ``k`` and ``v`` are scrubbed, the slot leaves (``g`` among
+    them: no page, the slot's next prefill writes its row whole) are left
+    alone, and the neighbour's tokens are those of a fault-free run. The
+    victim's NaN did reach its row of ``g`` (or this would hold nothing);
+    the slot's next tenant reads none of it and leaves the leaf finite."""
     from orion_tpu.runtime.fault import FaultInjector, FaultSpec
 
     prompts = [list(range(1, 22)), list(range(30, 40))]
+    later = list(range(50, 57))         # 7 positions: a tail and no state
     guard = ["inference.nan_guard=true"]
     eng = _engine(tiny[1], guard)
     want = eng.generate(prompts, max_new_tokens=16)
+    want_later = eng.generate([later], max_new_tokens=30)[0]
     eng.close()
     eng = _engine(tiny[1], guard, FaultInjector([FaultSpec("nan", step=1)]))
     reqs = [eng.submit_request(p, 16) for p in prompts]
+    victim = None
     while eng.has_work():
         eng.step()
+        if reqs[0].done and victim is None:
+            victim = np.asarray(eng.cache["g"])
+            # the neighbour still decodes beside the row the victim left
+            assert not reqs[1].done
     assert [r.outcome for r in reqs] == ["error:nan", "completed"]
     assert list(reqs[1].generated) == list(want[1])
     assert eng.reset_timing()["quarantined_requests"] == 1
-    for name in ("k", "v", "g"):
+    for name in ("k", "v"):
         assert np.isfinite(np.asarray(eng.cache[name])).all(), name
+    assert not np.isfinite(victim[:, 1]).all()       # slot 0 owns row 1
+    assert np.isfinite(np.delete(victim, 1, axis=1)).all()
+    req = eng.submit_request(later, 30)              # folds at 16 and at 32
+    eng.step()
+    assert req.slot == 0
+    while eng.has_work():
+        eng.step()
+    assert list(req.generated) == list(want_later)
+    assert eng.reset_timing()["folds"] == 2
+    assert np.isfinite(np.asarray(eng.cache["g"])).all()
     eng.assert_page_accounting()
     eng.close()
 
@@ -404,6 +423,226 @@ def test_the_one_token_body_runs_again_and_never_writes_the_state(tiny):
     for name in ("k", "v", "g"):
         assert (np.asarray(c2[name]) == np.asarray(c1[name])).all()
     eng.close()
+
+
+def _reference_log_gates(params, tokens):
+    """[layers, K, S] float32: every layer's log-gates of one sequence, by
+    the reference's own layer (``benchmarks/reference/brumby.hidden_states``
+    with the gates handed out beside the residual stream)."""
+    ref = _reference()
+    f32 = lambda a: a.astype(jnp.float32)                      # noqa: E731
+    N, K, H = (HF["num_attention_heads"], HF["num_key_value_heads"],
+               HF["head_dim"])
+    eps, theta = HF["rms_norm_eps"], HF["rope_theta"]
+    tokens = jnp.asarray(tokens)
+    S, positions = tokens.shape[0], jnp.arange(tokens.shape[0])
+
+    def layer(x, bp):
+        a = bp["attn"]
+        h = ref._rmsnorm(x, f32(bp["attn_norm"]["scale"]), eps)
+        q, k, v = (ref._matmul(h, f32(a[w]), None).reshape(S, n, H)
+                   for w, n in (("wq", N), ("wk", K), ("wv", K)))
+        q = ref._rope(ref._head_norm(q, f32(a["q_norm"]), eps), positions,
+                      theta)
+        k = ref._rope(ref._head_norm(k, f32(a["k_norm"]), eps), positions,
+                      theta)
+        log_g = ref._log_gate(h, f32(a["wr"]), None)
+        y = ref._retention(q, k, v, log_g).reshape(S, N * H)
+        x = x + ref._matmul(y, f32(a["wo"]), None)
+        h = ref._rmsnorm(x, f32(bp["mlp_norm"]["scale"]), eps)
+        return x + ref._mlp(h, bp["mlp"], None), log_g.T
+
+    with jax.default_matmul_precision("highest"):
+        return jax.lax.scan(
+            layer, f32(params["embed"]["tokens"][tokens]),
+            params["blocks"])[1]
+
+
+def _gate_rows(eng, reqs):
+    """Run the engine dry; after every step, of every request that holds a
+    slot: (positions in the cache, ``state_len``, its row of ``g``, the
+    step's number)."""
+    seen, step = {r.rid: [] for r in reqs}, 0
+    while eng.has_work():
+        eng.step()
+        step += 1
+        g, folded = np.asarray(eng.cache["g"]), np.asarray(
+            eng.cache["state_len"])
+        for r in reqs:
+            if r.slot is not None:
+                assert folded[1 + r.slot] == eng.fold_lens[r.slot]
+                seen[r.rid].append((int(eng.seq_lens[r.slot]),
+                                    int(folded[1 + r.slot]),
+                                    g[:, 1 + r.slot], step))
+    return seen
+
+
+def _assert_rows_are_the_references(params, req, seen, C=16):
+    """Column c of a slot's row is position ``state_len + c``: its gate
+    summed within its own chunk, as ``chunk_cumsum`` of the reference's
+    log-gates has it, at every position since ``state_len``."""
+    tokens = list(req.prompt) + list(req.generated)
+    want = ret.chunk_cumsum(
+        jnp.moveaxis(_reference_log_gates(params, tokens), 2, 1), C)
+    want = np.moveaxis(np.asarray(want), 1, 2)                 # [L, K, S]
+    assert len(seen) > 2
+    for n, folded, row, _ in seen:
+        assert 0 < n - folded <= C + 4                 # a chunk and a window
+        np.testing.assert_allclose(
+            row[:, :, :n - folded], want[:, :, folded:n],
+            rtol=2e-4, atol=2e-5, err_msg=f"{n} positions, {folded} folded")
+    return {folded for _, folded, _, _ in seen}
+
+
+def test_a_slots_row_of_gates_is_the_references_column_for_column(tiny):
+    """A prompt that ends inside a chunk (29 = 16 + 13), a window of 4, the
+    fold at 32, four windows more and the fold at 48: after every engine
+    step the slot's row holds the reference's gates of the positions since
+    its ``state_len``, column for column."""
+    _, params = tiny
+    eng = _engine(params)
+    req = eng.submit_request(list(range(1, 30)), 30)
+    seen = _gate_rows(eng, [req])[req.rid]
+    assert _assert_rows_are_the_references(params, req, seen) == {16, 32, 48}
+    assert eng.reset_timing()["folds"] == 2
+    assert seen[0][:2] == (33, 16)       # a whole chunk waits for its fold
+    eng.close()
+
+
+def test_no_column_behind_the_newest_position_is_read(tiny):
+    """Every column of ``g`` that holds no live position poisoned with NaN,
+    empty slots' rows and the scratch row whole: a decode step's logits, a
+    fold's state and the step after that fold are bitwise those of the
+    clean cache, and the logits are finite in every slot."""
+    from orion_tpu.infer import runner
+
+    cfg, params = tiny
+    eng = _engine(params)
+    req = eng.submit_request(list(range(1, 30)), 24)
+    while not req.generated or int(eng.seq_lens[req.slot]) < 32:
+        eng.step()
+    slot, n = req.slot, int(eng.seq_lens[req.slot])
+    assert (n, int(eng.fold_lens[slot])) == (33, 16)   # a fold is due
+    clean = {k: jnp.array(v) for k, v in eng.cache.items()}
+    live = jnp.zeros(clean["g"].shape, bool).at[:, 1 + slot, :, :n - 16].set(
+        True)
+    bad = {**clean, "g": jnp.where(live, clean["g"], jnp.nan)}
+    tok, pos = jnp.asarray(eng.last_token), jnp.asarray(eng.seq_lens)
+    table = jnp.asarray(eng.page_table)
+
+    def step(cache):
+        return runner._decode_core(params, cache, tok, pos, table, cfg, None)
+
+    def fold(cache):
+        return runner.fold_step(cache, jnp.int32(slot), table[slot], cfg=cfg)
+
+    def same_step(a, b, end):
+        """One step on the clean cache ``a`` and on the poisoned ``b``:
+        the same finite logits, the new column written and the poison
+        (up to column ``end``) left where it was."""
+        (la, ca), (lb, cb) = step(a), step(b)
+        assert np.isfinite(np.asarray(lb)).all()
+        assert (np.asarray(la) == np.asarray(lb)).all()
+        keep = n + 1 - int(ca["state_len"][1 + slot])
+        ga, gb = (np.asarray(c["g"])[:, 1 + slot] for c in (ca, cb))
+        assert (ga[:, :, :keep] == gb[:, :, :keep]).all()
+        assert np.isnan(gb[:, :, keep:end]).all()
+
+    same_step(clean, bad, None)
+    a, b = fold(clean), fold(bad)
+    for name in ("state", "state_z", "state_len"):
+        assert (np.asarray(a[name]) == np.asarray(b[name])).all()
+    assert int(a["state_len"][1 + slot]) == 32
+    same_step(a, b, -16)    # a fold moves a row down, zeros behind it
+    eng.close()
+
+
+def test_two_slots_fold_in_windows_of_their_own_and_keep_their_rows(tiny):
+    """Prompts of 29 and 21: the first slot folds after its first window,
+    the second after its third. Each row follows its own sequence's
+    reference at every step, and a fold of one slot leaves every other row
+    of ``g`` (the scratch row too) bitwise as it was."""
+    from orion_tpu.infer import runner
+
+    cfg, params = tiny
+    eng = _engine(params)
+    reqs = [eng.submit_request(list(range(1, 30)), 30),
+            eng.submit_request(list(range(40, 61)), 30)]
+    eng.step()
+    while int(eng.seq_lens[reqs[0].slot]) < 32:
+        eng.step()
+    a, b = reqs[0].slot, reqs[1].slot
+    assert int(eng.seq_lens[b]) - int(eng.fold_lens[b]) < 16   # none due
+    before = {k: jnp.array(v) for k, v in eng.cache.items()}
+    after = runner.fold_step(before, jnp.int32(a),
+                             jnp.asarray(eng.page_table[a]), cfg=cfg)
+    g0, g1 = np.asarray(before["g"]), np.asarray(after["g"])
+    others = np.arange(g0.shape[1]) != 1 + a
+    assert (g0[:, others] == g1[:, others]).all()
+    assert (g1[:, 1 + a, :, :-16] == g0[:, 1 + a, :, 16:]).all()
+    assert not g1[:, 1 + a, :, -16:].any()
+    assert [int(x) for x in after["state_len"]] == [
+        int(x) + 16 * (i == 1 + a) for i, x in enumerate(before["state_len"])]
+    seen = _gate_rows(eng, reqs)
+    folds = [_assert_rows_are_the_references(params, r, seen[r.rid])
+             for r in reqs]
+    assert folds == [{32, 48}, {16, 32}]     # seen from here on
+    when = [[step for _, folded, _, step in seen[r.rid] if folded == 32][0]
+            for r in reqs]
+    assert when[0] != when[1]
+    eng.close()
+
+
+@pytest.mark.parametrize("num_pages", [40, 160])
+def test_the_gates_leaf_is_the_slots_and_not_the_pools(num_pages):
+    """[layers, slots + 1, K, T], T a chunk and a window in whole lane
+    rows, whatever ``inference.num_pages`` is; the other slot leaves
+    alike, and only ``k`` / ``v`` grow with the pool."""
+    from orion_tpu.infer.kv_cache import SLOT_LEAVES, init_cache
+
+    cfg = get_config("tiny-brumby", [f"inference.num_pages={num_pages}"])
+    cache = init_cache(cfg.model, cfg.inference)
+    assert set(cache) == {"k", "v", *SLOT_LEAVES} and "g" in SLOT_LEAVES
+    T = ret.tail_pages(16, 4) * 4
+    assert T == 128 and cache["g"].shape == (3, 4 + 1, 2, T)
+    assert cache["g"].dtype == jnp.float32
+    assert cache["state_len"].shape == (5,)
+    assert cache["state"].shape[0] == cache["state_z"].shape[0] == 3 * 5
+    assert cache["k"].shape[0] == cache["v"].shape[0] == 3 * num_pages
+    big = get_config("brumby-14b", [
+        "inference.page_size=64", "inference.max_batch_size=32",
+        f"inference.num_pages={num_pages}"])
+    shapes = jax.eval_shape(lambda: init_cache(big.model, big.inference))
+    assert shapes["g"].shape == (40, 33, 8, 640)     # 512 + 8 -> 5 x 128
+
+
+def test_no_float32_array_of_the_decode_window_is_as_long_as_the_pool(tiny):
+    """The lowered decode window of a pool of 53 pages (a number no other
+    size of the tiny model is): no float32 array has a dimension of 53,
+    as the paged gates [layers, pages, K, page] and a layer's slice of
+    them had; the K/V pools are [layers x pages, ...] and the slots' gates
+    [layers, slots + 1, K, T]."""
+    import functools
+    import re
+
+    from orion_tpu.infer import runner
+    from orion_tpu.infer.kv_cache import init_cache, pages_per_seq
+
+    cfg = get_config("tiny-brumby", ["inference.num_pages=53"])
+    m, i = cfg.model, cfg.inference
+    B, W = i.max_batch_size, i.decode_window
+    cache = init_cache(m, i)
+    text = jax.jit(functools.partial(
+        runner.decode_window, cfg=m, max_seq_len=i.max_seq_len)).lower(
+        tiny[1], cache, jnp.zeros(B, jnp.int32), jnp.ones(B, jnp.int32),
+        jnp.zeros((B, pages_per_seq(i)), jnp.int32), jnp.ones(B, bool),
+        jax.random.split(jax.random.key(0), W), jnp.zeros(B), jnp.zeros(
+            B, jnp.int32), jnp.ones(B)).as_text()
+    shapes = {tuple(int(d) for d in dims.split("x") if d)
+              for dims in re.findall(r"tensor<((?:\d+x)+)f32>", text)}
+    assert (3, 5, 2, 128) in shapes and (3 * 53, 2, 4, 16) in shapes
+    assert not [s for s in shapes if 53 in s], sorted(
+        s for s in shapes if 53 in s)
 
 
 def test_a_preempted_request_re_prefills_to_the_same_tokens(tiny):
