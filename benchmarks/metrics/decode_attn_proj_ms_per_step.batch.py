@@ -1,0 +1,12 @@
+"""Device time of the decode-window program (``jit_orion_decode_window``) in
+attention's norm, its q/k/v projections with their per-head norms, rotary
+and gates, and its output projection (``attention/norm``, ``attention/qkv``,
+``attention/out``), per token step (the denominator of
+``decode_step_ms.batch``), from the instructions' scope paths in the trace
+(``benchmarks/trace/scopes.py``). A program without named programs and parts
+reads nothing."""
+from benchmarks.trace import scopes
+
+
+def read(obs):
+    return scopes.decode_ms_per_step(obs, scopes.DECODE_ATTN_PROJ)

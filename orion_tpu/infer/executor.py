@@ -34,9 +34,21 @@ from orion_tpu.infer.runner import (
     prefill_step,
     verify_step,
 )
+from orion_tpu.obs.parts import PROGRAM_NAMES
 from orion_tpu.runtime.fault import DispatchFault, InjectedFault
 
 log = logging.getLogger("orion_tpu.infer")
+
+
+def named_program(fn, stem: str, **kw):
+    """``partial(fn, **kw)`` under a name: ``jax.jit`` names a program after
+    the function it is given (a ``partial`` has no name of its own, and its
+    module is ``jit__unknown``), so this one's module is ``jit_orion_<stem>``
+    in the compiled text and in a device profile. Still a ``partial``: its
+    parameters keep the names ``fn`` gives them."""
+    program = partial(fn, **kw)
+    program.__name__ = program.__qualname__ = PROGRAM_NAMES[stem]
+    return program
 
 
 class DispatchExecutor:
@@ -81,7 +93,7 @@ class DispatchExecutor:
         fn = self.PROGRAM_FNS[stem]
         if stem == "fold":
             # No weights, no sampling: (cache, slot, page-table row).
-            return jax.jit(partial(fn, cfg=mcfg, mesh=mesh),
+            return jax.jit(named_program(fn, stem, cfg=mcfg, mesh=mesh),
                            donate_argnums=(0,))
         if stem == "prefill":
             kw: dict[str, Any] = dict(cfg=mcfg, mesh=mesh)
@@ -102,7 +114,7 @@ class DispatchExecutor:
                 top_k=icfg.top_k,
                 top_p=icfg.top_p,
             )
-        program = jax.jit(partial(fn, **kw), donate_argnums=(1,))
+        program = jax.jit(named_program(fn, stem, **kw), donate_argnums=(1,))
         if stem == "prefill" and mcfg.is_retention:
             # Which state row each row of a burst owns is the engine's to
             # say; a caller that says nothing (a warm-up) gets the scratch

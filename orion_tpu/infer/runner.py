@@ -60,6 +60,10 @@ from orion_tpu.ops import attention
 from orion_tpu.ops.attention import attention_xla
 
 Cache = dict[str, jax.Array]
+# The part (``orion_tpu.obs.parts``) of a cache write that a layer's ``attend``
+# hands back for its caller to trace behind the feed-forward, outside the
+# layer's own ``attention`` scope.
+_CACHE_PART = "attention/cache"
 # A kernel with a fused write hands the pools back in this order (the scale
 # pools only where the cache is int8).
 _POOLS = ("k", "v", "k_scale", "v_scale")
@@ -238,35 +242,38 @@ def _dense_layer(
                 paged_flash_prefill,
             )
 
-            out, *pools = paged_flash_prefill(
-                q, cc["k"], cc["v"], ctx["walk"], ctx["prefix_lens"],
-                ctx["lengths"], k, v,
-                n_prefix_pages=P_pre, layer_base=l * NP,
-                logit_softcap=cfg.attn_logit_softcap,
-                window=win, interpret=ctx["interpret"],
-                k_scale=cc.get("k_scale"), v_scale=cc.get("v_scale"),
-                mesh=mesh,
-            )
+            with jax.named_scope("kernel"):
+                out, *pools = paged_flash_prefill(
+                    q, cc["k"], cc["v"], ctx["walk"], ctx["prefix_lens"],
+                    ctx["lengths"], k, v,
+                    n_prefix_pages=P_pre, layer_base=l * NP,
+                    logit_softcap=cfg.attn_logit_softcap,
+                    window=win, interpret=ctx["interpret"],
+                    k_scale=cc.get("k_scale"), v_scale=cc.get("v_scale"),
+                    mesh=mesh,
+                )
             return out, lambda: dict(zip(_POOLS, pools))
         kv, kv_seg, kv_at = (k, v), seg, {}
         if P_pre:
             # Gather this layer's cached prefix K/V pages from the pool
             # and attend tail queries over prefix + tail. [Nb, P_pre] page
             # rows -> [Nb, P_pre*psz, K, H] (heads-major pages).
-            k_pre, v_pre = _gather_context(
-                cc, l * NP + ctx["prefix_pages"], psz, k.dtype)
-            kv = (jnp.concatenate([k_pre, k], axis=1),
-                  jnp.concatenate([v_pre, v], axis=1))
+            with jax.named_scope("cache"):
+                k_pre, v_pre = _gather_context(
+                    cc, l * NP + ctx["prefix_pages"], psz, k.dtype)
+                kv = (jnp.concatenate([k_pre, k], axis=1),
+                      jnp.concatenate([v_pre, v], axis=1))
             kv_seg = ctx["kv_seg"]
             kv_at = dict(q_positions=positions, kv_positions=ctx["kv_pos"])
-        out = attention(
-            q, *kv, causal=True,
-            q_segment_ids=seg, kv_segment_ids=kv_seg, seg_pad_zero=True,
-            **kv_at,
-            logit_softcap=cfg.attn_logit_softcap, window=win,
-            block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
-            impl=cfg.kernels, mesh=mesh,
-        )
+        with jax.named_scope("kernel"):
+            out = attention(
+                q, *kv, causal=True,
+                q_segment_ids=seg, kv_segment_ids=kv_seg, seg_pad_zero=True,
+                **kv_at,
+                logit_softcap=cfg.attn_logit_softcap, window=win,
+                block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
+                impl=cfg.kernels, mesh=mesh,
+            )
         return out, lambda: _scatter_pages(cc, k, v, l * NP + ctx["pages"])
 
     # Padded positions are not routed: nothing reads their activations
@@ -287,7 +294,8 @@ def _dense_layer(
     x, _, written = block(
         x, bp, cfg, positions, attend, kind=_kind(cfg, j), mesh=mesh,
         ffn_mesh=mesh, valid=valid, layer_stack=stack, ffn_tap=tap)
-    cc = {**cc, **written()}
+    with jax.named_scope(_CACHE_PART):
+        cc = {**cc, **written()}
     if held is not None:
         cc[HELD_ROWS] = held
     return x, cc
@@ -353,10 +361,11 @@ def _prefill_logits(
 
     Gathers before the LM head so the vocab matmul is [Nb, 1, V], not
     [Nb, S_pad, V]."""
-    idx = (lengths - 1).astype(jnp.int32)[:, None, None]
-    x_last = jnp.take_along_axis(
-        x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[-1])), axis=1
-    )
+    with jax.named_scope("unembed"):
+        idx = (lengths - 1).astype(jnp.int32)[:, None, None]
+        x_last = jnp.take_along_axis(
+            x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[-1])), axis=1
+        )
     return unembed(params, x_last, cfg, mesh)[:, 0]
 
 
@@ -715,53 +724,59 @@ def _paged_layer(
         if ctx["use_pallas"]:
             from orion_tpu.ops.pallas.paged_attention import attend as kernel
 
-            out, *pools = kernel(
-                q, cc["k"], cc["v"], page_table, ctx["start"], ctx["k_lens"],
-                layer_base=l * NP,
-                k_new=k, v_new=v,
-                logit_softcap=cfg.attn_logit_softcap,
-                window=win,
-                interpret=ctx["interpret"],
-                k_scale=cc.get("k_scale"),
-                v_scale=cc.get("v_scale"),
-                tree_mask=ctx["tree_mask"],
-                depths=ctx["depths"],
-                mesh=mesh,
-                name=ctx["name"],
-            )
+            with jax.named_scope("kernel"):
+                out, *pools = kernel(
+                    q, cc["k"], cc["v"], page_table, ctx["start"],
+                    ctx["k_lens"],
+                    layer_base=l * NP,
+                    k_new=k, v_new=v,
+                    logit_softcap=cfg.attn_logit_softcap,
+                    window=win,
+                    interpret=ctx["interpret"],
+                    k_scale=cc.get("k_scale"),
+                    v_scale=cc.get("v_scale"),
+                    tree_mask=ctx["tree_mask"],
+                    depths=ctx["depths"],
+                    mesh=mesh,
+                    name=ctx["name"],
+                )
             new.update(zip(_POOLS, pools))
             return out, new
-        rows, offset = l * NP + ctx["page_idx"], ctx["offset"]    # [B, W]
         written = {"k": k, "v": v}
-        if "k_scale" in cc:
-            from orion_tpu.infer.kv_cache import quantize_kv
+        with jax.named_scope("cache"):
+            rows, offset = l * NP + ctx["page_idx"], ctx["offset"]  # [B, W]
+            if "k_scale" in cc:
+                from orion_tpu.infer.kv_cache import quantize_kv
 
-            written["k"], written["k_scale"] = quantize_kv(k)
-            written["v"], written["v_scale"] = quantize_kv(v)
-        for name, val in written.items():   # [B,W,K,H] (scales [B,W,K])
-            new[name] = cc[name].at[rows, :, offset].set(val)
-        # Padded-context gather (the just-written K/V reads back out of the
-        # pool, so under kv_quant each query attends its own dispatch's
-        # tokens DEQUANTIZED — what a later step reads of them).
-        k_ctx, v_ctx = _gather_context(new, l * NP + page_table, psz, q.dtype)
-        kv_mask = ctx["kv_base_mask"]
-        if win is not None:
-            wmask = (
-                ctx["kv_arange"] >= (ctx["q_pos"] - win + 1)[:, :, None]
+                written["k"], written["k_scale"] = quantize_kv(k)
+                written["v"], written["v_scale"] = quantize_kv(v)
+            for name, val in written.items():   # [B,W,K,H] (scales [B,W,K])
+                new[name] = cc[name].at[rows, :, offset].set(val)
+            # Padded-context gather (the just-written K/V reads back out of
+            # the pool, so under kv_quant each query attends its own
+            # dispatch's tokens DEQUANTIZED — what a later step reads of
+            # them).
+            k_ctx, v_ctx = _gather_context(
+                new, l * NP + page_table, psz, q.dtype)
+        with jax.named_scope("kernel"):
+            kv_mask = ctx["kv_base_mask"]
+            if win is not None:
+                wmask = (
+                    ctx["kv_arange"] >= (ctx["q_pos"] - win + 1)[:, :, None]
+                )
+                if ctx["tree_mask"] is not None:
+                    # Among the W new columns the window measures DEPTH
+                    # distance (logical positions), not pool-slot distance —
+                    # chain-degenerate trees make the two identical.
+                    dmask = ctx["slot_depth"] >= (
+                        ctx["depths"].astype(jnp.int32) - win + 1
+                    )[:, :, None]
+                    wmask = jnp.where(ctx["in_slots"], dmask, wmask)
+                kv_mask = kv_mask & wmask
+            out = attention_xla(
+                q, k_ctx, v_ctx, causal=False, mask=kv_mask,
+                logit_softcap=cfg.attn_logit_softcap,
             )
-            if ctx["tree_mask"] is not None:
-                # Among the W new columns the window measures DEPTH
-                # distance (logical positions), not pool-slot distance —
-                # chain-degenerate trees make the two identical.
-                dmask = ctx["slot_depth"] >= (
-                    ctx["depths"].astype(jnp.int32) - win + 1
-                )[:, :, None]
-                wmask = jnp.where(ctx["in_slots"], dmask, wmask)
-            kv_mask = kv_mask & wmask
-        out = attention_xla(
-            q, k_ctx, v_ctx, causal=False, mask=kv_mask,
-            logit_softcap=cfg.attn_logit_softcap,
-        )
         return out, new
 
     x, _, cc = block(
@@ -1069,10 +1084,11 @@ def _latent_prefill_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
     pool = cc[LATENT]
 
     def attend(q, row, wkv_b):
-        out = latent_attention(
-            q, row, wkv_b, cfg, q_segment_ids=seg, kv_segment_ids=seg,
-            seg_pad_zero=True, block_q=cfg.attn_block_q,
-            block_kv=cfg.attn_block_kv, impl=cfg.kernels, mesh=mesh)
+        with jax.named_scope("kernel"):
+            out = latent_attention(
+                q, row, wkv_b, cfg, q_segment_ids=seg, kv_segment_ids=seg,
+                seg_pad_zero=True, block_q=cfg.attn_block_q,
+                block_kv=cfg.attn_block_kv, impl=cfg.kernels, mesh=mesh)
 
         def written():
             Nb, S, w = row.shape
@@ -1086,7 +1102,8 @@ def _latent_prefill_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
     x, _, written = block(
         x, bp, cfg, ctx["positions"], attend, kind=_kind(cfg, j), mesh=mesh,
         ffn_mesh=mesh, valid=valid, layer_stack=stack)
-    return x, {**cc, **written()}
+    with jax.named_scope(_CACHE_PART):
+        return x, {**cc, **written()}
 
 
 def _latent_ctx(cache: Cache, pos: jax.Array, page_table: jax.Array,
@@ -1121,9 +1138,10 @@ def _latent_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
 
     def attend(q, row, wkv_b):
         pad = pool.shape[-1] - row.shape[-1]
-        q_lat = jnp.pad(latent_absorb(q, wkv_b, cfg)[:, 0],
-                        ((0, 0), (0, 0), (0, pad)))           # [B, N, Wd]
-        new = jnp.pad(row[:, 0], ((0, 0), (0, pad))).astype(pool.dtype)
+        with jax.named_scope("kernel"):
+            q_lat = jnp.pad(latent_absorb(q, wkv_b, cfg)[:, 0],
+                            ((0, 0), (0, 0), (0, pad)))       # [B, N, Wd]
+            new = jnp.pad(row[:, 0], ((0, 0), (0, pad))).astype(pool.dtype)
         if ctx["use_pallas"]:
             if mesh is not None:
                 raise ValueError(
@@ -1132,21 +1150,26 @@ def _latent_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
                 latent_paged_attention,
             )
 
-            o_lat, written = latent_paged_attention(
-                q_lat, pool, page_table, ctx["at"], new,
-                layer_base=l * NP, value_width=R, scale=scale,
-                interpret=ctx["interpret"])
+            with jax.named_scope("kernel"):
+                o_lat, written = latent_paged_attention(
+                    q_lat, pool, page_table, ctx["at"], new,
+                    layer_base=l * NP, value_width=R, scale=scale,
+                    interpret=ctx["interpret"])
         else:
-            written = pool.at[l * NP + ctx["page"], 0, ctx["offset"]].set(new)
-            rows = written[l * NP + page_table][:, :, 0]      # [B, P, psz, Wd]
-            rows = rows.reshape(rows.shape[0], -1, rows.shape[-1])
-            z = jnp.einsum("bnw,btw->bnt", q_lat, rows,
-                           preferred_element_type=jnp.float32) * scale
-            z = jnp.where(ctx["live"][:, None, :], z, -jnp.inf)
-            o_lat = jnp.einsum(
-                "bnt,btr->bnr", jax.nn.softmax(z, axis=-1).astype(q.dtype),
-                rows[..., :R])
-        out = latent_unabsorb(o_lat[:, None].astype(q.dtype), wkv_b, cfg)
+            with jax.named_scope("cache"):
+                written = pool.at[
+                    l * NP + ctx["page"], 0, ctx["offset"]].set(new)
+                rows = written[l * NP + page_table][:, :, 0]  # [B, P, psz, Wd]
+                rows = rows.reshape(rows.shape[0], -1, rows.shape[-1])
+            with jax.named_scope("kernel"):
+                z = jnp.einsum("bnw,btw->bnt", q_lat, rows,
+                               preferred_element_type=jnp.float32) * scale
+                z = jnp.where(ctx["live"][:, None, :], z, -jnp.inf)
+                o_lat = jnp.einsum(
+                    "bnt,btr->bnr",
+                    jax.nn.softmax(z, axis=-1).astype(q.dtype), rows[..., :R])
+        with jax.named_scope("kernel"):
+            out = latent_unabsorb(o_lat[:, None].astype(q.dtype), wkv_b, cfg)
         return out, {**cc, LATENT: written}
 
     x, _, cc = block(x, bp, cfg, ctx["positions"], attend,
@@ -1208,8 +1231,10 @@ def _retained_prefill(params, cache, tokens, lengths, pages, state_rows,
         x, cc = carry
 
         def attend(q, k, v, log_g):
-            y, (S, z) = power_retention(
-                q, k, v, log_g, lengths=lengths, chunk=C, impl=cfg.kernels)
+            with jax.named_scope("kernel"):
+                y, (S, z) = power_retention(
+                    q, k, v, log_g, lengths=lengths, chunk=C,
+                    impl=cfg.kernels)
 
             def written():
                 new = _scatter_pages(cc, k, v, l * NP + pages)
@@ -1228,11 +1253,13 @@ def _retained_prefill(params, cache, tokens, lengths, pages, state_rows,
         x, _, written = block(
             x, bp, cfg, positions, attend, kind=_kind(cfg, j), mesh=mesh,
             ffn_mesh=mesh, valid=valid)
-        return x, {**cc, **written()}
+        with jax.named_scope(_CACHE_PART):
+            return x, {**cc, **written()}
 
     x = embed(params, tokens, positions, cfg)
     x, cache = _scan_layers(params, cfg, body, (x, dict(cache)))
-    cache["state_len"] = cache["state_len"].at[state_rows].set(folded)
+    with jax.named_scope(_CACHE_PART):
+        cache["state_len"] = cache["state_len"].at[state_rows].set(folded)
     return _prefill_logits(params, x, lengths, cfg, mesh), cache
 
 
@@ -1282,32 +1309,36 @@ def _retained_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
     def attend(q, k, v, log_g):
         g = cc["g"]
         at_l = (l, 1, 0, 0)                     # the slots' rows of layer l
-        bt = jax.lax.dynamic_slice(g, at_l, (1, B, *g.shape[2:]))[0]
-        b_new = log_g[:, 0] + jnp.where(
-            ctx["prev_col"][:, None, :], bt, 0.0).sum(-1)      # [B, K]
-        bt = jnp.where(ctx["new_col"][:, None, :], b_new[:, :, None], bt)
-        # A tail spans two chunks at most: positions of the second add the
-        # whole first chunk's sum (its last position's entry).
-        first = bt[:, :, C - 1]                                # [B, K]
-        c_tail = bt + jnp.where(ctx["second"][:, None, :],
-                                first[:, :, None], 0.0)
-        c_tail = jnp.where(ctx["live"][:, None, :], c_tail, BIG)
-        c_q = b_new + jnp.where((at >= F + C)[:, None], first, 0.0)
-        kw = dict(layer_base=l * NP, state_base=l * ctx["n_rows"])
-        args = (q[:, 0], k[:, 0], v[:, 0], c_q, c_tail, cc["k"], cc["v"],
-                cc["state"], cc["state_z"], ctx["page_table"], F, pos)
-        if ctx["use_pallas"]:
-            if mesh is not None:
-                raise ValueError("the retention kernels run on one device")
-            from orion_tpu.ops.pallas.retention import retention_decode
+        with jax.named_scope("cache"):
+            bt = jax.lax.dynamic_slice(g, at_l, (1, B, *g.shape[2:]))[0]
+            b_new = log_g[:, 0] + jnp.where(
+                ctx["prev_col"][:, None, :], bt, 0.0).sum(-1)  # [B, K]
+            bt = jnp.where(ctx["new_col"][:, None, :], b_new[:, :, None], bt)
+        with jax.named_scope("kernel"):
+            # A tail spans two chunks at most: positions of the second add
+            # the whole first chunk's sum (its last position's entry).
+            first = bt[:, :, C - 1]                            # [B, K]
+            c_tail = bt + jnp.where(ctx["second"][:, None, :],
+                                    first[:, :, None], 0.0)
+            c_tail = jnp.where(ctx["live"][:, None, :], c_tail, BIG)
+            c_q = b_new + jnp.where((at >= F + C)[:, None], first, 0.0)
+            kw = dict(layer_base=l * NP, state_base=l * ctx["n_rows"])
+            args = (q[:, 0], k[:, 0], v[:, 0], c_q, c_tail, cc["k"], cc["v"],
+                    cc["state"], cc["state_z"], ctx["page_table"], F, pos)
+            if ctx["use_pallas"]:
+                if mesh is not None:
+                    raise ValueError(
+                        "the retention kernels run on one device")
+                from orion_tpu.ops.pallas.retention import retention_decode
 
-            y, kp, vp = retention_decode(
-                *args, interpret=ctx["interpret"], **kw)
-        else:
-            y, kp, vp = retention_decode_xla(*args, **kw)
-        return y[:, None], {**cc, "k": kp, "v": vp,
-                            "g": jax.lax.dynamic_update_slice(
-                                g, bt[None], at_l)}
+                y, kp, vp = retention_decode(
+                    *args, interpret=ctx["interpret"], **kw)
+            else:
+                y, kp, vp = retention_decode_xla(*args, **kw)
+            y = y[:, None]
+        with jax.named_scope("cache"):
+            g = jax.lax.dynamic_update_slice(g, bt[None], at_l)
+        return y, {**cc, "k": kp, "v": vp, "g": g}
 
     x, _, cc = block(x, bp, cfg, ctx["positions"], attend,
                      kind=_kind(cfg, j), mesh=mesh)
@@ -1331,12 +1362,14 @@ def fold_step(cache: Cache, slot: jax.Array, page_row: jax.Array, *,
     psz, NP = page_geometry(cache, cfg.n_layers)
     n_rows = cache["state_len"].shape[0]
     C, P = _chunk(cfg), page_row.shape[0]
-    F = cache["state_len"][slot + 1]
-    pages = page_row[jnp.minimum(F // psz + jnp.arange(C // psz), P - 1)]
-    gates = jax.lax.dynamic_index_in_dim(
-        cache["g"], slot + 1, axis=1, keepdims=False)          # [L, K, T]
     use_pallas, interpret = resolve_impl(cfg.kernels)
+    with jax.named_scope(_CACHE_PART):
+        F = cache["state_len"][slot + 1]
+        pages = page_row[jnp.minimum(F // psz + jnp.arange(C // psz), P - 1)]
+        gates = jax.lax.dynamic_index_in_dim(
+            cache["g"], slot + 1, axis=1, keepdims=False)      # [L, K, T]
 
+    @jax.named_scope(_CACHE_PART)
     def chunk(pool, rows):      # [n, K, psz, ...] -> [K, C, ...]
         x = jnp.moveaxis(pool[rows], 1, 0)
         return x.reshape(x.shape[0], C, *x.shape[3:])
@@ -1347,17 +1380,19 @@ def fold_step(cache: Cache, slot: jax.Array, page_row: jax.Array, *,
         rows = l * NP + pages
         args = (state, z, chunk(cache["k"], rows), chunk(cache["v"], rows),
                 b, l * n_rows + slot + 1)
-        if use_pallas:
-            from orion_tpu.ops.pallas.retention import retention_fold
+        with jax.named_scope("attention/kernel"):
+            if use_pallas:
+                from orion_tpu.ops.pallas.retention import retention_fold
 
-            return tuple(retention_fold(*args, interpret=interpret)), None
-        return retention_fold_xla(*args), None
+                return tuple(retention_fold(*args, interpret=interpret)), None
+            return retention_fold_xla(*args), None
 
     (state, z), _ = jax.lax.scan(
         body, (cache["state"], cache["state_z"]),
         (jnp.arange(cfg.n_layers), gates[:, :, :C]))
-    moved = jnp.pad(gates[:, :, C:], ((0, 0), (0, 0), (0, C)))
-    return {**cache, "state": state, "state_z": z,
-            "g": jax.lax.dynamic_update_index_in_dim(
-                cache["g"], moved, slot + 1, 1),
-            "state_len": cache["state_len"].at[slot + 1].add(C)}
+    with jax.named_scope(_CACHE_PART):
+        moved = jnp.pad(gates[:, :, C:], ((0, 0), (0, 0), (0, C)))
+        return {**cache, "state": state, "state_z": z,
+                "g": jax.lax.dynamic_update_index_in_dim(
+                    cache["g"], moved, slot + 1, 1),
+                "state_len": cache["state_len"].at[slot + 1].add(C)}

@@ -57,6 +57,7 @@ def _apply_mask(logits: jax.Array, legal_mask) -> jax.Array:
     return jnp.where(legal_mask, logits.astype(jnp.float32), NEG_INF)
 
 
+@jax.named_scope("sample")
 def sample(
     logits: jax.Array,
     key: jax.Array,
@@ -187,6 +188,7 @@ def filter_logits(
     return scaled
 
 
+@jax.named_scope("sample")
 def spec_verify_sample(
     logits: jax.Array,       # [B, W, V] verify logits, position-major
     draft_next: jax.Array,   # [B, W] i32: the draft token each position is
@@ -267,6 +269,7 @@ def spec_verify_sample(
     return accept.reshape(B, W), alt.reshape(B, W)
 
 
+@jax.named_scope("sample")
 def spec_verify_sample_tree(
     logits: jax.Array,       # [B, W, V] verify logits, column-major
     tokens: jax.Array,       # [B, W] i32: col 0 the pending token, cols

@@ -75,6 +75,7 @@ def _held(idx: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
     return local, (local >= 0) & (local < cfg.n_experts)
 
 
+@jax.named_scope("router")
 def _router_topk(
     x: jax.Array, router_w: jax.Array, cfg: ModelConfig,
     bias: Optional[jax.Array] = None,
@@ -121,6 +122,7 @@ def _router_topk(
     return probs, gate, idx
 
 
+@jax.named_scope("router")
 def _aux_stats(
     probs: jax.Array, idx: jax.Array, cfg: ModelConfig
 ) -> tuple[jax.Array, jax.Array]:
@@ -136,6 +138,7 @@ def _aux_stats(
     return frac, mean_prob
 
 
+@jax.named_scope("router")
 def _aux_loss(probs: jax.Array, idx: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Switch eq. 4 load-balance loss: E * sum_e fraction_e * mean-prob_e."""
     frac, mean_prob = _aux_stats(probs, idx, cfg)
@@ -153,20 +156,21 @@ def route(
 
     probs, gate, idx = _router_topk(x, router_w, cfg, bias)
 
-    # Slot-major priority: all slot-0 (top-1) choices claim capacity before
-    # any slot-1 choice, matching Switch-Transformer semantics.
-    onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)  # [B,S,k,E]
-    prio = onehot.transpose(0, 2, 1, 3).reshape(B, k * S, E)  # [B,k*S,E]
-    pos = jnp.cumsum(prio, axis=1) - prio  # position within expert
-    keep = (pos < C).astype(jnp.float32) * prio
-    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), C, dtype=jnp.float32)
-    disp_flat = keep[..., None] * pos_oh  # [B,k*S,E,C]
-    disp = disp_flat.reshape(B, k, S, E, C).sum(axis=1)  # [B,S,E,C]
+    with jax.named_scope("dispatch"):
+        # Slot-major priority: all slot-0 (top-1) choices claim capacity
+        # before any slot-1 choice, matching Switch-Transformer semantics.
+        onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)  # [B,S,k,E]
+        prio = onehot.transpose(0, 2, 1, 3).reshape(B, k * S, E)  # [B,k*S,E]
+        pos = jnp.cumsum(prio, axis=1) - prio  # position within expert
+        keep = (pos < C).astype(jnp.float32) * prio
+        pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), C, dtype=jnp.float32)
+        disp_flat = keep[..., None] * pos_oh  # [B,k*S,E,C]
+        disp = disp_flat.reshape(B, k, S, E, C).sum(axis=1)  # [B,S,E,C]
 
-    gate_slot = gate.transpose(0, 2, 1).reshape(B, k, S)[..., None, None]
-    comb = (
-        disp_flat.reshape(B, k, S, E, C) * gate_slot
-    ).sum(axis=1)  # [B,S,E,C]
+        gate_slot = gate.transpose(0, 2, 1).reshape(B, k, S)[..., None, None]
+        comb = (
+            disp_flat.reshape(B, k, S, E, C) * gate_slot
+        ).sum(axis=1)  # [B,S,E,C]
 
     return disp, comb, _aux_loss(probs, idx, cfg)
 
@@ -183,13 +187,14 @@ def moe_mlp(
     dtype = x.dtype
     disp, comb, aux = route(
         x, params["router"], cfg, params.get("router_bias"))
-    disp = disp.astype(dtype)
-    comb = comb.astype(dtype)
-
-    # Dispatch: [B,S,E,C] x [B,S,D] -> (E,B,C,D) capacity buckets.
-    xin = jnp.einsum("bsec,bsd->ebcd", disp, x)
+    with jax.named_scope("dispatch"):
+        disp = disp.astype(dtype)
+        comb = comb.astype(dtype)
+        # Dispatch: [B,S,E,C] x [B,S,D] -> (E,B,C,D) capacity buckets.
+        xin = jnp.einsum("bsec,bsd->ebcd", disp, x)
     out = _expert_ffn(xin, params, cfg)
-    y = jnp.einsum("bsec,ebcd->bsd", comb, out)
+    with jax.named_scope("dispatch"):
+        y = jnp.einsum("bsec,ebcd->bsd", comb, out)
     return y, aux.astype(jnp.float32)
 
 
@@ -213,16 +218,17 @@ def route_indices(
 
     probs, gate, idx = _router_topk(x, router_w, cfg, bias)
 
-    # Slot-major assignment stream [B, k*S]: all slot-0 choices precede any
-    # slot-1 choice (matches route()'s prio layout).
-    idx_km = idx.transpose(0, 2, 1).reshape(B, k * S)
-    onehot = jax.nn.one_hot(idx_km, E, dtype=jnp.int32)      # [B, kS, E]
-    pos_all = jnp.cumsum(onehot, axis=1) - onehot            # count before me
-    pos_km = jnp.take_along_axis(
-        pos_all, idx_km[..., None], axis=-1
-    )[..., 0]                                                # [B, kS]
-    pos = pos_km.reshape(B, k, S).transpose(0, 2, 1)         # [B, S, k]
-    keep = pos < C
+    with jax.named_scope("dispatch"):
+        # Slot-major assignment stream [B, k*S]: all slot-0 choices precede
+        # any slot-1 choice (matches route()'s prio layout).
+        idx_km = idx.transpose(0, 2, 1).reshape(B, k * S)
+        onehot = jax.nn.one_hot(idx_km, E, dtype=jnp.int32)  # [B, kS, E]
+        pos_all = jnp.cumsum(onehot, axis=1) - onehot        # count before me
+        pos_km = jnp.take_along_axis(
+            pos_all, idx_km[..., None], axis=-1
+        )[..., 0]                                            # [B, kS]
+        pos = pos_km.reshape(B, k, S).transpose(0, 2, 1)     # [B, S, k]
+        keep = pos < C
     # Sanitizer hook (SURVEY.md §6): routing indices feed scatter/gather —
     # and, on the a2a path, a cross-device all_to_all — INSIDE shard_map
     # regions where checkify cannot reach; an OOB here otherwise surfaces
@@ -248,6 +254,7 @@ def route_indices(
     return idx, gate, pos, keep, _aux_stats(probs, idx, cfg)
 
 
+@jax.named_scope("experts")
 def _expert_ffn(xin: jax.Array, params: dict[str, Any], cfg: ModelConfig
                 ) -> jax.Array:
     """Batched expert feed-forward on capacity buckets. xin: [E, B, C, D]."""
@@ -262,6 +269,7 @@ def _expert_ffn(xin: jax.Array, params: dict[str, Any], cfg: ModelConfig
     return jnp.einsum("ebcf,efd->ebcd", h, params["w_out"])
 
 
+@jax.named_scope("dispatch")
 def _scatter_dispatch(x, idx, pos, keep, E, C):
     """Tokens -> capacity buckets by index. x: [B,S,D] -> [E, B, C, D].
 
@@ -279,6 +287,7 @@ def _scatter_dispatch(x, idx, pos, keep, E, C):
     return xin[:, :, :C].transpose(1, 0, 2, 3)               # [E, B, C, D]
 
 
+@jax.named_scope("dispatch")
 def _gather_combine(out, idx, pos, keep, gate, dtype):
     """Inverse of _scatter_dispatch: per-assignment gather + gate-weighted
     sum over the k slots. out: [E, B, C, D] -> [B, S, D]."""
@@ -307,13 +316,15 @@ def moe_mlp_sorted(
         # Assignments to experts held elsewhere are dropped here (their
         # bucket position was counted per expert, so the held ones keep
         # theirs); the gates stay normalised over all k chosen.
-        idx, held = _held(idx, cfg)
-        keep = keep & held
-        idx = jnp.clip(idx, 0, E - 1)
+        with jax.named_scope("dispatch"):
+            idx, held = _held(idx, cfg)
+            keep = keep & held
+            idx = jnp.clip(idx, 0, E - 1)
     xin = _scatter_dispatch(x, idx, pos, keep, E, C)
     out = _expert_ffn(xin, params, cfg)
     y = _gather_combine(out, idx, pos, keep, gate, dtype)
-    aux = cfg.resolved_router_width * jnp.sum(frac * mp)
+    with jax.named_scope("router"):
+        aux = cfg.resolved_router_width * jnp.sum(frac * mp)
     return y, aux.astype(jnp.float32)
 
 
@@ -379,25 +390,28 @@ def moe_mlp_sorted_a2a(
         xin = _scatter_dispatch(x_loc, idx, pos, keep, E, C_loc)
         # [E, B_loc, C_loc, D] -> [E/ep, B_loc, ep*C_loc, D]: bucket j of
         # expert e travels to e's owner; owners see every slice's bucket.
-        xin = lax.all_to_all(
-            xin, "ep", split_axis=0, concat_axis=2, tiled=True)
+        with jax.named_scope("dispatch"):
+            xin = lax.all_to_all(
+                xin, "ep", split_axis=0, concat_axis=2, tiled=True)
         out = _expert_ffn(xin, p_loc, cfg)
         # The F axis of the expert weights is tp-sharded, so the w_out
         # contraction leaves each tp shard holding a partial sum: reduce
         # over tp BEFORE the inverse a2a (megatron row-parallel pattern).
-        if mesh.shape.get("tp", 1) > 1:
-            out = lax.psum(out, "tp")
-        out = lax.all_to_all(
-            out, "ep", split_axis=2, concat_axis=0, tiled=True)
+        with jax.named_scope("dispatch"):
+            if mesh.shape.get("tp", 1) > 1:
+                out = lax.psum(out, "tp")
+            out = lax.all_to_all(
+                out, "ep", split_axis=2, concat_axis=0, tiled=True)
         y = _gather_combine(out, idx, pos, keep, gate, x_loc.dtype)
         # Combine the aux STATS across equal-sized token/batch shards, then
         # form the bilinear loss — this reproduces the global-token aux
         # exactly (a pmean of per-shard losses would not: the loss is a
         # product of two token means). tp shards carry identical values.
         axes = ("dp", "fsdp", "ep", sp_ax, "tp")
-        frac = lax.pmean(frac, axis_name=axes)
-        mp = lax.pmean(mp, axis_name=axes)
-        aux = E * jnp.sum(frac * mp)
+        with jax.named_scope("router"):
+            frac = lax.pmean(frac, axis_name=axes)
+            mp = lax.pmean(mp, axis_name=axes)
+            aux = E * jnp.sum(frac * mp)
         return y, aux
 
     x_spec = P(batch_axes, (sp_ax, "ep"), None)
@@ -501,27 +515,28 @@ def moe_mlp_grouped(
     probs, gate, idx = _router_topk(
         x, params["router"], cfg, params.get("router_bias"))
 
-    # Assignment a = t*k + j (token t, slot j); invalid ones, and those to
-    # experts held elsewhere, get key E.
-    key, held = (a.reshape(T * k) for a in _held(idx, cfg))
-    gate = gate.reshape(T, k)
-    if valid is not None:
-        held = held & jnp.repeat(valid.reshape(T), k)
     partial = valid is not None or cfg.holds_expert_share
-    if partial:
-        key = jnp.where(held, key, E)
-        gate = gate * held.reshape(T, k).astype(gate.dtype)
-    order = jnp.argsort(key, stable=True)                    # [kT]
-    group_sizes = jnp.sum(
-        key[:, None] == jnp.arange(E, dtype=key.dtype), axis=0,
-        dtype=jnp.int32)                                     # [E]
-    xs = x.reshape(T, D)[order // k]                         # [kT, D]
-    if partial:
-        # Rows behind the last group are never computed (the kernel leaves
-        # them uninitialised); the selects keep what is there out of the
-        # result and out of the cotangents.
-        live = (jnp.arange(T * k) < group_sizes.sum())[:, None]
-        xs = jnp.where(live, xs, 0)
+    with jax.named_scope("dispatch"):
+        # Assignment a = t*k + j (token t, slot j); invalid ones, and those
+        # to experts held elsewhere, get key E.
+        key, held = (a.reshape(T * k) for a in _held(idx, cfg))
+        gate = gate.reshape(T, k)
+        if valid is not None:
+            held = held & jnp.repeat(valid.reshape(T), k)
+        if partial:
+            key = jnp.where(held, key, E)
+            gate = gate * held.reshape(T, k).astype(gate.dtype)
+        order = jnp.argsort(key, stable=True)                # [kT]
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(E, dtype=key.dtype), axis=0,
+            dtype=jnp.int32)                                 # [E]
+        xs = x.reshape(T, D)[order // k]                     # [kT, D]
+        if partial:
+            # Rows behind the last group are never computed (the kernel
+            # leaves them uninitialised); the selects keep what is there out
+            # of the result and out of the cotangents.
+            live = (jnp.arange(T * k) < group_sizes.sum())[:, None]
+            xs = jnp.where(live, xs, 0)
 
     def gmm(a, name, contract_tp=False):
         w, layer = params[name], None
@@ -530,18 +545,20 @@ def moe_mlp_grouped(
         return grouped_matmul(a, w, group_sizes, impl=cfg.kernels, mesh=mesh,
                               contract_tp=contract_tp, layer=layer)
 
-    h_in = gmm(xs, "w_in")
-    if cfg.is_gated_mlp:
-        from orion_tpu.models.transformer import _gate_act
+    with jax.named_scope("experts"):
+        h_in = gmm(xs, "w_in")
+        if cfg.is_gated_mlp:
+            from orion_tpu.models.transformer import _gate_act
 
-        h = _gate_act(cfg)(gmm(xs, "w_gate")) * h_in
-    else:
-        h = jax.nn.gelu(h_in)
-    out = gmm(h, "w_out", contract_tp=True)                  # [kT, D]
-    if partial:
-        out = jnp.where(live, out, 0)
-    out = out[jnp.argsort(order)].reshape(T, k, D)           # un-sort
-    y = jnp.einsum("tkd,tk->td", out, gate.astype(x.dtype))
+            h = _gate_act(cfg)(gmm(xs, "w_gate")) * h_in
+        else:
+            h = jax.nn.gelu(h_in)
+        out = gmm(h, "w_out", contract_tp=True)              # [kT, D]
+    with jax.named_scope("dispatch"):
+        if partial:
+            out = jnp.where(live, out, 0)
+        out = out[jnp.argsort(order)].reshape(T, k, D)       # un-sort
+        y = jnp.einsum("tkd,tk->td", out, gate.astype(x.dtype))
     return y.reshape(B, S, D), _aux_loss(probs, idx, cfg).astype(jnp.float32)
 
 
@@ -576,10 +593,13 @@ def moe_dispatch(
     else:
         y, aux = moe_mlp_sorted(x, params, cfg)
     if "shared" in params:
-        y = y + _shared_expert(x, params["shared"], cfg)
+        shared = _shared_expert(x, params["shared"], cfg)
+        with jax.named_scope("shared"):
+            y = y + shared
     return y, aux
 
 
+@jax.named_scope("shared")
 def _shared_expert(x: jax.Array, p: dict[str, Any], cfg: ModelConfig
                    ) -> jax.Array:
     """The shared expert, a gated feed-forward every token takes, added
@@ -599,7 +619,9 @@ def held_rows(x: jax.Array, router_w: jax.Array, cfg: ModelConfig,
     experts held here (int32 scalar): the rows this layer's expert matmuls
     have to compute. ``valid`` [B, S] as in ``moe_dispatch``. The same
     router head as the dispatch, so XLA computes it once."""
-    _, held = _held(_router_topk(x, router_w, cfg)[2], cfg)
-    if valid is not None:
-        held = held & valid[..., None]
-    return held.sum(dtype=jnp.int32)
+    idx = _router_topk(x, router_w, cfg)[2]
+    with jax.named_scope("router"):
+        _, held = _held(idx, cfg)
+        if valid is not None:
+            held = held & valid[..., None]
+        return held.sum(dtype=jnp.int32)

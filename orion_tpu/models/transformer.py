@@ -354,6 +354,7 @@ def _norm(
     return ops.layernorm(x, scale, p.get("bias"), eps=cfg.norm_eps)
 
 
+@jax.named_scope("embed")
 def embed(
     params: Params, tokens: jax.Array, positions: jax.Array, cfg: ModelConfig
 ) -> jax.Array:
@@ -369,6 +370,7 @@ def embed(
     return x
 
 
+@jax.named_scope("unembed")
 def unembed(
     params: Params, x: jax.Array, cfg: ModelConfig,
     mesh: Optional[Any] = None,
@@ -388,6 +390,7 @@ def unembed(
     return logits
 
 
+@jax.named_scope("qkv")
 def qkv_proj(
     x: jax.Array, p: Params, cfg: ModelConfig, positions: jax.Array,
     mesh: Optional[Any] = None, kind=None,
@@ -438,6 +441,7 @@ def qkv_proj(
     return q, k, v
 
 
+@jax.named_scope("out")
 def out_proj(out: jax.Array, p: Params, cfg: ModelConfig,
              h: Optional[jax.Array] = None) -> jax.Array:
     """Attention output projection. out: [B, S, N, H] -> [B, S, D].
@@ -467,6 +471,7 @@ def _attn_gate(h: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
         jnp.einsum("bsd,dn->bsn", h, _load_w(p["wg"], h.dtype)))
 
 
+@jax.named_scope("qkv")
 def retention_log_gate(h: jax.Array, p: Params) -> jax.Array:
     """[B, S, K] float32: the log of a power-retention layer's gate, one a
     K/V head and position, from the layer's normed input ``h`` (the one
@@ -475,6 +480,7 @@ def retention_log_gate(h: jax.Array, p: Params) -> jax.Array:
         "bsd,dk->bsk", h, _load_w(p["wr"], h.dtype)).astype(jnp.float32))
 
 
+@jax.named_scope("qkv")
 def latent_proj(
     x: jax.Array, p: Params, cfg: ModelConfig, positions: jax.Array,
 ) -> tuple[jax.Array, jax.Array]:
@@ -617,6 +623,7 @@ def _train_attend(
                 "a latent-attention model trains on one sequence shard: "
                 "no sequence axis")
 
+        @jax.named_scope("kernel")
         def expanded(q, row, wkv_b):
             # The expanded form, which JAX differentiates (the absorbed
             # kernel is decode's and has no backward).
@@ -636,6 +643,7 @@ def _train_attend(
                 "segment ids")
         from orion_tpu.ops.retention import fold_chunk, power_retention
 
+        @jax.named_scope("kernel")
         def retain(q, k, v, log_g):
             # The XLA chunked form, which JAX differentiates (the Pallas
             # kernels have no backward).
@@ -646,6 +654,7 @@ def _train_attend(
 
         return retain
 
+    @jax.named_scope("kernel")
     def attend(q, k, v):
         if sp_active:
             from orion_tpu.parallel.sequence import sequence_attention
@@ -697,6 +706,7 @@ def _train_attend(
     return attend
 
 
+@jax.named_scope("dense")
 def _mlp_block(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
     dtype = x.dtype
     h_in = jnp.einsum("bsd,df->bsf", x, _load_w(p["w_in"], dtype))
@@ -756,15 +766,21 @@ def block(
     ``ffn_tap`` is handed the feed-forward's normed input (the runner counts
     a prefill's held-expert rows off it).
 
-    jax.named_scope annotations label the phases in profiler traces
-    (SURVEY.md §6 "Tracing / profiling": xprof shows attention vs mlp time
-    per block without guessing from fused-op names); the checkpoint_name
-    marks are what remat="names" saves, identities in every other program.
+    The jax.named_scope annotations are the vocabulary of parts
+    (``orion_tpu.obs.parts.PARTS``: ``attention/norm``, ``attention/qkv``,
+    ``attention/kernel`` ...): the parents here, the children here, in the
+    projections, in ``models/moe.py`` and in each backend's ``attend``. They
+    reach every compiled instruction's ``op_name``, from which the
+    benchmark's ``trace/scopes.py`` splits a program's device time by part
+    (and XProf's framework-op view shows an operator the same paths). The
+    checkpoint_name marks are what remat="names" saves, identities in every
+    other program.
     """
     with jax.named_scope("attention"):
-        h = checkpoint_name(
-            _norm(x, bp["attn_norm"], cfg, mesh), "attn_norm_out"
-        )
+        with jax.named_scope("norm"):
+            h = checkpoint_name(
+                _norm(x, bp["attn_norm"], cfg, mesh), "attn_norm_out"
+            )
         if cfg.is_latent:
             # Such a layer hands ``attend`` its queries, the ONE row a
             # position a cache keeps, and the matrix that expands the row
@@ -785,19 +801,27 @@ def block(
         out = checkpoint_name(out, "attn_out")
         a = out_proj(out, bp["attn"], cfg, h)
         if cfg.post_norms:
-            a = _norm(a, bp["post_attn_norm"], cfg, mesh)
-        x = x + a
+            with jax.named_scope("norm"):
+                a = _norm(a, bp["post_attn_norm"], cfg, mesh)
+        with jax.named_scope("out"):
+            x = x + a
     with jax.named_scope("mlp_moe"):
-        h2 = checkpoint_name(
-            _norm(x, bp["mlp_norm"], cfg, mesh), "mlp_norm_out"
-        )
+        with jax.named_scope("norm"):
+            h2 = checkpoint_name(
+                _norm(x, bp["mlp_norm"], cfg, mesh), "mlp_norm_out"
+            )
         y, aux = mlp_or_moe(h2, bp, cfg, ffn_mesh, valid, layer_stack)
         if ffn_tap is not None:
             ffn_tap(h2)
         y = checkpoint_name(y, "ffn_out")
         if cfg.post_norms:
-            y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
-    return x + y, aux, state
+            with jax.named_scope("norm"):
+                y = _norm(y, bp["post_mlp_norm"], cfg, mesh)
+        # The residual add goes where its operand came from: an expert
+        # layer's combine, a dense layer's MLP.
+        with jax.named_scope("dispatch" if "moe" in bp else "dense"):
+            x = x + y
+    return x, aux, state
 
 
 def scan_layer_plan(blocks: Params, plan, body, carry):
@@ -851,9 +875,7 @@ def forward(
         segment_ids=segment_ids,
         mesh=mesh,
     )
-    with jax.named_scope("unembed"):
-        logits = unembed(params, x, cfg, mesh)
-    return logits, moe_aux
+    return unembed(params, x, cfg, mesh), moe_aux
 
 
 def _hidden_states(
@@ -877,8 +899,7 @@ def _hidden_states(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
 
-    with jax.named_scope("embed"):
-        x = embed(params, tokens, positions, cfg)
+    x = embed(params, tokens, positions, cfg)
 
     def _remat(fn):
         """Wrap a scan/pipeline body in the configured remat policy. The
@@ -1171,8 +1192,7 @@ def loss_fn(
 
     def ce_chunk(carry, xs):
         xc, tc, mc = xs
-        with jax.named_scope("unembed_chunk"):
-            logits = unembed(params, xc, cfg, mesh)  # [B, chunk, V] f32
+        logits = unembed(params, xc, cfg, mesh)  # [B, chunk, V] f32
         logz = jax.scipy.special.logsumexp(logits, axis=-1)
         tgt = _gather_target(logits, tc)
         nll_sum = ((logz - tgt) * mc).sum()

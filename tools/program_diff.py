@@ -9,8 +9,9 @@ line taken out, and compares two such dumps:
 
 ``dump`` writes, per serving cell of BENCHMARK.json, the optimized HLO of
 ``decode_window`` and of the cell's widest and narrowest prefill shape, and
-for ``mistral-7b.train-8k`` the lowered and the optimized text of the donated
-train step. Taken out: op metadata, ``loc(...)``, the compiled text's tables
+for each training cell (the four-chip one on the described 2x2) the lowered
+and the optimized text of the donated train step. Taken out: op metadata
+(scope paths with it), the module's name, ``loc(...)``, the compiled text's tables
 of files / functions / stack frames, and the debug locations inside every
 Mosaic kernel body (each body is replaced by a hash of its text without
 them; a call site's stack is in there).
@@ -39,7 +40,15 @@ import re
 import sys
 from functools import partial
 
-TRAIN_CELL = "mistral-7b.train-8k"
+
+def _jitted(stem, fn, **kw):
+    """What ``infer/executor.jit_program`` jits for ``stem``: the tree's own
+    named partial where it has one (the module is then ``jit_orion_<stem>``),
+    the bare ``partial`` of a tree from before the programs had names."""
+    from orion_tpu.infer import executor
+
+    named = getattr(executor, "named_program", None)
+    return named(fn, stem, **kw) if named else partial(fn, **kw)
 
 
 def _strip(text: str) -> str:
@@ -63,6 +72,10 @@ def _strip(text: str) -> str:
         return m.group(1) + seen[b64]
 
     text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    # The module's name (``HloModule jit_<name>``, ``module @jit_<name>``,
+    # ``jit(<name>)`` in a location): a name is no instruction.
+    text = re.sub(r"^(HloModule |module @)jit_\w+", r"\1jit_", text, flags=re.M)
+    text = re.sub(r"jit\(\w+\)", "jit()", text)
     text = re.sub(r" loc\(.*?\)$|^#loc.*$|loc\(#loc\d+\)", "", text, flags=re.M)
     text = re.sub(r'(body\\22: \\22|"body": ?")([A-Za-z0-9+/=]+)', body, text)
     return re.sub(r"^FileNames\n.*?(?=^\S*%|^ENTRY|^HloModule)", "", text,
@@ -123,10 +136,11 @@ def dump(tree: str, out: str) -> int:
         cache = abstract(jax.eval_shape(lambda: init_cache(mcfg, icfg)))
         i32 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.int32, sharding=one_chip)
         B, W = icfg.max_batch_size, icfg.decode_window
-        decode = jax.jit(partial(
-            runner.decode_window, cfg=mcfg, max_seq_len=icfg.max_seq_len,
-            mesh=None, nan_guard=False, temperature=icfg.temperature,
-            top_k=icfg.top_k, top_p=icfg.top_p), donate_argnums=(1,))
+        decode = jax.jit(_jitted(
+            "decode", runner.decode_window, cfg=mcfg,
+            max_seq_len=icfg.max_seq_len, mesh=None, nan_guard=False,
+            temperature=icfg.temperature, top_k=icfg.top_k,
+            top_p=icfg.top_p), donate_argnums=(1,))
         keys = jax.ShapeDtypeStruct((W,), jax.random.key(0).dtype,
                                     sharding=one_chip)
         save(f"{name}.decode_window.compiled.txt", decode.lower(
@@ -135,8 +149,8 @@ def dump(tree: str, out: str) -> int:
         ).compile().as_text())
         todo = serve.cell_prefill_shapes(cell, icfg)
         size = lambda s: (s[0] * s[1], s[0])
-        prefill = jax.jit(partial(
-            runner.prefill_step, cfg=mcfg, mesh=None,
+        prefill = jax.jit(_jitted(
+            "prefill", runner.prefill_step, cfg=mcfg, mesh=None,
             paged_prefill=icfg.paged_prefill), donate_argnums=(1,))
         for nb, s_pad in sorted({max(todo, key=size), min(todo, key=size)}):
             save(f"{name}.prefill_{nb}x{s_pad}.compiled.txt", prefill.lower(
@@ -168,7 +182,8 @@ def dump(tree: str, out: str) -> int:
     for w in load_benchmark()["workloads"]:
         if load_mix(w["traffic"], BENCH / "traffic")["kind"] == "serve":
             serve_cell(w["name"])
-    train_cell(TRAIN_CELL)
+        else:
+            train_cell(w["name"])
     return 0
 
 
