@@ -657,15 +657,14 @@ def _engine_call(eng, program: str):
     mask = np.zeros(B, bool)
     pt = np.zeros((B, pps), i32)
     sampling = (np.zeros(B, f32), np.zeros(B, i32), np.ones(B, f32))
-    key = jax.random.key(0)
+    key = eng._key
 
     if program in ("decode", "decode_defaults"):
-        common = (
-            eng.params, eng.cache, zB, zB, pt, mask,
-            jax.random.split(key, eng.decode_window),
-        )
+        # The engine's one key; the window's length is static.
+        common = (eng.params, eng.cache, zB, zB, pt, mask, key)
         extra = sampling if program == "decode" else ()
-        return getattr(eng, "_" + program), common + extra, {}
+        return (getattr(eng, "_" + program), common + extra,
+                {"window": eng.decode_window})
 
     if program in ("migrate_gather", "migrate_scatter"):
         # The migration copy envelope (ISSUE 20): pow2-padded page-id
@@ -690,8 +689,11 @@ def _engine_call(eng, program: str):
             np.zeros((nb, S), i32), np.ones(nb, i32),
             np.zeros((nb, S // eng.psz), i32),
             np.zeros(nb, i32), np.zeros((nb, 0), i32),
+            # No state rows (a K/V model); each row's slot, the step's
+            # last tokens and the engine's key.
+            None, np.zeros(nb, i32), zB, key,
         )
-        return eng._prefill, args, {}
+        return eng._prefill.program, args, {}
 
     if program in ("verify", "verify_defaults", "verify_masked"):
         if getattr(eng, "_verify", None) is None:

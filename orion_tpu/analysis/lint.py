@@ -151,6 +151,9 @@ _DISPATCH_SCOPE = {
     "orion_tpu/infer/engine.py": (
         "step", "_decode", "_mixed", "_verify", "_prefill", "_propose",
         "_accept", "_run_dispatch", "_grow_pages", "_roll_window",
+        # The other half of a launched prefill (ISSUE 40): its wait and
+        # the one fetch of its picks.
+        "_finish_prefill",
         # Host-tier copy paths (ISSUE 18): the batched d2h/h2d envelopes
         # run from admission/eviction inside step — their single syncs
         # are the documented one-copy points (justified allows).

@@ -20,6 +20,7 @@ registry gauges, ``prefix_match_tokens``) and nothing deeper.
 from __future__ import annotations
 
 import collections
+import dataclasses
 import itertools
 import logging
 import time
@@ -70,6 +71,20 @@ from orion_tpu.runtime.fault import (
 )
 
 log = logging.getLogger("orion_tpu.infer")
+
+
+@dataclasses.dataclass
+class _Burst:
+    """A prefill dispatch that was launched and not waited for yet."""
+
+    reqs: list             # the burst's requests, row by row
+    args: tuple            # what was launched: the wait's ladder runs it again
+    logits: jax.Array      # as launched, perhaps still running (its cache
+    #                        is the engine's, and the next program's)
+    picked: bool           # the first tokens are the program's greedy picks
+    key: jax.Array         # the engine's key before the launch
+    ends: frozenset        # rids whose budget the host knows to end at
+    #                        their first token: no decode window takes them
 
 
 @lru_cache(maxsize=None)
@@ -367,7 +382,16 @@ class InferenceEngine:
         self._just_finished: list[Request] = []
         self._rid = itertools.count()
         self._admit_seq = itertools.count()
-        self._key = jax.random.key(seed)
+        # The PRNG stream: one key, on the device. A sampling event splits
+        # it once; the decode window and a greedy prefill do so inside
+        # their programs (key in, next key out), every other path eagerly.
+        # The key's raw data (the same stream as the typed key's): a typed
+        # key that crosses a program's boundary lowers with a custom call
+        # around it, which a reader of the program's text would have to
+        # tell from a kernel.
+        self._key = jax.random.PRNGKey(seed)
+        # The step's prefill between its launch and its wait (_Burst).
+        self._burst: Optional[_Burst] = None
         self.preemptions = 0
         # Page-management window: with interleaved local/global layers
         # (model.layer_kinds) the GLOBAL layers read the whole
@@ -535,7 +559,7 @@ class InferenceEngine:
         # must not advance the engine PRNG stream (sampled chunked-vs-
         # unchunked equivalence relies on one split per SAMPLING event,
         # not per dispatch).
-        self._null_key = jax.random.key(0)
+        self._null_key = jax.random.PRNGKey(0)
 
         # Speculative decoding (inference.speculative): host-side n-gram
         # proposer (infer/spec_decode.py) + single-dispatch batched
@@ -862,8 +886,10 @@ class InferenceEngine:
         envelope (injection points, XLA-fallback retry ladder with
         ``inference.dispatch_retries`` jittered-backoff attempts); raises
         DispatchFault when every path is exhausted — the engine fails the
-        step, not the process."""
-        return self._executor.run(path, name, *args, **kwargs)
+        step, not the process. Launch and wait, back to back: only the
+        plain step queues anything between the two (step)."""
+        out = self._executor.run(path, name, *args, **kwargs)
+        return self._executor.wait(path, name, out, *args, **kwargs)
 
     def _note_spec_fault(self, e: Exception) -> None:
         """Degradation ladder rung 2: count a verify-path PRIMARY dispatch
@@ -1247,6 +1273,24 @@ class InferenceEngine:
         (``self.decode_window`` fused token steps, one host round-trip)
         for all active slots; returns the requests that finished.
 
+        A plain step (no chunked, mixed, speculative or verify dispatch)
+        queues its programs on the device before it waits for any: the
+        prefill is LAUNCHED (``prefill/run``: uploads and launch; a greedy
+        burst's first tokens are picked inside the program and scattered
+        into the step's ``last_token`` there), the decode window is built
+        while it runs (``decode/build``; a retention model's folds are
+        launched without a wait) and LAUNCHED behind it on the prefill's
+        own ``last_token`` and key (``decode/run``), then the host WAITS
+        for the prefill (``prefill/run`` again), fetches the picks and
+        emits the first tokens (``prefill/sample``), and WAITS for the
+        window (``decode/run`` again) and fetches its ``[W, B]`` tokens
+        (``decode/fetch``). The step keeps the older order, each program
+        waited for before the next is built, where it can see that it has
+        to: a burst that samples or is constrained (the host picks from
+        the logits), more than one prefill dispatch a step, or a window
+        whose pages the free list cannot provision (reclaiming might
+        re-queue a request whose first token is not on the host yet).
+
         The step is one ``orion/step`` phase whose children (reap, admit,
         prefill/*, decode/*, ...) book their host time into ``timing``
         (see reset_timing and _PHASE_KEYS): the split between waiting on
@@ -1295,6 +1339,14 @@ class InferenceEngine:
                 # re-raise after max_step_faults consecutive losses.
                 if isinstance(e, MemoryError):
                     self.robust.pool_faults += 1
+                if self._burst is not None:
+                    # Between a prefill's launch and its wait (the window's
+                    # provisioning or launch failed): the prefill itself
+                    # ran, so its first tokens are kept.
+                    try:
+                        self._finish_prefill()
+                    except DispatchFault as e2:
+                        log.error("the launched prefill failed too: %s", e2)
                 self.robust.failed_steps += 1
                 self._consec_failed += 1
                 log.error(
@@ -1440,6 +1492,11 @@ class InferenceEngine:
             # dispatched [rows -> power of two] x [largest bucket] block.
             "prefill_dispatches": 0, "prefill_tokens": 0,
             "prefill_pad_tokens": 0,
+            # Of the prefill dispatches, those whose first tokens were the
+            # program's own greedy picks (no sampler program, no logits
+            # read on the host); of the steps, those whose decode window
+            # was launched before its prefill's picks were fetched.
+            "prefill_picks_in_program": 0, "chained_steps": 0,
             # Expert-matmul rows per MoE layer those dispatches computed
             # (models/moe.expert_rows: k per routed position on the
             # dropless grouped path, E per dispatched position on the
@@ -1512,6 +1569,7 @@ class InferenceEngine:
         first-token sample), host_s (scheduler remainder), the leaf
         ``<phase>_s`` keys those three are sums of (_zero_timing), the
         prefill_dispatches/prefill_tokens/prefill_pad_tokens/
+        prefill_picks_in_program/chained_steps/
         prefill_expert_rows/prefill_held_expert_rows, decode_kv_tokens/
         decode_kv_token_layers/decode_kv_pages_read and kv_live_page_layers/
         kv_dead_window_page_layers sizing counters,
@@ -3082,7 +3140,8 @@ class InferenceEngine:
 
             if resolve_impl(self.mcfg.kernels)[0]:
                 self._prefill_bucket(
-                    [r for r, _ in admitted], max(s for _, s in admitted)
+                    [r for r, _ in admitted], max(s for _, s in admitted),
+                    chain=True,
                 )
             else:
                 by_bucket: dict[int, list[Request]] = {}
@@ -3091,7 +3150,9 @@ class InferenceEngine:
                 items = list(by_bucket.items())
                 for bi, (s_pad, reqs) in enumerate(items):
                     try:
-                        self._prefill_bucket(reqs, s_pad)
+                        # The step's one prefill may stay in flight.
+                        self._prefill_bucket(
+                            reqs, s_pad, chain=len(items) == 1)
                     except DispatchFault:
                         # The faulted bucket unwound its own admissions;
                         # the not-yet-dispatched buckets are admitted but
@@ -3104,14 +3165,22 @@ class InferenceEngine:
                                 self.waiting.appendleft(r)
                         raise
 
-    def _prefill_bucket(self, reqs: list[Request], s_pad: int) -> None:
+    def _prefill_bucket(
+        self, reqs: list[Request], s_pad: int, chain: bool = False
+    ) -> None:
         """Prefill a group of admitted requests in one dispatch; rows may
         be shorter than ``s_pad`` (their tail positions write to the
         scratch page and their compute blocks skip via segment ids).
         Prefix-matched rows carry only their uncached TAIL here — the
         prefix page ids ride along for the mid-sequence attention gather
         (runner.prefill_step), padded to the burst's max match (power of
-        two, so jit specializations stay bounded)."""
+        two, so jit specializations stay bounded).
+
+        The dispatch is launched here. With ``chain`` (the step's one
+        prefill) and a greedy burst it stays in flight (``self._burst``)
+        for the step's decode window to be queued behind it; whoever needs
+        the first tokens on the host calls ``_finish_prefill``. Any other
+        burst is finished before this returns."""
         with self._phase("prefill/build"):
             n_pages = s_pad // self.psz
             nb = 1 << (len(reqs) - 1).bit_length()   # next power of two
@@ -3123,11 +3192,14 @@ class InferenceEngine:
             p_pre = 1 << (max_pre - 1).bit_length() if max_pre > 0 else 0
             pre_lens = np.zeros(nb, np.int32)
             pre_pages = np.zeros((nb, p_pre), np.int32)
+            # Where each row's pick lands in the step's last tokens (a
+            # padding row: out of range, which the scatter drops).
+            slots = np.full(nb, self.max_batch, np.int32)
+            slots[: len(reqs)] = [r.slot for r in reqs]
             # A power-retention model: the state row each row of the burst
             # owns (slot + 1; padding rows take scratch row 0).
-            state_rows = () if self._chunk is None else (jnp.asarray(
-                [r.slot + 1 for r in reqs] + [0] * (nb - len(reqs)),
-                jnp.int32),)
+            state_rows = None if self._chunk is None else np.where(
+                slots < self.max_batch, slots + 1, 0).astype(np.int32)
             for i, req in enumerate(reqs):
                 npre = req.n_prefix
                 tail = req.context[npre * self.psz:]
@@ -3149,11 +3221,17 @@ class InferenceEngine:
                 pages[i, : len(tail_pg)] = [
                     0 if p is None else p for p in tail_pg
                 ]
+            # Greedy with no legal mask, read off the requests (_admit
+            # resolved None-means-default into the slot arrays): the
+            # program's own argmax is then what the sampler would return.
+            picked = all(self.slot_temp[r.slot] <= 0.0 for r in reqs) and not (
+                self.constrained
+                and any(r.constraint is not None for r in reqs))
+        key = self._key
         try:
             # The uploads are part of prefill_s, as they always were.
             with self._phase("prefill/run"):
-                logits, self.cache = self._run_dispatch(
-                    "prefill", "prefill",
+                args = (
                     self.params,
                     self.cache,
                     jnp.asarray(tokens),
@@ -3161,19 +3239,29 @@ class InferenceEngine:
                     jnp.asarray(pages),
                     jnp.asarray(pre_lens),
                     jnp.asarray(pre_pages),
-                    *state_rows,
+                    None if state_rows is None else jnp.asarray(state_rows),
+                    jnp.asarray(slots),
+                    # A copy, like every mirror handed to a program that is
+                    # not waited for at once: an upload may alias the
+                    # host's buffer, which the engine writes again.
+                    jnp.asarray(self.last_token.copy()),
+                    key,
                 )
+                out = self._executor.run("prefill", "prefill", *args)
         except DispatchFault:
-            # Unwind this burst's admissions: their slots are claimed but
-            # NO KV was written, so tear down with nothing donated
-            # (n_cached=0 — donating would insert garbage pages into the
-            # prefix cache) and re-queue at the head for the next step's
-            # re-prefill.
-            for r in reversed(reqs):
-                self._teardown_slot(r, 0)
-                r.freed_until = 0
-                self.waiting.appendleft(r)
+            self._unwind_burst(reqs)
             raise
+        logits, self.cache = out
+        if picked:
+            self._key = self._executor.key
+            self.timing["prefill_picks_in_program"] += 1
+        # Whose budget ends at the first token, as far as the host knows
+        # (_maybe_finish's rule less the stop token).
+        ends = frozenset(
+            r.rid for r in reqs
+            if r.max_new_tokens - len(r.generated) <= 1
+            or int(self.seq_lens[r.slot]) >= self.icfg.max_seq_len)
+        self._burst = _Burst(reqs, args, logits, picked, key, ends)
         real = int(lengths[: len(reqs)].sum())
         self.timing["prefill_dispatches"] += 1
         self.timing["prefill_tokens"] += real
@@ -3194,23 +3282,65 @@ class InferenceEngine:
             # Pad rows have length 1, so one position of each routes too.
             self.timing["prefill_expert_rows"] += expert_rows(
                 self.mcfg, nb, s_pad, int(lengths.sum()), self.mesh)
+        if not (chain and picked):
+            self._finish_prefill()
+
+    def _unwind_burst(self, reqs: list[Request]) -> None:
+        """Unwind a failed prefill's admissions: their slots are claimed
+        but NO KV is theirs, so tear down with nothing donated (n_cached=0
+        — donating would insert garbage pages into the prefix cache) and
+        re-queue at the head for the next step's re-prefill."""
+        for r in reversed(reqs):
+            self._teardown_slot(r, 0)
+            r.freed_until = 0
+            self.waiting.appendleft(r)
+
+    def _finish_prefill(self) -> bool:
+        """Wait for the prefill in flight, bring its first tokens to the
+        host and emit them. True where the wait's fallback ladder ran the
+        prefill again: ``self.cache`` and the key are then the fallback's,
+        and whatever was queued behind the first launch is void. A fault
+        unwinds the burst's admissions and leaves the key where it was
+        before the launch."""
+        b, self._burst = self._burst, None
+        try:
+            with self._phase("prefill/run"):
+                # The logits alone: the cache may be a later program's by
+                # now (donated to it), which waits for it in its turn.
+                out = self._executor.wait(
+                    "prefill", "prefill", b.logits, *b.args)
+        except DispatchFault:
+            self._key = b.key
+            self._unwind_burst(b.reqs)
+            raise
+        again = out is not b.logits
+        logits = b.logits
+        if again:
+            logits, self.cache = out
+            if b.picked:
+                self._key = self._executor.key
         with self._phase("prefill/sample"):
-            firsts = self._sample(logits, reqs)  # blocks on the fetch
+            if b.picked:
+                # orion: allow[host-sync] [nb] picks of a program that has ended: the prefill's ONE fetch
+                firsts = np.asarray(jax.device_get(self._executor.picks))
+            else:
+                firsts = self._sample(logits, b.reqs)  # blocks on the fetch
             if self.mcfg.holds_expert_share:
                 # The program has ended (its tokens are here): a 4-byte
                 # copy, no wait.
                 self.timing["prefill_held_expert_rows"] += int(
                     self._executor.held_rows)
-        for i, req in enumerate(reqs):
-            if req.done:
-                continue   # quarantined during mask build (_sample_masks)
-            if req.max_new_tokens <= 0:
-                req.done = True   # prefill-only (scoring) request
-                continue
-            first = int(firsts[i])
-            self.last_token[req.slot] = first
-            req.generated.append(first)
-            self._maybe_finish(req, first)
+            for i, req in enumerate(b.reqs):
+                if req.done:
+                    continue   # quarantined during mask build (_sample_masks)
+                if req.max_new_tokens <= 0:
+                    req.done = True   # prefill-only (scoring) request
+                    continue
+                first = int(firsts[i])
+                self.last_token[req.slot] = first
+                req.generated.append(first)
+                self._maybe_finish(req, first)
+        return again
 
     def _release_request(self, req: Request, n_cached: int) -> None:
         """Release a leaving request's pages. With prefix caching, the
@@ -3283,6 +3413,26 @@ class InferenceEngine:
         req.prefill_done = 0
         self.waiting.appendleft(req)
 
+    def _decodes(self, req: Optional[Request]) -> bool:
+        """The next decode dispatch takes this slot: a live request that is
+        not done, nor known to end at the first token of a prefill still in
+        flight (which it will be the moment that token is here)."""
+        return req is not None and not req.done and (
+            self._burst is None or req.rid not in self._burst.ends)
+
+    def _window_page_need(self, W: int) -> int:
+        """Pages _grow_pages would have to allocate to cover ``W`` write
+        positions ahead of every decoding slot."""
+        need = 0
+        for req in self.slots:
+            if not self._decodes(req):
+                continue
+            pos = int(self.seq_lens[req.slot])
+            last = min(pos + W - 1, self.icfg.max_seq_len - 1)
+            n_need = min(last // self.psz + 1, self.pages_per_seq)
+            need += max(n_need - len(req.pages), 0)
+        return need
+
     def _grow_pages(self, window: Optional[int] = None) -> None:
         """Pre-provision every active slot with pages covering the whole
         upcoming decode window (the device writes up to W positions ahead of
@@ -3301,7 +3451,7 @@ class InferenceEngine:
         # all-default priorities this is exactly the pre-priority
         # youngest-admitted order.
         by_age = sorted(
-            (r for r in self.slots if r is not None and not r.done),
+            (r for r in self.slots if self._decodes(r)),
             key=lambda r: (-r.priority, r.admit_seq),
         )
         # Batched pre-evict: compute the whole pass's page shortfall and
@@ -3311,15 +3461,7 @@ class InferenceEngine:
         # Tier-off this frees the identical LRU page set the lazy loop
         # would have, just up front.
         if self._pcache is not None:
-            need_total = 0
-            for req in by_age:
-                if req.slot is None:
-                    continue
-                pos = int(self.seq_lens[req.slot])
-                last = min(pos + W - 1, self.icfg.max_seq_len - 1)
-                n_need = min(last // self.psz + 1, self.pages_per_seq)
-                need_total += max(n_need - len(req.pages), 0)
-            short = need_total - self.alloc.free_pages
+            short = self._window_page_need(W) - self.alloc.free_pages
             if short > 0:
                 self.prefix_stats.evicted_pages += self._pcache.evict(
                     short
@@ -3924,6 +4066,16 @@ class InferenceEngine:
                 self._rollback_slot(r)
 
     def _decode_all(self) -> bool:
+        if self._burst is not None and (
+            self._long
+            or (self._spec is not None and not self._spec_disabled)
+            or (self.constrained and any(
+                r is not None and r.constraint is not None
+                for r in self.slots))
+        ):
+            # A step that pages in, drafts or verifies reads the first
+            # tokens on the host: today's order.
+            self._finish_prefill()
         with self._phase("decode/build"):
             self._roll_window()
             live = [r for r in self.slots if r is not None and not r.done]
@@ -3965,23 +4117,32 @@ class InferenceEngine:
         window; None when no slot is live. Runs inside ``decode/build``."""
         if self._chunk is not None:
             self._fold_tails()
+        W = self.decode_window
+        if (
+            self._burst is not None
+            and self._window_page_need(W) > self.alloc.free_pages
+        ):
+            # _grow_pages would have to reclaim pages (evict, spill or
+            # preempt), perhaps from a request whose first token is not on
+            # the host yet: wait for the prefill first, then as ever.
+            self._finish_prefill()
         self._grow_pages()
-        active = [r for r in self.slots if r is not None and not r.done]
+        mask = np.array([self._decodes(r) for r in self.slots], bool)
+        active = [r for r, m in zip(self.slots, mask) if m]
         if not active:
             return None
-        W = self.decode_window
-        mask = np.array(
-            [r is not None and not r.done for r in self.slots], bool
-        )
-        self._key, sub = jax.random.split(self._key)
         common = (
             self.params,
             self.cache,
-            jnp.asarray(self.last_token),
-            jnp.asarray(self.seq_lens),
-            jnp.asarray(self.page_table),
+            # Behind a prefill in flight: the array it returned, the picks
+            # at their slots, which never leaves the device.
+            self._executor.last_token if self._burst is not None
+            else jnp.asarray(self.last_token.copy()),
+            # Copies: the first tokens are emitted while the window runs.
+            jnp.asarray(self.seq_lens.copy()),
+            jnp.asarray(self.page_table.copy()),
             jnp.asarray(mask),
-            jax.random.split(sub, W),
+            self._key,
         )
         # Token step j of the window reads seq_len + j cached positions
         # per live slot (the sliding window's last at most).
@@ -4034,9 +4195,14 @@ class InferenceEngine:
             slot = req.slot
             while int(self.seq_lens[slot]) - int(self.fold_lens[slot]) >= C:
                 with self._phase("fold/run"):
-                    self.cache = self._run_dispatch(
+                    # Launched, not waited for: a fold chains on the cache
+                    # between the programs before and behind it, and the
+                    # window's wait is its wait.
+                    self.cache = self._executor.run(
                         "fold", "fold", self.cache,
-                        jnp.int32(slot), jnp.asarray(self.page_table[slot]))
+                        np.int32(slot),     # (jnp.int32 is a program)
+                        # a copy: _roll_window writes the row below
+                        jnp.asarray(self.page_table[slot].copy()))
                 self.fold_lens[slot] += C
                 self.timing["folds"] += 1
         self._roll_window()
@@ -4064,32 +4230,59 @@ class InferenceEngine:
                 self._window_layers * int(dead.sum()))
 
     def _decode_run_window(self, window) -> bool:
-        """Dispatch a built decode window, fetch its ``[W, B]`` tokens and
-        emit them (the non-speculative step body)."""
+        """Launch a built decode window, behind the step's prefill where
+        that is still in flight (its first tokens are then brought to the
+        host and emitted while the window runs), wait for it, fetch its
+        ``[W, B]`` tokens and emit them (the non-speculative step body)."""
         if window is None:
+            if self._burst is not None:
+                self._finish_prefill()
             self._reap()
             return False
         active, W, common = window
+        name, args = "decode_defaults", common
+        if not all(
+            r.temperature is None and r.top_k is None and r.top_p is None
+            for r in active
+        ):
+            name, args = "decode", common + (
+                jnp.asarray(self.slot_temp),
+                jnp.asarray(self.slot_top_k),
+                jnp.asarray(self.slot_top_p),
+            )
         with self._phase("decode/run"):
-            if all(
-                r.temperature is None and r.top_k is None and r.top_p is None
-                for r in active
-            ):
-                out = self._run_dispatch("decode", "decode_defaults", *common)
-            else:
-                out = self._run_dispatch(
-                    "decode", "decode", *common,
-                    jnp.asarray(self.slot_temp),
-                    jnp.asarray(self.slot_top_k),
-                    jnp.asarray(self.slot_top_p),
-                )
+            out = self._executor.run("decode", name, *args, window=W)
+        again = False
+        try:
+            if self._burst is not None:
+                self.timing["chained_steps"] += 1
+                again = self._finish_prefill()
+            if not again:
+                with self._phase("decode/run"):
+                    out = self._executor.wait(
+                        "decode", name, out, *args, window=W)
+        except DispatchFault:
+            # The window's tokens are lost with the step (behind a failed
+            # prefill they are void; else the slots decode these positions
+            # again); the cache it hands back is the one that is live.
+            self.cache = out[-1]
+            raise
+        if again:
+            # The prefill's results are its fallback's: the window above
+            # ran on the failed launch's. Build and run it anew.
+            with self._phase("decode/build"):
+                window = self._decode_build_window()
+            return self._decode_run_window(window)
         with self._phase("decode/fetch"):
+            # The program's key' sits before its cache: the stream goes on
+            # from it once the window is known to have run.
+            *out, self._key, self.cache = out
             if self._guard:
-                toks, ok, self.cache = out
+                toks, ok = out
                 tokens, okh = jax.device_get((toks, ok))   # orion: allow[host-sync] the decode window's ONE documented fetch
                 tokens = np.asarray(tokens)
             else:
-                toks, self.cache = out
+                toks, = out
                 tokens = np.asarray(jax.device_get(toks))  # orion: allow[host-sync] [W, B] — the decode window's ONE documented fetch
                 okh = None
         with self._phase("decode/emit"):

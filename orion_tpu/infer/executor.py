@@ -8,6 +8,9 @@ fault-tolerance envelope: injection points, the degradation-ladder
 fallback retry loop (``inference.dispatch_retries`` attempts with
 jittered backoff between them — ISSUE 12 satellite), and the
 DispatchFault contract the engine's failed-step containment consumes.
+The envelope has two halves (ISSUE 40): ``run`` launches a program and
+``wait`` waits for it, so that a caller can queue the next program on
+the device between the two; both end in the same ladder.
 
 The executor holds a back-reference to its engine rather than copies of
 the engine's mutable state (robust stats, injector, tracer): those
@@ -76,9 +79,12 @@ class DispatchExecutor:
         # sleeps the same schedule; sleep durations never touch tokens,
         # so this is log-determinism, not output-determinism.
         self._rng = random.Random(0)
-        # The last prefill's count of expert rows on held experts (a device
-        # scalar; only a model that holds a share of its experts has one).
-        self.held_rows = None
+        # What the last prefill returned between its logits and its cache,
+        # all on the device: the greedy picks [nb], the step's last tokens
+        # [B] with the picks at their slots, the key after one sampling
+        # event, and the count of expert rows on held experts (only a
+        # model that holds a share of its experts has one).
+        self.picks = self.last_token = self.key = self.held_rows = None
 
     def jit_program(self, name: str, mcfg, mesh):
         """Build one jitted dispatch program. ``name`` is a coarse path
@@ -114,33 +120,47 @@ class DispatchExecutor:
                 top_k=icfg.top_k,
                 top_p=icfg.top_p,
             )
-        program = jax.jit(named_program(fn, stem, **kw), donate_argnums=(1,))
-        if stem == "prefill" and mcfg.is_retention:
-            # Which state row each row of a burst owns is the engine's to
-            # say; a caller that says nothing (a warm-up) gets the scratch
-            # row in the same shape and dtype, so that it compiles the
-            # program the engine runs.
-            import jax.numpy as jnp
+        program = jax.jit(
+            named_program(fn, stem, **kw), donate_argnums=(1,),
+            # The decode window's length, since its keys are derived inside
+            # the program from the engine's one key.
+            static_argnames=("window",) if stem == "decode" else None,
+        )
+        if stem != "prefill":
+            return program
+        import jax.numpy as jnp
 
-            def run_rows(params, cache, tokens, lengths, pages, pre_lens,
-                         pre_pages, state_rows=None):
-                if state_rows is None:
-                    state_rows = jnp.zeros((tokens.shape[0],), jnp.int32)
-                return program(params, cache, tokens, lengths, pages,
-                               pre_lens, pre_pages, state_rows)
+        eng = self.eng
 
-            return run_rows
-        if stem == "prefill" and mcfg.holds_expert_share:
-            # Such a model's prefill has a third result, the rows it
-            # computed on experts held here (runner.HELD_ROWS): kept on the
-            # device for the engine to fetch after the sampled tokens, so
-            # that every caller still gets (logits, cache).
-            def run(*args):
-                logits, cache, self.held_rows = program(*args)
-                return logits, cache
+        def prefill(params, cache, tokens, lengths, pages, pre_lens,
+                    pre_pages, state_rows=None, slots=None, last_token=None,
+                    key=None):
+            """(logits, cache) of the prefill program; what it returns
+            between the two is parked on the executor (``picks``,
+            ``last_token``, ``key``, ``held_rows``) for the engine to take
+            up. What only the engine can say (the state row and the slot of
+            each row, the step's last tokens, its key) a caller that says
+            nothing (a warm-up) gets as placeholders of the same shapes and
+            dtypes, so that it compiles the program the engine runs."""
+            nb = tokens.shape[0]
+            if state_rows is None and mcfg.is_retention:
+                state_rows = jnp.zeros((nb,), jnp.int32)    # the scratch row
+            if slots is None:
+                # Out of range: the scatter of the picks drops every row.
+                slots = jnp.full((nb,), eng.max_batch, jnp.int32)
+            if last_token is None:
+                last_token = jnp.zeros((eng.max_batch,), jnp.int32)
+            if key is None:
+                key = eng._key
+            logits, self.picks, self.last_token, self.key, *held, cache = (
+                program(params, cache, tokens, lengths, pages, pre_lens,
+                        pre_pages, state_rows, slots, last_token, key))
+            if held:
+                self.held_rows, = held
+            return logits, cache
 
-            return run
-        return program
+        prefill.program = program
+        return prefill
 
     def fallback_program(self, name: str):
         """The XLA reference program for ``name`` (degradation ladder rung
@@ -173,24 +193,24 @@ class DispatchExecutor:
         time.sleep(base * (2 ** attempt) * (0.5 + 0.5 * self._rng.random()))
 
     def run(self, path: str, name: str, *args, **kwargs):
-        """Run one device dispatch with the fault-tolerance envelope: the
-        injection points (stall sleeps; dispatch exceptions raised BEFORE
-        the primary call, so engine/cache state is untouched and retry is
-        sound), then — only with ``inference.dispatch_fallback`` on — on
-        ANY failure up to ``inference.dispatch_retries`` retries on the
-        XLA reference path, jittered backoff between attempts. Raises
-        DispatchFault(path) when every path is exhausted (at once, with
-        the fallback off) — the engine fails the step, not the process.
+        """LAUNCH one device dispatch under the fault-tolerance envelope and
+        return its results without waiting for them (``wait`` is the other
+        half): the injection points (stall sleeps; dispatch exceptions
+        raised BEFORE the primary call, so engine/cache state is untouched
+        and retry is sound), then the call, whose trace / compile /
+        lowering failures (the dominant Pallas fault class) surface here.
+        Only with ``inference.dispatch_fallback`` on, ANY failure is
+        retried up to ``inference.dispatch_retries`` times on the XLA
+        reference path, jittered backoff between attempts (``_recover``,
+        which waits for each attempt's results). Raises DispatchFault(path)
+        when every path is exhausted (at once, with the fallback off): the
+        engine fails the step, not the process.
 
-        The primary result is blocked on HERE so that execute-time device
-        errors (async dispatch defers them to the first fetch) surface
-        inside this envelope instead of crashing the caller's device_get;
-        the engine fetches the step's tokens immediately afterwards
-        anyway, so no overlap is lost. Fallback scope: trace/compile/
-        lowering failures (the dominant Pallas fault class) and injected
-        faults retry cleanly; an EXECUTE-time failure may already have
-        consumed the donated cache buffer, in which case the fallback
-        double-faults and the episode is contained as a failed step."""
+        The device runs the program while the host goes on: what a caller
+        queues between ``run`` and ``wait`` (the plain step queues its
+        decode window behind its prefill) starts on the device the moment
+        this program ends. A caller with nothing to queue calls the two
+        back to back (``engine._run_dispatch``)."""
         eng = self.eng
         inj = eng._injector
         if inj is not None:
@@ -210,52 +230,83 @@ class DispatchExecutor:
                 )
             # The caller's ``orion/<path>/run`` phase (engine._phase) names
             # this dispatch in the ring and in a device profile.
-            out = getattr(eng, "_" + name)(*args, **kwargs)
-            # orion: allow[host-sync] THE envelope sync point: execute-time faults must surface here, not at the caller's fetch
+            return getattr(eng, "_" + name)(*args, **kwargs)
+        # orion: allow[fault-except] the fault envelope exists to contain ANY dispatch failure (DispatchFault re-raise in _recover)
+        except Exception as e:
+            return self._recover(path, name, e, args, kwargs)
+
+    def wait(self, path: str, name: str, out, *args, **kwargs):
+        """WAIT for the results ``out`` that ``run(path, name, *args,
+        **kwargs)`` launched (or for the part of them the caller still
+        owns: a cache already handed on to the next program is that
+        program's to wait for), and return them. Execute-time device errors
+        (async dispatch defers them to the first fetch) surface HERE, inside
+        the same envelope and the same fallback ladder, instead of crashing
+        the caller's device_get; an injected ``execute`` fault fires here
+        too. An EXECUTE-time failure may already have consumed the donated
+        cache buffer, in which case the fallback double-faults and the
+        episode is contained as a failed step. Where the ladder recovers,
+        the results returned are the fallback's, not ``out``."""
+        eng = self.eng
+        try:
+            if eng._injector is not None and (
+                eng._injector.take("execute", eng.step_no, path) is not None
+            ):
+                raise InjectedFault(
+                    f"injected {path} execute fault (step {eng.step_no})"
+                )
+            # orion: allow[host-sync] THE envelope sync point, once a program and after everything the device needs has been queued: execute-time faults must surface here, not at the caller's fetch
             jax.block_until_ready(out)
             return out
-        # orion: allow[fault-except] the fault envelope exists to contain ANY dispatch failure (DispatchFault re-raise below)
+        # orion: allow[fault-except] the fault envelope exists to contain ANY dispatch failure (DispatchFault re-raise in _recover)
         except Exception as e:
-            eng.robust.dispatch_faults += 1
-            eng._flight_note(
-                "dispatch_fault", path=path,
-                error=f"{type(e).__name__}: {e}",
-            )
-            if path in ("verify", "mixed_verify"):
-                # Degradation ladder rung 2 counts PRIMARY verify faults
-                # here — before the fallback — so a persistently broken
-                # verify kernel disables speculation even when every
-                # episode is absorbed by a successful XLA retry (otherwise
-                # the engine would pay a doomed primary attempt + fallback
-                # on every verify step forever).
-                eng._note_spec_fault(e)
-            fb = self.fallback_program(name)
-            if fb is None:
-                raise DispatchFault(
-                    path, f"{type(e).__name__}: {e}"
-                ) from e
-            last: Exception = e
-            for attempt in range(eng.icfg.dispatch_retries):
-                self._backoff(attempt)
-                eng.robust.dispatch_retries += 1
-                log.warning(
-                    "%s dispatch failed (%s: %s); retry %d/%d on the XLA "
-                    "reference path", path, type(last).__name__, last,
-                    attempt + 1, eng.icfg.dispatch_retries,
-                )
-                try:
-                    with eng._phase(path + "/fallback"):
-                        out = fb(*args, **kwargs)
-                        # orion: allow[host-sync] fallback attempts must surface their own execute-time faults inside the retry loop
-                        jax.block_until_ready(out)
-                # orion: allow[fault-except] retry-ladder rung: a failed fallback attempt feeds the next retry, then DispatchFault
-                except Exception as e2:
-                    eng.robust.dispatch_faults += 1
-                    last = e2
-                    continue
-                eng.robust.dispatch_fallbacks += 1
-                eng._flight_note("dispatch_fallback", path=path)
-                return out
+            return self._recover(path, name, e, args, kwargs)
+
+    def _recover(self, path: str, name: str, e: Exception, args, kwargs):
+        """A dispatch failed, at its launch or at its wait: count it, then
+        the degradation ladder (module docstring). Returns a fallback
+        attempt's results, waited for, or raises DispatchFault."""
+        eng = self.eng
+        eng.robust.dispatch_faults += 1
+        eng._flight_note(
+            "dispatch_fault", path=path,
+            error=f"{type(e).__name__}: {e}",
+        )
+        if path in ("verify", "mixed_verify"):
+            # Degradation ladder rung 2 counts PRIMARY verify faults
+            # here — before the fallback — so a persistently broken
+            # verify kernel disables speculation even when every
+            # episode is absorbed by a successful XLA retry (otherwise
+            # the engine would pay a doomed primary attempt + fallback
+            # on every verify step forever).
+            eng._note_spec_fault(e)
+        fb = self.fallback_program(name)
+        if fb is None:
             raise DispatchFault(
-                path, f"xla fallback failed too: {last}"
-            ) from last
+                path, f"{type(e).__name__}: {e}"
+            ) from e
+        last: Exception = e
+        for attempt in range(eng.icfg.dispatch_retries):
+            self._backoff(attempt)
+            eng.robust.dispatch_retries += 1
+            log.warning(
+                "%s dispatch failed (%s: %s); retry %d/%d on the XLA "
+                "reference path", path, type(last).__name__, last,
+                attempt + 1, eng.icfg.dispatch_retries,
+            )
+            try:
+                with eng._phase(path + "/fallback"):
+                    out = fb(*args, **kwargs)
+                    # orion: allow[host-sync] fallback attempts must surface their own execute-time faults inside the retry loop
+                    jax.block_until_ready(out)
+            # orion: allow[fault-except] retry-ladder rung: a failed fallback attempt feeds the next retry, then DispatchFault
+            except Exception as e2:
+                eng.robust.dispatch_faults += 1
+                last = e2
+                continue
+            eng.robust.dispatch_fallbacks += 1
+            eng._flight_note("dispatch_fallback", path=path)
+            return out
+        raise DispatchFault(
+            path, f"xla fallback failed too: {last}"
+        ) from last

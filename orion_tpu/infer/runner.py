@@ -379,11 +379,15 @@ def prefill_step(
     prefix_pages: Optional[jax.Array] = None,  # [Nb, P_pre] int32 page ids
     state_rows: Optional[jax.Array] = None,    # [Nb] int32: slot + 1, a
     #              power-retention model's (0 = the scratch row); else None
+    slots: Optional[jax.Array] = None,         # [Nb] int32: each row's slot
+    #              (a padding row: B, which the scatter drops)
+    last_token: Optional[jax.Array] = None,    # [B] int32: the step's
+    key: Optional[jax.Array] = None,           # the engine's PRNG key
     *,
     cfg: ModelConfig,
     mesh: Optional[jax.sharding.Mesh] = None,
     paged_prefill: bool = False,
-) -> tuple[jax.Array, Cache]:
+) -> tuple[jax.Array, ...]:
     """Prefill a batch of same-bucket prompts in ONE dispatch.
 
     ``mesh`` (tensor-parallel serving) makes the flash kernel run under a
@@ -410,7 +414,33 @@ def prefill_step(
     Padding rows (engine rounds the batch up to a bucket size) carry
     all-zero page lists: their K/V lands on the reserved scratch page 0 and
     is never read.
+
+    With ``last_token`` (the engine's call; ``slots`` and ``key`` come with
+    it) the first tokens are picked here and stay on the device: the
+    results between the logits and the cache are then the greedy picks
+    ``[Nb]`` int32, ``last_token`` with each row's pick at its slot (what
+    the step's decode window takes as its tokens), and the key the
+    engine's stream holds after one sampling event (``split(key)[0]``, what
+    the eager sampler would leave). A burst that is not greedy samples
+    from the logits on the host's side and takes none of the three.
     """
+    out = _prefill(params, cache, tokens, lengths, pages, prefix_lens,
+                   prefix_pages, state_rows, cfg, mesh, paged_prefill)
+    if last_token is None:
+        return out
+    from orion_tpu.infer.sampling import sample
+
+    logits, cache, *held = out
+    picks = sample(logits, key)     # scalar temperature 0: the bare argmax
+    with jax.named_scope("sample"):
+        last_token = last_token.at[slots].set(picks, mode="drop")
+    return logits, picks, last_token, jax.random.split(key)[0], *held, cache
+
+
+def _prefill(params, cache, tokens, lengths, pages, prefix_lens,
+             prefix_pages, state_rows, cfg, mesh, paged_prefill):
+    """``prefill_step``'s (logits, cache), or (logits, cache, rows on held
+    experts) for a model that holds a share of its experts."""
     if cfg.is_retention:
         if prefix_pages is not None and prefix_pages.shape[1]:
             raise ValueError(
@@ -486,7 +516,8 @@ def decode_window(
     seq_lens: jax.Array,      # [B] int32
     page_table: jax.Array,    # [B, pages_per_seq] int32
     active: jax.Array,        # [B] bool: slot holds a live request
-    keys: jax.Array,          # [W] PRNG keys, one per inner step
+    keys: jax.Array,          # [W] PRNG keys, one per inner step (with
+    #                           ``window``: the engine's one key)
     temperature: jax.Array,   # [B] f32 per-request (vLLM-style params)
     top_k: jax.Array,         # [B] i32
     top_p: jax.Array,         # [B] f32
@@ -494,8 +525,15 @@ def decode_window(
     max_seq_len: int,
     mesh: Optional[jax.sharding.Mesh] = None,
     nan_guard: bool = False,
+    window: Optional[int] = None,
 ) -> tuple[jax.Array, ...]:
     """W fused decode+sample steps; returns (tokens [W, B] int32, cache).
+
+    With ``window`` (static; the engine's call) ``keys`` is the engine's
+    ONE key and the W keys are derived here, as the host derived them:
+    ``key', sub = split(key)``, ``split(sub, window)``. ``key'`` is then
+    the result before the cache, and no key program runs between two
+    dispatches.
 
     The engine fetches the whole [W, B] token block once per window and does
     its bookkeeping (EOS, max_new, admission) on the host afterwards; slots
@@ -530,16 +568,20 @@ def decode_window(
             return (tok, sl, ok, cc), toks
         return (tok, sl, cc), toks
 
+    key = ()
+    if window is not None:
+        key_next, sub = jax.random.split(keys)
+        key, keys = (key_next,), jax.random.split(sub, window)
     if nan_guard:
         init = (
             tokens, seq_lens, jnp.ones_like(active, dtype=bool), dict(cache)
         )
         (_, _, ok, cache), toks = jax.lax.scan(stepf, init, keys)
-        return toks, ok, cache
+        return toks, ok, *key, cache
     (_, _, cache), toks = jax.lax.scan(
         stepf, (tokens, seq_lens, dict(cache)), keys
     )
-    return toks, cache
+    return toks, *key, cache
 
 
 def _paged_ctx(

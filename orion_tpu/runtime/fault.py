@@ -298,6 +298,11 @@ class FaultSpec:
       - "dispatch": raise InjectedFault instead of running the jit program
         (fired BEFORE the call, so engine/cache state is untouched and the
         XLA-fallback retry exercises the real degradation path).
+      - "execute":  raise InjectedFault where the engine WAITS for a
+        dispatch's results (executor.wait): what an execute-time device
+        error does, which asynchronous dispatch defers from the launch to
+        the first wait, after the program was queued and whatever the
+        engine queued behind it.
       - "nan":      poison the victim request's newest private KV page with
         NaN before the step's dispatch — real NaNs flow through the real
         attention into that slot's logits (requires inference.nan_guard for
@@ -354,7 +359,7 @@ class FaultSpec:
 
     ``step`` is the engine step number (``InferenceEngine.step_no``) to fire
     at — or the router step for replica-scoped kinds; ``path`` optionally
-    restricts dispatch/stall faults to one coarse dispatch path
+    restricts dispatch/execute/stall faults to one coarse dispatch path
     ("prefill" | "decode" | "verify" | "mixed" | "mixed_verify" |
     "train"); ``rid`` optionally selects the nan victim (default: the
     oldest active request); ``replica`` selects the replica-scoped
@@ -373,7 +378,7 @@ class FaultSpec:
 
     def __post_init__(self):
         if self.kind not in (
-            "dispatch", "nan", "pool", "stall", "partial_write",
+            "dispatch", "execute", "nan", "pool", "stall", "partial_write",
             "restore", "migration",
         ) + self.REPLICA_KINDS:
             raise ValueError(f"unknown fault kind {self.kind!r}")

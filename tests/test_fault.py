@@ -323,6 +323,90 @@ def test_injected_prefill_fault_unwinds_admission(tiny):
     eng.assert_page_accounting()
 
 
+def _launches(eng):
+    """(step, path) of every dispatch the engine launches from now on."""
+    seen, real = [], eng._executor.run
+
+    def run(path, name, *args, **kwargs):
+        seen.append((eng.step_no, path))
+        return real(path, name, *args, **kwargs)
+
+    eng._executor.run = run
+    return seen
+
+
+def test_prefill_launch_fault_unwinds_before_any_window(tiny):
+    """The plain step queues its window behind its prefill (ISSUE 40): a
+    fault at the prefill's LAUNCH still unwinds the burst before any
+    window is launched."""
+    params, ref = tiny
+    inj = FaultInjector([FaultSpec("dispatch", step=0, path="prefill")])
+    eng = _engine(params, inj=inj)
+    seen = _launches(eng)
+    for p in MIX:
+        eng.submit(p, 8)
+    eng.step()
+    assert seen == [(0, "prefill")]
+    assert eng.slots.count(None) == len(eng.slots) and len(eng.waiting) == 3
+    eng.assert_page_accounting()
+    done = _drain_outcomes(eng)
+    assert [done[i].generated for i in range(3)] == ref
+    t = eng.reset_timing()
+    assert t["failed_steps"] == 1 and t["chained_steps"] == 1
+    eng.assert_page_accounting()
+
+
+def test_prefill_wait_fault_unwinds_and_drops_the_queued_window(tiny):
+    """An execute-time fault surfaces where the prefill is WAITED for,
+    after the window was queued behind it: the burst's admissions are
+    unwound, the window's results dropped, the step fails with the engine
+    consistent, and the re-prefill is byte-identical."""
+    params, ref = tiny
+    inj = FaultInjector([FaultSpec("execute", step=0, path="prefill")])
+    eng = _engine(params, inj=inj)
+    seen = _launches(eng)
+    reqs = [eng.submit_request(p, 8) for p in MIX]
+    key = np.asarray(eng._key).tolist()
+    eng.step()
+    assert seen == [(0, "prefill"), (0, "decode")]
+    assert inj.fired == [("execute", 0, "prefill")]
+    assert eng.slots.count(None) == len(eng.slots) and len(eng.waiting) == 3
+    assert all(not r.generated for r in reqs)
+    assert not eng.seq_lens.any() and not eng.last_token.any()
+    assert np.asarray(eng._key).tolist() == key     # no sampling event
+    eng.assert_page_accounting()
+    done = _drain_outcomes(eng)
+    assert [done[i].generated for i in range(3)] == ref
+    t = eng.reset_timing()
+    assert t["failed_steps"] == 1 and t["dispatch_faults"] == 1
+    assert t["chained_steps"] == 2      # the failed step's, and the retry's
+    eng.assert_page_accounting()
+
+
+@pytest.mark.parametrize("kind", ["dispatch", "execute"])
+def test_decode_fault_behind_an_unwaited_prefill_keeps_first_tokens(
+        tiny, kind):
+    """The window fails at its launch (the prefill in flight is then
+    waited for and its first tokens emitted) or at its wait (they were
+    emitted while it ran): either way the step fails with the prefill's
+    work kept, and the slots decode the lost positions again."""
+    params, ref = tiny
+    inj = FaultInjector([FaultSpec(kind, step=0, path="decode")])
+    eng = _engine(params, inj=inj)
+    reqs = [eng.submit_request(p, 8) for p in MIX]
+    eng.step()
+    assert [len(r.generated) for r in reqs] == [1, 1, 1]
+    assert [r.generated[0] for r in reqs] == [g[0] for g in ref]
+    assert eng.slots.count(None) == len(eng.slots) - 3
+    eng.assert_page_accounting()
+    done = _drain_outcomes(eng)
+    assert [done[i].generated for i in range(3)] == ref
+    t = eng.reset_timing()
+    assert t["failed_steps"] == 1 and t["prefill_dispatches"] == 1
+    assert t["chained_steps"] == (1 if kind == "execute" else 0)
+    eng.assert_page_accounting()
+
+
 def test_dispatch_fallback_xla_reference(tiny):
     """Degradation ladder rung 1: with kernels=pallas a failed dispatch
     retries once on the XLA reference path — same step, no failed step,

@@ -236,14 +236,16 @@ def test_trace_off_identical_and_trace_covers_lifecycle(tmp_path):
     evs = [e for e in doc["traceEvents"] if e["ph"] != "M"]
     spans = [e for e in evs if e["ph"] == "X"]
     inst = [e for e in evs if e["ph"] == "i"]
-    # Every dispatch has a span: the prefill burst + one decode span per
-    # decode step; every step has an "orion/step" span.
+    # Every dispatch has two spans of its name, its launch and its wait
+    # (the plain step queues the window between the prefill's two): the
+    # prefill burst + the window of every decode step; every step has an
+    # "orion/step" span.
     dispatch = [e for e in spans if e["name"].endswith("/run")]
     assert sum(
         1 for e in dispatch if e["name"] == "orion/prefill/run"
-    ) == t["prefill_dispatches"] >= 1
+    ) == 2 * t["prefill_dispatches"] >= 2
     n_decode = sum(1 for e in dispatch if e["name"] == "orion/decode/run")
-    assert n_decode == t["windows"]
+    assert n_decode == 2 * t["windows"]
     assert sum(1 for e in spans if e["name"] == "orion/step") == t["steps"]
     assert all(e["dur"] >= 0 for e in spans)
     # Full request lifecycle: submit -> admit -> first_token -> outcome,

@@ -136,13 +136,25 @@ def dump(tree: str, out: str) -> int:
         cache = abstract(jax.eval_shape(lambda: init_cache(mcfg, icfg)))
         i32 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.int32, sharding=one_chip)
         B, W = icfg.max_batch_size, icfg.decode_window
+        # The programs as the engine launches them (ISSUE 40: the window's
+        # keys derived inside it from one key, the first tokens picked and
+        # scattered inside the prefill); a tree from before that takes W
+        # keys and seven arguments.
+        import inspect
+
+        chained = "window" in inspect.signature(
+            runner.decode_window).parameters
+        key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+        u32 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.uint32,
+                                               sharding=one_chip)
         decode = jax.jit(_jitted(
             "decode", runner.decode_window, cfg=mcfg,
             max_seq_len=icfg.max_seq_len, mesh=None, nan_guard=False,
             temperature=icfg.temperature, top_k=icfg.top_k,
-            top_p=icfg.top_p), donate_argnums=(1,))
-        keys = jax.ShapeDtypeStruct((W,), jax.random.key(0).dtype,
-                                    sharding=one_chip)
+            top_p=icfg.top_p, **({"window": W} if chained else {})),
+            donate_argnums=(1,))
+        keys = u32(*key.shape) if chained else jax.ShapeDtypeStruct(
+            (W,), jax.random.key(0).dtype, sharding=one_chip)
         save(f"{name}.decode_window.compiled.txt", decode.lower(
             params, cache, i32(B), i32(B), i32(B, pages_per_seq(icfg)),
             jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip), keys,
@@ -153,9 +165,12 @@ def dump(tree: str, out: str) -> int:
             "prefill", runner.prefill_step, cfg=mcfg, mesh=None,
             paged_prefill=icfg.paged_prefill), donate_argnums=(1,))
         for nb, s_pad in sorted({max(todo, key=size), min(todo, key=size)}):
+            extra = (i32(nb) if mcfg.is_retention else None, i32(nb),
+                     i32(B), u32(*key.shape)) if chained else ()
             save(f"{name}.prefill_{nb}x{s_pad}.compiled.txt", prefill.lower(
                 params, cache, i32(nb, s_pad), i32(nb),
                 i32(nb, s_pad // icfg.page_size), i32(nb), i32(nb, 0),
+                *extra,
             ).compile().as_text())
 
     def train_cell(name):

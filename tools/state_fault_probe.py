@@ -33,13 +33,13 @@ ROOT = _pathlib.Path(__file__).resolve().parent.parent
 
 
 def plant(engine, fault: str):
-    """Wrap ``engine._run_dispatch`` (under the harness's own ``LogitTap``,
+    """Wrap the executor's launch (under the harness's own ``LogitTap``,
     which wraps it again a probe): buffers are donated, so that nothing is
     held twice beside a pool that fills the chip."""
     import jax
     import jax.numpy as jnp
 
-    run = engine._run_dispatch
+    run = engine._executor.run
     n_rows = engine.cache["state_len"].shape[0]
     layers = jnp.arange(engine.mcfg.n_layers) * n_rows
     # Times a zero that is an ARGUMENT: written in place into the donated
@@ -60,7 +60,7 @@ def plant(engine, fault: str):
             return put(run(path, name, *args, **kw), rows, S, z)
         return run(path, name, *args, **kw)
 
-    engine._run_dispatch = faulty
+    engine._executor.run = faulty
     return run
 
 
@@ -88,7 +88,7 @@ def main() -> int:
         real = plant(engine, fault)
         numbers = serve.probe_numbers(
             engine, cell.reference(), cell.config, cell.mix, args.seed)
-        engine._run_dispatch = real
+        engine._executor.run = real
         print(f"-- fault planted: {fault}", flush=True)
         per = len(numbers["err"]) // len(cell.mix["probe_prompts"])
         errs = np.asarray(numbers["err"]).reshape(-1, per)
