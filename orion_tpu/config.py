@@ -64,24 +64,59 @@ class LayerKind(typing.NamedTuple):
     n_heads: int                # query heads
     rope: RopeConfig
     moe: bool                   # sparse feed-forward (else dense, d_ff wide)
+    # What the layer's attention computes, and with it what it caches:
+    # "softmax" (K and V in pages), "power_retention" (a state row beside a
+    # paged tail), "latent" (one compressed row a position in pages), "kda"
+    # (a state row and a convolution's tail a slot, no page).
+    attention: str = "softmax"
 
 
 class LayerPlan(typing.NamedTuple):
     """The layer program of a model whose layers differ in shape: ``lead``
-    layers of their own, then ``repeats`` periods of ``period`` layers and a
-    tail of the period's first ``tail`` positions. Position j of the period
-    has its own stacked leaves, ``counts[j]`` = repeats (+ 1 under the tail)
-    layers deep; layer ``lead + g * period + j`` is entry g of stack j."""
+    elements of their own, then ``repeats`` periods of ``period`` elements
+    and a tail of the period's first ``tail`` positions. Position j of the
+    period has its own stacked leaves, ``counts[j]`` = repeats (+ 1 under
+    the tail) deep. An element is ONE layer (``widths`` empty: layer ``lead
+    + g * period + j`` is entry g of stack j) or, in a model of several
+    attention kinds, a RUN of ``widths[e]`` equal layers one behind the
+    other (lead elements first, then the period's positions), which the
+    layer program scans: its leaves are one dimension deeper, ``[width,
+    ...]`` in the lead and ``[counts[j], width, ...]`` in the period, but
+    for a run of one layer, which has no such dimension."""
 
     lead: int
     period: int
     repeats: int
     tail: int
+    widths: Tuple[int, ...] = ()
 
     @property
     def counts(self) -> Tuple[int, ...]:
         return tuple(self.repeats + (j < self.tail)
                      for j in range(self.period))
+
+    def width(self, e: int) -> int:
+        """Layers in element ``e`` (period position j is element lead + j)."""
+        return self.widths[e] if self.widths else 1
+
+    def start(self, e: int) -> int:
+        """The first layer of element ``e`` (in the first period): a layer
+        of the element's kind, and the static index its body is given."""
+        return sum(self.width(i) for i in range(e))
+
+    @property
+    def period_layers(self) -> int:
+        return self.start(self.lead + self.period) - self.start(self.lead)
+
+    def layers(self, e: int):
+        """The layers of element ``e`` in the shape of its leaves' leading
+        dimensions (nested lists; an int for a lead element of one)."""
+        w, first = self.width(e), self.start(e)
+        run = lambda at: at if w == 1 else list(range(at, at + w))
+        if e < self.lead:
+            return run(first)
+        return [run(first + g * self.period_layers)
+                for g in range(self.counts[e - self.lead])]
 
 
 @dataclass(frozen=True)
@@ -152,9 +187,26 @@ class ModelConfig:
     # recurrence too: serving keeps a fixed-size state row a slot and only
     # the positions since the last complete chunk in pages (the chunk is the
     # program's choice, not the model's: ops/retention.fold_chunk).
+    #
+    # "kda" (ops/kda.py, Kimi delta attention): a delta rule under a
+    # per-channel decay behind a depthwise causal convolution of
+    # ``kda_conv_size`` positions, n_heads heads of head_dim keys and
+    # values; the log-decay a key channel is kda_lower_bound x sigmoid(
+    # exp(A_log) (h wf + dt_bias)). With ``layer_group_size`` G (and the
+    # latent sizes below) the layers l with (l + 1) % G == 0 are LATENT
+    # layers among the KDA ones: ``LayerKind.attention`` says which a layer
+    # is, and a layer's attention is one value a LAYER, not a model.
     attention: str = "softmax"
+    layer_group_size: Optional[int] = None
+    kda_conv_size: int = 4
+    kda_lower_bound: float = -5.0
     # RMSNorm over each query and key head before the rotary embedding
-    # (attn.q_norm / attn.k_norm [head_dim]; Qwen3-family).
+    # (attn.q_norm / attn.k_norm [head_dim]; Qwen3-family). On a latent
+    # layer: over each head's qk_nope + qk_rope query numbers (q_norm), and
+    # over the ONE rotary key all heads share (k_norm [qk_rope_head_dim]),
+    # both before the rotation: what a cached row can hold (a norm over a
+    # head's expanded key is a number a head and position, which the
+    # absorbed form cannot carry).
     qk_norm: bool = False
     # Latent attention (kv_lora_rank set; the five sizes go together): a
     # layer projects its input down to ONE row a position, ``kv_lora_rank``
@@ -165,8 +217,10 @@ class ModelConfig:
     # (``v_head_dim``) from the row (training, prefill); the ABSORBED form
     # carries the query into the latent space and attends over the rows
     # themselves (decode). Serving pages hold the row and nothing else
-    # (infer/kv_cache.latent_leaf). head_dim is qk_nope + qk_rope, and
-    # n_kv_heads = n_heads (the expanded form's).
+    # (infer/kv_cache.latent_leaf). head_dim is qk_nope + qk_rope (where
+    # every layer is latent; ``latent_head_dim`` either way), and
+    # n_kv_heads = n_heads (the expanded form's). q_lora_rank None: no
+    # query bottleneck, one ``wq`` [d_model, n_heads x (nope + rope)].
     q_lora_rank: Optional[int] = None
     kv_lora_rank: Optional[int] = None
     qk_nope_head_dim: Optional[int] = None
@@ -211,6 +265,12 @@ class ModelConfig:
     # renormalised over the chosen (+ 1e-20) and scaled.
     router_score: str = "softmax"
     router_bias: bool = False
+    # Group-limited selection: the router's experts in ``n_group`` groups of
+    # equal size; a group's score is the sum of its two largest (score +
+    # bias), the best ``topk_group`` groups are kept and the top-k is taken
+    # inside them. 1 / 1: no groups.
+    n_group: int = 1
+    topk_group: int = 1
     # An expert layer that holds a SHARE of the experts and routes over all
     # of them: router_width is the router's outputs (None => n_experts, the
     # whole layer is here), n_experts stays the number of expert matrices
@@ -334,10 +394,10 @@ class ModelConfig:
         # `is None` first: the override parser maps the literal "none" to
         # None for every field, and None < 1 is a TypeError, not the
         # domain-check message.
-        if self.attention not in ("softmax", "power_retention"):
+        if self.attention not in ("softmax", "power_retention", "kda"):
             raise ValueError(
                 f"model.attention={self.attention!r}; "
-                f"softmax|power_retention")
+                f"softmax|power_retention|kda")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"model.router_score={self.router_score!r}; softmax|sigmoid")
@@ -362,7 +422,60 @@ class ModelConfig:
 
     @property
     def is_latent(self) -> bool:
+        """EVERY layer is a latent layer."""
+        return self.kv_lora_rank is not None and not self.has_kda
+
+    @property
+    def has_latent(self) -> bool:
+        """Some layer is a latent layer (the cache has the latent leaf)."""
         return self.kv_lora_rank is not None
+
+    @property
+    def has_kda(self) -> bool:
+        """Some layer is a KDA layer (the cache has its slot leaves)."""
+        return self.attention == "kda"
+
+    def layer_attention(self, layer: int) -> str:
+        """``LayerKind.attention`` of layer ``layer`` (a Python int)."""
+        if self.has_kda:
+            G = self.layer_group_size
+            return ("latent" if self.has_latent and G and (layer + 1) % G == 0
+                    else "kda")
+        return "latent" if self.has_latent else self.attention
+
+    @property
+    def n_paged_layers(self) -> int:
+        """The layers that keep pages: a cache's paged leaves are [these
+        layers x pages, ...]. All of them, but for a model with KDA layers,
+        whose latent layers alone have any."""
+        return self.n_layers_of("latent") if self.has_kda else self.n_layers
+
+    def n_layers_of(self, attention: str) -> int:
+        return sum(k.attention == attention for k in self.layer_kinds)
+
+    def cache_layer(self, l, j: int):
+        """Layer ``l``'s index among the layers of ITS attention kind: the
+        row of its cache leaves, which are sized over those layers alone.
+        ``l`` may be traced under a layer scan; ``j`` is the static index
+        of the first layer of its element of the plan, a layer of the same
+        kind (``transformer.scan_layer_plan``'s). A model of one kind:
+        ``l``."""
+        plan = self.layer_plan
+        kinds = self.layer_kinds
+        if plan is None or len({k.attention for k in kinds}) == 1:
+            return l
+        att = kinds[j].attention
+        same = [k.attention == att for k in kinds]
+        first = plan.start(plan.lead)
+        if j < first:                   # in a lead run: l - j layers into it
+            return sum(same[:j]) + (l - j)
+        a_period = sum(same[first:first + plan.period_layers])
+        return (sum(same[:j]) + (l - j) // plan.period_layers * a_period
+                + (l - j) % plan.period_layers)
+
+    @property
+    def latent_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     @property
     def latent_row_width(self) -> int:
@@ -458,6 +571,7 @@ class ModelConfig:
                          else self.n_heads_per_layer[l]),
                 rope=sliding if windowed else full,
                 moe=self.is_moe and l >= self.n_dense_layers,
+                attention=self.layer_attention(l),
             ))
         return tuple(kinds)
 
@@ -485,18 +599,43 @@ class ModelConfig:
         (every model of one head count, one rotary table and one
         feed-forward kind: the layer scan and ``window_pattern`` serve it,
         parameter tree and programs as they always were). Else the leading
-        dense layers, the smallest period of what follows, and its tail."""
+        dense layers, the smallest period of what follows, and its tail; in
+        a model of KDA and latent layers the same over RUNS of equal
+        layers (``LayerPlan.widths``)."""
         if (self.layer_types is None and self.n_heads_per_layer is None
-                and self.n_dense_layers == 0):
+                and self.n_dense_layers == 0
+                and not (self.has_kda and self.has_latent)):
             return None
         kinds = self.layer_kinds
+
+        def periodic(rest):
+            p = next((p for p in range(1, len(rest) + 1)
+                      if all(rest[i] == rest[i % p]
+                             for i in range(len(rest)))), 1)
+            return p, len(rest) // p, len(rest) % p
+
         lead = min(self.n_dense_layers, self.n_layers)
-        rest = kinds[lead:]
-        period = next(
-            (p for p in range(1, len(rest) + 1)
-             if all(rest[i] == rest[i % p] for i in range(len(rest)))), 1)
-        return LayerPlan(lead, period, len(rest) // period,
-                         len(rest) % period)
+        if not (self.has_kda and self.has_latent):
+            return LayerPlan(lead, *periodic(kinds[lead:]))
+        # Several attention kinds: the elements are RUNS of equal layers
+        # (five KDA layers to a latent one: a body a run, not a layer), and
+        # the lead goes on over as many runs as leave the fewest bodies
+        # (the published model: two dense and three sparse KDA layers, then
+        # six periods of a latent layer and five KDA layers, and the last
+        # latent layer; its first eight layers: runs of 2, 3, 1 and 2).
+        runs = []
+        for k in kinds:
+            if runs and runs[-1][0] == k:
+                runs[-1][1] += 1
+            else:
+                runs.append([k, 1])
+        runs = [tuple(r) for r in runs]
+        dense = sum(not k.moe for k, _ in runs)
+        lead = min(range(dense, len(runs) + 1), key=lambda e: (
+            e + sum(periodic(runs[e:])[::2]), e))
+        period, repeats, tail = periodic(runs[lead:])
+        return LayerPlan(lead, period, repeats, tail,
+                         tuple(w for _, w in runs[:lead + period]))
 
     def num_params(self) -> int:
         """Approximate parameter count (embeddings + blocks + norms)."""
@@ -2190,6 +2329,77 @@ def _p_tiny_glm() -> Config:
         data=DataConfig(batch_size=4, seq_len=64),
         inference=InferenceConfig(max_seq_len=128, page_size=8,
                                   num_pages=128, max_batch_size=4,
+                                  prefill_chunk=16, decode_window=4),
+    )
+
+
+def _ling_flash_model(**kw) -> ModelConfig:
+    """Ling-3.0-flash (inclusionAI, config.json, model_type bailing_hybrid):
+    Kimi-delta-attention layers (32 heads of 128 keys and values behind a
+    convolution of 4 positions, a bounded per-channel decay) with every
+    sixth layer a latent layer (one ``wq``, one row of 512 + 64 a position,
+    32 heads of 128 | 64 and values of 128, a norm on queries and the rotary
+    key, a per-head output gate); two leading dense layers, then 512 experts
+    768 wide in 8 groups of which the best 4 are kept, top-8 of sigmoid
+    scores under a selection bias, gates renormalised and scaled by 2.5,
+    and a shared expert. The multi-token-prediction module is no part of
+    the served logits and is left out."""
+    base = dict(
+        name="ling-3.0-flash", vocab_size=157_184, max_seq_len=262_144,
+        d_model=2560, n_layers=42, n_heads=32, n_kv_heads=32, head_dim=128,
+        d_ff=6144, pos_embedding="rope", rope_theta=6_000_000.0,
+        norm="rmsnorm", norm_eps=1e-6, activation="swiglu",
+        tie_embeddings=False,
+        attention="kda", layer_group_size=6, kda_conv_size=4,
+        kda_lower_bound=-5.0,
+        q_lora_rank=None, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, qk_norm=True,
+        attn_gate="per-head",
+        n_experts=512, router_width=512, n_experts_per_token=8,
+        n_dense_layers=2, moe_d_ff=768, shared_expert_d_ff=768,
+        router_scale=2.5, router_score="sigmoid", router_bias=True,
+        n_group=8, topk_group=4,
+        # Dropless: an expert's bucket holds every row of a decode block
+        # from router_width / top-k = 64 up (the published model drops none).
+        capacity_factor=64.0,
+        dtype="bfloat16", param_dtype="bfloat16", kernels="pallas",
+        remat="full",
+    )
+    base.update(kw)
+    return ModelConfig(**base)
+
+
+@register_preset("ling-3.0-flash")
+def _p_ling_flash() -> Config:
+    """Ling-3.0-flash at its published sizes, for serving (a deployment
+    holds a share: model.n_experts / model.expert_offset, the vocabulary
+    and the depth its chips hold)."""
+    return Config(
+        model=_ling_flash_model(),
+        inference=InferenceConfig(max_seq_len=12_288, page_size=64),
+    )
+
+
+@register_preset("tiny-ling")
+def _p_tiny_ling() -> Config:
+    """Tiny Ling-family model for CPU tests: two dense layers and one period
+    (KDA x3, latent, KDA x2); 4 heads of 16 (KDA) and of 16 | 8 with values
+    of 16 (latent); 16 experts in 4 groups of which 2 are kept, top-4, a
+    shared one, sigmoid scores under a bias."""
+    return Config(
+        model=_ling_flash_model(
+            name="tiny-ling", vocab_size=256, max_seq_len=128, d_model=64,
+            n_layers=8, n_heads=4, n_kv_heads=4, head_dim=16, d_ff=128,
+            kv_lora_rank=48, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16,
+            n_experts=16, router_width=16, n_experts_per_token=4,
+            n_group=4, topk_group=2, moe_d_ff=32, shared_expert_d_ff=32,
+            capacity_factor=4.0,
+            dtype="float32", param_dtype="float32", kernels="xla",
+            remat="none"),
+        data=DataConfig(batch_size=4, seq_len=64),
+        inference=InferenceConfig(max_seq_len=128, page_size=8,
+                                  num_pages=64, max_batch_size=4,
                                   prefill_chunk=16, decode_window=4),
     )
 

@@ -23,6 +23,7 @@ import collections
 import dataclasses
 import itertools
 import logging
+import math
 import time
 from functools import lru_cache, partial
 from typing import Any, Optional, Sequence
@@ -183,13 +184,23 @@ class InferenceEngine:
                 "keeps a fixed-size state a request "
                 "(model.attention=power_retention)")
             refused += kv_only
-        if self.mcfg.is_latent:
+        if self.mcfg.has_latent:
             # A latent cache holds one compressed row a position, which the
             # prefix gather, the chunk rows, the host tier's paging, the
             # int8 pools and the verify kernel do not read yet.
             why.append(
                 "caches one compressed row a position "
                 "(model.kv_lora_rank)")
+            refused += kv_only
+        if self.mcfg.has_kda:
+            # A KDA layer keeps a state row and a convolution's tail a
+            # slot and no page: no snapshot of the rows at a page boundary
+            # (a cached prefix, a chunk to resume from, a host tier to page
+            # to), no rollback of a step that advanced them (drafts), no
+            # int8 form of them.
+            why.append(
+                "keeps a state row a request and no page in its KDA layers "
+                "(model.attention=kda)")
             refused += kv_only
         off = list(dict.fromkeys(name for name, on in refused if on))
         if off:
@@ -261,7 +272,7 @@ class InferenceEngine:
             _detect_tp_mesh(self.params)
             if resolve_impl(self.mcfg.kernels)[0] else None
         )
-        if self.mesh is not None and self.mcfg.is_latent:
+        if self.mesh is not None and self.mcfg.has_latent:
             raise ValueError(
                 "a latent-attention model is served on one device: its "
                 "decode kernel is not run per shard yet")
@@ -310,10 +321,10 @@ class InferenceEngine:
         # scatter donates the pool like every other cache-updating
         # program.
         self._gather_pages = _gather_pages_jit(
-            self.mcfg.n_layers, self.icfg.num_pages
+            self.mcfg.n_paged_layers, self.icfg.num_pages
         )
         self._scatter_pages = _scatter_pages_jit(
-            self.mcfg.n_layers, self.icfg.num_pages
+            self.mcfg.n_paged_layers, self.icfg.num_pages
         )
         if self.icfg.host_tier_bytes > 0:
             if not (self.icfg.prefix_cache or self._long):
@@ -323,7 +334,7 @@ class InferenceEngine:
                     "the radix tree) or inference.long_context=true "
                     "(per-request paging owns its slots directly)"
                 )
-            pb = host_page_bytes(self.cache, self.mcfg.n_layers)
+            pb = host_page_bytes(self.cache, self.mcfg.n_paged_layers)
             cap = self.icfg.host_tier_bytes // pb
             if cap < 1:
                 raise ValueError(
@@ -365,7 +376,7 @@ class InferenceEngine:
         self._cow = jax.jit(
             partial(
                 copy_page,
-                n_layers=self.mcfg.n_layers,
+                n_layers=self.mcfg.n_paged_layers,
                 num_pages=self.icfg.num_pages,
             ),
             donate_argnums=(0,),
@@ -424,11 +435,14 @@ class InferenceEngine:
         # What a window-aware allocator would know (the counters
         # kv_dead_window_page_layers / kv_live_page_layers): how many
         # layers read only their window of a context whose pages all stay.
+        # Over the layers that keep K and V in pages alone (decode_kv_*).
         self._layers_by_window = collections.Counter(
-            k.window for k in self.mcfg.layer_kinds)
+            k.window for k in self.mcfg.layer_kinds
+            if k.attention == "softmax")
         self._window_layers = (
             0 if self.page_window is not None
-            else self.mcfg.n_layers - self._layers_by_window[None])
+            else sum(self._layers_by_window.values())
+            - self._layers_by_window[None])
         # Decode window: mutable engine state (inference.decode_window is
         # only the starting point when auto-tune is on). Page provisioning
         # and admission always budget for _provision_window, so growth can
@@ -472,7 +486,7 @@ class InferenceEngine:
         self._poison = jax.jit(
             partial(
                 poison_page,
-                n_layers=self.mcfg.n_layers,
+                n_layers=self.mcfg.n_paged_layers,
                 num_pages=self.icfg.num_pages,
             ),
             donate_argnums=(0,),
@@ -480,7 +494,7 @@ class InferenceEngine:
         self._scrub = jax.jit(
             partial(
                 scrub_pages,
-                n_layers=self.mcfg.n_layers,
+                n_layers=self.mcfg.n_paged_layers,
                 num_pages=self.icfg.num_pages,
             ),
             donate_argnums=(0,),
@@ -611,7 +625,7 @@ class InferenceEngine:
                 kv_quant=self.icfg.kv_quant,
                 dtype_itemsize=jnp.dtype(self.mcfg.dtype).itemsize,
             )
-        if (self.icfg.paged_prefill and not self.mcfg.is_latent
+        if (self.icfg.paged_prefill and not self.mcfg.has_latent
                 and resolve_impl(self.mcfg.kernels)[0]):
             # Same init-time VMEM gate for the paged-flash prefill
             # kernel: its blocks are page-sized (one page of queries x
@@ -1486,6 +1500,18 @@ class InferenceEngine:
             # over the layers). Host arithmetic on lengths.
             "decode_latent_token_layers": 0, "prefill_attn_pairs": 0,
             "latent_live_page_bytes": 0, "latent_live_tokens": 0,
+            # A model with KDA layers (all 0 for any other; its latent
+            # counters above are over its latent layers alone): per token
+            # step, the live slots x the KDA layers, each one state row the
+            # decode kernel reads and writes (decode_kda_slot_layers); the
+            # real prompt positions x the KDA layers a prefill's chunked
+            # form computes (prefill_kda_token_layers); and, summed at each
+            # decode window, the bytes of the live slots' state and
+            # convolution rows (kda_live_state_bytes; over
+            # latent_live_tokens with latent_live_page_bytes: what a cached
+            # token costs). Host arithmetic on lengths.
+            "decode_kda_slot_layers": 0, "prefill_kda_token_layers": 0,
+            "kda_live_state_bytes": 0,
             # Prefill sizing: prefill_tokens counts the real prompt
             # positions the prefill dispatches computed (prefix-cached
             # positions excluded), prefill_pad_tokens the rest of each
@@ -2641,6 +2667,11 @@ class InferenceEngine:
                 f"model {self.mcfg.name!r} keeps a state row a request "
                 f"(model.attention=power_retention), which migration does "
                 f"not ship yet")
+        if self.mcfg.has_kda:
+            raise ValueError(
+                f"model {self.mcfg.name!r} keeps a state row a request and "
+                f"no page in its KDA layers (model.attention=kda), which "
+                f"migration, a copy of pages, does not ship")
         if self.mcfg.is_latent:
             raise ValueError(
                 f"model {self.mcfg.name!r} caches one compressed row a "
@@ -3198,8 +3229,10 @@ class InferenceEngine:
             slots[: len(reqs)] = [r.slot for r in reqs]
             # A power-retention model: the state row each row of the burst
             # owns (slot + 1; padding rows take scratch row 0).
-            state_rows = None if self._chunk is None else np.where(
-                slots < self.max_batch, slots + 1, 0).astype(np.int32)
+            state_rows = (
+                None if self._chunk is None and not self.mcfg.has_kda
+                else np.where(
+                    slots < self.max_batch, slots + 1, 0).astype(np.int32))
             for i, req in enumerate(reqs):
                 npre = req.n_prefix
                 tail = req.context[npre * self.psz:]
@@ -3275,9 +3308,13 @@ class InferenceEngine:
                 self.timing["prefill_retention_units"] += (
                     self.mcfg.n_layers
                     * query_units(n, self.mcfg.resolved_head_dim))
-        if self.mcfg.is_latent:
-            self.timing["prefill_attn_pairs"] += self.mcfg.n_layers * sum(
-                int(n) * (int(n) + 1) // 2 for n in lengths[: len(reqs)])
+        if self.mcfg.has_latent:
+            self.timing["prefill_attn_pairs"] += (
+                self.mcfg.n_layers_of("latent") * sum(
+                    int(n) * (int(n) + 1) // 2 for n in lengths[: len(reqs)]))
+        if self.mcfg.has_kda:
+            self.timing["prefill_kda_token_layers"] += (
+                self.mcfg.n_layers_of("kda") * real)
         if self.mcfg.is_moe:
             # Pad rows have length 1, so one position of each routes too.
             self.timing["prefill_expert_rows"] += expert_rows(
@@ -4165,12 +4202,19 @@ class InferenceEngine:
             self.timing["decode_tail_token_layers"] += L * int(
                 (lens - folded).sum() * W + len(active) * (W * (W + 1) // 2))
             return active, W, common
-        if self.mcfg.is_latent:
+        if self.mcfg.has_kda:
+            L = self.mcfg.n_layers_of("kda")
+            self.timing["decode_kda_slot_layers"] += L * W * len(active)
+            self.timing["kda_live_state_bytes"] += len(active) * sum(
+                math.prod(self.cache[name].shape[2:])
+                * self.cache[name].dtype.itemsize * L
+                for name in ("kda_state", "kda_conv"))
+        if self.mcfg.has_latent:
             # The rows the latent decode kernel reads, and what the pool
             # holds for the live slots at this window: whole pages (the
             # ones provisioned for the window ahead among them) of the
             # leaf's own row width, padding included.
-            L = self.mcfg.n_layers
+            L = self.mcfg.n_layers_of("latent")
             self.timing["decode_latent_token_layers"] += L * kv
             held = sum(p is not None for r in active for p in r.pages)
             self.timing["latent_live_page_bytes"] += (

@@ -143,7 +143,7 @@ class DispatchExecutor:
             nothing (a warm-up) gets as placeholders of the same shapes and
             dtypes, so that it compiles the program the engine runs."""
             nb = tokens.shape[0]
-            if state_rows is None and mcfg.is_retention:
+            if state_rows is None and (mcfg.is_retention or mcfg.has_kda):
                 state_rows = jnp.zeros((nb,), jnp.int32)    # the scratch row
             if slots is None:
                 # Out of range: the scatter of the picks drops every row.

@@ -101,10 +101,32 @@ def latent_width(mcfg: ModelConfig) -> int:
 def latent_leaf(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> "Cache":
     """A latent-attention model's whole cache: ONE row a token and layer
     (the normed compressed row | the shared rotary key | zeros), in the
-    paged layout with one "head", [layers x pages, 1, page, width]."""
+    paged layout with one "head", [layers x pages, 1, page, width]; the
+    layers are the latent ones (``ModelConfig.n_paged_layers``)."""
     return {LATENT: jnp.zeros(
-        (mcfg.n_layers * icfg.num_pages, 1, icfg.page_size,
+        (mcfg.n_paged_layers * icfg.num_pages, 1, icfg.page_size,
          latent_width(mcfg)), dtype)}
+
+
+KDA_STATE, KDA_CONV = "kda_state", "kda_conv"
+
+
+def kda_leaves(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> "Cache":
+    """What a model's KDA layers keep: a SLOT's rows and no page, [KDA
+    layers, slots + 1, ...] with row 0 a scratch row as page 0 is and slot b
+    owning row b + 1. ``kda_state`` [.., heads, d_v, d_k] float32 is the
+    recurrence's state, value-major (the transpose of ``ops/kda.py``'s S:
+    the decode kernel broadcasts a key channel's decay over lanes);
+    ``kda_conv`` [.., K - 1, 3 x heads x d] the last K - 1 rows of q | k | v
+    BEFORE the convolution. A prefill writes a slot's rows whole (from a
+    zero state), a decode step updates them in place; nothing folds."""
+    L, slots = mcfg.n_layers_of("kda"), icfg.max_batch_size + 1
+    N, H = mcfg.n_heads, mcfg.resolved_head_dim
+    return {
+        KDA_STATE: jnp.zeros((L, slots, N, H, H), jnp.float32),
+        KDA_CONV: jnp.zeros(
+            (L, slots, mcfg.kda_conv_size - 1, 3 * N * H), dtype),
+    }
 
 
 def init_cache(
@@ -136,6 +158,13 @@ def init_cache(
         if icfg.kv_quant is not None:
             raise ValueError(f"unknown inference.kv_quant={icfg.kv_quant!r}")
         dtype = jnp.dtype(mcfg.dtype)
+        if mcfg.has_kda:
+            if not mcfg.has_latent:
+                raise ValueError(
+                    "a model of KDA layers alone has no paged layer, which "
+                    "the engine's allocator counts sequences by: not served")
+            return {**latent_leaf(mcfg, icfg, dtype),
+                    **kda_leaves(mcfg, icfg, dtype)}
         if mcfg.is_latent:
             return latent_leaf(mcfg, icfg, dtype)
         cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -151,6 +180,8 @@ def init_cache(
 
 # The leaves of ``retention_leaves`` that are a slot's and not a page's.
 SLOT_LEAVES = ("state", "state_z", "state_len", "g")
+# Every leaf of any backend that is a slot's: what page operations pass by.
+NOT_PAGED = SLOT_LEAVES + (KDA_STATE, KDA_CONV)
 
 
 def retention_leaves(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> Cache:
@@ -368,7 +399,7 @@ def scrub_pages(
     ).reshape(-1)
     out = {}
     for name, arr in cache.items():
-        if name in SLOT_LEAVES:     # no page: the next prefill writes the row
+        if name in NOT_PAGED:       # no page: the next prefill writes the row
             out[name] = arr
         else:                       # [layers x pages, ...]
             out[name] = arr.at[layer_rows].set(jnp.zeros((), arr.dtype))
@@ -540,11 +571,13 @@ def scatter_pages(
 
 
 def host_page_bytes(cache: Cache, n_layers: int) -> int:
-    """Host bytes one pool page occupies across every cache array (all
-    layers; scale pools included under kv_quant) — the unit the
+    """Host bytes one pool page occupies across every paged cache array
+    (``n_layers`` layers; scale pools included under kv_quant) — the unit the
     ``inference.host_tier_bytes`` budget is divided by."""
     total = 0
-    for arr in cache.values():
+    for name, arr in cache.items():
+        if name in NOT_PAGED:
+            continue
         per_row = math.prod(arr.shape[1:]) * arr.dtype.itemsize
         total += n_layers * per_row
     return total

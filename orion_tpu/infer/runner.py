@@ -44,12 +44,18 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.config import ModelConfig
-from orion_tpu.infer.kv_cache import LATENT, page_geometry
+from orion_tpu.infer.kv_cache import (
+    KDA_CONV,
+    KDA_STATE,
+    LATENT,
+    page_geometry,
+)
 from orion_tpu.models import moe as moe_lib
 from orion_tpu.models.transformer import (
     Params,
     block,
     embed,
+    kda_activate,
     latent_absorb,
     latent_attention,
     latent_unabsorb,
@@ -154,7 +160,7 @@ def _prefill_ctx(
     from orion_tpu.ops._dispatch import resolve_impl
 
     Nb, S_pad = tokens.shape
-    psz, NP = page_geometry(cache, cfg.n_layers)
+    psz, NP = page_geometry(cache, cfg.n_paged_layers)
     P_pre = 0 if prefix_pages is None else prefix_pages.shape[1]
     use_pallas, interpret = resolve_impl(cfg.kernels)
     paged = bool(paged_prefill and P_pre and use_pallas and S_pad % psz == 0)
@@ -282,14 +288,24 @@ def _dense_layer(
     valid = seg > 0
     if ctx["moe_stack"] is not None:
         stack = (ctx["moe_stack"], l)
-    # Where ``prefill_step`` carries the counter (a model that holds a share
-    # of its experts), add this layer's routed rows on held experts.
+    return _prefill_block(x, cc, bp, j, positions, attend, valid, stack, cfg,
+                          mesh)
+
+
+def _prefill_block(x, cc: Cache, bp: Any, j: int, positions, attend, valid,
+                   stack, cfg: ModelConfig, mesh) -> tuple[jax.Array, Cache]:
+    """``transformer.block`` for one layer of a prefill, whatever its
+    backend: ``attend`` hands back what the layer writes (traced behind the
+    feed-forward, under the cache part), and where ``prefill_step`` carries
+    the counter (a model that holds a share of its experts) this layer's
+    routed rows on held experts are added to it."""
     held, tap = cc.get(HELD_ROWS), None
     if held is not None and "moe" in bp:
         def tap(h2):
             nonlocal held
             held = held + moe_lib.held_rows(
-                h2, bp["moe"]["router"], cfg, valid)
+                h2, bp["moe"]["router"], cfg, valid,
+                bp["moe"].get("router_bias"))
 
     x, _, written = block(
         x, bp, cfg, positions, attend, kind=_kind(cfg, j), mesh=mesh,
@@ -452,11 +468,22 @@ def _prefill(params, cache, tokens, lengths, pages, prefix_lens,
         raise ValueError(
             "a latent-attention model prefills whole prompts: a cached "
             "prefix would have to be expanded again, which no path does")
+    if cfg.has_kda and prefix_pages is not None and prefix_pages.shape[1]:
+        raise ValueError(
+            "a model with KDA layers prefills whole prompts: a cached prefix "
+            "would need its state, which nothing snapshots")
     ctx = _prefill_ctx(
         params, cache, tokens, lengths, pages, prefix_lens, prefix_pages,
         cfg, paged_prefill=paged_prefill,
     )
-    layer = _latent_prefill_layer if cfg.is_latent else _dense_layer
+    if cfg.has_kda:
+        # The state row each row of the burst owns (slot + 1; padding rows
+        # and a caller that says nothing take scratch row 0).
+        ctx["state_rows"] = (jnp.zeros((tokens.shape[0],), jnp.int32)
+                             if state_rows is None else state_rows)
+        layer = _hybrid(_kda_prefill_layer, _latent_prefill_layer)
+    else:
+        layer = _latent_prefill_layer if cfg.is_latent else _dense_layer
 
     def body(carry, bp, l, j, stack=None):
         x, cc = carry
@@ -483,13 +510,21 @@ def _decode_core(
     page_table: jax.Array,    # [B, pages_per_seq] int32 (per-layer-relative)
     cfg: ModelConfig,
     mesh: Optional[jax.sharding.Mesh] = None,
+    active: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, Cache]:
     """One decode forward for every slot -> (logits [B, V], cache'): the
     paged backend at W = 1 (the retained backend for a power-retention
     model: it reads a slot's state row and never writes it, so the body can
     be run again on the cache it handed back; the latent backend for a
-    latent-attention model)."""
-    if cfg.is_retention:
+    latent-attention model; the KDA backend beside it for a model with KDA
+    layers, which ADVANCES the state rows of the ``active`` slots, default
+    all: run again on the cache it handed back it computes the next
+    position's step)."""
+    if cfg.has_kda:
+        ctx = {**_latent_ctx(cache, write_pos, page_table, cfg),
+               "active": active}
+        layer = _hybrid(_kda_layer, _latent_layer)
+    elif cfg.is_retention:
         ctx, layer = _retained_ctx(
             cache, write_pos, page_table, cfg), _retained_layer
     elif cfg.is_latent:
@@ -557,7 +592,10 @@ def decode_window(
             tok, sl, cc = carry
         act = active & (sl < max_seq_len)
         wp = jnp.minimum(sl, max_seq_len - 1)
-        logits, cc = _decode_core(params, cc, tok, wp, page_table, cfg, mesh)
+        # ``act``: a KDA layer's state rows advance where live (no other
+        # backend reads it).
+        logits, cc = _decode_core(
+            params, cc, tok, wp, page_table, cfg, mesh, active=act)
         toks = sample(
             logits, sub, temperature=temperature, top_k=top_k, top_p=top_p
         )
@@ -625,7 +663,7 @@ def _paged_ctx(
     verify; with both None this function is untouched (same trace).
     """
     B = seq_lens.shape[0]
-    psz, NP = page_geometry(cache, cfg.n_layers)
+    psz, NP = page_geometry(cache, cfg.n_paged_layers)
     P = page_table.shape[1]
     batch_idx = jnp.arange(B)[:, None]
     steps = jnp.arange(W, dtype=jnp.int32)[None, :]
@@ -721,7 +759,7 @@ def _one_token_ctx(
     return _paged_ctx(
         cache, write_pos, jnp.ones_like(write_pos), page_table,
         jnp.ones(write_pos.shape, bool), 1,
-        page_table.shape[1] * page_geometry(cache, cfg.n_layers)[0], cfg,
+        page_table.shape[1] * page_geometry(cache, cfg.n_paged_layers)[0], cfg,
         name="paged_decode")
 
 
@@ -1124,6 +1162,7 @@ def _latent_prefill_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
     feed-forward, as the dense backend's scatter is)."""
     psz, NP, seg = ctx["psz"], ctx["NP"], ctx["seg"]
     pool = cc[LATENT]
+    l = cfg.cache_layer(l, j)       # among the layers that keep pages
 
     def attend(q, row, wkv_b):
         with jax.named_scope("kernel"):
@@ -1140,12 +1179,8 @@ def _latent_prefill_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
 
         return out, written
 
-    valid = seg > 0
-    x, _, written = block(
-        x, bp, cfg, ctx["positions"], attend, kind=_kind(cfg, j), mesh=mesh,
-        ffn_mesh=mesh, valid=valid, layer_stack=stack)
-    with jax.named_scope(_CACHE_PART):
-        return x, {**cc, **written()}
+    return _prefill_block(x, cc, bp, j, ctx["positions"], attend, seg > 0,
+                          stack, cfg, mesh)
 
 
 def _latent_ctx(cache: Cache, pos: jax.Array, page_table: jax.Array,
@@ -1155,7 +1190,7 @@ def _latent_ctx(cache: Cache, pos: jax.Array, page_table: jax.Array,
     reach, as for ``_one_token_ctx``)."""
     from orion_tpu.ops._dispatch import resolve_impl
 
-    psz, NP = page_geometry(cache, cfg.n_layers)
+    psz, NP = page_geometry(cache, cfg.n_paged_layers)
     P = page_table.shape[1]
     at = jnp.minimum(pos, P * psz - 1)
     use_pallas, interpret = resolve_impl(cfg.kernels)
@@ -1175,8 +1210,9 @@ def _latent_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
     comes back through W_uv. XLA branch: scatter + masked padded-context
     gather, the reference."""
     NP, page_table = ctx["NP"], ctx["page_table"]
-    R, scale = cfg.kv_lora_rank, cfg.resolved_head_dim ** -0.5
+    R, scale = cfg.kv_lora_rank, cfg.latent_head_dim ** -0.5
     pool = cc[LATENT]
+    l = cfg.cache_layer(l, j)       # among the layers that keep pages
 
     def attend(q, row, wkv_b):
         pad = pool.shape[-1] - row.shape[-1]
@@ -1219,6 +1255,109 @@ def _latent_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
     return x, cc
 
 
+# -- the KDA backend: a state row and a convolution's tail a slot, no page -----
+#
+# A KDA layer (model.attention=kda; ops/kda.py) keeps of a sequence its
+# recurrence's state and the last rows in front of its convolution, both a
+# SLOT's (``kv_cache.kda_leaves``: row slot + 1 of the layer's index among
+# the KDA layers, ``cfg.cache_layer``). Prefill computes a whole prompt in
+# the chunked form from a zero state and writes the slot's rows whole; a
+# decode step advances them in place (inside the kernel on the pallas path,
+# the state aliased through it) for the slots that are live. Such layers
+# stand AMONG latent layers in one layer plan: ``_hybrid`` picks a layer's
+# backend by its kind, and the latent layers' pool is sized over them alone.
+
+
+def _hybrid(kda_layer, latent_layer):
+    """One layer function for a model whose layers differ in backend."""
+    def layer(x, cc, bp, l, j, ctx, cfg, *rest):
+        fn = (kda_layer if cfg.layer_kind(j).attention == "kda"
+              else latent_layer)
+        return fn(x, cc, bp, l, j, ctx, cfg, *rest)
+
+    return layer
+
+
+def _kda_prefill_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
+                       cfg: ModelConfig, mesh, stack=None
+                       ) -> tuple[jax.Array, Cache]:
+    """One KDA layer of whole-prompt prefill: the convolution and the
+    chunked form over the block's own rows; each row's final state and the
+    convolution's tail into its slot's rows (behind the feed-forward, as
+    the other backends' writes are)."""
+    from orion_tpu.ops.kda import kda_chunked, short_conv
+
+    ki, rows, lengths = cfg.cache_layer(l, j), ctx["state_rows"], ctx["lengths"]
+
+    def attend(xs, g, b, conv):
+        with jax.named_scope("qkv"), jax.named_scope("kda/conv"):
+            y, tail = short_conv(xs, conv, lengths)
+            q, k, v = kda_activate(y, cfg)
+        with jax.named_scope("kernel"):
+            o, state = kda_chunked(q, k, v, g, b, lengths=lengths)
+
+        def written():
+            return {
+                KDA_STATE: cc[KDA_STATE].at[ki, rows].set(
+                    jnp.swapaxes(state, -1, -2)),
+                KDA_CONV: cc[KDA_CONV].at[ki, rows].set(
+                    tail.astype(cc[KDA_CONV].dtype)),
+            }
+
+        return o.astype(xs.dtype), written
+
+    return _prefill_block(x, cc, bp, j, ctx["positions"], attend,
+                          ctx["seg"] > 0, stack, cfg, mesh)
+
+
+def _kda_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
+               cfg: ModelConfig, mesh) -> tuple[jax.Array, Cache]:
+    """The KDA backend: one layer of one new token a slot. The slot's
+    convolution tail takes the new row and its state advances one position
+    (``ctx['active']`` [B]: the slots that do; None: all), both in place."""
+    from orion_tpu.ops.kda import kda_step, short_conv_step
+
+    ki, active = cfg.cache_layer(l, j), ctx["active"]
+    B = ctx["at"].shape[0]
+
+    def attend(xs, g, b, conv):
+        tails = cc[KDA_CONV]
+        at = (ki, 1, 0, 0)                      # the slots' rows of the layer
+        with jax.named_scope("cache"):
+            tail = jax.lax.dynamic_slice(
+                tails, at, (1, B, *tails.shape[2:]))[0]
+        with jax.named_scope("qkv"), jax.named_scope("kda/conv"):
+            y, moved = short_conv_step(xs[:, 0], tail, conv)
+            q, k, v = kda_activate(y, cfg)
+        with jax.named_scope("kernel"):
+            if ctx["use_pallas"]:
+                if mesh is not None:
+                    raise ValueError("the KDA decode kernel runs on one device")
+                from orion_tpu.ops.pallas.kda import kda_decode
+
+                o, state = kda_decode(
+                    cc[KDA_STATE], q, k, v, g[:, 0], b[:, 0], layer=ki,
+                    active=active, interpret=ctx["interpret"])
+            else:
+                st = cc[KDA_STATE]
+                at_s = (ki, 1, 0, 0, 0)
+                o, new = kda_step(
+                    jax.lax.dynamic_slice(
+                        st, at_s, (1, B, *st.shape[2:]))[0],
+                    q, k, v, g[:, 0], b[:, 0], active)
+                state = jax.lax.dynamic_update_slice(st, new[None], at_s)
+        with jax.named_scope("cache"):
+            if active is not None:
+                moved = jnp.where(active[:, None, None], moved, tail)
+            tails = jax.lax.dynamic_update_slice(tails, moved[None], at)
+        return (o[:, None].astype(xs.dtype),
+                {**cc, KDA_STATE: state, KDA_CONV: tails})
+
+    x, _, cc = block(x, bp, cfg, ctx["positions"], attend,
+                     kind=_kind(cfg, j), mesh=mesh)
+    return x, cc
+
+
 # -- the retained backend: a fixed-size state row a slot beside a paged tail --
 #
 # A power-retention model (model.attention, ops/retention.py) keeps of a
@@ -1250,7 +1389,7 @@ def _retained_prefill(params, cache, tokens, lengths, pages, state_rows,
     from orion_tpu.ops.retention import chunk_cumsum, power_retention
 
     Nb, S_pad = tokens.shape
-    _, NP = page_geometry(cache, cfg.n_layers)
+    _, NP = page_geometry(cache, cfg.n_paged_layers)
     n_rows = cache["state_len"].shape[0]
     C, T = _chunk(cfg), cache["g"].shape[-1]
     if state_rows is None:
@@ -1311,7 +1450,7 @@ def _retained_ctx(cache: Cache, pos: jax.Array, page_table: jax.Array,
     position ``pos`` (slot b of the page table owns state row b + 1)."""
     from orion_tpu.ops._dispatch import resolve_impl
 
-    psz, NP = page_geometry(cache, cfg.n_layers)
+    psz, NP = page_geometry(cache, cfg.n_paged_layers)
     C = _chunk(cfg)
     F = cache["state_len"][1:]                                 # [B]
     at = jnp.minimum(pos, page_table.shape[1] * psz - 1)
@@ -1401,7 +1540,7 @@ def fold_step(cache: Cache, slot: jax.Array, page_row: jax.Array, *,
     from orion_tpu.ops.retention import retention_fold_xla
 
     del mesh
-    psz, NP = page_geometry(cache, cfg.n_layers)
+    psz, NP = page_geometry(cache, cfg.n_paged_layers)
     n_rows = cache["state_len"].shape[0]
     C, P = _chunk(cfg), page_row.shape[0]
     use_pallas, interpret = resolve_impl(cfg.kernels)

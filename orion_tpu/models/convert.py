@@ -37,7 +37,17 @@ def _has_importer(cfg: ModelConfig) -> None:
     """A latent-attention model's projections (``wq_a``, ``q_a_norm``,
     ``wq_b``, ``wkv_a``, ``kv_a_norm``, ``wkv_b``; a router's selection
     bias) have no slot in any schema here: say so, where a converter would
-    otherwise fail on the first key it misses (ROADMAP R11)."""
+    otherwise fail on the first key it misses (ROADMAP R11). Nor has a
+    ``bailing_hybrid`` checkpoint (Ling-3.0-flash: KDA layers' ``conv`` /
+    ``wf`` / ``a_log`` / ``dt_bias`` / ``wb`` / ``wg`` / ``o_norm`` among
+    latent layers, whose rotary columns it stores interleaved): no importer
+    is written for it, as for the other layer-plan models."""
+    if cfg.has_kda:
+        raise ValueError(
+            f"model {cfg.name!r} has Kimi-delta-attention layers among "
+            f"latent layers (model.attention=kda): no converter here reads "
+            f"or writes the bailing_hybrid key set; it is served from "
+            f"seeded weights only (ROADMAP R11)")
     if cfg.is_latent:
         raise ValueError(
             f"model {cfg.name!r} has latent-attention projections "

@@ -84,7 +84,12 @@ def _router_topk(
     idx [B,S,k] int32). ONE function with two scorings
     (``model.router_score``): a softmax over the experts, or each logit's
     sigmoid with the top-k chosen on score + ``bias`` [E] (``moe.router_bias``,
-    under ``model.router_bias``) and the gates the scores WITHOUT it.
+    under ``model.router_bias``) and the gates the scores WITHOUT it. With
+    ``model.n_group`` > 1 the choice is group-limited: the experts in
+    n_group groups of equal size, a group's score the sum of its two largest
+    (score + bias), the best ``model.topk_group`` groups kept and the top-k
+    taken inside them (the others' scores set to 0 before it, as the
+    published code does; the gates still read the scores themselves).
 
     Top-k is argsort + a one-hot product rather than ``lax.top_k`` +
     gather: identical values/indices (verified in tests), negligible cost
@@ -101,6 +106,8 @@ def _router_topk(
         chosen_on = probs if bias is None else probs + bias.astype(probs.dtype)
     else:
         chosen_on = probs = jax.nn.softmax(logits, axis=-1)
+    if cfg.n_group > 1:
+        chosen_on = _keep_groups(chosen_on, cfg)
     idx = jnp.argsort(-chosen_on, axis=-1)[
         ..., : cfg.n_experts_per_token
     ].astype(jnp.int32)
@@ -120,6 +127,18 @@ def _router_topk(
         # What the load-balance statistics read: the scores as shares.
         probs = probs / probs.sum(-1, keepdims=True)
     return probs, gate, idx
+
+
+def _keep_groups(scores: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """``scores`` [B, S, E] with every expert outside the best
+    ``topk_group`` of ``n_group`` groups set to 0 (a group's score: the sum
+    of its two largest; argsort + one-hot as in ``_router_topk``)."""
+    G = cfg.n_group
+    grouped = scores.reshape(*scores.shape[:-1], G, -1)       # [B, S, G, E/G]
+    best2 = -jnp.sort(-grouped, axis=-1)[..., :2].sum(-1)     # [B, S, G]
+    kept = jnp.argsort(-best2, axis=-1)[..., : cfg.topk_group]
+    keep = jax.nn.one_hot(kept, G, dtype=scores.dtype).sum(-2)  # [B, S, G]
+    return jnp.where(keep[..., None] > 0, grouped, 0.0).reshape(scores.shape)
 
 
 @jax.named_scope("router")
@@ -375,10 +394,10 @@ def moe_mlp_sorted_a2a(
         )
 
     has_gate = "w_gate" in params
-    if "router_bias" in params:
+    if "router_bias" in params or cfg.n_group > 1:
         raise ValueError(
-            "model.router_bias is not carried into moe_dispatch=sorted_a2a's "
-            "shard_map: use moe_dispatch=sorted")
+            "model.router_bias / model.n_group are not carried into "
+            "moe_dispatch=sorted_a2a's shard_map: use moe_dispatch=sorted")
 
     def body(x_loc, router_w, w_in, w_out, *gate_w):
         p_loc = {"w_in": w_in, "w_out": w_out}
@@ -614,12 +633,13 @@ def _shared_expert(x: jax.Array, p: dict[str, Any], cfg: ModelConfig
 
 
 def held_rows(x: jax.Array, router_w: jax.Array, cfg: ModelConfig,
-              valid: Optional[jax.Array] = None) -> jax.Array:
+              valid: Optional[jax.Array] = None,
+              bias: Optional[jax.Array] = None) -> jax.Array:
     """How many of the block's routed (token, expert) assignments fall on
     experts held here (int32 scalar): the rows this layer's expert matmuls
     have to compute. ``valid`` [B, S] as in ``moe_dispatch``. The same
     router head as the dispatch, so XLA computes it once."""
-    idx = _router_topk(x, router_w, cfg)[2]
+    idx = _router_topk(x, router_w, cfg, bias)[2]
     with jax.named_scope("router"):
         _, held = _held(idx, cfg)
         if valid is not None:

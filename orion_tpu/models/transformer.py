@@ -116,22 +116,51 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         whose layers differ; None is the model's one kind."""
         N = cfg.n_heads if kind is None else kind.n_heads
         moe = cfg.is_moe if kind is None else kind.moe
+        att = _attention_of(cfg, kind)
         bkeys = iter(jax.random.split(bkey, 16))
-        if cfg.is_latent:
+        if att == "latent":
             # Latent attention's projections in qkv_proj's place
             # (``latent_proj`` / ``latent_expand``).
             qr, R = cfg.q_lora_rank, cfg.kv_lora_rank
             nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                               cfg.v_head_dim)
-            attn = {
+            attn = {} if qr is None else {
                 "wq_a": _normal(next(bkeys), (D, qr), pdt, std),
                 "q_a_norm": jnp.ones((qr,), pdt),
                 "wq_b": _normal(next(bkeys), (qr, N * (nope + rope)), pdt,
                                 std),
+            }
+            if qr is None:
+                attn["wq"] = _normal(
+                    next(bkeys), (D, N * (nope + rope)), pdt, std)
+            attn.update({
                 "wkv_a": _normal(next(bkeys), (D, R + rope), pdt, std),
                 "kv_a_norm": jnp.ones((R,), pdt),
                 "wkv_b": _normal(next(bkeys), (R, N * (nope + vd)), pdt, std),
                 "wo": _normal(next(bkeys), (N * vd, D), pdt, resid_std),
+            })
+            if cfg.qk_norm:
+                attn["q_norm"] = jnp.ones((nope + rope,), pdt)
+                attn["k_norm"] = jnp.ones((rope,), pdt)
+        elif att == "kda":
+            # Kimi delta attention (``kda_proj``; ops/kda.py): q, k, v
+            # behind ONE depthwise convolution leaf (q | k | v columns), the
+            # decay's full-rank projection with its A_log / dt_bias, the
+            # write strength a head, the output's full-rank gate and the
+            # head norm in front of it.
+            attn = {
+                "wq": _normal(next(bkeys), (D, N * H), pdt, std),
+                "wk": _normal(next(bkeys), (D, N * H), pdt, std),
+                "wv": _normal(next(bkeys), (D, N * H), pdt, std),
+                "conv": jnp.ones((cfg.kda_conv_size, 3 * N * H), pdt)
+                / cfg.kda_conv_size,
+                "wf": _normal(next(bkeys), (D, N * H), pdt, std),
+                "a_log": jnp.zeros((N,), pdt),
+                "dt_bias": jnp.zeros((N * H,), pdt),
+                "wb": _normal(next(bkeys), (D, N), pdt, std),
+                "wg": _normal(next(bkeys), (D, N * H), pdt, std),
+                "o_norm": jnp.ones((H,), pdt),
+                "wo": _normal(next(bkeys), (N * H, D), pdt, resid_std),
             }
         else:
             attn = {
@@ -188,12 +217,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             if cfg.mlp_bias:
                 block["mlp"]["b_in"] = jnp.zeros((F,), pdt)
                 block["mlp"]["b_out"] = jnp.zeros((D,), pdt)
-        if cfg.attn_gate is not None:
+        if cfg.attn_gate is not None and att != "kda":
             block["attn"]["wg"] = _normal(next(bkeys), (D, N), pdt, std)
-        if cfg.qk_norm:
+        if cfg.qk_norm and att not in ("latent", "kda"):
             block["attn"]["q_norm"] = jnp.ones((H,), pdt)
             block["attn"]["k_norm"] = jnp.ones((H,), pdt)
-        if cfg.is_retention:
+        if att == "power_retention":
             block["attn"]["wr"] = _normal(next(bkeys), (D, K), pdt, std)
 
         return block
@@ -201,18 +230,24 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     layer_keys = jax.random.split(next(keys), L)
     plan = cfg.layer_plan
     if plan is not None:
-        # Layers that differ in shape: the leading layers each on their
-        # own, and one stack per position of the period (config.LayerPlan).
+        # Layers that differ in shape: the leading elements each on their
+        # own, and one stack per position of the period (config.LayerPlan);
+        # an element's leaves are as deep as its ``layers`` are nested.
         kinds = cfg.layer_kinds
+
+        def element(e):
+            at = jnp.asarray(plan.layers(e))
+            fn = functools.partial(init_block, kind=kinds[plan.start(e)])
+            for _ in range(at.ndim):
+                fn = jax.vmap(fn)
+            return fn(layer_keys[at])
+
         params["blocks"] = {"period": {
-            str(j): jax.vmap(functools.partial(
-                init_block, kind=kinds[plan.lead + j]))(
-                    layer_keys[plan.lead + j::plan.period])
+            str(j): element(plan.lead + j)
             for j in range(plan.period) if plan.counts[j]}}
         if plan.lead:
             params["blocks"]["lead"] = {
-                str(i): init_block(layer_keys[i], kinds[i])
-                for i in range(plan.lead)}
+                str(i): element(i) for i in range(plan.lead)}
     elif cfg.scan_layers:
         params["blocks"] = jax.vmap(init_block)(layer_keys)
     else:
@@ -229,12 +264,16 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     plan = cfg.layer_plan
     if plan is not None:
         kinds = cfg.layer_kinds
+
+        def element(e):
+            depth = jnp.asarray(plan.layers(e)).ndim
+            return _block_axes(cfg, ("layers",) * depth, kinds[plan.start(e)])
+
         blocks: Params = {"period": {
-            str(j): _block_axes(cfg, ("layers",), kinds[plan.lead + j])
+            str(j): element(plan.lead + j)
             for j in range(plan.period) if plan.counts[j]}}
         if plan.lead:
-            blocks["lead"] = {str(i): _block_axes(cfg, (), kinds[i])
-                              for i in range(plan.lead)}
+            blocks["lead"] = {str(i): element(i) for i in range(plan.lead)}
     else:
         block = _block_axes(
             cfg, ("layers",) if cfg.scan_layers else (), None)
@@ -256,6 +295,7 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
 def _block_axes(cfg: ModelConfig, lead: tuple, kind) -> Params:
     """One block's logical axes (``kind`` as in ``init_params``)."""
     moe = cfg.is_moe if kind is None else kind.moe
+    att = _attention_of(cfg, kind)
     block = {
         "attn_norm": {"scale": lead + ("embed",)},
         "mlp_norm": {"scale": lead + ("embed",)},
@@ -266,7 +306,7 @@ def _block_axes(cfg: ModelConfig, lead: tuple, kind) -> Params:
             "wo": lead + ("heads", "embed"),
         },
     }
-    if cfg.is_latent:
+    if att == "latent":
         block["attn"] = {
             "wq_a": lead + ("embed", None),
             "q_a_norm": lead + (None,),
@@ -276,6 +316,25 @@ def _block_axes(cfg: ModelConfig, lead: tuple, kind) -> Params:
             "wkv_b": lead + (None, "heads"),
             "wo": lead + ("heads", "embed"),
         }
+        if cfg.q_lora_rank is None:
+            for name in ("wq_a", "q_a_norm", "wq_b"):
+                del block["attn"][name]
+            block["attn"]["wq"] = lead + ("embed", "heads")
+        if cfg.qk_norm:
+            block["attn"]["q_norm"] = lead + (None,)
+            block["attn"]["k_norm"] = lead + (None,)
+    if att == "kda":
+        block["attn"].update({
+            "wk": lead + ("embed", "heads"),
+            "wv": lead + ("embed", "heads"),
+            "conv": lead + (None, "heads"),
+            "wf": lead + ("embed", "heads"),
+            "a_log": lead + ("heads",),
+            "dt_bias": lead + ("heads",),
+            "wb": lead + ("embed", "heads"),
+            "wg": lead + ("embed", "heads"),
+            "o_norm": lead + (None,),
+        })
     if cfg.norm == "layernorm":
         block["attn_norm"]["bias"] = lead + ("embed",)
         block["mlp_norm"]["bias"] = lead + ("embed",)
@@ -288,12 +347,12 @@ def _block_axes(cfg: ModelConfig, lead: tuple, kind) -> Params:
         block["attn"]["bv"] = lead + ("kv_heads",)
     if cfg.resolved_attn_out_bias:
         block["attn"]["bo"] = lead + ("embed",)
-    if cfg.attn_gate is not None:
+    if cfg.attn_gate is not None and att != "kda":
         block["attn"]["wg"] = lead + ("embed", "heads")
-    if cfg.qk_norm:
+    if cfg.qk_norm and att not in ("latent", "kda"):
         block["attn"]["q_norm"] = lead + (None,)
         block["attn"]["k_norm"] = lead + (None,)
-    if cfg.is_retention:
+    if att == "power_retention":
         block["attn"]["wr"] = lead + ("embed", "kv_heads")
     if moe:
         block["moe"] = {
@@ -327,6 +386,12 @@ def _block_axes(cfg: ModelConfig, lead: tuple, kind) -> Params:
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
+
+
+def _attention_of(cfg: ModelConfig, kind) -> str:
+    """What a layer's attention computes (``config.LayerKind.attention``);
+    ``kind`` None is the one kind of a model whose layers share a stack."""
+    return cfg.layer_attention(0) if kind is None else kind.attention
 
 
 def _gate_act(cfg: ModelConfig):
@@ -443,15 +508,25 @@ def qkv_proj(
 
 @jax.named_scope("out")
 def out_proj(out: jax.Array, p: Params, cfg: ModelConfig,
-             h: Optional[jax.Array] = None) -> jax.Array:
+             h: Optional[jax.Array] = None, att: str = "softmax"
+             ) -> jax.Array:
     """Attention output projection. out: [B, S, N, H] -> [B, S, D].
 
     With ``model.attn_gate`` each head's output is first multiplied by
     ``sigmoid(h wg)``, ``h`` [B, S, D] the layer's normed input (the one
-    ``qkv_proj`` read): the head-wise gate of arXiv:2505.06708."""
+    ``qkv_proj`` read): the head-wise gate of arXiv:2505.06708. A KDA
+    layer (``att``) has a gate of its own: every head's output under an
+    RMSNorm over its numbers (``o_norm`` [H]) times ``sigmoid(h wg)``, wg
+    of full rank [D, N x H]."""
     B, S = out.shape[0], out.shape[1]
     dtype = out.dtype
-    if cfg.attn_gate is not None:
+    if att == "kda":
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dh->bsh", h, _load_w(p["wg"], h.dtype)))
+        out = ops.rmsnorm(out, p["o_norm"], eps=cfg.norm_eps).astype(
+            h.dtype) * gate.reshape(out.shape)
+        dtype = h.dtype
+    elif cfg.attn_gate is not None:
         out = out * _attn_gate(h, p, cfg)[..., None].astype(dtype)
     y = jnp.einsum(
         "bsh,hd->bsd", out.reshape(B, S, -1), _load_w(p["wo"], dtype)
@@ -481,6 +556,44 @@ def retention_log_gate(h: jax.Array, p: Params) -> jax.Array:
 
 
 @jax.named_scope("qkv")
+def kda_proj(h: jax.Array, p: Params, cfg: ModelConfig
+             ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """A KDA layer's projections of its normed input ``h`` [B, S, D]: (x
+    [B, S, 3 x N x H]: q | k | v BEFORE the convolution, whose last rows a
+    cache keeps, so the convolution is the backend's; g [B, S, N, H]
+    float32: the log-decay a key channel, ``ops.kda.safe_log_decay``; b
+    [B, S, N] float32: the write strength a head)."""
+    from orion_tpu.ops.kda import safe_log_decay
+
+    B, S, _ = h.shape
+    N, H = cfg.n_heads, cfg.resolved_head_dim
+    dtype = h.dtype
+    x = jnp.concatenate([
+        jnp.einsum("bsd,dh->bsh", h, _load_w(p[w], dtype))
+        for w in ("wq", "wk", "wv")], axis=-1)
+    with jax.named_scope("kda/gate"):
+        z = jnp.einsum("bsd,dh->bsh", h, _load_w(p["wf"], dtype))
+        g = safe_log_decay(z.reshape(B, S, N, H), p["a_log"],
+                           p["dt_bias"].reshape(N, H), cfg.kda_lower_bound)
+        b = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dn->bsn", h, _load_w(p["wb"], dtype)).astype(jnp.float32))
+    return x, g, b
+
+
+def kda_activate(y: jax.Array, cfg: ModelConfig
+                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """q, k, v [..., N, H] float32 from the convolution's output ``y`` [...,
+    3 x N x H]: SiLU on all three, then q and k l2-normalised a head and q
+    scaled by H^-0.5."""
+    from orion_tpu.ops.kda import l2norm
+
+    N, H = cfg.n_heads, cfg.resolved_head_dim
+    y = jax.nn.silu(y.astype(jnp.float32)).reshape(*y.shape[:-1], 3, N, H)
+    q, k, v = (y[..., i, :, :] for i in range(3))
+    return l2norm(q) * H ** -0.5, l2norm(k), v
+
+
+@jax.named_scope("qkv")
 def latent_proj(
     x: jax.Array, p: Params, cfg: ModelConfig, positions: jax.Array,
 ) -> tuple[jax.Array, jax.Array]:
@@ -492,7 +605,8 @@ def latent_proj(
     B, S, _ = x.shape
     N, R = cfg.n_heads, cfg.kv_lora_rank
     nope, rope_d = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    if cfg.resolved_head_dim != nope + rope_d or cfg.n_kv_heads != N:
+    if ((cfg.is_latent and cfg.resolved_head_dim != nope + rope_d)
+            or cfg.n_kv_heads != N):
         raise ValueError(
             f"a latent model's head_dim is qk_nope_head_dim + "
             f"qk_rope_head_dim ({nope} + {rope_d}) and its n_kv_heads its "
@@ -501,16 +615,25 @@ def latent_proj(
     dtype = x.dtype
     rope = functools.partial(ops.apply_rope, theta=cfg.rope_theta, impl="xla")
     with jax.named_scope("latent/down"):
-        c_q = ops.rmsnorm(
-            jnp.einsum("bsd,dr->bsr", x, _load_w(p["wq_a"], dtype)),
-            p["q_a_norm"], eps=cfg.norm_eps)
-        q = jnp.einsum("bsr,rh->bsh", c_q, _load_w(p["wq_b"], dtype))
+        if cfg.q_lora_rank is None:
+            q = jnp.einsum("bsd,dh->bsh", x, _load_w(p["wq"], dtype))
+        else:
+            c_q = ops.rmsnorm(
+                jnp.einsum("bsd,dr->bsr", x, _load_w(p["wq_a"], dtype)),
+                p["q_a_norm"], eps=cfg.norm_eps)
+            q = jnp.einsum("bsr,rh->bsh", c_q, _load_w(p["wq_b"], dtype))
         q = q.reshape(B, S, N, nope + rope_d)
+        row = jnp.einsum("bsd,dr->bsr", x, _load_w(p["wkv_a"], dtype))
+        k_pe = row[..., None, R:]
+        if cfg.qk_norm:
+            # Before the rotation: each head's query, and the one rotary
+            # key all heads share (config.qk_norm).
+            q = ops.rmsnorm(q, p["q_norm"], eps=cfg.norm_eps)
+            k_pe = ops.rmsnorm(k_pe, p["k_norm"], eps=cfg.norm_eps)
         q = jnp.concatenate(
             [q[..., :nope], rope(q[..., nope:], positions)], axis=-1)
-        row = jnp.einsum("bsd,dr->bsr", x, _load_w(p["wkv_a"], dtype))
         c_kv = ops.rmsnorm(row[..., :R], p["kv_a_norm"], eps=cfg.norm_eps)
-        k_pe = rope(row[..., None, R:], positions)[:, :, 0]
+        k_pe = rope(k_pe, positions)[:, :, 0]
         row = jnp.concatenate([c_kv, k_pe], axis=-1)
     return q, row
 
@@ -605,6 +728,7 @@ def _train_attend(
     segment_ids: Optional[jax.Array],
     mesh: Optional[Any] = None,
     window: Optional[int] = None,
+    kind=None,
 ) -> Callable[..., tuple[jax.Array, None]]:
     """The training layer's ``attend`` for ``block``: causal attention of a
     layer's q over its own k/v (flash, or ``sequence_attention`` when the
@@ -617,7 +741,24 @@ def _train_attend(
         and mesh.shape.get(cfg.sequence_axis, 1) > 1
     )
 
-    if cfg.is_latent:
+    att = _attention_of(cfg, kind)
+    if att == "kda":
+        if sp_active or segment_ids is not None:
+            raise ValueError(
+                "a KDA layer trains whole unpacked sequences on one "
+                "sequence shard: no sequence axis, no segment ids")
+        from orion_tpu.ops.kda import kda_chunked, short_conv
+
+        def delta(x, g, b, conv):
+            with jax.named_scope("qkv"), jax.named_scope("kda/conv"):
+                q, k, v = kda_activate(short_conv(x, conv)[0], cfg)
+            with jax.named_scope("kernel"):
+                # The XLA chunked form, which JAX differentiates.
+                return kda_chunked(q, k, v, g, b)[0].astype(x.dtype), None
+
+        return delta
+
+    if att == "latent":
         if sp_active:
             raise ValueError(
                 "a latent-attention model trains on one sequence shard: "
@@ -635,7 +776,7 @@ def _train_attend(
 
         return expanded
 
-    if cfg.is_retention:
+    if att == "power_retention":
         if sp_active or segment_ids is not None:
             raise ValueError(
                 "model.attention=power_retention trains whole unpacked "
@@ -744,7 +885,9 @@ def block(
     ``attend(q, k, v) -> (out [B, S, N, H], state)`` (with the log-gates
     [B, S, K] as a fourth argument under model.attention=power_retention;
     ``attend(q, row, wkv_b) -> (out [B, S, N, v_head_dim], state)`` for a
-    latent-attention model) is all that differs
+    latent layer; ``attend(x, g, b, conv) -> (out [B, S, N, H], state)``
+    for a KDA layer, ``kda_proj``; which of the four a layer is,
+    ``kind.attention`` says) is all that differs
     between them: what attention reads and where K/V go (``_train_attend``
     here; the dense and paged backends of ``infer/runner.py``, whose state
     is the KV pool). The body never sees a cache, a page table or a segment
@@ -781,13 +924,20 @@ def block(
             h = checkpoint_name(
                 _norm(x, bp["attn_norm"], cfg, mesh), "attn_norm_out"
             )
-        if cfg.is_latent:
+        att = _attention_of(cfg, kind)
+        if att == "latent":
             # Such a layer hands ``attend`` its queries, the ONE row a
             # position a cache keeps, and the matrix that expands the row
             # (or absorbs the query): which form attends is the backend's.
             q, row = latent_proj(h, bp["attn"], cfg, positions)
             out, state = attend(q, row, bp["attn"]["wkv_b"])
-        elif cfg.is_retention:
+        elif att == "kda":
+            # Such a layer hands ``attend`` q | k | v BEFORE the
+            # convolution (its tail is the cache's), the log-decays, the
+            # write strengths and the convolution's taps.
+            out, state = attend(
+                *kda_proj(h, bp["attn"], cfg), bp["attn"]["conv"])
+        elif att == "power_retention":
             q, k, v = qkv_proj(h, bp["attn"], cfg, positions, mesh, kind)
             # Such a layer's attention also reads the log-gates, handed to
             # ``attend`` the way ``out_proj`` is handed ``h``.
@@ -799,7 +949,7 @@ def block(
         # per-layer tensor to rebuild (a full flash fwd pass) at [B,S,N,H]
         # storage. (No-op identity under every other policy.)
         out = checkpoint_name(out, "attn_out")
-        a = out_proj(out, bp["attn"], cfg, h)
+        a = out_proj(out, bp["attn"], cfg, h, att)
         if cfg.post_norms:
             with jax.named_scope("norm"):
                 a = _norm(a, bp["post_attn_norm"], cfg, mesh)
@@ -827,23 +977,41 @@ def block(
 def scan_layer_plan(blocks: Params, plan, body, carry):
     """Run ``body(carry, bp, l, j, stack) -> carry`` over the layers of a
     model whose layers differ in shape (``config.LayerPlan``): the leading
-    layers, a ``lax.scan`` over the periods that calls the body once per
-    static position, and the tail. ``l`` is the layer's index (traced under
-    the scan), ``j`` the STATIC index of a layer of the same kind
-    (``cfg.layer_kind(j)``), ``stack`` = (the position's layer-stacked MoE
-    weights or None, the layer's index in that stack) for the dispatch that
-    reads expert matrices in place. Shared by the training forward and the
-    cache runner."""
+    elements, a ``lax.scan`` over the periods that calls the body once per
+    static position, and the tail; an element that is a run of equal layers
+    is a ``lax.scan`` over them. ``l`` is the layer's index (traced under a
+    scan), ``j`` the STATIC index of a layer of the same kind
+    (``cfg.layer_kind(j)``: the element's first), ``stack`` = (the layer-
+    stacked MoE weights [layers, ...] the layer's are a row of, or None; that
+    row) for the dispatch that reads expert matrices in place. Shared by the
+    training forward and the cache runner."""
+    def element(carry, leaves, e, g):
+        """Element ``e`` in period ``g`` (None: a lead element)."""
+        w, j, base = plan.width(e), plan.start(e), plan.start(plan.lead)
+        first = j if g is None else base + g * plan.period_layers + (j - base)
+        bp = leaves if g is None else jax.tree.map(lambda a: a[g], leaves)
+        moe = leaves.get("moe")
+        if w == 1:
+            return body(carry, bp, first, j,
+                        None if g is None or moe is None else (moe, g))
+        if moe is not None:     # [.., width, ...] -> one row a layer
+            moe = jax.tree.map(
+                lambda a: a.reshape(-1, *a.shape[1 + (g is not None):]), moe)
+
+        def one(c, r):
+            row = r if g is None else g * w + r
+            return body(c, jax.tree.map(lambda a: a[r], bp), first + r, j,
+                        None if moe is None else (moe, row)), None
+
+        return jax.lax.scan(one, carry, jnp.arange(w))[0]
+
     for i in range(plan.lead):
-        carry = body(carry, blocks["lead"][str(i)], i, i, None)
+        carry = element(carry, blocks["lead"][str(i)], i, None)
 
     def one_period(carry, g, positions):
         for j in range(positions):
-            stack = blocks["period"][str(j)]
-            carry = body(
-                carry, jax.tree.map(lambda a: a[g], stack),
-                plan.lead + g * plan.period + j, plan.lead + j,
-                (stack["moe"], g) if "moe" in stack else None)
+            carry = element(
+                carry, blocks["period"][str(j)], plan.lead + j, g)
         return carry
 
     if plan.repeats == 1:
@@ -928,7 +1096,8 @@ def _hidden_states(
             def block_fn(carry, bp, rs):
                 return block(
                     carry, bp, cfg, rs["positions"],
-                    _train_attend(cfg, rs.get("segment_ids"), mesh, window),
+                    _train_attend(cfg, rs.get("segment_ids"), mesh, window,
+                                  kind),
                     kind=kind, mesh=mesh, ffn_mesh=mesh,
                 )[:2]
         else:
@@ -940,7 +1109,7 @@ def _hidden_states(
                     )
                 return block(
                     carry, bp, cfg, pos,
-                    _train_attend(cfg, segment_ids, mesh, window),
+                    _train_attend(cfg, segment_ids, mesh, window, kind),
                     kind=kind, mesh=mesh, ffn_mesh=mesh,
                 )[:2]
 
@@ -1013,8 +1182,7 @@ def _hidden_states(
                 f"scan_group=1 and no pipeline axis")
         kinds = cfg.layer_kinds
         fns = {j: _remat(make_block_fn(kinds[j].window, kind=kinds[j]))
-               for j in set(range(plan.lead)) | {
-                   plan.lead + j for j in range(plan.period)}}
+               for j in map(plan.start, range(plan.lead + plan.period))}
 
         def body(carry, bp, l, j, stack):
             x, aux_t = carry

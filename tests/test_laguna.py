@@ -98,7 +98,7 @@ def test_the_layer_plan_crosses_the_period_and_the_tail(tiny):
     assert [k.moe for k in m.layer_kinds] == [False] + [True] * 5
     assert m.page_window is None            # a full layer keeps every page
     full = get_config("laguna-s-2.1").model
-    assert full.layer_plan == (1, 4, 11, 3)
+    assert full.layer_plan == (1, 4, 11, 3, ())     # no runs: a layer an element
     # models of one kind have no plan: their tree and scans are untouched
     for preset in ("tiny-llama", "tiny-mixtral", "tiny-gemma2"):
         assert get_config(preset).model.layer_plan is None

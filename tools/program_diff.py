@@ -195,7 +195,10 @@ def dump(tree: str, out: str) -> int:
         save(f"{name}.train_step.compiled.txt", lowered.compile().as_text())
 
     for w in load_benchmark()["workloads"]:
-        if load_mix(w["traffic"], BENCH / "traffic")["kind"] == "serve":
+        # ``serve`` and the kinds that are ``serve`` under another tap
+        # (``serve_rows``: the same engine, the same programs)
+        if load_mix(w["traffic"], BENCH / "traffic")["kind"].startswith(
+                "serve"):
             serve_cell(w["name"])
         else:
             train_cell(w["name"])

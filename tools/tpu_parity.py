@@ -29,6 +29,10 @@ compares fwd + grads against the xla reference ops:
   - latent attention (``--only latent`` runs these alone): the latent
     paged decode kernel at the GLM cell's shapes against the XLA absorbed
     form, against the expanded form, and a control without the rotary term.
+  - Kimi delta attention (``--only kda`` runs these alone): the chunked
+    form and the decode kernel ``kda_decode`` at the Ling cell's shapes, with
+    decays near 1 and at the -5 bound, against the plain recurrence in
+    float32; a dead slot passed by; a control without the erase term.
   - power retention (``--only retention`` runs these alone): the chunked
     prefill kernel (outputs and the state it hands out, ragged lengths),
     the decode kernel over a state row and a paged tail (tail only, state
@@ -1102,6 +1106,80 @@ def latent_checks() -> None:
            "expanded form", rel > 0.1, f": rel={rel:.3e}")
 
 
+def kda_checks() -> None:
+    """Kimi delta attention at the Ling cell's shapes (32 heads of 128 keys
+    and values) with decays NEAR 1 (a state that remembers: a step's
+    log-decay in (-0.02, 0)) and at the gate's bound of -5, each against the
+    plain recurrence in float32 (``ops.kda.kda_recurrent``): the chunked
+    form a prefill runs (outputs and the state it hands out, ragged
+    lengths), the decode kernel ``kda_decode`` (a step on a state the
+    recurrence made, at a traced layer, a dead slot passed by and its row
+    bitwise untouched), the chain chunked prefill -> eight decode steps
+    against the recurrence over the whole sequence, and a control that
+    drops the erase term and has to fail."""
+    from orion_tpu.ops import kda
+    from orion_tpu.ops.pallas.kda import kda_decode
+
+    N, H = (4, 128) if INTERP else (32, 128)
+    B, S = (2, 200) if INTERP else (4, 1500)
+    lens = jnp.asarray([S, S // 3] if INTERP else [S, 1111, 64, 3], jnp.int32)
+    W = 8
+
+    def draw(seed, gscale, n):
+        ks = jax.random.split(jax.random.key(seed), 5)
+        q = kda.l2norm(jax.random.normal(ks[0], (B, n, N, H))) * H ** -0.5
+        k = kda.l2norm(jax.random.normal(ks[1], (B, n, N, H)))
+        v = jax.random.normal(ks[2], (B, n, N, H))
+        g = gscale * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], (B, n, N, H)))
+        b = jax.nn.sigmoid(jax.random.normal(ks[4], (B, n, N)))
+        return q, k, v, g, b
+
+    for tag, gscale in (("decays near 1", -0.02), ("the -5 bound", -5.0)):
+        q, k, v, g, b = draw(11, gscale, S + W)
+        head = lambda x: x[:, :S]
+        want_o, want_s = jax.jit(
+            lambda *a: kda.kda_recurrent(*a, lengths=lens))(
+                *(head(x) for x in (q, k, v, g, b)))
+        got_o, got_s = jax.jit(
+            lambda *a: kda.kda_chunked(*a, lengths=lens))(
+                *(head(x) for x in (q, k, v, g, b)))
+        live = (jnp.arange(S)[None, :] < lens[:, None])[..., None, None]
+        check(f"kda chunked out, {tag}", jnp.where(live, got_o, 0),
+              jnp.where(live, want_o, 0), 2e-3)
+        check(f"kda chunked state, {tag}", got_s, want_s, 2e-3)
+        # The decode kernel: W steps from the state the prefill handed out,
+        # rows at (layer 1, slot + 1); slot 1 dead.
+        state = jnp.zeros((2, B + 1, N, H, H), jnp.float32).at[1, 1:].set(
+            jnp.swapaxes(got_s, -1, -2))
+        active = jnp.arange(B) != 1
+        step = jax.jit(lambda st, t: kda_decode(
+            st, q[:, t], k[:, t], v[:, t], g[:, t], b[:, t],
+            layer=jnp.int32(1), active=active, interpret=INTERP))
+        ref_s, outs = want_s, []
+        for t in range(S, S + W):
+            o, state = step(state, t)
+            ref_s, ref_o = kda._step(ref_s, q[:, t], k[:, t], v[:, t],
+                                     g[:, t], b[:, t])
+            outs.append((o, ref_o))
+        # Row 0 of the lengths is whole: its chain is the recurrence's.
+        check(f"kda decode out after {W} steps, {tag}", outs[-1][0][0],
+              outs[-1][1][0], 2e-3)
+        check(f"kda decode state after {W} steps, {tag}", state[1, 1],
+              jnp.swapaxes(ref_s, -1, -2)[0], 2e-3)
+        bitwise(f"kda decode passes a dead slot and the other layer by, "
+                f"{tag}", [(state[1, 2], jnp.swapaxes(got_s, -1, -2)[1]),
+                           (state[0], jnp.zeros_like(state[0]))])
+    # CONTROL: a gated sum (no erase term) is not the delta rule.
+    q, k, v, g, b = (x[:, :128, :2] for x in draw(11, -0.02, S))
+    blind = jnp.einsum("bsnkv,bsnk->bsnv", jnp.cumsum(jnp.einsum(
+        "bsnk,bsnv->bsnkv", k * b[..., None], v), axis=1), q)
+    want = kda.kda_recurrent(q, k, v, 0 * g, b)[0]
+    rel = float(jnp.max(jnp.abs(blind - want))) / float(
+        jnp.max(jnp.abs(want)))
+    record("kda CONTROL without the erase term differs from the "
+           "recurrence", rel > 0.1, f": rel={rel:.3e}")
+
+
 def main() -> int:
     global INTERP
     INTERP = "--interpret" in sys.argv[1:]
@@ -1121,7 +1199,8 @@ def main() -> int:
 
     for name, group in (("retention", retention_checks),
                         ("rope", rope_decode_checks),
-                        ("latent", latent_checks)):
+                        ("latent", latent_checks),
+                        ("kda", kda_checks)):
         if name in sys.argv[1:]:        # --only <name>
             guarded(name, group)
             green = sum(ok for _, ok in RESULTS)
@@ -1130,6 +1209,7 @@ def main() -> int:
             return 0 if green == len(RESULTS) else 1
     guarded("retention", retention_checks)
     guarded("latent", latent_checks)
+    guarded("kda", kda_checks)
     guarded("flash", flash_checks)
     if not INTERP:      # the cells' sizes: minutes under the interpreter
         guarded("flash @cells", flash_cell_checks)
