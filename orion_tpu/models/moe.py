@@ -41,6 +41,15 @@ capacity that drops (training at 1.25) and expert-parallel layouts keep their
 buckets. ``einsum`` mode is never rerouted (it is the plain form the others
 are tested against).
 
+At ONE position a row (``[B, 1, D]``: the decode step) a bucket is one row
+deep, a position's k choices are k different experts and nothing overflows:
+an expert's bucket of a batch row holds that row or nothing. So
+``moe_mlp_sorted`` builds no bucket tensor there: the experts' input is the
+block itself, broadcast over the experts (``_broadcast_dispatch``), the same
+expert matmuls run over the same E x B rows and the combine gathers the k
+rows a position's router chose, as it does from buckets. A block of more
+positions (a verify block, training) is scattered into buckets as before.
+
 Aux load-balancing loss follows Switch/Mixtral: E * sum_e f_e * p_e.
 """
 
@@ -295,6 +304,11 @@ def _scatter_dispatch(x, idx, pos, keep, E, C):
     Dropped assignments land in a trash row (C) that is sliced off; kept
     (expert, pos) pairs are unique per batch row, so the scatter-add never
     actually collides and its gradient is the plain gather transpose.
+
+    Called for blocks of MORE than one position a row by ``moe_mlp_sorted``
+    (a verify block, training at a capacity that drops) and for every block
+    by ``moe_mlp_sorted_a2a`` (its buckets are what the all-to-all moves);
+    one position a row is ``_broadcast_dispatch``'s.
     """
     B, S, D = x.shape
     k = idx.shape[-1]
@@ -304,6 +318,22 @@ def _scatter_dispatch(x, idx, pos, keep, E, C):
     xv = jnp.broadcast_to(x[:, :, None, :], (B, S, k, D))
     xin = xin.at[b_ix, idx, pos_c].add(xv, mode="drop")
     return xin[:, :, :C].transpose(1, 0, 2, 3)               # [E, B, C, D]
+
+
+@jax.named_scope("dispatch")
+def _broadcast_dispatch(x, E):
+    """``_scatter_dispatch`` at one position a row. x: [B,1,D] -> [E, B, 1, D].
+
+    The capacity is 1, a position's k choices are k different experts, so
+    ``pos`` is 0 and nothing is dropped: the bucket of (expert, batch row)
+    held that row or zeros. Every bucket gets the row: what an expert makes
+    of a row nobody routed to it is computed as it was from zeros (the
+    matmuls run over all E x B rows either way) and is never gathered, so
+    neither it nor its cotangent (the gather's transpose leaves it zero)
+    reaches a result. No zeros, no scatter-add, no re-laid bucket tensor.
+    """
+    B, _, D = x.shape
+    return jnp.broadcast_to(x[None], (E, B, 1, D))
 
 
 @jax.named_scope("dispatch")
@@ -339,7 +369,10 @@ def moe_mlp_sorted(
             idx, held = _held(idx, cfg)
             keep = keep & held
             idx = jnp.clip(idx, 0, E - 1)
-    xin = _scatter_dispatch(x, idx, pos, keep, E, C)
+    if x.shape[1] == 1:
+        xin = _broadcast_dispatch(x, E)     # a bucket IS the row: no scatter
+    else:
+        xin = _scatter_dispatch(x, idx, pos, keep, E, C)
     out = _expert_ffn(xin, params, cfg)
     y = _gather_combine(out, idx, pos, keep, gate, dtype)
     with jax.named_scope("router"):
@@ -476,7 +509,10 @@ def takes_grouped_path(cfg: ModelConfig, B: int, S: int, mesh=None) -> bool:
         tile rounding counted at the worst case (every expert's group ends
         inside a tile): ``k*T + E*tm < E*B*C``. Decode (``[32, 1, D]``: 64
         + 8*tm against 256) stays on the buckets, where all E experts'
-        weights are read whichever path runs.
+        weights are read whichever path runs; a bucket there is one row
+        deep and holds the batch row itself or nothing, so
+        ``moe_mlp_sorted`` hands the experts the block broadcast over them
+        and scatters nothing (``_broadcast_dispatch``).
     """
     from orion_tpu.ops.grouped_matmul import TILE_M
 

@@ -945,11 +945,10 @@ def test_moe_grouped_rule(B, S, factor, axes, grouped):
     assert moe_lib.expert_rows(einsum, B, S, n_valid, mesh) == buckets
 
 
-def test_decode_program_holds_no_grouped_matmul():
-    """At the cell's MoE geometry the prefill program multiplies routed rows
-    (a ragged dot in its jaxpr) and the decode-window program keeps the
-    capacity buckets: the benchmark finds decode's expert fusions by shape,
-    and the output check ties the window program to the one-step body."""
+def _moe_cell_decode_jaxpr():
+    """The decode-window jaxpr at the Mixtral cell's MoE geometry (tiny
+    widths, 32 slots, 8 experts at the dropless factor), with the config
+    and the abstract params and cache it was traced on."""
     from functools import partial
 
     from orion_tpu.infer import runner
@@ -971,12 +970,174 @@ def test_decode_program_holds_no_grouped_matmul():
         params, cache, i32(B), i32(B), i32(B, pages_per_seq(icfg)),
         jax.ShapeDtypeStruct((B,), jnp.bool_),
         jax.eval_shape(lambda: jax.random.split(jax.random.key(0), W)))
-    assert "ragged_dot" not in str(decode)
+    return cfg, params, cache, str(decode)
+
+
+def test_decode_program_holds_no_grouped_matmul():
+    """At the cell's MoE geometry the prefill program multiplies routed rows
+    (a ragged dot in its jaxpr) and the decode-window program keeps the
+    capacity buckets: the benchmark finds decode's expert fusions by shape,
+    ``[E, slots, F]`` and ``[slots, E, 1, D]``, and the output check ties
+    the window program to the one-step body. Since PR 42 a bucket at one
+    position a row is the row itself, broadcast and not scattered; both
+    shapes were kept (``test_decode_program_scatters_no_bucket``)."""
+    from functools import partial
+
+    from orion_tpu.infer import runner
+
+    cfg, params, cache, decode = _moe_cell_decode_jaxpr()
+    mcfg, icfg = cfg.model, cfg.inference
+    i32 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.int32)
+    assert "ragged_dot" not in decode
     nb, s_pad = 8, 512
     prefill = jax.make_jaxpr(partial(runner.prefill_step, cfg=mcfg))(
         params, cache, i32(nb, s_pad), i32(nb),
         i32(nb, s_pad // icfg.page_size), i32(nb), i32(nb, 0))
     assert str(prefill).count("ragged_dot") >= 3      # w_in, w_gate, w_out
+
+
+def test_decode_program_scatters_no_bucket():
+    """At one position a row an expert's bucket is the row itself: the
+    decode-window program of the cell's MoE geometry holds no scatter-add
+    and no zeros of a bucket tensor ``[slots, E, 2, D]`` (capacity 1 and its
+    trash row), where a block of two positions a row at the same geometry
+    (what a verify block is) still scatters into one. The benchmark still
+    finds decode's expert fusions by shape, ``[E, slots, F]`` and
+    ``[slots, E, 1, D]``: the experts' operand stays ``[E, slots, 1, D]``
+    and the combine still transposes and gathers their output, so both
+    shapes are kept (folding the gates into the out matmul would remove
+    the second; ROADMAP S15 (a), M6 (2))."""
+    from functools import partial
+
+    from orion_tpu.models import moe as moe_lib
+
+    cfg, params, _, decode = _moe_cell_decode_jaxpr()
+    mcfg = cfg.model
+    B, E, D = cfg.inference.max_batch_size, mcfg.n_experts, mcfg.d_model
+    layer = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+        params["blocks"]["moe"])
+
+    def block(S):
+        assert not moe_lib.takes_grouped_path(mcfg, B, S)
+        return str(jax.make_jaxpr(partial(moe_lib.moe_dispatch, cfg=mcfg))(
+            jax.ShapeDtypeStruct((B, S, D), jnp.float32), layer))
+
+    def zeros(S):       # of a bucket tensor: the capacity and a trash row
+        C = moe_lib.moe_capacity(mcfg, S)
+        return f"f32[{B},{E},{C + 1},{D}] = broadcast_in_dim"
+
+    verify = block(2)
+    assert "scatter-add" in verify and zeros(2) in verify
+    for program in (decode, block(1)):
+        assert "scatter-add" not in program and zeros(1) not in program
+        # The experts' operand and output: [E, slots, 1, D], as they were.
+        assert f"f32[{E},{B},1,{D}]" in program
+
+
+def _one_position_case(router, B, D=16):
+    """A sparse layer at one position a row, [B, 1, D], under one of the
+    three routers the serving cells run. Returns (cfg, params) of the layer
+    as served and (cfg, params, held) of the SAME function in the einsum
+    form over every expert the router chooses among: ``held`` is the slice
+    of them the served layer holds, and the others' ``w_out`` is zero there
+    (an assignment to an expert held elsewhere adds nothing here)."""
+    import dataclasses
+
+    base = get_config("tiny-mixtral").model
+    W, E, off, kw = {
+        # Mixtral's: softmax, top-2 of 8, every expert held.
+        "softmax_top2": (8, 8, 0, dict(n_experts_per_token=2)),
+        # Laguna's: the chip holds experts [8, 16) of the router's 32.
+        "held_share": (32, 8, 8, dict(n_experts_per_token=2)),
+        # Ling's: sigmoid scores under a selection bias, 8 groups of which
+        # the best 4 are kept, top-8.
+        "grouped_sigmoid": (32, 32, 0, dict(
+            n_experts_per_token=8, router_score="sigmoid", router_bias=True,
+            n_group=8, topk_group=4, router_scale=2.5)),
+    }[router]
+    full = dataclasses.replace(
+        base, n_experts=W, router_width=W, expert_offset=0,
+        capacity_factor=W / kw["n_experts_per_token"],
+        moe_dispatch="sorted", **kw)
+    cfg = dataclasses.replace(full, n_experts=E, expert_offset=off)
+    keys = jax.random.split(jax.random.key(B), 6)
+    F = full.d_ff
+    x = jax.random.normal(keys[0], (B, 1, D), jnp.float32)
+    p_full = {
+        "router": jax.random.normal(keys[1], (D, W), jnp.float32) * 0.3,
+        "w_in": jax.random.normal(keys[2], (W, D, F), jnp.float32) * 0.1,
+        "w_gate": jax.random.normal(keys[3], (W, D, F), jnp.float32) * 0.1,
+        "w_out": jax.random.normal(keys[4], (W, F, D), jnp.float32) * 0.1,
+    }
+    if full.router_bias:
+        p_full["router_bias"] = jax.random.normal(keys[5], (W,)) * 0.1
+    held = slice(off, off + E)
+    params = {k: v[held] if k.startswith("w_") else v
+              for k, v in p_full.items()}
+    p_full["w_out"] = jnp.zeros_like(p_full["w_out"]).at[held].set(
+        p_full["w_out"][held])
+    return x, (cfg, params), (full, p_full, held)
+
+
+@pytest.mark.parametrize("B", [1, 32, 128])
+@pytest.mark.parametrize(
+    "router", ["softmax_top2", "held_share", "grouped_sigmoid"])
+def test_moe_sorted_one_position_is_the_bucket_form(router, B):
+    """At one position a row (the decode step) ``moe_mlp_sorted`` builds no
+    bucket tensor: an expert's bucket of a batch row held that row or
+    nothing, so the experts are handed the block broadcast over them. It
+    equals the bucket form (scatter -> experts -> gather, called directly:
+    ``tools/moe_dispatch_bench.bucket_form``, what that tool times it
+    against) BIT FOR BIT in float32, the einsum form within the tolerance of
+    ``tests/test_model.py::test_moe_sorted_matches_einsum`` (its neighbour
+    by subject; that file is wholly ``slow``, and these cases are to run in
+    tier-1), and its gradients w.r.t. x and the three expert matrices are
+    the einsum form's (the broadcast's transpose sums cotangents that are
+    zero for the experts a position did not choose). A row whose k choices
+    are all held elsewhere comes out zero."""
+    from functools import partial
+
+    from orion_tpu.models import moe as moe_lib
+    from tools.moe_dispatch_bench import bucket_form
+
+    x, (cfg, params), (full, p_full, held) = _one_position_case(router, B)
+
+    y, aux = jax.jit(partial(moe_lib.moe_mlp_sorted, cfg=cfg))(x, params)
+    assert y.shape == x.shape
+    np.testing.assert_array_equal(
+        np.asarray(y),
+        np.asarray(jax.jit(partial(bucket_form, cfg=cfg))(x, params)))
+    y_e, aux_e = jax.jit(partial(moe_lib.moe_mlp, cfg=full))(x, p_full)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_e), atol=2e-5)
+    np.testing.assert_allclose(float(aux), float(aux_e), rtol=1e-6)
+
+    idx = moe_lib._router_topk(
+        x, params["router"], cfg, params.get("router_bias"))[2]
+    elsewhere = ~np.asarray(moe_lib._held(idx, cfg)[1]).any(-1)[:, 0]
+    if router != "held_share":
+        assert not elsewhere.any()
+    elif B > 1:
+        assert elsewhere.any() and not elsewhere.all()
+    assert not np.asarray(y)[elsewhere].any()
+    assert np.asarray(y)[~elsewhere].any(-1).all()
+
+    def loss(fn, cfg, x, p):
+        y, aux = fn(x, p, cfg)
+        return (y ** 2).sum() + aux
+
+    g_s = jax.jit(jax.grad(
+        lambda x, p: loss(moe_lib.moe_mlp_sorted, cfg, x, p),
+        argnums=(0, 1)))(x, params)
+    g_e = jax.jit(jax.grad(
+        lambda x, p: loss(moe_lib.moe_mlp, full, x, p),
+        argnums=(0, 1)))(x, p_full)
+    np.testing.assert_allclose(np.asarray(g_s[0]), np.asarray(g_e[0]),
+                               atol=5e-5)
+    for name in ("w_in", "w_gate", "w_out"):
+        np.testing.assert_allclose(
+            np.asarray(g_s[1][name]), np.asarray(g_e[1][name][held]),
+            atol=5e-5, err_msg=name)
 
 
 def _moe_burst(monkeypatch, grouped):

@@ -11,6 +11,16 @@ measured dispatch share. Runs on the real TPU by default:
     python tools/moe_dispatch_bench.py --cpu      # logic check (tiny shape)
 
 Output: one JSON line per mode + a summary line with the dispatch share.
+
+``--decode`` (PR 42) times instead ONE sparse layer's forward at a decode
+step's shape, Ling-3.0-flash as its cell holds it ([128, 1, 2560], 128 of
+the router's 512 experts, F 768, bf16): the capacity-bucket form (zeros,
+scatter-add, experts, gather: ``moe_mlp_sorted`` as it was for every block
+before PR 42 and still is for more than one position a row) against the
+tree's ``moe_mlp_sorted``, whose bucket at one position a row is the row
+itself. The layer's time without a benchmark cell, for whoever takes the
+combine side next (ROADMAP S15 (a)); with ``--cpu`` a logic check at
+tiny-ling's shapes.
 """
 import sys as _sys, pathlib as _pathlib
 _sys.path.insert(0, str(_pathlib.Path(__file__).resolve().parent.parent))
@@ -36,6 +46,59 @@ def bench(fn, args, iters=20, warmup=3):
     return (time.perf_counter() - t0) / iters
 
 
+def bucket_form(x, params, cfg):
+    """``moe_mlp_sorted`` through the capacity buckets whatever the block's
+    shape: what a decode step ran before PR 42."""
+    E = cfg.n_experts
+    idx, gate, pos, keep, _ = moe_lib.route_indices(
+        x, params["router"], cfg, params.get("router_bias"))
+    idx, held = moe_lib._held(idx, cfg)
+    keep, idx = keep & held, jnp.clip(idx, 0, E - 1)
+    xin = moe_lib._scatter_dispatch(
+        x, idx, pos, keep, E, moe_lib.moe_capacity(cfg, x.shape[1]))
+    out = moe_lib._expert_ffn(xin, params, cfg)
+    return moe_lib._gather_combine(out, idx, pos, keep, gate, x.dtype)
+
+
+def decode_row(cpu: bool) -> int:
+    if cpu:
+        cfg = get_config("tiny-ling", ["runtime.platform=cpu",
+                                       "model.n_experts=4"]).model
+        B, dtype = 8, jnp.float32
+    else:
+        cfg = get_config("ling-3.0-flash", ["model.n_experts=128"]).model
+        B, dtype = 128, jnp.bfloat16
+    E, W = cfg.n_experts, cfg.resolved_router_width
+    D, F = cfg.d_model, cfg.resolved_moe_d_ff
+    keys = jax.random.split(jax.random.key(0), 6)
+    x = jax.random.normal(keys[0], (B, 1, D), dtype)
+    params = {
+        "router": jax.random.normal(keys[1], (D, W), jnp.float32) * 0.3,
+        "router_bias": jax.random.normal(keys[5], (W,), jnp.float32) * 0.1,
+        "w_in": jax.random.normal(keys[2], (E, D, F), dtype) * 0.02,
+        "w_gate": jax.random.normal(keys[3], (E, D, F), dtype) * 0.02,
+        "w_out": jax.random.normal(keys[4], (E, F, D), dtype) * 0.02,
+    }
+    forms = {"buckets": lambda x, p: bucket_form(x, p, cfg),
+             "tree": lambda x, p: moe_lib.moe_mlp_sorted(x, p, cfg)[0]}
+    ys = {k: jax.jit(f)(x, params) for k, f in forms.items()}
+    err = float(jnp.max(jnp.abs(
+        ys["tree"].astype(jnp.float32) - ys["buckets"].astype(jnp.float32))))
+    # A time is the chip's: the logic check reports none.
+    ms = {k: None if cpu else round(1e3 * bench(f, (x, params), iters=50), 4)
+          for k, f in forms.items()}
+    print(json.dumps({
+        "summary": "moe_decode_dispatch", "device": jax.devices()[0].device_kind,
+        "shape": {"B": B, "D": D, "F": F, "held": E, "router_width": W,
+                  "k": cfg.n_experts_per_token, "dtype": jnp.dtype(dtype).name},
+        "buckets_ms_per_layer": ms["buckets"],
+        "tree_ms_per_layer": ms["tree"],
+        "expert_weight_bytes": 3 * E * D * F * jnp.dtype(dtype).itemsize,
+        "max_abs_diff": err,
+    }))
+    return 0 if err <= (0.0 if cpu else 1e-2) else 1
+
+
 def main() -> int:
     cpu = "--cpu" in sys.argv[1:]
     if cpu:
@@ -44,6 +107,8 @@ def main() -> int:
         print(f"FAIL: no TPU backend (default backend is "
               f"{jax.default_backend()!r}); use --cpu for the logic check")
         return 1
+    if "--decode" in sys.argv[1:]:
+        return decode_row(cpu)
     if cpu:
         B, S, D, F = 2, 128, 64, 256
         cfg = get_config("tiny-mixtral", ["runtime.platform=cpu"]).model
