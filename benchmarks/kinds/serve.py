@@ -272,16 +272,37 @@ def judged(numbers: dict, margin_min: float, errs: str = "err") -> dict:
     are left out by a rule that asks the reference alone. A single position
     does not tell bfloat16 from the int8 control (0.03-0.08 against 0.09-0.2),
     a probe's median does; a probe with no position left has no number and
-    fails."""
+    fails.
+
+    Two steadier numbers beside it, for a configuration whose ``limits``
+    name them (PR 43; a cell is held to the numbers its file names and to no
+    other). Where experts are many and a position's margin is of the size of
+    the rounding in a bfloat16 score, no ``margin_min`` leaves positions
+    over, a fifth of them picks another last expert and reads 0.03-0.09, and
+    they come in RUNS (the positions behind one long prompt route alike): 12
+    of one probe's 17 in a sound run, which moves its median into the
+    control's range. The positions that did not flip tell the precisions
+    apart, so: ``..._worst_probe_octile_clear``, each probe's LOWER OCTILE
+    (of 17 positions the third smallest: it moves only if 15 do, as when
+    every decode position is wrong) and of those the worst probe's; and
+    ``..._all_probes_median_clear``, the median over all probes' clear
+    positions together, which a fault in part of EVERY probe moves and a run
+    of flips in one probe does not."""
     by_probe = collections.defaultdict(list)
     for probe, err, margin in zip(numbers["probe"], numbers[errs],
                                   numbers["margin"]):
         by_probe[probe] += [err] if margin >= margin_min else []
-    medians = [float(np.median(v)) if v else float("nan")
-               for v in by_probe.values()]
+    clear = list(by_probe.values())
+    if all(clear):
+        median = max(float(np.median(v)) for v in clear)
+        octile = max(float(np.quantile(v, 0.125)) for v in clear)
+        pooled = float(np.median([e for v in clear for e in v]))
+    else:       # a probe with nothing clear: no number at all
+        median = octile = pooled = float("nan")
     return {
-        "logit_rel_err_worst_probe_median_clear": max(medians)
-        if not any(np.isnan(medians)) else float("nan"),
+        "logit_rel_err_worst_probe_median_clear": median,
+        "logit_rel_err_worst_probe_octile_clear": octile,
+        "logit_rel_err_all_probes_median_clear": pooled,
         "window_kv_rel_err_max": max(numbers["window_kv_rel_err"]),
         "window_token_gap_max": max(numbers["window_token_gap"]),
         "clear_positions_per_probe": [len(v) for v in by_probe.values()],

@@ -5,8 +5,8 @@ named ``kda_prefill.N`` where a later program has one) against
 KDA layers) x the recurrence's own operations a position
 (``kda.prefill_flops``: the cheaper of the two exact forms, which no
 chunking can undercut) over the chip's bf16 peak. A program without the
-counter reads nothing; a segment of one that has it in which no prompt was
-admitted reads 0."""
+counter reads nothing, and so does a segment in which no prompt was
+admitted: a share of a roofline is never 0."""
 from benchmarks.metrics import kda
 from benchmarks.metrics.lib import op_seconds
 
@@ -16,12 +16,10 @@ def read(obs):
     if not tr or not obs.get("peaks"):
         return None
     tokens = tr["timing"].get("prefill_kda_token_layers")
-    if tokens is None:
-        return None
     seconds = (op_seconds(obs, r"^kda_prefill\.")
                or kda.scope_seconds(obs, "orion_prefill", "kda/chunk"))
     if not tokens or not seconds:
-        return 0.0
+        return None
     least = (kda.prefill_flops(obs["config"], tokens)
              / obs["peaks"]["bf16_flops"])
     return 100.0 * least / seconds

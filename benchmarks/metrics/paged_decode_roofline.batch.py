@@ -4,7 +4,11 @@ context). The least time is the K and V of every context position the
 kernel had to read (``decode_kv_tokens``: per token step, the live slots'
 context lengths, the sliding window at most) in every layer, over the
 published bandwidth; the kernel's time is that of the operations named
-``paged_decode.N`` in the traced segment."""
+``paged_decode.N`` in the traced segment. ``kv_bytes`` multiplies by
+``num_hidden_layers``, so this reader is for a model whose EVERY layer keeps
+K and V over the same span; a model of mixed layers lists itself under
+``mixed_paged_decode_roofline.batch4k``, which counts (position, layer)
+pairs."""
 from benchmarks.metrics.lib import op_seconds
 
 

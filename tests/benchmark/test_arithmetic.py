@@ -1,6 +1,7 @@
 """Percentiles, inter-token gaps, FLOP and byte counts on hand-computed
 shapes, and the front end's burst rule."""
 
+import numpy as np
 import pytest
 
 from benchmarks.harness.stats import emission_gaps, percentile
@@ -123,6 +124,16 @@ def test_the_judged_logit_error_is_the_worst_probes_median_clear_of_a_tie():
     assert serve.decide(numbers, {"router_margin_min": 0.1, "limits": limits})[0]
     assert not serve.decide(one_bad, {"router_margin_min": 0.1,
                                       "limits": limits})[0]
+    # the two steadier numbers (PR 43): each probe's lower octile, the worst
+    # probe's; the median over all probes' clear positions together
+    got = serve.judged(numbers, 0.0)
+    assert got["logit_rel_err_worst_probe_octile_clear"] == pytest.approx(
+        0.04 + 0.25 * 0.02)            # [0.04, 0.06, 0.8] at 1/8; [0.03, ...]
+    assert got["logit_rel_err_all_probes_median_clear"] == pytest.approx(0.055)
+    nothing_clear = serve.judged(
+        dict(numbers, margin=[0.0] * 3 + [1.0] * 3), 0.1)
+    assert all(np.isnan(v) for k, v in nothing_clear.items()
+               if k.startswith("logit_"))
     # a probe with nothing clear of a tie has no number: not correct
     ok, checks = serve.decide(dict(numbers, margin=[0.0] * 3 + [1.0] * 3),
                               {"router_margin_min": 0.1, "limits": limits})

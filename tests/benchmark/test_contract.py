@@ -40,8 +40,13 @@ def assert_configuration_keeps_to_its_source(entry, cfg, published):
     assert set(entry) == {"name", "source", "file", "reduced", "why"}
     assert NAME.match(entry["name"])
     assert cfg["source"] == entry["source"] and "assumed" in cfg
+    # ``reduced`` may be empty: a model that one chip holds whole cuts
+    # nothing. Its file then has no ``published`` group (or an empty one),
+    # states every published key as published (the loop below) and is asked
+    # for no ``deployment.chips_sharing_a_layer`` (nothing is shared).
     reduced = cfg["reduced"]
-    assert reduced and reduced == entry["reduced"]
+    assert isinstance(reduced, list) and reduced == entry["reduced"]
+    assert len(set(reduced)) == len(reduced)
     assert set(cfg.get("published", {})) == set(reduced)
     for key, value in published.items():
         if key in reduced:
@@ -154,6 +159,148 @@ def test_the_configuration_that_is_not_mistral_keeps_to_its_source_too(
         with pytest.raises((AssertionError, TypeError, KeyError)):
             assert_configuration_keeps_to_its_source(
                 dict(entry, reduced=bad["reduced"]), bad, published)
+
+
+# -- a configuration that cuts nothing (PR 43) -----------------------------------
+# The configuration that is not Mistral again, of a source that publishes the
+# depth and the vocabulary the tiny program runs: ``reduced`` is empty, and
+# the file has no ``published`` group and no deployment to state.
+UNCUT, UNCUT_CELL = "tiny-uncut-serve", "tiny.uncut-batch"
+
+
+def uncut_configuration() -> tuple[dict, dict]:
+    from tests.benchmark.conftest import other_configuration
+
+    cfg, published = other_configuration()
+    published.update({key: cfg[key] for key in cfg["reduced"]})
+    del cfg["published"], cfg["deployment"]
+    cfg["reduced"] = []
+    return cfg, published
+
+
+def uncut_root(root, cfg, published):
+    """The tiny root with the uncut configuration and a cell of it, listed
+    under what ``tiny.other-batch`` is listed under; its ``configs`` entry."""
+    from tests.benchmark.conftest import add_configuration, write_root
+
+    write_root(root)
+    bm = json.loads((root / "BENCHMARK.json").read_text())
+    entry = add_configuration(root, UNCUT, cfg, published)
+    bm["configs"].append(entry)
+    bm["workloads"].append({"name": UNCUT_CELL, "config": UNCUT,
+                            "traffic": "tiny-batch", "chips": 1,
+                            "why": "test"})
+    for m in bm["end_to_end"] + bm["per_layer"]:
+        if "tiny.other-batch" in m.get("workloads", ()):
+            m["workloads"].append(UNCUT_CELL)
+    (root / "BENCHMARK.json").write_text(json.dumps(bm))
+    return entry
+
+
+def test_a_configuration_may_cut_nothing(tmp_path, capsys, monkeypatch):
+    """An empty ``reduced`` all the way: the ``configs`` entry, ``Cell.find``,
+    ``program_config`` against the source's published keys, the contract's
+    assertions, and ``run.py`` itself on the CPU."""
+    from benchmarks.harness.cell import Cell
+    from tests.benchmark.conftest import run_cell
+
+    cfg, published = uncut_configuration()
+    root = tmp_path / "root"
+    entry = uncut_root(root, cfg, published)
+    assert entry["reduced"] == [] == cfg["reduced"]
+    assert "published" not in cfg and "deployment" not in cfg
+    cell = Cell.find(UNCUT_CELL, root=root)
+    assert cell.config["reduced"] == [] and cell.published == published
+    model = cell.program_config().model
+    assert (model.n_layers, model.vocab_size) == (
+        published["num_layers"], published["padded_vocab_size"])
+    assert_configuration_keeps_to_its_source(entry, cfg, published)
+    assert_configuration_keeps_to_its_source(      # an empty group is none
+        entry, dict(cfg, published={}), published)
+    rc, lines = run_cell(root, UNCUT_CELL, capsys, monkeypatch, trace=1)
+    line = json.loads(lines[-1])
+    assert rc == 0 and line["correct"] is True and line["failed"] == 0
+    assert line["metrics"]["compiles_in_window.batch"]["value"] == 0
+
+
+def _cut_and_not_listed(cfg, entry):
+    cfg["num_layers"] = 1
+    cfg["orion"]["overrides"].append("model.n_layers=1")
+
+
+def _listed_and_not_cut(cfg, entry):
+    cfg.update(reduced=["num_layers"],
+               published={"num_layers": cfg["num_layers"]})
+    entry["reduced"] = ["num_layers"]
+
+
+def _listed_in_the_entry_alone(cfg, entry):
+    entry["reduced"] = ["num_layers"]
+
+
+def _listed_in_the_file_alone(cfg, entry):
+    cfg.update(reduced=["num_layers"], num_layers=1,
+               published={"num_layers": 2})
+
+
+def _a_published_group_with_nothing_cut(cfg, entry):
+    cfg["published"] = {"num_layers": cfg["num_layers"]}
+
+
+def _no_list_at_all(cfg, entry):
+    del cfg["reduced"]
+
+
+def _none_for_a_list(cfg, entry):
+    cfg["reduced"] = entry["reduced"] = None
+
+
+def _a_width_cut_and_not_listed(cfg, entry):
+    cfg["ffn_hidden_size"] = 80
+
+
+def _a_width_cut_and_listed(cfg, entry):
+    cfg.update(reduced=["kv_channels"], kv_channels=16,
+               published={"kv_channels": 32})
+    entry["reduced"] = ["kv_channels"]
+
+
+def _a_share_with_no_deployment(cfg, entry):
+    cfg.update(reduced=["padded_vocab_size"], padded_vocab_size=128,
+               published={"padded_vocab_size": 256})
+    entry["reduced"] = ["padded_vocab_size"]
+
+
+@pytest.mark.parametrize("change", [
+    _cut_and_not_listed, _listed_and_not_cut, _listed_in_the_entry_alone,
+    _listed_in_the_file_alone, _a_published_group_with_nothing_cut,
+    _no_list_at_all, _none_for_a_list, _a_width_cut_and_not_listed,
+    _a_width_cut_and_listed, _a_share_with_no_deployment])
+def test_an_uncut_configuration_is_still_held_to_its_source(change):
+    """The assertions that take an empty ``reduced`` refuse what they
+    refused: a key that is cut and not listed, a listed key that is not cut,
+    a list the entry and the file do not share, a width, a share of a layer
+    with no deployment behind it."""
+    cfg, published = uncut_configuration()
+    entry = {"name": UNCUT, "source": cfg["source"], "reduced": [],
+             "why": "test", "file": f"benchmarks/configs/{UNCUT}.json"}
+    assert_configuration_keeps_to_its_source(entry, cfg, published)
+    change(cfg, entry)
+    with pytest.raises((AssertionError, TypeError, KeyError)):
+        assert_configuration_keeps_to_its_source(entry, cfg, published)
+
+
+def test_the_program_refuses_a_key_cut_under_an_empty_list(tmp_path):
+    """``program_config`` from the other side: an uncut file whose depth is
+    not the source's is refused by the key, and names ``reduced``."""
+    from benchmarks.harness.cell import Cell
+
+    cfg, published = uncut_configuration()
+    _cut_and_not_listed(cfg, {})
+    uncut_root(tmp_path / "root", cfg, published)
+    cell = Cell.find(UNCUT_CELL, root=tmp_path / "root")
+    with pytest.raises(SystemExit, match="num_layers.*not in 'reduced'"):
+        cell.program_config()
 
 
 # What each cell reported before every per-layer metric named its cells

@@ -177,22 +177,34 @@ def _tiny_configuration():
     return cfg, published
 
 
+# What the next PR brings: one more per-layer entry BEHIND the four, with a
+# reader file of its own; the tiny root lists it through
+# ``write_root(extra_metrics=)`` and ``run.py`` reads it on the CPU.
+FIFTH = "kda_slot_layers_in_window.next"
+FIFTH_READER = '''"""Live slots x KDA layers over the window's token steps."""
+
+
+def read(obs):
+    return obs["timing"].get("decode_kda_slot_layers") or None
+'''
+
+
 @pytest.fixture(scope="module")
 def ling_root(tmp_path_factory):
     """The tests' tiny benchmark root with one more configuration, a mix of
-    the kind this configuration brings, and a cell listed under the metrics
-    the real cell is listed under and under the four ``.reason128`` entries
-    as a ``benchmark`` PR would append them (the real file cannot hold them
-    yet: ``test_the_parent_of_this_configuration_reads_nothing``)."""
+    the kind this configuration brings, and a cell listed under every metric
+    the real cell is listed under (the four ``.reason128`` entries and the
+    nine by-part metrics among them, since PR 43), and a fifth entry behind
+    the four as a later PR would append it."""
     root = write_root(tmp_path_factory.mktemp("tiny_ling"), extra_metrics=[
-        {"name": name, "unit": "B" if name == NEW[3] else "%",
-         "better": "lower" if name == NEW[3] else "higher",
-         "source": "program_counter" if name == NEW[3] else "device_trace",
-         "layer": "scheduler" if name == NEW[3] else "kernels",
-         "moves": "serve_tokens_per_s", "workloads": [TINY]}
-        for name in NEW])
+        {"name": FIFTH, "unit": "count", "better": "higher",
+         "source": "program_counter", "layer": "scheduler",
+         "moves": "serve_tokens_per_s", "workloads": [TINY]}])
+    (root / "benchmarks" / "metrics").mkdir()
+    (root / "benchmarks" / "metrics" / f"{FIFTH}.py").write_text(FIFTH_READER)
     real = json.loads((REPO / "BENCHMARK.json").read_text())
     bm = json.loads((root / "BENCHMARK.json").read_text())
+    assert [m["name"] for m in bm["per_layer"]][-5:] == list(NEW) + [FIFTH]
     bm["configs"].append(add_configuration(
         root, "tiny-ling-serve", *_tiny_configuration()))
     (root / "benchmarks" / "traffic" / "tiny-rows.json").write_text(
@@ -202,6 +214,7 @@ def ling_root(tmp_path_factory):
                             "why": "test"})
     mine = {m["name"] for m in real["end_to_end"] + real["per_layer"]
             if CELL in m.get("workloads", ())}
+    assert set(NEW) <= mine and len(mine) >= 1 + 28
     for m in bm["end_to_end"] + bm["per_layer"]:
         if m["name"] in mine:
             m["workloads"].append(TINY)
@@ -228,6 +241,10 @@ def test_a_tiny_configuration_of_this_kind_runs_end_to_end(
     # alone reads here; the three shares of a roofline need a device trace
     assert [n for n in m if n.endswith(".reason128")] == [NEW[3]]
     assert m[NEW[3]] > 0
+    # and the entry a later PR appends behind the four is found and read
+    assert m[FIFTH] > 0 and list(m)[-2:] == [NEW[3], FIFTH]
+    per = {x["name"] for x in Cell.find(TINY, root=ling_root).per_layer}
+    assert set(NEW) <= per and "decode_ffn_ms_per_step.batch" in per
     assert "paged_decode_roofline.batch" not in m          # a K/V model's
     assert "latent_cache_bytes_per_token.longctx" not in m  # all-latent's
     checks = dict(line.split(" = ")[0].split("check: ")[1:] + [line]
@@ -249,22 +266,30 @@ def test_the_parent_of_this_configuration_reads_nothing():
              "trace": {"timing": {}, "op_s": {"fusion.1": 1.0},
                        "module_s": {"jit__unknown(1)": 1.0},
                        "module_n": {"jit__unknown(1)": 2}}}
-    # The four are reader files WITHOUT entries in the real ``per_layer``:
-    # tests/benchmark/test_scopes.py holds PR 38's ten to the last places of
-    # that list and the driver takes a new entry only behind the last one,
-    # so no entry can be added before a ``benchmark`` PR drops that line
-    # (PERF.md section 7, ROADMAP M9). What was there stays as it was.
+    # Since PR 43 the four ARE entries of the real ``per_layer``, the last
+    # four of that PR's list, and the cell reads them beside the nine by-part
+    # metrics (PR 41 could bring them as reader files only: a line of
+    # tests/benchmark/test_scopes.py held PR 38's ten to the END of the list);
+    # that file holds their places and what each entry says.
     bm = json.loads((REPO / "BENCHMARK.json").read_text())
     names = [m["name"] for m in bm["per_layer"]]
-    assert not set(NEW) & set(names)
-    assert names[-10] == "decode_attn_kernel_ms_per_step.batch"
-    assert "latent_decode_roofline.longctx" in {
-        m["name"] for m in cell.per_layer}
+    at = names.index("paged_decode_page_rounding.batch")
+    assert tuple(names[at + 1:at + 5]) == NEW
+    mine = [m["name"] for m in cell.per_layer]
+    assert [n for n in mine if n in NEW] == list(NEW) and len(mine) >= 28
+    assert {"latent_decode_roofline.longctx", "decode_ffn_ms_per_step.batch",
+            "decode_unscoped_ms_per_step.batch",
+            "prefill_attn_ms_per_ktoken.batch"} <= set(mine)
     other = Cell.find("mixtral-8x7b.serve-batch").config
     for name in NEW:
         assert cell.reader(name).read(empty) is None
         assert cell.reader(name).read(dict(empty, trace=None)) is None
         assert cell.reader(name).read(dict(empty, config=other)) is None
+    # a traced segment in which no prompt was admitted: the counter is there
+    # and reads 0, and a share of a roofline is left out, never reported as 0
+    quiet = dict(empty, trace=dict(
+        empty["trace"], timing={"prefill_kda_token_layers": 0}))
+    assert cell.reader(NEW[1]).read(quiet) is None
 
 
 def test_the_readers_arithmetic():
@@ -324,6 +349,100 @@ def test_the_planted_faults_run_through_the_harness(
     assert [l for l in lines if l.startswith("correct: ")] == [
         "correct: True", "correct: False", "correct: False", "correct: True"]
     assert lines[-1].endswith("the check sees ['erase', 'groups']")
+
+
+# -- the output check's numbers, on readings of the chip (PR 43) -----------------
+
+READINGS = json.loads(
+    (REPO / "tests/benchmark/data/calibrate_ling_reason128_v5e.json")
+    .read_text())
+OLD = "logit_rel_err_worst_probe_median_clear"
+OCTILE = "logit_rel_err_worst_probe_octile_clear"
+POOLED = "logit_rel_err_all_probes_median_clear"
+
+
+def _numbers(reading: dict, errs: str = "err") -> dict:
+    """What ``probe_numbers`` handed ``decide`` on the chip for one seed;
+    with ``errs='control_err'`` the int8 control in the program's place."""
+    return {"probe": READINGS["probe"], "err": reading[errs],
+            "margin": reading["margin"],
+            "window_kv_rel_err": reading["window_kv_rel_err"],
+            "window_token_gap": reading["window_token_gap"]}
+
+
+@pytest.mark.parametrize("reading", READINGS["seeds"],
+                         ids=lambda r: str(r["seed"]))
+def test_the_check_passes_the_program_and_fails_the_control(reading):
+    """Twelve seeds of the cell on a TPU v5e, position by position, through
+    the benchmark's own ``decide`` under the cell's own limits: the program
+    is correct on every one, the int8 control in its place on none, nor a
+    one-step body fed another token."""
+    from benchmarks.kinds import serve
+
+    correct = Cell.find(CELL).config["correct"]
+    assert set(correct["limits"]) == {OCTILE, POOLED, "window_kv_rel_err_max",
+                                      "window_token_gap_max"}
+    ok, checks = serve.decide(_numbers(reading), correct)
+    assert ok and len(checks) == 4
+    ok, checks = serve.decide(_numbers(reading, "control_err"), correct)
+    assert not ok
+    assert all(v > lim for name, v, lim in checks if name.startswith("logit"))
+    broken = dict(_numbers(reading), window_kv_rel_err=[
+        reading["link_broken"]["window_kv_rel_err_max"]])
+    assert not serve.decide(broken, correct)[0]
+
+
+def test_the_worst_probes_median_failed_a_sound_run_and_these_do_not():
+    """Why the cell is not held to the worst probe's median any more: on
+    seed 1789062289 twelve of the longest probe's 17 positions flipped a
+    last expert together and its median read 0.0448, inside the control's
+    range; the lower octile and the median over all probes keep the two
+    readings 3.5x apart, and each limit has more room above the program's
+    largest reading than under the control's smallest."""
+    from benchmarks.kinds import serve
+
+    limits = Cell.find(CELL).config["correct"]["limits"]
+    read = lambda errs, name: [
+        serve.judged(_numbers(r, errs), 0.0)[name] for r in READINGS["seeds"]]
+    first = READINGS["seeds"][0]
+    assert first["seed"] == 1789062289
+    assert sum(e > 0.02 for e in first["err"][-17:]) == 12
+    assert serve.judged(_numbers(first), 0.0)[OLD] == pytest.approx(0.044813)
+    assert max(read("err", OLD)) > min(read("control_err", OLD)) > 0.021
+    for name in (OCTILE, POOLED):
+        lower, upper = max(read("err", name)), min(read("control_err", name))
+        assert upper > 3 * lower
+        assert lower < limits[name] < upper
+        assert limits[name] / lower > upper / limits[name] > 1.5
+    # every position reads as unflipped or as flipped, nothing between
+    errs = [e for r in READINGS["seeds"] for e in r["err"]]
+    assert not [e for e in errs if 0.012 < e < 0.03]
+    assert 0.15 < sum(e > 0.03 for e in errs) / len(errs) < 0.25
+
+
+@pytest.mark.parametrize("at, seen", [
+    pytest.param(range(18, 34), True, id="every decode position of one probe"),
+    pytest.param([17 * p + i for p in range(4) for i in range(4, 17)], True,
+                 id="the last thirteen positions of every probe"),
+    pytest.param(range(51, 68), True, id="every position of one probe"),
+    pytest.param([0, 17, 34, 51], False,
+                 id="the last prompt position of every probe"),
+    pytest.param(range(5, 17), False,
+                 id="the last twelve positions of one probe"),
+])
+def test_what_the_two_numbers_hold_and_give_up(at, seen):
+    """Faults planted in the readings of a sound seed (a position at fault
+    reads ten times what it read): what moves the lower octile of a probe or
+    the median over all of them, and what neither sees (nor did the worst
+    probe's median see a single position; a run of twelve it did see, and
+    that is what a sound run can show)."""
+    from benchmarks.kinds import serve
+
+    reading = READINGS["seeds"][1]
+    err = [e * 10 if i in at else e for i, e in enumerate(reading["err"])]
+    ok, _ = serve.decide(dict(_numbers(reading), err=err),
+                         Cell.find(CELL).config["correct"])
+    assert ok is not seen
 
 
 def test_decode_and_the_widest_burst_fit_the_chip():

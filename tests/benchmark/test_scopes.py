@@ -427,6 +427,7 @@ def test_recorded_v5e_trace_with_the_scopes():
 
 UNPINNED = ["laguna-s-2.1.serve-batch-4k", "brumby-14b.serve-longout",
             "glm-4.7-flash.serve-longctx"]
+LING = "ling-3.0-flash.serve-reason-128"
 BY_PART = ["decode_attn_kernel_ms_per_step.batch",
            "decode_attn_proj_ms_per_step.batch",
            "decode_ffn_ms_per_step.batch", "decode_head_ms_per_step.batch",
@@ -436,17 +437,48 @@ BY_PART = ["decode_attn_kernel_ms_per_step.batch",
            "prefill_route_ms_per_ktoken.batch",
            "prefill_other_ms_per_ktoken.batch"]
 ROUNDING = "paged_decode_page_rounding.batch"
+# PR 43's four, Ling's alone: name -> (unit, better, source, layer)
+REASON128 = {
+    "kda_decode_roofline.reason128": ("%", "higher", "device_trace", "kernels"),
+    "kda_prefill_roofline.reason128": ("%", "higher", "device_trace", "kernels"),
+    "held_expert_ffn_roofline.reason128": (
+        "%", "higher", "device_trace", "kernels"),
+    "hybrid_cache_bytes_per_token.reason128": (
+        "B", "lower", "program_counter", "scheduler"),
+}
+# The order in which these were accepted, and the place of the first. The
+# driver compares ``per_layer`` place by place and takes a new entry only
+# BEHIND the last one, so an accepted entry never moves and what a later PR
+# adds stands behind all of these: their places are held, as the driver holds
+# them, and not their distance from the end (a ``benchmark`` PR that takes an
+# earlier entry away moves FIRST with it).
+ACCEPTED = BY_PART + [ROUNDING] + list(REASON128)
+FIRST = 36
 
 
 def reader(name: str):
     return Cell.find(UNPINNED[0]).reader(name)
 
 
-@pytest.mark.parametrize("name", BY_PART + [ROUNDING])
-def test_a_new_metric_keeps_to_the_contract(name):
+def serving_cells(bm: dict) -> list:
+    """The cells that report the metric the by-part metrics move."""
+    e2e = next(m for m in bm["end_to_end"]
+               if m["name"] == "serve_tokens_per_s")
+    return [w["name"] for w in bm["workloads"]
+            if "workloads" not in e2e or w["name"] in e2e["workloads"]]
+
+
+def assert_a_by_part_list_is_kept(workloads: list, serving: list) -> None:
+    """A by-part metric's cells: the three it was accepted with, in their
+    places, and behind them only further serving cells, each once."""
+    assert workloads[:len(UNPINNED)] == UNPINNED
+    assert len(set(workloads)) == len(workloads)
+    assert set(workloads) <= set(serving)
+
+
+def assert_a_new_metric_keeps_to_the_contract(bm: dict, name: str) -> None:
     from tests.benchmark.test_contract import NAME, UNIT
 
-    bm = load_benchmark()
     entry = next(m for m in bm["per_layer"] if m["name"] == name)
     assert NAME.match(name) and UNIT.match(entry["unit"])
     assert entry["moves"] == "serve_tokens_per_s"
@@ -456,19 +488,100 @@ def test_a_new_metric_keeps_to_the_contract(name):
         assert (entry["source"], entry["layer"], entry["unit"]) == (
             "program_counter", "kernels", "x")
         assert entry["workloads"] == UNPINNED[:1]
+    elif name in REASON128:
+        assert (entry["unit"], entry["better"], entry["source"],
+                entry["layer"]) == REASON128[name]
+        assert entry["workloads"] == [LING]
     else:
         assert (entry["source"], entry["layer"], entry["better"]) == (
             "device_trace", "dispatch programs", "lower")
-        assert entry["workloads"] == UNPINNED
-    # new entries stand behind the accepted ones, and their cells find them
+        assert_a_by_part_list_is_kept(entry["workloads"], serving_cells(bm))
+    # the accepted entries keep their order, and their cells find them
     names = [m["name"] for m in bm["per_layer"]]
-    assert names.index(name) >= len(names) - len(BY_PART) - 1
+    assert len(set(names)) == len(names)
+    assert names[FIRST:FIRST + len(ACCEPTED)] == ACCEPTED
     for cell in entry["workloads"]:
-        assert name in {m["name"] for m in Cell.find(cell).per_layer}
+        found = Cell.find(cell, benchmark=bm)
+        assert name in {m["name"] for m in found.per_layer}
+        assert hasattr(found.reader(name), "read")
+
+
+@pytest.mark.parametrize("name", ACCEPTED)
+def test_a_new_metric_keeps_to_the_contract(name):
+    assert_a_new_metric_keeps_to_the_contract(load_benchmark(), name)
     # a run without a trace, and a program without the counter, read nothing
     assert reader(name).read(
         {"trace": None, "timing": {}, "decode_window": 8,
          "config": {"orion": {"overrides": []}}}) is None
+
+
+def test_lings_cell_is_split_by_part_and_reads_its_four():
+    """Ling's ledger line: the fifteen it had, the nine by-part metrics and
+    the four ``.reason128`` entries (28; a later PR may add to them); no
+    other cell reads one of the four."""
+    bm = load_benchmark()
+    mine = [m["name"] for m in Cell.find(LING, benchmark=bm).per_layer]
+    assert len(mine) >= 28 and set(BY_PART) | set(REASON128) <= set(mine)
+    assert ROUNDING not in mine
+    for m in bm["per_layer"]:
+        if m["name"] in BY_PART:
+            assert m["workloads"][:4] == UNPINNED + [LING]
+    for cell in UNPINNED + ["mixtral-8x7b.serve-batch"]:
+        theirs = {m["name"] for m in Cell.find(cell, benchmark=bm).per_layer}
+        assert not theirs & set(REASON128)
+
+
+SERVING = UNPINNED + [LING, "mixtral-8x7b.serve-batch"]
+
+
+@pytest.mark.parametrize("workloads, kept", [
+    (UNPINNED, True),                                  # as PR 38 had them
+    (UNPINNED + [LING], True),                         # as this PR has them
+    (UNPINNED + [LING, "mixtral-8x7b.serve-batch"], True),   # a later PR's
+    (UNPINNED[:2] + [LING], False),                    # one of the three lost
+    (UNPINNED[1:] + [LING], False),
+    ([UNPINNED[1], UNPINNED[0], UNPINNED[2], LING], False),  # reordered
+    ([LING] + UNPINNED, False),                        # in front of them
+    (UNPINNED + [LING, LING], False),                  # twice
+    (UNPINNED + ["mistral-7b.train-8k"], False),       # no serving cell
+    (UNPINNED + ["no-such.cell"], False),
+])
+def test_a_by_part_list_grows_by_serving_cells_and_loses_none(
+        workloads, kept):
+    assert set(SERVING) == set(serving_cells(load_benchmark()))
+    if kept:
+        assert_a_by_part_list_is_kept(workloads, SERVING)
+        return
+    with pytest.raises(AssertionError):
+        assert_a_by_part_list_is_kept(workloads, SERVING)
+
+
+def test_a_fifth_entry_behind_the_four_is_taken():
+    """What the next PR does: one more per-layer entry behind the last one,
+    and its cell behind the last name of a by-part list. Every accepted
+    entry still keeps to the contract; with an entry put in front of an
+    accepted one, or an accepted one moved, none does."""
+    import copy
+
+    fifth = {"name": "ssm_scan_roofline.chat256", "unit": "%",
+             "better": "higher", "source": "device_trace", "layer": "kernels",
+             "moves": "serve_tokens_per_s", "workloads": [LING]}
+    bm = load_benchmark()
+    grown = copy.deepcopy(bm)
+    grown["per_layer"].append(fifth)
+    for m in grown["per_layer"]:
+        if m["name"] in BY_PART:
+            m["workloads"].append("mixtral-8x7b.serve-batch")
+    for name in ACCEPTED:
+        assert_a_new_metric_keeps_to_the_contract(grown, name)
+    last = FIRST + len(ACCEPTED) - 1
+    for bad in (lambda p: p.insert(last, fifth),
+                lambda p: p.insert(FIRST, p.pop(last))):
+        moved = copy.deepcopy(bm)
+        bad(moved["per_layer"])
+        for name in ACCEPTED:
+            with pytest.raises(AssertionError):
+                assert_a_new_metric_keeps_to_the_contract(moved, name)
 
 
 def test_the_readers_divide_by_what_the_metrics_they_split_divide(monkeypatch):
