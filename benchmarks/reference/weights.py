@@ -4,9 +4,22 @@ One jitted call draws every leaf on the device, in the dtype it is used in
 (``fold_in`` of the leaf's path, so a leaf does not depend on the others).
 Which leaves there are is the configuration's reference's to say
 (``param_spec(hf)`` of ``reference/<name>.py``: ``{path: (shape, kind)}``,
-kind 'normal', 'resid' or 'norm'): the tree has the layout the program's
-model reads, and the reference reads the same arrays. A test pins each
-configuration's layout against ``orion_tpu.models.init_params``."""
+kind 'normal', 'resid', 'norm' or ``("uniform", lo, hi)``): the tree has the
+layout the program's model reads, and the reference reads the same arrays. A
+test pins each configuration's layout against
+``orion_tpu.models.init_params``.
+
+``("uniform", lo, hi)`` is ``lo + (hi - lo) x U[0, 1)``, drawn in float32 and
+cast to the tree's dtype (so within a rounding step of ``[lo, hi)`` in a
+narrower one), for the leaves of a recurrence that are time constants and not
+matrices: drawn as N(0, 0.02) a state decays within a position or two, and
+its carry across a chunk or a fold never reaches the output check (PERF.md
+section 6, PRs 33 and 41). Such a leaf takes the range its source initialises
+it over, and the reference that names the kind states that source: a
+state-space layer's ``A_log`` over ``[0, log d_state]`` and its step bias
+over ``[softplus^-1(0.001), softplus^-1(0.1)]`` = ``[-6.907, -2.252]``
+(Mamba, arXiv:2312.00752, section 3.6 and its code's ``dt_min``, ``dt_max``,
+``A = 1..d_state``) give time constants of ten to a thousand positions."""
 
 from __future__ import annotations
 
@@ -36,6 +49,13 @@ def _draw(spec: dict, n_layers: int, dtype, key):
     flat = {}
     for path, (shape, kind) in spec.items():
         k = jax.random.fold_in(key, zlib.crc32("/".join(path).encode()))
+        if isinstance(kind, tuple):
+            name, lo, hi = kind
+            if name != "uniform" or not lo < hi:
+                raise ValueError(f"{'/'.join(path)}: no such kind {kind!r}")
+            u = jax.random.uniform(k, shape, jnp.float32)
+            flat[path] = (lo + (hi - lo) * u).astype(dtype)
+            continue
         z = jax.random.normal(k, shape, dtype)
         if kind == "norm":
             flat[path] = (1.0 + NORM_JITTER * z).astype(dtype)

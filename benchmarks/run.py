@@ -5,8 +5,9 @@
 
 Prints, as the last line of standard output, one JSON object with
 ``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (and
-``breakdown`` when traced). Exits non-zero, printing no result, without an
-accelerator or with fewer chips than the cell asks for."""
+``breakdown`` when traced), then ``checks``: each number compared, with its
+limit. Exits non-zero, printing no result, without an accelerator or with
+fewer chips than the cell asks for."""
 
 from __future__ import annotations
 
@@ -95,6 +96,10 @@ def main(argv=None, root: pathlib.Path = ROOT, allow_cpu: bool = False) -> int:
             "failed": out.failed, "metrics": metrics, "device": device}
     if args.trace and out.breakdown is not None and on_device:
         line["breakdown"] = out.breakdown
+    # each number compared beside its limit, under a key of its own that
+    # comes last in the line (the driver's record keeps the line's end)
+    line["checks"] = {name: {"value": value, "limit": limit}
+                      for name, value, limit in out.checks}
     print(json.dumps(line), flush=True)
     # Each number compared beside its limit, as the last lines of standard
     # error too: of a run that is not correct the driver keeps the end of that.

@@ -325,8 +325,20 @@ PER_LAYER = {
 }
 
 
+# The per-layer entries accepted when this table was last read against the
+# file (``test_scopes.FIRST + len(ACCEPTED)``; this module imports no jax): the
+# driver holds each to its place, so a later PR's entry stands behind them.
+ACCEPTED_ENTRIES = 50
+
+
 @pytest.mark.parametrize("cell", sorted(PER_LAYER))
 def test_a_cell_reports_the_metrics_it_reported(cell):
+    """Of the accepted entries exactly those, so that no accepted entry's
+    list gains or loses the cell; an entry behind the last accepted one that
+    names the cell is an addition, not an edit, and is taken."""
     from benchmarks.harness.cell import Cell
 
-    assert {m["name"] for m in Cell.find(cell).per_layer} == PER_LAYER[cell]
+    accepted = [m["name"] for m in BM["per_layer"][:ACCEPTED_ENTRIES]]
+    assert len(accepted) == ACCEPTED_ENTRIES
+    reported = [m["name"] for m in Cell.find(cell).per_layer]
+    assert {n for n in reported if n in accepted} == PER_LAYER[cell]

@@ -9,7 +9,7 @@ from tests.benchmark.conftest import (OTHER, PUBLISHED, add_configuration,
                                       other_configuration, run_cell,
                                       write_root)
 
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = ["correct", "attempted", "failed", "metrics", "device", "checks"]
 
 
 @pytest.mark.parametrize("workload", ["tiny.train", "tiny.dense-batch",
@@ -20,7 +20,7 @@ def test_each_kind_runs_and_prints_the_contract_line(
     rc, lines = run_cell(tiny_root, workload, capsys, monkeypatch, trace=trace)
     assert rc == 0
     line = json.loads(lines[-1])
-    assert set(line) == KEYS          # exactly the contract's keys
+    assert list(line) == KEYS         # the contract's keys; ``checks`` last
     assert line["correct"] is True
     assert line["attempted"] > 0 and line["failed"] == 0
     assert set(line["device"]) == {"platform", "kind", "count",
@@ -29,6 +29,9 @@ def test_each_kind_runs_and_prints_the_contract_line(
     # Every compared number is printed beside its limit, and the in-window
     # compile count is printed in every run and reads 0.
     assert any(l.startswith("check: ") and "limit" in l for l in lines)
+    assert [f"check: {name} = {c['value']!r} (limit {c['limit']!r})"
+            for name, c in line["checks"].items()] == [
+        l for l in lines if l.startswith("check: ")]
     assert "compiles_in_window: 0" in lines
     # A CPU run prints counts only: no time, rate, share or utilisation
     # under a metric's name.

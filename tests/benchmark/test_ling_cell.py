@@ -15,12 +15,11 @@ from benchmarks.harness.cell import Cell
 from benchmarks.metrics import kda
 from tests.benchmark.conftest import (MIXES, REPO, add_configuration,
                                       run_cell, write_root)
+from tests.benchmark.test_scopes import ACCEPTED, FIRST, LING as CELL, REASON128
 
-CELL = "ling-3.0-flash.serve-reason-128"
 TINY = "tiny.ling"
-NEW = ("kda_decode_roofline.reason128", "kda_prefill_roofline.reason128",
-       "held_expert_ffn_roofline.reason128",
-       "hybrid_cache_bytes_per_token.reason128")
+# the cell's four entries: kda decode, kda prefill, held experts, cache bytes
+NEW = tuple(REASON128)
 
 
 def test_bytes_against_the_cut_table():
@@ -204,7 +203,12 @@ def ling_root(tmp_path_factory):
     (root / "benchmarks" / "metrics" / f"{FIFTH}.py").write_text(FIFTH_READER)
     real = json.loads((REPO / "BENCHMARK.json").read_text())
     bm = json.loads((root / "BENCHMARK.json").read_text())
-    assert [m["name"] for m in bm["per_layer"]][-5:] == list(NEW) + [FIFTH]
+    # The four stand where they were accepted (the driver holds an entry's
+    # place, not its distance from the end) and the fifth is the last of the
+    # tiny root's list, whatever a later PR appended between them.
+    names = [m["name"] for m in bm["per_layer"]]
+    at = FIRST + ACCEPTED.index(NEW[0])
+    assert tuple(names[at:at + len(NEW)]) == NEW and names[-1] == FIFTH
     bm["configs"].append(add_configuration(
         root, "tiny-ling-serve", *_tiny_configuration()))
     (root / "benchmarks" / "traffic" / "tiny-rows.json").write_text(

@@ -4,6 +4,7 @@ control that has to fail, and the layout of the benchmark-made weights."""
 import dataclasses
 import hashlib
 import json
+import math
 
 import jax
 import jax.numpy as jnp
@@ -156,6 +157,14 @@ DRAWN_AT_THE_PARENT = {
 }
 
 
+def _digest(params) -> str:
+    digest = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        digest.update(jax.tree_util.keystr(path).encode())
+        digest.update(np.asarray(leaf).tobytes())
+    return digest.hexdigest()
+
+
 @pytest.mark.parametrize("cfg_name, dtype, seed", sorted(DRAWN_AT_THE_PARENT))
 def test_the_weights_are_bitwise_those_of_the_parent(tiny_root, cfg_name,
                                                      dtype, seed):
@@ -165,11 +174,7 @@ def test_the_weights_are_bitwise_those_of_the_parent(tiny_root, cfg_name,
                      root=tiny_root)
     p = weights.make_params(cell.reference().param_spec(cell.config),
                             cell.config["num_hidden_layers"], dtype, seed)
-    digest = hashlib.sha256()
-    for path, leaf in jax.tree_util.tree_flatten_with_path(p)[0]:
-        digest.update(jax.tree_util.keystr(path).encode())
-        digest.update(np.asarray(leaf).tobytes())
-    assert digest.hexdigest() == DRAWN_AT_THE_PARENT[cfg_name, dtype, seed]
+    assert _digest(p) == DRAWN_AT_THE_PARENT[cfg_name, dtype, seed]
 
 
 def test_the_same_seed_gives_the_same_weights():
@@ -180,3 +185,91 @@ def test_the_same_seed_gives_the_same_weights():
     assert all(bool(jnp.array_equal(x, y)) for x, y in
                zip(jax.tree.leaves(a), jax.tree.leaves(b)))
     assert not bool(jnp.array_equal(a["lm_head"], c["lm_head"]))
+
+
+# -- a recurrence's time constants: the kind ("uniform", lo, hi) (PR 44) ---------
+# A state-space layer's two such leaves over the ranges its source initialises
+# them over (Mamba, arXiv:2312.00752, section 3.6: A = 1..d_state, dt in
+# [0.001, 0.1]), beside one leaf of each older kind.
+D_STATE = 16
+A_LOG = ("uniform", 0.0, math.log(D_STATE))
+DT_BIAS = ("uniform", math.log(math.expm1(0.001)), math.log(math.expm1(0.1)))
+RECURRENT = {
+    ("blocks", "ssm", "A_log"): ((2, 40, D_STATE), A_LOG),
+    ("blocks", "ssm", "dt_bias"): ((2, 40), DT_BIAS),
+    ("blocks", "ssm", "D"): ((2, 40), A_LOG),
+    ("blocks", "ssm", "w_in"): ((2, 24, 80), "normal"),
+    ("blocks", "ssm", "w_out"): ((2, 40, 24), "resid"),
+    ("blocks", "ssm_norm", "scale"): ((2, 24), "norm"),
+}
+
+
+def test_a_uniform_leaf_lies_in_its_range_and_is_the_seeds_and_the_paths():
+    assert DT_BIAS[1:] == pytest.approx((-6.907, -2.252), abs=1e-3)
+    a = weights.make_params(RECURRENT, 2, "float32", 2 ** 31 + 9)["blocks"]
+    b = weights.make_params(RECURRENT, 2, "float32", 2 ** 31 + 9)["blocks"]
+    c = weights.make_params(RECURRENT, 2, "float32", 2 ** 31 + 10)["blocks"]
+    for name, (_, lo, hi) in (("A_log", A_LOG), ("dt_bias", DT_BIAS)):
+        leaf = np.asarray(a["ssm"][name])
+        assert leaf.dtype == np.float32 and leaf.shape == RECURRENT[
+            "blocks", "ssm", name][0]
+        assert lo <= leaf.min() and leaf.max() < hi
+        # spread over the range, not huddled at one end of it
+        tenth = 0.1 * (hi - lo)
+        assert leaf.min() < lo + tenth and hi - tenth < leaf.max()
+        np.testing.assert_array_equal(leaf, np.asarray(b["ssm"][name]))
+        assert not np.array_equal(leaf, np.asarray(c["ssm"][name]))
+    # two paths of one shape and one range draw from keys of their own
+    assert a["ssm"]["D"].shape == a["ssm"]["dt_bias"].shape
+    u = lambda name, kind: (np.asarray(a["ssm"][name]) - kind[1]) / (
+        kind[2] - kind[1])
+    assert np.abs(u("D", A_LOG) - u("dt_bias", DT_BIAS)).max() > 0.5
+    # the time constants the ranges are for: 1 / (dt x A) positions
+    dt = np.log1p(np.exp(np.asarray(a["ssm"]["dt_bias"])))
+    assert 0.001 <= dt.min() and dt.max() <= 0.1
+    slowest = 1.0 / (dt[..., None] * np.exp(np.asarray(a["ssm"]["A_log"])))
+    assert np.median(slowest) > 10 and slowest.max() > 300
+    # in a narrower dtype: the float32 draw, cast
+    low = weights.make_params(RECURRENT, 2, "bfloat16", 2 ** 31 + 9)["blocks"]
+    assert low["ssm"]["A_log"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(low["ssm"]["A_log"]),
+        np.asarray(a["ssm"]["A_log"].astype(jnp.bfloat16)))
+    # a leaf of an older kind draws what it drew with no such leaf beside it
+    alone = weights.make_params(
+        {k: v for k, v in RECURRENT.items() if not isinstance(v[1], tuple)},
+        2, "float32", 2 ** 31 + 9)["blocks"]
+    for name in ("w_in", "w_out"):
+        np.testing.assert_array_equal(np.asarray(alone["ssm"][name]),
+                                      np.asarray(a["ssm"][name]))
+
+
+@pytest.mark.parametrize("kind", [("uniform", 1.0, 1.0), ("uniform", 2.0, 1.0),
+                                  ("normal", 0.0, 1.0)])
+def test_a_kind_that_is_none_is_refused_by_the_leaf(kind):
+    with pytest.raises(ValueError, match="blocks/ssm/A_log"):
+        weights.make_params({("blocks", "ssm", "A_log"): ((2, 4), kind)},
+                            2, "float32", 0)
+
+
+# sha256 as above, of the tree ``make_params`` draws for the spec of
+# ``data/tiny_other`` (leaves of all three older kinds) at seed 0, computed at
+# ea36d75, the parent of the PR that added the fourth kind: the three draw
+# what they drew, to the bit.
+OTHER_DRAWN_AT_THE_PARENT = {
+    "float32":
+        "ff849035e2d2dc7098ab8721a342a1b9916f1fc2098ff3547f937441258e5e67",
+    "bfloat16":
+        "7935b4b7d7516d4c1009b06e9e75e5c29919fd7bbac2296d3daf04df274e4ce7",
+}
+
+
+@pytest.mark.parametrize("dtype", sorted(OTHER_DRAWN_AT_THE_PARENT))
+def test_the_older_kinds_draw_what_they_drew_at_the_parent(tiny_root, dtype):
+    from benchmarks.harness.cell import Cell
+
+    cell = Cell.find("tiny.other-batch", root=tiny_root)
+    spec = cell.reference().param_spec(cell.config)
+    assert {kind for _, kind in spec.values()} == {"normal", "resid", "norm"}
+    p = weights.make_params(spec, cell.config["num_layers"], dtype, 0)
+    assert _digest(p) == OTHER_DRAWN_AT_THE_PARENT[dtype]

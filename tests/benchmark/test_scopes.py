@@ -5,6 +5,7 @@ the reader's arithmetic on hand-made events and on a cut of a chip trace; the
 new metrics under the contract's rules. Nothing here is a device time of this
 machine."""
 
+import copy
 import functools
 import json
 import re
@@ -531,53 +532,98 @@ def test_lings_cell_is_split_by_part_and_reads_its_four():
         assert not theirs & set(REASON128)
 
 
-SERVING = UNPINNED + [LING, "mixtral-8x7b.serve-batch"]
+# What the next PR brings: a serving cell that is none of today's. In memory
+# it borrows an accepted cell's configuration and another's traffic, so that
+# ``Cell.find`` finds files for it.
+SIXTH = "in-memory.serve-chat-256"
+# in a case below: every serving cell of the file that is not one of the
+# three, in the file's order (Ling's, Mixtral's, and what later PRs added)
+FURTHER = "<every further serving cell of the file>"
 
 
-@pytest.mark.parametrize("workloads, kept", [
-    (UNPINNED, True),                                  # as PR 38 had them
-    (UNPINNED + [LING], True),                         # as this PR has them
-    (UNPINNED + [LING, "mixtral-8x7b.serve-batch"], True),   # a later PR's
-    (UNPINNED[:2] + [LING], False),                    # one of the three lost
-    (UNPINNED[1:] + [LING], False),
-    ([UNPINNED[1], UNPINNED[0], UNPINNED[2], LING], False),  # reordered
-    ([LING] + UNPINNED, False),                        # in front of them
-    (UNPINNED + [LING, LING], False),                  # twice
-    (UNPINNED + ["mistral-7b.train-8k"], False),       # no serving cell
-    (UNPINNED + ["no-such.cell"], False),
+def with_a_sixth_serving_cell(bm: dict, listed: bool = True) -> dict:
+    """``bm`` grown as a ``model_config`` PR grows it: one more cell behind
+    the last and, if ``listed``, its name behind the last name of
+    ``serve_tokens_per_s``'s list."""
+    grown = copy.deepcopy(bm)
+    cells = {w["name"]: w for w in grown["workloads"]}
+    assert SIXTH not in cells
+    grown["workloads"].append({
+        "name": SIXTH, "config": cells[LING]["config"],
+        "traffic": cells[UNPINNED[0]]["traffic"], "chips": 1, "why": "test"})
+    if listed:
+        next(m for m in grown["end_to_end"]
+             if m["name"] == "serve_tokens_per_s")["workloads"].append(SIXTH)
+    return grown
+
+
+@pytest.mark.parametrize("workloads, kept, sixth", [
+    (UNPINNED, True, None),                            # as PR 38 had them
+    (UNPINNED + [LING], True, None),                   # as PR 43 had them
+    (UNPINNED + [FURTHER], True, None),                # a later PR's
+    (UNPINNED[:2] + [LING], False, None),              # one of the three lost
+    (UNPINNED[1:] + [LING], False, None),
+    ([UNPINNED[1], UNPINNED[0], UNPINNED[2], LING], False, None),  # reordered
+    ([LING] + UNPINNED, False, None),                  # in front of them
+    (UNPINNED + [LING, LING], False, None),            # twice
+    (UNPINNED + ["mistral-7b.train-8k"], False, None),  # no serving cell
+    (UNPINNED + ["no-such.cell"], False, None),
+    # what the next PR does: a list that ends in a serving cell that is none
+    # of the file's today; the same name where ``serve_tokens_per_s`` does
+    # not list it, and where no cell has it, is no serving cell
+    (UNPINNED + [FURTHER, SIXTH], True, "listed"),
+    (UNPINNED + [SIXTH], True, "listed"),
+    (UNPINNED + [FURTHER, SIXTH], False, "unlisted"),
+    (UNPINNED + [FURTHER, SIXTH], False, None),
 ])
 def test_a_by_part_list_grows_by_serving_cells_and_loses_none(
-        workloads, kept):
-    assert set(SERVING) == set(serving_cells(load_benchmark()))
+        workloads, kept, sixth):
+    """The serving cells are READ from the file, whatever a later PR added
+    to them (PR 43 wrote today's five down here, and any sixth failed every
+    case)."""
+    bm = load_benchmark()
+    today = serving_cells(bm)
+    assert set(UNPINNED + [LING]) <= set(today)
+    further = [c for c in today if c not in UNPINNED]
+    workloads = [c for w in workloads
+                 for c in (further if w == FURTHER else [w])]
+    serving = today if sixth is None else serving_cells(
+        with_a_sixth_serving_cell(bm, listed=sixth == "listed"))
+    assert (SIXTH in serving) == (sixth == "listed")
     if kept:
-        assert_a_by_part_list_is_kept(workloads, SERVING)
+        assert_a_by_part_list_is_kept(workloads, serving)
         return
     with pytest.raises(AssertionError):
-        assert_a_by_part_list_is_kept(workloads, SERVING)
+        assert_a_by_part_list_is_kept(workloads, serving)
 
 
 def test_a_fifth_entry_behind_the_four_is_taken():
-    """What the next PR does: one more per-layer entry behind the last one,
-    and its cell behind the last name of a by-part list. Every accepted
-    entry still keeps to the contract; with an entry put in front of an
-    accepted one, or an accepted one moved, none does."""
-    import copy
-
-    fifth = {"name": "ssm_scan_roofline.chat256", "unit": "%",
+    """What the next PR does: a sixth serving cell, its name behind the last
+    name of every by-part list, and one more per-layer entry behind the last
+    one that lists THAT cell. Every accepted entry still keeps to the
+    contract; with an entry put in front of an accepted one, or an accepted
+    one moved, none does."""
+    # (a name no PR will bring: the one PR 43 rehearsed here,
+    # ``ssm_scan_roofline.chat256``, is the next PR's own, and this test
+    # would have found it twice in that PR's list)
+    fifth = {"name": "in_memory_roofline.chat256", "unit": "%",
              "better": "higher", "source": "device_trace", "layer": "kernels",
-             "moves": "serve_tokens_per_s", "workloads": [LING]}
+             "moves": "serve_tokens_per_s", "workloads": [SIXTH]}
     bm = load_benchmark()
-    grown = copy.deepcopy(bm)
+    grown = with_a_sixth_serving_cell(bm)
     grown["per_layer"].append(fifth)
     for m in grown["per_layer"]:
         if m["name"] in BY_PART:
-            m["workloads"].append("mixtral-8x7b.serve-batch")
+            m["workloads"].append(SIXTH)
     for name in ACCEPTED:
         assert_a_new_metric_keeps_to_the_contract(grown, name)
+    sixth = Cell.find(SIXTH, benchmark=grown)
+    assert [m["name"] for m in sixth.per_layer] == BY_PART + [fifth["name"]]
     last = FIRST + len(ACCEPTED) - 1
     for bad in (lambda p: p.insert(last, fifth),
                 lambda p: p.insert(FIRST, p.pop(last))):
-        moved = copy.deepcopy(bm)
+        moved = copy.deepcopy(grown)
+        moved["per_layer"].pop()
         bad(moved["per_layer"])
         for name in ACCEPTED:
             with pytest.raises(AssertionError):
