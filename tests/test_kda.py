@@ -134,19 +134,33 @@ def test_the_bounded_gate_stays_inside_its_bound():
     assert float(g.min()) < -4.99 and float(g.max()) > -0.01
 
 
-@pytest.mark.parametrize("gscale", [-0.01, -5.0])
-@pytest.mark.parametrize("N", [8, 4])
-def test_the_decode_kernel_is_a_step_of_the_recurrence(gscale, N):
-    """``kda_decode`` under the interpreter: live slots advance as
-    ``kda_step`` (and so as the recurrence) says, at a traced layer; a dead
-    slot's row, the other layer and nothing else moves. Eight heads are one
-    block of heads, four a block of their own."""
-    from orion_tpu.ops.pallas.kda import kda_decode
+LIVE_DEAD_LIVE = (True, False, True)
 
-    B, H = 3, 128
+
+@pytest.mark.parametrize("gscale, N, H, active", [
+    (-0.01, 8, 128, LIVE_DEAD_LIVE), (-5.0, 8, 128, LIVE_DEAD_LIVE),
+    (-0.01, 4, 128, LIVE_DEAD_LIVE), (-5.0, 4, 128, LIVE_DEAD_LIVE),
+    # a slot's 32 heads in one grid step, four turns of the loop of eight
+    (-0.01, 32, 16, LIVE_DEAD_LIVE), (-5.0, 32, 16, LIVE_DEAD_LIVE),
+    # heads that are no multiple of a tile of eight: one unrolled group
+    (-0.01, 12, 16, LIVE_DEAD_LIVE),
+    # more heads than the buffers hold at 128 x 128: two blocks of 20
+    (-5.0, 40, 128, (True, False)),
+    # a run of dead slots between live ones, and dead ones at both ends
+    (-0.01, 16, 16, (False, True, False, False, False, True, True, False)),
+])
+def test_the_decode_kernel_is_a_step_of_the_recurrence(gscale, N, H, active):
+    """``kda_decode`` under the interpreter: live slots advance as
+    ``kda_step`` (and so as the recurrence) says, at a traced layer; the
+    dead slots' rows and the other layer are bitwise what they were.
+    ``head_block`` takes all the heads of a slot that fit its buffers."""
+    from orion_tpu.ops.pallas.kda import head_block, kda_decode
+
+    assert head_block(N, H, H) == {40: 20}.get(N, N)
+    B = len(active)
     q, k, v, g, b = (x[:, 0] for x in _draw(4, B, 1, N, H, H, gscale))
     state = jax.random.normal(jax.random.key(9), (2, B + 1, N, H, H))
-    active = jnp.asarray([True, False, True])
+    active = jnp.asarray(active)
     want_o, want_s = kda.kda_step(state[1, 1:], q, k, v, g, b, active)
     got_o, got_s = jax.jit(lambda st, l: kda_decode(
         st, q, k, v, g, b, layer=l, active=active, interpret=True))(
@@ -154,11 +168,12 @@ def test_the_decode_kernel_is_a_step_of_the_recurrence(gscale, N):
     assert _rel(got_o[active], want_o[active]) < TOL
     assert _rel(got_s[1, 1:], want_s) < TOL
     assert bool((got_s[0] == state[0]).all())
-    assert bool((got_s[1, 2] == state[1, 2]).all())      # the dead slot
+    assert bool((got_s[1, 1:][~active] == state[1, 1:][~active]).all())
     # and the step is the recurrence's (the rows are value-major)
     s, o = kda._step(jnp.swapaxes(state[1, 1:], -1, -2), q, k, v, g, b)
     assert _rel(want_o, o) < TOL
-    assert _rel(want_s[0], jnp.swapaxes(s, -1, -2)[0]) < TOL
+    live = int(jnp.argmax(active))
+    assert _rel(want_s[live], jnp.swapaxes(s, -1, -2)[live]) < TOL
 
 
 def test_prefill_then_steps_is_the_recurrence_over_the_whole():
