@@ -29,6 +29,16 @@ Three forms, all float32 inside:
     ``exp(-G_j)`` of a whole cumulative sum is ever formed: at the gate's
     bound of -5 a step a chunk's sum reaches -320 and the usual ratio trick
     overflows float32.
+    The triangular inverse is block elimination in whole ``[CHUNK, CHUNK]``
+    products under a mask (PR 48: ten products of 64 rows a chunk and head
+    where doubling on 16 x 16 blocks and forward substitution issued
+    thirty-two of 16), and what a chunk needs beside the state is made for
+    ``SEGMENT`` positions at a time: the form is bound by the traffic of its
+    intermediates, and at 256 (128-256 chunk-heads a segment) a segment
+    inside the outer scan costs what a block of one segment costs, where at
+    2048 it cost 1.7 times that (PERF.md section 6, PR 48). Four child
+    scopes of ``kda/chunk`` (``scores``, ``inverse``, ``apply``, ``carry``)
+    split its device time.
 ``kda_step``       one new position a slot (decode): the XLA form of
     ``ops/pallas/kda.kda_decode``.
 
@@ -45,7 +55,7 @@ import jax.numpy as jnp
 
 CHUNK = 64
 SUB = 16
-SEGMENT = 2048     # positions of a block whose chunks are prepared together
+SEGMENT = 256      # positions of a block whose chunks are prepared together
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -182,42 +192,31 @@ def _pair_scores(rows, keys, G, sub: int):
     return full.reshape(*lead, C, C)
 
 
-def _unit_lower_inverse(A, sub: int):
-    """(I + A)^-1 for A [..., C, C] strictly lower triangular: the diagonal
-    sub-blocks by the product (I + N)(I + N^2)(I + N^4) ... of N = -A_II
-    (N^sub = 0), then block forward substitution over the C / sub row
-    blocks. Matmuls in full float32. Both steps are loops of ONE body (two
-    matmuls each), not unrolled: at ``highest`` precision a matmul is six
-    passes, and thirteen of them a copy of this function were most of a
-    prefill program's code."""
-    *lead, C, _ = A.shape
-    n = C // sub
-    eye = jnp.eye(sub, dtype=A.dtype)
+def _unit_lower_inverse(A):
+    """(I + A)^-1 for A [..., C, C] strictly lower triangular, C a power of
+    two, by block elimination from the diagonal up: with the inverses of
+    the diagonal blocks of s positions in X (s = 1: the identity), those of
+    2s are ``X - X A_s X``, A_s the lower-left quarter of every block of 2s
+    ([[T1, 0], [-T2 A21 T1, T2]]). Whole [C, C] products under a mask, in
+    full float32, no slice and no power of A (nothing cancels that forward
+    substitution would not cancel): five levels of two products of 64 rows
+    at C = 64. A loop of ONE body (two matmuls), not
+    unrolled: at ``highest`` precision a matmul is six passes, and thirteen
+    of them a copy of this function were most of a prefill program's
+    code."""
+    C = A.shape[-1]
+    i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
     mm = lambda x, y: jnp.matmul(x, y, precision=_HI)
-    blocks = A.reshape(*lead, n, sub, n, sub)
-    N = -jnp.einsum("...isjt,ij->...ist", blocks, jnp.eye(n, dtype=A.dtype))
 
-    def double(_, TP):
-        T, P = TP
-        P = mm(P, P)
-        return mm(T, eye + P), P
+    def quarter(l):
+        return jnp.where((i >> (l + 1) == j >> (l + 1)) & ((i >> l) & 1 == 1)
+                         & ((j >> l) & 1 == 0), A, 0.0)
 
-    # T [..., n, sub, sub]: the diagonal blocks' inverses.
-    T, _ = jax.lax.fori_loop(
-        0, max(sub.bit_length() - 2, 0), double, (eye + N, N))
+    def couple(l, X):
+        return X - mm(X, mm(quarter(l), X))
 
-    def row_block(i, X):
-        # Row block I of the inverse: T_II (E_I - A[I, <I] inverse[<I]);
-        # the rows of X at and behind I are still zero, so A's whole row
-        # block multiplies it.
-        at = (0,) * len(lead)
-        a = jax.lax.dynamic_slice(A, (*at, i * sub, 0), (*lead, sub, C))
-        e = (jnp.arange(C)[None, :] == i * sub + jnp.arange(sub)[:, None]
-             ).astype(A.dtype) - mm(a, X)
-        t = jax.lax.dynamic_index_in_dim(T, i, axis=len(lead), keepdims=False)
-        return jax.lax.dynamic_update_slice(X, mm(t, e), (*at, i * sub, 0))
-
-    return jax.lax.fori_loop(0, n, row_block, jnp.zeros_like(A))
+    return jax.lax.fori_loop(1, C.bit_length() - 1, couple,
+                             jnp.eye(C, dtype=A.dtype) - quarter(0))
 
 
 def kda_chunked(q, k, v, g, b, state: Optional[jax.Array] = None,
@@ -229,7 +228,9 @@ def kda_chunked(q, k, v, g, b, state: Optional[jax.Array] = None,
     behind each row's last real position. What a chunk needs beside the
     state (the pair scores, the inverse) is made for about ``segment``
     positions of the block at a time, an outer scan over the sequence, so
-    that a block of 8192 positions keeps a quarter of it alive and not all."""
+    that a block of 8192 positions keeps a part of it alive and not all.
+    The four child scopes of ``kda/chunk`` are its tracing
+    (``tools/kda_prefill_sweep.py`` times each alone)."""
     f32 = jnp.float32
     q, k, v, g, b = (x.astype(f32) for x in (q, k, v, g, b))
     B, S, H, dk = q.shape
@@ -255,17 +256,20 @@ def kda_chunked(q, k, v, g, b, state: Optional[jax.Array] = None,
     def one_segment(state, xs):
         qc, kc, vc, gc, bc = xs                            # [m, B, H, C, ..]
         G = jnp.cumsum(gc, axis=-2)
-        bj = bc[..., None, :]                              # over columns j
-        A = _pair_scores(kc, kc, G, sub)
-        A = A * bj * jnp.tril(jnp.ones((chunk, chunk), f32), -1)
-        Bm = _pair_scores(qc, kc, G, sub) * bj
-        T = _unit_lower_inverse(A, sub)
-        eG = jnp.exp(G)
-        tv = jnp.matmul(T, vc, precision=_HI)              # [.., C, dv]
-        tk = jnp.matmul(T, kc * eG, precision=_HI)         # [.., C, dk]
-        GC = G[..., -1:, :]                                # [.., 1, dk]
-        kd = kc * jnp.exp(GC - G) * bc[..., None]          # [.., C, dk]
-        decay = jnp.exp(GC)                                # [.., 1, dk]
+        with jax.named_scope("kda/chunk/scores"):
+            bj = bc[..., None, :]                          # over columns j
+            A = _pair_scores(kc, kc, G, sub)
+            A = A * bj * jnp.tril(jnp.ones((chunk, chunk), f32), -1)
+            Bm = _pair_scores(qc, kc, G, sub) * bj
+        with jax.named_scope("kda/chunk/inverse"):
+            T = _unit_lower_inverse(A)
+        with jax.named_scope("kda/chunk/apply"):
+            eG = jnp.exp(G)
+            tv = jnp.matmul(T, vc, precision=_HI)          # [.., C, dv]
+            tk = jnp.matmul(T, kc * eG, precision=_HI)     # [.., C, dk]
+            GC = G[..., -1:, :]                            # [.., 1, dk]
+            kd = kc * jnp.exp(GC - G) * bc[..., None]      # [.., C, dk]
+            decay = jnp.exp(GC)                            # [.., 1, dk]
 
         def step(st, ys):
             # ``st`` is the state VALUE-major, S^T [B, H, dv, dk], as the
@@ -281,7 +285,8 @@ def kda_chunked(q, k, v, g, b, state: Optional[jax.Array] = None,
                 "bhcv,bhck->bhvk", u, kd_, precision=_HI)
             return st, o
 
-        return jax.lax.scan(step, state, (tv, tk, qc * eG, Bm, kd, decay))
+        with jax.named_scope("kda/chunk/carry"):
+            return jax.lax.scan(step, state, (tv, tk, qc * eG, Bm, kd, decay))
 
     with jax.named_scope("kda/chunk"):
         state, o = jax.lax.scan(

@@ -31,8 +31,11 @@ the scratch row 0 of the layer, which the pipeline fetches once for a run of
 them, and nothing is computed. Inference-only; no VJP.
 
 The prefill's chunked form is ``ops.kda.kda_chunked`` in XLA under the scope
-``kda/chunk`` on every backend: the chunk's products are the MXU's either
-way, and a Pallas kernel of it is left to a later PR (PERF.md section 7).
+``kda/chunk`` on every backend (since PR 48 in segments of 256 positions,
+the triangular inverse by block elimination in whole 64 x 64 products). A
+Pallas kernel of it was tried in PR 45 and refused: with the XLA form gone
+from a prefill program of two or more rows the chip halts in a fusion beside
+the call (ROADMAP S1 (g), PERF.md section 7).
 """
 
 from __future__ import annotations

@@ -83,21 +83,71 @@ def test_the_delta_rule_erases_before_it_writes():
     assert np.allclose(np.asarray(o[0, 1, 0]), [5.0, 7.0])
 
 
-def test_pair_scores_and_the_inverse_against_their_definitions():
+@pytest.mark.parametrize("sub", [4, 16])
+def test_pair_scores_and_the_inverse_against_their_definitions(sub):
     rng = np.random.default_rng(0)
     C, dk = 64, 8
     rows, keys = (jnp.asarray(rng.normal(size=(C, dk)), jnp.float32)
                   for _ in range(2))
     G = jnp.cumsum(-jnp.asarray(rng.uniform(0, 5, (C, dk)), jnp.float32), 0)
-    got = kda._pair_scores(rows, keys, G, 16)
+    got = kda._pair_scores(rows, keys, G, sub)
     d = np.asarray(G, np.float64)[:, None, :] - np.asarray(G, np.float64)[None]
     want = np.tril((np.asarray(rows, np.float64)[:, None, :]
                     * np.asarray(keys, np.float64)[None, :, :]
                     * np.exp(np.minimum(d, 0))).sum(-1))
     assert np.abs(np.asarray(got) - want).max() < 1e-5
-    A = jnp.tril(jnp.asarray(rng.normal(size=(C, C)) * 0.3, jnp.float32), -1)
-    T = kda._unit_lower_inverse(A, 16)
-    assert np.abs(np.asarray(T @ (jnp.eye(C) + A)) - np.eye(C)).max() < 1e-4
+    n = 4 * sub                      # the inverse at 16 and at 64 positions
+    A = jnp.tril(jnp.asarray(rng.normal(size=(n, n)) * 0.3, jnp.float32), -1)
+    T = kda._unit_lower_inverse(A)
+    assert np.abs(np.asarray(T @ (jnp.eye(n) + A)) - np.eye(n)).max() < 1e-4
+
+
+@pytest.mark.parametrize("gscale", [-0.01, -5.0])
+@pytest.mark.parametrize("B, S, lens", [
+    (1, 100, [61]), (2, 130, [130, 65]), (8, 70, [70, 1, 64, 65, 3, 33, 69, 2])])
+def test_the_chunked_form_from_a_state_by_rows(gscale, B, S, lens):
+    """Rows 1, 2 and 8 (a segment of 256 positions holds 4, 2 and 1 chunks
+    a row), lengths short of the block, from a carried state: the
+    outputs at real positions and each row's state behind its last one."""
+    q, k, v, g, b = _draw(11, B, S, 2, 16, 8, gscale)
+    state = jax.random.normal(jax.random.key(12), (B, 2, 16, 8))
+    lens = jnp.asarray(lens)
+    want_o, want_s = kda.kda_recurrent(q, k, v, g, b, state, lens)
+    got_o, got_s = jax.jit(kda.kda_chunked)(q, k, v, g, b, state, lens)
+    live = (jnp.arange(S)[None, :] < lens[:, None])[..., None, None]
+    assert _rel(jnp.where(live, got_o, 0), jnp.where(live, want_o, 0)) < TOL
+    assert _rel(got_s, want_s) < TOL
+
+
+def test_the_inverse_issues_no_product_with_a_narrow_side():
+    """The regrouping held by COUNT: at one row of 2048 positions, 32 heads
+    of 128 x 128, the lowered program holds 10 ``dot_general``s, every one
+    float32 at ``highest``, and 2 of them have a matrix side of 16 or less
+    (a row block's pair scores, for ``A`` and for ``B``); before PR 48 seven
+    of thirteen had one: four more in the inverse's two loops, and a 4 x 4
+    product at default precision that picked the diagonal blocks. Shapes
+    from the lowered text; nothing is compiled or timed."""
+    import re
+
+    f = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    x = f(1, 2048, 32, 128)
+    text = jax.jit(kda.kda_chunked).lower(x, x, x, x, f(1, 2048, 32)).as_text()
+    sides = []
+    for line in text.splitlines():
+        if "stablehlo.dot_general" not in line:
+            continue
+        batch = re.search(r"batching_dims = \[([\d, ]*)\] x \[([\d, ]*)\]", line)
+        shapes = re.findall(r"tensor<([\dx]+)xf32>", line.split(" : ")[-1])
+        assert "precision = [HIGHEST, HIGHEST]" in line
+        for dims, skip in zip(shapes[:2], batch.groups()):
+            skip = {int(i) for i in skip.split(",") if i.strip()}
+            # a block of one row leaves ``jnp.matmul`` a dimension of 1: no
+            # side of a matrix
+            sides.append([int(n) for i, n in enumerate(dims.split("x"))
+                          if i not in skip and int(n) > 1])
+    pairs = list(zip(sides[::2], sides[1::2]))
+    assert len(pairs) == 10
+    assert sum(min(l + r) <= 16 for l, r in pairs) == 2
 
 
 @pytest.mark.parametrize("lens", [[1, 2], [3, 4], [9, 12]])
