@@ -28,8 +28,7 @@ __all__ = [
 ]
 
 # Entry scripts + packages the sweep covers (repo-relative).
-DEFAULT_TARGETS = ("orion_tpu", "tools", "train.py", "generate.py",
-                   "bench.py")
+DEFAULT_TARGETS = ("orion_tpu", "tools", "train.py", "generate.py")
 
 _ALLOW_RE = re.compile(
     r"#\s*orion:\s*allow\[([a-z0-9_,\s-]+)\]\s*(.*)"

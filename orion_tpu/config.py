@@ -295,13 +295,6 @@ class ModelConfig:
     # nearly doubles the decode roofline. Training rejects the flag.
     weight_quant: Optional[str] = None
 
-    # Flash-attention tile sizes (pallas only). None => auto: large tiles
-    # (up to 1024) amortize the online-softmax bookkeeping on the MXU; the
-    # v5e microbench (bench_r3 notes) puts 1024x1024 at ~2.3x the xla
-    # attention fwd+bwd throughput while 128x128 is ~2x slower than xla.
-    attn_block_q: Optional[int] = None
-    attn_block_kv: Optional[int] = None
-
     # Sequence/context parallelism for attention. When sequence_axis names a
     # mesh axis of size > 1 (the trainer sets this from ParallelConfig.sp),
     # attention runs as ring attention or Ulysses over that axis.
@@ -939,9 +932,6 @@ class TrainConfig:
     # the process so a supervisor restart resumes from the checkpoint — a
     # hung collective is unrecoverable in-process).
     watchdog_action: str = "log"
-    # Device peak bf16 FLOP/s for MFU; None => metrics.DEVICE_PEAKS by the
-    # mesh's exact device_kind (not measured on CPU).
-    peak_flops_per_device: Optional[float] = None
     metrics_jsonl: Optional[str] = None
     # Held-out evaluation: every eval_interval optimizer steps, average the
     # loss over eval_batches fixed batches from the eval stream (see
@@ -1075,17 +1065,6 @@ class InferenceConfig:
     # window). Larger windows amortize host round-trips at the cost of
     # decoding past EOS by up to W-1 tokens.
     decode_window: int = 8
-    # Auto-tune the window from the engine's measured device/host timing
-    # split: whenever the rolling host share of a step exceeds
-    # decode_host_share_target, the window doubles (up to
-    # decode_window_max). Growth-only: the wasted-decode cost of a large
-    # window is bounded and observable (timing['wasted_steps']), while a
-    # host-bound engine wastes wall-clock every single step. Page
-    # provisioning and the submit() pool check are sized against
-    # decode_window_max so growth never strands an admitted request.
-    decode_window_autotune: bool = False
-    decode_window_max: int = 64
-    decode_host_share_target: float = 0.25
     # KV-cache quantization: None (pool in model dtype) or "int8" (pool in
     # int8 with per-token per-kv-head f32 scales stored alongside;
     # dequantization happens inside the paged kernel / at the xla gather).
@@ -2406,7 +2385,8 @@ def _p_tiny_ling() -> Config:
 
 @register_preset("llama-1b-bench")
 def _p_llama_bench() -> Config:
-    """Llama-shaped ~1B model sized for one 16 GB v5e chip (bench.py).
+    """Llama-shaped ~1B model sized for one 16 GB v5e chip (the shape of
+    six ``tools/*_bench.py`` instruments).
 
     pallas kernels, remat=full, batch 8 x seq 2048: the f32 master params
     and grads plus bf16 moments take ~12.5 GB, so larger batches and

@@ -420,9 +420,7 @@ class InferenceEngine:
             self._chunk = fold_chunk(self.mcfg.max_seq_len)
         self.fold_lens = np.zeros(self.max_batch, np.int64)
         if self._chunk is not None:
-            widest = max(self.icfg.decode_window, self.icfg.decode_window_max
-                         if self.icfg.decode_window_autotune else 0)
-            if self._chunk % self.psz or widest > self.psz:
+            if self._chunk % self.psz or self.icfg.decode_window > self.psz:
                 raise ValueError(
                     f"the fold chunk of {self._chunk} positions "
                     f"(ops/retention.fold_chunk of model.max_seq_len) must "
@@ -443,18 +441,9 @@ class InferenceEngine:
             0 if self.page_window is not None
             else sum(self._layers_by_window.values())
             - self._layers_by_window[None])
-        # Decode window: mutable engine state (inference.decode_window is
-        # only the starting point when auto-tune is on). Page provisioning
-        # and admission always budget for _provision_window, so growth can
-        # never strand an already-admitted request.
+        # Decode window: the configured value for the engine's life. Page
+        # provisioning and admission budget for _provision_window.
         self.decode_window = self.icfg.decode_window
-        if self.icfg.decode_window_autotune and (
-            self.icfg.decode_window_max < self.icfg.decode_window
-        ):
-            raise ValueError(
-                f"decode_window_max={self.icfg.decode_window_max} < "
-                f"decode_window={self.icfg.decode_window}"
-            )
         # Lazy chunk provisioning (the over-pool admission path): only
         # meaningful with a sliding window — a full-attention chunk reads
         # its WHOLE history from the pool, so its device working set is
@@ -584,8 +573,6 @@ class InferenceEngine:
         self._spec = None
         self._tree = False          # token-tree drafting (spec_tree_width>1)
         self.spec_stats = SpecDecodeStats()
-        self._spec_step = False     # this step ran verify, not decode
-        self._autotune_skip = False  # first step after a window resize
         # Grammar-constrained decoding (inference.constrained; ISSUE 16):
         # constrained slots decode through the VERIFY path — FSM forced
         # runs are free drafts and per-position legal masks are
@@ -810,13 +797,12 @@ class InferenceEngine:
 
     # The fixed set of ``orion/<phase>`` spans and the reset_timing() keys
     # each one's SELF time feeds (obs.PhaseClock). The first key is the
-    # phase's own leaf; the rest are the sums the router's ITL proxy, the
-    # window autotune and the benchmark read (host_s, prefill_s, device_s,
-    # decode_device_s), which are therefore sums of leaves by
-    # construction, never separate measurements. A phase that raises
-    # books nothing: a failed dispatch's time stays with its parent and
-    # ends in host_s. The fallback phases mark an XLA retry inside
-    # ``<path>/run`` and book nothing of their own.
+    # phase's own leaf; the rest are the sums the router's ITL proxy and
+    # the benchmark read (host_s, prefill_s, device_s, decode_device_s),
+    # which are therefore sums of leaves by construction, never separate
+    # measurements. A phase that raises books nothing: a failed dispatch's
+    # time stays with its parent and ends in host_s. The fallback phases
+    # mark an XLA retry inside ``<path>/run`` and book nothing of their own.
     _PHASE_KEYS = {
         "step": ("step_self_s", "host_s"),
         "reap": ("reap_s", "host_s"),
@@ -1320,16 +1306,12 @@ class InferenceEngine:
                 # (Watchdog's first-completed-step contract): the first
                 # step's unbounded jit compile must not trip a false stall.
                 self._watchdog.heartbeat()
-            tune = self.icfg.decode_window_autotune
-            waited0 = self._autotune_waited() if tune else 0.0
-            self._spec_step = False
             with self._phase("reap"):
                 self._reap_expired()
                 # Reap expired/cancelled slots BEFORE admission so their
                 # pages are already donated/free when this step's
                 # admission pass budgets.
                 self._reap()
-            mixed = False
             try:
                 if self.waiting:
                     with self._phase("admit"):
@@ -1380,29 +1362,9 @@ class InferenceEngine:
                     )
                     raise
                 decoded = False
-            total = time.monotonic() - span.t0
             self.timing["steps"] += 1
             if decoded:
                 self.timing["windows"] += 1
-                # While chunked prefill is in flight the decode window is
-                # clamped to 1 (the mixed step); autotune only reads clean
-                # decode-window timings, so mixed steps never resize it.
-                # Speculative verify steps are held out the same way:
-                # their dispatch is the static verify shape, not the
-                # [W, B] decode window, so their split says nothing about
-                # the window.
-                if tune and not mixed and not self._spec_step:
-                    if self._autotune_skip:
-                        # First decode-window step at a freshly-resized
-                        # [W, B] shape: its spans carry the retrace/
-                        # recompile cost, not steady-state timing —
-                        # excluded from the tuner (see _autotune_window).
-                        self._autotune_skip = False
-                    else:
-                        self._autotune_window(
-                            total,
-                            total - (self._autotune_waited() - waited0),
-                        )
             if self.mcfg.debug_asserts:
                 from orion_tpu.runtime.asserts import raise_if_failed
 
@@ -1418,7 +1380,10 @@ class InferenceEngine:
                     # the process carries on, deadline expiry handles the
                     # SLO consequences at the next boundary.
                     self.robust.stalled_steps += 1
-                    self._flight_dump("watchdog_stall", step_wall_s=total)
+                    self._flight_dump(
+                        "watchdog_stall",
+                        step_wall_s=time.monotonic() - span.t0,
+                    )
                 self._watchdog.heartbeat()
             if span.tags is not None:
                 # Request-lifecycle instants, swept at the step boundary
@@ -1601,8 +1566,8 @@ class InferenceEngine:
         kv_dead_window_page_layers sizing counters,
         windows/steps counters, the slot_steps/wasted_steps
         decode-waste tally, the mixed_steps/prefill_chunks/chunk_tokens/
-        chunk_pad_tokens chunked-prefill tally, the CURRENT decode_window
-        (after any autotune growth/shrink — a snapshot, not zeroed), with
+        chunk_pad_tokens chunked-prefill tally, the decode_window (a
+        snapshot, not zeroed), with
         inference.prefix_cache the prefix-cache counters
         (prefix_hits/misses/hit_rate, cached_tokens, inserted/evicted/cow
         pages), and with inference.speculative the speculation counters
@@ -1655,57 +1620,6 @@ class InferenceEngine:
             except OSError as e:
                 log.error("metrics export failed: %s", e)
         return out
-
-    def _autotune_waited(self) -> float:
-        """Running total of the time steps spent waiting on dispatches
-        and tier copies — what _autotune_window's host share excludes."""
-        t = self.timing
-        return (
-            t["decode_device_s"] + t["prefill_s"] + t["spill_s"]
-            + t["restore_s"] + t["page_in_s"]
-        )
-
-    def _autotune_window(self, step_total: float, host: float) -> None:
-        """Resize the decode window from the step's measured device/host
-        split (see InferenceConfig.decode_window_autotune): double while
-        the per-step host share exceeds the target; halve when it falls
-        below a quarter of the target (hysteresis band [target/4, target]
-        is stable), so a load drop is not stuck with a doubled window's
-        ITL forever. Floors at the configured inference.decode_window,
-        caps at decode_window_max. Uses the step's own measured split, so
-        one outlier pass (e.g. a compile) moves the window at most one
-        notch.
-
-        Every resize changes the [W, B] decode shape and forces a full
-        retrace/recompile of the fused decode program on the NEXT decode
-        dispatch; that compile lands inside that step's device span and
-        would distort the very split this tuner reads, so step() excludes
-        the first post-resize decode-window step from tuning
-        (_autotune_skip) — the recompile cost is paid once per resize
-        either way, but it can no longer cascade into a second, spurious
-        resize."""
-        denom = step_total if step_total > 0 else 1.0
-        target = self.icfg.decode_host_share_target
-        if (
-            host / denom > target
-            and self.decode_window * 2 <= self.icfg.decode_window_max
-        ):
-            self.decode_window *= 2
-            self._autotune_skip = True
-            log.info(
-                "decode_window autotune: host share %.2f > %.2f, window -> %d",
-                host / denom, target, self.decode_window,
-            )
-        elif (
-            host / denom < target / 4
-            and self.decode_window // 2 >= self.icfg.decode_window
-        ):
-            self.decode_window //= 2
-            self._autotune_skip = True
-            log.info(
-                "decode_window autotune: host share %.2f < %.2f, window -> %d",
-                host / denom, target / 4, self.decode_window,
-            )
 
     def clear_prefix_cache(self) -> int:
         """Drop every cached prefix (idle cached pages return to the free
@@ -2260,17 +2174,12 @@ class InferenceEngine:
 
     @property
     def _provision_window(self) -> int:
-        """The decode window the pool must budget for: with auto-tune on,
-        the cap the window may grow to — admission/submit checks against
-        this, so growth never strands an admitted request. With
-        speculation on, also at least speculate_tokens+1: a verify step
-        writes draft KV that far past the cursor, and its page
-        provisioning must never preempt a request admission promised to
-        hold."""
-        base = (
-            self.icfg.decode_window_max
-            if self.icfg.decode_window_autotune else self.decode_window
-        )
+        """The decode window the pool must budget for (admission/submit
+        check against this). With speculation on, at least
+        speculate_tokens+1: a verify step writes draft KV that far past
+        the cursor, and its page provisioning must never preempt a request
+        admission promised to hold."""
+        base = self.decode_window
         if self.icfg.speculative:
             base = max(base, self.icfg.speculate_tokens + 1)
         return base
@@ -3828,7 +3737,6 @@ class InferenceEngine:
             # window instead (it re-provisions to the decode window).
             # Constrained slots are exempt: even draftless they must
             # decode through the masked verify program (lens-1 rows).
-            self._spec_step = False
             return self._decode_window_all()
         if self._tree:
             tokens, lens, depths, parents, words = (
@@ -4137,7 +4045,6 @@ class InferenceEngine:
                 drafts = self._propose_drafts(live)
             window = self._decode_build_window() if drafts is None else None
         if drafts is not None:
-            self._spec_step = True
             return self._verify_all(drafts)
         return self._decode_run_window(window)
 
@@ -4534,7 +4441,6 @@ class InferenceEngine:
             # Speculative mixed step: verify rows replace the 1-token
             # decode rows (runner.mixed_verify_step); prompt-phase slots
             # are plain chunk rows, exactly as without speculation.
-            self._spec_step = True
             if self._tree:
                 vtok, vlens, vdepths, vparents, vwords = (
                     self._build_verify_tree_rows(dec, drafts)

@@ -277,7 +277,6 @@ def _dense_layer(
                 q_segment_ids=seg, kv_segment_ids=kv_seg, seg_pad_zero=True,
                 **kv_at,
                 logit_softcap=cfg.attn_logit_softcap, window=win,
-                block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
                 impl=cfg.kernels, mesh=mesh,
             )
         return out, lambda: _scatter_pages(cc, k, v, l * NP + ctx["pages"])
@@ -1168,8 +1167,7 @@ def _latent_prefill_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
         with jax.named_scope("kernel"):
             out = latent_attention(
                 q, row, wkv_b, cfg, q_segment_ids=seg, kv_segment_ids=seg,
-                seg_pad_zero=True, block_q=cfg.attn_block_q,
-                block_kv=cfg.attn_block_kv, impl=cfg.kernels, mesh=mesh)
+                seg_pad_zero=True, impl=cfg.kernels, mesh=mesh)
 
         def written():
             Nb, S, w = row.shape

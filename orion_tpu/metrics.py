@@ -31,9 +31,9 @@ class DevicePeaks:
 
 _CLOUD_TPU_DOCS = "Google Cloud TPU documentation, system architecture"
 
-# The ONE peaks table (bench.py reads it too), keyed by the exact
-# ``device_kind`` string JAX reports. A TPU kind that is not here is an
-# error, never a default: add it with its source.
+# The ONE peaks table, keyed by the exact ``device_kind`` string JAX
+# reports. A TPU kind that is not here is an error, never a default: add
+# it with its source.
 DEVICE_PEAKS: dict[str, DevicePeaks] = {
     "TPU v4": DevicePeaks(275e12, 1200e9, _CLOUD_TPU_DOCS + " (TPU v4)"),
     "TPU v5 lite": DevicePeaks(197e12, 819e9, _CLOUD_TPU_DOCS + " (TPU v5e)"),
@@ -494,18 +494,15 @@ class MetricsLogger:
         flops_per_token: float,
         num_devices: int,
         device: jax.Device,
-        peak_flops: Optional[float] = None,
         jsonl_path: Optional[str] = None,
         log_interval: int = 10,
     ):
         """``device`` is one of the mesh's devices (they are homogeneous):
-        its kind picks the MFU peak unless ``peak_flops`` overrides it."""
+        its kind picks the MFU peak (``device_peaks``)."""
         self.flops_per_token = flops_per_token
         self.num_devices = max(num_devices, 1)
-        if not peak_flops:
-            peaks = device_peaks(device)
-            peak_flops = peaks.bf16_flops if peaks is not None else None
-        self.peak_flops = peak_flops
+        peaks = device_peaks(device)
+        self.peak_flops = peaks.bf16_flops if peaks is not None else None
         self.jsonl_path = jsonl_path
         self.log_interval = max(log_interval, 1)
         self.history: list[StepMetrics] = []

@@ -771,7 +771,6 @@ def _train_attend(
             return latent_attention(
                 q, row, wkv_b, cfg, q_segment_ids=segment_ids,
                 kv_segment_ids=segment_ids, seg_pad_zero=True,
-                block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv,
                 impl=cfg.kernels, mesh=mesh), None
 
         return expanded
@@ -816,8 +815,6 @@ def _train_attend(
                 kv_segment_ids=segment_ids,
                 logit_softcap=cfg.attn_logit_softcap,
                 window=window,
-                block_q=cfg.attn_block_q,
-                block_kv=cfg.attn_block_kv,
                 impl=cfg.kernels,
                 debug_asserts=cfg.debug_asserts,
             ), None
@@ -838,8 +835,6 @@ def _train_attend(
             seg_pad_zero=True,
             logit_softcap=cfg.attn_logit_softcap,
             window=window,
-            block_q=cfg.attn_block_q,
-            block_kv=cfg.attn_block_kv,
             impl=cfg.kernels,
             mesh=mesh,
         ), None

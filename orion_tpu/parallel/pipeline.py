@@ -107,7 +107,7 @@ def _make_hop(npp: int, axis: str, wrap: bool = False):
 
 
 def validate_row_state(row_state: Any, batch: int, num_microbatches: int):
-    """Normalize per-row state for microbatch slicing (ADVICE r5).
+    """Normalize per-row state for microbatch slicing.
 
     The non-pp block_fn accepts row-state leaves with a broadcast [1, ...]
     leading dim; pipelining slices leaves to [M, B/M, ...], so lift the

@@ -93,8 +93,7 @@ def raise_if_failed() -> None:
     last call. Call sites: Trainer.train_step / InferenceEngine.step (the
     per-step host sync points). Drains the record either way — the swap
     happens atomically under the callback lock, so a failure appended by
-    the async callback thread between snapshot and clear can't be lost
-    (ADVICE r5)."""
+    the async callback thread between snapshot and clear can't be lost."""
     with _failures_lock:
         if not _failures:
             return

@@ -858,7 +858,6 @@ class Trainer:
             flops_per_token=cfg.model.flops_per_token(cfg.data.seq_len),
             num_devices=self.mesh.size,
             device=self.mesh.devices.flat[0],
-            peak_flops=cfg.train.peak_flops_per_device,
             jsonl_path=cfg.train.metrics_jsonl,
             log_interval=cfg.train.log_interval,
         )

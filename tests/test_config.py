@@ -50,9 +50,21 @@ def test_overrides_optional_and_tuple_types():
     assert cfg.parallel.dcn_axes == ("dp",)
 
 
-def test_override_unknown_key_raises():
-    with pytest.raises(ValueError, match="unknown config key"):
-        apply_overrides(Config(), ["model.not_a_field=1"])
+# A name that never was a field, and the six PR 49 removed (nothing set
+# them): a stale script must not silently carry a dead option.
+@pytest.mark.parametrize("key", [
+    "model.not_a_field",
+    "model.attn_block_q",
+    "model.attn_block_kv",
+    "train.peak_flops_per_device",
+    "inference.decode_window_autotune",
+    "inference.decode_window_max",
+    "inference.decode_host_share_target",
+])
+def test_override_unknown_key_raises(key):
+    name = key.split(".")[1]
+    with pytest.raises(ValueError, match=f"unknown config key '{name}'"):
+        apply_overrides(Config(), [f"{key}=1"])
 
 
 def test_parallel_num_devices():

@@ -303,7 +303,7 @@ def test_packed_composes_with_sequence_parallelism(method):
 
 
 def test_pack_rows_drop_counter_observable():
-    """The bounded token loss at carry-group resets (ADVICE r4) is tallied
+    """The bounded token loss at carry-group resets is tallied
     in loader.pack_stats so it can be monitored at scale."""
     import numpy as np
 

@@ -261,8 +261,8 @@ INFER = [
     "inference.max_new_tokens=8",
     "inference.decode_window=1",
 ]
-# Cyclic prompt -> looping greedy continuation on the seed-0 tiny model,
-# so the n-gram proposer drafts (same workload as test_spec_decode).
+# The n-gram proposer drafts on MIX[1] from its 12th token on (as in
+# test_spec_decode): see SPEC_TOKENS below.
 REP = [7, 8, 9, 7, 8, 9, 7, 8, 9, 7, 8]
 MIX = [REP, [5, 3, 9, 250, 17], [7, 7, 7]]
 SPEC = ["inference.speculative=true", "inference.speculate_tokens=4"]
@@ -694,23 +694,58 @@ def test_drain_finishes_preempted_requests(tiny):
     eng.assert_page_accounting()
 
 
+# The spec-fault tests need VERIFY dispatches to fault. Within the module's
+# 8 tokens nothing of MIX repeats itself on the seed-0 tiny model (the
+# cyclic prompt's stream does not loop), so the proposer never drafts and
+# no verify runs; MIX[1]'s stream repeats from its 12th token on.
+SPEC_TOKENS = 24
+
+
+def _spec_dry_run(params, extra=()):
+    """(fault-free greedy reference of MIX at SPEC_TOKENS, the number of
+    verify steps the speculative engine ran on it with no fault planted).
+    Speculation must not change the stream, and must verify at all."""
+    ref = _engine(params, extra).generate(MIX, SPEC_TOKENS)
+    dry = _engine(params, SPEC + list(extra))
+    assert dry.generate(MIX, SPEC_TOKENS) == ref
+    return ref, dry.reset_timing()["verify_steps"]
+
+
+def _verify_faults():
+    """A fault on every step's verify dispatch, by path name: whichever
+    steps verify, each attempt faults until speculation is off."""
+    return FaultInjector([
+        FaultSpec("dispatch", step=s, path="verify")
+        for s in range(4 * SPEC_TOKENS)
+    ])
+
+
 def test_spec_fault_auto_disable(tiny):
     """Degradation ladder rung 2: repeated verify-path dispatch faults
     auto-disable speculation (SpecDecodeStats.disabled_reason, carried
-    across reset_timing) and decoding continues exactly on the plain
-    window."""
-    params, ref = tiny
-    sref = _engine(params, SPEC).generate(MIX, 8)
-    assert sref == ref              # spec greedy equivalence (upstream)
-    inj = FaultInjector(
-        [FaultSpec("dispatch", step=s, path="verify") for s in range(16)]
-    )
-    eng = _engine(params, SPEC + ["inference.spec_fault_limit=2"], inj=inj)
-    assert eng.generate(MIX, 8) == ref
-    assert eng._spec_disabled
+    across reset_timing) after exactly spec_fault_limit of them, and
+    decoding continues exactly on the plain window."""
+    params, _ = tiny
+    limit = 2
+    ref, n_verify = _spec_dry_run(params)
+    assert n_verify > limit, "the workload must verify more than it faults"
+    inj = _verify_faults()
+    eng = _engine(params, SPEC + [f"inference.spec_fault_limit={limit}"],
+                  inj=inj)
+    rids = [eng.submit(p, SPEC_TOKENS) for p in MIX]
+    out = {}
+    while eng.has_work():
+        # Off after the limit-th verify fault, not before.
+        assert eng._spec_disabled == (len(inj.fired) >= limit)
+        for r in eng.step():
+            out[r.rid] = r.generated
+    assert [out[i] for i in rids] == ref
+    assert eng._spec_disabled and eng._spec_faults == limit
     t = eng.reset_timing()
-    assert "auto-disabled" in t["spec_disabled_reason"]
-    assert len(inj.fired) == 2      # disabled: no third verify attempted
+    assert f"auto-disabled after {limit} verify" in t["spec_disabled_reason"]
+    # disabled: no further verify attempted, none ever ran
+    assert [f[2] for f in inj.fired] == ["verify"] * limit
+    assert t["verify_steps"] == 0 and t["failed_steps"] == limit, t
     # the reason survives the drain (engine-lifetime state)
     assert "auto-disabled" in eng.reset_timing()["spec_disabled_reason"]
     eng.assert_page_accounting()
@@ -721,20 +756,22 @@ def test_spec_fault_disable_counts_primary_faults_under_fallback(tiny):
     when every episode is absorbed by a successful XLA fallback —
     otherwise a persistently broken verify kernel pays a doomed primary
     attempt + fallback forever and spec_fault_limit is a dead knob."""
-    params, ref = tiny
-    pall = SPEC + FALLBACK + [
-        "model.kernels=pallas_interpret", "inference.spec_fault_limit=1",
-    ]
-    inj = FaultInjector(
-        [FaultSpec("dispatch", step=s, path="verify") for s in range(16)]
+    params, _ = tiny
+    pall = FALLBACK + ["model.kernels=pallas_interpret"]
+    ref, n_verify = _spec_dry_run(params, pall)
+    assert n_verify > 1, "the workload must verify more than it faults"
+    inj = _verify_faults()
+    eng = _engine(
+        params, SPEC + pall + ["inference.spec_fault_limit=1"], inj=inj
     )
-    eng = _engine(params, pall, inj=inj)
-    assert eng.generate(MIX, 8) == ref
-    assert eng._spec_disabled
+    assert eng.generate(MIX, SPEC_TOKENS) == ref
+    assert eng._spec_disabled and eng._spec_faults == 1
     t = eng.reset_timing()
-    assert "auto-disabled" in t["spec_disabled_reason"]
-    assert t["failed_steps"] == 0       # every fault was absorbed
+    assert "auto-disabled after 1 verify" in t["spec_disabled_reason"]
+    assert len(inj.fired) == 1 and inj.fired[0][2] == "verify"
+    assert t["failed_steps"] == 0       # the fault was absorbed ...
     assert t["dispatch_fallbacks"] == 1
+    assert t["verify_steps"] == 1, t    # ... by the fallback's verify
     eng.assert_page_accounting()
 
 

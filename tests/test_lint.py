@@ -209,6 +209,13 @@ def test_allow_inside_string_literal_is_inert():
 # ---------------------------------------------------------------------------
 
 
+def test_default_targets_exist():
+    """A sweep target that was deleted or renamed fails here:
+    ``iter_target_files`` passes a missing one by in silence."""
+    missing = [t for t in lint.DEFAULT_TARGETS if not (ROOT / t).exists()]
+    assert missing == []
+
+
 def test_repo_sweeps_clean():
     """The acceptance pin: zero unsuppressed findings across orion_tpu/,
     tools/, and the entry scripts — every violation the first full sweep

@@ -26,8 +26,7 @@ BASE = [
     "inference.decode_window=2",
 ]
 # What reset_timing() returned before the leaves existed: every key keeps
-# its name (the benchmark's readers, the router's ITL proxy and the window
-# autotune read them).
+# its name (the benchmark's readers and the router's ITL proxy read them).
 OLD_KEYS = {
     "device_s", "host_s", "prefill_s", "decode_device_s", "mixed_device_s",
     "windows", "steps", "slot_steps", "wasted_steps", "decode_slot_steps",

@@ -1,4 +1,4 @@
-"""Pipeline row-state validation (ADVICE r5) — fast, execution-free
+"""Pipeline row-state validation — fast, execution-free
 checks that stay in tier-1 while the pipeline-execution tests (slow tier)
 carry the schedule equivalence."""
 
@@ -13,7 +13,7 @@ from tests.conftest import make_mesh
 def test_pipeline_row_state_broadcast_lifted():
     """A [1, S] broadcast row-state leaf (explicitly supported by the
     non-pp block_fn) is lifted to [B, S] before microbatch slicing instead
-    of dying in an opaque reshape (ADVICE r5)."""
+    of dying in an opaque reshape."""
     from orion_tpu.parallel.pipeline import validate_row_state
 
     rs = validate_row_state(
@@ -32,7 +32,7 @@ def test_pipeline_row_state_broadcast_lifted():
 def test_pipeline_row_state_bad_leading_dim_raises(cpu_devices):
     """A row-state leaf whose leading dim is neither B nor 1 must raise a
     descriptive ValueError up front, from the real pipeline entry point
-    (ADVICE r5: it previously surfaced as an opaque reshape error)."""
+    (it previously surfaced as an opaque reshape error)."""
     from orion_tpu.parallel.pipeline import pipeline_forward
 
     mesh = make_mesh(cpu_devices, pp=2, dp=4)

@@ -404,7 +404,7 @@ def test_debug_asserts_catch_true_router_corruption():
 
 
 def test_checkpoint_stream_format_stamp(tmp_path, caplog):
-    """Checkpoints record the data-stream format (ADVICE r4) — since
+    """Checkpoints record the data-stream format — since
     ISSUE 8 in the manifest itself (the sidecar stamp remains for
     fleet-wide warnings): matching formats restore silently; a mismatched
     manifest warns that resume replays a different token order."""

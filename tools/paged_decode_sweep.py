@@ -1,18 +1,14 @@
 """Time the paged decode kernel alone on the chip at the two serving cells'
-shapes: the walk it had before PR 29 (one page a grid step, kept here for the
-comparison and nowhere else) against the block walk of
-``orion_tpu/ops/pallas/paged_attention.py`` over pages-per-step ``nb``. The
-module's ``BLOCK_PAGES`` comes from this script's table
-(PERF.md section 6, PR 29).
+shapes: the block walk of ``orion_tpu/ops/pallas/paged_attention.py`` as the
+engine calls it (``tree``) and over pages-per-step ``nb`` (``new_nb*``). The
+module's ``BLOCK_PAGES`` comes from this script's table (PERF.md section 6,
+PR 29, where the walk it replaced, one page a grid step, was last timed).
 
-    chiprun -- python tools/paged_decode_sweep.py [--only old,tree,new]
+    chiprun -- python tools/paged_decode_sweep.py [--only tree,new]
 
 Shapes: B 32, K 8, H 128, page 64; G 4 / P 40 (Mixtral), G 6 / P 76 (Laguna's
 full layers), G 9 / P 76 / window 512 (its window layers). Contexts: drawn as
-the cells' traffic mixes draw them, every page live, and 64 tokens. The old
-walk also runs with bf16 operands and without the fused write, which ranks its
-three costs: dead grid steps (all live against the mix), f32 operands, and the
-write-back of every visited page.
+the cells' traffic mixes draw them, every page live, and 64 tokens.
 
 Prints one JSON line per (shape, contexts, implementation) and keeps them in
 ``chiprun_out/paged_decode_sweep.jsonl``. Raises without a TPU."""
@@ -32,8 +28,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax import lax  # noqa: E402
-from jax.experimental import pallas as pl  # noqa: E402
-from jax.experimental.pallas import tpu as pltpu  # noqa: E402
 
 from orion_tpu.ops.pallas import paged_attention as pa  # noqa: E402
 
@@ -46,115 +40,6 @@ SHAPES = [                      # (tag, G, P, window, traffic mix)
     ("laguna-full", 6, 76, None, "serve-batch-4k"),
     ("laguna-window", 9, 76, 512, "serve-batch-4k"),
 ]
-NEG_INF = -1e30
-
-
-def _old_kernel(psz, G8, fused, window, odt, pt_ref, base_ref, sl_ref, *refs):
-    """The (batch, page) walk of PR 28 and before, int8 left out; ``odt`` is
-    the operand dtype of its two matmuls (it had float32)."""
-    q_ref, k_ref, v_ref = refs[:3]
-    if fused:
-        kn_ref, vn_ref, o_ref, ko_ref, vo_ref = refs[3:8]
-    else:
-        o_ref = refs[3]
-    m_s, l_s, acc_s = refs[-3:]
-    b, ip = pl.program_id(0), pl.program_id(1)
-    last_pos = sl_ref[b]
-
-    @pl.when(ip == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
-
-    k_src, v_src = k_ref, v_ref
-    if fused:
-        row = lax.broadcasted_iota(jnp.int32, (K, psz, 1), 1)
-        sel = (ip >= last_pos // psz) & (row == last_pos % psz)
-        ko_ref[0] = jnp.where(sel, kn_ref[0][:, None, :], k_ref[0])
-        vo_ref[0] = jnp.where(sel, vn_ref[0][:, None, :], v_ref[0])
-        k_src, v_src = ko_ref, vo_ref
-
-    run = ip * psz <= last_pos
-    if window is not None:
-        run &= ip * psz + psz - 1 >= last_pos - window + 1
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0].reshape(K, G8, H).astype(jnp.float32) * (H ** -0.5)
-        z = lax.dot_general(
-            q.astype(odt), k_src[0].astype(odt),
-            (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32,
-        ).reshape(K * G8, psz)
-        kv_pos = ip * psz + lax.broadcasted_iota(jnp.int32, z.shape, 1)
-        mask = kv_pos <= last_pos
-        if window is not None:
-            mask &= kv_pos >= last_pos - window + 1
-        z = jnp.where(mask, z, NEG_INF)
-        m_prev = m_s[:, :1]
-        m_new = jnp.maximum(m_prev, z.max(axis=-1, keepdims=True))
-        p = jnp.exp(z - m_new) * mask.astype(jnp.float32)
-        alpha = jnp.exp(m_prev - m_new)
-        l_s[:] = jnp.broadcast_to(
-            l_s[:, :1] * alpha + p.sum(axis=-1, keepdims=True), l_s.shape)
-        pv = lax.dot_general(
-            p.reshape(K, G8, psz).astype(odt), v_src[0].astype(odt),
-            (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32)
-        acc_s[:] = acc_s[:] * alpha + pv.reshape(K * G8, H)
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-
-    @pl.when(ip == pl.num_programs(1) - 1)
-    def _finish():
-        l = l_s[:, :1]
-        o_ref[0] = (acc_s[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
-
-
-def old_walk(q, k_pool, v_pool, page_table, last_pos, k_new, v_new, *,
-             window, odt):
-    """PR 28's ``paged_attention`` (clamped index maps and all); the fused
-    write is on when ``k_new`` is given. Returns (out, k_pool, v_pool)."""
-    N = q.shape[1]
-    P, psz = page_table.shape[1], k_pool.shape[2]
-    G = N // K
-    G8 = max(-(-G // 8) * 8, 8)
-    fused = k_new is not None
-    qg = jnp.pad(q.reshape(B, K, G, H), ((0, 0), (0, 0), (0, G8 - G), (0, 0)))
-    qg = qg.reshape(B, K * G8, H)
-
-    def kv_index(b, ip, pt, bs, sl):
-        valid = jnp.minimum(ip, sl[b] // psz)
-        if window is not None:
-            first = jnp.maximum(sl[b] - window + 1, 0) // psz
-            valid = jnp.maximum(valid, jnp.minimum(first, sl[b] // psz))
-        return (bs[0] + pt[b, valid], 0, 0, 0)
-
-    q_spec = pl.BlockSpec((1, K * G8, H), lambda b, ip, *_: (b, 0, 0))
-    kv_spec = pl.BlockSpec((1, K, psz, H), kv_index)
-    in_specs, args = [q_spec, kv_spec, kv_spec], [qg, k_pool, v_pool]
-    out_specs = [q_spec]
-    out_shape = [jax.ShapeDtypeStruct(qg.shape, q.dtype)]
-    if fused:
-        new_spec = pl.BlockSpec((1, K, H), lambda b, ip, *_: (b, 0, 0))
-        in_specs += [new_spec, new_spec]
-        args += [k_new, v_new]
-        out_specs += [kv_spec, kv_spec]
-        out_shape += [jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)] * 2
-    out = pl.pallas_call(
-        functools.partial(_old_kernel, psz, G8, fused, window, odt),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(B, P), in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=[
-                pltpu.VMEM((K * G8, 128), jnp.float32),
-                pltpu.VMEM((K * G8, 128), jnp.float32),
-                pltpu.VMEM((K * G8, H), jnp.float32),
-            ]),
-        out_shape=out_shape,
-        input_output_aliases={4: 1, 5: 2} if fused else {},
-        name="paged_decode_old",
-    )(page_table, jnp.zeros(1, jnp.int32), last_pos, *args)
-    attn = out[0].reshape(B, K, G8, H)[:, :, :G, :].reshape(B, N, H)
-    return (attn, *out[1:]) if fused else (attn, k_pool, v_pool)
 
 
 def tree_walk(q, k_pool, v_pool, page_table, last_pos, k_new, v_new, *,
@@ -240,10 +125,6 @@ def sweep(dev, sink):
             "one_page": np.full(B, 64, np.int32),
         }
         impls = {
-            "old_f32": functools.partial(old_walk, window=window,
-                                         odt=jnp.float32),
-            "old_bf16": functools.partial(old_walk, window=window,
-                                          odt=jnp.bfloat16),
             "tree": functools.partial(tree_walk, window=window),
             **{f"new_nb{nb}": functools.partial(new_walk, window=window,
                                                 nb=nb)
@@ -258,19 +139,16 @@ def sweep(dev, sink):
             tokens = int((ctx - first).sum())
             pages = int(((ctx - 1) // PSZ - first // PSZ + 1).sum())
             for name, step in impls.items():
-                variants = [(name, step, (k_new, v_new))]
-                if name.startswith("old"):
-                    variants.append((name + "_nowrite", step, (None, None)))
-                for vname, fn, new in variants:
-                    try:
-                        sec, k_pool, v_pool = timed(
-                            fn, q, k_pool, v_pool, page_table, last_pos, *new)
-                    except Exception as e:   # a shape Mosaic refuses
-                        emit(sink, {"shape": tag, "contexts": cname,
-                                    "impl": vname,
-                                    "error": str(e).splitlines()[0][:200]})
-                        continue
-                    line(sink, dev, tag, cname, vname, sec, tokens, pages, 2)
+                try:
+                    sec, k_pool, v_pool = timed(
+                        step, q, k_pool, v_pool, page_table, last_pos,
+                        k_new, v_new)
+                except Exception as e:   # a shape Mosaic refuses
+                    emit(sink, {"shape": tag, "contexts": cname,
+                                "impl": name,
+                                "error": str(e).splitlines()[0][:200]})
+                    continue
+                line(sink, dev, tag, cname, name, sec, tokens, pages, 2)
         if window is None and hasattr(pa, "BLOCK_PAGES"):
             int8_lines(sink, dev, tag, rng, q, k_new, v_new, page_table,
                        rows, P)

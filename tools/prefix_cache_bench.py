@@ -24,8 +24,9 @@ a verdict asserting warm < host < recompute strictly at every pool size
 on ``ttft_ms`` — the admit-step COMPUTE span, prefill_s + restore_s,
 TTFT's compute term (the wall-clock ``admit_ms`` rides along but is
 scheduler noise at smoke shapes, where the phases differ by ~1 ms); the
-exit code is nonzero on any inversion, so the tier-1 wiring
-(tests/test_host_tier.py) fails when the tier stops paying.
+exit code is nonzero on any inversion. That order is the chip's to show:
+the tier-1 wiring (tests/test_host_tier.py) judges each phase's counts
+(tokens prefilled, tokens cached, pages restored), not the CPU's clocks.
 
     python tools/prefix_cache_bench.py                    # on-chip
     python tools/prefix_cache_bench.py --smoke            # CPU check
@@ -153,6 +154,7 @@ def capacity_sweep(smoke: bool) -> int:
                     (t["prefill_s"] + t["restore_s"]) * 1e3, 2),
                 "admit_ms": round(admit_ms, 2),
                 "prefill_ms": round(t["prefill_s"] * 1e3, 2),
+                "prefill_tokens": int(t.get("prefill_tokens", 0)),
                 "prefix_hits": int(t.get("prefix_hits", 0)),
                 "cached_tokens": int(t.get("cached_tokens", 0)),
                 "host_hits": int(t.get("host_hits", 0)),
