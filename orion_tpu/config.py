@@ -69,6 +69,11 @@ class LayerKind(typing.NamedTuple):
     # paged tail), "latent" (one compressed row a position in pages), "kda"
     # (a state row and a convolution's tail a slot, no page).
     attention: str = "softmax"
+    # K/V heads of a softmax layer where the model's kinds differ in them
+    # (None = model.n_kv_heads), and whether the layer's softmax carries a
+    # learned sink logit a query head (``attn.sink`` [n_heads]).
+    n_kv_heads: Optional[int] = None
+    sink: bool = False
 
 
 class LayerPlan(typing.NamedTuple):
@@ -177,6 +182,18 @@ class ModelConfig:
     # rope_theta (rope_sliding: None => rope_full's).
     rope_full: Optional[RopeConfig] = None
     rope_sliding: Optional[RopeConfig] = None
+    # K/V heads of a WINDOW layer where they differ from a full layer's
+    # n_kv_heads (with layer_types). Such a model's cache has two kinds of
+    # leaves (infer/kv_cache.ring_leaves): pages for the full layers, which
+    # the allocator counts, and a ring of ``ring_pages`` pages a slot for
+    # the window layers, which keep only what their window reads.
+    n_kv_heads_sliding: Optional[int] = None
+    # The values are multiplied by it (attention_value_scale).
+    value_scale: float = 1.0
+    # "sliding": a window layer's softmax has one more term in its
+    # denominator, exp(attn.sink[head]), a learned logit a query head whose
+    # column is dropped: a row's weights add up to less than 1.
+    attn_sink: Optional[str] = None
     # "per-head": each head's attention output is multiplied by a sigmoid
     # gate computed from the layer's normed input (attn.wg [D, heads]).
     attn_gate: Optional[str] = None
@@ -394,6 +411,9 @@ class ModelConfig:
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"model.router_score={self.router_score!r}; softmax|sigmoid")
+        if self.attn_sink not in (None, "sliding"):
+            raise ValueError(
+                f"model.attn_sink={self.attn_sink!r}; sliding|None")
         if self.scan_group is None or self.scan_group < 1:
             raise ValueError(f"model.scan_group={self.scan_group} must be >= 1")
         if self.scan_unroll is None or self.scan_unroll < 1:
@@ -441,10 +461,20 @@ class ModelConfig:
         """The layers that keep pages: a cache's paged leaves are [these
         layers x pages, ...]. All of them, but for a model with KDA layers,
         whose latent layers alone have any."""
+        if self.has_window_ring:
+            return sum(self.cache_kind(k) == "softmax"
+                       for k in self.layer_kinds)
         return self.n_layers_of("latent") if self.has_kda else self.n_layers
 
     def n_layers_of(self, attention: str) -> int:
         return sum(k.attention == attention for k in self.layer_kinds)
+
+    def cache_kind(self, kind: LayerKind) -> str:
+        """Which leaves of the cache a layer's rows are in: its attention,
+        but "ring" for a window layer of a ``has_window_ring`` model."""
+        if self.has_window_ring and kind.window is not None:
+            return "ring"
+        return kind.attention
 
     def cache_layer(self, l, j: int):
         """Layer ``l``'s index among the layers of ITS attention kind: the
@@ -455,16 +485,41 @@ class ModelConfig:
         ``l``."""
         plan = self.layer_plan
         kinds = self.layer_kinds
-        if plan is None or len({k.attention for k in kinds}) == 1:
+        if plan is None or len({self.cache_kind(k) for k in kinds}) == 1:
             return l
-        att = kinds[j].attention
-        same = [k.attention == att for k in kinds]
+        att = self.cache_kind(kinds[j])
+        same = [self.cache_kind(k) == att for k in kinds]
         first = plan.start(plan.lead)
         if j < first:                   # in a lead run: l - j layers into it
             return sum(same[:j]) + (l - j)
         a_period = sum(same[first:first + plan.period_layers])
         return (sum(same[:j]) + (l - j) // plan.period_layers * a_period
                 + (l - j) % plan.period_layers)
+
+    @property
+    def has_window_ring(self) -> bool:
+        """Window layers keep their K and V in a ring a slot, apart from the
+        full layers' pages: the model whose two kinds of layer differ in
+        their K/V heads (one pool cannot hold both shapes)."""
+        return self.n_kv_heads_sliding is not None
+
+    @property
+    def ring_window(self) -> Optional[int]:
+        """The positions a window layer of a ``has_window_ring`` model reads
+        and keeps (``page_window`` is the POOL's, the full layers')."""
+        return self.sliding_window if self.has_window_ring else None
+
+    @property
+    def resolved_v_head_dim(self) -> int:
+        """A softmax layer's value head width (a latent layer's
+        ``v_head_dim`` is read where latent layers are built)."""
+        if self.v_head_dim is None or self.has_latent:
+            return self.resolved_head_dim
+        return self.v_head_dim
+
+    def kv_heads_of(self, kind: Optional[LayerKind]) -> int:
+        return (self.n_kv_heads if kind is None or kind.n_kv_heads is None
+                else kind.n_kv_heads)
 
     @property
     def latent_head_dim(self) -> int:
@@ -565,6 +620,8 @@ class ModelConfig:
                 rope=sliding if windowed else full,
                 moe=self.is_moe and l >= self.n_dense_layers,
                 attention=self.layer_attention(l),
+                n_kv_heads=(self.n_kv_heads_sliding if windowed else None),
+                sink=windowed and self.attn_sink == "sliding",
             ))
         return tuple(kinds)
 
@@ -582,7 +639,10 @@ class ModelConfig:
     def page_window(self) -> Optional[int]:
         """The window the page allocator may free behind: the sliding
         window where EVERY layer is windowed, else None (one full layer
-        reads the whole history, and pages are shared by all layers)."""
+        reads the whole history, and pages are shared by all layers). For a
+        ``has_window_ring`` model the pages are the full layers' alone, so
+        None says what is true of them: every page is read for as long as
+        its request lives; what a window layer keeps is ``ring_window``."""
         windows = {k.window for k in self.layer_kinds}
         return self.sliding_window if windows == {self.sliding_window} else None
 
@@ -2202,6 +2262,86 @@ def _p_tiny_laguna() -> Config:
         inference=InferenceConfig(max_seq_len=128, page_size=8,
                                   num_pages=128, max_batch_size=4,
                                   prefill_chunk=16),
+    )
+
+
+# MiMo-V2.5's published per-layer lists (config.json): 1 = window layer /
+# sparse feed-forward.
+MIMO_HYBRID_LAYER_PATTERN = (0, 1, 1, 1, 1) + (0, 1, 1, 1, 1, 1) * 7 + (0,)
+MIMO_MOE_LAYER_FREQ = (0,) + (1,) * 47
+
+
+def _mimo_model(**kw) -> ModelConfig:
+    """MiMo-V2.5's language model (XiaomiMiMo, config.json, model_type
+    mimo_v2): 48 layers, the first dense; 9 full layers (4 K/V heads, theta
+    1e7) among 39 window layers of 128 positions (8 K/V heads, theta 1e4, a
+    learned sink a query head); 64 query heads, keys 192 wide of which the
+    first 64 are rotated, values 128 wide and scaled by 0.707; 256 experts
+    2048 wide, top-8 on sigmoid scores under a selection bias, gates
+    renormalised, no shared expert. The vision and audio towers and the
+    multi-token-prediction layers are not part of it."""
+    rot = 0.334
+    base = dict(
+        name="mimo-v2.5", vocab_size=152_576, max_seq_len=18_432,
+        d_model=4096, n_layers=48, n_heads=64, n_kv_heads=4, head_dim=192,
+        v_head_dim=128, n_kv_heads_sliding=8, value_scale=0.707,
+        attn_sink="sliding",
+        d_ff=16_384, pos_embedding="rope", rope_theta=10_000_000.0,
+        norm="rmsnorm", norm_eps=1e-5,
+        activation="swiglu", tie_embeddings=False, sliding_window=128,
+        layer_types=tuple(
+            "sliding_attention" if w else "full_attention"
+            for w in MIMO_HYBRID_LAYER_PATTERN),
+        rope_full=RopeConfig(theta=10_000_000.0, rotary_fraction=rot),
+        rope_sliding=RopeConfig(theta=10_000.0, rotary_fraction=rot),
+        n_experts=256, router_width=256, n_experts_per_token=8,
+        n_dense_layers=MIMO_MOE_LAYER_FREQ.index(1), moe_d_ff=2048,
+        router_score="sigmoid", router_bias=True,
+        # Dropless: an expert's capacity is at least the row length from
+        # router_width / top-k = 32 up (the published model drops none).
+        capacity_factor=32.0,
+        dtype="bfloat16", param_dtype="bfloat16", kernels="pallas",
+        remat="full",
+    )
+    base.update(kw)
+    return ModelConfig(**base)
+
+
+@register_preset("mimo-v2.5")
+def _p_mimo() -> Config:
+    """MiMo-V2.5's language model at its published sizes, for serving (a
+    deployment holds a share: model.n_experts / model.expert_offset, the
+    vocabulary and the depth its chips hold)."""
+    return Config(
+        model=_mimo_model(),
+        inference=InferenceConfig(max_seq_len=18_432, page_size=64),
+    )
+
+
+@register_preset("tiny-mimo")
+def _p_tiny_mimo() -> Config:
+    """Tiny MiMo-family model for CPU tests: a dense full lead layer and two
+    periods of (window, window, full); 8 query heads over 2 K/V heads in
+    full layers and 4 in window layers, keys 24 wide (8 rotated) and values
+    16; a window of 8 positions under pages of 8 (a ring of 2 pages a
+    slot), shorter than the test prompts; 16 experts top-4, sigmoid scores
+    under a bias, no shared expert."""
+    types = ("full_attention",) + (
+        "sliding_attention", "sliding_attention", "full_attention") * 2
+    return Config(
+        model=_mimo_model(
+            name="tiny-mimo", vocab_size=256, max_seq_len=128, d_model=64,
+            n_layers=7, n_heads=8, n_kv_heads=2, n_kv_heads_sliding=4,
+            head_dim=24, v_head_dim=16, d_ff=128, sliding_window=8,
+            layer_types=types,
+            n_experts=16, router_width=16, n_experts_per_token=4,
+            moe_d_ff=32, capacity_factor=4.0,
+            dtype="float32", param_dtype="float32", kernels="xla",
+            remat="none"),
+        data=DataConfig(batch_size=4, seq_len=64),
+        inference=InferenceConfig(max_seq_len=128, page_size=8,
+                                  num_pages=64, max_batch_size=4,
+                                  prefill_chunk=16, decode_window=4),
     )
 
 

@@ -35,6 +35,8 @@ import numpy as np
 from orion_tpu.config import Config
 from orion_tpu.infer.executor import DispatchExecutor
 from orion_tpu.infer.kv_cache import (
+    RING_K,
+    RING_V,
     HostPagePool,
     PageAllocator,
     copy_page,
@@ -201,6 +203,17 @@ class InferenceEngine:
             why.append(
                 "keeps a state row a request and no page in its KDA layers "
                 "(model.attention=kda)")
+            refused += kv_only
+        if self.mcfg.has_window_ring:
+            # Window layers keep a ring of their last positions a slot and
+            # nothing behind it: a cached prefix's window rows are gone (no
+            # prefix reuse, no host tier, no migration of pages), a prompt
+            # cannot resume mid-way from rows a later chunk overwrote, a
+            # rejected draft's write has already gone round, and the packed
+            # key rows have no int8 form.
+            why.append(
+                "keeps its window layers' K and V in a ring a request "
+                "(model.n_kv_heads_sliding)")
             refused += kv_only
         off = list(dict.fromkeys(name for name, on in refused if on))
         if off:
@@ -437,8 +450,10 @@ class InferenceEngine:
         self._layers_by_window = collections.Counter(
             k.window for k in self.mcfg.layer_kinds
             if k.attention == "softmax")
+        # (a model whose window layers keep a ring holds no pool page for
+        # them: none is dead.)
         self._window_layers = (
-            0 if self.page_window is not None
+            0 if self.page_window is not None or self.mcfg.has_window_ring
             else sum(self._layers_by_window.values())
             - self._layers_by_window[None])
         # Decode window: the configured value for the engine's life. Page
@@ -613,13 +628,15 @@ class InferenceEngine:
                 dtype_itemsize=jnp.dtype(self.mcfg.dtype).itemsize,
             )
         if (self.icfg.paged_prefill and not self.mcfg.has_latent
+                and not self.mcfg.has_window_ring
                 and resolve_impl(self.mcfg.kernels)[0]):
             # Same init-time VMEM gate for the paged-flash prefill
             # kernel: its blocks are page-sized (one page of queries x
             # the GQA group), so the failure mode is a too-large
             # page_size, named here instead of a Mosaic OOM mid-chunk.
-            # (A latent model prefills whole prompts: no row of it ever
-            # reaches that kernel.)
+            # (A latent model, and one whose window layers keep a ring,
+            # prefill whole prompts: no row of theirs ever reaches that
+            # kernel.)
             from orion_tpu.ops.pallas.paged_flash_prefill import (
                 check_prefill_fit,
             )
@@ -1516,6 +1533,27 @@ class InferenceEngine:
             # behind a window layer's window (a model that mixes window
             # and full layers keeps every page for every layer).
             "kv_live_page_layers": 0, "kv_dead_window_page_layers": 0,
+            # A model whose window layers keep a ring a slot beside the
+            # full layers' pages (model.n_kv_heads_sliding), at each decode
+            # window over the live slots: the positions the full layers
+            # hold for them and those positions' bytes there, and the bytes
+            # of the pages the pool holds for them (kv_full_page_bytes_held:
+            # whole pages, out to the end of the prompt's bucket and the
+            # window ahead); the positions a window layer still holds of
+            # them (a ring's reach at most) and their bytes over all window
+            # layers.
+            # decode_kv_pages_read by the leaves walked, and the times a
+            # slot's write came round to its ring's first page again; and
+            # decode_kv_token_layers by the same split (the two kinds differ
+            # in their K/V heads, so in bytes a position). Its prefill's
+            # prefill_attn_pairs: the (query, key) pairs each layer's own
+            # mask keeps of a real prompt, summed over the layers.
+            "kv_full_positions_live": 0, "kv_full_bytes_live": 0,
+            "kv_full_page_bytes_held": 0,
+            "kv_window_positions_held": 0, "kv_window_bytes_held": 0,
+            "decode_kv_pages_read_full": 0, "decode_kv_pages_read_ring": 0,
+            "decode_kv_token_layers_full": 0, "decode_kv_token_layers_ring": 0,
+            "window_ring_wraps": 0,
             # Per-phase device split (ISSUE 20 load-gauge satellite):
             # decode_device_s covers pure decode-phase dispatches
             # (decode windows, verify, draft compaction) and pairs with
@@ -3139,7 +3177,8 @@ class InferenceEngine:
             # A power-retention model: the state row each row of the burst
             # owns (slot + 1; padding rows take scratch row 0).
             state_rows = (
-                None if self._chunk is None and not self.mcfg.has_kda
+                None if self._chunk is None and not (
+                    self.mcfg.has_kda or self.mcfg.has_window_ring)
                 else np.where(
                     slots < self.max_batch, slots + 1, 0).astype(np.int32))
             for i, req in enumerate(reqs):
@@ -3221,6 +3260,18 @@ class InferenceEngine:
             self.timing["prefill_attn_pairs"] += (
                 self.mcfg.n_layers_of("latent") * sum(
                     int(n) * (int(n) + 1) // 2 for n in lengths[: len(reqs)]))
+        if self.mcfg.has_window_ring:
+            # A prompt's pages go round its ring too (the program writes
+            # the last of them alone).
+            rp = self.cache[RING_K].shape[2]
+            self.timing["window_ring_wraps"] += sum(
+                (int(n) - 1) // self.psz // rp for n in lengths[: len(reqs)])
+            for window, layers in self._layers_by_window.items():
+                for n in lengths[: len(reqs)]:
+                    n, w = int(n), int(n) if window is None else min(
+                        int(n), window)
+                    self.timing["prefill_attn_pairs"] += layers * (
+                        w * (w + 1) // 2 + (n - w) * w)
         if self.mcfg.has_kda:
             self.timing["prefill_kda_token_layers"] += (
                 self.mcfg.n_layers_of("kda") * real)
@@ -4167,9 +4218,18 @@ class InferenceEngine:
         for window, n in self._layers_by_window.items():
             read = steps if window is None else np.minimum(steps, window)
             self.timing["decode_kv_token_layers"] += n * int(read.sum())
+            if self.mcfg.has_window_ring:
+                self.timing["decode_kv_token_layers_" + (
+                    "full" if window is None else "ring")] += n * int(
+                        read.sum())
             first = 0 if window is None else np.maximum(steps - window + 1, 0)
-            self.timing["decode_kv_pages_read"] += n * int(
-                (steps // self.psz - first // self.psz + 1).sum())
+            pages = n * int((steps // self.psz - first // self.psz + 1).sum())
+            self.timing["decode_kv_pages_read"] += pages
+            if self.mcfg.has_window_ring:
+                self.timing["decode_kv_pages_read_" + (
+                    "full" if window is None else "ring")] += pages
+        if self.mcfg.has_window_ring:
+            self._count_split_cache(lens, steps)
         if self._window_layers:
             # A page is dead for a window layer when the query at position
             # len reads none of it: its last position is under len - window.
@@ -4179,6 +4239,32 @@ class InferenceEngine:
                 (-(-lens // self.psz)).sum())
             self.timing["kv_dead_window_page_layers"] += (
                 self._window_layers * int(dead.sum()))
+
+    def _count_split_cache(self, lens: np.ndarray, steps: np.ndarray) -> None:
+        """The kv_full_* / kv_window_* / window_ring_wraps counters of one
+        decode window over live slots of lengths ``lens`` (``steps``: each
+        token step's write position), from the leaves' own shapes."""
+        def page_bytes(k, v, layers):   # one page of K and V in ``layers``
+            return layers * sum(
+                math.prod(self.cache[n].shape[-3:])
+                * self.cache[n].dtype.itemsize for n in (k, v))
+
+        ring = self.cache[RING_K].shape
+        full = page_bytes("k", "v", self.mcfg.n_paged_layers)
+        pages = sum(p is not None for r in self.slots
+                    if self._decodes(r) for p in r.pages)
+        held = int(np.minimum(lens, ring[2] * self.psz).sum())
+        t = self.timing
+        t["kv_full_positions_live"] += int(lens.sum())
+        t["kv_full_bytes_live"] += int(lens.sum()) * full // self.psz
+        t["kv_full_page_bytes_held"] += pages * full
+        t["kv_window_positions_held"] += held
+        t["kv_window_bytes_held"] += held * page_bytes(
+            RING_K, RING_V, ring[0]) // self.psz
+        page = steps // self.psz
+        t["window_ring_wraps"] += int(
+            ((steps % self.psz == 0) & (page % ring[2] == 0)
+             & (page > 0)).sum())
 
     def _decode_run_window(self, window) -> bool:
         """Launch a built decode window, behind the step's prefill where

@@ -143,7 +143,9 @@ class DispatchExecutor:
             nothing (a warm-up) gets as placeholders of the same shapes and
             dtypes, so that it compiles the program the engine runs."""
             nb = tokens.shape[0]
-            if state_rows is None and (mcfg.is_retention or mcfg.has_kda):
+            if state_rows is None and (
+                    mcfg.is_retention or mcfg.has_kda
+                    or mcfg.has_window_ring):
                 state_rows = jnp.zeros((nb,), jnp.int32)    # the scratch row
             if slots is None:
                 # Out of range: the scatter of the picks drops every row.

@@ -129,6 +129,96 @@ def kda_leaves(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> "Cache":
     }
 
 
+RING_K, RING_V = "ring_k", "ring_v"
+
+
+def ring_pages(window: int, psz: int) -> int:
+    """Pages a slot's ring holds in a window layer: what the ``window``
+    positions up to any position span, ``ceil((window - 1) / page) + 1``
+    (the page that takes the new token, and the pages the window reaches
+    back over). A page comes round again only when every position it held
+    lies behind the window."""
+    return -(-(window - 1) // psz) + 1
+
+
+def _packed(H: int, Hv: int, K: int) -> int:
+    """The dims of a key that sit in the paired rows: keys ``H`` wide
+    beside values ``Hv`` wide are kept where a head's last ``Hv`` dims
+    fill one row and the ``H - Hv`` before them half a row."""
+    if 2 * (H - Hv) != Hv or K % 2:
+        raise ValueError(
+            f"keys {H} wide beside values {Hv} wide over {K} K/V heads: the "
+            f"packed key layout needs H - Hv = Hv / 2 and an even head count")
+    return H - Hv
+
+
+def pack_keys(k: jax.Array, Hv: int) -> jax.Array:
+    """Keys [..., K, H] as the rows a K pool of a model with values
+    narrower than keys holds a position, [..., K + K / 2, Hv]: rows 0..K-1
+    each head's LAST Hv dims, rows K.. the dims before them, heads 2i and
+    2i + 1 side by side in row K + i. Every row is Hv lanes (128 at the
+    published sizes: no padding, where a 192-wide minor dimension would be
+    laid out 256 wide)."""
+    *lead, K, H = k.shape
+    X = _packed(H, Hv, K)
+    return jnp.concatenate(
+        [k[..., X:], k[..., :X].reshape(*lead, K // 2, 2 * X)], axis=-2)
+
+
+def unpack_keys(rows: jax.Array, K: int) -> jax.Array:
+    """``pack_keys``' inverse: [..., K + K / 2, Hv] -> [..., K, H]."""
+    *lead, _, Hv = rows.shape
+    extra = rows[..., K:, :].reshape(*lead, K, Hv // 2)
+    return jnp.concatenate([extra, rows[..., :K, :]], axis=-1)
+
+
+def pack_queries(q: jax.Array, K: int, Hv: int) -> jax.Array:
+    """Queries [..., N, H] as the paged kernel reads them against packed
+    keys, [..., N, 2 Hv]: a head's last Hv dims, then one paired row's
+    width with the head's other dims in the half its K/V head has there and
+    zeros in the other half."""
+    *_, N, H = q.shape
+    X = _packed(H, Hv, K)
+    odd = ((jnp.arange(N) // (N // K)) % 2 == 1)[:, None]
+    extra = q[..., :X]
+    zero = jnp.zeros_like(extra)
+    return jnp.concatenate(
+        [q[..., X:], jnp.where(odd, zero, extra), jnp.where(odd, extra, zero)],
+        axis=-1)
+
+
+def ring_cache(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> "Cache":
+    """The cache of a model whose window layers differ from its full
+    layers in their K/V heads (``ModelConfig.has_window_ring``), by cache
+    kind. ``k`` / ``v``: the FULL layers' pages, [full layers x pages, ...],
+    which the allocator counts as ever. ``ring_k`` / ``ring_v``: the WINDOW
+    layers' rows, a ring of ``ring_pages`` pages a slot, [window layers,
+    slots + 1, ring pages, heads, page, width] (slot b owns row b + 1, row
+    0 a scratch ring as page 0 is; the runner walks the leaf flat, [layers
+    x slots x ring pages, ...], in the paged layout): position p of a slot lives in ring page
+    ``(p // page) % ring_pages`` at column ``p % page``, whatever the
+    request's length; a prefill writes the pages of its last positions
+    alone, a decode step goes round. A slot's own and ``NOT_PAGED``: the
+    allocator never sees them (a ring was chosen over freeing pool pages
+    behind the window because the two kinds' pages differ in shape, so one
+    pool could not hand a freed window page to a full layer anyway, and a
+    ring needs no page table entry, no allocation and no host arithmetic a
+    step). Keys are ``pack_keys``' rows: both leaves of a kind are Hv wide."""
+    Kf, Kw = mcfg.n_kv_heads, mcfg.n_kv_heads_sliding
+    psz, Hv = icfg.page_size, mcfg.resolved_v_head_dim
+    for K in (Kf, Kw):      # (refused here, by name, and not in a kernel)
+        _packed(mcfg.resolved_head_dim, Hv, K)
+    full = mcfg.n_paged_layers * icfg.num_pages
+    ring = (mcfg.n_layers - mcfg.n_paged_layers, icfg.max_batch_size + 1,
+            ring_pages(mcfg.sliding_window, psz))
+    return {
+        "k": jnp.zeros((full, Kf + Kf // 2, psz, Hv), dtype),
+        "v": jnp.zeros((full, Kf, psz, Hv), dtype),
+        RING_K: jnp.zeros((*ring, Kw + Kw // 2, psz, Hv), dtype),
+        RING_V: jnp.zeros((*ring, Kw, psz, Hv), dtype),
+    }
+
+
 def init_cache(
     mcfg: ModelConfig,
     icfg: InferenceConfig,
@@ -158,6 +248,8 @@ def init_cache(
         if icfg.kv_quant is not None:
             raise ValueError(f"unknown inference.kv_quant={icfg.kv_quant!r}")
         dtype = jnp.dtype(mcfg.dtype)
+        if mcfg.has_window_ring:
+            return ring_cache(mcfg, icfg, dtype)
         if mcfg.has_kda:
             if not mcfg.has_latent:
                 raise ValueError(
@@ -181,7 +273,7 @@ def init_cache(
 # The leaves of ``retention_leaves`` that are a slot's and not a page's.
 SLOT_LEAVES = ("state", "state_z", "state_len", "g")
 # Every leaf of any backend that is a slot's: what page operations pass by.
-NOT_PAGED = SLOT_LEAVES + (KDA_STATE, KDA_CONV)
+NOT_PAGED = SLOT_LEAVES + (KDA_STATE, KDA_CONV, RING_K, RING_V)
 
 
 def retention_leaves(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> Cache:

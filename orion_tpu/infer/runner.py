@@ -48,7 +48,12 @@ from orion_tpu.infer.kv_cache import (
     KDA_CONV,
     KDA_STATE,
     LATENT,
+    RING_K,
+    RING_V,
+    pack_keys,
+    pack_queries,
     page_geometry,
+    unpack_keys,
 )
 from orion_tpu.models import moe as moe_lib
 from orion_tpu.models.transformer import (
@@ -324,6 +329,7 @@ def _scatter_pages(cc: Cache, k: jax.Array, v: jax.Array, rows: jax.Array):
     overwrites its slot."""
     (Nb, n_pages), psz = rows.shape, cc["k"].shape[2]
     K, H = k.shape[2], k.shape[3]
+    Kv, Hv = v.shape[2], v.shape[3]     # (a packed K pool has more rows)
     new = {}
     if "k_scale" in cc:
         from orion_tpu.infer.kv_cache import quantize_kv
@@ -338,7 +344,7 @@ def _scatter_pages(cc: Cache, k: jax.Array, v: jax.Array, rows: jax.Array):
         new["v_scale"] = cc["v_scale"].at[rows, :, :psz].set(vspg)
     # Pool pages are [K, psz, H] (heads major, see kv_cache.py).
     kpages = k.reshape(Nb, n_pages, psz, K, H).transpose(0, 1, 3, 2, 4)
-    vpages = v.reshape(Nb, n_pages, psz, K, H).transpose(0, 1, 3, 2, 4)
+    vpages = v.reshape(Nb, n_pages, psz, Kv, Hv).transpose(0, 1, 3, 2, 4)
     new["k"] = cc["k"].at[rows].set(kpages)
     new["v"] = cc["v"].at[rows].set(vpages)
     return new
@@ -475,11 +481,18 @@ def _prefill(params, cache, tokens, lengths, pages, prefix_lens,
         params, cache, tokens, lengths, pages, prefix_lens, prefix_pages,
         cfg, paged_prefill=paged_prefill,
     )
-    if cfg.has_kda:
+    if cfg.has_kda or cfg.has_window_ring:
         # The state row each row of the burst owns (slot + 1; padding rows
         # and a caller that says nothing take scratch row 0).
         ctx["state_rows"] = (jnp.zeros((tokens.shape[0],), jnp.int32)
                              if state_rows is None else state_rows)
+    if cfg.has_window_ring:
+        if ctx["P_pre"]:
+            raise ValueError(
+                "a model whose window layers keep a ring prefills whole "
+                "prompts: a cached prefix's window rows are gone")
+        layer = _split_prefill_layer
+    elif cfg.has_kda:
         layer = _hybrid(_kda_prefill_layer, _latent_prefill_layer)
     else:
         layer = _latent_prefill_layer if cfg.is_latent else _dense_layer
@@ -519,7 +532,10 @@ def _decode_core(
     layers, which ADVANCES the state rows of the ``active`` slots, default
     all: run again on the cache it handed back it computes the next
     position's step)."""
-    if cfg.has_kda:
+    if cfg.has_window_ring:
+        ctx, layer = _split_ctx(
+            cache, write_pos, page_table, cfg), _split_layer
+    elif cfg.has_kda:
         ctx = {**_latent_ctx(cache, write_pos, page_table, cfg),
                "active": active}
         layer = _hybrid(_kda_layer, _latent_layer)
@@ -633,6 +649,8 @@ def _paged_ctx(
     name: str = "ragged_paged",             # the kernel's name in a trace
     depths: Optional[jax.Array] = None,     # [B, W] tree depth per column
     tree_mask: Optional[jax.Array] = None,  # [B, W] packed ancestor words
+    geometry: Optional[tuple[int, int]] = None,  # (page size, pages a
+    #           layer) of the leaves walked, where not the pool's (a ring)
 ) -> dict:
     """Batch-level tensors of the paged backend (``_paged_layer``): W new
     tokens per slot written into the pool and attended over it. Draft
@@ -662,7 +680,7 @@ def _paged_ctx(
     verify; with both None this function is untouched (same trace).
     """
     B = seq_lens.shape[0]
-    psz, NP = page_geometry(cache, cfg.n_paged_layers)
+    psz, NP = geometry or page_geometry(cache, cfg.n_paged_layers)
     P = page_table.shape[1]
     batch_idx = jnp.arange(B)[:, None]
     steps = jnp.arange(W, dtype=jnp.int32)[None, :]
@@ -860,6 +878,161 @@ def _paged_layer(
 
     x, _, cc = block(
         x, bp, cfg, ctx["positions"], attend, kind=_kind(cfg, j), mesh=mesh)
+    return x, cc
+
+
+# -- the split cache: full layers in pages, window layers in a ring -----------
+#
+# A model whose window layers differ from its full layers in their K/V heads
+# (``ModelConfig.has_window_ring``; kv_cache.ring_cache). A full layer is the
+# paged backend over ``k`` / ``v`` (rows of the layer's index among the full
+# layers); a window layer is the SAME kernel over ``ring_k`` / ``ring_v``
+# through a page table that is arithmetic: the ``ring_pages`` pages up to the
+# one that takes the new token, each at its place in the slot's ring, with
+# the positions shifted down by whole pages to match (causal and window
+# masks compare differences, which a shift leaves alone). So the kernel's
+# walk is ``ring_pages`` entries whatever the request's length. Keys are kept
+# as ``kv_cache.pack_keys`` lays them out; both kinds may carry a sink.
+
+
+def _scatter_ring(cc: Cache, k: jax.Array, v: jax.Array, li, ctx: dict):
+    """A prefill's K rows [Nb, S, K + K / 2, Hv] and V [Nb, S, K, Hv] of
+    window layer ``li`` (its index among them) into each row's slot's ring:
+    the pages of the last ``ring_pages`` x page positions up to the row's
+    length, and nothing before them (a short prompt's pages twice over,
+    with the same rows)."""
+    psz, RP, lengths = ctx["psz"], cc[RING_K].shape[2], ctx["lengths"]
+    Nb, S = k.shape[:2]
+    last = (jnp.maximum(lengths, 1) - 1) // psz                   # [Nb]
+    src = jnp.clip(
+        last[:, None] - (RP - 1) + jnp.arange(RP, dtype=jnp.int32)[None],
+        0, S // psz - 1)                                           # [Nb, RP]
+    new = {}
+    for name, a in ((RING_K, k), (RING_V, v)):
+        pages = a.reshape(Nb, S // psz, psz, *a.shape[2:])
+        pages = jnp.take_along_axis(
+            pages, src[:, :, None, None, None], axis=1)
+        new[name] = cc[name].at[
+            li, ctx["state_rows"][:, None], src % RP].set(
+                pages.transpose(0, 1, 3, 2, 4))
+    return new
+
+
+def _split_prefill_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
+                         cfg: ModelConfig, mesh, stack=None):
+    """One layer of a whole-prompt prefill of a split-cache model: flash
+    (or XLA) attention over the prompt's own K/V under the layer's window
+    and sink; a full layer's pages go to the pool, a window layer's last
+    pages to the rings."""
+    kind, li = cfg.layer_kind(j), cfg.cache_layer(l, j)
+    positions, seg = ctx["positions"], ctx["seg"]
+
+    def attend(q, k, v, sink=None):
+        with jax.named_scope("kernel"):
+            out = attention(
+                q, k, v, causal=True, q_segment_ids=seg, kv_segment_ids=seg,
+                seg_pad_zero=True, logit_softcap=cfg.attn_logit_softcap,
+                window=kind.window, impl=cfg.kernels, mesh=mesh, sink=sink)
+        if kind.window is None:
+            return out, lambda: _scatter_pages(
+                cc, pack_keys(k, v.shape[-1]), v,
+                li * ctx["NP"] + ctx["pages"])
+        return out, lambda: _scatter_ring(
+            cc, pack_keys(k, v.shape[-1]), v, li, ctx)
+
+    x, cc = _prefill_block(x, cc, bp, j, positions, attend, seg > 0, stack,
+                           cfg, mesh)
+    # The residual stream is written out at each layer's end: the eleven
+    # layers of this plan are one straight program (no scan carries x), and
+    # XLA otherwise keeps every layer's addends to sum them again wherever x
+    # is read (ten [16384, 4096] buffers alive at once: 1.3 GB the chip does
+    # not have beside the weights and the cache). The held-rows count goes
+    # through the same barrier, or its router runs at the program's end on
+    # every layer's normed rows, kept until then.
+    if HELD_ROWS in cc:
+        x, held = jax.lax.optimization_barrier((x, cc[HELD_ROWS]))
+        return x, {**cc, HELD_ROWS: held}
+    return jax.lax.optimization_barrier(x), cc
+
+
+def _split_ctx(cache: Cache, write_pos: jax.Array, page_table: jax.Array,
+               cfg: ModelConfig) -> dict:
+    """The decode step's tensors of a split-cache model: the paged
+    backend's for the full layers (``full``) and, for the window layers
+    (``ring``), the same over each slot's ring: its ``ring_pages`` pages up
+    to the one that takes the new token, positions shifted down by the
+    pages before them."""
+    full = _one_token_ctx(cache, write_pos, page_table, cfg)
+    psz = full["psz"]
+    slots, RP = cache[RING_K].shape[1:3]
+    B = write_pos.shape[0]
+    first = jnp.maximum(write_pos // psz - (RP - 1), 0)            # [B]
+    table = (jnp.arange(1, B + 1, dtype=jnp.int32)[:, None] * RP
+             + (first[:, None] + jnp.arange(RP, dtype=jnp.int32)[None]) % RP)
+    shifted = write_pos - first * psz
+    ring = _paged_ctx(
+        cache, shifted, jnp.ones_like(shifted), table,
+        jnp.ones(shifted.shape, bool), 1, RP * psz, cfg,
+        name="paged_decode", geometry=(psz, slots * RP))
+    return {"full": full, "ring": ring, "positions": full["positions"]}
+
+
+def _split_layer(x: jax.Array, cc: Cache, bp: Any, l, j: int, ctx: dict,
+                 cfg: ModelConfig, mesh) -> tuple[jax.Array, Cache]:
+    """One layer of a split-cache model's decode step (one token a slot):
+    the paged kernel with the new token's write fused in, over the pool for
+    a full layer and over the rings for a window layer (XLA: a scatter and
+    a masked gather of the same rows, the reference)."""
+    kind = cfg.layer_kind(j)
+    ring = kind.window is not None
+    sub = ctx["ring" if ring else "full"]
+    kn, vn = (RING_K, RING_V) if ring else ("k", "v")
+    base = cfg.cache_layer(l, j) * sub["NP"]
+    K, win = cfg.kv_heads_of(kind), kind.window
+
+    def attend(q, k, v, sink=None):
+        new, Hv = dict(cc), v.shape[-1]
+        # The leaves in the paged layout ([rows, heads, page, width]: a
+        # ring's leading dimensions flat) and back.
+        flat = {n: cc[n].reshape(-1, *cc[n].shape[-3:]) for n in (kn, vn)}
+        if sub["use_pallas"]:
+            from orion_tpu.ops.pallas.paged_attention import attend as kernel
+
+            with jax.named_scope("kernel"):
+                out, kp, vp = kernel(
+                    pack_queries(q, K, Hv), flat[kn], flat[vn],
+                    sub["page_table"], sub["start"], sub["k_lens"],
+                    layer_base=base, k_new=pack_keys(k, Hv), v_new=v,
+                    logit_softcap=cfg.attn_logit_softcap, window=win,
+                    interpret=sub["interpret"], k_scale=None, v_scale=None,
+                    name=sub["name"], sink=sink, scale=q.shape[-1] ** -0.5)
+            new[kn], new[vn] = (kp.reshape(cc[kn].shape),
+                                vp.reshape(cc[vn].shape))
+            return out, new
+        with jax.named_scope("cache"):
+            at, offset = base + sub["page_idx"], sub["offset"]      # [B, 1]
+            flat[kn] = flat[kn].at[at, :, offset].set(pack_keys(k, Hv))
+            flat[vn] = flat[vn].at[at, :, offset].set(v)
+            walk = base + sub["page_table"]                         # [B, P]
+            B, P = walk.shape
+            k_ctx, v_ctx = (
+                flat[name][walk].transpose(0, 1, 3, 2, 4).reshape(
+                    B, P * sub["psz"], -1, Hv) for name in (kn, vn))
+            k_ctx = unpack_keys(k_ctx, K)
+            new[kn], new[vn] = (flat[kn].reshape(cc[kn].shape),
+                                flat[vn].reshape(cc[vn].shape))
+        with jax.named_scope("kernel"):
+            mask = sub["kv_base_mask"]
+            if win is not None:
+                mask = mask & (
+                    sub["kv_arange"] >= (sub["q_pos"] - win + 1)[:, :, None])
+            out = attention_xla(
+                q, k_ctx, v_ctx.astype(q.dtype), causal=False, mask=mask,
+                logit_softcap=cfg.attn_logit_softcap, sink=sink)
+        return out, new
+
+    x, _, cc = block(
+        x, bp, cfg, ctx["positions"], attend, kind=kind, mesh=mesh)
     return x, cc
 
 

@@ -617,6 +617,36 @@ def moe_mlp_grouped(
     return y.reshape(B, S, D), _aux_loss(probs, idx, cfg).astype(jnp.float32)
 
 
+# The sorted rows of ONE grouped dispatch ([k x tokens, D], and as many
+# again of its result) stay under this: a longer block is dispatched in equal
+# parts, one behind the other. A row's result does not depend on which rows
+# share its matmul, so the parts compute what the whole would; 16384 tokens
+# x 8 picks x 4096 wide are 1 GiB of rows (and three more arrays of their
+# size around the matmuls), which beside 13.4 GB of weights and cache does
+# not fit; the largest block of any other configuration is 320 MiB.
+GROUPED_ROWS_BYTES = 384 * 2 ** 20
+
+
+def _grouped_in_parts(x, params, cfg: ModelConfig, valid, mesh, layer_stack):
+    """``moe_mlp_grouped`` over the block, whole where its sorted rows stay
+    within ``GROUPED_ROWS_BYTES`` and else in the fewest equal parts of the
+    sequence that do."""
+    B, S, D = x.shape
+    rows = cfg.n_experts_per_token * B * S * D * x.dtype.itemsize
+    parts = next((p for p in range(1, S + 1)
+                  if S % p == 0 and rows <= p * GROUPED_ROWS_BYTES), 1)
+    if parts == 1:
+        return moe_mlp_grouped(x, params, cfg, valid, mesh, layer_stack)
+    cut = lambda a: None if a is None else jnp.moveaxis(
+        a.reshape(B, parts, S // parts, *a.shape[2:]), 1, 0)
+
+    y, aux = jax.lax.map(
+        lambda xv: moe_mlp_grouped(
+            xv[0], params, cfg, xv[1], mesh, layer_stack),
+        (cut(x), cut(valid)))
+    return jnp.moveaxis(y, 0, 1).reshape(B, S, D), aux.mean()
+
+
 def moe_dispatch(
     x: jax.Array,
     params: dict[str, Any],
@@ -640,7 +670,7 @@ def moe_dispatch(
             "> model.n_experts) is computed by moe_dispatch=sorted with no "
             "ep axis: the share IS this device's part of the experts")
     if takes_grouped_path(cfg, x.shape[0], x.shape[1], mesh):
-        y, aux = moe_mlp_grouped(x, params, cfg, valid, mesh, layer_stack)
+        y, aux = _grouped_in_parts(x, params, cfg, valid, mesh, layer_stack)
     elif mode == "einsum":
         y, aux = moe_mlp(x, params, cfg)
     elif mode == "sorted_a2a" and mesh is not None:

@@ -163,12 +163,16 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                 "wo": _normal(next(bkeys), (N * H, D), pdt, resid_std),
             }
         else:
+            # A kind may fix its K/V heads; values may be narrower than keys.
+            Kl, Hv = cfg.kv_heads_of(kind), cfg.resolved_v_head_dim
             attn = {
                 "wq": _normal(next(bkeys), (D, N * H), pdt, std),
-                "wk": _normal(next(bkeys), (D, K * H), pdt, std),
-                "wv": _normal(next(bkeys), (D, K * H), pdt, std),
-                "wo": _normal(next(bkeys), (N * H, D), pdt, resid_std),
+                "wk": _normal(next(bkeys), (D, Kl * H), pdt, std),
+                "wv": _normal(next(bkeys), (D, Kl * Hv), pdt, std),
+                "wo": _normal(next(bkeys), (N * Hv, D), pdt, resid_std),
             }
+            if kind is not None and kind.sink:
+                attn["sink"] = jnp.zeros((N,), pdt)
         block: Params = {
             "attn_norm": {"scale": norm_scale()},
             "mlp_norm": {"scale": norm_scale()},
@@ -323,6 +327,8 @@ def _block_axes(cfg: ModelConfig, lead: tuple, kind) -> Params:
         if cfg.qk_norm:
             block["attn"]["q_norm"] = lead + (None,)
             block["attn"]["k_norm"] = lead + (None,)
+    if kind is not None and kind.sink:
+        block["attn"]["sink"] = lead + ("heads",)
     if att == "kda":
         block["attn"].update({
             "wk": lead + ("embed", "heads"),
@@ -470,7 +476,7 @@ def qkv_proj(
     kind.
     """
     B, S, _ = x.shape
-    N, K, H = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    N, K, H = cfg.n_heads, cfg.kv_heads_of(kind), cfg.resolved_head_dim
     theta, table = cfg.rope_theta, None
     if kind is not None:
         N, theta = kind.n_heads, kind.rope.theta
@@ -486,7 +492,9 @@ def qkv_proj(
         v = v + p["bv"].astype(dtype)
     q = q.reshape(B, S, N, H)
     k = k.reshape(B, S, K, H)
-    v = v.reshape(B, S, K, H)
+    v = v.reshape(B, S, K, cfg.resolved_v_head_dim)
+    if cfg.value_scale != 1.0:
+        v = v * jnp.asarray(cfg.value_scale, v.dtype)
     if cfg.qk_norm:
         # Per head, over its H numbers, before the rotary embedding.
         q = ops.rmsnorm(q, p["q_norm"], eps=cfg.norm_eps)
@@ -795,8 +803,12 @@ def _train_attend(
         return retain
 
     @jax.named_scope("kernel")
-    def attend(q, k, v):
+    def attend(q, k, v, sink=None):
         if sp_active:
+            if sink is not None:
+                raise ValueError(
+                    "a layer with an attention sink trains on one sequence "
+                    "shard: no sequence axis")
             from orion_tpu.parallel.sequence import sequence_attention
 
             # sliding_window threads through every SP method; under "ring"
@@ -837,6 +849,7 @@ def _train_attend(
             window=window,
             impl=cfg.kernels,
             mesh=mesh,
+            sink=sink,
         ), None
 
     return attend
@@ -939,7 +952,10 @@ def block(
             out, state = attend(q, k, v, retention_log_gate(h, bp["attn"]))
         else:
             q, k, v = qkv_proj(h, bp["attn"], cfg, positions, mesh, kind)
-            out, state = attend(q, k, v)
+            # A layer with a learned sink hands ``attend`` its logits.
+            sink = ({"sink": bp["attn"]["sink"]} if "sink" in bp["attn"]
+                    else {})
+            out, state = attend(q, k, v, **sink)
         # remat="names" saves the kernel output: the single most expensive
         # per-layer tensor to rebuild (a full flash fwd pass) at [B,S,N,H]
         # storage. (No-op identity under every other policy.)

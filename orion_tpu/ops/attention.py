@@ -84,8 +84,12 @@ def attention_xla(
     q_positions: Optional[jax.Array] = None,
     kv_positions: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """q: [B, Sq, N, H]; k, v: [B, Skv, K, H] with N % K == 0 -> [B, Sq, N, H]."""
+    """q: [B, Sq, N, H]; k: [B, Skv, K, H], v: [B, Skv, K, Hv] with N % K
+    == 0 -> [B, Sq, N, Hv]. ``sink`` [N]: a learned logit a query head that
+    joins each row's softmax and whose column is dropped (the weights of a
+    row add up to less than 1)."""
     dtype = q.dtype
     n_heads, head_dim = q.shape[2], q.shape[3]
     k = _gqa_expand(k, n_heads)
@@ -122,8 +126,15 @@ def attention_xla(
             mask = mask[:, None, :, :]
         logits = jnp.where(mask, logits, NEG_INF)
 
-    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
-    return jnp.einsum("bnqk,bknh->bqnh", probs, v)
+    if sink is not None:
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None, None],
+            (*logits.shape[:3], 1))
+        probs = jax.nn.softmax(
+            jnp.concatenate([logits, col], axis=-1), axis=-1)[..., :-1]
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bnqk,bknh->bqnh", probs.astype(dtype), v)
 
 
 def attention(
@@ -146,6 +157,7 @@ def attention(
     seg_pad_zero: bool = False,
     mesh: Optional[jax.sharding.Mesh] = None,
     tp_axis: str = "tp",
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Grouped-query scaled-dot-product attention. Shapes as attention_xla.
 
@@ -187,6 +199,12 @@ def attention(
         )
         tp = mesh.shape.get(tp_axis, 1) if mesh is not None else 1
         n_heads, n_kv = q.shape[2], k.shape[2]
+        if sink is not None:
+            if mesh is not None and mesh.size > 1:
+                raise ValueError(
+                    "flash attention with a sink runs on one device: its "
+                    "per-head logits are not split over a mesh yet")
+            kernel_kw["sink"] = sink
         if n_heads % tp or n_kv % tp:
             raise ValueError(
                 f"tp-sharded flash attention needs n_heads ({n_heads}) "
@@ -233,4 +251,5 @@ def attention(
         q_positions=q_positions,
         kv_positions=kv_positions,
         window=window,
+        sink=sink,
     )

@@ -92,6 +92,14 @@ class _Statics:
     # real segment id (0==0 attends), and skipping would change results
     # for such callers.
     seg_pad_zero: bool = False
+    # The logits' scale where it is not q's (padded) width ** -0.5, and
+    # whether a learned sink logit a query head joins the softmax's
+    # denominator (forward only: ``flash_attention``'s ``sink``).
+    scale: Optional[float] = None
+    sink: bool = False
+    # False: no log-sum-exp output (the forward-only call, whose [B, N, S,
+    # 128] float32 rows nothing reads: 512 MiB at 16384 tokens of 64 heads).
+    lse: bool = True
 
 
 def _static_range(st: _Statics) -> bool:
@@ -344,12 +352,19 @@ def _lane_sums(p):
 
 def _fwd_kernel(st: _Statics, has_seg, nk, *refs):
     (q_ref, k_ref, v_ref, qseg, kseg, qpos, kpos,
-     (o_ref, lse_ref, m_s, l_s, acc_s)) = _unpack_refs(
-        has_seg, st.has_pos, refs)
+     rest) = _unpack_refs(has_seg, st.has_pos, refs)
+    sink_ref = None
+    if st.sink:
+        sink_ref, rest = rest[0], rest[1:]
+    lse_ref = None
+    if st.lse:
+        o_ref, lse_ref, m_s, l_s, acc_s = rest
+    else:
+        o_ref, m_s, l_s, acc_s = rest
 
     iq, j = pl.program_id(2), pl.program_id(3)
     ik, live = _step(st, _kv_range, iq, j, nk)
-    scale = q_ref.shape[-1] ** -0.5
+    scale = q_ref.shape[-1] ** -0.5 if st.scale is None else st.scale
 
     @pl.when(j == 0)
     def _init():
@@ -379,11 +394,16 @@ def _fwd_kernel(st: _Statics, has_seg, nk, *refs):
     @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
         l = l_s[:].sum(axis=-1, keepdims=True)
+        if sink_ref is not None:
+            # One more term of the denominator and no column: the head's
+            # sink logit under the row's running maximum.
+            l = l + jnp.exp(sink_ref[0, :1, :1] - m_s[:, :1])
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_s[:] / l_safe).astype(o_ref.dtype)
-        lse = m_s[:, :1] + jnp.log(l_safe)
-        lse = jnp.where(l == 0.0, NEG_INF, lse)
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+        if lse_ref is not None:
+            lse = m_s[:, :1] + jnp.log(l_safe)
+            lse = jnp.where(l == 0.0, NEG_INF, lse)
+            lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
 
 def _bwd_block(st: _Statics, iq, ik, scale, q_ref, k_ref, v_ref, qseg, kseg,
@@ -509,35 +529,46 @@ def _ids(qseg, kseg, qpos, kpos):
 
 
 @functools.partial(jax.jit, static_argnums=0)
-def _fwd_call(st: _Statics, q, k, v, qseg, kseg, qpos=None, kpos=None):
-    """q: [B,N,Sq,H]; k,v: [B,K,Skv,H] (padded) -> (o, lse[f32 B,N,Sq])."""
+def _fwd_call(st: _Statics, q, k, v, qseg, kseg, qpos=None, kpos=None,
+              sink=None):
+    """q: [B,N,Sq,H]; k: [B,K,Skv,H], v: [B,K,Skv,Hv] (padded) -> (o
+    [B,N,Sq,Hv], lse[f32 B,N,Sq]). ``sink`` [N, 8, LANES] float32 (a head's
+    logit on every row and lane) with ``st.sink``."""
     B, N, Sq, H = q.shape
-    K, Skv = k.shape[1], k.shape[2]
+    K, Skv, Hv = k.shape[1], k.shape[2], v.shape[3]
     nq, nk = Sq // st.block_q, Skv // st.block_kv
     ids = _ids(qseg, kseg, qpos, kpos)
     q_spec, row_spec, kv_spec, id_specs = _row_specs(
         st, N // K, H, nk, Sq, Skv, len(ids) // 2)
+    if Hv == H:
+        o_spec, v_spec = q_spec, kv_spec
+    else:
+        o_spec, _, v_spec, _ = _row_specs(st, N // K, Hv, nk, Sq, Skv, 0)
+    extra, extra_specs = [], []
+    if st.sink:
+        extra = [sink]
+        extra_specs = [pl.BlockSpec((1, 8, LANES), lambda b, n, *_: (n, 0, 0))]
 
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, st, qseg is not None, nk),
         grid=(B, N, nq, _steps(st, _kv_range, nq, nk)),
-        in_specs=[q_spec, kv_spec, kv_spec, *id_specs],
-        out_specs=[q_spec, row_spec],
+        in_specs=[q_spec, kv_spec, v_spec, *id_specs, *extra_specs],
+        out_specs=[o_spec, row_spec][:1 + st.lse],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((B, N, Sq, Hv), q.dtype),
             # lse is lanes-broadcast [B, N, Sq, 128]: TPU tiling forbids a
             # (1, 1, block_q) block, so the row stat rides a full lane dim.
             jax.ShapeDtypeStruct((B, N, Sq, LANES), jnp.float32),
-        ],
+        ][:1 + st.lse],
         scratch_shapes=[
             pltpu.VMEM((st.block_q, LANES), jnp.float32),
             pltpu.VMEM((st.block_q, LANES), jnp.float32),
-            pltpu.VMEM((st.block_q, H), jnp.float32),
+            pltpu.VMEM((st.block_q, Hv), jnp.float32),
         ],
         interpret=st.interpret,
         name="flash_fwd",
-    )(q, k, v, *ids)
-    return out[0], out[1]
+    )(q, k, v, *ids, *extra)
+    return out[0], (out[1] if st.lse else None)
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -794,6 +825,7 @@ def flash_attention(
     kv_positions: Optional[jax.Array] = None,
     window: Optional[int] = None,
     seg_pad_zero: bool = False,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Flash attention; shapes/semantics match ``attention_xla``.
 
@@ -807,11 +839,49 @@ def flash_attention(
     (ragged prefill / packed tails) — only set it when the caller
     guarantees the pack_rows convention.
     See ``_prep`` for the tile-size default rationale.
+
+    ``sink`` ([N] learned logits, one a query head) adds ``exp(sink)`` to
+    each row's softmax denominator and no column; values may be narrower
+    than keys (``v`` [B, Skv, K, Hv] -> [B, Sq, N, Hv]), and keys whose
+    width is not a whole number of 128-lane tiles are padded with zeros
+    under the scale of their own width. Either is the FORWARD alone: no
+    backward is defined for them.
     """
+    H, Hv = q.shape[-1], v.shape[-1]
+    fwd_only = sink is not None or Hv != H
+    scale = None
+    if fwd_only and H % LANES:
+        scale = H ** -0.5
+        q, k = (pad_axis(a, 3, round_up(H, LANES)) for a in (q, k))
     st, qt, kt, vt, qseg, kseg, qpos, kpos, Sq = _prep(
         q, k, v, q_segment_ids, kv_segment_ids,
         causal, logit_softcap, q_offset, block_q, block_kv, interpret,
         q_positions, kv_positions, window, seg_pad_zero,
     )
+    if fwd_only:
+        st = dataclasses.replace(
+            st, scale=scale, sink=sink is not None, lse=False)
+        rows = None if sink is None else jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None, None], (q.shape[2], 8, LANES))
+        o = _flash_forward_only(st, qt, kt, vt, qseg, kseg, qpos, kpos, rows)
+        return o[:, :, :Sq, :].transpose(0, 2, 1, 3)
     o = _flash(st, qt, kt, vt, qseg, kseg, qpos, kpos)
     return o[:, :, :Sq, :].transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash_forward_only(st: _Statics, q, k, v, qseg, kseg, qpos, kpos, sink):
+    return _fwd_call(st, q, k, v, qseg, kseg, qpos, kpos, sink)[0]
+
+
+def _forward_only_fwd(st, *args):
+    return _flash_forward_only(st, *args), None
+
+
+def _forward_only_bwd(st, _, g):
+    raise NotImplementedError(
+        "flash attention with a sink or with values narrower than keys has "
+        "no backward: train such a model with impl='xla'")
+
+
+_flash_forward_only.defvjp(_forward_only_fwd, _forward_only_bwd)

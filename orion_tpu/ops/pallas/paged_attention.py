@@ -116,6 +116,9 @@ def _kernel(
     window: Optional[int],
     quant: bool,
     tree: bool,
+    split: bool,
+    sink: bool,
+    scale: Optional[float],
     pt_ref,        # [B, P] scalar-prefetched page table (per-layer-relative)
     base_ref,      # [1] scalar-prefetched flat-pool row base (layer * NP)
     st_ref,        # [B] scalar-prefetched cursor (first new position)
@@ -135,6 +138,9 @@ def _kernel(
     new_refs = ()
     if fused_write:
         new_refs, refs = refs[:2], refs[2:]
+    sink_ref = None
+    if sink:
+        sink_ref, refs = refs[0], refs[1:]
     o_ref, refs = refs[0], refs[1:]
     if fused_write:
         # Aliased in/out: read through the OUTPUT refs, so a page written
@@ -147,7 +153,9 @@ def _kernel(
 
     b, ib = pl.program_id(0), pl.program_id(1)
     B = pl.num_programs(0)
-    K, T, H = bufs[0].shape[1:]
+    # The V block's: one row of heads a kv head (a split K block holds half
+    # as many rows again, below).
+    K, T, H = bufs[1].shape[1:]
     WG8 = q_ref.shape[1] // K
     cdt = q_ref.dtype if quant else bufs[0].dtype
 
@@ -279,13 +287,31 @@ def _kernel(
         if fused_write:
             write_back(wait=False)
 
-        q = q_ref[0].reshape(K, WG8, H).astype(cdt)
-        k = bufs[0][slot].astype(cdt)                        # [K, T, H]
-        v = bufs[1][slot].astype(cdt)
-        z = lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * (H ** -0.5)                                      # [K, WG8, T]
+        if split:
+            # Keys wider than values: a head's last H key dims in row k of
+            # the K block, and the dims before them, two heads to a row of
+            # H lanes, in rows K.. (kv_cache.pack_keys). The query comes
+            # as [its last H dims | the others in its head's half of a
+            # row, zeros in the other half], so a pair's row serves both.
+            qf = q_ref[0].astype(cdt)                        # [K*WG8, 2H]
+            k = bufs[0][slot].astype(cdt)                    # [K + K/2, T, H]
+            v = bufs[1][slot].astype(cdt)
+            dims = (((2,), (2,)), ((0,), (0,)))
+            z = lax.dot_general(
+                qf[:, :H].reshape(K, WG8, H), k[:K], dims,
+                preferred_element_type=jnp.float32)
+            zx = lax.dot_general(
+                qf[:, H:].reshape(K // 2, 2 * WG8, H), k[K:], dims,
+                preferred_element_type=jnp.float32)
+            z = (z + zx.reshape(K, WG8, T)) * scale
+        else:
+            q = q_ref[0].reshape(K, WG8, H).astype(cdt)
+            k = bufs[0][slot].astype(cdt)                    # [K, T, H]
+            v = bufs[1][slot].astype(cdt)
+            z = lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            ) * (H ** -0.5 if scale is None else scale)      # [K, WG8, T]
         if quant:
             # int8 pool: the per-(head, token) K scale applies to the
             # logit COLUMNS after the matmul (cheaper than dequantizing
@@ -372,6 +398,10 @@ def _kernel(
         @pl.when(at_end)
         def _finish():
             l = l_s[:, :1]
+            if sink_ref is not None:
+                # One more term of the denominator and no column: each
+                # row's head's sink logit under the row's running maximum.
+                l = l + jnp.exp(sink_ref[:, :1] - m_s[:, :1])
             l_safe = jnp.where(l == 0.0, 1.0, l)
             o_ref[0] = (acc_s[:] / l_safe).astype(o_ref.dtype)
 
@@ -381,12 +411,15 @@ def _kernel(
 # once, not once a call site: pallas_call itself re-traces its kernel on
 # every call, and the block walk's body is long.
 @functools.partial(jax.jit, static_argnames=(
-    "softcap", "window", "interpret", "name", "nb"))
+    "softcap", "window", "interpret", "name", "nb", "scale"))
 def _call(q, k_pool, v_pool, page_table, start, lens, base, k_new, v_new,
-          k_scale, v_scale, tree_mask, depths, *, softcap, window, interpret,
-          name, nb):
-    B, W, N, H = q.shape
-    _, K, psz, _ = k_pool.shape
+          k_scale, v_scale, tree_mask, depths, sink=None, *, softcap, window,
+          interpret, name, nb, scale=None):
+    B, W, N, Hq = q.shape
+    _, K, psz, H = v_pool.shape
+    # Keys wider than values (``_kernel``'s split): q is two pool rows wide.
+    split = k_pool.shape[1] != K
+    assert Hq == (2 * H if split else H), (q.shape, k_pool.shape, v_pool.shape)
     P = page_table.shape[1]
     G = N // K
     WG = W * G
@@ -397,11 +430,11 @@ def _call(q, k_pool, v_pool, page_table, start, lens, base, k_new, v_new,
 
     # Pack the W queries' GQA bands per kv head: [K, W*G] rows, padded to
     # a sublane multiple — the kernel recovers (w, g) from the row index.
-    qg = q.reshape(B, W, K, G, H).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(B, K, WG, H)
+    qg = q.reshape(B, W, K, G, Hq).transpose(0, 2, 1, 3, 4)
+    qg = qg.reshape(B, K, WG, Hq)
     if WG8 != WG:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, WG8 - WG), (0, 0)))
-    qg = qg.reshape(B, K * WG8, H)
+    qg = qg.reshape(B, K * WG8, Hq)
 
     prefetch = [
         page_table.astype(jnp.int32), base, start.astype(jnp.int32),
@@ -410,32 +443,44 @@ def _call(q, k_pool, v_pool, page_table, start, lens, base, k_new, v_new,
     if tree:
         prefetch += [tree_mask.astype(jnp.int32), depths.astype(jnp.int32)]
 
-    q_spec = pl.BlockSpec((1, K * WG8, H), lambda b, ib, *_: (b, 0, 0))
+    o_spec = pl.BlockSpec((1, K * WG8, H), lambda b, ib, *_: (b, 0, 0))
+    q_spec = o_spec if Hq == H else pl.BlockSpec(
+        (1, K * WG8, Hq), lambda b, ib, *_: (b, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     pools = [k_pool, v_pool] + ([k_scale, v_scale] if quant else [])
     in_specs = [q_spec] + [hbm] * len(pools)
     args = [qg, *pools]
-    out_specs = [q_spec]
+    out_specs = [o_spec]
     out_shape = [jax.ShapeDtypeStruct((B, K * WG8, H), q.dtype)]
     aliases = {}
     if fused_write:
         # The runner's [B, W, K, H] as it is: token w is a leading index.
         new_spec = pl.BlockSpec(
             (1, W, K, H), lambda b, ib, *_: (b, 0, 0, 0))
-        in_specs += [new_spec, new_spec]
+        in_specs += [new_spec if not split else pl.BlockSpec(
+            (1, W, k_pool.shape[1], H), lambda b, ib, *_: (b, 0, 0, 0)),
+            new_spec]
         args += [k_new, v_new]
         out_specs += [hbm] * len(pools)
         out_shape += [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools]
         # Operand indices count the scalar-prefetch args and q before the
         # pools; pool i aliases output 1 + i.
         aliases = {len(prefetch) + 1 + i: 1 + i for i in range(len(pools))}
+    if sink is not None:
+        # A head's logit on the rows its queries have in the q block.
+        rows = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(K, 1, G), (K, W, G))
+        rows = jnp.pad(rows.reshape(K, WG), ((0, 0), (0, WG8 - WG)))
+        in_specs += [pl.BlockSpec((K * WG8, LANES), lambda b, ib, *_: (0, 0))]
+        args += [jnp.broadcast_to(
+            rows.reshape(K * WG8, 1), (K * WG8, LANES))]
 
     T = nb * psz
     scratch = [
         pltpu.VMEM((K * WG8, LANES), jnp.float32),
         pltpu.VMEM((K * WG8, LANES), jnp.float32),
         pltpu.VMEM((K * WG8, H), jnp.float32),
-        pltpu.VMEM((2, K, T, H), k_pool.dtype),
+        pltpu.VMEM((2, k_pool.shape[1], T, H), k_pool.dtype),
         pltpu.VMEM((2, K, T, H), v_pool.dtype),
     ]
     if quant:
@@ -451,7 +496,7 @@ def _call(q, k_pool, v_pool, page_table, start, lens, base, k_new, v_new,
     out = pl.pallas_call(
         functools.partial(
             _kernel, softcap, psz, P, G, W, nb, fused_write, window, quant,
-            tree,
+            tree, split, sink is not None, scale,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
@@ -480,10 +525,16 @@ def _call(q, k_pool, v_pool, page_table, start, lens, base, k_new, v_new,
 def attend(q, k_pool, v_pool, page_table, start, lens, *, layer_base,
            k_new, v_new, logit_softcap, window, interpret, k_scale, v_scale,
            tree_mask=None, depths=None, mesh=None, tp_axis="tp",
-           name="paged_decode"):
+           name="paged_decode", sink=None, scale=None):
     """W-query attention over the paged pool: ``paged_attention`` and
     ``ragged_paged_attention`` are this at W = 1 and at W. Returns
-    ``(out [B, W, N, H], *written pools)``."""
+    ``(out [B, W, N, H], *written pools)``.
+
+    ``sink`` [N] (a learned logit a query head) adds ``exp(sink)`` to each
+    row's softmax denominator and no column. Keys wider than values: a K
+    pool of half as many rows of heads again as the V pool's
+    (``kv_cache.pack_keys``), ``q`` as ``kv_cache.pack_queries`` lays it
+    out and ``scale`` the logits' own (the key's true width ** -0.5)."""
     assert (k_new is None) == (v_new is None)
     assert (k_scale is None) == (v_scale is None)
     if (tree_mask is None) != (depths is None):
@@ -496,11 +547,11 @@ def attend(q, k_pool, v_pool, page_table, start, lens, *, layer_base,
         )
     if window is not None and window < 1:
         raise ValueError(f"window={window} must be >= 1")
-    N, K = q.shape[2], k_pool.shape[1]
+    N, K = q.shape[2], v_pool.shape[1]
     assert N % K == 0, (q.shape, K)
     base = jnp.asarray(layer_base, jnp.int32).reshape(1)
     # Optional operands, in _call's order; None where absent.
-    opt = [k_new, v_new, k_scale, v_scale, tree_mask, depths]
+    opt = [k_new, v_new, k_scale, v_scale, tree_mask, depths, sink]
 
     def run(q_, kp_, vp_, pt_, st_, ln_, base_, *given):
         it = iter(given)
@@ -508,7 +559,7 @@ def attend(q, k_pool, v_pool, page_table, start, lens, *, layer_base,
         return _call(
             q_, kp_, vp_, pt_, st_, ln_, base_, *full,
             softcap=logit_softcap, window=window, interpret=interpret,
-            name=name, nb=min(BLOCK_PAGES, pt_.shape[1]),
+            name=name, nb=min(BLOCK_PAGES, pt_.shape[1]), scale=scale,
         )
 
     args = [q, k_pool, v_pool, page_table, start, lens, base]
@@ -524,6 +575,10 @@ def attend(q, k_pool, v_pool, page_table, start, lens, *, layer_base,
     # head-independent (page table, cursors, base, tree words replicate),
     # and the fused in-place write stays consistent per shard: each device
     # owns its K/tp slice of every page. G = N/K is preserved per shard.
+    if sink is not None or k_pool.shape[1] != K:
+        raise ValueError(
+            "paged attention with a sink or a packed K pool runs on one "
+            "device: neither is split over a mesh yet")
     if N % tp or K % tp:
         raise ValueError(
             f"tp-sharded paged attention needs n_heads ({N}) and "

@@ -29,6 +29,9 @@ compares fwd + grads against the xla reference ops:
   - latent attention (``--only latent`` runs these alone): the latent
     paged decode kernel at the GLM cell's shapes against the XLA absorbed
     form, against the expanded form, and a control without the rotary term.
+  - a sink and values narrower than keys (``--only sink`` runs these
+    alone; PR 50): the flash forward and the paged decode kernel over packed
+    key rows at the MiMo cell's shapes, against the XLA form;
   - Kimi delta attention (``--only kda`` runs these alone): the chunked
     form and the decode kernel ``kda_decode`` at the Ling cell's shapes, with
     decays near 1 and at the -5 bound, against the plain recurrence in
@@ -1180,6 +1183,90 @@ def kda_checks() -> None:
            "recurrence", rel > 0.1, f": rel={rel:.3e}")
 
 
+def sink_checks() -> None:
+    """The two softmax kernels at the MiMo cell's shapes (PR 50): keys 192
+    wide, values 128, 64 query heads over 8 K/V heads under a window of 128
+    with a learned sink, and over 4 with neither. The flash forward against
+    the XLA form (one prompt a row, ragged lengths by segment ids); the
+    paged decode kernel over packed key rows, on a ring of 3 pages that has
+    gone round and on a pool at contexts up to 16384, the new token's write
+    fused in (the pools it hands back bitwise against a scatter); and a
+    control without the sink that has to fail."""
+    from orion_tpu.infer import kv_cache
+    from orion_tpu.ops.pallas.paged_attention import attend as paged
+
+    N, H, Hv, psz, dt = 64, 192, 128, 64, jnp.bfloat16
+    ks = jax.random.split(jax.random.key(11), 8)
+    sink = 4.0 * jax.random.uniform(ks[0], (N,))
+    # flash forward: rows of 2048 (lengths 2048 and 300) and one of 4096
+    for K, window, b, S, lens in ((8, 128, sink, 256 if INTERP else 2048,
+                                   (1.0, 0.15)),
+                                  (4, None, None, 512 if INTERP else 4096,
+                                   (1.0,))):
+        q = jax.random.normal(ks[1], (len(lens), S, N, H), jnp.float32)
+        k = jax.random.normal(ks[2], (len(lens), S, K, H), jnp.float32)
+        v = jax.random.normal(ks[3], (len(lens), S, K, Hv), jnp.float32)
+        seg = (jnp.arange(S)[None, :] < jnp.asarray(
+            [int(f * S) for f in lens])[:, None]).astype(jnp.int32)
+        kw = dict(q_segment_ids=seg, kv_segment_ids=seg, window=window)
+        args = [a.astype(dt) for a in (q * 0.5, k, v)]
+        want = attention_xla(*args, sink=b, **kw)
+        got = flash_attention(*args, sink=b, seg_pad_zero=True,
+                              interpret=INTERP, **kw)
+        live = seg[:, :, None, None] > 0
+        tag = f"flash fwd K={K} window={window} sink={b is not None}"
+        check(tag, jnp.where(live, got, 0), jnp.where(live, want, 0), 2e-2)
+        if b is not None:
+            bare = flash_attention(*args, seg_pad_zero=True,
+                                   interpret=INTERP, **kw)
+            rel = float(jnp.max(jnp.abs(jnp.where(live, bare - want, 0))))
+            record(tag + " control (no sink) differs", rel > 0.05,
+                   f": max abs {rel:.3e}")
+    # paged decode over packed keys: a ring of 3 pages (window layers) and a
+    # pool (full layers), B slots
+    # (64 slots on their rings; 8 on the pool, whose pages for 64 slots of
+    # 288 would not fit beside the scatter that checks them)
+    for K, window, b, B, P, hi in (
+            (8, 128, sink, 4 if INTERP else 64, 3, 190),
+            (4, None, None, 4 if INTERP else 8, 8 if INTERP else 288,
+             500 if INTERP else 16384)):
+        NP = B * P + 1
+        pool_k = jax.random.normal(
+            ks[4], (2 * NP, K + K // 2, psz, Hv), jnp.float32).astype(dt)
+        pool_v = jax.random.normal(
+            ks[5], (2 * NP, K, psz, Hv), jnp.float32).astype(dt)
+        table = 1 + jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
+        start = jnp.asarray(np.random.default_rng(3).integers(
+            1, hi, B), jnp.int32).at[0].set(hi - 1)
+        q = (0.5 * jax.random.normal(ks[6], (B, 1, N, H))).astype(dt)
+        kn = jax.random.normal(ks[7], (B, 1, K, H)).astype(dt)
+        vn = jax.random.normal(ks[1], (B, 1, K, Hv)).astype(dt)
+        rows = kv_cache.pack_keys(kn, Hv)
+        out, kp, vp = paged(
+            kv_cache.pack_queries(q, K, Hv), pool_k, pool_v, table, start,
+            jnp.ones_like(start), layer_base=NP, k_new=rows, v_new=vn,
+            logit_softcap=None, window=window, interpret=INTERP,
+            k_scale=None, v_scale=None, sink=b, scale=H ** -0.5)
+        at = (NP + table[jnp.arange(B), start // psz], slice(None),
+              start % psz)
+        want_k = pool_k.at[at].set(rows[:, 0])
+        want_v = pool_v.at[at].set(vn[:, 0])
+        ctx_k = kv_cache.unpack_keys(
+            want_k[NP + table].transpose(0, 1, 3, 2, 4).reshape(
+                B, P * psz, K + K // 2, Hv), K)
+        ctx_v = want_v[NP + table].transpose(0, 1, 3, 2, 4).reshape(
+            B, P * psz, K, Hv)
+        pos = jnp.arange(P * psz)[None, None, :]
+        mask = pos <= start[:, None, None]
+        if window is not None:
+            mask &= pos > (start[:, None, None] - window)
+        want = attention_xla(q, ctx_k, ctx_v, causal=False, mask=mask,
+                             sink=b)
+        tag = f"paged packed K={K} window={window} sink={b is not None}"
+        check(tag, out, want, 2e-2)
+        bitwise(tag + " pools", [(kp, want_k), (vp, want_v)])
+
+
 def main() -> int:
     global INTERP
     INTERP = "--interpret" in sys.argv[1:]
@@ -1200,7 +1287,8 @@ def main() -> int:
     for name, group in (("retention", retention_checks),
                         ("rope", rope_decode_checks),
                         ("latent", latent_checks),
-                        ("kda", kda_checks)):
+                        ("kda", kda_checks),
+                        ("sink", sink_checks)):
         if name in sys.argv[1:]:        # --only <name>
             guarded(name, group)
             green = sum(ok for _, ok in RESULTS)
@@ -1210,6 +1298,7 @@ def main() -> int:
     guarded("retention", retention_checks)
     guarded("latent", latent_checks)
     guarded("kda", kda_checks)
+    guarded("sink", sink_checks)
     guarded("flash", flash_checks)
     if not INTERP:      # the cells' sizes: minutes under the interpreter
         guarded("flash @cells", flash_cell_checks)
