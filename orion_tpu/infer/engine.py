@@ -1515,6 +1515,11 @@ class InferenceEngine:
             # (runner.HELD_ROWS); 0 unless model.router_width says the
             # device holds a share.
             "prefill_held_expert_rows": 0,
+            # Of those layers' dispatches, the ones whose held rows passed
+            # the bound of one pass (models/moe.held_row_bound) and took a
+            # further pass; counted by the same program, and 0 wherever the
+            # dispatch bounds nothing (moe.bounds_held_rows).
+            "prefill_held_bound_overflows": 0,
             # What the paged decode kernel had to read: over every token
             # step of every decode window, the live slots' context
             # lengths (bounded by the sliding window where there is one).
@@ -1599,7 +1604,8 @@ class InferenceEngine:
         ``<phase>_s`` keys those three are sums of (_zero_timing), the
         prefill_dispatches/prefill_tokens/prefill_pad_tokens/
         prefill_picks_in_program/chained_steps/
-        prefill_expert_rows/prefill_held_expert_rows, decode_kv_tokens/
+        prefill_expert_rows/prefill_held_expert_rows/
+        prefill_held_bound_overflows, decode_kv_tokens/
         decode_kv_token_layers/decode_kv_pages_read and kv_live_page_layers/
         kv_dead_window_page_layers sizing counters,
         windows/steps counters, the slot_steps/wasted_steps
@@ -3327,6 +3333,9 @@ class InferenceEngine:
                 # copy, no wait.
                 self.timing["prefill_held_expert_rows"] += int(
                     self._executor.held_rows)
+                if self._executor.held_overflows is not None:
+                    self.timing["prefill_held_bound_overflows"] += int(
+                        self._executor.held_overflows)
             for i, req in enumerate(b.reqs):
                 if req.done:
                     continue   # quarantined during mask build (_sample_masks)

@@ -83,8 +83,11 @@ class DispatchExecutor:
         # all on the device: the greedy picks [nb], the step's last tokens
         # [B] with the picks at their slots, the key after one sampling
         # event, and the count of expert rows on held experts (only a
-        # model that holds a share of its experts has one).
-        self.picks = self.last_token = self.key = self.held_rows = None
+        # model that holds a share of its experts has one) with, where the
+        # dispatch bounds those rows, the layer-dispatches that passed the
+        # bound.
+        self.picks = self.last_token = self.key = None
+        self.held_rows = self.held_overflows = None
 
     def jit_program(self, name: str, mcfg, mesh):
         """Build one jitted dispatch program. ``name`` is a coarse path
@@ -137,7 +140,8 @@ class DispatchExecutor:
                     key=None):
             """(logits, cache) of the prefill program; what it returns
             between the two is parked on the executor (``picks``,
-            ``last_token``, ``key``, ``held_rows``) for the engine to take
+            ``last_token``, ``key``, ``held_rows``, ``held_overflows``) for
+            the engine to take
             up. What only the engine can say (the state row and the slot of
             each row, the step's last tokens, its key) a caller that says
             nothing (a warm-up) gets as placeholders of the same shapes and
@@ -158,7 +162,7 @@ class DispatchExecutor:
                 program(params, cache, tokens, lengths, pages, pre_lens,
                         pre_pages, state_rows, slots, last_token, key))
             if held:
-                self.held_rows, = held
+                self.held_rows, self.held_overflows = (*held, None)[:2]
             return logits, cache
 
         prefill.program = program

@@ -301,15 +301,20 @@ def _prefill_block(x, cc: Cache, bp: Any, j: int, positions, attend, valid,
     """``transformer.block`` for one layer of a prefill, whatever its
     backend: ``attend`` hands back what the layer writes (traced behind the
     feed-forward, under the cache part), and where ``prefill_step`` carries
-    the counter (a model that holds a share of its experts) this layer's
-    routed rows on held experts are added to it."""
-    held, tap = cc.get(HELD_ROWS), None
+    the counters (a model that holds a share of its experts) this layer's
+    routed rows on held experts are added to the one and, where the
+    dispatch bounds its rows, whether they passed the bound to the other."""
+    held, over, tap = cc.get(HELD_ROWS), cc.get(HELD_OVERFLOWS), None
     if held is not None and "moe" in bp:
         def tap(h2):
-            nonlocal held
-            held = held + moe_lib.held_rows(
+            nonlocal held, over
+            rows = moe_lib.held_rows(
                 h2, bp["moe"]["router"], cfg, valid,
                 bp["moe"].get("router_bias"))
+            held = held + rows
+            if over is not None:
+                over = over + (rows > moe_lib.held_row_bound(
+                    cfg, x.shape[0] * x.shape[1])).astype(jnp.int32)
 
     x, _, written = block(
         x, bp, cfg, positions, attend, kind=_kind(cfg, j), mesh=mesh,
@@ -318,6 +323,8 @@ def _prefill_block(x, cc: Cache, bp: Any, j: int, positions, attend, valid,
         cc = {**cc, **written()}
     if held is not None:
         cc[HELD_ROWS] = held
+    if over is not None:
+        cc[HELD_OVERFLOWS] = over
     return x, cc
 
 
@@ -372,6 +379,7 @@ def _kind(cfg: ModelConfig, j: int):
 
 
 HELD_ROWS = "held_expert_rows"
+HELD_OVERFLOWS = "held_bound_overflows"
 
 
 def _prefill_logits(
@@ -461,7 +469,9 @@ def prefill_step(
 def _prefill(params, cache, tokens, lengths, pages, prefix_lens,
              prefix_pages, state_rows, cfg, mesh, paged_prefill):
     """``prefill_step``'s (logits, cache), or (logits, cache, rows on held
-    experts) for a model that holds a share of its experts."""
+    experts) for a model that holds a share of its experts, or (logits,
+    cache, those rows, layer-dispatches that passed ``moe.held_row_bound``)
+    where its dispatch bounds the rows of this block."""
     if cfg.is_retention:
         if prefix_pages is not None and prefix_pages.shape[1]:
             raise ValueError(
@@ -503,15 +513,19 @@ def _prefill(params, cache, tokens, lengths, pages, prefix_lens,
 
     x = embed(params, tokens, ctx["positions"], cfg)
     cache = dict(cache)
+    counters = []
     if cfg.holds_expert_share:
-        # Rides the layer scan beside the pool and leaves as a third
-        # result: the engine's prefill_held_expert_rows counter.
-        cache[HELD_ROWS] = jnp.zeros((), jnp.int32)
+        # Ride the layer scan beside the pool and leave as further results:
+        # the engine's prefill_held_expert_rows counter and, where the
+        # dispatch bounds the rows of this block (moe.bounds_held_rows), its
+        # prefill_held_bound_overflows.
+        counters = [HELD_ROWS]
+        if moe_lib.bounds_held_rows(cfg, tokens.size):
+            counters.append(HELD_OVERFLOWS)
+        cache.update({name: jnp.zeros((), jnp.int32) for name in counters})
     x, cache = _scan_layers(params, cfg, body, (x, cache))
     logits = _prefill_logits(params, x, lengths, cfg, mesh)
-    if cfg.holds_expert_share:
-        return logits, cache, cache.pop(HELD_ROWS)
-    return logits, cache
+    return logits, cache, *(cache.pop(name) for name in counters)
 
 
 def _decode_core(
@@ -949,9 +963,10 @@ def _split_prefill_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
     # not have beside the weights and the cache). The held-rows count goes
     # through the same barrier, or its router runs at the program's end on
     # every layer's normed rows, kept until then.
-    if HELD_ROWS in cc:
-        x, held = jax.lax.optimization_barrier((x, cc[HELD_ROWS]))
-        return x, {**cc, HELD_ROWS: held}
+    counts = {n: cc[n] for n in (HELD_ROWS, HELD_OVERFLOWS) if n in cc}
+    if counts:
+        x, counts = jax.lax.optimization_barrier((x, counts))
+        return x, {**cc, **counts}
     return jax.lax.optimization_barrier(x), cc
 
 

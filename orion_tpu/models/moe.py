@@ -537,6 +537,114 @@ def expert_rows(cfg: ModelConfig, B: int, S: int,
     return _bucket_rows(cfg, B, S)
 
 
+def held_row_bound(cfg: ModelConfig, tokens: int) -> int:
+    """How many sorted rows ONE pass of the bounded grouped dispatch builds,
+    multiplies and combines for a block of ``tokens`` positions in a layer
+    that holds ``n_experts`` of the ``router_width`` experts its router
+    chooses among: twice the share's expectation ``k * tokens * n_experts /
+    router_width``, rounded up to the grouped matmul's row tile, and never
+    more than the ``k * tokens`` assignments there are.
+
+    Twice: the held count is a sum over thousands of choices, so a router
+    that spreads its load stays within a few per cent of the expectation,
+    and the room is for one that favours the experts held here. More room
+    moves that many more rows in EVERY block (the form costs what it moves:
+    PR 51's layer timings in ``bounds_held_rows``), while a block that passes
+    the bound costs one further pass and loses nothing (``_bounded_rows``).
+    The prefill program counts those blocks
+    (``engine.timing["prefill_held_bound_overflows"]``)."""
+    from orion_tpu.ops.grouped_matmul import TILE_M
+
+    kt = cfg.n_experts_per_token * tokens
+    twice = -(-2 * kt * cfg.n_experts // cfg.resolved_router_width)
+    return min(-(-twice // TILE_M) * TILE_M, kt)
+
+
+def bounds_held_rows(cfg: ModelConfig, tokens: int) -> bool:
+    """The rule by which ``moe_mlp_grouped`` moves ``held_row_bound`` rows a
+    pass and not all ``k * tokens``: the layer holds a share of its experts
+    and the bound is at most a QUARTER of the assignments, both known at
+    trace time. Measured on the v5e, one layer's dispatch and experts, the
+    bound against the whole (builder, PR 51; ``tools/moe_dispatch_bench.py
+    --prefill`` draws it again): at an eighth 23.6 -> 8.2 ms, at a half 10.3
+    -> 11.6 ms, at the whole 12.4 -> 14.2 ms. The bounded form pays a
+    scatter-add into float32 token rows where the whole pays an un-sort and
+    a product over k, so it wins only where it moves far fewer rows. A layer
+    that holds every expert has nothing to bound."""
+    return (cfg.holds_expert_share
+            and 4 * held_row_bound(cfg, tokens)
+            <= cfg.n_experts_per_token * tokens)
+
+
+@jax.custom_vjp
+def _forward_only(y: jax.Array) -> jax.Array:
+    """The identity, with a reverse mode that says why there is none."""
+    return y
+
+
+def _forward_only_fwd(y):
+    raise NotImplementedError(
+        "moe_mlp_grouped bounds the rows of a layer that holds a small "
+        "share of its experts (moe.bounds_held_rows) and computes what "
+        "passes the bound under lax.while_loop, which has no reverse mode: "
+        "a held share is a serving layout (model.router_width > "
+        "model.n_experts); differentiate a model that holds every expert")
+
+
+_forward_only.defvjp(_forward_only_fwd, lambda *_: None)
+
+
+def _bounded_rows(x2, order, group_sizes, gate, R: int, experts):
+    """The grouped dispatch of ``x2`` [T, D] over the first rows of the
+    sorted assignments alone, ``R`` (``held_row_bound``) at a time: the
+    held assignments sort first, so one pass of R rows takes them all
+    wherever they are no more than R, and the gather, the expert matmuls
+    (``experts(rows, sizes)``) and the select are [R, .] and never
+    [k T, .]. The combine adds each gated result row onto its token (a
+    scatter-add over ``order // k`` in float32, what the whole form's
+    product over k accumulates in). Returns y [T, D] float32.
+
+    Nothing is dropped: the first pass is traced in line, and while held
+    rows remain behind it (a skewed router) further passes of R rows run
+    under ``lax.while_loop``, each over its own window of the same sort, an
+    expert's group cut at the window's ends. A row's result does not depend
+    on which rows share its matmul, so the passes add up to what the whole
+    computes.
+
+    ``lax.while_loop`` has no reverse mode, and a held share exists only
+    in serving (``moe_dispatch`` refuses it under ``ep``): ``jax.grad``
+    through a bounded layer raises ``_forward_only``'s sentence."""
+    T, D = x2.shape
+    k = order.shape[0] // T
+    with jax.named_scope("dispatch"):
+        ends = jnp.cumsum(group_sizes)
+        n_held = ends[-1]
+        order = jnp.pad(order, (0, -order.shape[0] % R))
+        gate = gate.reshape(T * k).astype(x2.dtype)
+
+    def one(lo, y):
+        with jax.named_scope("dispatch"):
+            rows = lax.dynamic_slice(order, (lo,), (R,))
+            tok = rows // k
+            sizes = (jnp.clip(ends, lo, lo + R)
+                     - jnp.clip(ends - group_sizes, lo, lo + R))
+            xs = x2[tok]                                     # [R, D]
+        out = experts(xs, sizes)                             # [R, D]
+        with jax.named_scope("dispatch"):
+            # Rows behind the last group are never computed (the kernel
+            # leaves them uninitialised): the select keeps them out.
+            live = (lo + jnp.arange(R) < n_held)[:, None]
+            z = jnp.where(live, out, 0).astype(jnp.float32) * (
+                gate[rows].astype(jnp.float32)[:, None])
+            return y.at[tok].add(z)
+
+    y = one(0, jnp.zeros((T, D), jnp.float32))
+    _, y = lax.while_loop(
+        lambda c: c[0] < n_held,
+        lambda c: (c[0] + R, one(c[0], c[1])), (jnp.int32(R), y))
+    return _forward_only(y)
+
+
 def moe_mlp_grouped(
     x: jax.Array,
     params: dict[str, Any],
@@ -561,6 +669,12 @@ def moe_mlp_grouped(
     layer's index) lets the matmuls read the expert matrices out of the
     stack in place (``ops.grouped_matmul``'s ``layer``); ``params`` then
     serves the router, and any matrix the stack holds in another dtype.
+
+    Where the layer holds a small share of its experts (``bounds_held_rows``)
+    only the share's rows are gathered, multiplied and combined
+    (``_bounded_rows``): the rest of the k*T sort behind them under key E
+    and were never multiplied, yet the whole form gathers, selects and
+    un-sorts them too.
     """
     from orion_tpu.ops.grouped_matmul import grouped_matmul
 
@@ -585,6 +699,31 @@ def moe_mlp_grouped(
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(E, dtype=key.dtype), axis=0,
             dtype=jnp.int32)                                 # [E]
+
+    def gmm(a, name, sizes, contract_tp=False):
+        w, layer = params[name], None
+        if layer_stack is not None and layer_stack[0][name].dtype == a.dtype:
+            w, layer = layer_stack[0][name], layer_stack[1]
+        return grouped_matmul(a, w, sizes, impl=cfg.kernels, mesh=mesh,
+                              contract_tp=contract_tp, layer=layer)
+
+    @jax.named_scope("experts")
+    def experts(xs, sizes):
+        h_in = gmm(xs, "w_in", sizes)
+        if cfg.is_gated_mlp:
+            from orion_tpu.models.transformer import _gate_act
+
+            h = _gate_act(cfg)(gmm(xs, "w_gate", sizes)) * h_in
+        else:
+            h = jax.nn.gelu(h_in)
+        return gmm(h, "w_out", sizes, contract_tp=True)
+
+    if bounds_held_rows(cfg, T):
+        y = _bounded_rows(x.reshape(T, D), order, group_sizes, gate,
+                          held_row_bound(cfg, T), experts)
+        return (y.astype(x.dtype).reshape(B, S, D),
+                _aux_loss(probs, idx, cfg).astype(jnp.float32))
+    with jax.named_scope("dispatch"):
         xs = x.reshape(T, D)[order // k]                     # [kT, D]
         if partial:
             # Rows behind the last group are never computed (the kernel
@@ -592,59 +731,13 @@ def moe_mlp_grouped(
             # of the result and out of the cotangents.
             live = (jnp.arange(T * k) < group_sizes.sum())[:, None]
             xs = jnp.where(live, xs, 0)
-
-    def gmm(a, name, contract_tp=False):
-        w, layer = params[name], None
-        if layer_stack is not None and layer_stack[0][name].dtype == a.dtype:
-            w, layer = layer_stack[0][name], layer_stack[1]
-        return grouped_matmul(a, w, group_sizes, impl=cfg.kernels, mesh=mesh,
-                              contract_tp=contract_tp, layer=layer)
-
-    with jax.named_scope("experts"):
-        h_in = gmm(xs, "w_in")
-        if cfg.is_gated_mlp:
-            from orion_tpu.models.transformer import _gate_act
-
-            h = _gate_act(cfg)(gmm(xs, "w_gate")) * h_in
-        else:
-            h = jax.nn.gelu(h_in)
-        out = gmm(h, "w_out", contract_tp=True)              # [kT, D]
+    out = experts(xs, group_sizes)                           # [kT, D]
     with jax.named_scope("dispatch"):
         if partial:
             out = jnp.where(live, out, 0)
         out = out[jnp.argsort(order)].reshape(T, k, D)       # un-sort
         y = jnp.einsum("tkd,tk->td", out, gate.astype(x.dtype))
     return y.reshape(B, S, D), _aux_loss(probs, idx, cfg).astype(jnp.float32)
-
-
-# The sorted rows of ONE grouped dispatch ([k x tokens, D], and as many
-# again of its result) stay under this: a longer block is dispatched in equal
-# parts, one behind the other. A row's result does not depend on which rows
-# share its matmul, so the parts compute what the whole would; 16384 tokens
-# x 8 picks x 4096 wide are 1 GiB of rows (and three more arrays of their
-# size around the matmuls), which beside 13.4 GB of weights and cache does
-# not fit; the largest block of any other configuration is 320 MiB.
-GROUPED_ROWS_BYTES = 384 * 2 ** 20
-
-
-def _grouped_in_parts(x, params, cfg: ModelConfig, valid, mesh, layer_stack):
-    """``moe_mlp_grouped`` over the block, whole where its sorted rows stay
-    within ``GROUPED_ROWS_BYTES`` and else in the fewest equal parts of the
-    sequence that do."""
-    B, S, D = x.shape
-    rows = cfg.n_experts_per_token * B * S * D * x.dtype.itemsize
-    parts = next((p for p in range(1, S + 1)
-                  if S % p == 0 and rows <= p * GROUPED_ROWS_BYTES), 1)
-    if parts == 1:
-        return moe_mlp_grouped(x, params, cfg, valid, mesh, layer_stack)
-    cut = lambda a: None if a is None else jnp.moveaxis(
-        a.reshape(B, parts, S // parts, *a.shape[2:]), 1, 0)
-
-    y, aux = jax.lax.map(
-        lambda xv: moe_mlp_grouped(
-            xv[0], params, cfg, xv[1], mesh, layer_stack),
-        (cut(x), cut(valid)))
-    return jnp.moveaxis(y, 0, 1).reshape(B, S, D), aux.mean()
 
 
 def moe_dispatch(
@@ -670,7 +763,7 @@ def moe_dispatch(
             "> model.n_experts) is computed by moe_dispatch=sorted with no "
             "ep axis: the share IS this device's part of the experts")
     if takes_grouped_path(cfg, x.shape[0], x.shape[1], mesh):
-        y, aux = _grouped_in_parts(x, params, cfg, valid, mesh, layer_stack)
+        y, aux = moe_mlp_grouped(x, params, cfg, valid, mesh, layer_stack)
     elif mode == "einsum":
         y, aux = moe_mlp(x, params, cfg)
     elif mode == "sorted_a2a" and mesh is not None:

@@ -321,7 +321,7 @@ def test_the_sixteen_shares_of_an_expert_layer_add_up_to_the_whole_layer(
     twice), adds up to what the uncut reference gives for the whole layer;
     a share equals the reference's own cut. Both dispatches."""
     if grouped:      # the rule's tile term keeps tiny blocks off this path
-        monkeypatch.setattr("orion_tpu.ops.grouped_matmul.TILE_M", 0)
+        monkeypatch.setattr("orion_tpu.ops.grouped_matmul.TILE_M", 1)
     cfg, params = tiny
     m, ref = cfg.model, _reference()
     bp = jax.tree.map(lambda a: a[0], params["blocks"]["period"]["1"])
@@ -417,30 +417,3 @@ def test_what_a_ring_cannot_be_served_with_is_refused_by_name(
 
     with pytest.raises(ValueError, match=rf"ring a request.*unset.*{named}"):
         InferenceEngine(get_config("tiny-mimo", [override]), tiny[1])
-
-
-@pytest.mark.parametrize("masked", [False, True])
-def test_a_grouped_dispatch_in_parts_computes_what_the_whole_does(
-        tiny, masked, monkeypatch):
-    """``moe._grouped_in_parts``: a block whose sorted rows pass the limit is
-    dispatched in equal parts of the sequence (a 16384-token prefill's 1 GiB
-    of rows does not fit the chip); a row's result does not depend on which
-    rows share its matmul."""
-    cfg, params = tiny
-    m = get_config("tiny-mimo", ["model.n_experts=8"]).model
-    bp = _share(jax.tree.map(
-        lambda a: a[0], params["blocks"]["period"]["0"]), 0, 8)["moe"]
-    x = jax.random.normal(jax.random.key(1), (2, 64, m.d_model))
-    valid = (jnp.arange(64)[None, :] < jnp.asarray([64, 40])[:, None]
-             if masked else None)
-    whole, _ = moe_lib.moe_mlp_grouped(x, bp, m, valid)
-    rows = m.n_experts_per_token * 2 * 64 * m.d_model * 4
-    monkeypatch.setattr(moe_lib, "GROUPED_ROWS_BYTES", rows // 4)
-    parts, _ = moe_lib._grouped_in_parts(x, bp, m, valid, None, None)
-    live = jnp.ones((2, 64), bool) if valid is None else valid
-    np.testing.assert_allclose(
-        jnp.where(live[..., None], parts, 0),
-        jnp.where(live[..., None], whole, 0), rtol=1e-5, atol=1e-6)
-    monkeypatch.setattr(moe_lib, "GROUPED_ROWS_BYTES", rows)   # one part
-    same, _ = moe_lib._grouped_in_parts(x, bp, m, valid, None, None)
-    np.testing.assert_array_equal(same, whole)

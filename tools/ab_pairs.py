@@ -15,7 +15,9 @@ time; this launcher stays off JAX), all sharing one compile cache
 that a side pays its compiles once a call. Per run: the whole output under
 ``<out>/<n>-<side>-<seed>[-trace].log``, the result line with its side in
 ``<out>/runs.jsonl``; after a traced run ``tools/step_chain_trace.py``
-reads the trace it left. At the end: per side
+reads the trace it left. A run that outlasts ``--run-timeout`` seconds (1300:
+the driver stops a run at 1200) is killed and counted as failed, rc 124. At
+the end: per side
 the median and the quartile spread of every end-to-end metric, and the
 pairs' ratios. The exit code is non-zero if a run failed or was not
 ``correct``."""
@@ -69,6 +71,7 @@ def main() -> int:
     ap.add_argument("--seed0", type=int, default=None)
     ap.add_argument("--out", required=True)
     ap.add_argument("--metric", default="serve_tokens_per_s")
+    ap.add_argument("--run-timeout", type=float, default=1300.0)
     args = ap.parse_args()
 
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -95,9 +98,15 @@ def main() -> int:
         cmd = [*bench["command"], "--workload", args.workload, "--seed",
                str(seed), "--seconds", str(seconds), "--trace", str(trace)]
         t0 = time.time()
-        proc = subprocess.run(cmd, cwd=trees[side], env=env, text=True,
-                              stdout=subprocess.PIPE,
-                              stderr=subprocess.STDOUT)
+        try:
+            proc = subprocess.run(cmd, cwd=trees[side], env=env, text=True,
+                                  stdout=subprocess.PIPE,
+                                  stderr=subprocess.STDOUT,
+                                  timeout=args.run_timeout)
+        except subprocess.TimeoutExpired as e:
+            late = e.stdout or ""
+            proc = subprocess.CompletedProcess(
+                cmd, 124, late if isinstance(late, str) else late.decode())
         tag = f"{n:02d}-{side}-{seed}" + ("-trace" if trace else "")
         text = proc.stdout
         if trace and proc.returncode == 0:

@@ -21,6 +21,18 @@ tree's ``moe_mlp_sorted``, whose bucket at one position a row is the row
 itself. The layer's time without a benchmark cell, for whoever takes the
 combine side next (ROADMAP S15 (a)); with ``--cpu`` a logic check at
 tiny-ling's shapes.
+
+``--prefill [--only mimo,ling,laguna] [--held N]`` (PR 52) times ONE sparse
+layer's grouped dispatch and experts (``moe_mlp_grouped``, forward, bf16) at
+a cell's prefill block shapes and held share: the whole form (all k x T
+sorted rows gathered, selected and un-sorted) against the bounded form
+(``moe._bounded_rows``: ``moe.held_row_bound`` rows a pass), the bounded
+form FORCED where the rule refuses it, so that the line of
+``moe.bounds_held_rows`` (a quarter) can be drawn again when a configuration
+with another share arrives: MiMo's 16 of 256 (bound k T / 8), Ling's 128 of
+512 (k T / 2), Laguna's 128 of 256 (k T: no bound at all); ``--held N``
+holds N experts instead. ms a layer and the rows a pass moves; with
+``--cpu`` a logic check at the tiny presets and no time.
 """
 import sys as _sys, pathlib as _pathlib
 _sys.path.insert(0, str(_pathlib.Path(__file__).resolve().parent.parent))
@@ -99,6 +111,83 @@ def decode_row(cpu: bool) -> int:
     return 0 if err <= (0.0 if cpu else 1e-2) else 1
 
 
+PREFILL = {   # preset, experts held, (rows, bucket) of the cell's blocks
+    "mimo": ("mimo-v2.5", 16, [(1, 2048), (1, 16384), (8, 2048)]),
+    "ling": ("ling-3.0-flash", 128, [(1, 1024), (1, 8192), (8, 1024)]),
+    "laguna": ("laguna-s-2.1", 128, [(1, 512), (1, 4096), (8, 512)]),
+}
+PREFILL_TINY = {
+    "mimo": ("tiny-mimo", 2, [(2, 128)]),
+    "ling": ("tiny-ling", 4, [(2, 256)]),
+    "laguna": ("tiny-laguna", 8, [(2, 64)]),
+}
+
+
+def _flag(name: str):
+    args = sys.argv[1:]
+    return args[args.index(name) + 1] if name in args else None
+
+
+def prefill_rows(cpu: bool) -> int:
+    only, held_n = _flag("--only"), _flag("--held")
+    rule = moe_lib.bounds_held_rows
+    worst = 0.0
+    for name, (preset, held, shapes) in (
+            PREFILL_TINY if cpu else PREFILL).items():
+        if only and name not in only.split(","):
+            continue
+        held = int(held_n) if held_n else held
+        cfg = get_config(preset, [f"model.n_experts={held}"] + (
+            ["runtime.platform=cpu"] if cpu else ["model.kernels=pallas"])
+        ).model
+        dtype = jnp.float32 if cpu else jnp.bfloat16
+        E, W = cfg.n_experts, cfg.resolved_router_width
+        D, F, k = cfg.d_model, cfg.resolved_moe_d_ff, cfg.n_experts_per_token
+        keys = jax.random.split(jax.random.key(0), 6)
+        params = {
+            "router": jax.random.normal(keys[1], (D, W), jnp.float32) * 0.3,
+            "w_in": jax.random.normal(keys[2], (E, D, F), dtype) * 0.02,
+            "w_gate": jax.random.normal(keys[3], (E, D, F), dtype) * 0.02,
+            "w_out": jax.random.normal(keys[4], (E, F, D), dtype) * 0.02,
+        }
+        if cfg.router_bias:
+            params["router_bias"] = 0.1 * jax.random.normal(keys[5], (W,))
+        for B, S in shapes:
+            x = jax.random.normal(keys[0], (B, S, D), dtype)
+            ys, ms = {}, {}
+            for form, forced in (("whole", lambda c, t: False),
+                                 ("bounded", lambda c, t: c.holds_expert_share)):
+                moe_lib.bounds_held_rows = forced
+                try:
+                    f = lambda x, p: moe_lib.moe_mlp_grouped(x, p, cfg)[0]
+                    ys[form] = jax.jit(f)(x, params).astype(jnp.float32)
+                    # A time is the chip's: the logic check reports none.
+                    ms[form] = None if cpu else round(
+                        1e3 * bench(f, (x, params), iters=10, warmup=2), 3)
+                finally:
+                    moe_lib.bounds_held_rows = rule
+            err = float(jnp.max(jnp.abs(ys["bounded"] - ys["whole"])))
+            worst = max(worst, err / float(jnp.max(jnp.abs(ys["whole"]))))
+            print(json.dumps({
+                "summary": "moe_prefill_dispatch", "cell": name,
+                "device": jax.devices()[0].device_kind,
+                "shape": {"B": B, "S": S, "D": D, "F": F, "held": E,
+                          "router_width": W, "k": k,
+                          "dtype": jnp.dtype(dtype).name},
+                "rows_whole": k * B * S,
+                "rows_bounded": moe_lib.held_row_bound(cfg, B * S),
+                "rows_held": int(moe_lib.held_rows(
+                    x, params["router"], cfg, None,
+                    params.get("router_bias"))),
+                "rule_admits": bool(rule(cfg, B * S)),
+                "whole_ms_per_layer": ms["whole"],
+                "bounded_ms_per_layer": ms["bounded"],
+                "max_abs_diff": err,
+            }), flush=True)
+    # One step of bfloat16 where the two forms sum over k in another order.
+    return 0 if worst <= (1e-5 if cpu else 2 ** -7) else 1
+
+
 def main() -> int:
     cpu = "--cpu" in sys.argv[1:]
     if cpu:
@@ -109,6 +198,8 @@ def main() -> int:
         return 1
     if "--decode" in sys.argv[1:]:
         return decode_row(cpu)
+    if "--prefill" in sys.argv[1:]:
+        return prefill_rows(cpu)
     if cpu:
         B, S, D, F = 2, 128, 64, 256
         cfg = get_config("tiny-mixtral", ["runtime.platform=cpu"]).model
