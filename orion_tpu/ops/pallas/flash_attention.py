@@ -25,6 +25,10 @@ Design (SURVEY.md §8 hard-part #1):
   ``seg_pad_zero`` liveness is data: the range is the static superset (the
   whole row under positions) and a ``pl.when`` test on the block's positions
   / segment ids skips inside it. ``block_counts`` reports the visited set.
+- Under a window much narrower than a block the wrapper hands the same kernel
+  a BAND (PR 53, ``_band_chunk`` / ``_fold_bands``): query chunks folded into
+  the batch, each with its own keys behind the ``window`` before them, so that
+  a chunk is one grid step with no carried softmax state.
 - Every visited block is masked, also the 18 of 30 at the train shape that no
   edge crosses: the mask on token index is three compares on one iota
   difference, and skipping it costs more than it saves (under a ``lax.cond``
@@ -730,19 +734,34 @@ def _prep(
 ):
     """Shared wrapper prep: statics + [B,N,S,H] transpose + block padding.
 
-    block_q/block_kv default to large (1024) tiles, whatever the window and
-    the head count: a grid step costs the forward about a microsecond of
-    per-row bookkeeping (running max, rescale of the accumulator) whatever
-    its width, so a narrow kv block loses more than its tighter fit to the
-    mask saves. The sweep (tools/flash_sweep.py on a v5e, PERF.md §6 PR 32):
-    at the train shape (8192 under window 4096) forward / dq / dkv take 4.3 /
-    5.4 / 6.5 ms a call at 1024 x 1024, 5.4 / 5.5 / 6.7 at 512 x 512 and
-    11.8 / 8.9 / 10.5 at 256 x 256, though those visit 30, 27 and 25.5 M
-    pairs; with no window at 4096 tokens 1024 x 1024 takes 2.4 ms and
-    512 x 512 3.8. The one shape where 512 wins is Laguna's window 512 at
-    4096 tokens (15 blocks of half the pairs: 2.41 ms against 2.65), not
-    enough of a prefill program to earn a rule. 2048-wide blocks overrun
-    VMEM.
+    block_q/block_kv default to large (1024) tiles, whatever the head count
+    and under any window wider than ``BAND_MAX_WINDOW``: a grid step
+    costs the forward about a microsecond of per-row bookkeeping (running
+    max, rescale of the accumulator) whatever its width, so a narrow kv
+    block loses more than its tighter fit to the mask saves. The sweep
+    (tools/flash_sweep.py on a v5e, PERF.md §6 PR 32): at the train shape
+    (8192 under window 4096) forward / dq / dkv take 4.3 / 5.4 / 6.5 ms a
+    call at 1024 x 1024, 5.4 / 5.5 / 6.7 at 512 x 512 and 11.8 / 8.9 / 10.5
+    at 256 x 256, though those visit 30, 27 and 25.5 M pairs; with no window
+    at 4096 tokens 1024 x 1024 takes 2.4 ms and 512 x 512 3.8. The one shape
+    where 512 wins is Laguna's window 512 at 4096 tokens (15 blocks of half
+    the pairs: 2.41 ms against 2.65), not enough of a prefill program to
+    earn a rule. 2048-wide blocks overrun VMEM.
+
+    Under a window of 128 no block size of this walk is good, which is why
+    such a call is folded into bands before it gets here (``_band_chunk``).
+    The forward alone at MiMo's window layer (a sink, 64 / 8 heads, keys 192
+    padded to 256, values 128, ragged rows; tools/flash_sweep.py ``--only
+    mimo`` on a v5e, PERF.md §6 PR 53), ms a call:
+
+        rows x length   1024^2  512^2  256^2 | band C=256  C=512  C=1024
+        1 x 16384        12.37   9.37  11.31 |       5.96   5.39    6.56
+        2 x 8192         11.54   8.87  10.97 |       5.68   5.10    6.25
+        8 x 2048          9.56   7.59   9.53 |       5.10   4.67    6.27
+
+    A chunk of C rows against its C + 128 keys is one grid step with no
+    carried softmax state; 512 is ahead at every shape (256 pays twice the
+    steps, 1024 a 1024 x 1152 block of which a ninth attends).
     """
     assert (q_segment_ids is None) == (kv_segment_ids is None)
     assert (q_positions is None) == (kv_positions is None)
@@ -808,6 +827,62 @@ def _prep(
     return st, qt, kt, vt, qseg, kseg, qpos, kpos, Sq
 
 
+# A window of at most a quarter of the default block is attended as a band:
+# there the two 1024-wide blocks a query block visits keep under an eighth of
+# their pairs. Chunks of BAND_CHUNK query rows: the table in ``_prep``'s
+# docstring (tools/flash_sweep.py's group ``mimo-window`` on a v5e).
+BAND_MAX_WINDOW = 256
+BAND_CHUNK = 512
+
+
+def _band_chunk(causal, window, q_offset, Sq, Skv, has_pos, has_seg,
+                seg_pad_zero, blocks) -> Optional[int]:
+    """Query rows a chunk where the call is attended as a band, else None.
+
+    On what the call itself says, at trace time: a causal window on token
+    index, narrow (``BAND_MAX_WINDOW``), over q and kv of one length that is
+    more than one chunk, at offset 0 and the default blocks. Segment ids,
+    where given, hold 0 for padding (the band's front rows carry it).
+    """
+    narrow = causal and window is not None and window <= BAND_MAX_WINDOW
+    plain = not has_pos and q_offset == 0 and blocks == (None, None)
+    if (narrow and plain and Sq == Skv and Sq > BAND_CHUNK
+            and (seg_pad_zero or not has_seg)):
+        return BAND_CHUNK
+    return None
+
+
+def _fold_bands(q, k, v, q_seg, kv_seg, window: int, chunk: int):
+    """(q, k, v, q_seg, kv_seg) of the band form: q ``[B, S, N, H]`` as
+    ``[B n, chunk, N, H]`` (S padded to n chunks), k and v as the
+    overlapping bands ``[B n, window + chunk, K, H]``: a chunk's own rows
+    behind the ``window`` rows before them, zeros of segment 0 before the
+    first. Without segment ids every real position is segment 1.
+
+    With ``q_offset=window`` row r of a chunk sits at band column
+    ``window + r``, so the causal window ``0 <= (r + window) - c < window``
+    keeps exactly the positions the unfolded call keeps; one block of
+    ``chunk x (window + chunk)`` holds them all.
+    """
+    B, S = q.shape[:2]
+    n = -(-S // chunk)
+    if q_seg is None:
+        q_seg = kv_seg = jnp.ones((B, S), jnp.int32)
+
+    def rows(x):
+        return pad_axis(x, 1, n * chunk).reshape(B * n, chunk, *x.shape[2:])
+
+    def bands(x):
+        x = jnp.pad(x, ((0, 0), (window, n * chunk - S))
+                    + ((0, 0),) * (x.ndim - 2))
+        own = x[:, window:].reshape(B, n, chunk, *x.shape[2:])
+        halo = x[:, :n * chunk].reshape(own.shape)[:, :, :window]
+        return jnp.concatenate([halo, own], axis=2).reshape(
+            B * n, window + chunk, *x.shape[2:])
+
+    return rows(q), bands(k), bands(v), rows(q_seg), bands(kv_seg)
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -846,7 +921,26 @@ def flash_attention(
     width is not a whole number of 128-lane tiles are padded with zeros
     under the scale of their own width. Either is the FORWARD alone: no
     backward is defined for them.
+
+    A causal window of at most ``BAND_MAX_WINDOW`` positions over more than
+    ``BAND_CHUNK`` of them is attended as a band (``_band_chunk``): the same
+    pairs under the same masks through the same kernel, one grid step a
+    chunk of query rows.
     """
+    chunk = _band_chunk(
+        causal, window, q_offset, q.shape[1], k.shape[1],
+        q_positions is not None, q_segment_ids is not None, seg_pad_zero,
+        (block_q, block_kv))
+    if chunk is not None:
+        B, S = q.shape[:2]
+        q, k, v, q_seg, kv_seg = _fold_bands(
+            q, k, v, q_segment_ids, kv_segment_ids, window, chunk)
+        o = flash_attention(
+            q, k, v, causal=True, q_segment_ids=q_seg, kv_segment_ids=kv_seg,
+            logit_softcap=logit_softcap, q_offset=window, block_q=chunk,
+            block_kv=chunk + window, interpret=interpret, window=window,
+            seg_pad_zero=True, sink=sink)
+        return o.reshape(B, -1, *o.shape[2:])[:, :S]
     H, Hv = q.shape[-1], v.shape[-1]
     fwd_only = sink is not None or Hv != H
     scale = None

@@ -1,7 +1,7 @@
 """Time the three flash attention kernels alone on the chip at the cells'
 shapes, with one cost put back at a time (PERF.md section 6, PR 32).
 
-    chiprun -- python tools/flash_sweep.py [--only train,laguna,mixtral]
+    chiprun -- python tools/flash_sweep.py [--only train,laguna,mixtral,mimo]
         [--kernels fwd,dq,dkv] [--impls tree,b512x512,dead,dead+f32]
 
 Runs on any checkout that has ``ops/pallas/flash_attention.py`` (copy it into
@@ -23,6 +23,20 @@ four. Per line: ms a call, the share of 197 TFLOP/s that the matmuls the
 kernel runs take over the pairs that attend, blocks full / visited / minimum
 (blocks with one attending pair, from the dense mask) for one head, and the
 seconds to trace and lower one instance in a fresh cache.
+
+The group ``mimo-window`` (PR 53; ``--only mimo``) is MiMo's window layer:
+window 128 under a sink, 64 / 8 heads, keys 192 and values 128 wide, rows x
+length 1 x 16384, 2 x 8192 and 8 x 2048 with a ragged burst's segment ids,
+forward only. ``b<n>x<n>`` is the plain walk at those blocks, ``band<C>`` the
+band form (``_fold_bands``) at chunks of C query rows: where
+``flash_attention.BAND_CHUNK`` comes from. ``fwd`` times the kernel alone;
+``call`` the whole wrapper from the model's ``[B, S, N, H]`` layout, with the
+transposes, the key padding to 256 lanes and the bands it builds (one program
+a call, dispatched back to back: a loop would let XLA hoist them). A program
+of the call alone takes q in the layout the device keeps a parameter in, and
+the band form pays one or two passes more over q for that than the plain walk
+(PERF.md §6 PR 53); behind the rotary kernel, as in a prefill program, both
+take the same two. So ``fwd`` settles the chunk and a traced prefill the gain.
 
 Prints one JSON line each and keeps them in ``chiprun_out/flash_sweep.jsonl``.
 Raises without a TPU."""
@@ -61,12 +75,16 @@ SHAPES = [
 ]
 BLOCKS = [(256, 256), (512, 512), (1024, 1024), (512, 1024), (1024, 512)]
 ABLATIONS = ("dead", "f32", "nomask")
+# MiMo's window layer (PR 53): keys wider than values under a sink, prefill
+# buckets of 2048, so it has a walk of its own (``sweep_mimo``).
+MIMO = dict(group="mimo-window", N=64, K=8, Hk=192, Hv=128, window=128,
+            sizes=[(1, 16384), (2, 8192), (8, 2048)], bucket=2048, reps=8)
 
 
-def burst_segments(rows: int, length: int, rng) -> np.ndarray:
-    """A padded burst: each row's prompt fills between a bucket (512) less
-    and the whole length; the rest is id 0."""
-    real = rng.integers(max(length - 511, 64), length + 1, size=rows)
+def burst_segments(rows: int, length: int, rng, bucket: int = 512) -> np.ndarray:
+    """A padded burst: each row's prompt fills between a bucket less and the
+    whole length; the rest is id 0."""
+    real = rng.integers(max(length - bucket + 1, 64), length + 1, size=rows)
     real[0] = length                       # the row that set the bucket
     return (np.arange(length)[None, :] < real[:, None]).astype(np.int32)
 
@@ -104,6 +122,8 @@ PATCHES = {
              "_steps": lambda st, rng, n_outer, n_inner: n_inner},
     "nomask": {"_block_mask": lambda *a, **k: None},
     "f32": {},
+    # the band form (PR 53) at another chunk of query rows
+    **{f"band{c}": {"BAND_CHUNK": c} for c in (256, 512, 1024)},
 }
 
 
@@ -164,7 +184,11 @@ def programs(st, arrays, reps):
     return {"fwd": (fwd, qt), "dq": (bwd("dq"), o), "dkv": (bwd("dkv"), o)}
 
 
-def timed(prog, arg, reps):
+def timed(prog, arg, reps, calls=1):
+    """(seconds a repetition, seconds to lower) of ``prog(arg)``, which holds
+    ``reps`` repetitions; best of four trials of ``calls`` dispatches back to
+    back (more than one: a whole wrapper a program, whose preparation a loop
+    would let XLA hoist out)."""
     jax.clear_caches()
     t0 = time.perf_counter()
     lowered = jax.jit(prog).lower(arg)
@@ -173,9 +197,11 @@ def timed(prog, arg, reps):
     best = float("inf")
     for _ in range(4):
         t0 = time.perf_counter()
-        jax.block_until_ready(run(arg))
+        for _ in range(calls):
+            out = run(arg)
+        jax.block_until_ready(out)
         best = min(best, time.perf_counter() - t0)
-    return best / reps, lower_s
+    return best / (reps * calls), lower_s
 
 
 def emit(sink, row):
@@ -185,10 +211,7 @@ def emit(sink, row):
     sink.flush()
 
 
-def sweep(dev, sink):
-    only = None
-    if "--only" in sys.argv:
-        only = tuple(sys.argv[sys.argv.index("--only") + 1].split(","))
+def sweep(dev, sink, only):
     new = hasattr(fa, "block_counts")
     keys = jax.random.split(jax.random.key(32), 3)
     for gi, (group, N, window, sizes, kernels, reps) in enumerate(SHAPES):
@@ -248,13 +271,110 @@ def sweep(dev, sink):
                                     "device": dev.device_kind})
 
 
+def kernel_operands(call, args):
+    """(statics, operands) that the wrapper hands ``_fwd_call`` for
+    ``call(args)``: the call is run eagerly with the kernel's entry swapped
+    for a recorder, so the sweep repeats none of the wrapper's preparation."""
+    seen = []
+
+    def record(st, q, k, v, *rest):
+        seen.append((st, (q, k, v, *rest)))
+        return jnp.zeros((*q.shape[:3], v.shape[3]), q.dtype)
+
+    kept, fa._flash_forward_only = fa._flash_forward_only, record
+    try:
+        call(args)
+    finally:
+        fa._flash_forward_only = kept
+    return seen[0]
+
+
+def sweep_mimo(dev, sink):
+    """The plain walk at three block sizes against the band form at three
+    chunk sizes, kernel alone (``fwd``) and whole wrapper (``call``)."""
+    m = MIMO
+    N, W, reps = m["N"], m["window"], m["reps"]
+    keys = jax.random.split(jax.random.key(53), 4)
+    impls = [("tree", None)]
+    impls += [(f"b{b}x{b}", (b, b)) for b in (1024, 512, 256)]
+    if hasattr(fa, "BAND_CHUNK"):
+        impls += [(f"band{c}", None) for c in (256, 512, 1024)]
+    if "--impls" in sys.argv:
+        named = sys.argv[sys.argv.index("--impls") + 1].split(",")
+        impls = [i for i in impls if i[0] in named]
+    for rows, S in m["sizes"]:
+        rng = np.random.default_rng([53, rows])
+        seg_np = burst_segments(rows, S, rng, m["bucket"])
+        n = seg_np.sum(axis=1)
+        # the pairs the window keeps of each row's real positions
+        pairs = float((W * (W + 1) // 2 + (n - W) * W).sum()) * N
+        flops = pairs * (m["Hk"] + m["Hv"]) * 2
+        seg = jnp.asarray(seg_np)
+        q = jax.random.normal(keys[0], (rows, S, N, m["Hk"]), jnp.bfloat16)
+        k = jax.random.normal(keys[1], (rows, S, m["K"], m["Hk"]), jnp.bfloat16)
+        v = jax.random.normal(keys[2], (rows, S, m["K"], m["Hv"]), jnp.bfloat16)
+        b = jax.random.uniform(keys[3], (N,), jnp.float32, 0.0, 4.0)
+        for name, blocks in impls:
+            bq, bk = blocks or (None, None)
+
+            def call(args):
+                q, k, v, seg, b = args
+                return fa.flash_attention(
+                    q, k, v, window=W, q_segment_ids=seg, kv_segment_ids=seg,
+                    seg_pad_zero=True, sink=b, block_q=bq, block_kv=bk)
+
+            with ablation([name] if name in PATCHES else []):
+                st, ops = kernel_operands(call, (q, k, v, seg, b))
+                folds = ops[0].shape[0] // rows
+                counts = fa.block_counts(
+                    st, ops[0].shape[2] // st.block_q,
+                    ops[1].shape[2] // st.block_kv, True)
+                row = {"tree": TAG, "shape": m["group"], "rows": rows,
+                       "len": S, "heads": N, "window": W, "impl": name,
+                       "blocks": [st.block_q, st.block_kv],
+                       "steps": counts["steps"] * folds,
+                       "pairs_visited_a_position": round(
+                           counts["visited"] * folds * st.block_q
+                           * st.block_kv / S, 1)}
+
+                def alone(ops):
+                    # the sink rows carry the loop: q and o differ in width
+                    def body(_, sink_rows):
+                        o = fa._fwd_call(st, *ops[:-1], sink_rows)[0]
+                        return sink_rows + o[0, 0, 0, 0].astype(
+                            sink_rows.dtype) * 0
+                    return lax.fori_loop(0, reps, body, ops[-1])
+
+                for kern, run in (
+                        ("fwd", lambda: timed(alone, ops, reps)),
+                        ("call", lambda: timed(
+                            call, (q, k, v, seg, b), 1, calls=reps))):
+                    try:
+                        sec, lower_s = run()
+                    except Exception as e:   # a shape Mosaic refuses
+                        emit(sink, {**row, "kernel": kern, "error":
+                                    str(e).splitlines()[0][:200]})
+                        continue
+                    emit(sink, {**row, "kernel": kern,
+                                "ms": round(1e3 * sec, 4),
+                                "mxu_pct": round(
+                                    100 * flops / PEAK_FLOPS / sec, 2),
+                                "lower_s": round(lower_s, 3),
+                                "device": dev.device_kind})
+
+
 def main():
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise SystemExit(f"needs a TPU, found {dev.platform}")
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
+    only = None
+    if "--only" in sys.argv:
+        only = tuple(sys.argv[sys.argv.index("--only") + 1].split(","))
     with open(OUT, "a") as sink:
-        sweep(dev, sink)
+        sweep(dev, sink, only)
+        if not only or MIMO["group"].startswith(only):
+            sweep_mimo(dev, sink)
 
 
 if __name__ == "__main__":
