@@ -148,7 +148,8 @@ _DISPATCH_SCOPE = {
     "orion_tpu/infer/runner.py": None,
     "orion_tpu/infer/executor.py": None,
     "orion_tpu/infer/engine.py": (
-        "step", "_decode", "_mixed", "_verify", "_prefill", "_propose",
+        "step", "_decode", "_denoise", "_mixed", "_verify", "_prefill",
+        "_propose",
         "_accept", "_run_dispatch", "_grow_pages", "_roll_window",
         # The other half of a launched prefill (ISSUE 40): its wait and
         # the one fetch of its picks.

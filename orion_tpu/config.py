@@ -298,6 +298,15 @@ class ModelConfig:
     router_width: Optional[int] = None
     expert_offset: int = 0
 
+    # Generation by diffusion over blocks (0 => autoregressive, every other
+    # model): position i sees position j iff j // block_length <= i //
+    # block_length (a block's positions see each other and every earlier
+    # block), the logits at a position are over the token AT it, and an
+    # undecided position is fed as ``mask_token_id``. The engine generates
+    # a block a dispatch (runner.denoise_block; inference.denoising_steps).
+    block_length: int = 0
+    mask_token_id: int = 0
+
     # Numerics.
     dtype: str = "bfloat16"         # activation / weight compute dtype
     param_dtype: str = "float32"    # master parameter dtype
@@ -411,6 +420,18 @@ class ModelConfig:
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"model.router_score={self.router_score!r}; softmax|sigmoid")
+        if self.block_length is None or not 0 <= self.block_length <= 16 or (
+                self.block_length & (self.block_length - 1)):
+            raise ValueError(
+                f"model.block_length={self.block_length} must be 0 or a "
+                f"power of two up to 16 (a block never straddles a kernel's "
+                f"tile, and its positions are one W-query dispatch under "
+                f"ancestor words of 31 bits)")
+        if self.block_length and not (
+                0 <= self.mask_token_id < self.vocab_size):
+            raise ValueError(
+                f"model.mask_token_id={self.mask_token_id} must lie inside "
+                f"the vocabulary of {self.vocab_size}")
         if self.attn_sink not in (None, "sliding"):
             raise ValueError(
                 f"model.attn_sink={self.attn_sink!r}; sliding|None")
@@ -1385,6 +1406,21 @@ class InferenceConfig:
     # process-wide memo keeps. Repeated schemas across requests hit the
     # cache and pay zero compile.
     constraint_cache: int = 32
+    # Generation by diffusion over blocks (a model with model.block_length;
+    # read by no other): a block's positions are decided over
+    # ``denoising_steps`` forwards, each deciding the positions the sampler
+    # is surest of, by ``remasking``: "low_confidence_static" decides an
+    # even share of the block a forward (block_length / denoising_steps,
+    # the remainder to the first forwards), "low_confidence_dynamic" every
+    # position whose drawn token's probability passes
+    # ``confidence_threshold``, and the static share where those are fewer.
+    # Engine-wide: one schedule for every request. The defaults are the
+    # schedule the benchmark's cell runs (PERF.md section 4); the dynamic
+    # rule has no chip number behind it until trained weights give a
+    # threshold something to pass.
+    denoising_steps: int = 2
+    remasking: str = "low_confidence_static"
+    confidence_threshold: float = 0.9
 
     def __post_init__(self):
         # Domain checks only (each field alone), matching ModelConfig's
@@ -1405,6 +1441,15 @@ class InferenceConfig:
                 f"inference.spec_tree_width={self.spec_tree_width} must "
                 f"be >= 1 (1 = chain drafting)"
             )
+        if self.denoising_steps is None or self.denoising_steps < 1:
+            raise ValueError(
+                f"inference.denoising_steps={self.denoising_steps} must "
+                f"be >= 1")
+        if self.remasking not in (
+                "low_confidence_static", "low_confidence_dynamic"):
+            raise ValueError(
+                f"inference.remasking={self.remasking!r}; "
+                f"low_confidence_static|low_confidence_dynamic")
         if self.spec_fault_limit is None or self.spec_fault_limit < 1:
             raise ValueError(
                 f"inference.spec_fault_limit={self.spec_fault_limit} "
@@ -2342,6 +2387,61 @@ def _p_tiny_mimo() -> Config:
         inference=InferenceConfig(max_seq_len=128, page_size=8,
                                   num_pages=64, max_batch_size=4,
                                   prefill_chunk=16, decode_window=4),
+    )
+
+
+def _sdar_model(**kw) -> ModelConfig:
+    """SDAR-30B-A3B-Chat (JetLM, config.json, model_type sdar_moe): the
+    Qwen3-MoE layer (48 alike: 32 query heads over 4 K/V heads of 128 with
+    a norm a head, 128 experts 768 wide, top-8 of a softmax renormalised
+    over the picks, no shared expert) generated by diffusion over blocks
+    of 4 under a block-causal mask; an undecided position is fed as
+    <|MASK|> (151669)."""
+    base = dict(
+        name="sdar-30b-a3b", vocab_size=151_936, max_seq_len=32_768,
+        d_model=2048, n_layers=48, n_heads=32, n_kv_heads=4, head_dim=128,
+        d_ff=6144, pos_embedding="rope", rope_theta=1_000_000.0,
+        norm="rmsnorm", norm_eps=1e-6, activation="swiglu",
+        tie_embeddings=False, qk_norm=True,
+        n_experts=128, n_experts_per_token=8, moe_d_ff=768,
+        # Dropless: an expert's bucket holds a whole row from n_experts /
+        # top-k = 16 up (the published model drops none).
+        capacity_factor=16.0,
+        block_length=4, mask_token_id=151_669,
+        dtype="bfloat16", param_dtype="bfloat16", kernels="pallas",
+        remat="full",
+    )
+    base.update(kw)
+    return ModelConfig(**base)
+
+
+@register_preset("sdar-30b-a3b")
+def _p_sdar() -> Config:
+    """SDAR-30B-A3B-Chat at its published sizes, for serving (a deployment
+    holds the depth its chip holds)."""
+    return Config(
+        model=_sdar_model(),
+        inference=InferenceConfig(max_seq_len=5120, page_size=64),
+    )
+
+
+@register_preset("tiny-sdar")
+def _p_tiny_sdar() -> Config:
+    """Tiny SDAR-family model for CPU tests: 2 layers, 4 query heads over 2
+    K/V heads of 16, 8 experts top-2; blocks of 4 under pages of 8, so a
+    block starts on and off a page boundary."""
+    return Config(
+        model=_sdar_model(
+            name="tiny-sdar", vocab_size=256, max_seq_len=128, d_model=64,
+            n_layers=2, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
+            n_experts=8, n_experts_per_token=2, moe_d_ff=32,
+            capacity_factor=4.0, mask_token_id=255,
+            dtype="float32", param_dtype="float32", kernels="xla",
+            remat="none"),
+        data=DataConfig(batch_size=4, seq_len=64),
+        inference=InferenceConfig(max_seq_len=128, page_size=8,
+                                  num_pages=64, max_batch_size=4,
+                                  prefill_chunk=16),
     )
 
 

@@ -215,12 +215,34 @@ class InferenceEngine:
                 "keeps its window layers' K and V in a ring a request "
                 "(model.n_kv_heads_sliding)")
             refused += kv_only
+        # Generation by diffusion over blocks: a block's denoising forwards
+        # write rows beyond the cursor that only the block program may read
+        # (no shared, spilled or migrated page may hold them), a prompt's
+        # tail enters its first block (no chunk to resume from), the
+        # sampler ranks positions inside the program (no draft to verify,
+        # no mask a position), and neither int8 form has been held to the
+        # reference under the block mask.
+        blocks = bool(self.mcfg.block_length)
+        if blocks:
+            why.append(
+                "generates by diffusion over blocks (model.block_length)")
+            refused += kv_only
         off = list(dict.fromkeys(name for name, on in refused if on))
         if off:
             raise ValueError(
                 f"model {self.mcfg.name!r} {' and '.join(why)} and is "
-                f"served by whole-prompt prefill and the decode window "
+                f"served by whole-prompt prefill and the "
+                f"{'block program' if blocks else 'decode window'} "
                 f"only: unset {', '.join(off)}")
+        if blocks and (
+                self.icfg.max_seq_len % self.mcfg.block_length
+                or self.icfg.page_size % self.mcfg.block_length):
+            raise ValueError(
+                f"inference.max_seq_len={self.icfg.max_seq_len} and "
+                f"inference.page_size={self.icfg.page_size} must be "
+                f"multiples of model.block_length="
+                f"{self.mcfg.block_length}: a block never straddles a page "
+                f"or the context's end")
         if self.mcfg.weight_quant == "int8":
             from orion_tpu.models.quantize import quantize_params
 
@@ -289,6 +311,11 @@ class InferenceEngine:
             raise ValueError(
                 "a latent-attention model is served on one device: its "
                 "decode kernel is not run per shard yet")
+        if self.mesh is not None and blocks:
+            raise ValueError(
+                f"model {self.mcfg.name!r} generates by diffusion over "
+                f"blocks (model.block_length) and is served on one device: "
+                f"the block program has not been run over a mesh yet")
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -459,6 +486,11 @@ class InferenceEngine:
         # Decode window: the configured value for the engine's life. Page
         # provisioning and admission budget for _provision_window.
         self.decode_window = self.icfg.decode_window
+        if blocks:
+            # A dispatch advances a slot by one block: what page
+            # provisioning and admission budget for, and what the engine
+            # reports as its window.
+            self.decode_window = self.mcfg.block_length
         # Lazy chunk provisioning (the over-pool admission path): only
         # meaningful with a sliding window — a full-attention chunk reads
         # its WHOLE history from the pool, so its device working set is
@@ -567,6 +599,11 @@ class InferenceEngine:
             "decode_defaults", self.mcfg, self.mesh
         )
         self._prefill = self._jit_program("prefill", self.mcfg, self.mesh)
+        if blocks:
+            self._denoise = self._jit_program(
+                "denoise", self.mcfg, self.mesh)
+            self._denoise_defaults = self._jit_program(
+                "denoise_defaults", self.mcfg, self.mesh)
         if self._chunk is not None:
             self._fold = self._jit_program("fold", self.mcfg, self.mesh)
         self._mixed = self._jit_program("mixed", self.mcfg, self.mesh)
@@ -1559,6 +1596,24 @@ class InferenceEngine:
             "decode_kv_pages_read_full": 0, "decode_kv_pages_read_ring": 0,
             "decode_kv_token_layers_full": 0, "decode_kv_token_layers_ring": 0,
             "window_ring_wraps": 0,
+            # A model that generates by diffusion over blocks (all 0 for
+            # any other): block programs dispatched and the forwards inside
+            # them (denoising and commit); live slots x forwards
+            # (block_slot_forwards), the positions those fed
+            # (block_positions_fed) and of them the ones fed as the mask
+            # token, from the program's own record of the forward that
+            # decided each (block_positions_undecided_fed); tokens emitted
+            # (tokens_committed) and tokens of a committed block beyond
+            # max_new_tokens or an EOS (tokens_discarded); first blocks
+            # that carried a prompt's tail; and, summed over slots and
+            # forwards, the cached positions a forward's attention read,
+            # the block's own among them (block_kv_positions_read). Host
+            # arithmetic on lengths, no device value read.
+            "denoise_dispatches": 0, "denoise_forwards": 0,
+            "commit_forwards": 0, "block_slot_forwards": 0,
+            "block_positions_fed": 0, "block_positions_undecided_fed": 0,
+            "tokens_committed": 0, "tokens_discarded": 0,
+            "blocks_with_prompt_tail": 0, "block_kv_positions_read": 0,
             # Per-phase device split (ISSUE 20 load-gauge satellite):
             # decode_device_s covers pure decode-phase dispatches
             # (decode windows, verify, draft compaction) and pairs with
@@ -2630,6 +2685,11 @@ class InferenceEngine:
                 f"model {self.mcfg.name!r} caches one compressed row a "
                 f"position (model.kv_lora_rank), which migration has not "
                 f"been run on yet")
+        if self.mcfg.block_length:
+            raise ValueError(
+                f"model {self.mcfg.name!r} generates by diffusion over "
+                f"blocks (model.block_length), which migration has not "
+                f"been run on yet")
         slot = req.slot
         return {
             "prompt": list(req.prompt),
@@ -3095,7 +3155,10 @@ class InferenceEngine:
                     # nothing to compute; reap re-donates the pages.
                     req.done = True
             else:
-                self.seq_lens[slot] = len(context)
+                # (a block model's cursor: the prompt's whole blocks)
+                self.seq_lens[slot] = len(context) - (
+                    len(context) % self.mcfg.block_length
+                    if self.mcfg.block_length else 0)
                 admitted.append((req, s_pad))
 
         # Pass 2. Chunked prefill (inference.chunked_prefill): NO eager
@@ -3192,6 +3255,11 @@ class InferenceEngine:
                 tail = req.context[npre * self.psz:]
                 tokens[i, : len(tail)] = tail
                 lengths[i] = len(tail)
+                if self.mcfg.block_length:
+                    # Whole blocks alone (the rest enters the first block as
+                    # decided positions); a prompt shorter than one block
+                    # prefills one position, written beyond the cursor.
+                    lengths[i] = max(int(self.seq_lens[req.slot]), 1)
                 pre_lens[i] = npre * self.psz
                 if npre:
                     # Dead (behind-window) matched pages point at scratch
@@ -3214,6 +3282,8 @@ class InferenceEngine:
             picked = all(self.slot_temp[r.slot] <= 0.0 for r in reqs) and not (
                 self.constrained
                 and any(r.constraint is not None for r in reqs))
+            if self.mcfg.block_length:
+                picked = True   # prefill samples nothing: no host sampler
         key = self._key
         try:
             # The uploads are part of prefill_s, as they always were.
@@ -3239,14 +3309,16 @@ class InferenceEngine:
             self._unwind_burst(reqs)
             raise
         logits, self.cache = out
-        if picked:
+        if picked and not self.mcfg.block_length:
             self._key = self._executor.key
             self.timing["prefill_picks_in_program"] += 1
         # Whose budget ends at the first token, as far as the host knows
-        # (_maybe_finish's rule less the stop token).
+        # (_maybe_finish's rule less the stop token); a block model's
+        # prefill yields no token, and ends a request that asks for none.
+        first = 0 if self.mcfg.block_length else 1
         ends = frozenset(
             r.rid for r in reqs
-            if r.max_new_tokens - len(r.generated) <= 1
+            if r.max_new_tokens - len(r.generated) <= first
             or int(self.seq_lens[r.slot]) >= self.icfg.max_seq_len)
         self._burst = _Burst(reqs, args, logits, picked, key, ends)
         real = int(lengths[: len(reqs)].sum())
@@ -3278,6 +3350,12 @@ class InferenceEngine:
                         int(n), window)
                     self.timing["prefill_attn_pairs"] += layers * (
                         w * (w + 1) // 2 + (n - w) * w)
+        if self.mcfg.block_length:
+            # The pairs the block mask keeps of a prompt's whole blocks:
+            # a row sees to the end of its block, n (n + L) / 2 a layer.
+            L = self.mcfg.block_length
+            self.timing["prefill_attn_pairs"] += self.mcfg.n_layers * sum(
+                int(n) * (int(n) + L) // 2 for n in lengths[: len(reqs)])
         if self.mcfg.has_kda:
             self.timing["prefill_kda_token_layers"] += (
                 self.mcfg.n_layers_of("kda") * real)
@@ -3320,8 +3398,13 @@ class InferenceEngine:
         logits = b.logits
         if again:
             logits, self.cache = out
-            if b.picked:
+            if b.picked and not self.mcfg.block_length:
                 self._key = self._executor.key
+        if self.mcfg.block_length:
+            for req in b.reqs:
+                if req.max_new_tokens <= len(req.generated):
+                    req.done = True   # prefill-only (scoring) request
+            return again
         with self._phase("prefill/sample"):
             if b.picked:
                 # orion: allow[host-sync] [nb] picks of a program that has ended: the prefill's ONE fetch
@@ -4071,6 +4154,8 @@ class InferenceEngine:
                 self._rollback_slot(r)
 
     def _decode_all(self) -> bool:
+        if self.mcfg.block_length:
+            return self._denoise_all()
         if self._burst is not None and (
             self._long
             or (self._spec is not None and not self._spec_disabled)
@@ -4107,6 +4192,106 @@ class InferenceEngine:
         if drafts is not None:
             return self._verify_all(drafts)
         return self._decode_run_window(window)
+
+    def _denoise_all(self) -> bool:
+        """One block for every live slot of a model that generates by
+        diffusion over blocks (``runner.denoise_block``): provision a block
+        of pages ahead, launch the block program behind the step's prefill
+        where that is in flight (it needs nothing of it but the cache),
+        fetch the ``[B, L]`` tokens and emit each slot's: the positions
+        after a prompt's tail, up to ``max_new_tokens`` or an EOS. The
+        cursor moves by whole blocks."""
+        L, S = self.mcfg.block_length, self.icfg.denoising_steps
+        with self._phase("decode/build"):
+            if (self._burst is not None
+                    and self._window_page_need(L) > self.alloc.free_pages):
+                self._finish_prefill()   # as _decode_build_window
+            self._grow_pages()
+            mask = np.array([self._decodes(r) for r in self.slots], bool)
+            active = [r for r, m in zip(self.slots, mask) if m]
+            if not active:
+                if self._burst is not None:
+                    self._finish_prefill()
+                self._reap()
+                return False
+            tokens = np.zeros((self.max_batch, L), np.int32)
+            tails = np.zeros(self.max_batch, np.int32)
+            for req in active:
+                # Not yet cached: a prompt's tail, at a first block alone
+                # (past it the cursor is the context's length).
+                cursor = int(self.seq_lens[req.slot])
+                if cursor < len(req.prompt) + len(req.generated):
+                    tail = req.context[cursor:]
+                    tokens[req.slot, :len(tail)] = tail
+                    tails[req.slot] = len(tail)
+            args = (
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(tails), jnp.asarray(self.seq_lens.copy()),
+                jnp.asarray(self.page_table.copy()), jnp.asarray(mask),
+                self._key,
+            )
+            name = "denoise_defaults"
+            if not all(
+                r.temperature is None and r.top_k is None and r.top_p is None
+                for r in active
+            ):
+                name, args = "denoise", args + (
+                    jnp.asarray(self.slot_temp),
+                    jnp.asarray(self.slot_top_k),
+                    jnp.asarray(self.slot_top_p),
+                )
+            t = self.timing
+            t["denoise_dispatches"] += 1
+            t["denoise_forwards"] += S
+            t["commit_forwards"] += 1
+            t["block_slot_forwards"] += (S + 1) * len(active)
+            t["block_positions_fed"] += (S + 1) * L * len(active)
+            t["blocks_with_prompt_tail"] += int((tails[mask] > 0).sum())
+            t["block_kv_positions_read"] += (S + 1) * (
+                int(self.seq_lens[mask].sum()) + L * len(active))
+        with self._phase("decode/run"):
+            out = self._executor.run("decode", name, *args)
+        again = False
+        try:
+            if self._burst is not None:
+                self.timing["chained_steps"] += 1
+                again = self._finish_prefill()
+            if not again:
+                with self._phase("decode/run"):
+                    out = self._executor.wait("decode", name, out, *args)
+        except DispatchFault:
+            self.cache = out[-1]    # as _decode_run_window
+            raise
+        if again:
+            return self._denoise_all()
+        with self._phase("decode/fetch"):
+            *out, self._key, self.cache = out
+            # orion: allow[host-sync] [B, L] tokens, the forward that decided each (and ok flags): the block program's ONE fetch
+            toks, at, *ok = jax.device_get(out)
+        with self._phase("decode/emit"):
+            # Forward s fed as the mask token what forward s or a later one
+            # decided.
+            self.timing["block_positions_undecided_fed"] += int(sum(
+                (at[mask] >= s).sum() for s in range(S)))
+            for req in active:
+                if ok and not ok[0][req.slot]:
+                    self._quarantine(req, "nan")
+                    continue
+                slot, tail = req.slot, int(tails[req.slot])
+                for tok in toks[slot, tail:].tolist():
+                    if req.done:
+                        self.timing["tokens_discarded"] += 1
+                        continue
+                    self.last_token[slot] = tok
+                    req.generated.append(tok)
+                    self.timing["tokens_committed"] += 1
+                    self._maybe_finish(req, tok)
+                # The whole block is cached; no room for another ends it.
+                self.seq_lens[slot] += L
+                if int(self.seq_lens[slot]) >= self.icfg.max_seq_len:
+                    req.done = True
+            self._reap()
+        return True
 
     def _decode_window_all(self) -> bool:
         """The plain fused decode window over all live slots, for the

@@ -31,6 +31,7 @@ import jax
 
 from orion_tpu.infer.runner import (
     decode_window,
+    denoise_block,
     fold_step,
     mixed_step,
     mixed_verify_step,
@@ -67,6 +68,7 @@ class DispatchExecutor:
         "verify": verify_step,
         "mixed_verify": mixed_verify_step,
         "fold": fold_step,
+        "denoise": denoise_block,
     }
 
     def __init__(self, engine):
@@ -111,6 +113,10 @@ class DispatchExecutor:
                 cfg=mcfg, max_seq_len=icfg.max_seq_len, mesh=mesh,
                 nan_guard=self.eng._guard,
             )
+        if stem == "denoise":
+            # The schedule is the engine's, for every request alike.
+            kw.update(steps=icfg.denoising_steps, remasking=icfg.remasking,
+                      threshold=icfg.confidence_threshold)
         if stem in ("prefill", "mixed", "mixed_verify"):
             # Blockwise paged-flash prefill (inference.paged_prefill):
             # resolved against THIS build's kernels — the XLA fallback

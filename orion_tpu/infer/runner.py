@@ -205,17 +205,22 @@ def _prefill_ctx(
     # The layer-stacked expert weights, where the layer scan slices one
     # stack [L, ...] (no window pattern): the dropless MoE dispatch reads a
     # layer's matrices out of it in place (ops.grouped_matmul).
-    moe_stack = None
-    if (cfg.is_moe and cfg.scan_layers and cfg.window_pattern is None
-            and cfg.layer_plan is None):
-        moe_stack = params["blocks"]["moe"]
     return dict(
-        moe_stack=moe_stack, psz=psz, NP=NP,
+        moe_stack=_moe_stack(params, cfg), psz=psz, NP=NP,
         P_pre=P_pre, positions=positions, seg=seg,
         kv_pos=kv_pos, kv_seg=kv_seg, pages=pages,
         prefix_pages=prefix_pages, prefix_lens=prefix_lens,
         lengths=lengths, paged=paged, interpret=interpret, walk=walk,
     )
+
+
+def _moe_stack(params: Params, cfg: ModelConfig):
+    """The layer-stacked expert weights where the layer scan slices ONE stack
+    [L, ...] (no window pattern, no layer plan), else None."""
+    if (cfg.is_moe and cfg.scan_layers and cfg.window_pattern is None
+            and cfg.layer_plan is None):
+        return params["blocks"]["moe"]
+    return None
 
 
 def _dense_layer(
@@ -282,7 +287,7 @@ def _dense_layer(
                 q_segment_ids=seg, kv_segment_ids=kv_seg, seg_pad_zero=True,
                 **kv_at,
                 logit_softcap=cfg.attn_logit_softcap, window=win,
-                impl=cfg.kernels, mesh=mesh,
+                impl=cfg.kernels, mesh=mesh, block=cfg.block_length,
             )
         return out, lambda: _scatter_pages(cc, k, v, l * NP + ctx["pages"])
 
@@ -460,6 +465,12 @@ def prefill_step(
     from orion_tpu.infer.sampling import sample
 
     logits, cache, *held = out
+    if cfg.block_length:
+        # Generation by blocks: prefill samples nothing (the logits are over
+        # the token AT the last whole block's end, which is the prompt's
+        # own), and the key stays where it is.
+        picks = jnp.zeros((tokens.shape[0],), jnp.int32)
+        return logits, picks, last_token, key, *held, cache
     picks = sample(logits, key)     # scalar temperature 0: the bare argmax
     with jax.named_scope("sample"):
         last_token = last_token.at[slots].set(picks, mode="drop")
@@ -803,6 +814,7 @@ def _paged_layer(
     ctx: dict,
     cfg: ModelConfig,
     mesh: Optional[jax.sharding.Mesh],
+    stack=None,
 ) -> tuple[jax.Array, Cache]:
     """The paged backend: one layer of W new tokens per slot — every
     position's K/V lands in the pool first (quantized under kv_quant,
@@ -891,7 +903,8 @@ def _paged_layer(
         return out, new
 
     x, _, cc = block(
-        x, bp, cfg, ctx["positions"], attend, kind=_kind(cfg, j), mesh=mesh)
+        x, bp, cfg, ctx["positions"], attend, kind=_kind(cfg, j), mesh=mesh,
+        layer_stack=stack)
     return x, cc
 
 
@@ -1174,6 +1187,183 @@ def verify_step(
         logits, tokens, lens, active, key, temperature=temperature,
         top_k=top_k, top_p=top_p, parents=parents, legal_mask=legal_mask,
         nan_guard=nan_guard), cache)
+
+
+def denoise_schedule(block_length: int, steps: int) -> tuple[int, ...]:
+    """Positions the static rule decides at each denoising forward: an even
+    share of the block, the remainder to the first forwards."""
+    each, rest = divmod(block_length, steps)
+    return tuple(each + (s < rest) for s in range(steps))
+
+
+def choose_positions(conf: jax.Array, undecided: jax.Array, count,
+                     remasking: str, threshold: float) -> jax.Array:
+    """[B, L] bool: the undecided positions one denoising forward decides.
+    ``conf`` is the probability of the token drawn at each position.
+    ``low_confidence_static``: the ``min(count, undecided left)`` undecided
+    positions of largest ``conf`` (ties: the lower index).
+    ``low_confidence_dynamic``: every undecided position with ``conf >
+    threshold``, or the static set where those are fewer than ``count``."""
+    score = jnp.where(undecided, conf, -1.0)
+    order = jnp.argsort(-score, axis=-1, stable=True)
+    rank = jnp.argsort(order, axis=-1, stable=True)
+    left = undecided.sum(-1, keepdims=True)
+    take = undecided & (rank < jnp.minimum(count, left))
+    if remasking == "low_confidence_dynamic":
+        over = undecided & (conf > threshold)
+        take = jnp.where(over.sum(-1, keepdims=True) < count, take, over)
+    return take
+
+
+def _block_ctx(cache: Cache, seq_lens: jax.Array, page_table: jax.Array,
+               active: jax.Array, max_seq_len: int, cfg: ModelConfig) -> dict:
+    """The paged backend's tensors for one block of ``cfg.block_length``
+    positions a slot from ``seq_lens`` on, every new row visible to every
+    query: the W-query kernel's tree path under FULL ancestor words and the
+    chain's depths (a block's positions see each other and everything below
+    the cursor; positions and writes as the chain's)."""
+    B, L = seq_lens.shape[0], cfg.block_length
+    return _paged_ctx(
+        cache, seq_lens, jnp.full((B,), L, jnp.int32), page_table, active, L,
+        max_seq_len, cfg, name="block_paged",
+        depths=jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L)),
+        tree_mask=jnp.full((B, L), (1 << L) - 1, jnp.int32),
+    )
+
+
+def _block_hidden(params: Params, cache: Cache, fed: jax.Array, ctx: dict,
+                  cfg: ModelConfig, mesh) -> tuple[jax.Array, Cache]:
+    """The layers on one block as fed [B, L] -> (hidden [B, L, D], cache
+    with the block's K/V rows written at the cursor)."""
+    # A block of B x L rows takes the dropless grouped dispatch, which reads
+    # a layer's matrices out of the stack in place (as prefill's does).
+    moe_stack = _moe_stack(params, cfg)
+
+    def body(carry, bp, l, j, stack=None):
+        x, cc = carry
+        if moe_stack is not None:
+            stack = (moe_stack, l)
+        return _paged_layer(x, cc, bp, l, j, ctx, cfg, mesh, stack)
+
+    x = embed(params, fed, ctx["positions"], cfg)
+    return _scan_layers(params, cfg, body, (x, dict(cache)))
+
+
+def block_forward(
+    params: Params, cache: Cache, fed: jax.Array, seq_lens: jax.Array,
+    page_table: jax.Array, active: jax.Array, cfg: ModelConfig,
+    max_seq_len: int, mesh: Optional[jax.sharding.Mesh] = None,
+) -> tuple[jax.Array, Cache]:
+    """ONE forward of a block as fed [B, L] -> (logits [B, L, V] over the
+    token AT each position, cache with the block's rows written): the body
+    ``denoise_block`` runs ``steps`` times and once more without the head.
+    What a check holds the block program to, forward by forward."""
+    ctx = _block_ctx(cache, seq_lens, page_table, active, max_seq_len, cfg)
+    x, cache = _block_hidden(params, cache, fed, ctx, cfg, mesh)
+    return _block_logits(params, x, cfg, mesh).reshape(*fed.shape, -1), cache
+
+
+def _block_logits(params: Params, x: jax.Array, cfg: ModelConfig, mesh
+                  ) -> jax.Array:
+    """The head on a block's hidden states [B, L, D] -> float32 logits
+    [B x L, V], a row a position: accumulated and left in float32 (the
+    choice of positions ranks probabilities of them, and a check's
+    one-forward body has to rank them the same), and flat, so that nothing
+    of the vocabulary's width is laid out again for the sampler."""
+    B, L, D = x.shape
+    return unembed(params, x.reshape(1, B * L, D), cfg, mesh, precise=True)[0]
+
+
+def denoise_block(
+    params: Params,
+    cache: Cache,
+    tokens: jax.Array,        # [B, L]: the block as the host knows it (a
+    #                           prompt's tail at its first positions)
+    n_decided: jax.Array,     # [B] int32: leading positions that are decided
+    seq_lens: jax.Array,      # [B] int32: the block's first position
+    page_table: jax.Array,    # [B, pages_per_seq] int32
+    active: jax.Array,        # [B] bool: slot holds a live request
+    key: jax.Array,           # the engine's ONE key
+    temperature: jax.Array,   # [B] f32 per-request sampling params (python
+    top_k: jax.Array,         # [B] i32   scalars for the all-defaults
+    top_p: jax.Array,         # [B] f32   specialization)
+    cfg: ModelConfig,
+    max_seq_len: int,
+    mesh: Optional[jax.sharding.Mesh] = None,
+    nan_guard: bool = False,
+    steps: int = 1,
+    remasking: str = "low_confidence_static",
+    threshold: float = 0.9,
+) -> tuple[jax.Array, ...]:
+    """One block of ``cfg.block_length`` positions for EVERY live slot in one
+    dispatch: ``steps`` denoising forwards and the commit forward; returns
+    ``(tokens [B, L] int32, decided_at [B, L] int32, key', cache)`` (``ok``
+    [B] third under ``nan_guard``). ``decided_at`` is the forward that
+    decided a position (-1: it came decided, a prompt's tail).
+
+    A forward feeds the block's L positions, decided tokens as they are and
+    undecided ones as ``cfg.mask_token_id``, through ``_paged_layer`` at
+    W = L with every new row visible to every query (``_block_ctx``). The
+    logits at a position are over the token AT it. At every undecided
+    position a token is drawn from the filtered distribution and its
+    float32 softmax probability is its confidence; ``choose_positions``
+    decides some, which keep their token for good. The rows a denoising
+    forward writes land BEYOND the slot's cursor (``seq_lens`` does not
+    move), where the next forward overwrites them and nothing else reads
+    them; the commit forward, on the finished block and without the head,
+    leaves the rows that stay. The trip count is fixed: a block decided
+    early makes its remaining forwards for nothing.
+
+    The key is handled as ``decode_window`` handles it: ``key', sub =
+    split(key)``, one of ``split(sub, steps)`` a forward."""
+    from orion_tpu.infer.sampling import sample
+
+    B, L = tokens.shape
+    ctx = _block_ctx(cache, seq_lens, page_table, active, max_seq_len, cfg)
+
+    def rows(a):        # a [B] sampling parameter for each of the L rows
+        return a if jnp.ndim(a) == 0 else jnp.repeat(a, L)
+
+    def stepf(carry, xs):
+        toks, at, ok, cc = carry
+        sub, count, s = xs
+        decided = at < s
+        with jax.named_scope("denoise/forward"):
+            x, cc = _block_hidden(
+                params, cc, jnp.where(decided, toks, cfg.mask_token_id), ctx,
+                cfg, mesh)
+            logits = _block_logits(params, x, cfg, mesh)    # [B x L, V]
+        with jax.named_scope("denoise/choose"):
+            drawn = sample(
+                logits, sub, temperature=rows(temperature),
+                top_k=rows(top_k), top_p=rows(top_p))
+            conf = jnp.exp(jnp.take_along_axis(
+                jax.nn.log_softmax(logits, axis=-1), drawn[:, None],
+                axis=-1)).reshape(B, L)
+            drawn = drawn.reshape(B, L)
+            take = choose_positions(
+                conf, ~decided, count, remasking, threshold)
+            toks = jnp.where(take, drawn, toks)
+            at = jnp.where(take, s, at)
+            if nan_guard:
+                ok = ok & (jnp.isfinite(logits).reshape(B, -1).all(-1)
+                           | ~active)
+        return (toks, at, ok, cc), None
+
+    key_next, sub = jax.random.split(key)
+    came = jnp.arange(L, dtype=jnp.int32)[None, :] < n_decided[:, None]
+    init = (tokens, jnp.where(came, -1, steps).astype(jnp.int32),
+            jnp.ones((B,), bool), dict(cache))
+    (toks, at, ok, cache), _ = jax.lax.scan(
+        stepf, init,
+        (jax.random.split(sub, steps),
+         jnp.asarray(denoise_schedule(L, steps), jnp.int32),
+         jnp.arange(steps, dtype=jnp.int32)))
+    with jax.named_scope("denoise/commit"):
+        _, cache = _block_hidden(params, cache, toks, ctx, cfg, mesh)
+    if nan_guard:
+        return toks, at, ok, key_next, cache
+    return toks, at, key_next, cache
 
 
 def mixed_step(

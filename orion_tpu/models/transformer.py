@@ -444,16 +444,22 @@ def embed(
 @jax.named_scope("unembed")
 def unembed(
     params: Params, x: jax.Array, cfg: ModelConfig,
-    mesh: Optional[Any] = None,
+    mesh: Optional[Any] = None, precise: bool = False,
 ) -> jax.Array:
-    """Final norm + LM head -> float32 logits; shared like ``embed``."""
+    """Final norm + LM head -> float32 logits; shared like ``embed``.
+    ``precise``: the head's products are accumulated and LEFT in float32
+    (else they pass through ``x``'s dtype, or not, as the compiler fuses):
+    for a caller that ranks positions by a probability of the logits, which
+    two programs must then compute alike."""
     x = _norm(x, params["final_norm"], cfg, mesh)
+    kw = {"preferred_element_type": jnp.float32} if precise else {}
     if cfg.tie_embeddings:
         logits = jnp.einsum(
-            "bsd,vd->bsv", x, params["embed"]["tokens"].astype(x.dtype)
+            "bsd,vd->bsv", x, params["embed"]["tokens"].astype(x.dtype), **kw
         )
     else:
-        logits = jnp.einsum("bsd,dv->bsv", x, _load_w(params["lm_head"], x.dtype))
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, _load_w(params["lm_head"], x.dtype), **kw)
     logits = logits.astype(jnp.float32)
     if cfg.final_logit_softcap is not None:
         cap = cfg.final_logit_softcap

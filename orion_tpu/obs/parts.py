@@ -53,4 +53,5 @@ PROGRAM_NAMES = {
     "verify": "orion_verify",
     "mixed_verify": "orion_mixed_verify",
     "fold": "orion_fold",
+    "denoise": "orion_denoise_block",
 }

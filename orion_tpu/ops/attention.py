@@ -38,6 +38,7 @@ def attention_mask(
     q_positions: Optional[jax.Array] = None,
     kv_positions: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    block: int = 0,
 ) -> Optional[jax.Array]:
     """Boolean [.., q_len, kv_len] mask; True = attend.
 
@@ -45,11 +46,18 @@ def attention_mask(
     ``window`` positions: 0 <= q_pos - kv_pos < window. Positions default
     to token index (+ q_offset for q); explicit per-token positions
     ([.., q_len] / [.., kv_len]) serve packed/permuted layouts.
+
+    ``block`` (generation by diffusion over blocks): the causal mask with
+    its diagonal rounded up to the end of the query's block of ``block``
+    positions, kv_pos // block <= q_pos // block.
     """
     if window is not None and (not causal or window < 1):
         raise ValueError(
             f"window={window} requires causal attention and window >= 1"
         )
+    if block and (not causal or window is not None):
+        raise ValueError(
+            f"block={block} requires causal attention and no window")
     mask = None
     if causal:
         q_pos = (
@@ -60,6 +68,8 @@ def attention_mask(
         kv_pos = (
             kv_positions if kv_positions is not None else jnp.arange(kv_len)
         )
+        if block:
+            q_pos = q_pos // block * block + block - 1
         dist = q_pos[..., :, None] - kv_pos[..., None, :]
         mask = dist >= 0
         if window is not None:
@@ -85,6 +95,7 @@ def attention_xla(
     kv_positions: Optional[jax.Array] = None,
     window: Optional[int] = None,
     sink: Optional[jax.Array] = None,
+    block: int = 0,
 ) -> jax.Array:
     """q: [B, Sq, N, H]; k: [B, Skv, K, H], v: [B, Skv, K, Hv] with N % K
     == 0 -> [B, Sq, N, Hv]. ``sink`` [N]: a learned logit a query head that
@@ -118,6 +129,7 @@ def attention_xla(
             q_positions=q_positions,
             kv_positions=kv_positions,
             window=window,
+            block=block,
         )
     if mask is not None:
         if mask.ndim == 2:
@@ -158,6 +170,7 @@ def attention(
     mesh: Optional[jax.sharding.Mesh] = None,
     tp_axis: str = "tp",
     sink: Optional[jax.Array] = None,
+    block: int = 0,
 ) -> jax.Array:
     """Grouped-query scaled-dot-product attention. Shapes as attention_xla.
 
@@ -205,6 +218,8 @@ def attention(
                     "flash attention with a sink runs on one device: its "
                     "per-head logits are not split over a mesh yet")
             kernel_kw["sink"] = sink
+        if block:
+            kernel_kw["block"] = block
         if n_heads % tp or n_kv % tp:
             raise ValueError(
                 f"tp-sharded flash attention needs n_heads ({n_heads}) "
@@ -252,4 +267,5 @@ def attention(
         kv_positions=kv_positions,
         window=window,
         sink=sink,
+        block=block,
     )

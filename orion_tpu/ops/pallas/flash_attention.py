@@ -104,6 +104,11 @@ class _Statics:
     # False: no log-sum-exp output (the forward-only call, whose [B, N, S,
     # 128] float32 rows nothing reads: 512 MiB at 16384 tokens of 64 heads).
     lse: bool = True
+    # Generation by diffusion over blocks: the causal diagonal rounded up to
+    # the end of the query's block of ``block`` positions (a power of two
+    # that divides both tile sizes and ``q_offset``), on token index; 0 is
+    # the plain causal mask. Forward only, like the sink.
+    block: int = 0
 
 
 def _static_range(st: _Statics) -> bool:
@@ -130,6 +135,10 @@ def _kv_range(st: _Statics, iq, nk: int, xp=jnp):
     bq, bk = st.block_q, st.block_kv
     q_min = iq * bq + st.q_offset
     q_max = xp.minimum(iq * bq + bq - 1, st.seq_q - 1) + st.q_offset
+    if st.block:
+        # The last row sees to the end of its block: inside the same kv
+        # tile, whose width the block divides, so ``hi`` does not move.
+        q_max = q_max | (st.block - 1)
     hi = xp.where(q_max < 0, -1,
                   xp.minimum(_fdiv(xp.maximum(q_max, 0), bk), nk - 1))
     lo = 0 * iq
@@ -185,7 +194,8 @@ def _unmasked(st: _Statics, iq, ik):
     full = ik * bk + bk <= st.seq_kv + 0 * iq
     if st.causal:
         q_min = iq * bq + st.q_offset
-        full &= ik * bk + bk - 1 <= q_min
+        full &= ik * bk + bk - 1 <= (q_min | (st.block - 1) if st.block
+                                     else q_min)
         if st.window is not None:
             full &= q_min + bq - 1 - ik * bk < st.window
     return full
@@ -264,7 +274,12 @@ def _block_mask(st: _Statics, iq, ik, qseg_ref, kseg_ref, qpos_ref, kpos_ref,
         else:
             # Token index: q position - kv position is (row - col) less a
             # scalar of the block, so the compares are on ONE iota difference.
-            dist = jax.lax.broadcasted_iota(jnp.int32, shape, q_ax) - kv_i
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, q_ax)
+            if st.block:
+                # The block divides the tile and the offset: rounding the
+                # row up inside the tile rounds the position up.
+                row = row | (st.block - 1)
+            dist = row - kv_i
             first = ik * bk - iq * bq - st.q_offset
         mask = both(mask, dist >= first)
         if st.window is not None:
@@ -901,6 +916,7 @@ def flash_attention(
     window: Optional[int] = None,
     seg_pad_zero: bool = False,
     sink: Optional[jax.Array] = None,
+    block: int = 0,
 ) -> jax.Array:
     """Flash attention; shapes/semantics match ``attention_xla``.
 
@@ -919,7 +935,10 @@ def flash_attention(
     each row's softmax denominator and no column; values may be narrower
     than keys (``v`` [B, Skv, K, Hv] -> [B, Sq, N, Hv]), and keys whose
     width is not a whole number of 128-lane tiles are padded with zeros
-    under the scale of their own width. Either is the FORWARD alone: no
+    under the scale of their own width. ``block`` (a power of two) rounds
+    the causal diagonal up to the end of the query's block of that many
+    positions (``attention_mask``'s; which tiles are visited does not
+    change, the mask inside them does). Each is the FORWARD alone: no
     backward is defined for them.
 
     A causal window of at most ``BAND_MAX_WINDOW`` positions over more than
@@ -942,7 +961,7 @@ def flash_attention(
             seg_pad_zero=True, sink=sink)
         return o.reshape(B, -1, *o.shape[2:])[:, :S]
     H, Hv = q.shape[-1], v.shape[-1]
-    fwd_only = sink is not None or Hv != H
+    fwd_only = sink is not None or Hv != H or bool(block)
     scale = None
     if fwd_only and H % LANES:
         scale = H ** -0.5
@@ -952,9 +971,17 @@ def flash_attention(
         causal, logit_softcap, q_offset, block_q, block_kv, interpret,
         q_positions, kv_positions, window, seg_pad_zero,
     )
+    if block:
+        if (not causal or window is not None or q_positions is not None
+                or block & (block - 1) or q_offset % block
+                or st.block_q % block or st.block_kv % block):
+            raise ValueError(
+                f"block={block} needs causal attention on token index with "
+                f"no window, and a power of two that divides q_offset="
+                f"{q_offset} and the tiles {st.block_q} x {st.block_kv}")
     if fwd_only:
         st = dataclasses.replace(
-            st, scale=scale, sink=sink is not None, lse=False)
+            st, scale=scale, sink=sink is not None, lse=False, block=block)
         rows = None if sink is None else jnp.broadcast_to(
             sink.astype(jnp.float32)[:, None, None], (q.shape[2], 8, LANES))
         o = _flash_forward_only(st, qt, kt, vt, qseg, kseg, qpos, kpos, rows)
@@ -974,8 +1001,9 @@ def _forward_only_fwd(st, *args):
 
 def _forward_only_bwd(st, _, g):
     raise NotImplementedError(
-        "flash attention with a sink or with values narrower than keys has "
-        "no backward: train such a model with impl='xla'")
+        "flash attention with a sink, with values narrower than keys or "
+        "under a block mask (block=) has no backward: train such a model "
+        "with impl='xla'")
 
 
 _flash_forward_only.defvjp(_forward_only_fwd, _forward_only_bwd)

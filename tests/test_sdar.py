@@ -1,0 +1,541 @@
+"""A model that generates by diffusion over blocks (SDAR; ``tiny-sdar``: blocks
+of 4 under pages of 8, 2 layers, 8 experts top-2): the block mask in the XLA
+form, the flash forward and the W-query paged kernel; prefill and the block
+program against the plain reference (``benchmarks/reference/sdar.py``, which
+imports nothing of the program); THE ENGINE'S TOKENS EQUAL THE REFERENCE'S
+(float32, greedy) for both strategies, every step count, every prompt tail,
+cut outputs, an EOS inside a block, a batch against each alone, and one
+sampled case with the engine's key replayed; what a commit leaves in the
+pages, the counters, the refusals by name, and that ``block_length = 0``
+traces the parent's statics."""
+
+import dataclasses
+import json
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from orion_tpu.config import get_config
+from orion_tpu.infer import InferenceEngine
+from orion_tpu.models import init_params
+from orion_tpu.ops.attention import attention, attention_mask, attention_xla
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
+PUBLISHED = json.loads((REPO / "tests/benchmark/data/published/"
+                        "sdar-30b-a3b-serve-1chip.json").read_text())
+
+
+def _reference():
+    from benchmarks.harness import cell
+
+    return cell._load(REPO / "benchmarks" / "reference" / "sdar.py")
+
+
+def tiny_hf(cfg) -> dict:
+    """The tiny preset under the published key names, as the reference reads
+    a configuration file."""
+    m, i = cfg.model, cfg.inference
+    return {
+        "hidden_size": m.d_model, "head_dim": m.resolved_head_dim,
+        "num_attention_heads": m.n_heads,
+        "num_key_value_heads": m.n_kv_heads, "vocab_size": m.vocab_size,
+        "num_hidden_layers": m.n_layers, "rms_norm_eps": m.norm_eps,
+        "rope_theta": m.rope_theta, "num_experts": m.n_experts,
+        "num_experts_per_tok": m.n_experts_per_token,
+        "moe_intermediate_size": m.moe_d_ff,
+        "generation": {
+            "block_length": m.block_length, "mask_token_id": m.mask_token_id,
+            "denoising_steps": i.denoising_steps, "remasking": i.remasking,
+            "confidence_threshold": i.confidence_threshold}}
+
+
+def _config(*overrides):
+    return get_config("tiny-sdar", list(overrides))
+
+
+@pytest.fixture(scope="module")
+def params():
+    """Seeded weights, the matrices ten times the preset's scale: at N(0,
+    0.02) one token wins every position, and equal outputs would say little."""
+    cfg = _config()
+    tree = init_params(cfg.model, jax.random.key(0))
+    return jax.tree.map(lambda a: a * 10.0 if a.ndim >= 3 else a, tree)
+
+
+def _prompt(n: int, seed: int = 0, vocab: int = 255) -> list:
+    rng = np.random.default_rng(1000 * seed + n)
+    return [int(t) for t in rng.integers(1, vocab, n)]
+
+
+_ENGINES: dict = {}
+
+
+def _engine(params, *overrides, **kw):
+    """ONE engine a configuration for the whole file (an engine compiles its
+    programs anew, 1-2 s each on the CPU): a request meets pages and rows
+    beyond its cursor that earlier tests' requests left, which no block may
+    read. Tests that replace ``_executor.run`` put it back."""
+    key = (overrides, tuple(sorted(kw.items())))
+    if key not in _ENGINES:
+        _ENGINES[key] = InferenceEngine(_config(*overrides), params,
+                                        **{"seed": 0, **kw})
+    return _ENGINES[key]
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _close_engines():
+    yield
+    while _ENGINES:
+        _ENGINES.popitem()[1].close()
+
+
+def _generate(params, requests, *overrides, **kw):
+    """``requests``: [(prompt, max_new)] all submitted before the first step;
+    returns them and the counters of their steps alone."""
+    engine = _engine(params, *overrides, **kw)
+    engine.reset_timing()
+    reqs = [engine.submit_request(p, m) for p, m in requests]
+    while engine.has_work():
+        engine.step()
+    return reqs, engine.reset_timing()
+
+
+def _rel(a, b) -> float:
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+# -- the mask -----------------------------------------------------------------
+
+
+def test_the_preset_is_the_published_model():
+    m = get_config("sdar-30b-a3b").model
+    assert (m.d_model, m.n_layers, m.n_heads, m.n_kv_heads,
+            m.resolved_head_dim, m.vocab_size, m.d_ff, m.moe_d_ff,
+            m.n_experts, m.n_experts_per_token) == tuple(PUBLISHED[k] for k in (
+                "hidden_size", "num_hidden_layers", "num_attention_heads",
+                "num_key_value_heads", "head_dim", "vocab_size",
+                "intermediate_size", "moe_intermediate_size", "num_experts",
+                "num_experts_per_tok"))
+    assert (m.rope_theta, m.norm_eps, m.tie_embeddings, m.sliding_window,
+            m.attn_bias) == (PUBLISHED["rope_theta"],
+                             PUBLISHED["rms_norm_eps"],
+                             PUBLISHED["tie_word_embeddings"],
+                             PUBLISHED["sliding_window"],
+                             PUBLISHED["attention_bias"])
+    assert PUBLISHED["decoder_sparse_step"] == 1 and m.n_dense_layers == 0
+    assert PUBLISHED["mlp_only_layers"] == [] and m.shared_expert_d_ff == 0
+    assert (m.qk_norm, m.router_score, m.activation, m.block_length,
+            m.mask_token_id) == (True, "softmax", "swiglu", 4, 151669)
+    assert m.capacity_factor == m.n_experts / m.n_experts_per_token
+    assert m.layer_plan is None and m.holds_expert_share is False
+
+
+@pytest.mark.parametrize("length", [8, 30, 130, 256])
+def test_block_mask_xla_and_flash_against_the_dense_mask(length):
+    """``attention_mask(block=)`` is j // 4 <= i // 4; ``attention_xla`` and
+    the flash forward (interpreted; 128-wide tiles, lengths that are and are
+    not multiples of the tile; whole rows at 8 and 256, ragged rows at 30
+    and 130) attend exactly its pairs."""
+    i = np.arange(length)
+    dense = (i[None, :] // 4) <= (i[:, None] // 4)
+    assert (np.asarray(attention_mask(length, length, block=4)) == dense).all()
+    ks = jax.random.split(jax.random.key(length), 3)
+    q = jax.random.normal(ks[0], (2, length, 4, 16))
+    k = jax.random.normal(ks[1], (2, length, 2, 16))
+    v = jax.random.normal(ks[2], (2, length, 2, 16))
+    want = attention_xla(q, k, v, causal=False, mask=jnp.asarray(dense))
+    assert _rel(attention_xla(q, k, v, block=4), want) < 1e-6
+    tiles = dict(block_q=128, block_kv=128) if length > 128 else {}
+    if length in (8, 256):          # whole rows
+        assert _rel(attention(q, k, v, block=4, impl="pallas_interpret",
+                              **tiles), want) < 1e-6
+        return
+    lens = jnp.array([length, length - 4])      # ragged rows
+    seg = (jnp.arange(length)[None] < lens[:, None]).astype(jnp.int32)
+    got = attention(q, k, v, block=4, impl="pallas_interpret", **tiles,
+                    q_segment_ids=seg, kv_segment_ids=seg, seg_pad_zero=True)
+    ragged = attention_xla(q, k, v, block=4, q_segment_ids=seg,
+                           kv_segment_ids=seg)
+    real = np.asarray(seg, bool)
+    assert _rel(np.asarray(got)[0], want[0]) < 1e-6
+    assert _rel(np.asarray(got)[real], np.asarray(ragged)[real]) < 1e-6
+
+
+def test_block_zero_traces_the_parents_statics_and_the_backward_refuses():
+    """``block=0`` is the parent's call (the same ``_Statics``, spelled out);
+    a block mask is forward only, refused by name."""
+    import importlib
+
+    fa = importlib.import_module("orion_tpu.ops.pallas.flash_attention")
+    q = jnp.ones((1, 16, 2, 16))
+    seen = []
+    kept = fa._flash
+
+    def spy(st, *a):
+        seen.append(st)
+        return kept(st, *a)
+
+    fa._flash = spy
+    try:
+        fa.flash_attention(q, q, q, interpret=True)
+        fa.flash_attention(q, q, q, interpret=True, block=0)
+    finally:
+        fa._flash = kept
+    assert seen[0] == seen[1] == fa._Statics(
+        causal=True, logit_softcap=None, q_offset=0, seq_q=16, seq_kv=16,
+        block_q=16, block_kv=16, interpret=True)
+    assert seen[0].block == 0
+    with pytest.raises(NotImplementedError, match="block mask"):
+        jax.grad(lambda x: fa.flash_attention(
+            x, q, q, interpret=True, block=4).sum())(q)
+    for bad in (dict(window=8), dict(causal=False), dict(q_offset=2)):
+        with pytest.raises(ValueError, match="block="):
+            fa.flash_attention(q, q, q, interpret=True, block=4, **bad)
+    for bad in (3, 32):     # no power of two; wider than an ancestor word
+        with pytest.raises(ValueError, match="power of two up to 16"):
+            dataclasses.replace(_config().model, block_length=bad)
+
+
+@pytest.mark.parametrize("start", [8, 12, 20])
+def test_the_paged_kernel_with_every_new_row_visible(start):
+    """The W-query paged kernel (interpreted) at W = 4 under full ancestor
+    words, starts on a page boundary (8), inside a page (12, 20): against
+    ``attention_xla`` over [cached | the 4 new rows], all 4 visible to each
+    of the 4 queries; the rows land at ``start .. start + 3``."""
+    from orion_tpu.ops.pallas.paged_attention import attend
+
+    B, W, N, K, H, psz, P = 2, 4, 4, 2, 16, 8, 4
+    ks = jax.random.split(jax.random.key(start), 5)
+    q = jax.random.normal(ks[0], (B, W, N, H))
+    k_new = jax.random.normal(ks[1], (B, W, K, H))
+    v_new = jax.random.normal(ks[2], (B, W, K, H))
+    pool_k = jax.random.normal(ks[3], (1 + B * P, K, psz, H))
+    pool_v = jax.random.normal(ks[4], (1 + B * P, K, psz, H))
+    table = 1 + jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
+    starts = jnp.array([start, start - 4], jnp.int32)
+    out, k2, v2 = attend(
+        q, pool_k, pool_v, table, starts, jnp.full((B,), W, jnp.int32),
+        layer_base=0, k_new=k_new, v_new=v_new, logit_softcap=None,
+        window=None, interpret=True, k_scale=None, v_scale=None,
+        tree_mask=jnp.full((B, W), 15, jnp.int32),
+        depths=jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32), (B, W)),
+        name="block_paged")
+    for b in range(B):
+        s = int(starts[b])
+        ctx = lambda pool: pool[table[b]].transpose(0, 2, 1, 3).reshape(
+            P * psz, K, H)[:s]
+        kk = jnp.concatenate([ctx(pool_k), k_new[b]])[None]
+        vv = jnp.concatenate([ctx(pool_v), v_new[b]])[None]
+        want = attention_xla(q[b][None], kk, vv, causal=False)
+        assert _rel(out[b], want[0]) < 1e-5
+        wrote = k2[table[b]].transpose(0, 2, 1, 3).reshape(P * psz, K, H)
+        assert _rel(wrote[s:s + W], k_new[b]) < 1e-6
+        assert (np.asarray(wrote[:s]) == np.asarray(ctx(pool_k))).all()
+
+
+# -- prefill and the block program against the reference ----------------------
+
+
+@pytest.mark.parametrize("kernels", ["xla", "pallas_interpret"])
+def test_prefill_logits_and_pages_against_the_reference(params, kernels):
+    """The prefill program on a prompt's whole blocks: its logits are the
+    reference's at the last position (over the token AT it) under the block
+    mask, and the pages hold the reference's rotated keys and values."""
+    from benchmarks.kinds import serve
+
+    cfg = _config(f"model.kernels={kernels}")
+    ref, hf = _reference(), tiny_hf(cfg)
+    engine = _engine(params, f"model.kernels={kernels}")
+    seen, pages = [], []
+    run = engine._executor.run
+
+    def tap(path, name, *a, **kw):
+        out = run(path, name, *a, **kw)
+        if path == "prefill":
+            seen.append(np.asarray(out[0], np.float32))
+            pages.append(np.asarray(a[4])[0])
+        return out
+
+    engine._executor.run = tap
+    prompt = _prompt(22)
+    req = engine.submit_request(prompt, 0)      # scoring: prefill alone
+    while engine.has_work():
+        engine.step()
+    engine._executor.run = run
+    assert req.outcome == "completed" and req.generated == []
+    toks = jnp.asarray(prompt[:20], jnp.int32)
+    want, _ = ref.logits_at(params, toks, jnp.array([19]), hf)
+    assert _rel(seen[0][0], want[0]) < 2e-4
+    # a causal mask in its place is another function
+    causal = {**hf, "generation": {**hf["generation"], "block_length": 1}}
+    assert _rel(ref.logits_at(params, toks, jnp.array([18]), causal)[0],
+                ref.logits_at(params, toks, jnp.array([18]), hf)[0]) > 1e-2
+    k, v = ref.kv_of(params, toks, hf)                # [layers, 20, K, H]
+    got = np.asarray(serve._kv_at(
+        engine.cache, jnp.asarray(pages[0][None, :]), 0, jnp.arange(20),
+        cfg.model.n_layers, cfg.inference.num_pages,
+        cfg.inference.page_size))
+    want_kv = np.concatenate([np.asarray(k).ravel(), np.asarray(v).ravel()])
+    assert _rel(got, want_kv) < 2e-4
+
+
+CASES = [
+    # (strategy, steps, prompt length, max_new)
+    ("low_confidence_static", 1, 8, 8),
+    ("low_confidence_static", 2, 9, 7),
+    ("low_confidence_static", 2, 10, 5),
+    ("low_confidence_static", 4, 11, 6),
+    ("low_confidence_static", 4, 3, 9),
+    ("low_confidence_dynamic", 1, 13, 6),
+    ("low_confidence_dynamic", 2, 12, 9),
+    ("low_confidence_dynamic", 4, 14, 11),
+    ("low_confidence_dynamic", 4, 21, 13),
+]
+
+
+@pytest.mark.parametrize("strategy,steps,n,max_new", CASES)
+def test_the_engines_tokens_are_the_references(params, strategy, steps, n,
+                                               max_new):
+    """Both strategies, S in {1, 2, 4}, prompt tails 0..3 (a prompt shorter
+    than a block among them), ``max_new`` that is and is not a multiple of
+    4: the engine's tokens equal ``reference.generate``'s, and the counters
+    add up. The dynamic rule's threshold (0.05) lies inside the confidences
+    these weights give, so both of its branches run."""
+    overrides = (f"inference.denoising_steps={steps}",) * (steps != 2)
+    if strategy == "low_confidence_dynamic":
+        overrides += (f"inference.remasking={strategy}",
+                      "inference.confidence_threshold=0.05")
+    ref, hf = _reference(), tiny_hf(_config(*overrides))
+    prompt = _prompt(n)
+    (req,), t = _generate(params, [(prompt, max_new)], *overrides)
+    trace = []
+    want = ref.generate(params, prompt, max_new, hf, trace=trace)
+    assert req.outcome == "completed" and req.generated == want
+    assert len(set(want)) > 1           # not one token everywhere
+    blocks = -(-(n + max_new) // 4) - n // 4
+    assert (t["denoise_dispatches"], t["denoise_forwards"],
+            t["commit_forwards"], t["block_slot_forwards"]) == (
+        blocks, blocks * steps, blocks, blocks * (steps + 1))
+    assert t["block_positions_fed"] == 4 * blocks * (steps + 1)
+    assert t["tokens_committed"] == max_new
+    assert t["tokens_discarded"] == 4 * blocks - n % 4 - max_new
+    assert t["blocks_with_prompt_tail"] == int(n % 4 > 0)
+    assert t["decode_window"] == 4
+    # what the reference fed as the mask token, forward by forward
+    assert t["block_positions_undecided_fed"] == sum(
+        int((fed == hf["generation"]["mask_token_id"]).sum())
+        for _, _, fed, _, _ in trace)
+    if strategy == "low_confidence_dynamic" and steps == 4:
+        per_forward = [int(after.sum()) for _, _, _, _, after in trace]
+        assert max(np.diff([0] + per_forward[:4])) > 1   # above the floor
+    cached = sum(n // 4 * 4 + 4 * b for b in range(blocks))
+    assert t["block_kv_positions_read"] == (steps + 1) * (cached + 4 * blocks)
+    if n % 4 == 0 and max_new % 4 == 0:
+        assert t["tokens_committed"] / t["block_slot_forwards"] == (
+            4 / (steps + 1))
+
+
+def test_a_batch_of_unequal_requests_against_each_alone(params):
+    """Four requests of unequal lengths and tails in one engine (4 slots,
+    every dispatch one block for every live slot) give what each gives
+    alone, which is the reference's; under the interpreted kernels too."""
+    ref = _reference()
+    requests = [(_prompt(n, seed=7), m)
+                for n, m in ((5, 9), (16, 4), (18, 13), (31, 6))]
+    together, _ = _generate(params, requests, "model.kernels=pallas_interpret")
+    want = [ref.generate(params, p, m, tiny_hf(_config()))
+            for p, m in requests]
+    assert [r.generated for r in together] == want
+    alone = [_generate(params, [r])[0][0].generated for r in requests[:2]]
+    assert alone == want[:2]
+
+
+def test_an_eos_inside_a_block_ends_the_request_there(params):
+    cfg = _config()
+    ref, hf = _reference(), tiny_hf(cfg)
+    prompt = _prompt(10)
+    full = ref.generate(params, prompt, 14, hf)
+    eos = full[7]                       # inside the third block
+    cut = full[:full.index(eos) + 1]
+    assert ref.generate(params, prompt, 14, hf, eos_id=eos) == cut
+    (req,), t = _generate(params, [(prompt, 14)], eos_id=eos)
+    assert req.generated == cut and req.outcome == "completed"
+    assert t["tokens_committed"] == len(cut) and t["tokens_discarded"] > 0
+
+
+def test_a_sampled_request_with_the_engines_key_replayed(params):
+    """Temperature 1: the engine's key is split as ``decode_window`` splits
+    it (key', sub = split(key); one of split(sub, steps) a forward), and a
+    forward draws every slot's rows at once; the reference, handed those
+    draws, gives the engine's tokens."""
+    from orion_tpu.infer.sampling import sample
+
+    cfg = _config("inference.temperature=1.0")
+    ref, hf = _reference(), tiny_hf(cfg)
+    B, L, S = cfg.inference.max_batch_size, 4, cfg.inference.denoising_steps
+    prompt = _prompt(9)
+    (req,), _ = _generate(params, [(prompt, 11)], "inference.temperature=1.0",
+                          seed=5)
+    keys, key = {}, jax.random.PRNGKey(5)
+    for b in range(9 // 4, -(-(9 + 11) // 4)):
+        key, sub = jax.random.split(key)
+        keys[b] = jax.random.split(sub, S)
+
+    def draw(logits, b, s):
+        rows = jnp.zeros((B * L, logits.shape[-1])).at[:L].set(logits)
+        return np.asarray(sample(rows, keys[b][s], temperature=1.0))[:L]
+
+    want = ref.generate(params, prompt, 11, hf, draw=draw)
+    assert req.generated == want
+    assert want != ref.generate(params, prompt, 11, hf)
+
+
+def test_a_prompt_token_equal_to_the_mask_id_stays_a_token(params):
+    cfg = _config()
+    ref, hf = _reference(), tiny_hf(cfg)
+    mask_id = cfg.model.mask_token_id
+    prompt = _prompt(14)
+    prompt[3] = prompt[9] = prompt[13] = mask_id     # the last in the tail
+    other = list(prompt)
+    other[13] = 7
+    (a, b), _ = _generate(params, [(prompt, 6), (other, 6)])
+    assert a.generated == ref.generate(params, prompt, 6, hf)
+    assert b.generated == ref.generate(params, other, 6, hf)
+    assert a.generated != b.generated    # the token was read, not a mask
+
+
+def test_what_a_commit_leaves_and_what_no_later_block_reads(params):
+    """After each commit the pages hold what a full forward of the final
+    tokens gives. The rows a denoising forward writes lie beyond the cursor
+    and are never read by a later block: poisoned (1e3 over everything
+    beyond the cursor, before every dispatch; finite, since a masked pair
+    still multiplies its value by 0), the tokens do not move."""
+    from benchmarks.kinds import serve
+
+    cfg = _config()
+    ref, hf = _reference(), tiny_hf(cfg)
+    prompt = _prompt(10)
+    want = ref.generate(params, prompt, 9, hf)
+    engine = _engine(params)
+    run = engine._executor.run
+    L, psz = 4, cfg.inference.page_size
+
+    def poisoned(path, name, *args, **kw):
+        if path == "decode":
+            cursor = int(np.asarray(args[4])[0])
+            pages = np.asarray(args[5])[0]
+            pos = np.arange(cursor, len(pages) * psz)
+            pos = pos[pages[pos // psz] > 0]
+            rows = (np.arange(cfg.model.n_layers)[:, None]
+                    * cfg.inference.num_pages + pages[pos // psz]).ravel()
+            cols = np.tile(pos % psz, cfg.model.n_layers)
+            cache = {leaf: a.at[rows, :, cols].set(1e3) if leaf in "kv"
+                     else a for leaf, a in args[1].items()}
+            args = (args[0], cache, *args[2:])
+        return run(path, name, *args, **kw)
+
+    engine._executor.run = poisoned
+    req = engine.submit_request(prompt, 9)
+    table = None
+    while engine.has_work():
+        engine.step()
+        if engine.slots[0] is not None:
+            table, cursor = engine.page_table.copy(), int(engine.seq_lens[0])
+            final = jnp.asarray((prompt + req.generated)[:cursor], jnp.int32)
+            k, v = ref.kv_of(params, final, hf)
+            got = np.asarray(serve._kv_at(
+                engine.cache, jnp.asarray(table), 0, jnp.arange(cursor),
+                cfg.model.n_layers, cfg.inference.num_pages, psz))
+            want_kv = np.concatenate(
+                [np.asarray(k).ravel(), np.asarray(v).ravel()])
+            assert cursor % L == 0 and _rel(got, want_kv) < 2e-4
+    engine._executor.run = run
+    assert table is not None and req.generated == want
+
+
+def test_preemption_falls_on_block_boundaries(params):
+    """A pool too small for both requests: one is preempted between blocks
+    and re-admitted on its context (a whole number of blocks since its
+    prompt's first), and both still give the reference's tokens."""
+    cfg = _config("inference.num_pages=9", "inference.max_batch_size=2")
+    ref, hf = _reference(), tiny_hf(cfg)
+    requests = [(_prompt(16, seed=3), 24), (_prompt(14, seed=4), 22)]
+    engine = InferenceEngine(cfg, params, seed=0)
+    reqs = [engine.submit_request(p, m) for p, m in requests]
+    while engine.has_work():
+        engine.step()
+    assert engine.preemptions > 0
+    assert [r.generated for r in reqs] == [
+        ref.generate(params, p, m, hf) for p, m in requests]
+    engine.close()
+
+
+# -- refusals -----------------------------------------------------------------
+
+
+@pytest.mark.parametrize("override,named", [
+    ("inference.prefix_cache=true", "inference.prefix_cache"),
+    ("inference.host_tier_bytes=1000000", "inference.host_tier_bytes"),
+    ("inference.speculative=true", "inference.speculative"),
+    ("inference.constrained=true", "inference.constrained"),
+    ("inference.chunked_prefill=true", "inference.chunked_prefill"),
+    ("inference.kv_quant=int8", "inference.kv_quant"),
+    ("model.weight_quant=int8", "model.weight_quant"),
+])
+def test_what_block_generation_is_not_served_with_is_refused_by_name(
+        params, override, named):
+    with pytest.raises(ValueError) as e:
+        InferenceEngine(_config(override), params)
+    assert "generates by diffusion over blocks (model.block_length)" in str(
+        e.value)
+    assert "the block program only" in str(e.value) and named in str(e.value)
+
+
+def test_sizes_a_block_would_straddle_and_migration_are_refused(params):
+    with pytest.raises(ValueError, match="multiples of model.block_length"):
+        InferenceEngine(_config("inference.max_seq_len=126"), params)
+    engine = _engine(params)
+    req = engine.submit_request(_prompt(9), 8)
+    engine.step()
+    with pytest.raises(ValueError, match="model.block_length"):
+        engine.export_migration_state(req.rid)
+    while engine.has_work():
+        engine.step()
+    from orion_tpu.obs.parts import PROGRAM_NAMES
+
+    assert PROGRAM_NAMES["denoise"] == "orion_denoise_block"
+    assert [s for s, n in PROGRAM_NAMES.items() if "decode_window" in n] == [
+        "decode"]
+
+
+def test_a_tp_mesh_is_refused_by_name(params):
+    """Weights on a mesh with a live ``tp`` axis under interpreted kernels:
+    the block program has not been run per shard."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    if len(jax.devices()) < 2:
+        pytest.skip("one device: no tp axis to refuse on")
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
+    sharded = jax.device_put(params, NamedSharding(mesh, P()))
+    with pytest.raises(ValueError, match="served on one device") as e:
+        InferenceEngine(_config("model.kernels=pallas_interpret"), sharded)
+    assert "model.block_length" in str(e.value)
+
+
+def test_an_autoregressive_model_traces_what_it_traced():
+    """``block_length = 0`` (every other preset): no block program is built,
+    the engine reports its own decode window and the block counters stay 0
+    (the flash wrapper under ``block=0``: the test of its statics above)."""
+    cfg = get_config("tiny-mixtral", ["inference.max_seq_len=64"])
+    assert cfg.model.block_length == 0
+    p = init_params(cfg.model, jax.random.key(0))
+    engine = InferenceEngine(cfg, p)
+    assert not hasattr(engine, "_denoise")
+    assert engine.decode_window == cfg.inference.decode_window
+    t = engine.reset_timing()
+    assert t["denoise_dispatches"] == t["tokens_committed"] == 0
+    engine.close()

@@ -8,7 +8,8 @@ line taken out, and compares two such dumps:
     python tools/program_diff.py compare <dir-a> <dir-b>
 
 ``dump`` writes, per serving cell of BENCHMARK.json, the optimized HLO of
-``decode_window`` and of the cell's widest and narrowest prefill shape, and
+``decode_window`` (of ``denoise_block`` for a model that generates by blocks)
+and of the cell's widest and narrowest prefill shape, and
 for each training cell (the four-chip one on the described 2x2) the lowered
 and the optimized text of the donated train step. Taken out: op metadata
 (scope paths with it), the module's name, ``loc(...)``, the compiled text's tables
@@ -147,18 +148,35 @@ def dump(tree: str, out: str) -> int:
         key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
         u32 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.uint32,
                                                sharding=one_chip)
-        decode = jax.jit(_jitted(
-            "decode", runner.decode_window, cfg=mcfg,
-            max_seq_len=icfg.max_seq_len, mesh=None, nan_guard=False,
-            temperature=icfg.temperature, top_k=icfg.top_k,
-            top_p=icfg.top_p, **({"window": W} if chained else {})),
-            donate_argnums=(1,))
-        keys = u32(*key.shape) if chained else jax.ShapeDtypeStruct(
-            (W,), jax.random.key(0).dtype, sharding=one_chip)
-        save(f"{name}.decode_window.compiled.txt", decode.lower(
-            params, cache, i32(B), i32(B), i32(B, pages_per_seq(icfg)),
-            jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip), keys,
-        ).compile().as_text())
+        if getattr(mcfg, "block_length", 0):
+            # A model that generates by blocks runs the block program where
+            # the others run the decode window.
+            L = mcfg.block_length
+            block = jax.jit(_jitted(
+                "denoise", runner.denoise_block, cfg=mcfg,
+                max_seq_len=icfg.max_seq_len, mesh=None, nan_guard=False,
+                steps=icfg.denoising_steps, remasking=icfg.remasking,
+                threshold=icfg.confidence_threshold,
+                temperature=icfg.temperature, top_k=icfg.top_k,
+                top_p=icfg.top_p), donate_argnums=(1,))
+            save(f"{name}.denoise_block.compiled.txt", block.lower(
+                params, cache, i32(B, L), i32(B), i32(B),
+                i32(B, pages_per_seq(icfg)),
+                jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip),
+                u32(*key.shape)).compile().as_text())
+        else:
+            decode = jax.jit(_jitted(
+                "decode", runner.decode_window, cfg=mcfg,
+                max_seq_len=icfg.max_seq_len, mesh=None, nan_guard=False,
+                temperature=icfg.temperature, top_k=icfg.top_k,
+                top_p=icfg.top_p, **({"window": W} if chained else {})),
+                donate_argnums=(1,))
+            keys = u32(*key.shape) if chained else jax.ShapeDtypeStruct(
+                (W,), jax.random.key(0).dtype, sharding=one_chip)
+            save(f"{name}.decode_window.compiled.txt", decode.lower(
+                params, cache, i32(B), i32(B), i32(B, pages_per_seq(icfg)),
+                jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip), keys,
+            ).compile().as_text())
         todo = serve.cell_prefill_shapes(cell, icfg)
         size = lambda s: (s[0] * s[1], s[0])
         prefill = jax.jit(_jitted(
