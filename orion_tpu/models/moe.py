@@ -507,21 +507,22 @@ def takes_grouped_path(cfg: ModelConfig, B: int, S: int, mesh=None) -> bool:
         (static shapes for the all-to-all);
     (c) the grouped matmul visits fewer MXU rows than the buckets hold, its
         tile rounding counted at the worst case (every expert's group ends
-        inside a tile): ``k*T + E*tm < E*B*C``. Decode (``[32, 1, D]``: 64
-        + 8*tm against 256) stays on the buckets, where all E experts'
-        weights are read whichever path runs; a bucket there is one row
-        deep and holds the batch row itself or nothing, so
+        inside a tile of the widest row tile, ``ROW_TILE``, whatever tile
+        the call's shape then gets): ``k*T + E*tm < E*B*C``. Decode
+        (``[32, 1, D]``: 64 + 8*tm against 256) stays on the buckets, where
+        all E experts' weights are read whichever path runs; a bucket there
+        is one row deep and holds the batch row itself or nothing, so
         ``moe_mlp_sorted`` hands the experts the block broadcast over them
         and scatters nothing (``_broadcast_dispatch``).
     """
-    from orion_tpu.ops.grouped_matmul import TILE_M
+    from orion_tpu.ops.grouped_matmul import ROW_TILE
 
     if cfg.moe_dispatch == "einsum" or moe_capacity(cfg, S) < S:
         return False
     if mesh is not None and mesh.shape.get("ep", 1) > 1:
         return False
     E, k = cfg.n_experts, cfg.n_experts_per_token
-    return k * B * S + E * TILE_M < _bucket_rows(cfg, B, S)
+    return k * B * S + E * ROW_TILE < _bucket_rows(cfg, B, S)
 
 
 def expert_rows(cfg: ModelConfig, B: int, S: int,
@@ -553,11 +554,11 @@ def held_row_bound(cfg: ModelConfig, tokens: int) -> int:
     the bound costs one further pass and loses nothing (``_bounded_rows``).
     The prefill program counts those blocks
     (``engine.timing["prefill_held_bound_overflows"]``)."""
-    from orion_tpu.ops.grouped_matmul import TILE_M
+    from orion_tpu.ops.grouped_matmul import ROW_TILE
 
     kt = cfg.n_experts_per_token * tokens
     twice = -(-2 * kt * cfg.n_experts // cfg.resolved_router_width)
-    return min(-(-twice // TILE_M) * TILE_M, kt)
+    return min(-(-twice // ROW_TILE) * ROW_TILE, kt)
 
 
 def bounds_held_rows(cfg: ModelConfig, tokens: int) -> bool:

@@ -174,7 +174,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_whole_layer(
     over the held ones. Both dispatches: capacity buckets (decode-sized
     blocks) and the grouped matmul (prefill)."""
     if grouped:      # the rule's tile term keeps tiny blocks off this path
-        monkeypatch.setattr("orion_tpu.ops.grouped_matmul.TILE_M", 1)
+        monkeypatch.setattr("orion_tpu.ops.grouped_matmul.ROW_TILE", 1)
     cfg, params = tiny
     m, ref = cfg.model, _reference()
     bp = jax.tree.map(lambda a: a[0], params["blocks"]["period"]["1"])

@@ -321,7 +321,7 @@ def test_the_sixteen_shares_of_an_expert_layer_add_up_to_the_whole_layer(
     twice), adds up to what the uncut reference gives for the whole layer;
     a share equals the reference's own cut. Both dispatches."""
     if grouped:      # the rule's tile term keeps tiny blocks off this path
-        monkeypatch.setattr("orion_tpu.ops.grouped_matmul.TILE_M", 1)
+        monkeypatch.setattr("orion_tpu.ops.grouped_matmul.ROW_TILE", 1)
     cfg, params = tiny
     m, ref = cfg.model, _reference()
     bp = jax.tree.map(lambda a: a[0], params["blocks"]["period"]["1"])

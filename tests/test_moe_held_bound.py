@@ -70,13 +70,13 @@ DTYPES = pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
 
 
 def test_the_bound_is_twice_the_share_in_whole_row_tiles():
-    from orion_tpu.ops.grouped_matmul import TILE_M
+    from orion_tpu.ops.grouped_matmul import ROW_TILE
 
     cfg = _layer(jnp.float32)[0]
     k, T = cfg.n_experts_per_token, B * S
     assert moe_lib.held_row_bound(cfg, T) == 256 == k * T // 4
     assert moe_lib.held_row_bound(cfg, 8 * T) == k * 8 * T // 8
-    assert moe_lib.held_row_bound(cfg, 8 * T + 8) % TILE_M == 0
+    assert moe_lib.held_row_bound(cfg, 8 * T + 8) % ROW_TILE == 0
     assert moe_lib.held_row_bound(cfg, 2) == k * 2      # never past k x T
     assert moe_lib.bounds_held_rows(cfg, T)
     assert not moe_lib.bounds_held_rows(cfg, T // 2)    # the tile is a half

@@ -1553,3 +1553,96 @@ def test_flash_visits_exactly_the_live_blocks(shape):
     assert counts["unmasked"] == skip.sum()
     if Sq % bq == 0:
         assert skip.sum() == (whole & live).sum()
+
+
+# -- the grouped matmul and the tiles it is lowered with ----------------------
+
+# (m, group sizes of G = 4; rows behind their sum belong to no group)
+_GMM_CASES = {
+    "even": (512, [128, 128, 128, 128]),
+    "empty_groups": (512, [0, 300, 0, 212]),
+    "a_group_over_a_tile_edge": (512, [100, 60, 250, 102]),
+    "rows_behind_the_last_group": (512, [70, 0, 90, 33]),
+    "m_no_multiple_of_the_tile": (328, [40, 200, 8, 80]),
+}
+# What ``_tiles`` can return, at toy widths (k, n) = (192, 320): the whole
+# matrix as one weight tile under either row tile, n-tiles that do not divide
+# n (768 under 512), a contraction cut in k-tiles; "rule" is the rule itself.
+_GMM_TILES = {
+    "rule": None,
+    "one_tile_128": (128, 192, 320), "one_tile_256": (256, 192, 320),
+    "n_tiles": (256, 192, 128), "k_tiles": (256, 64, 256),
+}
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["flat", "layer_stack"])
+@pytest.mark.parametrize("case", list(_GMM_CASES))
+@pytest.mark.parametrize("tiles", list(_GMM_TILES))
+def test_grouped_matmul_against_ragged_dot(tiles, case, stacked, monkeypatch):
+    """The megablox kernel (interpreted) under every form of tiling against
+    ``lax.ragged_dot`` on the rows that belong to a group: a row's result
+    does not depend on which rows share its tile. ``layer_stack`` reads one
+    layer's matrices out of a stack of three in place."""
+    from orion_tpu.ops import grouped_matmul as gm
+
+    if _GMM_TILES[tiles]:
+        monkeypatch.setattr(gm, "_tiles", lambda *a, **kw: _GMM_TILES[tiles])
+    m, sizes = _GMM_CASES[case]
+    k, n, G = 192, 320, len(sizes)
+    lhs = _rand(1, m, k)
+    rhs = _rand(2, 3, G, k, n)
+    gs = jnp.asarray(sizes, jnp.int32)
+    want = jax.lax.ragged_dot(lhs, rhs[1], gs)
+    if stacked:
+        got = gm.grouped_matmul(lhs, rhs, gs, impl="pallas_interpret",
+                                layer=jnp.int32(1))
+    else:
+        got = gm.grouped_matmul(lhs, rhs[1], gs, impl="pallas_interpret")
+    assert got.shape == (m, n) and got.dtype == lhs.dtype
+    real = sum(sizes)
+    assert np.abs(np.asarray(want[:real])).max() > 1
+    np.testing.assert_allclose(got[:real], want[:real], rtol=1e-5, atol=1e-4)
+
+
+def test_the_tiles_follow_the_calls_shape():
+    """``_tiles(m, G, k, n)``: Mixtral's call shapes, and every call at the
+    widths the wide tiles were fitted on, return those tiles; so do the
+    widths whose whole matrix does not fit the VMEM as one tile, forward or
+    in reverse; SDAR's block forward (32 rows a group) gets a smaller row
+    tile and n-tiles that divide n; every tile divides the padded m and
+    fits 16 MiB of scoped VMEM."""
+    from orion_tpu.ops.grouped_matmul import (
+        ROW_TILE, VMEM_BYTES, _tiles, tile_vmem_bytes,
+    )
+
+    wide_in, wide_out = (256, 4096, 512), (256, 1024, 2048)
+    for m in (1024, 2048, 3072, 4096, 6144, 8192):      # Mixtral's prefill
+        assert _tiles(m, 8, 4096, 14336) == wide_in
+        assert _tiles(m, 8, 14336, 4096) == wide_out
+    for G in (8, 64, 128):
+        for rows in (1, 32, 256, 1024):
+            assert _tiles(rows * G, G, 4096, 14336) == wide_in
+            assert _tiles(rows * G, G, 14336, 4096) == wide_out
+    # one tile forward, refused in reverse (2560 x 768), or too wide for one
+    for k, n in ((2560, 768), (3072, 1024), (2048, 1536), (4096, 2048)):
+        for m in (5120, 8192, 65536):
+            assert _tiles(m, 128, k, n) == (256, k, 512)
+            assert _tiles(m, 128, n, k) == (256, n, 512)
+    # SDAR: 128 experts of [2048, 768] and [768, 2048]
+    assert _tiles(4096, 128, 2048, 768) == (128, 2048, 768)
+    assert _tiles(4096, 128, 768, 2048) == (128, 768, 2048)
+    assert _tiles(8192, 128, 2048, 768)[0] == 128            # 64 rows a group
+    for m in (16384, 32768, 65536):                          # 128 and more
+        assert _tiles(m, 128, 2048, 768) == (256, 2048, 768)
+        assert _tiles(m, 128, 768, 2048) == (256, 768, 2048)
+    for m in (8, 328, 4096, 40960):
+        for G in (2, 8, 128, 768):
+            for k, n in ((64, 32), (192, 320), (768, 2048), (2048, 768),
+                         (2560, 768), (3072, 1024), (4096, 14336),
+                         (14336, 4096)):
+                tm, tk, tn = _tiles(m, G, k, n)
+                assert tm <= ROW_TILE and (m + -m % tm) % tm == 0
+                assert tm % 16 == 0 and tk <= max(k, 1024) and tn <= n
+                assert tile_vmem_bytes(tm, tk, tn) <= VMEM_BYTES
+                if tn < n:          # a cut n is cut in whole lanes
+                    assert tn % 128 == 0

@@ -283,6 +283,60 @@ def test_prefill_logits_and_pages_against_the_reference(params, kernels):
     assert _rel(got, want_kv) < 2e-4
 
 
+def test_a_block_forward_at_32_rows_an_expert_under_a_skewed_router(
+        params, monkeypatch):
+    """32 slots x 4 positions x 2 picks over 8 experts, the router leaning on
+    two of them: the block forward takes the grouped matmul (interpreted)
+    under the row tile its shape gets, 128 and not the widest, and gives
+    what the capacity buckets give and what the reference gives a block."""
+    from orion_tpu.infer import runner
+    from orion_tpu.infer.kv_cache import init_cache, pages_per_seq
+    from orion_tpu.models import moe as moe_lib
+    from orion_tpu.ops import grouped_matmul as gm
+
+    B = 32
+    moe = params["blocks"]["moe"]
+    lean = moe["router"] * jnp.asarray([3.0, 3.0, 1, 1, 1, 1, 1, 1])
+    params = {**params, "blocks": {**params["blocks"],
+                                   "moe": {**moe, "router": lean}}}
+    fed = jnp.asarray(np.random.default_rng(5).integers(1, 255, (B, 4)),
+                      jnp.int32)
+    table = jnp.zeros((B, pages_per_seq(_config().inference)), jnp.int32
+                      ).at[:, 0].set(1 + jnp.arange(B))
+
+    def forward(*overrides):
+        cfg = _config(f"inference.max_batch_size={B}", *overrides)
+        logits, _ = runner.block_forward(
+            params, init_cache(cfg.model, cfg.inference), fed,
+            jnp.zeros((B,), jnp.int32), table, jnp.ones((B,), bool),
+            cfg.model, cfg.inference.max_seq_len)
+        return cfg, logits
+
+    cfg, buckets = forward()
+    m = cfg.model
+    _, _, idx = moe_lib._router_topk(
+        jax.random.normal(jax.random.key(0), (B, 4, m.d_model)), lean[0], m)
+    counts = np.bincount(np.asarray(idx).ravel(), minlength=8)
+    assert counts[:2].sum() > 1.5 * counts.sum() / 4     # not an even router
+    assert not moe_lib.takes_grouped_path(m, B, 4)
+    # the path rule's tile term keeps toy blocks on the buckets
+    monkeypatch.setattr(gm, "ROW_TILE", 1)
+    assert moe_lib.takes_grouped_path(m, B, 4)
+    seen, rule = [], gm._tiles
+    monkeypatch.setattr(
+        gm, "_tiles",
+        lambda *a, **kw: seen.append((a, rule(*a, **kw))) or seen[-1][1])
+    _, grouped = forward("model.kernels=pallas_interpret")
+    assert {a for a, _ in seen} == {
+        (256, 8, m.d_model, m.moe_d_ff), (256, 8, m.moe_d_ff, m.d_model)}
+    assert {t[0] for _, t in seen} == {128}
+    assert _rel(grouped, buckets) < 2e-5
+    ref, hf = _reference(), tiny_hf(cfg)
+    for b in (0, 13, 31):
+        want, _ = ref.logits_at(params, fed[b], jnp.arange(4), hf)
+        assert _rel(grouped[b], want) < 2e-4
+
+
 CASES = [
     # (strategy, steps, prompt length, max_new)
     ("low_confidence_static", 1, 8, 8),
