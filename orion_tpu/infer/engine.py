@@ -857,6 +857,10 @@ class InferenceEngine:
     # measurements. A phase that raises books nothing: a failed dispatch's
     # time stays with its parent and ends in host_s. The fallback phases
     # mark an XLA retry inside ``<path>/run`` and book nothing of their own.
+    # The launch and wait phases are the executor's (ISSUE 56), entered once
+    # each a dispatch inside the caller's ``<path>/run``: a phase books SELF
+    # time, so each feeds its own leaf and then every key its run parent
+    # feeds, and the parent's keys read what they read without the children.
     _PHASE_KEYS = {
         "step": ("step_self_s", "host_s"),
         "reap": ("reap_s", "host_s"),
@@ -878,6 +882,23 @@ class InferenceEngine:
         "page_in": ("page_in_s",),
         "migrate_out": ("migrate_out_s",),
         "migrate_in": ("migrate_in_s",),
+        "prefill/launch": ("prefill_launch_s", "prefill_run_s", "prefill_s"),
+        "prefill/wait": ("prefill_wait_s", "prefill_run_s", "prefill_s"),
+        "decode/launch": ("decode_launch_s", "decode_run_s",
+                          "decode_device_s", "device_s"),
+        "decode/wait": ("decode_wait_s", "decode_run_s",
+                        "decode_device_s", "device_s"),
+        "verify/launch": ("verify_launch_s", "verify_run_s",
+                          "decode_device_s", "device_s"),
+        "verify/wait": ("verify_wait_s", "verify_run_s",
+                        "decode_device_s", "device_s"),
+        "fold/launch": ("fold_launch_s", "fold_s",
+                        "decode_device_s", "device_s"),
+        "mixed/launch": ("mixed_launch_s", "mixed_device_s", "device_s"),
+        "mixed/wait": ("mixed_wait_s", "mixed_device_s", "device_s"),
+        "mixed_verify/launch": ("mixed_launch_s", "mixed_device_s",
+                                "device_s"),
+        "mixed_verify/wait": ("mixed_wait_s", "mixed_device_s", "device_s"),
         "prefill/fallback": (),
         "fold/fallback": (),
         "decode/fallback": (),
@@ -1353,6 +1374,7 @@ class InferenceEngine:
         path, from data rather than assertion.
         """
         with self._phase("step") as span:
+            idle0 = self._executor.unqueued_until(span.t0)
             if self._watchdog is not None and self._watchdog.armed:
                 # Refresh at step START so idle gaps between caller-driven
                 # steps never read as stalls — only time INSIDE a step
@@ -1467,6 +1489,8 @@ class InferenceEngine:
                         **self._trace_ctx(r),
                     )
                 span.tags["decoded"] = bool(decoded)
+        self.timing["unqueued_in_step_s"] += (
+            self._executor.unqueued_until(span.t1) - idle0)
         self.step_no += 1
         done, self._just_finished = self._just_finished, []
         return done
@@ -1489,6 +1513,33 @@ class InferenceEngine:
             "decode_build_s": 0.0, "decode_run_s": 0.0,
             "decode_fetch_s": 0.0, "emit_s": 0.0, "step_self_s": 0.0,
             "verify_run_s": 0.0, "compact_s": 0.0,
+            # The dispatch seam (executor.run / wait, ISSUE 56). Inside each
+            # ``<path>/run`` the program's call and the wait for it are
+            # leaves of their own, and feed their parent's keys too:
+            #   prefill_run_s == its uploads + prefill_launch_s
+            #                    + prefill_wait_s
+            # and so for decode, verify, fold (fold_s: launched, never
+            # waited for) and mixed (mixed_device_s, which also holds the
+            # fetch; mixed_verify books under the same two leaves).
+            "prefill_launch_s": 0.0, "prefill_wait_s": 0.0,
+            "decode_launch_s": 0.0, "decode_wait_s": 0.0,
+            "verify_launch_s": 0.0, "verify_wait_s": 0.0,
+            "fold_launch_s": 0.0,
+            "mixed_launch_s": 0.0, "mixed_wait_s": 0.0,
+            # Launches, and the launches a wait covered (its own and every
+            # earlier one: the device runs them in order, and a fold's wait
+            # is the window's), so launches - waits over a window is what it
+            # left in flight. unqueued_s: host seconds with NOTHING queued on
+            # the device, from the return of the wait that covered the
+            # newest launch to the start of the next launch (booked when it
+            # ends, to the window open then); unqueued_in_step_s the part of
+            # it inside ``orion/step`` (the rest is the caller's), booked by
+            # step() at its end from the executor's reading at its two
+            # edges, so an interval still open there is in it already;
+            # unqueued_max_s the longest single interval: a stall, by its
+            # size. Always on, traced or not.
+            "launches": 0, "waits": 0, "unqueued_s": 0.0,
+            "unqueued_in_step_s": 0.0, "unqueued_max_s": 0.0,
             # A power-retention model (all 0 for a K/V model): fold_s the
             # fold dispatches at the start of a decode window (``folds`` of
             # them: one a slot whose tail holds a complete chunk); per token
@@ -1581,39 +1632,29 @@ class InferenceEngine:
             # hold for them and those positions' bytes there, and the bytes
             # of the pages the pool holds for them (kv_full_page_bytes_held:
             # whole pages, out to the end of the prompt's bucket and the
-            # window ahead); the positions a window layer still holds of
-            # them (a ring's reach at most) and their bytes over all window
-            # layers.
-            # decode_kv_pages_read by the leaves walked, and the times a
-            # slot's write came round to its ring's first page again; and
-            # decode_kv_token_layers by the same split (the two kinds differ
-            # in their K/V heads, so in bytes a position). Its prefill's
-            # prefill_attn_pairs: the (query, key) pairs each layer's own
-            # mask keeps of a real prompt, summed over the layers.
+            # window ahead); the bytes, over all window layers, of the
+            # positions a window layer still holds of them (a ring's reach
+            # at most).
+            # decode_kv_token_layers by the leaves walked (the two kinds
+            # differ in their K/V heads, so in bytes a position). Its
+            # prefill's prefill_attn_pairs: the (query, key) pairs each
+            # layer's own mask keeps of a real prompt, summed over the layers.
             "kv_full_positions_live": 0, "kv_full_bytes_live": 0,
-            "kv_full_page_bytes_held": 0,
-            "kv_window_positions_held": 0, "kv_window_bytes_held": 0,
-            "decode_kv_pages_read_full": 0, "decode_kv_pages_read_ring": 0,
+            "kv_full_page_bytes_held": 0, "kv_window_bytes_held": 0,
             "decode_kv_token_layers_full": 0, "decode_kv_token_layers_ring": 0,
-            "window_ring_wraps": 0,
             # A model that generates by diffusion over blocks (all 0 for
-            # any other): block programs dispatched and the forwards inside
-            # them (denoising and commit); live slots x forwards
-            # (block_slot_forwards), the positions those fed
-            # (block_positions_fed) and of them the ones fed as the mask
-            # token, from the program's own record of the forward that
-            # decided each (block_positions_undecided_fed); tokens emitted
-            # (tokens_committed) and tokens of a committed block beyond
-            # max_new_tokens or an EOS (tokens_discarded); first blocks
-            # that carried a prompt's tail; and, summed over slots and
-            # forwards, the cached positions a forward's attention read,
-            # the block's own among them (block_kv_positions_read). Host
-            # arithmetic on lengths, no device value read.
-            "denoise_dispatches": 0, "denoise_forwards": 0,
-            "commit_forwards": 0, "block_slot_forwards": 0,
-            "block_positions_fed": 0, "block_positions_undecided_fed": 0,
-            "tokens_committed": 0, "tokens_discarded": 0,
-            "blocks_with_prompt_tail": 0, "block_kv_positions_read": 0,
+            # any other; a block program counts as a window, and holds
+            # inference.denoising_steps + 1 forwards): live slots x forwards
+            # (block_slot_forwards: x the block length, the positions they
+            # fed) and of those positions the ones fed as the mask token,
+            # from the program's own record of the forward that decided
+            # each (block_positions_undecided_fed); tokens emitted
+            # (tokens_committed); and, summed over slots and forwards, the
+            # cached positions a forward's attention read, the block's own
+            # among them (block_kv_positions_read). Host arithmetic on
+            # lengths, no device value read.
+            "block_slot_forwards": 0, "block_positions_undecided_fed": 0,
+            "tokens_committed": 0, "block_kv_positions_read": 0,
             # Per-phase device split (ISSUE 20 load-gauge satellite):
             # decode_device_s covers pure decode-phase dispatches
             # (decode windows, verify, draft compaction) and pairs with
@@ -3339,11 +3380,6 @@ class InferenceEngine:
                 self.mcfg.n_layers_of("latent") * sum(
                     int(n) * (int(n) + 1) // 2 for n in lengths[: len(reqs)]))
         if self.mcfg.has_window_ring:
-            # A prompt's pages go round its ring too (the program writes
-            # the last of them alone).
-            rp = self.cache[RING_K].shape[2]
-            self.timing["window_ring_wraps"] += sum(
-                (int(n) - 1) // self.psz // rp for n in lengths[: len(reqs)])
             for window, layers in self._layers_by_window.items():
                 for n in lengths[: len(reqs)]:
                     n, w = int(n), int(n) if window is None else min(
@@ -4240,14 +4276,8 @@ class InferenceEngine:
                     jnp.asarray(self.slot_top_k),
                     jnp.asarray(self.slot_top_p),
                 )
-            t = self.timing
-            t["denoise_dispatches"] += 1
-            t["denoise_forwards"] += S
-            t["commit_forwards"] += 1
-            t["block_slot_forwards"] += (S + 1) * len(active)
-            t["block_positions_fed"] += (S + 1) * L * len(active)
-            t["blocks_with_prompt_tail"] += int((tails[mask] > 0).sum())
-            t["block_kv_positions_read"] += (S + 1) * (
+            self.timing["block_slot_forwards"] += (S + 1) * len(active)
+            self.timing["block_kv_positions_read"] += (S + 1) * (
                 int(self.seq_lens[mask].sum()) + L * len(active))
         with self._phase("decode/run"):
             out = self._executor.run("decode", name, *args)
@@ -4280,8 +4310,7 @@ class InferenceEngine:
                 slot, tail = req.slot, int(tails[req.slot])
                 for tok in toks[slot, tail:].tolist():
                     if req.done:
-                        self.timing["tokens_discarded"] += 1
-                        continue
+                        break   # beyond max_new_tokens or an EOS: discarded
                     self.last_token[slot] = tok
                     req.generated.append(tok)
                     self.timing["tokens_committed"] += 1
@@ -4417,13 +4446,10 @@ class InferenceEngine:
                     "full" if window is None else "ring")] += n * int(
                         read.sum())
             first = 0 if window is None else np.maximum(steps - window + 1, 0)
-            pages = n * int((steps // self.psz - first // self.psz + 1).sum())
-            self.timing["decode_kv_pages_read"] += pages
-            if self.mcfg.has_window_ring:
-                self.timing["decode_kv_pages_read_" + (
-                    "full" if window is None else "ring")] += pages
+            self.timing["decode_kv_pages_read"] += n * int(
+                (steps // self.psz - first // self.psz + 1).sum())
         if self.mcfg.has_window_ring:
-            self._count_split_cache(lens, steps)
+            self._count_split_cache(lens)
         if self._window_layers:
             # A page is dead for a window layer when the query at position
             # len reads none of it: its last position is under len - window.
@@ -4434,10 +4460,9 @@ class InferenceEngine:
             self.timing["kv_dead_window_page_layers"] += (
                 self._window_layers * int(dead.sum()))
 
-    def _count_split_cache(self, lens: np.ndarray, steps: np.ndarray) -> None:
-        """The kv_full_* / kv_window_* / window_ring_wraps counters of one
-        decode window over live slots of lengths ``lens`` (``steps``: each
-        token step's write position), from the leaves' own shapes."""
+    def _count_split_cache(self, lens: np.ndarray) -> None:
+        """The kv_full_* / kv_window_* counters of one decode window over
+        live slots of lengths ``lens``, from the leaves' own shapes."""
         def page_bytes(k, v, layers):   # one page of K and V in ``layers``
             return layers * sum(
                 math.prod(self.cache[n].shape[-3:])
@@ -4452,13 +4477,8 @@ class InferenceEngine:
         t["kv_full_positions_live"] += int(lens.sum())
         t["kv_full_bytes_live"] += int(lens.sum()) * full // self.psz
         t["kv_full_page_bytes_held"] += pages * full
-        t["kv_window_positions_held"] += held
         t["kv_window_bytes_held"] += held * page_bytes(
             RING_K, RING_V, ring[0]) // self.psz
-        page = steps // self.psz
-        t["window_ring_wraps"] += int(
-            ((steps % self.psz == 0) & (page % ring[2] == 0)
-             & (page > 0)).sum())
 
     def _decode_run_window(self, window) -> bool:
         """Launch a built decode window, behind the step's prefill where

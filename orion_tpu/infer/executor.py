@@ -10,7 +10,12 @@ jittered backoff between them — ISSUE 12 satellite), and the
 DispatchFault contract the engine's failed-step containment consumes.
 The envelope has two halves (ISSUE 40): ``run`` launches a program and
 ``wait`` waits for it, so that a caller can queue the next program on
-the device between the two; both end in the same ladder.
+the device between the two; both end in the same ladder. The seam times
+itself (ISSUE 56): each half is a leaf phase of its own
+(``orion/<path>/launch`` around the program's call, ``orion/<path>/wait``
+around ``block_until_ready``), and the executor numbers its launches, so
+that it knows what is in flight and books the host time in which nothing
+is queued (``unqueued_s`` and its siblings in ``reset_timing()``).
 
 The executor holds a back-reference to its engine rather than copies of
 the engine's mutable state (robust stats, injector, tracer): those
@@ -25,7 +30,7 @@ import logging
 import random
 import time
 from functools import partial
-from typing import Any
+from typing import Any, Optional
 
 import jax
 
@@ -53,6 +58,12 @@ def named_program(fn, stem: str, **kw):
     program = partial(fn, **kw)
     program.__name__ = program.__qualname__ = PROGRAM_NAMES[stem]
     return program
+
+
+def _program(name: str) -> str:
+    """The jit's name of dispatch ``name`` (``decode_defaults`` ->
+    ``orion_decode_window``): a launch or wait span's ``program`` tag."""
+    return PROGRAM_NAMES[name.removesuffix("_defaults")]
 
 
 class DispatchExecutor:
@@ -90,6 +101,19 @@ class DispatchExecutor:
         # bound.
         self.picks = self.last_token = self.key = None
         self.held_rows = self.held_overflows = None
+        # What is in flight: launches are numbered, the device runs them
+        # in that order, so a wait retires its own launch and every
+        # earlier one (a fold is launched and never waited for: the
+        # window's wait is its wait). ``_newest[path]`` is the number of
+        # the path's newest launch, ``_retired`` the newest number a wait
+        # has covered. Engine-lifetime state: reset_timing leaves it.
+        self._launched = self._retired = 0
+        self._newest: dict[str, int] = {}
+        # The instant (time.monotonic, the ring's and the buckets' clock)
+        # since which nothing is queued; None while a program is in flight.
+        # An engine that has launched nothing has nothing to be late with:
+        # None.
+        self._idle_since: Optional[float] = None
 
     def jit_program(self, name: str, mcfg, mesh):
         """Build one jitted dispatch program. ``name`` is a coarse path
@@ -240,9 +264,13 @@ class DispatchExecutor:
                 raise InjectedFault(
                     f"injected {path} dispatch fault (step {eng.step_no})"
                 )
-            # The caller's ``orion/<path>/run`` phase (engine._phase) names
-            # this dispatch in the ring and in a device profile.
-            return getattr(eng, "_" + name)(*args, **kwargs)
+            # The call alone: the caller's ``orion/<path>/run`` phase
+            # (engine._phase) holds its uploads too. A call that raises
+            # numbers no launch and leaves the unqueued interval open.
+            with eng._phase(path + "/launch") as span:
+                out = getattr(eng, "_" + name)(*args, **kwargs)
+                self._launch(path, name, span)
+            return out
         # orion: allow[fault-except] the fault envelope exists to contain ANY dispatch failure (DispatchFault re-raise in _recover)
         except Exception as e:
             return self._recover(path, name, e, args, kwargs)
@@ -267,12 +295,65 @@ class DispatchExecutor:
                 raise InjectedFault(
                     f"injected {path} execute fault (step {eng.step_no})"
                 )
-            # orion: allow[host-sync] THE envelope sync point, once a program and after everything the device needs has been queued: execute-time faults must surface here, not at the caller's fetch
-            jax.block_until_ready(out)
+            with eng._phase(path + "/wait") as span:
+                if span.tags is not None:
+                    span.tags.update(program=_program(name),
+                                     seq=self._newest.get(path, 0))
+                # orion: allow[host-sync] THE envelope sync point, once a program and after everything the device needs has been queued: execute-time faults must surface here, not at the caller's fetch
+                jax.block_until_ready(out)
+            self._retire(self._newest.get(path, 0), span.t1)
             return out
         # orion: allow[fault-except] the fault envelope exists to contain ANY dispatch failure (DispatchFault re-raise in _recover)
         except Exception as e:
+            # What was waited for has left the device, in an error (an
+            # injected one is taken for the device's).
+            self._retire(self._newest.get(path, 0), time.monotonic())
             return self._recover(path, name, e, args, kwargs)
+
+    # -- what is in flight (ISSUE 56) --------------------------------------
+
+    @property
+    def in_flight(self) -> int:
+        """Launches no wait has covered yet."""
+        return self._launched - self._retired
+
+    def unqueued_until(self, now: float) -> float:
+        """Host seconds with nothing queued up to ``now``: what
+        ``unqueued_s`` holds and, where nothing is queued now, the open
+        interval so far. The engine reads it at a step's two edges
+        (``unqueued_in_step_s``)."""
+        booked = self.eng.timing["unqueued_s"]
+        if self._idle_since is None:
+            return booked
+        return booked + (now - self._idle_since)
+
+    def _launch(self, path: str, name: str, span) -> None:
+        """Number the launch that ``span`` (its ``orion/<path>/launch``
+        phase, or a fallback attempt's, still open) made. Where nothing was queued, the interval
+        ends at the span's START: the call's own host time is the
+        ``*_launch_s`` leaf."""
+        t = self.eng.timing
+        if self._idle_since is not None:
+            gap = span.t0 - self._idle_since
+            t["unqueued_s"] += gap
+            if gap > t["unqueued_max_s"]:
+                t["unqueued_max_s"] = gap
+            self._idle_since = None
+        self._launched += 1
+        self._newest[path] = self._launched
+        t["launches"] += 1
+        if span.tags is not None:
+            span.tags.update(program=_program(name), seq=self._launched)
+
+    def _retire(self, upto: int, at: float) -> None:
+        """A wait that returned at ``at`` covered every launch numbered
+        ``upto`` or lower."""
+        if upto <= self._retired:
+            return
+        self.eng.timing["waits"] += upto - self._retired
+        self._retired = upto
+        if upto == self._launched:
+            self._idle_since = at
 
     def _recover(self, path: str, name: str, e: Exception, args, kwargs):
         """A dispatch failed, at its launch or at its wait: count it, then
@@ -306,18 +387,28 @@ class DispatchExecutor:
                 "reference path", path, type(last).__name__, last,
                 attempt + 1, eng.icfg.dispatch_retries,
             )
+            before = self._launched
             try:
-                with eng._phase(path + "/fallback"):
+                with eng._phase(path + "/fallback") as span:
                     out = fb(*args, **kwargs)
+                    # A launch like any other: numbered, and where nothing
+                    # was queued the interval ends at the attempt's start.
+                    self._launch(path, name, span)
                     # orion: allow[host-sync] fallback attempts must surface their own execute-time faults inside the retry loop
                     jax.block_until_ready(out)
             # orion: allow[fault-except] retry-ladder rung: a failed fallback attempt feeds the next retry, then DispatchFault
             except Exception as e2:
+                if self._launched > before:
+                    # Launched, and left the device in an error.
+                    self._retire(self._launched, time.monotonic())
                 eng.robust.dispatch_faults += 1
                 last = e2
                 continue
             eng.robust.dispatch_fallbacks += 1
             eng._flight_note("dispatch_fallback", path=path)
+            # Waited for, behind everything launched before it: nothing is
+            # queued from the attempt's end on.
+            self._retire(self._launched, span.t1)
             return out
         raise DispatchFault(
             path, f"xla fallback failed too: {last}"

@@ -407,19 +407,58 @@ def test_decode_fault_behind_an_unwaited_prefill_keeps_first_tokens(
     eng.assert_page_accounting()
 
 
+def _seam_marks(spans):
+    """(instant, launch's number: negative at a wait's return) of the ring's
+    launch, wait and fallback spans: a launch is made at its span's start, a
+    wait returns at its end, a fallback attempt is both in one span."""
+    marks = []
+    for _, name, t0, t1, tags in spans:
+        kind = name.rsplit("/", 1)[-1]
+        if kind in ("launch", "fallback"):
+            marks.append((t0, tags["seq"]))
+        if kind in ("wait", "fallback"):
+            marks.append((t1, -tags["seq"]))
+    return sorted(marks)
+
+
+def _unqueued_from(spans):
+    """The intervals in which nothing was queued, replayed from the ring."""
+    launched, idle_since, gaps = 0, None, []
+    for at, seq in _seam_marks(spans):
+        if seq > 0:
+            if idle_since is not None:
+                gaps.append(at - idle_since)
+            launched, idle_since = seq, None
+        elif -seq == launched and idle_since is None:
+            idle_since = at
+    return gaps
+
+
 def test_dispatch_fallback_xla_reference(tiny):
     """Degradation ladder rung 1: with kernels=pallas a failed dispatch
     retries once on the XLA reference path — same step, no failed step,
     byte-identical output."""
     params, _ = tiny
-    pall = ["model.kernels=pallas_interpret"] + FALLBACK
-    ref = _engine(params, pall).generate(MIX, 8)
+    pall = ["model.kernels=pallas_interpret", "inference.trace=true"] + FALLBACK
+    sound = _engine(params, pall)
+    ref = sound.generate(MIX, 8)
     inj = FaultInjector([FaultSpec("dispatch", step=2, path="decode")])
     eng = _engine(params, pall, inj=inj)
     assert eng.generate(MIX, 8) == ref
+    spans = [e for e in eng.tracer.events() if e[0] == "span"]
     t = eng.reset_timing()
     assert t["dispatch_fallbacks"] == 1 and t["failed_steps"] == 0
     eng.assert_page_accounting()
+    # The seam's counters (ISSUE 56): the attempt that ran is a launch like
+    # any other, waited for where it ran, and its time on the device (the
+    # fallback program's compile with it) is not time with nothing queued.
+    assert t["launches"] == t["waits"] == sound.reset_timing()["launches"]
+    assert eng._executor.in_flight == 0
+    (attempt,) = (e for e in spans if e[1] == "orion/decode/fallback")
+    assert attempt[4]["program"] == "orion_decode_window"
+    gaps = _unqueued_from(spans)
+    assert t["unqueued_s"] == pytest.approx(sum(gaps), abs=1e-9)
+    assert t["unqueued_max_s"] == pytest.approx(max(gaps), abs=1e-9)
 
 
 @slow   # tier-1 budget, round 11: knob variant of the fallback path;

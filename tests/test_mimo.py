@@ -189,30 +189,38 @@ def test_forward_engine_and_reference_agree_on_logits(tiny, held):
     assert max(n["err"]) < 2e-4
     assert max(n["window_kv_rel_err"]) < 1e-5
     assert min(n["control_err"]) > 50 * max(n["err"])     # the control fails
-    t = engine.reset_timing()
-    assert t["window_ring_wraps"] == 3      # the 50-token prompt's 7 pages
+    t, W = engine.reset_timing(), engine.decode_window
     if held is None:
-        # ... and decode alone takes it round three times more: 52 steps
-        # from position 20 cross into pages 4, 6 and 8.
+        # ... and decode alone takes the ring round three times more: 52
+        # steps from position 20 cross into pages 4, 6 and 8.
         n = serve_rows.probe_numbers(
             engine, ref, hf, {"probe_prompts": [20], "probe_windows": 13}, 4)
         assert max(n["err"]) < 2e-4 and max(n["window_kv_rel_err"]) < 1e-5
         t = engine.reset_timing()
-        assert t["window_ring_wraps"] == 1 + 3
-    assert t["decode_kv_pages_read_full"] > 0 < t["decode_kv_pages_read_ring"]
-    assert (t["decode_kv_pages_read_full"] + t["decode_kv_pages_read_ring"]
-            == t["decode_kv_pages_read"])
+    # The pages the decode kernels walked, by hand (pages of 8): every probe
+    # alone, its windows' new tokens at consecutive positions from its
+    # prompt's length on; a full layer (3 of them) walks every page up to
+    # the new token's, a window layer (4) the pages its last 8 positions
+    # lie in.
+    pos = np.concatenate([np.arange(a, a + b * W) for a, b in (
+        [(20, 13)] if held is None else [(5, 2), (8, 2), (50, 2)])])
+    assert t["decode_kv_pages_read"] == (
+        3 * int((pos // 8 + 1).sum())
+        + 4 * int((pos // 8 - np.maximum(pos - 7, 0) // 8 + 1).sum()))
+    assert (t["decode_kv_token_layers_full"] + t["decode_kv_token_layers_ring"]
+            == t["decode_kv_token_layers"])
     assert t["kv_dead_window_page_layers"] == 0
     # a window layer holds a ring's reach of a slot at most, whatever its
-    # length; a full layer every position
-    assert 0 < t["kv_window_positions_held"] < t["kv_full_positions_live"]
+    # length (a held position is 4 * 10 * 16 * 4 B over the window
+    # layers); a full layer every position
+    held_positions = t["kv_window_bytes_held"] // (4 * 10 * 16 * 4)
+    assert 0 < held_positions < t["kv_full_positions_live"]
     assert t["kv_window_bytes_held"] > 0 < t["kv_full_bytes_live"]
     # a full layer's position: (3 + 2) rows x 16 x 4 B in each of 3 layers;
     # the pool holds whole pages for it, out to the prompt's bucket
     assert t["kv_full_bytes_live"] == 960 * t["kv_full_positions_live"]
     assert t["kv_full_page_bytes_held"] > t["kv_full_bytes_live"]
-    assert t["kv_window_bytes_held"] == (
-        4 * 10 * 16 * 4 * t["kv_window_positions_held"])
+    assert t["kv_window_bytes_held"] % (4 * 10 * 16 * 4) == 0
     engine.close()
 
 

@@ -402,6 +402,11 @@ def test_obs_report_renders_trace_and_dump(tmp_path, capsys):
     assert "span groups by self time" in out
     assert "engine step split" in out and "orion/decode/emit" in out
     assert "orion/decode/run" in out
+    # the executor's launch and wait leaves: rows of the group table, and in
+    # the step split shown under their run parent
+    split = out[out.index("engine step split"):out.index("slowest")]
+    assert "orion/decode/launch" in out and "orion/decode/launch" not in split
+    assert "of it launch" in split and "of it wait" in split
     assert "per-request TTFT breakdown" in out
     assert "completed" in out
 

@@ -108,6 +108,56 @@ def test_phase_clock_with_the_ring_off_builds_no_tags():
     assert span.tags is None and buckets["a_s"] > 0.0
 
 
+def _engine_cls():
+    from orion_tpu.infer import InferenceEngine
+
+    return InferenceEngine
+
+
+@pytest.mark.parametrize("leaf", [
+    "prefill/launch", "prefill/wait", "decode/launch", "decode/wait",
+    "verify/launch", "verify/wait", "fold/launch",
+    "mixed/launch", "mixed/wait", "mixed_verify/launch", "mixed_verify/wait",
+])
+def test_a_seam_leaf_leaves_its_parents_buckets_what_they_were(leaf):
+    """A phase books SELF time, so a child takes its time out of its
+    parent's buckets unless it feeds them itself: with the key tuples as
+    ``_PHASE_KEYS`` spells them, every key of a ``<path>/run`` phase sums
+    to the run span's whole length with the leaf inside it, as it did
+    without, and the leaf's own key holds the leaf alone."""
+    eng = _engine_cls()
+    # a fold is launched and never waited for: eleven leaves, not twelve
+    assert sum(p.endswith(("/launch", "/wait")) for p in eng._PHASE_KEYS) == 11
+    run = leaf.rsplit("/", 1)[0] + "/run"
+    own, *parents = eng._PHASE_KEYS[leaf]
+    assert tuple(parents) == eng._PHASE_KEYS[run]
+    assert own not in eng._PHASE_KEYS[run] and own in eng._zero_timing()
+    buckets = dict.fromkeys(
+        set(eng._PHASE_KEYS[leaf]) | set(eng._PHASE_KEYS["step"]), 0.0)
+    clock = PhaseClock(NULL_TRACER, eng._PHASE_KEYS, lambda: buckets,
+                       lambda: {})
+    with clock("step") as step:
+        with clock(run) as parent:
+            with clock(leaf) as child:
+                pass
+    whole, inner = parent.t1 - parent.t0, child.t1 - child.t0
+    assert buckets[own] == pytest.approx(inner, abs=1e-12)
+    for key in eng._PHASE_KEYS[run]:
+        assert buckets[key] == pytest.approx(whole, abs=1e-12), key
+    assert buckets["host_s"] == pytest.approx(
+        step.t1 - step.t0 - whole, abs=1e-12)
+
+
+def test_every_key_a_phase_feeds_is_in_reset_timing():
+    eng = _engine_cls()
+    zero = eng._zero_timing()
+    fed = {k for keys in eng._PHASE_KEYS.values() for k in keys}
+    assert fed <= set(zero)
+    assert {"unqueued_s", "unqueued_in_step_s", "unqueued_max_s",
+            "launches", "waits"} <= set(zero)
+    assert "window_ring_wraps" not in zero
+
+
 # ---------------------------------------------------------------------------
 # (a) the leaves partition their parents; every old key keeps its meaning
 # ---------------------------------------------------------------------------
@@ -138,6 +188,17 @@ def test_leaves_partition_the_old_keys(params, mode):
     assert t["host_s"] == pytest.approx(
         wall - t["device_s"] - t["prefill_s"] - t["spill_s"]
         - t["restore_s"] - t["page_in_s"], abs=1e-9)
+    # The seam's leaves lie inside their run spans, and every launch was
+    # waited for; nothing was queued for part of the steps (booked at a
+    # step's end, so the last step's tail is in it before the next launch
+    # books it to unqueued_s), never longer than the steps took.
+    for path in ("prefill", "decode", "verify"):
+        assert 0 <= t[path + "_launch_s"] + t[path + "_wait_s"] <= (
+            t[path + "_run_s"] + 1e-12)
+    assert t["mixed_launch_s"] + t["mixed_wait_s"] <= t["mixed_device_s"]
+    assert t["launches"] == t["waits"] > 0
+    assert 0 < t["unqueued_max_s"] <= t["unqueued_s"] <= wall + 1e-9
+    assert 0 < t["unqueued_in_step_s"] <= wall + 1e-9
     # Each mode took its own path.
     if mode == "plain":
         assert t["decode_run_s"] > 0 and t["prefill_run_s"] > 0
@@ -230,9 +291,10 @@ def test_annotations_always_and_bounded_ring_only_when_asked(
     large = per_step([[1, 2, 3], [4, 5, 6, 7], [8, 9], [3] * 12], 24)
     # A step with a prefill burst opens the most (one build / run / sample
     # triple per dispatch: one per burst on the Pallas path, one per
-    # length bucket of the burst on the XLA path); a decode-only step the
+    # length bucket of the burst on the XLA path; a launch and a wait leaf
+    # inside each of a dispatch's two run spans); a decode-only step the
     # same number whatever the batch and however long the outputs.
-    assert max(small) == max(large) == small[0] == large[0] <= 12
+    assert max(small) == max(large) == small[0] == large[0] <= 16
     assert set(small[1:]) == set(large[1:]) and len(set(large[1:])) == 1
     assert len(large) > len(small)
 
@@ -247,6 +309,8 @@ def test_ring_spans_carry_their_step_and_nest_inside_it(params):
         "orion/reap", "orion/admit", "orion/prefill/build",
         "orion/prefill/run", "orion/prefill/sample", "orion/decode/build",
         "orion/decode/run", "orion/decode/fetch", "orion/decode/emit",
+        "orion/prefill/launch", "orion/prefill/wait",
+        "orion/decode/launch", "orion/decode/wait",
     }
     for _, name, t0, t1, tags in leaves:
         _, _, s0, s1, _ = steps[tags["step"]]

@@ -371,13 +371,15 @@ def test_the_engines_tokens_are_the_references(params, strategy, steps, n,
     assert req.outcome == "completed" and req.generated == want
     assert len(set(want)) > 1           # not one token everywhere
     blocks = -(-(n + max_new) // 4) - n // 4
-    assert (t["denoise_dispatches"], t["denoise_forwards"],
-            t["commit_forwards"], t["block_slot_forwards"]) == (
-        blocks, blocks * steps, blocks, blocks * (steps + 1))
-    assert t["block_positions_fed"] == 4 * blocks * (steps + 1)
-    assert t["tokens_committed"] == max_new
-    assert t["tokens_discarded"] == 4 * blocks - n % 4 - max_new
-    assert t["blocks_with_prompt_tail"] == int(n % 4 > 0)
+    # a block program counts as a window and holds ``steps`` + 1 forwards
+    assert (t["windows"], t["block_slot_forwards"]) == (
+        blocks, blocks * (steps + 1))
+    # The committed blocks hold 4 * blocks positions: the prompt's tail (in
+    # the first block alone), the tokens emitted, and what lies beyond
+    # max_new_tokens in the last block, which is discarded and not emitted.
+    assert t["tokens_committed"] == len(req.generated) == max_new
+    assert 4 * t["windows"] - n % 4 - len(req.generated) == (
+        -(n + max_new) % 4)
     assert t["decode_window"] == 4
     # what the reference fed as the mask token, forward by forward
     assert t["block_positions_undecided_fed"] == sum(
@@ -418,7 +420,12 @@ def test_an_eos_inside_a_block_ends_the_request_there(params):
     assert ref.generate(params, prompt, 14, hf, eos_id=eos) == cut
     (req,), t = _generate(params, [(prompt, 14)], eos_id=eos)
     assert req.generated == cut and req.outcome == "completed"
-    assert t["tokens_committed"] == len(cut) and t["tokens_discarded"] > 0
+    # It ended in the block that holds the EOS, whose positions behind the
+    # EOS were discarded: nothing was emitted once the request was done.
+    assert t["windows"] == -(-(10 + len(cut)) // 4) - 10 // 4
+    assert t["tokens_committed"] == len(req.generated) == len(cut)
+    assert req.generated[-1] == eos and eos not in req.generated[:-1]
+    assert 4 * t["windows"] - 10 % 4 - len(cut) == -(10 + len(cut)) % 4 > 0
 
 
 def test_a_sampled_request_with_the_engines_key_replayed(params):
@@ -591,5 +598,5 @@ def test_an_autoregressive_model_traces_what_it_traced():
     assert not hasattr(engine, "_denoise")
     assert engine.decode_window == cfg.inference.decode_window
     t = engine.reset_timing()
-    assert t["denoise_dispatches"] == t["tokens_committed"] == 0
+    assert t["block_slot_forwards"] == t["tokens_committed"] == 0
     engine.close()

@@ -317,6 +317,94 @@ def test_a_short_pool_waits_for_the_prefill_first(llama):
     assert u["prefill_picks_in_program"] == 3
 
 
+# -- the dispatch seam's own spans and counters (ISSUE 56) ----------------------
+
+
+def test_the_seam_times_itself(llama):
+    """One request on the module's engine shape, the ring on: the admitting
+    step is chained (launch, launch, wait, wait), each leaf inside a run span
+    of its path and tagged with its program and its launch's number; every
+    launch is covered by a wait; the time nothing is queued lies inside the
+    run's wall time, part of it inside steps; a 50 ms stall before a
+    decode-only step's launch is the longest such interval; and the run
+    spans' and the older keys' buckets are the sums of their leaves."""
+    import time
+
+    from orion_tpu.runtime.fault import FaultInjector, FaultSpec
+
+    _, params = llama
+    cfg = get_config("tiny-llama", INFER + ["inference.trace=true"])
+    inj = FaultInjector([FaultSpec("stall", step=1, path="decode",
+                                   stall_s=0.05)])
+    eng = InferenceEngine(cfg, params, seed=0, fault_injector=inj)
+    t_start = time.monotonic()
+    (req,) = _waves(eng, [[(PROMPTS[0], 9)]], sampled=False)
+    wall = time.monotonic() - t_start
+    assert len(req.generated) == 9 and len(inj.fired) == 1
+    assert eng._executor.in_flight == 0
+    # with the interval still open behind the last wait
+    unqueued = eng._executor.unqueued_until(time.monotonic())
+    spans = [e for e in eng.tracer.events() if e[0] == "span"]
+    t = eng.reset_timing()
+    eng.close()
+
+    seam = ("orion/prefill/launch", "orion/decode/launch",
+            "orion/prefill/wait", "orion/decode/wait")
+    first = sorted((e for e in spans if e[1] in seam and e[4]["step"] == 0),
+                   key=lambda e: e[2])
+    assert tuple(e[1] for e in first) == seam
+    assert [(e[4]["program"], e[4]["seq"]) for e in first] == [
+        ("orion_prefill", 1), ("orion_decode_window", 2),
+        ("orion_prefill", 1), ("orion_decode_window", 2)]
+    for _, name, t0, t1, _ in (e for e in spans if e[1] in seam):
+        run = name.rsplit("/", 1)[0] + "/run"
+        assert any(r[1] == run and r[2] <= t0 and t1 <= r[3]
+                   for r in spans), name
+    # a decode-only step: one launch, then its wait
+    assert [e[1] for e in sorted(
+        (e for e in spans if e[1] in seam and e[4]["step"] == 1),
+        key=lambda e: e[2])] == list(seam[1::2])
+
+    assert t["launches"] == t["waits"] == 1 + t["windows"]
+    assert 0 < t["unqueued_in_step_s"] <= unqueued <= wall
+    assert t["unqueued_s"] <= unqueued
+    assert 0.05 <= t["unqueued_max_s"] <= t["unqueued_s"]
+    # the same intervals, replayed from the ring's own spans
+    launched, idle_since, gaps = 0, None, []
+    for _, name, t0, t1, tags in sorted(
+            (e for e in spans if e[1] in seam),
+            key=lambda e: e[2] if e[1].endswith("launch") else e[3]):
+        if name.endswith("/launch"):
+            if idle_since is not None:
+                gaps.append(t0 - idle_since)
+            launched, idle_since = tags["seq"], None
+        elif tags["seq"] == launched:
+            idle_since = t1
+    assert t["unqueued_s"] == pytest.approx(sum(gaps), abs=1e-9)
+    assert t["unqueued_max_s"] == pytest.approx(max(gaps), abs=1e-9)
+
+    def total(name):
+        return sum(t1 - t0 for _, n, t0, t1, _ in spans if n == name)
+
+    for path in ("prefill", "decode"):
+        assert t[path + "_run_s"] == pytest.approx(
+            total(f"orion/{path}/run"), abs=1e-9)
+        assert t[path + "_launch_s"] == pytest.approx(
+            total(f"orion/{path}/launch"), abs=1e-9)
+        assert t[path + "_wait_s"] == pytest.approx(
+            total(f"orion/{path}/wait"), abs=1e-9)
+        assert t[path + "_launch_s"] + t[path + "_wait_s"] <= t[path + "_run_s"]
+    assert t["prefill_s"] == pytest.approx(
+        t["prefill_run_s"] + t["prefill_sample_s"], abs=1e-12)
+    assert t["device_s"] == pytest.approx(
+        t["decode_run_s"] + t["decode_fetch_s"], abs=1e-12)
+    assert t["host_s"] == pytest.approx(
+        t["reap_s"] + t["admit_s"] + t["prefill_build_s"]
+        + t["decode_build_s"] + t["emit_s"] + t["step_self_s"], abs=1e-12)
+    assert t["host_s"] + t["prefill_s"] + t["device_s"] == pytest.approx(
+        total("orion/step"), abs=1e-9)
+
+
 # -- the benchmark's reader of the spans, on the new layout ---------------------
 
 MS = 1_000_000
