@@ -50,6 +50,7 @@ from orion_tpu.infer.kv_cache import (
     scatter_pages,
     scrub_pages,
 )
+from orion_tpu.infer.runner import undecided_bounds
 from orion_tpu.infer.scheduler import AdmissionQueue, Request, in_flight
 from orion_tpu.infer.sampling import sample
 from orion_tpu.metrics import (
@@ -1651,10 +1652,16 @@ class InferenceEngine:
             # each (block_positions_undecided_fed); tokens emitted
             # (tokens_committed); and, summed over slots and forwards, the
             # cached positions a forward's attention read, the block's own
-            # among them (block_kv_positions_read). Host arithmetic on
-            # lengths, no device value read.
+            # among them (block_kv_positions_read). The rows the head and
+            # the choice were given (block_head_rows: a live slot's
+            # runner.undecided_bounds, summed over the denoising forwards)
+            # and of those the ones that decided a token, again from the
+            # program's record (block_head_rows_decided; the rest waited
+            # for a later forward or filled up a prompt's tail). Host
+            # arithmetic on lengths, no device value read.
             "block_slot_forwards": 0, "block_positions_undecided_fed": 0,
             "tokens_committed": 0, "block_kv_positions_read": 0,
+            "block_head_rows": 0, "block_head_rows_decided": 0,
             # Per-phase device split (ISSUE 20 load-gauge satellite):
             # decode_device_s covers pure decode-phase dispatches
             # (decode windows, verify, draft compaction) and pairs with
@@ -4279,6 +4286,8 @@ class InferenceEngine:
             self.timing["block_slot_forwards"] += (S + 1) * len(active)
             self.timing["block_kv_positions_read"] += (S + 1) * (
                 int(self.seq_lens[mask].sum()) + L * len(active))
+            self.timing["block_head_rows"] += len(active) * sum(
+                undecided_bounds(L, S))
         with self._phase("decode/run"):
             out = self._executor.run("decode", name, *args)
         again = False
@@ -4303,6 +4312,8 @@ class InferenceEngine:
             # decided.
             self.timing["block_positions_undecided_fed"] += int(sum(
                 (at[mask] >= s).sum() for s in range(S)))
+            self.timing["block_head_rows_decided"] += int(
+                (at[mask] >= 0).sum())
             for req in active:
                 if ok and not ok[0][req.slot]:
                     self._quarantine(req, "nan")

@@ -1255,9 +1255,11 @@ def block_forward(
     max_seq_len: int, mesh: Optional[jax.sharding.Mesh] = None,
 ) -> tuple[jax.Array, Cache]:
     """ONE forward of a block as fed [B, L] -> (logits [B, L, V] over the
-    token AT each position, cache with the block's rows written): the body
-    ``denoise_block`` runs ``steps`` times and once more without the head.
-    What a check holds the block program to, forward by forward."""
+    token AT each position, cache with the block's rows written): the layers
+    ``denoise_block`` runs ``steps`` times and once more without the head,
+    and the head at EVERY row, where the program's own forwards take it at
+    the rows still undecided alone. What a check holds the block program to,
+    forward by forward."""
     ctx = _block_ctx(cache, seq_lens, page_table, active, max_seq_len, cfg)
     x, cache = _block_hidden(params, cache, fed, ctx, cfg, mesh)
     return _block_logits(params, x, cfg, mesh).reshape(*fed.shape, -1), cache
@@ -1265,13 +1267,40 @@ def block_forward(
 
 def _block_logits(params: Params, x: jax.Array, cfg: ModelConfig, mesh
                   ) -> jax.Array:
-    """The head on a block's hidden states [B, L, D] -> float32 logits
-    [B x L, V], a row a position: accumulated and left in float32 (the
-    choice of positions ranks probabilities of them, and a check's
-    one-forward body has to rank them the same), and flat, so that nothing
-    of the vocabulary's width is laid out again for the sampler."""
-    B, L, D = x.shape
-    return unembed(params, x.reshape(1, B * L, D), cfg, mesh, precise=True)[0]
+    """The head on hidden states [B, R, D] -> float32 logits [B x R, V], a
+    row a position: accumulated and left in float32 (the choice of positions
+    ranks probabilities of them, and a check's one-forward body has to rank
+    them the same), and flat, so that nothing of the vocabulary's width is
+    laid out again for the sampler."""
+    B, R, D = x.shape
+    return unembed(params, x.reshape(1, B * R, D), cfg, mesh, precise=True)[0]
+
+
+def draw_with_confidence(logits: jax.Array, key: jax.Array, *, temperature,
+                         top_k, top_p) -> tuple[jax.Array, jax.Array]:
+    """float32 logits [R, V] -> (the token ``sampling.sample`` draws a row [R]
+    int32, its float32 softmax probability [R]): ``exp(log_softmax(logits))``
+    at the drawn column, taken as reductions over a row (its largest logit,
+    the sum of ``exp(logits - largest)``) and ONE column of it, in the
+    operations and the order ``jax.nn.log_softmax`` gives that column, with
+    no ``[R, V]`` result laid out."""
+    from orion_tpu.infer.sampling import sample
+
+    drawn = sample(logits, key, temperature=temperature, top_k=top_k,
+                   top_p=top_p)
+    top = logits.max(axis=-1)
+    norm = jnp.log(jnp.exp(logits - top[:, None]).sum(axis=-1))
+    picked = jnp.take_along_axis(logits, drawn[:, None], axis=-1)[:, 0]
+    return drawn, jnp.exp(picked - top - norm)
+
+
+def undecided_bounds(block_length: int, steps: int) -> tuple[int, ...]:
+    """The most positions a slot can have undecided at each denoising forward:
+    a forward decides its ``denoise_schedule`` count or all that are left
+    (the dynamic rule: at least that), so forward ``s`` meets at most the
+    block less the counts before it. The rows a slot the head is given."""
+    schedule = denoise_schedule(block_length, steps)
+    return tuple(block_length - sum(schedule[:s]) for s in range(steps))
 
 
 def denoise_block(
@@ -1304,10 +1333,17 @@ def denoise_block(
     A forward feeds the block's L positions, decided tokens as they are and
     undecided ones as ``cfg.mask_token_id``, through ``_paged_layer`` at
     W = L with every new row visible to every query (``_block_ctx``). The
-    logits at a position are over the token AT it. At every undecided
-    position a token is drawn from the filtered distribution and its
-    float32 softmax probability is its confidence; ``choose_positions``
-    decides some, which keep their token for good. The rows a denoising
+    logits at a position are over the token AT it. The head and the choice
+    run at the rows that can still be decided: forward ``s`` takes a slot's
+    first ``undecided_bounds(L, steps)[s]`` undecided positions (all it can
+    have by then; a slot with fewer, a prompt's tail in its first block,
+    fills up with decided positions, which the choice passes by), draws a
+    token at each from the filtered distribution with its float32 softmax
+    probability as its confidence (``draw_with_confidence``: reductions of
+    the logits, no second ``[rows, V]`` array), and lays both back over
+    ``[B, L]``, where ``choose_positions`` decides some, which keep their
+    token for good. The forwards differ in their rows, so they are a Python
+    loop (the layers stay scanned inside each). The rows a denoising
     forward writes land BEYOND the slot's cursor (``seq_lens`` does not
     move), where the next forward overwrites them and nothing else reads
     them; the commit forward, on the finished block and without the head,
@@ -1315,50 +1351,48 @@ def denoise_block(
     early makes its remaining forwards for nothing.
 
     The key is handled as ``decode_window`` handles it: ``key', sub =
-    split(key)``, one of ``split(sub, steps)`` a forward."""
-    from orion_tpu.infer.sampling import sample
-
+    split(key)``, one of ``split(sub, steps)`` a forward (a forward draws
+    its ``B x rows`` rows at once, slot by slot)."""
     B, L = tokens.shape
     ctx = _block_ctx(cache, seq_lens, page_table, active, max_seq_len, cfg)
-
-    def rows(a):        # a [B] sampling parameter for each of the L rows
-        return a if jnp.ndim(a) == 0 else jnp.repeat(a, L)
-
-    def stepf(carry, xs):
-        toks, at, ok, cc = carry
-        sub, count, s = xs
+    key_next, sub = jax.random.split(key)
+    keys = jax.random.split(sub, steps)
+    came = jnp.arange(L, dtype=jnp.int32)[None, :] < n_decided[:, None]
+    toks, at = tokens, jnp.where(came, -1, steps).astype(jnp.int32)
+    ok, cache = jnp.ones((B,), bool), dict(cache)
+    for s, (count, U) in enumerate(zip(denoise_schedule(L, steps),
+                                       undecided_bounds(L, steps))):
         decided = at < s
         with jax.named_scope("denoise/forward"):
-            x, cc = _block_hidden(
-                params, cc, jnp.where(decided, toks, cfg.mask_token_id), ctx,
-                cfg, mesh)
-            logits = _block_logits(params, x, cfg, mesh)    # [B x L, V]
+            x, cache = _block_hidden(
+                params, cache, jnp.where(decided, toks, cfg.mask_token_id),
+                ctx, cfg, mesh)
+        if not U:           # more forwards than positions: all are decided
+            continue
+        # A slot's undecided positions first, in their order.
+        at_rows = jnp.argsort(decided, axis=-1, stable=True)[:, :U]
+        with jax.named_scope("denoise/forward"):
+            logits = _block_logits(
+                params, jnp.take_along_axis(x, at_rows[..., None], axis=1),
+                cfg, mesh)                                  # [B x U, V]
         with jax.named_scope("denoise/choose"):
-            drawn = sample(
-                logits, sub, temperature=rows(temperature),
-                top_k=rows(top_k), top_p=rows(top_p))
-            conf = jnp.exp(jnp.take_along_axis(
-                jax.nn.log_softmax(logits, axis=-1), drawn[:, None],
-                axis=-1)).reshape(B, L)
-            drawn = drawn.reshape(B, L)
+            # a [B] sampling parameter for each of a slot's U rows
+            per_row = lambda a: a if jnp.ndim(a) == 0 else jnp.repeat(a, U)
+            drawn, conf = draw_with_confidence(
+                logits, keys[s], temperature=per_row(temperature),
+                top_k=per_row(top_k), top_p=per_row(top_p))
+            # back over the block; a position that was given no row is a
+            # decided one, which the choice passes by
+            over_block = lambda r: jnp.put_along_axis(
+                jnp.zeros((B, L), r.dtype), at_rows, r.reshape(B, U),
+                axis=1, inplace=False)
             take = choose_positions(
-                conf, ~decided, count, remasking, threshold)
-            toks = jnp.where(take, drawn, toks)
+                over_block(conf), ~decided, count, remasking, threshold)
+            toks = jnp.where(take, over_block(drawn), toks)
             at = jnp.where(take, s, at)
-            if nan_guard:
+            if nan_guard:   # the rows whose tokens can still change
                 ok = ok & (jnp.isfinite(logits).reshape(B, -1).all(-1)
                            | ~active)
-        return (toks, at, ok, cc), None
-
-    key_next, sub = jax.random.split(key)
-    came = jnp.arange(L, dtype=jnp.int32)[None, :] < n_decided[:, None]
-    init = (tokens, jnp.where(came, -1, steps).astype(jnp.int32),
-            jnp.ones((B,), bool), dict(cache))
-    (toks, at, ok, cache), _ = jax.lax.scan(
-        stepf, init,
-        (jax.random.split(sub, steps),
-         jnp.asarray(denoise_schedule(L, steps), jnp.int32),
-         jnp.arange(steps, dtype=jnp.int32)))
     with jax.named_scope("denoise/commit"):
         _, cache = _block_hidden(params, cache, toks, ctx, cfg, mesh)
     if nan_guard:

@@ -12,6 +12,7 @@ traces the parent's statics."""
 import dataclasses
 import json
 import pathlib
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -395,6 +396,164 @@ def test_the_engines_tokens_are_the_references(params, strategy, steps, n,
             4 / (steps + 1))
 
 
+@pytest.mark.parametrize("strategy,steps,requests", [
+    ("low_confidence_static", 2, [(9, 7)]),
+    ("low_confidence_static", 4, [(3, 9)]),
+    ("low_confidence_dynamic", 4, [(21, 13)]),
+    ("low_confidence_static", 2, [(5, 9), (18, 13)]),
+])
+def test_the_heads_rows_and_how_many_decided_a_token(params, strategy, steps,
+                                                     requests):
+    """``block_head_rows`` is the rows the head and the choice were given: a
+    live slot's ``undecided_bounds`` a block program (4 + 2 at two steps, 4 +
+    3 + 2 + 1 at four), whatever its tail; ``block_head_rows_decided`` the
+    positions a forward decided, which is every position of the run's blocks
+    that did not come as a prompt's tail."""
+    from orion_tpu.infer.runner import undecided_bounds
+
+    overrides = (f"inference.denoising_steps={steps}",) * (steps != 2)
+    if strategy == "low_confidence_dynamic":
+        overrides += (f"inference.remasking={strategy}",
+                      "inference.confidence_threshold=0.05")
+    reqs, t = _generate(
+        params, [(_prompt(n, seed=7), m) for n, m in requests], *overrides)
+    assert all(r.outcome == "completed" for r in reqs)
+    blocks = [-(-(n + m) // 4) - n // 4 for n, m in requests]
+    assert undecided_bounds(4, steps) == {2: (4, 2), 4: (4, 3, 2, 1)}[steps]
+    assert t["block_head_rows"] == sum(blocks) * sum(undecided_bounds(4, steps))
+    assert t["block_head_rows_decided"] == sum(
+        4 * b - n % 4 for b, (n, _) in zip(blocks, requests))
+    assert t["block_head_rows_decided"] <= t["block_head_rows"] < (
+        4 * steps * sum(blocks) if steps > 1 else 1 + 4 * sum(blocks))
+    # every forward's undecided positions were among the rows it was given
+    assert t["block_positions_undecided_fed"] <= t["block_head_rows"]
+
+
+@pytest.mark.parametrize("sampled", [False, True])
+def test_a_drawn_tokens_confidence_from_reductions(sampled):
+    """``draw_with_confidence`` draws what ``sample`` draws and gives
+    ``exp(log_softmax(logits))`` at the drawn column to float32 rounding, on
+    rows with a tied maximum, a row of equal logits, a row with one logit far
+    above the rest and rows far from zero."""
+    from orion_tpu.infer.runner import draw_with_confidence
+    from orion_tpu.infer.sampling import sample
+
+    R, V = 12, 517
+    logits = 4.0 * jax.random.normal(jax.random.key(3), (R, V), jnp.float32)
+    logits = logits.at[0, jnp.array([5, 200])].set(logits[0].max() + 1.0)
+    logits = logits.at[1].set(-3.25)                    # all equal
+    logits = logits.at[2, 77].set(90.0)                 # one certain token
+    logits = logits.at[3].add(1e4).at[4].add(-1e4)
+    logits = logits.at[5, jnp.array([0, V - 1])].set(logits[5].max())
+    kw = dict(temperature=0.0, top_k=0, top_p=1.0)
+    if sampled:
+        kw = dict(temperature=jnp.linspace(0.0, 1.5, R),
+                  top_k=jnp.full((R,), 50, jnp.int32),
+                  top_p=jnp.full((R,), 0.95, jnp.float32))
+    key = jax.random.key(11)
+    drawn, conf = jax.jit(lambda x: draw_with_confidence(x, key, **kw))(logits)
+    assert (np.asarray(drawn) == np.asarray(sample(logits, key, **kw))).all()
+    want = jnp.exp(jnp.take_along_axis(
+        jax.nn.log_softmax(logits, axis=-1), drawn[:, None], axis=-1))[:, 0]
+    np.testing.assert_allclose(np.asarray(conf), np.asarray(want), rtol=2e-6,
+                               atol=0)
+    assert conf.dtype == jnp.float32 and drawn.dtype == jnp.int32
+    if not sampled:
+        assert list(np.asarray(drawn[:3])) == [5, 0, 77]   # ties: the lower
+        assert float(conf[1]) == pytest.approx(1 / V, rel=1e-6)
+        assert float(conf[0]) < 0.5 < float(conf[2])
+
+
+_BLOCK_PROGRAMS: dict = {}
+
+LAYOUTS = {
+    # tails a slot, live slots
+    "no-tail": ((0, 0, 0, 0), (True, True, True, True)),
+    "tails": ((1, 2, 3, 0), (True, True, True, True)),
+    "dead-slot": ((3, 0, 2, 1), (True, False, True, True)),
+}
+
+
+@pytest.mark.parametrize("strategy,steps,threshold,layout", [
+    ("low_confidence_static", 1, 0.9, "tails"),
+    ("low_confidence_static", 2, 0.9, "no-tail"),
+    ("low_confidence_static", 2, 0.9, "tails"),
+    ("low_confidence_static", 2, 0.9, "dead-slot"),
+    ("low_confidence_static", 4, 0.9, "no-tail"),
+    ("low_confidence_static", 4, 0.9, "tails"),
+    ("low_confidence_static", 5, 0.9, "tails"),  # a forward past the last
+    ("low_confidence_dynamic", 2, 0.2, "no-tail"),
+    ("low_confidence_dynamic", 2, 0.2, "tails"),
+    ("low_confidence_dynamic", 2, 0.2, "dead-slot"),
+    ("low_confidence_dynamic", 4, 0.2, "no-tail"),
+    ("low_confidence_dynamic", 4, 0.2, "tails"),
+])
+def test_the_block_program_against_its_body_over_every_row(
+        params, strategy, steps, threshold, layout):
+    """``denoise_block`` takes the head and the choice at a slot's first
+    ``undecided_bounds`` undecided positions; the one-forward body
+    (``block_forward``: EVERY row's logits), driven by hand with the whole
+    ``log_softmax`` and ``choose_positions``, decides the same tokens at the
+    same forwards: over eight cached positions of random K/V, with no
+    prompt tail, tails of 1-3 decided positions and a dead slot, every step
+    count and both rules (the head twenty times the preset's scale and the
+    dynamic rule's threshold inside the confidences that gives, so that
+    some slots decide more than the static count and some fall back on it)."""
+    from orion_tpu.infer import runner
+    from orion_tpu.infer.kv_cache import init_cache, pages_per_seq
+
+    params = {**params, "lm_head": 20.0 * params["lm_head"]}
+    cfg = _config()
+    m, icfg = cfg.model, cfg.inference
+    B, L = icfg.max_batch_size, m.block_length
+    tails, live = (jnp.asarray(a) for a in LAYOUTS[layout])
+    rng = np.random.default_rng(17)
+    tokens = jnp.asarray(rng.integers(1, 255, (B, L)), jnp.int32)
+    tokens = jnp.where(jnp.arange(L) < tails[:, None], tokens, 0)
+    seq_lens = jnp.full((B,), 8, jnp.int32)
+    table = jnp.zeros((B, pages_per_seq(icfg)), jnp.int32
+                      ).at[:, :2].set(1 + jnp.arange(2 * B).reshape(B, 2))
+    ks = iter(jax.random.split(jax.random.key(2), 4))
+    cache = {n: 0.5 * jax.random.normal(next(ks), a.shape, a.dtype)
+             if n in "kv" else a for n, a in init_cache(m, icfg).items()}
+    kw = dict(cfg=m, max_seq_len=icfg.max_seq_len)
+    name = (strategy, steps)
+    if name not in _BLOCK_PROGRAMS:
+        _BLOCK_PROGRAMS[name] = jax.jit(partial(
+            runner.denoise_block, **kw, steps=steps, remasking=strategy,
+            threshold=threshold, temperature=0.0, top_k=0, top_p=1.0))
+    if "body" not in _BLOCK_PROGRAMS:
+        _BLOCK_PROGRAMS["body"] = jax.jit(partial(runner.block_forward, **kw))
+    toks, at, _, _ = _BLOCK_PROGRAMS[name](
+        params, cache, tokens, tails, seq_lens, table, live,
+        jax.random.PRNGKey(0))
+
+    want = tokens
+    want_at = jnp.where(jnp.arange(L) < tails[:, None], -1, steps)
+    for s, count in enumerate(runner.denoise_schedule(L, steps)):
+        decided = want_at < s
+        logits, _ = _BLOCK_PROGRAMS["body"](
+            params, cache, jnp.where(decided, want, m.mask_token_id),
+            seq_lens, table, live)
+        drawn = jnp.argmax(logits, axis=-1)
+        conf = jnp.exp(jnp.take_along_axis(
+            jax.nn.log_softmax(logits, axis=-1), drawn[..., None],
+            axis=-1))[..., 0]
+        take = runner.choose_positions(conf, ~decided, count, strategy,
+                                       threshold)
+        want, want_at = jnp.where(take, drawn, want), jnp.where(
+            take, s, want_at)
+        if (strategy, layout, s) == ("low_confidence_dynamic", "no-tail", 0
+                                     ) and steps > 1:
+            given = np.asarray(take.sum(-1))
+            assert given.max() > count == given.min()   # both branches
+    keep = np.asarray(live)
+    assert (np.asarray(toks)[keep] == np.asarray(want)[keep]).all()
+    assert (np.asarray(at)[keep] == np.asarray(want_at)[keep]).all()
+    assert (np.asarray(at)[keep] < min(steps, L)).all()    # all decided
+    assert len(set(np.asarray(toks)[keep].ravel().tolist())) > 1
+
+
 def test_a_batch_of_unequal_requests_against_each_alone(params):
     """Four requests of unequal lengths and tails in one engine (4 slots,
     every dispatch one block for every live slot) give what each gives
@@ -431,8 +590,10 @@ def test_an_eos_inside_a_block_ends_the_request_there(params):
 def test_a_sampled_request_with_the_engines_key_replayed(params):
     """Temperature 1: the engine's key is split as ``decode_window`` splits
     it (key', sub = split(key); one of split(sub, steps) a forward), and a
-    forward draws every slot's rows at once; the reference, handed those
-    draws, gives the engine's tokens."""
+    forward draws every slot's rows at once: the positions a slot can still
+    have undecided (``runner.undecided_bounds``), its undecided ones first;
+    the reference, handed those draws, gives the engine's tokens."""
+    from orion_tpu.infer.runner import undecided_bounds
     from orion_tpu.infer.sampling import sample
 
     cfg = _config("inference.temperature=1.0")
@@ -445,12 +606,21 @@ def test_a_sampled_request_with_the_engines_key_replayed(params):
     for b in range(9 // 4, -(-(9 + 11) // 4)):
         key, sub = jax.random.split(key)
         keys[b] = jax.random.split(sub, S)
+    trace = []
 
     def draw(logits, b, s):
-        rows = jnp.zeros((B * L, logits.shape[-1])).at[:L].set(logits)
-        return np.asarray(sample(rows, keys[b][s], temperature=1.0))[:L]
+        # decided before this forward: the prompt's tail, then what the
+        # reference's last forward of this block left
+        decided = trace[-1][4] if s else np.arange(L) < max(9 - b * L, 0)
+        at_rows = np.argsort(decided, kind="stable")[:undecided_bounds(L, S)[s]]
+        rows = jnp.zeros((B * len(at_rows), logits.shape[-1])
+                         ).at[:len(at_rows)].set(logits[at_rows])
+        x0 = np.zeros(L, np.int64)
+        x0[at_rows] = np.asarray(sample(
+            rows, keys[b][s], temperature=1.0))[:len(at_rows)]
+        return x0
 
-    want = ref.generate(params, prompt, 11, hf, draw=draw)
+    want = ref.generate(params, prompt, 11, hf, draw=draw, trace=trace)
     assert req.generated == want
     assert want != ref.generate(params, prompt, 11, hf)
 

@@ -17,8 +17,10 @@ fault is planted in traced code, so its programs are compiled anew):
   commit    the commit forward left out: the rows the last denoising forward
             wrote (undecided positions fed as the mask token) are kept;
   qk_norm   no norm over the query and key heads;
-  shift     a shifted head: the logits a block program reads at position i
-            are those of position i - 1;
+  shift     a shifted head: the logits read at a row are those of the row
+            before it, among the rows the head is given (every position in
+            the check's body and the program's first forward, a slot's
+            undecided ones after it);
   least     the static rule decides the LEAST confident positions.
 
 ``--faults`` names the passes to make (default all seven, ``none`` first);
@@ -64,19 +66,20 @@ def planted(fault: str, cell):
         return keep["attention"](
             *args, block=block, **{**kw, "q_offset": -block})
 
-    state = {"on": False, "calls": 0}
+    state = {"on": False, "calls": 0, "commit": 0}
 
-    def no_commit_program(*args, **kw):
-        state.update(on=True, calls=0)
+    def no_commit_program(*args, steps=1, **kw):
+        state.update(on=True, calls=0, commit=steps + 1)
         try:
-            return keep_program(*args, **kw)
+            return keep_program(*args, steps=steps, **kw)
         finally:
             state["on"] = False
 
     def hidden(params, cache, fed, ctx, cfg, mesh):
         if state["on"]:
             state["calls"] += 1
-            if state["calls"] == 2:    # the scan's body, then the commit
+            # the denoising forwards one by one, then the commit
+            if state["calls"] == state["commit"]:
                 return None, dict(cache)
         return keep["_block_hidden"](params, cache, fed, ctx, cfg, mesh)
 
