@@ -56,18 +56,53 @@ class RopeConfig:
                 and self.attention_factor == 1.0)
 
 
+@dataclass(frozen=True)
+class SparseConfig:
+    """Block selection of a "sparse" layer (InfLLM-v2 as MiniCPM4 publishes
+    it; ``ops/sparse.py``): keys are compressed by a mean over ``kernel``
+    positions every ``stride``; a query scores the compressed keys wholly in
+    its past, the scores of a K/V group's heads are summed and max-pooled
+    onto blocks of ``block`` positions (= the page), block 0..
+    ``init_blocks`` - 1 and the ``local_blocks`` that end with the query's
+    own are forced, and the ``topk`` best blocks (forced ones among them)
+    are attended."""
+
+    kernel: int = 32
+    stride: int = 16
+    block: int = 64
+    init_blocks: int = 1
+    local_blocks: int = 32
+    topk: int = 64
+
+    def __post_init__(self):
+        if self.kernel != 2 * self.stride or self.block % self.stride:
+            raise ValueError(
+                f"model.sparse: kernel={self.kernel} must be 2 x stride="
+                f"{self.stride} and stride must divide block={self.block} "
+                f"(a page's last kernel reads into the next page alone)")
+        if min(self.init_blocks, self.local_blocks) < 1 or (
+                self.topk < self.init_blocks + self.local_blocks):
+            raise ValueError(
+                f"model.sparse: topk={self.topk} must hold the forced blocks "
+                f"(init_blocks={self.init_blocks} + local_blocks="
+                f"{self.local_blocks}, each at least 1)")
+
+
 class LayerKind(typing.NamedTuple):
     """What is static about one layer: every kernel and every weight shape
     of the layer follows from it."""
 
     window: Optional[int]       # sliding window (None = full attention)
     n_heads: int                # query heads
-    rope: RopeConfig
+    rope: Optional[RopeConfig]
     moe: bool                   # sparse feed-forward (else dense, d_ff wide)
     # What the layer's attention computes, and with it what it caches:
     # "softmax" (K and V in pages), "power_retention" (a state row beside a
     # paged tail), "latent" (one compressed row a position in pages), "kda"
-    # (a state row and a convolution's tail a slot, no page).
+    # (a state row and a convolution's tail a slot, no page), "lightning" (a
+    # state row a slot, no page), "sparse" (K and V in pages beside their
+    # compressed keys, of which a query reads the pages it selects). A
+    # sparse layer has no rotary embedding: its ``rope`` is None.
     attention: str = "softmax"
     # K/V heads of a softmax layer where the model's kinds differ in them
     # (None = model.n_kv_heads), and whether the layer's softmax carries a
@@ -195,7 +230,9 @@ class ModelConfig:
     # column is dropped: a row's weights add up to less than 1.
     attn_sink: Optional[str] = None
     # "per-head": each head's attention output is multiplied by a sigmoid
-    # gate computed from the layer's normed input (attn.wg [D, heads]).
+    # gate computed from the layer's normed input (attn.wg [D, heads]);
+    # "elementwise": every number of it by its own (attn.wg [D, heads x
+    # head_dim]).
     attn_gate: Optional[str] = None
     # What a layer's attention computes: "softmax", or "power_retention"
     # (ops/retention.py): weights are the SQUARE of the scaled score under
@@ -213,7 +250,16 @@ class ModelConfig:
     # latent sizes below) the layers l with (l + 1) % G == 0 are LATENT
     # layers among the KDA ones: ``LayerKind.attention`` says which a layer
     # is, and a layer's attention is one value a LAYER, not a model.
+    #
+    # ``mixer_types`` (the source's per-layer list, as published:
+    # "minicpm4" | "lightning-attn"; the first n_layers entries are read)
+    # makes a layer "sparse" (softmax attention with no rotary embedding
+    # over the pages block selection picks, ``sparse``) or "lightning"
+    # (ops/lightning.py: linear attention under a fixed decay a head,
+    # n_heads K/V heads, rotary q/k, a norm over the concatenated heads).
     attention: str = "softmax"
+    mixer_types: Optional[Tuple[str, ...]] = None
+    sparse: Optional[SparseConfig] = None
     layer_group_size: Optional[int] = None
     kda_conv_size: int = 4
     kda_lower_bound: float = -5.0
@@ -246,7 +292,15 @@ class ModelConfig:
     # Gemma-family block/embedding details:
     post_norms: bool = False          # extra norms AFTER attention and MLP
     norm_scale_plus_one: bool = False  # rmsnorm multiplies by (1 + w)
-    embed_scale: bool = False          # embeddings scaled by sqrt(d_model)
+    # Embeddings are multiplied by it: True = sqrt(d_model) (Gemma), a
+    # number = that number (muP's scale_emb), False = not at all.
+    embed_scale: float = False
+    # muP's other two scalings, as numbers: each sublayer's output times
+    # ``residual_scale`` before it joins the residual stream (scale_depth /
+    # sqrt(published depth)), the final norm's output times ``logit_scale``
+    # before the head (dim_model_base / hidden_size).
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
     # Net attention logit scale (default head_dim**-0.5). Gemma-2 uses
     # query_pre_attn_scalar**-0.5, which differs from head_dim for 27B.
     query_scale: Optional[float] = None
@@ -469,8 +523,29 @@ class ModelConfig:
         """Some layer is a KDA layer (the cache has its slot leaves)."""
         return self.attention == "kda"
 
+    @property
+    def has_sparse(self) -> bool:
+        """Some layer selects the pages it reads (the cache has the
+        compressed-key leaf)."""
+        return self.mixer_types is not None and (
+            "minicpm4" in self.mixer_types[:self.n_layers])
+
+    @property
+    def resumes_prefill(self) -> bool:
+        """A prompt may enter in page-aligned chunks, each resuming from
+        what the last left in the cache (pages, compressed keys, state
+        rows): the model of sparse and lightning layers."""
+        return self.mixer_types is not None
+
     def layer_attention(self, layer: int) -> str:
         """``LayerKind.attention`` of layer ``layer`` (a Python int)."""
+        if self.mixer_types is not None:
+            kinds = {"minicpm4": "sparse", "lightning-attn": "lightning"}
+            if self.mixer_types[layer] not in kinds:
+                raise ValueError(
+                    f"model.mixer_types[{layer}]={self.mixer_types[layer]!r};"
+                    f" minicpm4|lightning-attn")
+            return kinds[self.mixer_types[layer]]
         if self.has_kda:
             G = self.layer_group_size
             return ("latent" if self.has_latent and G and (layer + 1) % G == 0
@@ -485,6 +560,8 @@ class ModelConfig:
         if self.has_window_ring:
             return sum(self.cache_kind(k) == "softmax"
                        for k in self.layer_kinds)
+        if self.mixer_types is not None:
+            return self.n_layers_of("sparse")
         return self.n_layers_of("latent") if self.has_kda else self.n_layers
 
     def n_layers_of(self, attention: str) -> int:
@@ -614,7 +691,7 @@ class ModelConfig:
             raise ValueError(
                 "model.layer_types and model.sliding_window_pattern both "
                 "write the per-layer list: set one")
-        for name in ("layer_types", "n_heads_per_layer"):
+        for name in ("layer_types", "n_heads_per_layer", "mixer_types"):
             got = getattr(self, name)
             if got is not None and len(got) < L:
                 raise ValueError(
@@ -634,14 +711,17 @@ class ModelConfig:
             else:
                 windowed = p is None or l % p != p - 1
             windowed = windowed and self.sliding_window is not None
+            att = self.layer_attention(l)
             kinds.append(LayerKind(
                 window=self.sliding_window if windowed else None,
                 n_heads=(self.n_heads if self.n_heads_per_layer is None
                          else self.n_heads_per_layer[l]),
-                rope=sliding if windowed else full,
+                rope=(None if att == "sparse"
+                      else sliding if windowed else full),
                 moe=self.is_moe and l >= self.n_dense_layers,
-                attention=self.layer_attention(l),
-                n_kv_heads=(self.n_kv_heads_sliding if windowed else None),
+                attention=att,
+                n_kv_heads=(self.n_heads if att == "lightning" else
+                            self.n_kv_heads_sliding if windowed else None),
                 sink=windowed and self.attn_sink == "sliding",
             ))
         return tuple(kinds)
@@ -674,11 +754,12 @@ class ModelConfig:
         feed-forward kind: the layer scan and ``window_pattern`` serve it,
         parameter tree and programs as they always were). Else the leading
         dense layers, the smallest period of what follows, and its tail; in
-        a model of KDA and latent layers the same over RUNS of equal
-        layers (``LayerPlan.widths``)."""
+        a model of KDA and latent layers, or of ``mixer_types``, the same
+        over RUNS of equal layers (``LayerPlan.widths``)."""
+        by_runs = ((self.has_kda and self.has_latent)
+                   or self.mixer_types is not None)
         if (self.layer_types is None and self.n_heads_per_layer is None
-                and self.n_dense_layers == 0
-                and not (self.has_kda and self.has_latent)):
+                and self.n_dense_layers == 0 and not by_runs):
             return None
         kinds = self.layer_kinds
 
@@ -689,14 +770,17 @@ class ModelConfig:
             return p, len(rest) // p, len(rest) % p
 
         lead = min(self.n_dense_layers, self.n_layers)
-        if not (self.has_kda and self.has_latent):
+        if not by_runs:
             return LayerPlan(lead, *periodic(kinds[lead:]))
         # Several attention kinds: the elements are RUNS of equal layers
         # (five KDA layers to a latent one: a body a run, not a layer), and
         # the lead goes on over as many runs as leave the fewest bodies
         # (the published model: two dense and three sparse KDA layers, then
         # six periods of a latent layer and five KDA layers, and the last
-        # latent layer; its first eight layers: runs of 2, 3, 1 and 2).
+        # latent layer; its first eight layers: runs of 2, 3, 1 and 2). A
+        # published ``mixer_types`` list goes through as it stands: a dense
+        # model's runs all count as lead, so an aperiodic list (runs of 1, 8,
+        # 1, 6, 2, 4, 1, 6, 3) is nine lead elements and no period.
         runs = []
         for k in kinds:
             if runs and runs[-1][0] == k:
@@ -1902,6 +1986,8 @@ def _parse_value(raw: str, target_type: Any) -> Any:
     if target_type is int:
         return int(raw)
     if target_type is float:
+        if raw.lower() in ("true", "false"):    # a flag that took a number
+            return raw.lower() == "true"
         return float(raw)
     return raw
 
@@ -2620,6 +2706,78 @@ def _p_tiny_ling() -> Config:
         inference=InferenceConfig(max_seq_len=128, page_size=8,
                                   num_pages=64, max_batch_size=4,
                                   prefill_chunk=16, decode_window=4),
+    )
+
+
+# MiniCPM-SALA's published ``mixer_types`` (config.json), as it stands: 8
+# sparse-attention layers among 24 lightning layers, in runs of no period.
+_SALA_MIXERS = tuple(
+    "minicpm4" if l in (0, 9, 16, 17, 22, 29, 30, 31) else "lightning-attn"
+    for l in range(32))
+
+
+def _sala_model(**kw) -> ModelConfig:
+    """MiniCPM-SALA (openbmb, config.json, model_type minicpm_sala): a dense
+    9B whose layers are ``minicpm4`` (InfLLM-v2 block-sparse softmax
+    attention: 32 query heads over 2 K/V heads, a norm on q and k, NO rotary
+    embedding, the 64 best blocks of 64 positions a query and K/V head, an
+    elementwise output gate) or ``lightning-attn`` (linear attention: 32
+    heads of 128 under a fixed decay a head, a norm and rotary q/k, a norm
+    over the concatenated heads and an elementwise output gate), under
+    muP's scalings (scale_emb 12, scale_depth 1.4 over sqrt(32 layers),
+    logits over hidden_size / dim_model_base = 16). The selection's sizes
+    are the family's (``SparseConfig``'s defaults)."""
+    base = dict(
+        name="minicpm-sala", vocab_size=73_448, max_seq_len=524_288,
+        d_model=4096, n_layers=32, n_heads=32, n_kv_heads=2, head_dim=128,
+        d_ff=16_384, pos_embedding="rope", rope_theta=10_000.0,
+        norm="rmsnorm", norm_eps=1e-6, activation="swiglu",
+        tie_embeddings=False, qk_norm=True, attn_gate="elementwise",
+        mixer_types=_SALA_MIXERS, sparse=SparseConfig(),
+        embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5,
+        logit_scale=256 / 4096,
+        dtype="bfloat16", param_dtype="bfloat16", kernels="pallas",
+        remat="full",
+    )
+    base.update(kw)
+    return ModelConfig(**base)
+
+
+@register_preset("minicpm-sala")
+def _p_sala() -> Config:
+    """MiniCPM-SALA at its published sizes, for serving (a deployment holds
+    a pipeline stage: the depth and the slice of ``mixer_types`` its chip
+    holds)."""
+    return Config(
+        model=_sala_model(),
+        inference=InferenceConfig(max_seq_len=71_680, page_size=64,
+                                  prefill_chunk=512,
+                                  prefill_chunk_tokens=4096),
+    )
+
+
+@register_preset("tiny-sala")
+def _p_tiny_sala() -> Config:
+    """Tiny MiniCPM-SALA for CPU tests: sparse, lightning x2, sparse; 4
+    query heads of 16 over 2 K/V heads; blocks (= pages) of 8 positions,
+    compressed keys over 4 positions every 2, block 0 and the last 3 forced
+    among the 6 a query attends: from 49 positions on a query chooses."""
+    return Config(
+        model=_sala_model(
+            name="tiny-sala", vocab_size=256, max_seq_len=256, d_model=64,
+            n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
+            mixer_types=("minicpm4", "lightning-attn", "lightning-attn",
+                         "minicpm4"),
+            sparse=SparseConfig(kernel=4, stride=2, block=8, init_blocks=1,
+                                local_blocks=3, topk=6),
+            residual_scale=1.4 / 4 ** 0.5, logit_scale=0.25,
+            dtype="float32", param_dtype="float32", kernels="xla",
+            remat="none"),
+        data=DataConfig(batch_size=4, seq_len=64),
+        inference=InferenceConfig(max_seq_len=256, page_size=8,
+                                  num_pages=160, max_batch_size=4,
+                                  prefill_chunk=16, prefill_chunk_tokens=32,
+                                  decode_window=4),
     )
 
 

@@ -35,6 +35,7 @@ import numpy as np
 from orion_tpu.config import Config
 from orion_tpu.infer.executor import DispatchExecutor
 from orion_tpu.infer.kv_cache import (
+    LIGHTNING_STATE,
     RING_K,
     RING_V,
     HostPagePool,
@@ -155,7 +156,10 @@ class InferenceEngine:
         # verify step agrees with its decode window on such a model
         # (tests/test_laguna.py); what is missing is a comparison of THESE
         # paths at the engine and a benchmark cell that runs them (ROADMAP
-        # R6). A power-retention model keeps a state row a slot, which
+        # R6). One plan model's prompts do enter in chunks, back to back
+        # inside a step (model.mixer_types, below): chunk rows BESIDE decode
+        # rows in one dispatch (inference.chunked_prefill) stay refused for
+        # every plan model. A power-retention model keeps a state row a slot, which
         # nothing can snapshot, share or roll back yet (ROADMAP R9): no
         # cached prefix or host tier, no resuming a prompt mid-way, no
         # drafts to reject, and its tail pages are not quantised.
@@ -204,6 +208,23 @@ class InferenceEngine:
             why.append(
                 "keeps a state row a request and no page in its KDA layers "
                 "(model.attention=kda)")
+            refused += kv_only
+        if self.mcfg.resumes_prefill:
+            # Sparse layers read the pages a query selects through their
+            # compressed keys, lightning layers keep a state row a slot. A
+            # prompt DOES enter in chunks that resume from both (back to
+            # back, inside one step: _prefill_bucket), which is all of
+            # chunking this model has: the mixed step's chunk rows attend
+            # through the dense prefix gather, which selects nothing and
+            # carries no state (inference.chunked_prefill). Nothing
+            # snapshots a state row at a page boundary (no cached prefix, no
+            # host tier, no long-context paging), nothing rolls one back (no
+            # drafts, no constrained drafts), the verify kernel walks every
+            # page, and neither int8 form has been held to the reference
+            # under a selection.
+            why.append(
+                "selects the pages its sparse layers read and keeps a state "
+                "row a request in its lightning layers (model.mixer_types)")
             refused += kv_only
         if self.mcfg.has_window_ring:
             # Window layers keep a ring of their last positions a slot and
@@ -1583,6 +1604,30 @@ class InferenceEngine:
             # token costs). Host arithmetic on lengths.
             "decode_kda_slot_layers": 0, "prefill_kda_token_layers": 0,
             "kda_live_state_bytes": 0,
+            # A model of sparse and lightning layers (all 0 for any other).
+            # Per token step, summed over the live slots, the sparse layers
+            # and their K/V heads: the keys the selected pages hold at or
+            # before the new position, which is what the decode kernel
+            # attends and reads (decode_sparse_visible_keys), and the keys
+            # in context (decode_sparse_context_keys: the first over it is
+            # the share a query sees). Over a prefill's real positions, the
+            # sparse layers and the QUERY heads: the (head, key) pairs the
+            # selections leave visible (prefill_sparse_visible_pairs). The
+            # live slots x the lightning layers a token step, each one state
+            # row read and written (decode_lightning_slot_layers), and the
+            # real prompt positions x the lightning layers the chunked form
+            # computes (prefill_lightning_token_layers). Summed at each
+            # decode window: the bytes of the pages the live slots hold (K,
+            # V and compressed keys: sala_live_page_bytes), of their state
+            # rows (lightning_live_state_bytes) and their cached positions
+            # (sala_live_tokens). Host arithmetic on lengths: a selection
+            # takes min(causal blocks, topk) pages whatever it picks.
+            "decode_sparse_visible_keys": 0, "decode_sparse_context_keys": 0,
+            "prefill_sparse_visible_pairs": 0,
+            "decode_lightning_slot_layers": 0,
+            "prefill_lightning_token_layers": 0,
+            "sala_live_page_bytes": 0, "lightning_live_state_bytes": 0,
+            "sala_live_tokens": 0,
             # Prefill sizing: prefill_tokens counts the real prompt
             # positions the prefill dispatches computed (prefix-cached
             # positions excluded), prefill_pad_tokens the rest of each
@@ -2728,6 +2773,11 @@ class InferenceEngine:
                 f"model {self.mcfg.name!r} keeps a state row a request and "
                 f"no page in its KDA layers (model.attention=kda), which "
                 f"migration, a copy of pages, does not ship")
+        if self.mcfg.resumes_prefill:
+            raise ValueError(
+                f"model {self.mcfg.name!r} keeps a state row a request in "
+                f"its lightning layers (model.mixer_types), which migration, "
+                f"a copy of pages, does not ship")
         if self.mcfg.is_latent:
             raise ValueError(
                 f"model {self.mcfg.name!r} caches one compressed row a "
@@ -3230,7 +3280,20 @@ class InferenceEngine:
         # row would pay the burst-max O(S^2) attention — so keep one
         # dispatch per bucket there. Rows are padded up to a power-of-two
         # batch so jit specializations stay bounded.
-        if admitted:
+        if admitted and self.mcfg.resumes_prefill:
+            # One request a burst: its chunks run back to back, the last
+            # of the step's last request may stay in flight.
+            for i, (req, s_pad) in enumerate(admitted):
+                try:
+                    self._prefill_bucket(
+                        [req], s_pad, chain=i == len(admitted) - 1)
+                except DispatchFault:
+                    for r, _ in reversed(admitted[i + 1:]):
+                        self._teardown_slot(r, 0)
+                        r.freed_until = 0
+                        self.waiting.appendleft(r)
+                    raise
+        elif admitted:
             from orion_tpu.ops._dispatch import resolve_impl
 
             if resolve_impl(self.mcfg.kernels)[0]:
@@ -3276,6 +3339,18 @@ class InferenceEngine:
         for the step's decode window to be queued behind it; whoever needs
         the first tokens on the host calls ``_finish_prefill``. Any other
         burst is finished before this returns."""
+        # Where the burst's rows start: behind a cached prefix, or, for a
+        # model whose prefill resumes, behind the chunks launched here.
+        first_page = {r.rid: r.n_prefix for r in reqs}
+        if self.mcfg.resumes_prefill:
+            (req,) = reqs       # (_admit hands such a model one at a time)
+            try:
+                done = self._prefill_earlier_chunks(req)
+            except DispatchFault:
+                self._unwind_burst(reqs)
+                raise
+            first_page[req.rid] = done // self.psz
+            s_pad = self._bucket_len(len(req.context) - done)
         with self._phase("prefill/build"):
             n_pages = s_pad // self.psz
             nb = 1 << (len(reqs) - 1).bit_length()   # next power of two
@@ -3285,6 +3360,8 @@ class InferenceEngine:
             pages = np.zeros((nb, n_pages), np.int32)
             max_pre = max(r.n_prefix for r in reqs)
             p_pre = 1 << (max_pre - 1).bit_length() if max_pre > 0 else 0
+            if self.mcfg.resumes_prefill:
+                p_pre = self.pages_per_seq      # the slot's whole row
             pre_lens = np.zeros(nb, np.int32)
             pre_pages = np.zeros((nb, p_pre), np.int32)
             # Where each row's pick lands in the step's last tokens (a
@@ -3295,11 +3372,12 @@ class InferenceEngine:
             # owns (slot + 1; padding rows take scratch row 0).
             state_rows = (
                 None if self._chunk is None and not (
-                    self.mcfg.has_kda or self.mcfg.has_window_ring)
+                    self.mcfg.has_kda or self.mcfg.has_window_ring
+                    or self.mcfg.resumes_prefill)
                 else np.where(
                     slots < self.max_batch, slots + 1, 0).astype(np.int32))
             for i, req in enumerate(reqs):
-                npre = req.n_prefix
+                npre = first_page[req.rid]
                 tail = req.context[npre * self.psz:]
                 tokens[i, : len(tail)] = tail
                 lengths[i] = len(tail)
@@ -3309,7 +3387,9 @@ class InferenceEngine:
                     # prefills one position, written beyond the cursor.
                     lengths[i] = max(int(self.seq_lens[req.slot]), 1)
                 pre_lens[i] = npre * self.psz
-                if npre:
+                if self.mcfg.resumes_prefill:
+                    pre_pages[i] = self.page_table[req.slot]
+                elif npre:
                     # Dead (behind-window) matched pages point at scratch
                     # 0 — behind every tail query's window, never
                     # attended.
@@ -3402,6 +3482,8 @@ class InferenceEngine:
         if self.mcfg.has_kda:
             self.timing["prefill_kda_token_layers"] += (
                 self.mcfg.n_layers_of("kda") * real)
+        if self.mcfg.resumes_prefill:
+            self._count_resumed_prefill(int(pre_lens[0]), real)
         if self.mcfg.is_moe:
             # Pad rows have length 1, so one position of each routes too.
             self.timing["prefill_expert_rows"] += expert_rows(
@@ -4394,6 +4476,25 @@ class InferenceEngine:
             self.timing["decode_tail_token_layers"] += L * int(
                 (lens - folded).sum() * W + len(active) * (W * (W + 1) // 2))
             return active, W, common
+        if self.mcfg.resumes_prefill:
+            lens = self.seq_lens[mask].astype(np.int64)
+            steps = lens[:, None] + np.arange(W)    # the new token's position
+            per = self.mcfg.n_layers_of("sparse") * self.mcfg.n_kv_heads
+            t = self.timing
+            t["decode_sparse_visible_keys"] += per * int(
+                self._visible_keys(steps).sum())
+            t["decode_sparse_context_keys"] += per * int((steps + 1).sum())
+            L = self.mcfg.n_layers_of("lightning")
+            t["decode_lightning_slot_layers"] += L * W * len(active)
+            state = self.cache[LIGHTNING_STATE]
+            t["lightning_live_state_bytes"] += len(active) * L * (
+                math.prod(state.shape[2:]) * state.dtype.itemsize)
+            held = sum(p is not None for r in active for p in r.pages)
+            t["sala_live_page_bytes"] += held * sum(
+                a.size * a.dtype.itemsize for n, a in self.cache.items()
+                if n != LIGHTNING_STATE) // self.icfg.num_pages
+            t["sala_live_tokens"] += int(lens.sum())
+            return active, W, common
         if self.mcfg.has_kda:
             L = self.mcfg.n_layers_of("kda")
             self.timing["decode_kda_slot_layers"] += L * W * len(active)
@@ -4417,6 +4518,58 @@ class InferenceEngine:
         self.timing["decode_kv_tokens"] += kv
         self._count_kv_by_layer_kind(self.seq_lens[mask].astype(np.int64), W)
         return active, W, common
+
+    def _visible_keys(self, pos: np.ndarray) -> np.ndarray:
+        """Keys a sparse layer's query at position ``pos`` attends in one
+        K/V head: every key up to it while its causal blocks are ``topk`` or
+        fewer, else ``topk - 1`` whole blocks and its own block up to it."""
+        sp = self.mcfg.sparse
+        return np.minimum(pos + 1, (sp.topk - 1) * sp.block
+                          + pos % sp.block + 1)
+
+    def _count_resumed_prefill(self, start: int, n: int) -> None:
+        """The prefill counters of a model of sparse and lightning layers
+        for ``n`` real positions from ``start`` on."""
+        pos = np.arange(start, start + n, dtype=np.int64)
+        self.timing["prefill_sparse_visible_pairs"] += (
+            self.mcfg.n_layers_of("sparse") * self.mcfg.n_heads
+            * int(self._visible_keys(pos).sum()))
+        self.timing["prefill_lightning_token_layers"] += (
+            self.mcfg.n_layers_of("lightning") * n)
+
+    def _prefill_earlier_chunks(self, req: Request) -> int:
+        """A model whose prefill resumes (``ModelConfig.resumes_prefill``):
+        launch every chunk of ``inference.prefill_chunk_tokens`` positions
+        of ``req``'s prompt but the last, back to back and waited for by
+        nobody (each takes the cache the one before hands on; the last
+        chunk's wait is theirs). Each reads the history through the slot's
+        page-table row and the slot's state row. Returns the positions
+        done, a multiple of the chunk: where the last chunk starts."""
+        C, n = self.icfg.prefill_chunk_tokens, len(req.context)
+        slot, done = req.slot, 0
+        if n <= C:
+            return 0
+        row = jnp.asarray(self.page_table[slot:slot + 1].copy())
+        one = lambda value: jnp.asarray(np.full((1,), value, np.int32))
+        state_rows, nowhere, length = one(slot + 1), one(self.max_batch), one(C)
+        while n - done > C:
+            first = done // self.psz
+            pages = np.zeros((1, C // self.psz), np.int32)
+            pages[0] = [0 if p is None else p
+                        for p in req.pages[first:first + C // self.psz]]
+            tokens = np.zeros((1, C), np.int32)
+            tokens[0] = req.context[done:done + C]
+            with self._phase("prefill/run"):
+                _, self.cache = self._executor.run(
+                    "prefill", "prefill", self.params, self.cache,
+                    jnp.asarray(tokens), length, jnp.asarray(pages),
+                    one(done), row, state_rows, nowhere,
+                    jnp.asarray(self.last_token.copy()), self._key)
+            self.timing["prefill_dispatches"] += 1
+            self.timing["prefill_tokens"] += C
+            self._count_resumed_prefill(done, C)
+            done += C
+        return done
 
     def _fold_tails(self) -> None:
         """A power-retention model, at the start of a decode window: every

@@ -179,7 +179,7 @@ class DispatchExecutor:
             nb = tokens.shape[0]
             if state_rows is None and (
                     mcfg.is_retention or mcfg.has_kda
-                    or mcfg.has_window_ring):
+                    or mcfg.has_window_ring or mcfg.resumes_prefill):
                 state_rows = jnp.zeros((nb,), jnp.int32)    # the scratch row
             if slots is None:
                 # Out of range: the scatter of the picks drops every row.

@@ -85,7 +85,19 @@ def paged_leaf(cache: "Cache") -> jax.Array:
 def page_geometry(cache: "Cache", n_layers: int) -> tuple[int, int]:
     """(page size, pages a layer) of a cache's pool."""
     leaf = paged_leaf(cache)
+    if "ck" in cache:       # ``sala_leaves``: K and V one head a row
+        return leaf.shape[2], cache["ck"].shape[0] // n_layers
     return leaf.shape[2], leaf.shape[0] // n_layers
+
+
+def page_rows(arr: jax.Array, rows: jax.Array, total: int) -> jax.Array:
+    """The rows of leaf ``arr`` that pool rows ``rows`` (layer x pages +
+    page, of ``total``) own: themselves, or, in a leaf that keeps several
+    rows a page (``sala_leaves``: one a K/V head), all of those."""
+    per = arr.shape[0] // total
+    if per == 1:
+        return rows
+    return (rows[:, None] * per + jnp.arange(per, dtype=rows.dtype)).reshape(-1)
 
 
 def latent_width(mcfg: ModelConfig) -> int:
@@ -126,6 +138,52 @@ def kda_leaves(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> "Cache":
         KDA_STATE: jnp.zeros((L, slots, N, H, H), jnp.float32),
         KDA_CONV: jnp.zeros(
             (L, slots, mcfg.kda_conv_size - 1, 3 * N * H), dtype),
+    }
+
+
+COMPRESSED, LIGHTNING_STATE = "ck", "lightning_state"
+
+
+def sala_leaves(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> "Cache":
+    """The cache of a model of sparse and lightning layers
+    (``ModelConfig.mixer_types``), by cache kind. ``k`` / ``v``: the SPARSE
+    layers' pages, which the allocator counts as ever, ONE K/V HEAD A ROW:
+    [sparse layers x pages x K/V heads, 1, page, head], head g of page p of
+    layer l at row ``(l x pages + p) x heads + g``. A query selects its
+    pages a K/V head, so the decode kernel walks a page list a (slot, head)
+    and copies one head's rows of a page; the compiler keeps a [.., 2, 64,
+    128] leaf with the two heads interleaved, and a view of it one head a
+    row is then a copy of the pool at every call. ``ck``: the compressed
+    keys, a page's ``block / stride`` kernels a row, [sparse layers x pages,
+    K/V heads, kernels, head] (page p's last kernel reads into page p + 1
+    and is written when that key arrives; ``ops/sparse.py``). A page and
+    its compressed keys are allocated, freed and scrubbed together
+    (``page_rows``).
+    ``lightning_state``: the LIGHTNING layers' recurrence, a slot's row and
+    no page, [lightning layers, slots + 1, heads, d_v, d_k] float32
+    (value-major; slot b owns row b + 1, row 0 a scratch row). A prompt's
+    chunk resumes from all three."""
+    from orion_tpu.ops.sparse import kernels_per_page
+
+    if not mcfg.has_sparse:
+        raise ValueError(
+            "a model of lightning layers alone has no paged layer, which "
+            "the engine's allocator counts sequences by: not served")
+    if mcfg.sparse.block != icfg.page_size:
+        raise ValueError(
+            f"a selected block is a page: inference.page_size="
+            f"{icfg.page_size} must be model.sparse.block="
+            f"{mcfg.sparse.block}")
+    rows = mcfg.n_paged_layers * icfg.num_pages
+    K, N, H = mcfg.n_kv_heads, mcfg.n_heads, mcfg.resolved_head_dim
+    return {
+        "k": jnp.zeros((rows * K, 1, icfg.page_size, H), dtype),
+        "v": jnp.zeros((rows * K, 1, icfg.page_size, H), dtype),
+        COMPRESSED: jnp.zeros(
+            (rows, K, kernels_per_page(mcfg.sparse), H), dtype),
+        LIGHTNING_STATE: jnp.zeros(
+            (mcfg.n_layers_of("lightning"), icfg.max_batch_size + 1, N, H, H),
+            jnp.float32),
     }
 
 
@@ -250,6 +308,8 @@ def init_cache(
         dtype = jnp.dtype(mcfg.dtype)
         if mcfg.has_window_ring:
             return ring_cache(mcfg, icfg, dtype)
+        if mcfg.mixer_types is not None:
+            return sala_leaves(mcfg, icfg, dtype)
         if mcfg.has_kda:
             if not mcfg.has_latent:
                 raise ValueError(
@@ -273,7 +333,8 @@ def init_cache(
 # The leaves of ``retention_leaves`` that are a slot's and not a page's.
 SLOT_LEAVES = ("state", "state_z", "state_len", "g")
 # Every leaf of any backend that is a slot's: what page operations pass by.
-NOT_PAGED = SLOT_LEAVES + (KDA_STATE, KDA_CONV, RING_K, RING_V)
+NOT_PAGED = SLOT_LEAVES + (KDA_STATE, KDA_CONV, RING_K, RING_V,
+                          LIGHTNING_STATE)
 
 
 def retention_leaves(mcfg: ModelConfig, icfg: InferenceConfig, dtype) -> Cache:
@@ -472,7 +533,9 @@ def poison_page(cache: Cache, page, *, n_layers: int, num_pages: int) -> Cache:
               else "k" if "k" in cache else LATENT)
     out = dict(cache)
     arr = out[target]
-    out[target] = arr.at[layer_rows].set(jnp.asarray(jnp.nan, arr.dtype))
+    out[target] = arr.at[page_rows(
+        arr, layer_rows, n_layers * num_pages)].set(
+            jnp.asarray(jnp.nan, arr.dtype))
     return out
 
 
@@ -494,7 +557,9 @@ def scrub_pages(
         if name in NOT_PAGED:       # no page: the next prefill writes the row
             out[name] = arr
         else:                       # [layers x pages, ...]
-            out[name] = arr.at[layer_rows].set(jnp.zeros((), arr.dtype))
+            out[name] = arr.at[page_rows(
+                arr, layer_rows, n_layers * num_pages)].set(
+                    jnp.zeros((), arr.dtype))
     return out
 
 

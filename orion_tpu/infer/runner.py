@@ -45,9 +45,11 @@ import jax.numpy as jnp
 
 from orion_tpu.config import ModelConfig
 from orion_tpu.infer.kv_cache import (
+    COMPRESSED,
     KDA_CONV,
     KDA_STATE,
     LATENT,
+    LIGHTNING_STATE,
     RING_K,
     RING_V,
     pack_keys,
@@ -498,6 +500,10 @@ def _prefill(params, cache, tokens, lengths, pages, prefix_lens,
         raise ValueError(
             "a model with KDA layers prefills whole prompts: a cached prefix "
             "would need its state, which nothing snapshots")
+    if cfg.resumes_prefill:
+        return _resumed_prefill(params, cache, tokens, lengths, pages,
+                                prefix_lens, prefix_pages, state_rows, cfg,
+                                mesh)
     ctx = _prefill_ctx(
         params, cache, tokens, lengths, pages, prefix_lens, prefix_pages,
         cfg, paged_prefill=paged_prefill,
@@ -560,6 +566,9 @@ def _decode_core(
     if cfg.has_window_ring:
         ctx, layer = _split_ctx(
             cache, write_pos, page_table, cfg), _split_layer
+    elif cfg.resumes_prefill:
+        ctx = _selected_ctx(cache, write_pos, page_table, cfg, active)
+        layer = _hybrid(lightning=_lightning_layer, sparse=_sparse_layer)
     elif cfg.has_kda:
         ctx = {**_latent_ctx(cache, write_pos, page_table, cfg),
                "active": active}
@@ -1678,11 +1687,13 @@ def _latent_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
 # backend by its kind, and the latent layers' pool is sized over them alone.
 
 
-def _hybrid(kda_layer, latent_layer):
-    """One layer function for a model whose layers differ in backend."""
+def _hybrid(kda_layer=None, latent_layer=None, **by_attention):
+    """One layer function for a model whose layers differ in backend: each
+    layer's by its ``LayerKind.attention``."""
+    by_attention = {"kda": kda_layer, "latent": latent_layer, **by_attention}
+
     def layer(x, cc, bp, l, j, ctx, cfg, *rest):
-        fn = (kda_layer if cfg.layer_kind(j).attention == "kda"
-              else latent_layer)
+        fn = by_attention[cfg.layer_kind(j).attention]
         return fn(x, cc, bp, l, j, ctx, cfg, *rest)
 
     return layer
@@ -1762,6 +1773,280 @@ def _kda_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
             tails = jax.lax.dynamic_update_slice(tails, moved[None], at)
         return (o[:, None].astype(xs.dtype),
                 {**cc, KDA_STATE: state, KDA_CONV: tails})
+
+    x, _, cc = block(x, bp, cfg, ctx["positions"], attend,
+                     kind=_kind(cfg, j), mesh=mesh)
+    return x, cc
+
+
+# -- the sparse and lightning backends: pages a query selects, a state row ----
+#
+# A model of ``mixer_types`` (config.ModelConfig; kv_cache.sala_leaves). A
+# SPARSE layer writes K and V into pages as the paged backend does, and the
+# compressed keys of the kernels that its new positions complete beside them;
+# each query then selects its pages through the compressed keys and attends
+# to those alone (ops/sparse.py: a page list a query and K/V head, walked by
+# the paged decode kernel over virtual slots). A LIGHTNING layer keeps a state
+# row a slot and no page (ops/lightning.py). Both RESUME: a prefill block is
+# a page-aligned chunk that starts at ``prefix_lens`` (0: the prompt's first)
+# over the row's page table ``prefix_pages`` (the slot's WHOLE row, the
+# chunk's own pages at their places), reads the history's pages and
+# compressed keys through it, and takes the lightning state the last chunk
+# left in the slot's row. With no ``prefix_pages`` the block is a whole
+# prompt over its own pages. A decode step is the same layer code at one
+# query a slot, the new token's write fused into the kernel.
+
+# An extra leaf a caller may put into the cache it hands a program: [sparse
+# layers, rows, K/V heads, topk] int32, which each sparse layer then fills
+# with the block ids it selected at each row's LAST real position (the
+# benchmark's probe reads the selection back through it). The engine's own
+# cache never has it.
+SELECTED = "sparse_ids"
+
+
+def _resumed_prefill(params, cache, tokens, lengths, pages, prefix_lens,
+                     prefix_pages, state_rows, cfg, mesh):
+    """``_prefill`` for a model of sparse and lightning layers: one block of
+    each row's prompt, from ``prefix_lens`` on."""
+    from orion_tpu.ops._dispatch import resolve_impl
+
+    Nb, S = tokens.shape
+    psz, NP = page_geometry(cache, cfg.n_paged_layers)
+    resumed = prefix_pages is not None and prefix_pages.shape[1] > 0
+    start = (prefix_lens.astype(jnp.int32) if resumed
+             else jnp.zeros((Nb,), jnp.int32))
+    at = jnp.arange(S, dtype=jnp.int32)[None]
+    use_pallas, interpret = resolve_impl(cfg.kernels)
+    ctx = dict(
+        psz=psz, NP=NP, positions=start[:, None] + at,
+        seg=(at < lengths[:, None]).astype(jnp.int32), lengths=lengths,
+        start=start, pages=pages, table=prefix_pages if resumed else pages,
+        state_rows=(jnp.zeros((Nb,), jnp.int32) if state_rows is None
+                    else state_rows),
+        use_pallas=use_pallas, interpret=interpret, fused=False,
+    )
+    layer = _hybrid(lightning=_lightning_prefill_layer,
+                    sparse=_sparse_prefill_layer)
+
+    def body(carry, bp, l, j, stack=None):
+        x, cc = carry
+        return layer(x, cc, bp, l, j, ctx, cfg, mesh, stack)
+
+    x = embed(params, tokens, ctx["positions"], cfg)
+    x, cache = _scan_layers(params, cfg, body, (x, dict(cache)))
+    return _prefill_logits(params, x, lengths, cfg, mesh), cache
+
+
+def _selected_ctx(cache: Cache, pos: jax.Array, page_table: jax.Array,
+                  cfg: ModelConfig, active) -> dict:
+    """The decode step's tensors of a model of sparse and lightning layers:
+    one new position a slot at ``pos`` [B]."""
+    from orion_tpu.ops._dispatch import resolve_impl
+
+    psz, NP = page_geometry(cache, cfg.n_paged_layers)
+    at = jnp.minimum(pos, page_table.shape[1] * psz - 1).astype(jnp.int32)
+    use_pallas, interpret = resolve_impl(cfg.kernels)
+    return dict(psz=psz, NP=NP, positions=at[:, None], table=page_table,
+                active=active, use_pallas=use_pallas, interpret=interpret,
+                fused=True)
+
+
+def _sparse_attend(q, k, v, cc: Cache, li, ctx: dict, cfg: ModelConfig):
+    """A sparse layer's attention for the new positions ``ctx['positions']``
+    [B, Q] of each row (queries q [B, Q, N, H], keys and values [B, Q, K, H]):
+    K, V and the compressed keys they complete into the pool, each query's
+    selection, and attention over the selected pages. -> (out [B, Q, N, H],
+    the leaves written)."""
+    from orion_tpu.ops import sparse
+
+    sp, psz, NP = cfg.sparse, ctx["psz"], ctx["NP"]
+    kpp = sparse.kernels_per_page(sp)
+    table, pos, base = ctx["table"], ctx["positions"], li * NP
+    (B, Q), K = pos.shape, k.shape[2]
+    fused, use_pallas = ctx["fused"], ctx["use_pallas"]
+    page_of = lambda idx: jnp.take_along_axis(table, idx, axis=1)
+    # The pools keep one K/V head a row: the rows of pages [...] -> [..., K].
+    heads = lambda pages: (base + pages)[..., None] * K + jnp.arange(K)
+    new = {}
+    with jax.named_scope("cache"):
+        if fused:
+            # One position a row: the kernel that ends with it (if one
+            # does) from the keys before it in the pool and the new key.
+            t = pos[:, 0]
+            done = ((t + 1) % sp.stride == 0) & (t + 1 >= sp.kernel)
+            back = jnp.maximum(
+                t[:, None] - (sp.kernel - 1)
+                + jnp.arange(sp.kernel - 1, dtype=jnp.int32)[None], 0)
+            earlier = cc["k"][heads(page_of(back // psz)), 0,
+                              (back % psz)[..., None]]      # [B, .., K, H]
+            c = sparse.compress_one(
+                jnp.concatenate([earlier, k.astype(earlier.dtype)], 1), sp)
+            jn = jnp.maximum((t + 1 - sp.kernel) // sp.stride, 0)
+            crow = jnp.where(done, base + page_of((jn // kpp)[:, None])[:, 0],
+                             0)
+            ck = cc[COMPRESSED].at[crow, :, jn % kpp].set(
+                c.astype(cc[COMPRESSED].dtype))
+            k_pool, v_pool = cc["k"], cc["v"]
+            if not use_pallas:      # (the kernel writes the position itself)
+                at = (heads(page_of(pos // psz)), 0, (pos % psz)[..., None])
+                k_pool = k_pool.at[at].set(k.astype(k_pool.dtype))
+                v_pool = v_pool.at[at].set(v.astype(v_pool.dtype))
+        else:
+            # A page-aligned chunk: its pages, then the kernels that end
+            # inside it (the first belongs to the page before the chunk).
+            rows = heads(ctx["pages"])                      # [B, pages, K]
+            paged = lambda a: a.reshape(B, Q // psz, psz, K, -1).transpose(
+                0, 1, 3, 2, 4)
+            k_pool = cc["k"].at[rows, 0].set(paged(k).astype(cc["k"].dtype))
+            v_pool = cc["v"].at[rows, 0].set(paged(v).astype(cc["v"].dtype))
+            s0 = ctx["start"]
+            before = jnp.where(
+                s0 > 0,
+                page_of(jnp.maximum(s0 // psz - 1, 0)[:, None])[:, 0], 0)
+            c = sparse.compress(
+                k_pool[heads(before), 0].transpose(0, 2, 1, 3), k, sp)
+            jn = (s0[:, None] // sp.stride - 1
+                  + jnp.arange(Q // sp.stride, dtype=jnp.int32)[None])
+            jc = jnp.maximum(jn, 0)
+            crow = jnp.where(jn >= 0, base + page_of(jc // kpp), 0)
+            ck = cc[COMPRESSED].at[crow, :, jc % kpp].set(
+                c.astype(cc[COMPRESSED].dtype))
+        new[COMPRESSED] = ck
+    with jax.named_scope("kernel"):
+        with jax.named_scope("select"):
+            # The row's compressed keys in position order, [B, J, K, H].
+            P = table.shape[1]
+            ckg = ck[base + table].transpose(0, 1, 3, 2, 4).reshape(
+                B, P * kpp, K, ck.shape[-1])
+
+        def tile(qt, post):
+            with jax.named_scope("select"):
+                ids, n = sparse.select(qt, ckg.astype(qt.dtype), post, sp)
+                T = ids.shape[-1]
+                used = jnp.arange(T)[None, None, None] < n[..., None]
+                pages = jnp.where(used, jnp.take_along_axis(
+                    jnp.broadcast_to(
+                        table[:, None, None, :], (*ids.shape[:3], P)),
+                    jnp.minimum(ids, P - 1), axis=-1), 0)
+            with jax.named_scope("sparse"):
+                if use_pallas:
+                    given = dict(k_new=k, v_new=v) if fused else {}
+                    out, *written = sparse.attend_pallas(
+                        qt, k_pool, v_pool, pages, n, post, layer_base=base,
+                        interpret=ctx["interpret"],
+                        name=("sparse_paged_decode" if fused
+                              else "sparse_paged_prefill"), **given)
+                else:
+                    out, written = sparse.attend_xla(
+                        qt, k_pool, v_pool, base + pages, ids, n, post), ()
+            return out, ids, written
+
+        Qt = sparse.query_tile(Q, B * K)
+        if Qt == Q:
+            out, ids, written = tile(q, pos)
+            if written:         # (a decode step's kernel wrote the position)
+                k_pool, v_pool = written
+        else:
+            cut = lambda a: jnp.moveaxis(
+                a.reshape(B, Q // Qt, Qt, *a.shape[2:]), 1, 0)
+            out, ids = jax.lax.map(
+                lambda xs: tile(*xs)[:2], (cut(q), cut(pos)))
+            out = jnp.moveaxis(out, 0, 1).reshape(q.shape)
+            ids = jnp.moveaxis(ids, 0, 2).reshape(
+                B, ids.shape[2], Q, ids.shape[-1])
+    new["k"], new["v"] = k_pool, v_pool
+    if SELECTED in cc:
+        # Each row's selection at its last real position.
+        last = (jnp.zeros((B,), jnp.int32) if fused
+                else jnp.maximum(ctx["lengths"], 1) - 1)
+        new[SELECTED] = cc[SELECTED].at[li].set(jnp.take_along_axis(
+            ids, last[:, None, None, None], axis=2)[:, :, 0])
+    return out, new
+
+
+def _sparse_prefill_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
+                          cfg: ModelConfig, mesh, stack=None):
+    """One sparse layer of a prompt's block: the block's pages and compressed
+    keys are written BEFORE its queries attend (they read their own block
+    out of the pool), so nothing is left to write behind the feed-forward."""
+    li = cfg.cache_layer(l, j)
+
+    def attend(q, k, v):
+        out, new = _sparse_attend(q, k, v, cc, li, ctx, cfg)
+        return out, lambda: new
+
+    return _prefill_block(x, cc, bp, j, ctx["positions"], attend,
+                          ctx["seg"] > 0, stack, cfg, mesh)
+
+
+def _sparse_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
+                  cfg: ModelConfig, mesh) -> tuple[jax.Array, Cache]:
+    """One sparse layer of a decode step (one new position a slot)."""
+    li = cfg.cache_layer(l, j)
+
+    def attend(q, k, v):
+        out, new = _sparse_attend(q, k, v, cc, li, ctx, cfg)
+        return out, {**cc, **new}
+
+    x, _, cc = block(x, bp, cfg, ctx["positions"], attend,
+                     kind=_kind(cfg, j), mesh=mesh)
+    return x, cc
+
+
+def _lightning_prefill_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
+                             cfg: ModelConfig, mesh, stack=None):
+    """One lightning layer of a prompt's block: the chunked form from the
+    state the last block left in the row's slot (zeros where the block
+    starts the prompt), and the state after the block's last real position
+    back into it."""
+    from orion_tpu.ops.lightning import lightning_chunked
+
+    li, rows = cfg.cache_layer(l, j), ctx["state_rows"]
+
+    def attend(q, k, v):
+        with jax.named_scope("kernel"), jax.named_scope("lightning"):
+            held = cc[LIGHTNING_STATE][li, rows]            # [Nb, N, H, H]
+            held = jnp.where(
+                ctx["start"][:, None, None, None] > 0, held, 0.0)
+            o, state = lightning_chunked(
+                q * jnp.asarray(q.shape[-1] ** -0.5, q.dtype), k, v, held,
+                ctx["lengths"])
+        return o.astype(q.dtype), lambda: {
+            LIGHTNING_STATE: cc[LIGHTNING_STATE].at[li, rows].set(state)}
+
+    return _prefill_block(x, cc, bp, j, ctx["positions"], attend,
+                          ctx["seg"] > 0, stack, cfg, mesh)
+
+
+def _lightning_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
+                     cfg: ModelConfig, mesh) -> tuple[jax.Array, Cache]:
+    """One lightning layer of a decode step: the slot's state advances one
+    position in place (``ctx['active']`` [B]: the slots that do; None: all)."""
+    from orion_tpu.ops.lightning import lightning_step
+
+    li, active = cfg.cache_layer(l, j), ctx["active"]
+    B = ctx["positions"].shape[0]
+
+    def attend(q, k, v):
+        q1 = q[:, 0] * jnp.asarray(q.shape[-1] ** -0.5, q.dtype)
+        st = cc[LIGHTNING_STATE]
+        with jax.named_scope("kernel"), jax.named_scope("lightning"):
+            if ctx["use_pallas"]:
+                if mesh is not None:
+                    raise ValueError(
+                        "the lightning decode kernel runs on one device")
+                from orion_tpu.ops.pallas.lightning import lightning_decode
+
+                o, state = lightning_decode(
+                    st, q1, k[:, 0], v[:, 0], layer=li, active=active,
+                    interpret=ctx["interpret"])
+            else:
+                at = (li, 1, 0, 0, 0)       # the slots' rows of the layer
+                o, moved = lightning_step(
+                    jax.lax.dynamic_slice(st, at, (1, B, *st.shape[2:]))[0],
+                    q1, k[:, 0], v[:, 0], active)
+                state = jax.lax.dynamic_update_slice(st, moved[None], at)
+        return o[:, None].astype(q.dtype), {**cc, LIGHTNING_STATE: state}
 
     x, _, cc = block(x, bp, cfg, ctx["positions"], attend,
                      kind=_kind(cfg, j), mesh=mesh)

@@ -42,6 +42,12 @@ def _has_importer(cfg: ModelConfig) -> None:
     ``wf`` / ``a_log`` / ``dt_bias`` / ``wb`` / ``wg`` / ``o_norm`` among
     latent layers, whose rotary columns it stores interleaved): no importer
     is written for it, as for the other layer-plan models."""
+    if cfg.mixer_types is not None:
+        raise ValueError(
+            f"model {cfg.name!r} has block-sparse and lightning layers "
+            f"(model.mixer_types): no converter here reads or writes the "
+            f"minicpm_sala key set; it is served from seeded weights only "
+            f"(ROADMAP R11)")
     if cfg.has_kda:
         raise ValueError(
             f"model {cfg.name!r} has Kimi-delta-attention layers among "

@@ -222,10 +222,14 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                 block["mlp"]["b_in"] = jnp.zeros((F,), pdt)
                 block["mlp"]["b_out"] = jnp.zeros((D,), pdt)
         if cfg.attn_gate is not None and att != "kda":
-            block["attn"]["wg"] = _normal(next(bkeys), (D, N), pdt, std)
+            block["attn"]["wg"] = _normal(
+                next(bkeys), (D, _gate_width(cfg, N)), pdt, std)
         if cfg.qk_norm and att not in ("latent", "kda"):
             block["attn"]["q_norm"] = jnp.ones((H,), pdt)
             block["attn"]["k_norm"] = jnp.ones((H,), pdt)
+        if att == "lightning":
+            # The norm over a position's concatenated heads (out_proj).
+            block["attn"]["o_norm"] = jnp.ones((N * H,), pdt)
         if att == "power_retention":
             block["attn"]["wr"] = _normal(next(bkeys), (D, K), pdt, std)
 
@@ -246,9 +250,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                 fn = jax.vmap(fn)
             return fn(layer_keys[at])
 
-        params["blocks"] = {"period": {
-            str(j): element(plan.lead + j)
-            for j in range(plan.period) if plan.counts[j]}}
+        # (a plan of lead elements alone has no period to stack)
+        period = {str(j): element(plan.lead + j)
+                  for j in range(plan.period) if plan.counts[j]}
+        params["blocks"] = {"period": period} if period else {}
         if plan.lead:
             params["blocks"]["lead"] = {
                 str(i): element(i) for i in range(plan.lead)}
@@ -273,9 +278,9 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
             depth = jnp.asarray(plan.layers(e)).ndim
             return _block_axes(cfg, ("layers",) * depth, kinds[plan.start(e)])
 
-        blocks: Params = {"period": {
-            str(j): element(plan.lead + j)
-            for j in range(plan.period) if plan.counts[j]}}
+        period = {str(j): element(plan.lead + j)
+                  for j in range(plan.period) if plan.counts[j]}
+        blocks: Params = {"period": period} if period else {}
         if plan.lead:
             blocks["lead"] = {str(i): element(i) for i in range(plan.lead)}
     else:
@@ -358,6 +363,8 @@ def _block_axes(cfg: ModelConfig, lead: tuple, kind) -> Params:
     if cfg.qk_norm and att not in ("latent", "kda"):
         block["attn"]["q_norm"] = lead + (None,)
         block["attn"]["k_norm"] = lead + (None,)
+    if att == "lightning":
+        block["attn"]["o_norm"] = lead + ("heads",)
     if att == "power_retention":
         block["attn"]["wr"] = lead + ("embed", "kv_heads")
     if moe:
@@ -400,6 +407,13 @@ def _attention_of(cfg: ModelConfig, kind) -> str:
     return cfg.layer_attention(0) if kind is None else kind.attention
 
 
+def _gate_width(cfg: ModelConfig, n_heads: int) -> int:
+    """Columns of ``attn.wg``: a gate a head, or one a number of it."""
+    if cfg.attn_gate == "elementwise":
+        return n_heads * cfg.resolved_head_dim
+    return n_heads
+
+
 def _gate_act(cfg: ModelConfig):
     """Gating nonlinearity for gated MLPs: SiLU (SwiGLU) or tanh-approx
     GELU (GeGLU, the Gemma-family gate)."""
@@ -433,9 +447,12 @@ def embed(
     the inference cache runner."""
     x = params["embed"]["tokens"].astype(jnp.dtype(cfg.dtype))[tokens]
     if cfg.embed_scale:
-        # Gemma-family: embeddings scaled by sqrt(d_model), rounded in the
-        # activation dtype (matches the HF normalizer semantics).
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        # Gemma-family (True): embeddings scaled by sqrt(d_model), rounded
+        # in the activation dtype (matches the HF normalizer semantics); a
+        # number: by that number (muP's scale_emb).
+        scale = (cfg.d_model ** 0.5 if cfg.embed_scale is True
+                 else cfg.embed_scale)
+        x = x * jnp.asarray(scale, x.dtype)
     if cfg.pos_embedding == "learned":
         x = x + params["embed"]["positions"].astype(x.dtype)[positions]
     return x
@@ -452,6 +469,8 @@ def unembed(
     for a caller that ranks positions by a probability of the logits, which
     two programs must then compute alike."""
     x = _norm(x, params["final_norm"], cfg, mesh)
+    if cfg.logit_scale != 1.0:
+        x = x * jnp.asarray(cfg.logit_scale, x.dtype)
     kw = {"preferred_element_type": jnp.float32} if precise else {}
     if cfg.tie_embeddings:
         logits = jnp.einsum(
@@ -484,9 +503,13 @@ def qkv_proj(
     B, S, _ = x.shape
     N, K, H = cfg.n_heads, cfg.kv_heads_of(kind), cfg.resolved_head_dim
     theta, table = cfg.rope_theta, None
+    rotary = cfg.pos_embedding == "rope"
     if kind is not None:
-        N, theta = kind.n_heads, kind.rope.theta
-        table = None if kind.rope.is_plain else kind.rope
+        N = kind.n_heads
+        rotary = rotary and kind.rope is not None
+        if rotary:
+            theta = kind.rope.theta
+            table = None if kind.rope.is_plain else kind.rope
     dtype = x.dtype
 
     q = jnp.einsum("bsd,dh->bsh", x, _load_w(p["wq"], dtype))
@@ -506,7 +529,7 @@ def qkv_proj(
         q = ops.rmsnorm(q, p["q_norm"], eps=cfg.norm_eps)
         k = ops.rmsnorm(k, p["k_norm"], eps=cfg.norm_eps)
 
-    if cfg.pos_embedding == "rope":
+    if rotary:
         rope = functools.partial(
             ops.apply_rope, theta=theta, rope=table, impl=cfg.kernels,
             mesh=mesh,
@@ -531,15 +554,25 @@ def out_proj(out: jax.Array, p: Params, cfg: ModelConfig,
     ``qkv_proj`` read): the head-wise gate of arXiv:2505.06708. A KDA
     layer (``att``) has a gate of its own: every head's output under an
     RMSNorm over its numbers (``o_norm`` [H]) times ``sigmoid(h wg)``, wg
-    of full rank [D, N x H]."""
+    of full rank [D, N x H]. ``attn_gate`` "elementwise": every number of
+    the output by its own gate (wg [D, N x H]); a lightning layer's output
+    is first normed over a position's concatenated heads (``o_norm``)."""
     B, S = out.shape[0], out.shape[1]
     dtype = out.dtype
+    if att == "lightning":
+        out = ops.rmsnorm(out.reshape(B, S, -1), p["o_norm"],
+                          eps=cfg.norm_eps).astype(h.dtype).reshape(out.shape)
+        dtype = h.dtype
     if att == "kda":
         gate = jax.nn.sigmoid(jnp.einsum(
             "bsd,dh->bsh", h, _load_w(p["wg"], h.dtype)))
         out = ops.rmsnorm(out, p["o_norm"], eps=cfg.norm_eps).astype(
             h.dtype) * gate.reshape(out.shape)
         dtype = h.dtype
+    elif cfg.attn_gate == "elementwise":
+        out = out * jax.nn.sigmoid(jnp.einsum(
+            "bsd,dh->bsh", h, _load_w(p["wg"], h.dtype))).reshape(
+                out.shape).astype(dtype)
     elif cfg.attn_gate is not None:
         out = out * _attn_gate(h, p, cfg)[..., None].astype(dtype)
     y = jnp.einsum(
@@ -808,6 +841,24 @@ def _train_attend(
 
         return retain
 
+    if att in ("lightning", "sparse"):
+        if sp_active or segment_ids is not None:
+            raise ValueError(
+                f"a {att} layer trains whole unpacked sequences on one "
+                f"sequence shard: no sequence axis, no segment ids")
+        from orion_tpu.ops.lightning import lightning_chunked
+        from orion_tpu.ops.sparse import whole_sequence
+
+        @jax.named_scope("kernel")
+        def whole(q, k, v):
+            # The XLA forms from an empty cache, which JAX differentiates.
+            if att == "sparse":
+                return whole_sequence(q, k, v, cfg.sparse), None
+            scale = jnp.asarray(q.shape[-1] ** -0.5, q.dtype)
+            return lightning_chunked(q * scale, k, v)[0].astype(q.dtype), None
+
+        return whole
+
     @jax.named_scope("kernel")
     def attend(q, k, v, sink=None):
         if sp_active:
@@ -971,7 +1022,7 @@ def block(
             with jax.named_scope("norm"):
                 a = _norm(a, bp["post_attn_norm"], cfg, mesh)
         with jax.named_scope("out"):
-            x = x + a
+            x = x + _residual(a, cfg)
     with jax.named_scope("mlp_moe"):
         with jax.named_scope("norm"):
             h2 = checkpoint_name(
@@ -987,8 +1038,15 @@ def block(
         # The residual add goes where its operand came from: an expert
         # layer's combine, a dense layer's MLP.
         with jax.named_scope("dispatch" if "moe" in bp else "dense"):
-            x = x + y
+            x = x + _residual(y, cfg)
     return x, aux, state
+
+
+def _residual(y: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """A sublayer's output as it joins the residual stream."""
+    if cfg.residual_scale == 1.0:
+        return y
+    return y * jnp.asarray(cfg.residual_scale, y.dtype)
 
 
 def scan_layer_plan(blocks: Params, plan, body, carry):
@@ -1199,7 +1257,8 @@ def _hidden_states(
                 f"scan_group=1 and no pipeline axis")
         kinds = cfg.layer_kinds
         fns = {j: _remat(make_block_fn(kinds[j].window, kind=kinds[j]))
-               for j in map(plan.start, range(plan.lead + plan.period))}
+               for j in map(plan.start, range(plan.lead + plan.period))
+               if j < len(kinds)}       # (a plan of lead elements alone)
 
         def body(carry, bp, l, j, stack):
             x, aux_t = carry

@@ -177,7 +177,13 @@ def dump(tree: str, out: str) -> int:
                 params, cache, i32(B), i32(B), i32(B, pages_per_seq(icfg)),
                 jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip), keys,
             ).compile().as_text())
-        todo = serve.cell_prefill_shapes(cell, icfg)
+        # A kind that closes its own set of shapes says so (``serve_chunks``:
+        # one row of a chunk and of its tail buckets, each resuming over the
+        # slot's whole page-table row).
+        todo = getattr(cell.kind_module(), "cell_prefill_shapes",
+                       serve.cell_prefill_shapes)(cell, icfg)
+        resumed = (pages_per_seq(icfg)
+                   if getattr(mcfg, "resumes_prefill", False) else 0)
         size = lambda s: (s[0] * s[1], s[0])
         prefill = jax.jit(_jitted(
             "prefill", runner.prefill_step, cfg=mcfg, mesh=None,
@@ -187,7 +193,7 @@ def dump(tree: str, out: str) -> int:
                      i32(B), u32(*key.shape)) if chained else ()
             save(f"{name}.prefill_{nb}x{s_pad}.compiled.txt", prefill.lower(
                 params, cache, i32(nb, s_pad), i32(nb),
-                i32(nb, s_pad // icfg.page_size), i32(nb), i32(nb, 0),
+                i32(nb, s_pad // icfg.page_size), i32(nb), i32(nb, resumed),
                 *extra,
             ).compile().as_text())
 
