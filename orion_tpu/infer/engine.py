@@ -1612,7 +1612,12 @@ class InferenceEngine:
             # in context (decode_sparse_context_keys: the first over it is
             # the share a query sees). Over a prefill's real positions, the
             # sparse layers and the QUERY heads: the (head, key) pairs the
-            # selections leave visible (prefill_sparse_visible_pairs). The
+            # selections leave visible (prefill_sparse_visible_pairs); over
+            # them, the sparse layers and the K/V heads, the pages a query
+            # lists (prefill_sparse_selected_pages) and those of them that
+            # every query of its block lists, which the prefill kernel walks
+            # once a block: the forced pages, and every page while there is
+            # nothing to choose (prefill_sparse_shared_pages). The
             # live slots x the lightning layers a token step, each one state
             # row read and written (decode_lightning_slot_layers), and the
             # real prompt positions x the lightning layers the chunked form
@@ -1624,6 +1629,8 @@ class InferenceEngine:
             # takes min(causal blocks, topk) pages whatever it picks.
             "decode_sparse_visible_keys": 0, "decode_sparse_context_keys": 0,
             "prefill_sparse_visible_pairs": 0,
+            "prefill_sparse_selected_pages": 0,
+            "prefill_sparse_shared_pages": 0,
             "decode_lightning_slot_layers": 0,
             "prefill_lightning_token_layers": 0,
             "sala_live_page_bytes": 0, "lightning_live_state_bytes": 0,
@@ -4531,10 +4538,18 @@ class InferenceEngine:
         """The prefill counters of a model of sparse and lightning layers
         for ``n`` real positions from ``start`` on."""
         pos = np.arange(start, start + n, dtype=np.int64)
-        self.timing["prefill_sparse_visible_pairs"] += (
-            self.mcfg.n_layers_of("sparse") * self.mcfg.n_heads
-            * int(self._visible_keys(pos).sum()))
-        self.timing["prefill_lightning_token_layers"] += (
+        sp, t = self.mcfg.sparse, self.timing
+        layers = self.mcfg.n_layers_of("sparse")
+        t["prefill_sparse_visible_pairs"] += (
+            layers * self.mcfg.n_heads * int(self._visible_keys(pos).sum()))
+        causal = pos // sp.block + 1
+        per = layers * self.mcfg.n_kv_heads
+        t["prefill_sparse_selected_pages"] += per * int(
+            np.minimum(causal, sp.topk).sum())
+        t["prefill_sparse_shared_pages"] += per * int(np.where(
+            causal <= sp.topk, causal,
+            sp.init_blocks + sp.local_blocks).sum())
+        t["prefill_lightning_token_layers"] += (
             self.mcfg.n_layers_of("lightning") * n)
 
     def _prefill_earlier_chunks(self, req: Request) -> int:

@@ -1785,8 +1785,9 @@ def _kda_layer(x, cc: Cache, bp: Any, l, j: int, ctx: dict,
 # SPARSE layer writes K and V into pages as the paged backend does, and the
 # compressed keys of the kernels that its new positions complete beside them;
 # each query then selects its pages through the compressed keys and attends
-# to those alone (ops/sparse.py: a page list a query and K/V head, walked by
-# the paged decode kernel over virtual slots). A LIGHTNING layer keeps a state
+# to those alone (ops/sparse.py: a page list a query and K/V head; a decode
+# step walks it by the paged decode kernel over virtual slots, a prompt's
+# chunk a block of queries at a time). A LIGHTNING layer keeps a state
 # row a slot and no page (ops/lightning.py). Both RESUME: a prefill block is
 # a page-aligned chunk that starts at ``prefix_lens`` (0: the prompt's first)
 # over the row's page table ``prefix_pages`` (the slot's WHOLE row, the
@@ -1919,7 +1920,7 @@ def _sparse_attend(q, k, v, cc: Cache, li, ctx: dict, cfg: ModelConfig):
             ckg = ck[base + table].transpose(0, 1, 3, 2, 4).reshape(
                 B, P * kpp, K, ck.shape[-1])
 
-        def tile(qt, post):
+        def tile(qt, post, live=None):
             with jax.named_scope("select"):
                 ids, n = sparse.select(qt, ckg.astype(qt.dtype), post, sp)
                 T = ids.shape[-1]
@@ -1929,31 +1930,39 @@ def _sparse_attend(q, k, v, cc: Cache, li, ctx: dict, cfg: ModelConfig):
                         table[:, None, None, :], (*ids.shape[:3], P)),
                     jnp.minimum(ids, P - 1), axis=-1), 0)
             with jax.named_scope("sparse"):
-                if use_pallas:
-                    given = dict(k_new=k, v_new=v) if fused else {}
-                    out, *written = sparse.attend_pallas(
-                        qt, k_pool, v_pool, pages, n, post, layer_base=base,
-                        interpret=ctx["interpret"],
-                        name=("sparse_paged_decode" if fused
-                              else "sparse_paged_prefill"), **given)
-                else:
+                if not use_pallas:
                     out, written = sparse.attend_xla(
                         qt, k_pool, v_pool, base + pages, ids, n, post), ()
+                elif fused:
+                    out, *written = sparse.attend_pallas(
+                        qt, k_pool, v_pool, pages, n, post, layer_base=base,
+                        k_new=k, v_new=v, interpret=ctx["interpret"])
+                else:
+                    out, written = sparse.attend_blocks(
+                        qt, k_pool, v_pool, pages, post, sp, layer_base=base,
+                        live=live, interpret=ctx["interpret"]), ()
             return out, ids, written
 
-        Qt = sparse.query_tile(Q, B * K)
-        if Qt == Q:
+        if fused:               # (the kernel wrote the position)
             out, ids, written = tile(q, pos)
-            if written:         # (a decode step's kernel wrote the position)
+            if written:
                 k_pool, v_pool = written
         else:
-            cut = lambda a: jnp.moveaxis(
-                a.reshape(B, Q // Qt, Qt, *a.shape[2:]), 1, 0)
-            out, ids = jax.lax.map(
-                lambda xs: tile(*xs)[:2], (cut(q), cut(pos)))
-            out = jnp.moveaxis(out, 0, 1).reshape(q.shape)
-            ids = jnp.moveaxis(ids, 0, 2).reshape(
-                B, ids.shape[2], Q, ids.shape[-1])
+            # Whole blocks of queries a tile; a block past a row's last real
+            # position attends nothing.
+            Qt = sparse.query_tile(Q, B * K, psz)
+            live = ctx["seg"][:, ::psz] > 0
+            if Qt == Q:
+                out, ids, _ = tile(q, pos, live)
+            else:
+                cut = lambda a, n: jnp.moveaxis(
+                    a.reshape(B, Q // Qt, n, *a.shape[2:]), 1, 0)
+                out, ids = jax.lax.map(
+                    lambda xs: tile(*xs)[:2],
+                    (cut(q, Qt), cut(pos, Qt), cut(live, Qt // psz)))
+                out = jnp.moveaxis(out, 0, 1).reshape(q.shape)
+                ids = jnp.moveaxis(ids, 0, 2).reshape(
+                    B, ids.shape[2], Q, ids.shape[-1])
     new["k"], new["v"] = k_pool, v_pool
     if SELECTED in cc:
         # Each row's selection at its last real position.

@@ -18,16 +18,26 @@ forced, and the ``topk`` best causal blocks are taken, forced ones among them
 attention). What comes out is a PAGE LIST a query and K/V head, ascending,
 the query's own block last.
 
-Attention over the list (``attend_xla`` / ``attend_pallas``): one softmax
-over the positions <= t of the selected pages. The list read as a page table
-of a sequence of its own, the own block is the last page and the causal mask
-is the one of a plain paged sequence whose newest position is ``(n - 1) x
-block + t % block``: the Pallas path is ``ops/pallas/paged_attention.attend``
-over VIRTUAL slots, one a (query, K/V head), on a pool that keeps one head a
-row, 16 query rows against up to ``topk`` pages each. A decode step is one
-query a slot with the new token's write fused in; a prompt's chunk is every
-position of the chunk as a virtual slot, in tiles that keep the page lists
-inside the scalar memory.
+Attention over the list: one softmax over the positions <= t of the selected
+pages (``attend_xla``, the gather form: the CPU path and the tests' oracle).
+The kernels differ by what a program has to attend.
+
+A DECODE step is one query a slot (``attend_pallas``): the list read as a
+page table of a sequence of its own, the own block is the last page and the
+causal mask is the one of a plain paged sequence whose newest position is
+``(n - 1) x block + t % block``, so ``ops/pallas/paged_attention.attend``
+walks it over VIRTUAL slots, one a (query, K/V head), on a pool that keeps
+one head a row, 16 query rows against up to ``topk`` pages each, the new
+token's write fused in.
+
+A prompt's CHUNK is whole blocks of queries (``attend_blocks``), and the
+queries of a block share their own block: the forced pages are the same for
+all of them, and while the block has ``topk`` causal blocks or fewer so is
+the whole list (``split_blocks``, a rule on positions alone). The kernel of
+``ops/pallas/sparse_prefill.py`` walks those SHARED pages once a (block, K/V
+head) against every row of the block, and only what is left of each query's
+list, its free choices, one query at a time. A chunk goes through in tiles of
+queries (``query_tile``) that bound the selection's scores.
 """
 
 from __future__ import annotations
@@ -35,8 +45,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# Virtual slots a kernel call (their page lists ride the scalar prefetch:
-# 512 x 64 ids are 128 KiB).
+# (Query, K/V head) lists a tile of a prompt's chunk: the selection scores a
+# tile's queries against every kernel of the row at once (256 queries x 16
+# heads x 4,480 kernels in float32 are 73 MB a K/V head before the softmax's
+# temporaries), and the tile's lists ride one kernel call's scalar prefetch
+# (512 x 31 private ids are 62 KiB).
 TILE_SLOTS = 512
 _BIG = 1e30
 
@@ -167,14 +180,13 @@ def attend_xla(q, k_pool, v_pool, rows, ids, n, pos):
 
 
 def attend_pallas(q, k_pool, v_pool, pages, n, pos, *, layer_base,
-                  k_new=None, v_new=None, interpret=False,
-                  name="sparse_paged_decode"):
-    """The kernel form: ``pages`` [B, K, Q, T] per-layer page ids (0 where
-    unused), the pools one K/V head a row (``kv_cache.sala_leaves``) and
-    every (query, K/V head) a virtual slot of ``paged_attention.attend``.
-    With ``k_new`` / ``v_new`` [B, 1, K, H] (Q = 1: a decode step) the new
-    position is written into the own page in the kernel. -> (out [B, Q, N,
-    H], *pools written)."""
+                  k_new=None, v_new=None, interpret=False):
+    """The kernel form of a decode step: ``pages`` [B, K, Q, T] per-layer
+    page ids (0 where unused), the pools one K/V head a row
+    (``kv_cache.sala_leaves``) and every (query, K/V head) a virtual slot of
+    ``paged_attention.attend``. With ``k_new`` / ``v_new`` [B, 1, K, H] (Q =
+    1) the new position is written into the own page in the kernel. -> (out
+    [B, Q, N, H], *pools written)."""
     from orion_tpu.ops.pallas.paged_attention import attend
 
     B, Q, N, H = q.shape
@@ -192,12 +204,66 @@ def attend_pallas(q, k_pool, v_pool, pages, n, pos, *, layer_base,
         jnp.ones_like(start),
         layer_base=layer_base * K, k_new=k_new, v_new=v_new,
         logit_softcap=None, window=None, interpret=interpret, k_scale=None,
-        v_scale=None, name=name)
+        v_scale=None, name="sparse_paged_decode")
     return (out.reshape(B, Q, N, H), *pools)
 
 
-def query_tile(S: int, rows: int) -> int:
-    """Queries a kernel call of a chunk of ``S`` positions: the largest
-    divisor of S whose virtual slots (``rows`` a query) fit TILE_SLOTS."""
-    most = max(TILE_SLOTS // rows, 1)
-    return max(t for t in range(1, min(S, most) + 1) if S % t == 0)
+def split_blocks(pages: jax.Array, pos: jax.Array, sp, live=None):
+    """The lists of whole blocks of queries, ``pages`` [B, K, Q, T] (a
+    selection's ids or their pages, ascending, the own block last) at
+    positions ``pos`` [B, Q] that start on a block's first, split by what
+    the positions alone determine. The queries of a block share its forced
+    pages (``forced_blocks``: the first ``init_blocks`` and the
+    ``local_blocks`` that end with the own), and with T causal blocks or
+    fewer they share every page, the selection having nothing to choose.
+    -> (shared [B, K, Q / block, T], n_shared [B, K, Q / block]: the pages
+    every query of a block attends, the own block last, and how many are
+    real; private [B, K, Q, T - forced], n_private [B, K, Q / block]: what
+    is left of each query's list and how many of them a query of the block
+    has, all or none). ``live`` [B, Q / block] bool: the blocks that hold a
+    real position; the others get no page."""
+    T = pages.shape[-1]
+    forced = sp.init_blocks + sp.local_blocks
+    own = pos[:, None, ::sp.block] // sp.block                  # [B, 1, QB]
+    lead = pages[:, :, ::sp.block]          # a block's first query's list
+    whole = jnp.broadcast_to(own < T, lead.shape[:3])
+    if T <= forced:     # (no free choice: a block's queries hold one list)
+        shared, private = lead, pages[..., :0]
+    else:
+        window = jnp.concatenate(
+            [lead[..., :sp.init_blocks], lead[..., T - sp.local_blocks:],
+             jnp.zeros_like(lead[..., forced:])], -1)
+        shared = jnp.where(whole[..., None], lead, window)
+        private = pages[..., sp.init_blocks:T - sp.local_blocks]
+    n_shared = jnp.where(whole, own + 1, forced).astype(jnp.int32)
+    n_private = jnp.where(whole, 0, T - forced).astype(jnp.int32)
+    if live is not None:
+        n_shared, n_private = (jnp.where(live[:, None], a, 0)
+                               for a in (n_shared, n_private))
+    return shared, n_shared, private, n_private
+
+
+def attend_blocks(q, k_pool, v_pool, pages, pos, sp, *, layer_base,
+                  live=None, interpret=False):
+    """The kernel form of a prompt's chunk: q [B, Q, N, H] at positions
+    ``pos`` [B, Q], whole blocks from a block's first on; ``pages`` [B, K,
+    Q, T] per-layer page ids (0 where unused); the pools one K/V head a row,
+    the chunk's K and V in them already. Each block's shared pages are
+    walked once for all its queries, its queries' private pages a query at a
+    time (``split_blocks``), in one softmax a query. Blocks outside ``live``
+    [B, Q / block] come out zero. -> [B, Q, N, H]."""
+    from orion_tpu.ops.pallas import sparse_prefill
+
+    return sparse_prefill.attend(
+        q, k_pool, v_pool, *split_blocks(pages, pos, sp, live),
+        layer_base=layer_base, interpret=interpret)
+
+
+def query_tile(S: int, rows: int, block: int) -> int:
+    """Queries a tile of a chunk of ``S`` positions, whole blocks: the
+    largest divisor of S in blocks whose lists (``rows`` a query) fit
+    TILE_SLOTS, at least one block."""
+    blocks = S // block
+    most = max(TILE_SLOTS // (rows * block), 1)
+    return block * max(
+        t for t in range(1, min(blocks, most) + 1) if blocks % t == 0)

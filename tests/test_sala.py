@@ -6,7 +6,8 @@ benchmark's own comparison; chunks of two sizes and a whole prompt leave the
 same pages, compressed keys, state rows and logits; the lightning chunked
 form against the recurrence; the program's selection against the
 reference's, id for id; a short context is plain causal attention; the decode
-kernel (interpret mode) against its gather form; under peaked weights, a
+kernel and the prefill kernel (interpret mode) against the gather form, a
+first chunk, resumed chunks and a tail bucket; under peaked weights, a
 selection without the forced window, without the pooling, a head's in a
 group's place, and a lightning layer without its decay each fail the logit
 comparison; what the engine refuses; the preset against the published file.
@@ -210,6 +211,27 @@ def test_the_selection_is_the_references_id_for_id_and_short_is_dense():
         q[:, :48], k[:, :48], v), dense) < 1e-5
 
 
+def _selected(sp, q, pos, key, NP, P=20):
+    """(page table [B, P] drawn from a pool of NP pages, and what ``select``
+    makes of q at ``pos`` over random compressed keys: ids, n, pages)."""
+    from orion_tpu.ops import sparse
+
+    B = q.shape[0]
+    table = jnp.asarray(np.random.default_rng(0).permutation(
+        np.arange(1, NP))[:B * P].reshape(B, P))
+    # compressed keys that are never complete score nothing: give some
+    ck = jax.random.normal(key, (B, P * 4, q.shape[2] // 2, q.shape[3]))
+    @jax.jit
+    def lists(q, ck, pos):      # (one program: the eager forms are slow)
+        ids, n = sparse.select(q, ck, pos, sp)
+        used = jnp.arange(ids.shape[-1])[None, None, None] < n[..., None]
+        return ids, n, jnp.where(used, jnp.take_along_axis(
+            jnp.broadcast_to(table[:, None, None], (*ids.shape[:3], P)),
+            jnp.minimum(ids, P - 1), -1), 0)
+
+    return (table, *lists(q, ck, pos))
+
+
 def test_the_decode_kernel_is_its_gather_form():
     """``attend_pallas`` (interpret mode: the paged decode kernel over
     virtual slots, the new token's write fused in) against ``attend_xla`` on
@@ -225,15 +247,7 @@ def test_the_decode_kernel_is_its_gather_form():
     q = jax.random.normal(next(key), (B, 1, N, H))
     k, v = (jax.random.normal(next(key), (B, 1, K, H)) for _ in range(2))
     pos = jnp.asarray([[150], [64]])
-    table = jnp.asarray(np.random.default_rng(0).permutation(
-        np.arange(1, NP))[:B * 20].reshape(B, 20))
-    # compressed keys that are never complete score nothing: give some
-    ck = jax.random.normal(next(key), (B, 20 * 4, K, H))
-    ids, n = sparse.select(q, ck, pos, sp)
-    used = jnp.arange(ids.shape[-1])[None, None, None] < n[..., None]
-    pages = jnp.where(used, jnp.take_along_axis(
-        jnp.broadcast_to(table[:, None, None], (*ids.shape[:3], 20)),
-        jnp.minimum(ids, 19), -1), 0)
+    table, ids, n, pages = _selected(sp, q, pos, next(key), NP)
     base = NP       # the second layer's rows
     out, kp, vp = sparse.attend_pallas(
         q, *pools, pages, n, pos, layer_base=base, k_new=k, v_new=v,
@@ -245,6 +259,49 @@ def test_the_decode_kernel_is_its_gather_form():
     assert bool((kp == written[0]).all()) and bool((vp == written[1]).all())
     want = sparse.attend_xla(q, *written, base + pages, ids, n, pos)
     assert _rel(out, want) < 1e-5
+
+
+@pytest.mark.parametrize("starts, lengths, steps, shared, private", [
+    # a prompt's first chunk: every block's pages are shared, none private
+    ([0], [32], None, [[1, 2, 3, 4]], [[0, 0, 0, 0]]),
+    # resumed chunks across and past ``topk`` causal blocks: both walks,
+    # lists of unlike length, two shared and two private steps a list
+    ([32, 64], [32, 32], (2, 1), [[5, 6, 4, 4], [4, 4, 4, 4]],
+     [[0, 0, 2, 2], [2, 2, 2, 2]]),
+    # a tail bucket: 13 real positions, two blocks that hold none
+    ([64], [13], None, [[4, 4, 0, 0]], [[2, 2, 0, 0]]),
+], ids=["first", "resumed", "ragged"])
+def test_the_prefill_kernel_is_its_gather_form(monkeypatch, starts, lengths,
+                                               steps, shared, private):
+    """``attend_blocks`` (interpret mode: a block's shared pages walked once
+    for its 8 queries, its queries' private pages a query at a time, one
+    softmax) against ``attend_xla`` at every real position of a chunk of 4
+    blocks, and how ``split_blocks`` split each block's lists."""
+    from orion_tpu.ops import sparse
+    from orion_tpu.ops.pallas import sparse_prefill
+
+    if steps:
+        monkeypatch.setattr(sparse_prefill, "SHARED_PAGES", steps[0])
+        monkeypatch.setattr(sparse_prefill, "PRIVATE_PAGES", steps[1])
+    sp = get_config("tiny-sala").model.sparse
+    B, Q, N, K, H, psz, NP = len(starts), 32, 4, 2, 16, sp.block, 64
+    key = iter(jax.random.split(jax.random.PRNGKey(7), 8))
+    pools = [jax.random.normal(next(key), (2 * NP * K, 1, psz, H))
+             for _ in range(2)]
+    q = jax.random.normal(next(key), (B, Q, N, H))
+    pos = jnp.asarray(starts)[:, None] + jnp.arange(Q)[None]
+    _, ids, n, pages = _selected(sp, q, pos, next(key), NP)
+    real = np.arange(Q)[None] < np.asarray(lengths)[:, None]
+    live = jnp.asarray(real[:, ::psz])
+    _, n_shared, _, n_private = sparse.split_blocks(pages, pos, sp, live)
+    for got, want in ((n_shared, shared), (n_private, private)):
+        assert (np.asarray(got) == np.asarray(want)[:, None]).all()
+    base = NP       # the second layer's rows
+    out = sparse.attend_blocks(q, *pools, pages, pos, sp, layer_base=base,
+                               live=live, interpret=True)
+    want = jax.jit(sparse.attend_xla)(q, *pools, base + pages, ids, n, pos)
+    assert _rel(np.asarray(out)[real], np.asarray(want)[real]) < 1e-5
+    assert not np.asarray(out)[~np.repeat(real[:, ::psz], psz, 1)].any()
 
 
 # -- the engine, through the benchmark's own comparison ---------------------------
@@ -345,6 +402,14 @@ def test_the_counters_are_host_arithmetic_on_lengths(tiny):
     seen = lambda p: min(p + 1, (sp.topk - 1) * sp.block + p % sp.block + 1)
     assert t["prefill_sparse_visible_pairs"] == L * 4 * sum(
         seen(p) for p in range(n))
+    # pages a (position, layer, K/V head) lists; every one is shared by
+    # its block while nothing is chosen, then the forced four of six
+    blocks = [p // sp.block + 1 for p in range(n)]
+    assert t["prefill_sparse_selected_pages"] == L * 2 * sum(
+        min(b, sp.topk) for b in blocks)
+    assert t["prefill_sparse_shared_pages"] == L * 2 * sum(
+        b if b <= sp.topk else sp.init_blocks + sp.local_blocks
+        for b in blocks)
     assert t["prefill_lightning_token_layers"] == 2 * n
     steps = range(n, n + new - 1)           # the first token is prefill's
     windows = -(-len(steps) // 4)
@@ -355,6 +420,18 @@ def test_the_counters_are_host_arithmetic_on_lengths(tiny):
         p + 1 for p in pos)
     assert t["prefill_dispatches"] == 4 and t["prefill_tokens"] == n
     eng.close()
+    # a model with no sparse layer lists nothing
+    from orion_tpu.infer import InferenceEngine
+    from orion_tpu.models.transformer import init_params
+
+    cfg = get_config("tiny-llama")
+    plain = InferenceEngine(
+        cfg, init_params(cfg.model, jax.random.PRNGKey(0)), seed=0)
+    plain.generate([[7, 8, 9]], max_new_tokens=2)
+    t = plain.reset_timing()
+    assert t["prefill_tokens"] == 3 and not (
+        t["prefill_sparse_selected_pages"] or t["prefill_sparse_shared_pages"])
+    plain.close()
 
 
 # -- faults the logit comparison has to see -------------------------------------
